@@ -184,8 +184,6 @@ def self_serve(args):
     import jax
     import jax.numpy as jnp
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     from infinistore_tpu.engine import InferenceEngine
     from infinistore_tpu.kv import PagedCacheConfig
     from infinistore_tpu.models import TINY, init_params, scaled

@@ -28,9 +28,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 import jax
 
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import infinistore_tpu as ist
 from infinistore_tpu.engine import InferenceEngine
 from infinistore_tpu.kv import PagedCacheConfig

@@ -312,7 +312,7 @@ class StageLedger:
     def snapshot(self, limit: Optional[int] = None,
                  include_rows: bool = True) -> Dict[str, Any]:
         """The ``/debug/critpath`` payload: overall + per-lane
-        aggregates, stage taxonomy, and (optionally) the row tail."""
+        aggregates, stage names, and (optionally) the row tail."""
         rows = self.rows()
         lanes: Dict[str, List[Dict[str, Any]]] = {}
         for r in rows:

@@ -4,25 +4,24 @@ The serving metrics say how the fleet is doing and the request ledger
 says where one request's latency went — but the ENGINE STEP LOOP and the
 device under it were a black box: no dispatch counts, no compile/retrace
 visibility, no host-blocked vs device-busy split, no HBM watermarks.
-The two losing on-chip stories (`prefill_store_overhead: 12.97x`,
-`spec_speedup: 0.53` at 0.938 acceptance — BENCH_TPU_SNAPSHOT.json)
-are unexplainable without exactly that attribution.  This module makes
-the step loop emit ONE structured record per scheduler step:
+A slow store-attached prefill or a speculation mode slower than plain
+decode at high acceptance (ROADMAP A1, A5) cannot be explained without
+exactly that attribution.  This module makes the step loop emit ONE
+structured record per scheduler step:
 
 * **step kind and batch composition** — prefill chunks advanced, decode
   sequences, speculative rounds, pending depth;
 * **dispatch counts** — compiled STEP programs launched (decode scan
   chunks, prefill chunk forwards, verify/draft forwards, fused
-  speculation rounds).  Counted at the granularity whose per-dispatch
-  overhead dominates on this platform (docs/tpu_perf_notes.md), not raw
-  XLA executable launches;
+  speculation rounds).  Counted per step program, not per raw XLA
+  executable launch;
 * **host-stall vs device time** — on SAMPLED steps (1 in
   ``ISTPU_STEPPROF_SAMPLE``, default 16) the profiler times a
   ``block_until_ready`` on the engine's cache after the step body:
   the measured wait is device work the host did NOT overlap.  High
   stall share ⇒ device-bound; ~0 stall with long steps ⇒ the host loop
   (dispatch overhead, Python) is the bottleneck — read this before
-  blaming a kernel (docs/tpu_perf_notes.md).  Sampling keeps the ≤5%
+  blaming a kernel.  Sampling keeps the ≤5%
   instrumentation-overhead guard passing: a per-step block would
   serialize the async dispatch pipeline the engine exists to keep full;
 * **compile/retrace events** — a ``jax.monitoring`` duration listener
@@ -36,7 +35,7 @@ the step loop emit ONE structured record per scheduler step:
   ``jax.live_arrays()`` on CPU; sampled with the stall probe;
 * **speculation attribution** — per-step deltas of the speculator's
   rounds/proposed/accepted counters next to the dispatch counts, so
-  "0.53x despite 0.938 acceptance" reads as tokens-per-dispatch, not a
+  a slowdown at high acceptance reads as tokens-per-dispatch, not a
   mystery;
 * **store-hop stages** — when a step moved pages, the transfer's
   ``last_push_stages`` / ``last_load_stages`` breakdown rides along
@@ -220,19 +219,29 @@ def default_mem_reader() -> Optional[Dict[str, int]]:
     """Device memory watermarks: ``memory_stats()`` where the backend
     provides it (TPU/GPU PJRT devices), else the CPU fallback — the sum
     of live jax array bytes (``live``) with ``peak`` tracked by the
-    caller.  Returns None when nothing is measurable."""
+    caller.  Every local device is read: the scalar keys are the FULLEST
+    device's (the one that runs out first) and ``devices`` lists each, so
+    a sharded engine whose shards all sit on device 0 shows as exactly
+    that.  Returns None when nothing is measurable."""
     try:
         import jax
 
-        dev = jax.devices()[0]
-        stats = getattr(dev, "memory_stats", lambda: None)()
-        if stats:
-            live = int(stats.get("bytes_in_use", 0))
-            peak = int(stats.get("peak_bytes_in_use", live))
-            limit = int(stats.get("bytes_limit", 0))
-            out = {"live_bytes": live, "peak_bytes": peak}
-            if limit:
-                out["limit_bytes"] = limit
+        per_dev = []
+        for dev in jax.local_devices():
+            stats = getattr(dev, "memory_stats", lambda: None)()
+            if stats:
+                live = int(stats.get("bytes_in_use", 0))
+                per_dev.append({
+                    "id": dev.id, "live_bytes": live,
+                    "peak_bytes": int(stats.get("peak_bytes_in_use", live)),
+                    "limit_bytes": int(stats.get("bytes_limit", 0)),
+                })
+        if per_dev:
+            out = {k: max(d[k] for d in per_dev)
+                   for k in ("live_bytes", "peak_bytes", "limit_bytes")}
+            if not out["limit_bytes"]:
+                del out["limit_bytes"]
+            out["devices"] = per_dev
             return out
         live = sum(int(x.nbytes) for x in jax.live_arrays())
         return {"live_bytes": live, "peak_bytes": live, "cpu_fallback": 1}
@@ -621,7 +630,7 @@ class StepProfiler:
             "mem": mem,
         }
         # speculation economy: accepted tokens per fused dispatch, the
-        # read that explained r4's "0.53x at 0.938 acceptance" (up is
+        # read that explains a slowdown at high acceptance (up is
         # good; absent when no spec step ever ran)
         n_spec_disp = dispatches.get("spec_round", 0)
         if n_spec_disp and spec_tot["proposed"]:

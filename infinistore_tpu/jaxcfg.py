@@ -4,23 +4,23 @@ Imported by every jax-touching subpackage (engine/models/kv/ops/parallel)
 before any tracing happens.  The store tier (config/protocol/lib/server)
 stays jax-free and must not import this.
 
-``jax_threefry_partitionable``: the legacy non-partitionable threefry
-``jax.random.split`` lowers to a pathologically slow program on TPU —
-measured ~90 ms per call on a v5e where a normal dispatch is ~0.02 ms.
-The decode scan splits twice per chunk, so this single flag was worth
-~2x end-to-end decode throughput on chip.  The partitionable form is
-also the one that shards cleanly under pjit (keys split identically on
-every device), which is what the tp/sp paths want.  Opt out with
-``ISTPU_PARTITIONABLE_PRNG=0`` (changes sampled streams, not their
-distribution).
+The installed JAX (0.9) already defaults ``jax_threefry_partitionable`` to
+true — the form whose keys split identically on every device, which the
+tp/sp paths and sharded weight init rely on — so nothing is set for it
+here; ``JAX_THREEFRY_PARTITIONABLE`` remains JAX's own switch.
 
-Import side effect, bounded: this mutates process-global jax config, so
-a host application embedding this package would see its PRNG streams
-change.  Two escape hatches keep that from being silent: the env
-opt-out above, and — checked here — if the host already set the flag
-explicitly (``jax.config.update`` or ``JAX_THREEFRY_PARTITIONABLE``)
-before importing us, we leave their choice alone.  Called out in
-README.md and docs/api.md, not only here.
+Persistent compilation cache: a serving process compiles one program per
+shape bucket, and on an accelerator that is most of a cold start.  The
+cache directory is part of each entry's key, so it has to be the same
+path in every process that should share compiled programs:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it; nothing is set here,
+  so whoever launches the process places the cache.
+* not set: ``<checkout>/.jax_cache`` — fixed, never a temporary
+  directory, a pid or a timestamp.
+* ``JAX_PLATFORMS`` pinning ``cpu`` (the test suite): no default.  XLA:CPU
+  reloads of ahead-of-time results warn about machine-feature mismatches
+  and can SIGILL on a different host; the CPU suite gains little from it.
 """
 
 from __future__ import annotations
@@ -29,23 +29,21 @@ import os
 
 import jax
 
-
-def _host_already_chose() -> bool:
-    """True when the embedding application explicitly chose
-    ``jax_threefry_partitionable`` before this import — their choice
-    wins over our default.  jax keeps no "was explicitly set" bit, but
-    since jax 0.4.36 the flag DEFAULTS to True, so observing False at
-    import time can only mean an explicit env/host choice."""
-    if "JAX_THREEFRY_PARTITIONABLE" in os.environ:
-        return True
-    try:
-        ver = tuple(int(x) for x in jax.__version__.split(".")[:3])
-    except ValueError:  # dev/rc suffixes — assume modern
-        ver = (0, 4, 36)
-    defaults_true = ver >= (0, 4, 36)
-    return defaults_true and not jax.config.jax_threefry_partitionable
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 
 
-if (os.environ.get("ISTPU_PARTITIONABLE_PRNG", "1") != "0"
-        and not _host_already_chose()):
-    jax.config.update("jax_threefry_partitionable", True)
+def default_cache_dir(environ) -> str | None:
+    """The cache directory this module should set given ``environ``, or
+    None when it must set none (placed from outside, or CPU-pinned)."""
+    if environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    if environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    return DEFAULT_COMPILATION_CACHE_DIR
+
+
+_cache_dir = default_cache_dir(os.environ)
+if _cache_dir is not None:
+    jax.config.update("jax_compilation_cache_dir", _cache_dir)

@@ -54,10 +54,12 @@ class PagedCacheConfig:
         return (2, self.n_kv_heads, self.block_tokens, self.head_dim)
 
 
-def init_cache(cfg: PagedCacheConfig) -> jax.Array:
+def init_cache(cfg: PagedCacheConfig, sharding=None) -> jax.Array:
+    """Zeroed cache; with ``sharding`` it is created in its shards (a cache
+    sized for a mesh need not fit one device first)."""
     return jnp.zeros(
         (cfg.n_layers, 2, cfg.n_kv_heads, cfg.n_blocks, cfg.block_tokens, cfg.head_dim),
-        dtype=cfg.dtype,
+        dtype=cfg.dtype, device=sharding,
     )
 
 
@@ -82,15 +84,31 @@ def write_token_kv(
     k: jax.Array,
     v: jax.Array,
 ) -> jax.Array:
-    """Scatter one token per sequence into layer ``layer``.
+    """Write one token per sequence into layer ``layer``.
 
     block_ids/slot_ids: [B] page id and in-page slot for each sequence's
-    current position; k/v: [B, n_kv_heads, head_dim].
-    """
+    current position; k/v: [B, n_kv_heads, head_dim].  The page ids must be
+    distinct (each sequence appends to a page of its own) or out of bounds
+    (pad rows: their write is dropped).
+
+    The write is a read-modify-write of WHOLE pages: gather the B pages,
+    put each token in its slot, scatter the pages back.  A scatter of the
+    bare [B, 2, H, D] token rows makes XLA:TPU re-lay the whole cache out
+    with heads next to head_dim for the decode loop (the one-row update
+    has no (T, D) tile), and the copy that does so is a second cache: on a
+    v5e a cache of 5.6 GB next to 7 GB of weights then fails to compile
+    ("Used 18.68G of 15.75G hbm").  Whole pages keep the [T, D] tile, the
+    donated cache is updated in place, and a step moves 16x the bytes of
+    the rows it writes — tens of MB next to GBs of weights."""
+    T = cache.shape[4]
     kv = jnp.stack([k, v], axis=1)  # [B, 2, H, D]
-    # advanced indices (layer, block_ids, slot_ids) are separated by slices,
-    # so the broadcast batch dim lands in FRONT: target shape [B, 2, H, D]
-    return cache.at[layer, :, :, block_ids, slot_ids].set(kv)
+    # advanced indices (layer, block_ids) are separated by slices, so the
+    # batch dim lands in FRONT: [B, 2, H, T, D]; out-of-bounds ids clamp
+    # on the gather and are dropped by the scatter
+    pages = cache[layer, :, :, block_ids]
+    here = jnp.arange(T)[None, :] == slot_ids[:, None]  # [B, T]
+    pages = jnp.where(here[:, None, None, :, None], kv[:, :, :, None, :], pages)
+    return cache.at[layer, :, :, block_ids].set(pages)
 
 
 def write_tokens_kv(
@@ -107,15 +125,16 @@ def write_tokens_kv(
 
     block_ids/slot_ids: [B, S]; k/v: [B, S, n_kv_heads, head_dim].
     Distinct (page, slot) targets per token, so the flat scatter is exact.
-    """
+    A run's tokens share pages, so this stays a scatter of token rows (a
+    page-wise read-modify-write would lose all but one of them) and keeps
+    the relayout cost write_token_kv describes: speculation at a
+    deployment-sized cache is not measured (ROADMAP A5)."""
     B, S = block_ids.shape
-    return write_token_kv(
-        cache, layer,
-        block_ids.reshape(B * S),
-        slot_ids.reshape(B * S),
-        k.reshape((B * S,) + k.shape[2:]),
-        v.reshape((B * S,) + v.shape[2:]),
-    )
+    kv = jnp.stack([k, v], axis=2).reshape((B * S, 2) + k.shape[2:])
+    # batch dim in FRONT, as above: target shape [B*S, 2, H, D]
+    return cache.at[
+        layer, :, :, block_ids.reshape(B * S), slot_ids.reshape(B * S)
+    ].set(kv)
 
 
 def prefill_to_pages(kv: jax.Array, n_pages: int, block_tokens: int) -> jax.Array:

@@ -17,6 +17,7 @@ layer-by-layer streaming (reference design.rst prefill flow) stays possible.
 from __future__ import annotations
 
 import time
+from functools import partial
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -28,6 +29,18 @@ from ..utils import tracing
 from .cache import PagedCacheConfig, read_pages, write_pages
 from .hashing import layer_key
 from .quant import dequantize_pages_jit, page_quant_bytes, quantize_pages
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _scatter_stacked(cache: jax.Array, block_ids: jax.Array,
+                     stacked: jax.Array) -> jax.Array:
+    """Store-layout pages [L, n, 2, H, T, D] into ``block_ids``'s slots of
+    the DONATED cache: a store hit updates the cache in place.  Eager, the
+    scatter needs a second whole cache, and a cache sized like a deployment
+    (most of HBM) cannot exist twice."""
+    return write_pages(
+        cache, block_ids, jnp.transpose(stacked, (0, 2, 3, 1, 4, 5))
+    )
 
 
 class KVTransferEngine:
@@ -80,8 +93,7 @@ class KVTransferEngine:
         # CPU backend possibly a zero-copy alias), so the buffer a call
         # used must not be rewritten by the NEXT call's pool reads while
         # transfers could still be in flight — the alternation plus the
-        # end-of-call block makes reuse safe even on runtimes whose
-        # block_until_ready is optimistic (docs/tpu_perf_notes.md trap 1)
+        # end-of-call block makes reuse safe
         self._staging: list = [None, None]
         self._staging_idx = 0
         # push path selector: "auto" (default) = alloc-first zero-copy on
@@ -368,8 +380,10 @@ class KVTransferEngine:
         serializing with it.  Bands write to DISTINCT staging offsets,
         so an in-flight upload never races the next read.
 
-        Returns the updated cache array.  Raises InfiniStoreKeyNotFound if
-        any page is missing (reference read semantics).
+        Returns the updated cache array; ``cache`` itself is donated to
+        it once every byte has landed (a failed fetch raises before that
+        and leaves it usable).  Raises InfiniStoreKeyNotFound if any page
+        is missing (reference read semantics).
         """
         assert len(block_ids) == len(chunk_keys_)
         n = len(block_ids)
@@ -437,15 +451,13 @@ class KVTransferEngine:
     ) -> jax.Array:
         """Device half of a load: dequantize/transpose the stacked
         pages ``fetch_pages`` returned and scatter them into
-        ``block_ids``'s slots.  Returns the updated cache (NOT yet
-        materialized — callers block once after the last scatter)."""
+        ``block_ids``'s slots.  ``cache`` is DONATED: use the returned
+        array (NOT yet materialized — callers block once after the last
+        scatter)."""
         if self.quant:
-            unpacked = dequantize_pages_jit(stacked, self.cfg)  # [L, n, 2, H, T, D]
-            pages = jnp.transpose(unpacked, (0, 2, 3, 1, 4, 5))
-        else:
-            pages = jnp.transpose(stacked, (0, 2, 3, 1, 4, 5))  # [L,2,H,n,T,D]
+            stacked = dequantize_pages_jit(stacked, self.cfg)  # [L, n, 2, H, T, D]
         ids = jnp.asarray(np.asarray(block_ids, dtype=np.int32))
-        return write_pages(cache, ids, pages)
+        return _scatter_stacked(cache, ids, stacked)
 
     def _load_pages_banded(
         self, cache: jax.Array, block_ids: Sequence[int],
@@ -522,9 +534,9 @@ class KVTransferEngine:
         chunk_keys_: Sequence[str],
     ) -> Tuple[jax.Array, bool]:
         """``load_pages`` degraded to ``(cache-unchanged, False)`` on any
-        failure.  Loads are all-or-nothing (``write_pages`` runs after
-        every byte landed), so a mid-load transport failure leaves the
-        HBM cache untouched and the caller falls back to recompute."""
+        failure.  Loads are all-or-nothing (the donating scatter runs
+        after every byte landed), so a mid-load transport failure leaves
+        the HBM cache untouched and the caller falls back to recompute."""
         if not self.breaker.allow():
             _resilience.count_degraded("load")
             return cache, False

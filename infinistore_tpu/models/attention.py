@@ -226,13 +226,9 @@ def causal_attention(
         # padding): 1B/B=8 decode 46->70 ms/step, TTFT 6.8->83 ms on a v5e
         and jax.default_backend() == "tpu"
         # OPT-IN (ISTPU_PALLAS_PREFILL, any truthy value — same parsing
-        # as ISTPU_PALLAS_DECODE), same policy as the decode kernel: the
-        # round-4 recorded flash-vs-XLA reads DISAGREE across runs
-        # (BENCH_r04.json: 0.75x; BENCH_TPU_SNAPSHOT.json: 1.07x) —
-        # exactly the unreplicated-single-shot problem VERDICT r4 weak
-        # #1 called out — so the default is the simpler XLA path until
-        # the round-5 median-of-3 leg (2k AND 8k, spread recorded)
-        # lands a replicated >1x.
+        # as ISTPU_PALLAS_DECODE), same policy as the decode kernel:
+        # flash against XLA is not measured on a directly attached
+        # chip, so the default is the simpler XLA path (ROADMAP A6).
         and bool(os.environ.get("ISTPU_PALLAS_PREFILL"))
         and not os.environ.get("ISTPU_NO_PALLAS")
     ):

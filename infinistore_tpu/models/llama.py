@@ -123,50 +123,98 @@ def scaled(cfg: LlamaConfig, **kw) -> LlamaConfig:
     return replace(cfg, **kw)
 
 
-def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
+def load_config_file(path: str) -> Tuple[str, LlamaConfig, int]:
+    """Resolve a checked-in model config file (``configs/*.json``) to
+    ``(model_id, cfg, seed)``: a preset of this module by name, the
+    ``published`` sizes it must agree with (so a jax-free launcher can read
+    them from the file, and a file cannot quietly serve other widths), a
+    ``reduced`` block that may cut ``n_layers`` only (widths are never cut
+    — a toy width measures overheads, not the model), and the seed the
+    weights are drawn from.  The id commits to everything the weights
+    depend on, so two files that build different weights never share store
+    keys.  Other keys (``source``, ``stands_for``, ``assumed``) document
+    the cut."""
+    import json
+
+    with open(path) as f:
+        spec = json.load(f)
+    base = globals().get(spec.get("preset"))
+    if type(base) is not LlamaConfig:
+        raise ValueError(f"{path}: preset {spec.get('preset')!r} is not a "
+                         f"dense preset of infinistore_tpu.models")
+    for k, v in spec.get("published", {}).items():
+        if getattr(base, k, None) != v:
+            raise ValueError(f"{path}: published {k}={v!r} is not preset "
+                             f"{spec['preset']}'s {getattr(base, k, None)!r}"
+                             f" (widths are never overridden)")
+    reduced = spec.get("reduced", {})
+    if set(reduced) - {"n_layers"}:
+        raise ValueError(f"{path}: 'reduced' may change n_layers only, got "
+                         f"{sorted(reduced)}")
+    n_layers = reduced.get("n_layers", base.n_layers)
+    if not (isinstance(n_layers, int) and 1 <= n_layers <= base.n_layers):
+        raise ValueError(f"{path}: n_layers must be in [1, {base.n_layers}]")
+    seed = spec.get("seed", 0)
+    if not (isinstance(seed, int) and seed >= 0):
+        raise ValueError(f"{path}: seed must be a non-negative integer")
+    model_id = f"{spec['preset'].lower()}-l{n_layers}-seed{seed}"
+    return model_id, replace(base, n_layers=n_layers), seed
+
+
+def init_params(cfg: LlamaConfig, key: jax.Array, out_shardings=None) -> Params:
+    """Random weights from ``key``.  One jitted program that draws every
+    stacked leaf ([n_layers, ...], scan-friendly, pp-shardable) directly, so
+    the peak is the weights plus one leaf's temporaries — never a per-layer
+    copy next to the stack — and, with ``out_shardings`` (a pytree of
+    shardings matching the result), each device only ever holds its shard:
+    that is what lets a model larger than one chip initialise on a mesh.
+    Layer ``li`` draws from ``split(split(key, L + 2)[li], 10)``."""
+    hd = cfg.head_dim
+    L = cfg.n_layers
+    # with the Gemma (1 + w) convention, zeros give identity scale
+    ln_one = (jnp.zeros if cfg.norm_offset else jnp.ones)
+
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)).astype(
             cfg.dtype
         )
 
-    keys = jax.random.split(key, cfg.n_layers + 2)
-    hd = cfg.head_dim
-    # with the Gemma (1 + w) convention, zeros give identity scale
-    ln_one = (jnp.zeros if cfg.norm_offset else jnp.ones)
-    layers = []
-    for li in range(cfg.n_layers):
-        k = jax.random.split(keys[li], 10)
-        layer = {
-            "wq": dense(k[0], (cfg.dim, cfg.n_heads * hd), cfg.dim),
-            "wk": dense(k[1], (cfg.dim, cfg.n_kv_heads * hd), cfg.dim),
-            "wv": dense(k[2], (cfg.dim, cfg.n_kv_heads * hd), cfg.dim),
-            "wo": dense(k[3], (cfg.n_heads * hd, cfg.dim), cfg.n_heads * hd),
-            "w_gate": dense(k[4], (cfg.dim, cfg.ffn_dim), cfg.dim),
-            "w_up": dense(k[5], (cfg.dim, cfg.ffn_dim), cfg.dim),
-            "w_down": dense(k[6], (cfg.ffn_dim, cfg.dim), cfg.ffn_dim),
-            "ln_attn": ln_one((cfg.dim,), cfg.dtype),
-            "ln_mlp": ln_one((cfg.dim,), cfg.dtype),
+    def build(key):
+        keys = jax.random.split(key, L + 2)
+        lk = jax.vmap(lambda k: jax.random.split(k, 10))(keys[:L])  # [L, 10]
+
+        def stacked(i, shape, fan_in):
+            return jax.vmap(lambda k: dense(k, shape, fan_in))(lk[:, i])
+
+        layers = {
+            "wq": stacked(0, (cfg.dim, cfg.n_heads * hd), cfg.dim),
+            "wk": stacked(1, (cfg.dim, cfg.n_kv_heads * hd), cfg.dim),
+            "wv": stacked(2, (cfg.dim, cfg.n_kv_heads * hd), cfg.dim),
+            "wo": stacked(3, (cfg.n_heads * hd, cfg.dim), cfg.n_heads * hd),
+            "w_gate": stacked(4, (cfg.dim, cfg.ffn_dim), cfg.dim),
+            "w_up": stacked(5, (cfg.dim, cfg.ffn_dim), cfg.dim),
+            "w_down": stacked(6, (cfg.ffn_dim, cfg.dim), cfg.ffn_dim),
+            "ln_attn": ln_one((L, cfg.dim), cfg.dtype),
+            "ln_mlp": ln_one((L, cfg.dim), cfg.dtype),
         }
         if cfg.post_norms:  # Gemma-2 sandwich norms
-            layer["ln_post_attn"] = ln_one((cfg.dim,), cfg.dtype)
-            layer["ln_post_mlp"] = ln_one((cfg.dim,), cfg.dtype)
+            layers["ln_post_attn"] = ln_one((L, cfg.dim), cfg.dtype)
+            layers["ln_post_mlp"] = ln_one((L, cfg.dim), cfg.dtype)
         if cfg.attn_bias:
-            layer["bq"] = dense(k[7], (cfg.n_heads * hd,), cfg.dim)
-            layer["bk"] = dense(k[8], (cfg.n_kv_heads * hd,), cfg.dim)
-            layer["bv"] = dense(k[9], (cfg.n_kv_heads * hd,), cfg.dim)
+            layers["bq"] = stacked(7, (cfg.n_heads * hd,), cfg.dim)
+            layers["bk"] = stacked(8, (cfg.n_kv_heads * hd,), cfg.dim)
+            layers["bv"] = stacked(9, (cfg.n_kv_heads * hd,), cfg.dim)
         if cfg.qk_norm:
-            layer["q_norm"] = jnp.ones((hd,), cfg.dtype)
-            layer["k_norm"] = jnp.ones((hd,), cfg.dtype)
-        layers.append(layer)
-    # stack layers: every leaf gets a leading [n_layers] axis (scan-friendly,
-    # pp-shardable)
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
-    return {
-        "embed": dense(keys[-2], (cfg.vocab_size, cfg.dim), cfg.dim),
-        "layers": stacked,
-        "ln_out": ln_one((cfg.dim,), cfg.dtype),
-        "lm_head": dense(keys[-1], (cfg.dim, cfg.vocab_size), cfg.dim),
-    }
+            layers["q_norm"] = jnp.ones((L, hd), cfg.dtype)
+            layers["k_norm"] = jnp.ones((L, hd), cfg.dtype)
+        return {
+            "embed": dense(keys[-2], (cfg.vocab_size, cfg.dim), cfg.dim),
+            "layers": layers,
+            "ln_out": ln_one((cfg.dim,), cfg.dtype),
+            "lm_head": dense(keys[-1], (cfg.dim, cfg.vocab_size), cfg.dim),
+        }
+
+    return jax.jit(build, out_shardings=out_shardings)(key)
 
 
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float,

@@ -10,7 +10,7 @@ disagree.  Two algorithms:
   bandwidth through numpy (~8 GB/s measured on the 1-vCPU reference
   host), which is what lets commit-time stamping and read-time
   verification coexist with the coalesced data plane's throughput floor
-  (docs/tpu_perf_notes.md).  Detects every single-bit flip, torn write,
+  (docs/design.md §2).  Detects every single-bit flip, torn write,
   and recycled-region read; the accepted weakness is commutativity
   (swapped aligned words collide), which none of the failure modes in
   docs/robustness.md produce.

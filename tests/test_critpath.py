@@ -180,7 +180,7 @@ def test_stage_ledger_fold_annotate_and_snapshot():
 # ---------------------------------------------------------------------------
 
 
-def test_trace_diff_stage_taxonomy_matches_package():
+def test_trace_diff_stage_names_match_package():
     td = _load_trace_diff()
     assert tuple(td.STAGES) == tuple(critpath.STAGES)
 

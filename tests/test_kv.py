@@ -77,6 +77,41 @@ def test_page_roundtrip():
     assert float(jnp.abs(cache[:, :, :, 0]).max()) == 0.0
 
 
+def test_token_writes_land_in_their_slots():
+    """write_token_kv rewrites whole pages (so the cache keeps its layout
+    on the chip): the result must be exactly the token rows in their
+    slots, an out-of-bounds pad row dropped, everything else untouched;
+    write_tokens_kv puts several tokens into ONE page."""
+    from infinistore_tpu.kv import write_token_kv
+    from infinistore_tpu.kv.cache import write_tokens_kv
+
+    L, H, NB, T, D = 3, 2, 6, 4, 8
+    rng = np.random.default_rng(0)
+    base = rng.standard_normal((L, 2, H, NB, T, D)).astype(np.float32)
+    k = rng.standard_normal((3, H, D)).astype(np.float32)
+    v = rng.standard_normal((3, H, D)).astype(np.float32)
+    blocks, slots = [4, 1, NB], [0, 3, 2]  # row 2: a pad row, one past the pool
+    got = jax.jit(write_token_kv, static_argnums=1)(
+        jnp.asarray(base), 1, jnp.asarray(blocks, jnp.int32),
+        jnp.asarray(slots, jnp.int32), jnp.asarray(k), jnp.asarray(v))
+    want = base.copy()
+    for b in range(2):
+        want[1, 0, :, blocks[b], slots[b]] = k[b]
+        want[1, 1, :, blocks[b], slots[b]] = v[b]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+    k2 = rng.standard_normal((1, 3, H, D)).astype(np.float32)
+    v2 = rng.standard_normal((1, 3, H, D)).astype(np.float32)
+    got = jax.jit(write_tokens_kv, static_argnums=1)(
+        jnp.asarray(base), 2, jnp.asarray([[5, 5, 0]], jnp.int32),
+        jnp.asarray([[2, 3, 0]], jnp.int32), jnp.asarray(k2), jnp.asarray(v2))
+    want = base.copy()
+    for s, (blk, slot) in enumerate([(5, 2), (5, 3), (0, 0)]):
+        want[2, 0, :, blk, slot] = k2[0, s]
+        want[2, 1, :, blk, slot] = v2[0, s]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_block_allocator():
     a = BlockAllocator(4)
     ids = a.alloc(3)

@@ -166,3 +166,115 @@ def test_train_step_reduces_loss(tiny_setup):
     for _ in range(5):
         p, loss = step(p, tokens)
     assert float(loss) < float(loss0)
+
+
+# ---- seeded weights and the --model <file>.json resolver ----
+
+
+def _init_params_loop(cfg, key):
+    """The per-layer loop init_params replaced (it held every layer twice:
+    a list of per-layer dicts, then their stack), kept as the reference."""
+    def dense(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32)
+                / np.sqrt(fan_in)).astype(cfg.dtype)
+
+    keys = jax.random.split(key, cfg.n_layers + 2)
+    hd = cfg.head_dim
+    layers = []
+    for li in range(cfg.n_layers):
+        k = jax.random.split(keys[li], 10)
+        layer = {
+            "wq": dense(k[0], (cfg.dim, cfg.n_heads * hd), cfg.dim),
+            "wk": dense(k[1], (cfg.dim, cfg.n_kv_heads * hd), cfg.dim),
+            "wv": dense(k[2], (cfg.dim, cfg.n_kv_heads * hd), cfg.dim),
+            "wo": dense(k[3], (cfg.n_heads * hd, cfg.dim), cfg.n_heads * hd),
+            "w_gate": dense(k[4], (cfg.dim, cfg.ffn_dim), cfg.dim),
+            "w_up": dense(k[5], (cfg.dim, cfg.ffn_dim), cfg.dim),
+            "w_down": dense(k[6], (cfg.ffn_dim, cfg.dim), cfg.ffn_dim),
+            "ln_attn": jnp.ones((cfg.dim,), cfg.dtype),
+            "ln_mlp": jnp.ones((cfg.dim,), cfg.dtype),
+        }
+        if cfg.attn_bias:
+            layer["bq"] = dense(k[7], (cfg.n_heads * hd,), cfg.dim)
+            layer["bk"] = dense(k[8], (cfg.n_kv_heads * hd,), cfg.dim)
+            layer["bv"] = dense(k[9], (cfg.n_kv_heads * hd,), cfg.dim)
+        if cfg.qk_norm:
+            layer["q_norm"] = jnp.ones((hd,), cfg.dtype)
+            layer["k_norm"] = jnp.ones((hd,), cfg.dtype)
+        layers.append(layer)
+    return {
+        "embed": dense(keys[-2], (cfg.vocab_size, cfg.dim), cfg.dim),
+        "layers": jax.tree.map(lambda *xs: jnp.stack(xs), *layers),
+        "ln_out": jnp.ones((cfg.dim,), cfg.dtype),
+        "lm_head": dense(keys[-1], (cfg.dim, cfg.vocab_size), cfg.dim),
+    }
+
+
+def test_init_params_matches_per_layer_loop():
+    """The stacked, jitted init draws the same weights as the loop.  Under
+    jit the f32 divide and the bf16 cast fuse, so a value may land on the
+    neighbouring bf16 (8 significant bits: one part in 2^7 at most)."""
+    cfg = scaled(TINY, attn_bias=True, qk_norm=True, head_dim_override=64)
+    got = init_params(cfg, jax.random.PRNGKey(3))
+    want = _init_params_loop(cfg, jax.random.PRNGKey(3))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32),
+            rtol=2.0 ** -7, atol=0,
+        )
+
+
+def test_model_config_file_resolver(tmp_path):
+    import json
+    import os
+
+    from infinistore_tpu.models import QWEN3_8B, load_config_file
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model_id, cfg, seed = load_config_file(
+        os.path.join(repo, "configs", "qwen3_8b_l12.json"))
+    # every width as published; only the depth is cut
+    assert cfg == scaled(QWEN3_8B, n_layers=12) and seed == 0
+    assert model_id == "qwen3_8b-l12-seed0"
+    _, tiny, _ = load_config_file(os.path.join(repo, "configs", "tiny.json"))
+    assert tiny == TINY
+
+    def load(spec):
+        path = tmp_path / "m.json"
+        path.write_text(json.dumps(spec))
+        return load_config_file(str(path))
+
+    ok = {"preset": "QWEN3_8B", "reduced": {"n_layers": 2}, "seed": 7}
+    assert load(ok)[0] == "qwen3_8b-l2-seed7"
+    for bad in (
+        {**ok, "reduced": {"n_layers": 2, "dim": 1024}},   # a width cut
+        {**ok, "published": {"ffn_dim": 4096}},            # a width override
+        {**ok, "reduced": {"n_layers": 37}},               # deeper than published
+        {**ok, "preset": "MIXTRAL_8X7B"},                  # not a dense preset
+        {**ok, "preset": "init_params"},
+        {**ok, "seed": -1},
+    ):
+        with pytest.raises(ValueError):
+            load(bad)
+
+
+def test_compile_cache_dir_rule():
+    """Placed from outside when JAX_COMPILATION_CACHE_DIR is set, a fixed
+    path in the checkout otherwise, nothing while the platform is pinned
+    to the CPU (this suite)."""
+    import os
+
+    from infinistore_tpu import jaxcfg
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert jaxcfg.default_cache_dir({}) == os.path.join(repo, ".jax_cache")
+    assert jaxcfg.default_cache_dir({"JAX_PLATFORMS": "tpu"}) == \
+        os.path.join(repo, ".jax_cache")
+    assert jaxcfg.default_cache_dir(
+        {"JAX_COMPILATION_CACHE_DIR": "/some/dir"}) is None
+    assert jaxcfg.default_cache_dir({"JAX_PLATFORMS": "cpu"}) is None
+    # conftest pins cpu, so importing the package set no directory here
+    assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert jax.config.jax_compilation_cache_dir is None

@@ -719,5 +719,14 @@ def test_serve_healthz_without_store():
         # plane's alerts block rides along with zero firing)
         assert "store_circuit" not in body and "reason" not in body
         assert body.get("alerts", {}).get("firing", 0) == 0
+        # where it runs, as JAX reports it: a launcher that must stay
+        # off JAX (one process per chip) learns the device from here
+        import jax
+
+        assert body["device"] == {
+            "platform": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "count": len(jax.devices()),
+        }
     finally:
         srv.close()

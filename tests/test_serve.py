@@ -1358,3 +1358,27 @@ def test_ngram_spec_http_matches_greedy():
         assert rounds and float(rounds[0].split()[1]) >= 1
     finally:
         srv.close()
+
+
+@pytest.mark.slow
+def test_chip_smoke_dry_run():
+    """chip_smoke.py is what proves the system on the chip; off the chip,
+    its --dry-run walks the same sequence (store + two serving processes
+    + HTTP clients) with the tiny preset, and without the flag it must
+    refuse to serve from the CPU."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)  # the smoke's servers see one device
+    smoke = [sys.executable, os.path.join(repo, "chip_smoke.py")]
+    r = subprocess.run(smoke, cwd=repo, env=env, capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode != 0 and '"ok"' not in r.stdout, r.stdout
+    r = subprocess.run(smoke + ["--dry-run"], cwd=repo, env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-1000:]
+    last = json.loads(r.stdout.strip().splitlines()[-1])
+    assert last["ok"] is True and last["device"]["platform"] == "cpu"
