@@ -1356,10 +1356,7 @@ def main() -> int:
                          "trace of the whole run: every leg wrapped in "
                          "a bench.<leg> trace, with the engine spans, "
                          "store-hop spans, and the step profiler's "
-                         "device sub-track inside (replaces the old "
-                         "bare jax.profiler directory — use "
-                         "utils.profiling.device_trace for an xprof "
-                         "capture)")
+                         "device sub-track inside")
     args = ap.parse_args()
 
     t_start = time.perf_counter()

@@ -19,6 +19,7 @@ stays in Python.
 
 from __future__ import annotations
 
+import queue
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -140,21 +141,24 @@ def _shared_jit(fn, bound: Dict[str, Any], donate: tuple = ()):
     # the count is exactly the trace-cache misses — the wrap-jit half of
     # istpu_engine_retraces_total{fn}); functools.wraps keeps the
     # signature inspectable for donate_argnames
+    def build():
+        bound_fn = partial(_stepprof.traced(fn), **bound)
+        # a partial has no name and jit would call the program
+        # ``jit__unknown``: name it after the function it binds
+        bound_fn.__name__ = bound_fn.__qualname__ = getattr(
+            fn, "__name__", "step")
+        return jax.jit(
+            bound_fn, **({"donate_argnames": donate} if donate else {})
+        )
+
     try:
         key = (fn, tuple(sorted(bound.items())), donate)
         hash(key)
     except TypeError:  # unhashable binding (exotic custom fn/mesh): private jit
-        return jax.jit(
-            partial(_stepprof.traced(fn), **bound),
-            **({"donate_argnames": donate} if donate else {}),
-        )
+        return build()
     got = _JIT_CACHE.get(key)
     if got is None:
-        got = jax.jit(
-            partial(_stepprof.traced(fn), **bound),
-            **({"donate_argnames": donate} if donate else {}),
-        )
-        _JIT_CACHE[key] = got
+        got = _JIT_CACHE[key] = build()
     return got
 
 
@@ -173,28 +177,64 @@ def _shared_partial(fn, bound: Dict[str, Any]):
 
 
 # the chunked-prefill KV append is engine-independent: one compiled copy
-_KV_APPEND = jax.jit(
-    lambda buf, kv, off: jax.lax.dynamic_update_slice(
-        buf, kv, (0, 0, 0, off, 0, 0)
-    ),
-    donate_argnums=(0,),
-)
+def kv_append(buf, kv, off):
+    return jax.lax.dynamic_update_slice(buf, kv, (0, 0, 0, off, 0, 0))
+
+
+_KV_APPEND = jax.jit(kv_append, donate_argnums=(0,))
+
 
 # Tiny compiled helpers for the per-call host glue.  On TPU every eager op
 # is its own dispatch, so the serving hot paths (decode chunks, verify
 # rounds, prefill epilogues) stay dispatch-only: one compiled program per
 # step plus these stable-identity helpers.  Each specializes per input
-# arity/shape; all are trivial programs.
-_SPLIT2 = jax.jit(lambda k: tuple(jax.random.split(k)))
-_STACK_ROWS = jax.jit(lambda *xs: jnp.stack(xs))        # B x [V] -> [B, V]
-_UNSTACK_ROWS = jax.jit(lambda x: tuple(x))             # [B, V] -> B x [V]
-_ROW0 = jax.jit(lambda x: x[0])                         # [1, S, V] -> [S, V]
-_LAST_ROW = jax.jit(lambda l, i: l[0, i])               # dynamic row pick
-_ARGMAX_I32 = jax.jit(
-    lambda l: jnp.argmax(l, axis=-1).astype(jnp.int32)
-)
-_Q_COL0 = jax.jit(lambda p: p[:, 0, :])                 # [k, 1, V] -> [k, V]
-_SPLIT3 = jax.jit(lambda k: tuple(jax.random.split(k, 3)))
+# arity/shape; all are trivial programs.  Named functions, not lambdas: a
+# profiler trace shows ``jit_<name>`` and ``PjitFunction(<name>)``.
+def split2(k):
+    return tuple(jax.random.split(k))
+
+
+def stack_rows(*xs):                    # B x [V] -> [B, V]
+    return jnp.stack(xs)
+
+
+def unstack_rows(x):                    # [B, V] -> B x [V]
+    return tuple(x)
+
+
+def row0(x):                            # [1, S, V] -> [S, V]
+    return x[0]
+
+
+def last_row(l, i):                     # dynamic row pick
+    return l[0, i]
+
+
+def argmax_i32(l):
+    return jnp.argmax(l, axis=-1).astype(jnp.int32)
+
+
+def q_col0(p):                          # [k, 1, V] -> [k, V]
+    return p[:, 0, :]
+
+
+def split3(k):
+    return tuple(jax.random.split(k, 3))
+
+
+def pick_last(l, idx):     # [B(+pad), S, V] + idx [B] -> B x [V] last rows
+    return tuple(l[jnp.arange(idx.shape[0]), idx])
+
+
+_SPLIT2 = jax.jit(split2)
+_STACK_ROWS = jax.jit(stack_rows)
+_UNSTACK_ROWS = jax.jit(unstack_rows)
+_ROW0 = jax.jit(row0)
+_LAST_ROW = jax.jit(last_row)
+_ARGMAX_I32 = jax.jit(argmax_i32)
+_Q_COL0 = jax.jit(q_col0)
+_SPLIT3 = jax.jit(split3)
+_PICK_LAST = jax.jit(pick_last)
 
 
 @partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
@@ -241,12 +281,6 @@ def _write_group_pages(cache, block_ids, kv, sel, block_tokens):
     return write_pages(cache, block_ids, pages[:, :, :, sel])
 
 
-# per-row last-position logits pick: [B(+pad), S, V] + idx [B] -> B x [V]
-_PICK_LAST = jax.jit(
-    lambda l, idx: tuple(l[jnp.arange(idx.shape[0]), idx])
-)
-
-
 class _StoreStreamer:
     """One background worker that pushes gathered KV pages to the store
     WHILE the next prefill chunk computes on device — the TPU shape of the
@@ -277,7 +311,6 @@ class _StoreStreamer:
 
     def __init__(self, transfer: KVTransferEngine, maxsize: int = 2,
                  durability: str = "strict"):
-        import queue
         import threading
 
         self._transfer = transfer
@@ -329,8 +362,13 @@ class _StoreStreamer:
         acct = _usage.current_account()
         with self._cond:
             self._pending[tid] = self._pending.get(tid, 0) + 1
-        self._q.put((self._transfer.push_begin(pages, chunk_keys_),
-                     chunk_keys_, tid, acct))
+        item = (self._transfer.push_begin(pages, chunk_keys_),
+                chunk_keys_, tid, acct)
+        try:
+            self._q.put_nowait(item)
+        except queue.Full:     # two chunks already wait: this is a wait too
+            with _stepprof.phase("kv.push_wait"):
+                self._q.put(item)
 
     def _record_marker_err(self, tid, err: BaseException) -> None:
         if tid is None or err is None:
@@ -487,6 +525,11 @@ class SequenceState:
     local_chunks: int = 0
     store_chunks: int = 0
     store_load_s: float = 0.0
+    # of ``store_load_s``, the lookup; and the host seconds and the count
+    # of this sequence's own prefill launches (the ledger's ``ttft`` block)
+    lookup_s: float = 0.0
+    launch_s: float = 0.0
+    chunks: int = 0
 
 
 @dataclass
@@ -516,6 +559,9 @@ class PartialPrefill:
     local_chunks: int = 0
     store_chunks: int = 0
     store_load_s: float = 0.0
+    lookup_s: float = 0.0
+    launch_s: float = 0.0
+    chunks: int = 0
 
 
 class InferenceEngine:
@@ -871,16 +917,16 @@ class InferenceEngine:
         max_reuse = (S_total - 1) // T
         local_ids = self.pages.match_prefix(keys[:max_reuse])  # pins hits
         reused = len(local_ids)
-        store_load_s = 0.0  # wall seconds spent on store hops (ledger)
+        lookup_s = load_s = 0.0  # wall seconds of the store hops (ledger)
         if self.transfer is not None and keys and reused < max_reuse:
             # breaker-guarded: a dead/hung store (or an open circuit)
             # reports 0 — a prefix-cache miss, never a failed request
-            t_store = time.perf_counter()
-            reused = max(
-                reused,
-                min(self.transfer.guarded_lookup_prefix(keys), max_reuse),
-            )
-            store_load_s += time.perf_counter() - t_store
+            with _stepprof.phase("kv.lookup") as ph:
+                reused = max(
+                    reused,
+                    min(self.transfer.guarded_lookup_prefix(keys), max_reuse),
+                )
+            lookup_s = ph.s
         P = reused * T
 
         # pages for the rest of the sequence (incl. a partial tail page)
@@ -900,13 +946,13 @@ class InferenceEngine:
             # #4) and a transport failure mid-load leave the cache
             # untouched; fall back to the locally-resident prefix and
             # recompute the rest instead of failing the request
-            t_store = time.perf_counter()
-            self.cache, ok = self.transfer.guarded_load(
-                self.cache,
-                block_ids[len(local_ids):reused],
-                keys[len(local_ids):reused],
-            )
-            store_load_s += time.perf_counter() - t_store
+            with _stepprof.phase("kv.load") as ph:
+                self.cache, ok = self.transfer.guarded_load(
+                    self.cache,
+                    block_ids[len(local_ids):reused],
+                    keys[len(local_ids):reused],
+                )
+            load_s = ph.s
             if not ok:
                 reused = len(local_ids)
                 P = reused * T
@@ -972,12 +1018,24 @@ class InferenceEngine:
             done=reused, n_complete=S_total // T, padded=padded, C=C,
             single=single, buf=buf, plen=plen, S=S, adapter_id=adapter_id,
             local_chunks=local_chunks, store_chunks=reused - local_chunks,
-            store_load_s=store_load_s,
+            store_load_s=lookup_s + load_s, lookup_s=lookup_s,
         )
 
     def prefill_step(self, pp: "PartialPrefill") -> Optional[SequenceState]:
         """One prefill chunk forward (+ cache scatter + store streaming).
-        Returns the finished SequenceState on the last chunk, else None."""
+        Returns the finished SequenceState on the last chunk, else None.
+        The phase times a LAUNCH: the forward and the scatter are enqueued,
+        not finished, when it ends (strict durability's last-chunk flush,
+        its own phase inside, is the one wait)."""
+        with _stepprof.phase("prefill.launch") as ph:
+            state = self._prefill_chunk(pp)
+        pp.launch_s += ph.s
+        pp.chunks += 1
+        if state is not None:
+            state.launch_s, state.chunks = pp.launch_s, pp.chunks
+        return state
+
+    def _prefill_chunk(self, pp: "PartialPrefill") -> Optional[SequenceState]:
         T = self.pc.block_tokens
         off, C = pp.off, pp.C
         chunk = pp.padded[off : off + C]
@@ -1013,10 +1071,14 @@ class InferenceEngine:
         if self.transfer is not None:
             lo, hi = max(prev_done, pp.reused), min(pp.done, pp.n_complete)
             if hi > lo:
-                self._streamer.submit(
-                    self.transfer.gather_pages(self.cache, pp.block_ids[lo:hi]),
-                    pp.keys[lo:hi],
-                )
+                # gather + push_begin + the bounded queue's put (where it
+                # blocks, two chunks already waiting, it is kv.push_wait)
+                with _stepprof.phase("kv.push_submit"):
+                    self._streamer.submit(
+                        self.transfer.gather_pages(
+                            self.cache, pp.block_ids[lo:hi]),
+                        pp.keys[lo:hi],
+                    )
         pp.off = off + C
         if pp.off < len(pp.padded):
             # another chunk still attends to this KV: grow the bucketed
@@ -1039,7 +1101,8 @@ class InferenceEngine:
         # reference's prefill-node contract, design.rst); relaxed returns
         # now — pushes drain behind decode, store_flush() is the barrier
         if self.transfer is not None and self.store_durability == "strict":
-            self._streamer.flush()
+            with _stepprof.phase("kv.push_wait"):
+                self._streamer.flush()
 
         # name this sequence's complete-chunk pages so later prefills can
         # share them in place (no-op for keys already resident)
@@ -1056,7 +1119,7 @@ class InferenceEngine:
             last_logits=_LAST_ROW(pp.logits, (pp.S - 1) - pp.off_last),
             adapter_id=pp.adapter_id,
             local_chunks=pp.local_chunks, store_chunks=pp.store_chunks,
-            store_load_s=pp.store_load_s,
+            store_load_s=pp.store_load_s, lookup_s=pp.lookup_s,
         )
         self._next_id += 1
         self.seqs[state.seq_id] = state
@@ -1218,9 +1281,12 @@ class InferenceEngine:
                         created.append(st)
                         states.append(st)
                 else:
-                    states = self._prefill_group(
-                        group, bucket, [aids[i] for i in idxs]
-                    )
+                    with _stepprof.phase("prefill.launch") as ph:
+                        states = self._prefill_group(
+                            group, bucket, [aids[i] for i in idxs]
+                        )
+                    for st in states:   # each rode the one group forward
+                        st.launch_s, st.chunks = ph.s, 1
                     created.extend(states)
                 for i, st in zip(idxs, states):
                     out[i] = st
@@ -1563,6 +1629,11 @@ class InferenceEngine:
         state (the vLLM per-request-seed contract)."""
         B = len(states)
         assert B >= 1
+        # flat phases, left to right: arguments and the jitted call
+        # (decode.launch), the blocking read-back (decode.wait: the device
+        # works, the host waits), the Python after it (decode.unpack) —
+        # which stays open for the caller to end
+        _stepprof.enter("decode.launch")
         samples = (
             [sample] * B if isinstance(sample, str) else [str(s) for s in sample]
         )
@@ -1749,7 +1820,11 @@ class InferenceEngine:
                 pen,
             )
             # one compiled scan dispatch advanced the whole batch a chunk
-            _stepprof.note_dispatch("decode")
+            _stepprof.note_decode(
+                steps=chunk, rows=B, padded_rows=Bp,
+                width_pages=block_table.shape[1], block_tokens=T,
+                live_tokens=int(pos[:B].sum()),
+            )
             _stepprof.note_tokens(chunk * B)
             if penalized:
                 # thread the device-side counts into the next chunk
@@ -1757,6 +1832,13 @@ class InferenceEngine:
                 pen = (counts_d,) + pen[1:]
             if logprobs:
                 toks, chosen, top_id, top_lp, logits, self.cache = res
+            else:
+                toks, logits, self.cache = res
+            _stepprof.enter("decode.wait")
+            _stepprof.note_sync("decode_tokens")
+            host_toks = np.asarray(toks)  # [chunk, Bp]; one sync/chunk
+            _stepprof.enter("decode.unpack")
+            if logprobs:
                 h_ch = np.asarray(chosen)   # [chunk, B]
                 h_ti = np.asarray(top_id)   # [chunk, B, k]
                 h_tl = np.asarray(top_lp)   # [chunk, B, k]
@@ -1769,14 +1851,12 @@ class InferenceEngine:
                           for j in range(logprobs)])
                         for s in range(chunk)
                     )
-            else:
-                toks, logits, self.cache = res
-            _stepprof.note_sync("decode_tokens")
-            host_toks = np.asarray(toks)  # [chunk, Bp]; one sync/chunk
             for b in range(B):
                 out[b].extend(int(t) for t in host_toks[:, b])
             pos += chunk
             remaining -= chunk
+            if remaining > 0:
+                _stepprof.enter("decode.launch")
         rows = _UNSTACK_ROWS(logits)  # one dispatch, not B eager slices
         for b, st in enumerate(states):
             st.tokens.extend(out[b])

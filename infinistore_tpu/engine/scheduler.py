@@ -126,6 +126,18 @@ class Request:
     t_submit: float = 0.0
     t_admit: float = 0.0
     t_first: float = 0.0
+    # the TTFT waterfall's inner stamps (ledger ``ttft`` block): prefill
+    # done = decode-ready; the host seconds of the phases that ran on THIS
+    # request's behalf before its first token (store lookup, store load,
+    # its own chunk launches); its prefill forwards and the scheduler
+    # steps from admission to first token
+    t_prefill_done: float = 0.0
+    own_lookup_s: float = 0.0
+    own_load_s: float = 0.0
+    own_prefill_s: float = 0.0
+    prefill_chunks: int = 0
+    steps_to_first: int = 0
+    _admit_step: int = 0
     # retirement stamp + the ledger's waterfall inputs: accumulated
     # on_token delivery time (slow consumers show up as "stream", not
     # "decode") and per-chunk token-delivery stamps (t_rel, cum_tokens)
@@ -250,6 +262,7 @@ class Scheduler:
             fn=lambda: len(self.pending),
         )
         self.max_batch = max_batch
+        self._steps = 0  # scheduler steps run (Request.steps_to_first)
         self.pending: List[Request] = []
         self.active: List[Request] = []
         # chunked-prefill admission: up to ``prefill_concurrency`` newcomers
@@ -543,6 +556,7 @@ class Scheduler:
                 first_admission = not req.t_admit
                 if first_admission:
                     req.t_admit = time.perf_counter()
+                    req._admit_step = self._steps
                 try:
                     # bound to the REQUEST's own trace: the admission
                     # store hops (kv.lookup_prefix, kv.load_pages) are
@@ -609,13 +623,27 @@ class Scheduler:
                 self._admission_hold = True  # retry after a retire frees pages
                 return
             for req, st in zip(admit, states):
-                req.state = st
                 if not req.t_admit:
                     # stamped at wave START so the wave's forward counts
                     # as prefill (t_first - t_admit), not queue-wait
                     req.t_admit = t_wave
-                self.active.append(req)
+                    req._admit_step = self._steps
+                self._prefilled(req, st)
             return
+
+    def _prefilled(self, req: Request, st: SequenceState) -> None:
+        """``req`` is decode-ready.  Until its first token, fold what the
+        engine timed on its behalf into the TTFT waterfall's stamps (a
+        request shed and prefilled again before its first token adds up;
+        after it, a re-prefill is not TTFT's)."""
+        req.state = st
+        self.active.append(req)
+        if not req.t_first:
+            req.t_prefill_done = time.perf_counter()
+            req.own_lookup_s += st.lookup_s
+            req.own_load_s += st.store_load_s - st.lookup_s
+            req.own_prefill_s += st.launch_s
+            req.prefill_chunks += st.chunks
 
     def _retire(self) -> List[Request]:
         done_now: List[Request] = []
@@ -624,6 +652,7 @@ class Scheduler:
         for req in self.active:
             if not req.t_first and req.output:
                 req.t_first = now
+                req.steps_to_first = self._steps - req._admit_step + 1
         for req in self.active:
             out = req.output
             hit_eos = bool(req.eos_ids) and not set(req.eos_ids).isdisjoint(out)
@@ -756,15 +785,13 @@ class Scheduler:
         return True
 
     def _spec_dispatch(self, reqs: List[Request], chunk: int) -> bool:
-        t0 = time.perf_counter()
-        with tracing.span("sched.decode_chunk", batch=len(reqs),
-                          chunk=chunk, spec=self.spec_kind):
+        with _stepprof.phase("spec.round") as ph:
             if self.spec_kind == "ngram":
                 ok = self._ngram_step_batch(reqs, chunk)
             else:
                 ok = self._spec_step_batch(reqs, chunk)
         if ok:
-            self._h_decode_step.observe(time.perf_counter() - t0)
+            self._h_decode_step.observe(ph.s)
         return ok
 
     def _spec_step_batch(self, reqs: List[Request], chunk: int) -> bool:
@@ -868,6 +895,8 @@ class Scheduler:
             return self._step_inner()
         with prof.step(self) as rec:
             retired = self._step_inner()
+        if prof.phase == "probe":   # a sampled step ends in the probe
+            prof.enter("retire_stream")
         self._attribute_step(rec, retired)
         return retired
 
@@ -899,8 +928,14 @@ class Scheduler:
                     )
 
     def _step_inner(self) -> List[Request]:
+        # flat phases (stepprof.enter): ``admit`` and ``sched`` are this
+        # file's own bookkeeping, the engine and the transfer enter theirs
+        # (kv.*, prefill.launch, decode.*), ``retire_stream`` ends the step
+        self._steps += 1
+        _stepprof.enter("admit")
         if not (self._admission_hold and self.active):
             self._admit()
+        _stepprof.enter("sched")
         cancelled_prefill: List[Request] = []
         still: List[Tuple[Request, PartialPrefill]] = []
         # degraded-mode chunked-prefill throttle: while a burn watchdog
@@ -923,14 +958,12 @@ class Scheduler:
                 still.append((req, pp))  # over budget: hold this step
                 continue
             with tracing.bind(req.trace_id), \
-                    _usage.bind_account(self._lane_label(req)), \
-                    tracing.span("sched.prefill_step", req=req.req_id):
+                    _usage.bind_account(self._lane_label(req)):
                 st = self.engine.prefill_step(pp)  # ONE chunk per step each
             if pf_budget is not None:
                 pf_budget -= chunk_cost
             if st is not None:
-                req.state = st
-                self.active.append(req)
+                self._prefilled(req, st)
             else:
                 still.append((req, pp))
         self._prefilling = still
@@ -938,6 +971,7 @@ class Scheduler:
             return cancelled_prefill
         if any(r.cancelled for r in self.active):
             # retire cancellations before burning a decode chunk on them
+            _stepprof.enter("retire_stream")
             return cancelled_prefill + self._retire()
         # chunk lengths are powers of two capped at decode_chunk, so the jit
         # cache holds at most log2(decode_chunk)+1 scan lengths per batch
@@ -969,6 +1003,7 @@ class Scheduler:
             # speculation pays when the chip is latency-bound: batch=1 by
             # default; spec_batch > 1 runs a small batch in lockstep
             # through the batched fused rounds (decode_batch)
+            _stepprof.enter("retire_stream")
             return cancelled_prefill + self._retire()
         self._rng, sub = _SPLIT2(self._rng)
         # any row asking for logprobs switches the batch to the collecting
@@ -976,7 +1011,9 @@ class Scheduler:
         # any row with penalties switches to the count-carrying program
         want_lp = any(r.logprobs for r in self.active)
         want_pen = any(self._penalized(r) for r in self.active)
-        t_decode = time.perf_counter()
+        # two phase switches time the dispatch for the histogram, with or
+        # without a profiler driving the step
+        t_decode = _stepprof.enter("decode.launch")
         try:
             outs = self.engine.decode_batch(
                 [r.state for r in self.active], chunk,
@@ -1018,9 +1055,9 @@ class Scheduler:
             self._enqueue(victim, front=True)
             self._admission_hold = True
             return cancelled_prefill
-        self._h_decode_step.observe(time.perf_counter() - t_decode)
-        tracing.add_stage("sched.decode_chunk", time.perf_counter() - t_decode,
-                          batch=len(self.active), chunk=chunk)
+        # ends the engine's decode.unpack
+        self._h_decode_step.observe(
+            _stepprof.enter("retire_stream") - t_decode)
         if want_lp:
             outs, lps = outs
             for req, lp in zip(self.active, lps):

@@ -37,10 +37,21 @@ structured record per scheduler step:
   rounds/proposed/accepted counters next to the dispatch counts, so
   a slowdown at high acceptance reads as tokens-per-dispatch, not a
   mystery;
-* **store-hop stages** — when a step moved pages, the transfer's
-  ``last_push_stages`` / ``last_load_stages`` breakdown rides along
-  (best-effort: pushes commit on the streamer thread, so a stage dict
-  may land one step late).
+* **store-hop stages** — when a step moved pages, the delta of the
+  transfer's ``push_totals`` / ``load_totals`` over the step rides along
+  (pushes commit on the streamer thread, so a push may count one step
+  late; the lifetime totals in ``summary()['store']`` lose nothing);
+* **flat engine-thread phases** — ``enter(name)`` ends the phase before
+  and begins the next, so the phases PARTITION the driving thread's time.
+  Each is at once a ``jax.profiler.TraceAnnotation("istpu.<name>")`` (the
+  host plane of the profiler's own trace: same clock as the device's
+  operations), seconds on the step record and in ``summary()['phase_s']``,
+  and a span in the bound istpu trace.  Never nested: the benchmark's
+  trace reduction names a device idle gap by the host event covering most
+  of it, so an enclosing annotation would name every gap;
+* **counts at the dispatch** — ``note_decode`` records what the program
+  knows when it launches the decode scan: steps, live rows, the context
+  tokens they hold and the table tokens the attention reads.
 
 Records live in a bounded ring (``ISTPU_STEPPROF_RING``, default 256),
 exported at the serving front-end's ``GET /debug/engine`` (``?limit=``),
@@ -51,8 +62,8 @@ request's own ``http.request`` trace, so one stitched Perfetto file runs
 HTTP handler → scheduler → engine.step → kv store hop → device dispatch
 under one trace id.
 
-Hooks (``note_dispatch`` / ``note_tokens`` / ``count_trace``) follow the
-tracing module's contract: with no active step record they cost one
+Hooks (``note_dispatch`` / ``note_tokens`` / ``count_trace`` / ``enter``)
+follow the tracing module's contract: with no active step record they cost one
 contextvar read and nothing else.
 """
 
@@ -60,6 +71,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import logging
 import os
 import threading
 import time
@@ -79,6 +91,13 @@ STEPPROF_RING_DEFAULT = 256    # records kept for /debug/engine
 # grow its ledger record without bound)
 MAX_STEP_IDS = 64
 
+# what ``note_decode`` sums, per step record and over the lifetime
+DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
+                 "table_token_steps")
+
+# the key that counts operations in the transfer's running totals
+_STORE_COUNT = {"push": "pushes", "load": "loads"}
+
 
 def _env_int(name: str, default: int) -> int:
     try:
@@ -92,6 +111,11 @@ def _env_int(name: str, default: int) -> int:
 _ACTIVE: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
     "istpu_stepprof", default=None
 )
+
+# the profiler driving the current step: the module-level ``enter`` /
+# ``phase`` reach it from engine and transfer code without plumbing
+_PROFILER: contextvars.ContextVar[Optional["StepProfiler"]] = \
+    contextvars.ContextVar("istpu_stepprof_owner", default=None)
 
 _TRACE_LOCK = threading.Lock()
 _TRACES: Dict[str, int] = {}     # fn name -> traces (first compile included)
@@ -120,7 +144,7 @@ def traced(fn, name: Optional[str] = None):
     """Wrap ``fn`` so every trace of the (later-jitted) function counts —
     the wrap-``jit`` fallback of the retrace tracker.  ``functools.wraps``
     keeps the signature inspectable, so ``donate_argnames`` on the
-    enclosing ``jax.jit`` still resolves."""
+    enclosing ``jax.jit`` still resolves; the wrapper is NAMED ``name``."""
     import functools
 
     label = name or getattr(fn, "__name__", repr(fn))
@@ -130,6 +154,9 @@ def traced(fn, name: Optional[str] = None):
         count_trace(label)
         return fn(*args, **kwargs)
 
+    # jit names its program after the function: ``jit_<label>`` is what a
+    # device trace shows, and what benchmarks/trace/programs.json keys on
+    counted.__name__ = counted.__qualname__ = label
     return counted
 
 
@@ -202,6 +229,68 @@ def note_sync(kind: str, n: int = 1) -> None:
     if rec is not None:
         s = rec["syncs"]
         s[kind] = s.get(kind, 0) + n
+
+
+def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
+                block_tokens: int, live_tokens: int) -> None:
+    """Count ONE decode-scan dispatch with what the engine knows at the
+    call: ``steps`` scan steps over ``rows`` live rows in a batch bucket
+    of ``padded_rows``, a block table ``width_pages`` wide, and
+    ``live_tokens`` = the rows' context lengths summed at the dispatch's
+    start.  ``table_token_steps`` is what the XLA attention reads
+    whatever ``seq_lens`` says (every padded row, the whole width).
+    Summed under ``rec["decode"]``; the benchmark's ``engine.decode_*``
+    readers take the window's gain of each."""
+    rec = _ACTIVE.get()
+    if rec is None:
+        return
+    d = rec["dispatches"]
+    d["decode"] = d.get("decode", 0) + 1
+    b = rec.setdefault("decode", dict.fromkeys(DECODE_COUNTS, 0))
+    b["steps"] += steps
+    b["row_steps"] += rows * steps
+    b["live_token_steps"] += live_tokens * steps
+    b["table_token_steps"] += padded_rows * width_pages * block_tokens * steps
+
+
+def enter(name: Optional[str]) -> float:
+    """``StepProfiler.enter`` on the profiler driving this thread's step,
+    and the switch's clock stamp: two of them time a site once.  A plain
+    ``perf_counter`` read when there is no profiler."""
+    prof = _PROFILER.get()
+    return prof.enter(name) if prof is not None else time.perf_counter()
+
+
+class phase:
+    """``with phase("kv.load") as p:`` — enter the phase, and on the way
+    out re-enter the one that was open (flat in every view: the outer
+    phase is closed and reopened, never nested).  ``p.s`` is the seconds
+    between the two switches, on the profiler's clock — the ONE timing of
+    the site; with no profiler on this thread it is a plain
+    ``perf_counter`` pair."""
+
+    __slots__ = ("name", "prof", "prev", "t0", "s")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.s = 0.0
+
+    def __enter__(self) -> "phase":
+        prof = _PROFILER.get()
+        if prof is not None and prof.enabled:
+            self.prof, self.prev = prof, prof.phase
+            self.t0 = prof.enter(self.name)
+        else:
+            self.prof = None
+            self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self.prof is not None:
+            self.s = self.prof.enter(self.prev) - self.t0
+        else:
+            self.s = time.perf_counter() - self.t0
+        return False
 
 
 def current_step() -> Optional[int]:
@@ -303,6 +392,20 @@ class StepProfiler:
         # number that explains a sub-1x spec speedup at high acceptance
         self._spec_totals = {"rounds": 0, "proposed": 0, "accepted": 0}
         self.tokens = 0
+        # lifetime sums of the decode dispatches' counts (note_decode)
+        self._decode_totals = dict.fromkeys(DECODE_COUNTS, 0)
+        # flat phases of the driving thread (see ``enter``)
+        self.phase: Optional[str] = None
+        self._phase_t0 = 0.0
+        self._phase_ann = None
+        self._phase_s: Dict[str, float] = {}
+        # the clock time with SOME phase open, from two reads per stretch:
+        # what the sum of phase_s must equal if no time falls between phases
+        self._phase_wall_s = 0.0
+        self._phase_wall_t0 = 0.0
+        # the transfer of the last scheduler stepped: summary()'s store
+        # totals are read from it
+        self._transfer = None
         self._wall_s = 0.0
         self._sampled_wall_s = 0.0
         self._stall_s = 0.0
@@ -381,14 +484,54 @@ class StepProfiler:
         return (int(spec.rounds), int(spec.proposed), int(spec.accepted))
 
     @staticmethod
-    def _stage_ids(scheduler) -> tuple:
-        transfer = getattr(getattr(scheduler, "engine", None), "transfer",
-                           None) if scheduler else None
-        if transfer is None:
-            return None, None, None
-        return (transfer,
-                id(getattr(transfer, "last_push_stages", None)),
-                id(getattr(transfer, "last_load_stages", None)))
+    def _store_totals(transfer) -> Dict[str, dict]:
+        """The transfer's running totals (each dict is replaced whole on
+        update, so holding one IS a consistent snapshot)."""
+        return {k: t for k in ("push", "load")
+                if (t := getattr(transfer, k + "_totals", None))}
+
+    # -- flat phases --
+
+    def _account(self, now: float, rec: Optional[dict]) -> None:
+        """Charge the open phase up to ``now`` (caller holds the lock)."""
+        name = self.phase
+        if name is not None:
+            dt = now - self._phase_t0
+            self._phase_s[name] = self._phase_s.get(name, 0.0) + dt
+            if rec is not None:
+                ph = rec["phases"]
+                ph[name] = ph.get(name, 0.0) + dt
+        self._phase_t0 = now
+
+    def enter(self, name: Optional[str]) -> float:
+        """End the open phase and begin ``name`` (``None``: begin none).
+        Called by ONE thread, the one that drives the steps; phases never
+        nest and never overlap, so from the first call on they partition
+        that thread's time.  Returns the switch's clock stamp."""
+        now = self._clock()
+        if not self.enabled:
+            return now
+        prev, t0 = self.phase, self._phase_t0
+        with self._lock:
+            self._account(now, _ACTIVE.get())
+            self.phase = name
+            if prev is None:
+                self._phase_wall_t0 = now
+            elif name is None:
+                self._phase_wall_s += now - self._phase_wall_t0
+        ann = self._phase_ann
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if name is None:
+            self._phase_ann = None
+        else:
+            ann = self._phase_ann = _annotation("istpu." + name)
+            ann.__enter__()
+        if prev is not None:
+            tr = tracing.TRACER.current()   # the step's, or a bound request's
+            if tr is not None:
+                tr.add("istpu." + prev, t0, now)
+        return now
 
     @contextlib.contextmanager
     def step(self, scheduler=None, kind_hint: Optional[str] = None):
@@ -412,6 +555,7 @@ class StepProfiler:
             "tokens": 0,
             "syncs": {},
             "retraces": {},
+            "phases": {},
             "sampled": sampled,
         }
         if scheduler is not None:
@@ -421,28 +565,42 @@ class StepProfiler:
                 "pending": len(getattr(scheduler, "pending", ())),
             }
         spec0 = self._spec_counts(scheduler)
-        transfer, push0, load0 = self._stage_ids(scheduler)
+        transfer = getattr(getattr(scheduler, "engine", None), "transfer",
+                           None)
+        if transfer is not None:
+            self._transfer = transfer
+        store0 = self._store_totals(transfer)
         compiles0, compile_s0 = _COMPILES, _COMPILE_S
         token = _ACTIVE.set(rec)
+        owner = _PROFILER.set(self)
+        outer = self.phase
         t0 = self._clock()
+        with self._lock:
+            self._account(t0, None)    # what came before is not this step's
         try:
             yield rec
         finally:
             t1 = self._clock()
+            with self._lock:
+                self._account(t1, rec)
+            _PROFILER.reset(owner)
             _ACTIVE.reset(token)
             self._finish(rec, scheduler, kind_hint, t0, t1, sampled,
-                         spec0, transfer, push0, load0,
-                         compiles0, compile_s0)
+                         spec0, transfer, store0, compiles0, compile_s0)
+            if outer is None:
+                # nobody partitions this thread's time between steps (a
+                # library caller, a bench leg): leave no phase running
+                self.enter(None)
 
     def _finish(self, rec, scheduler, kind_hint, t0, t1, sampled,
-                spec0, transfer, push0, load0,
-                compiles0, compile_s0) -> None:
+                spec0, transfer, store0, compiles0, compile_s0) -> None:
         dur = max(0.0, t1 - t0)
         rec["dur_s"] = round(dur, 6)
         rec["kind"] = kind_hint or self._classify(rec["dispatches"])
         # sampled probe: time the device drain, then read the watermarks
         # (reading them BEFORE the block would race in-flight dispatches)
         if sampled:
+            tb = self.enter("probe")
             stall = 0.0
             sentinel = self._sentinel
             target = None
@@ -452,7 +610,6 @@ class StepProfiler:
                 target = getattr(getattr(scheduler, "engine", None),
                                  "cache", None)
             if target is not None:
-                tb = self._clock()
                 try:
                     self._block(target)
                 except Exception:  # noqa: BLE001 — probe must not fault steps
@@ -480,19 +637,17 @@ class StepProfiler:
             with self._lock:
                 for key in self._spec_totals:
                     self._spec_totals[key] += rec["spec"][key]
-        # store-hop stages: attach the transfer's per-stage breakdown
-        # when it changed under this step (push commits land on the
-        # streamer thread, so attribution is best-effort by design)
-        if transfer is not None:
-            store: Dict[str, Any] = {}
-            push = getattr(transfer, "last_push_stages", None)
-            if push and id(push) != push0:
-                store["push"] = dict(push)
-            load = getattr(transfer, "last_load_stages", None)
-            if load and id(load) != load0:
-                store["load"] = dict(load)
-            if store:
-                rec["store"] = store
+        # store-hop stages: what the transfer's running totals gained
+        # under this step (a push commits on the streamer thread, so it
+        # may count towards the step after the one that submitted it)
+        store = {}
+        for k, tot in self._store_totals(transfer).items():
+            was = store0.get(k, {})
+            if tot[_STORE_COUNT[k]] != was.get(_STORE_COUNT[k], 0):
+                store[k] = {f: round(v - was.get(f, 0), 6)
+                            for f, v in tot.items()}
+        if store:
+            rec["store"] = store
         if _COMPILES != compiles0:
             rec["compiles"] = _COMPILES - compiles0
             rec["compile_s"] = round(_COMPILE_S - compile_s0, 6)
@@ -506,6 +661,8 @@ class StepProfiler:
             for k, n in rec["syncs"].items():
                 self._sync_totals[k] = self._sync_totals.get(k, 0) + n
             self.tokens += rec["tokens"]
+            for k, n in rec.get("decode", {}).items():
+                self._decode_totals[k] += n
             self._wall_s += dur
             if sampled:
                 self._sampled += 1
@@ -591,6 +748,16 @@ class StepProfiler:
             syncs = dict(self._sync_totals)
             spec_tot = dict(self._spec_totals)
             tokens = self.tokens
+            decode = dict(self._decode_totals)
+            # the open phase counts up to this moment: a scrape in the
+            # middle of a long decode.wait loses nothing
+            phase_s = dict(self._phase_s)
+            phase_wall = self._phase_wall_s
+            if self.phase is not None:
+                now = self._clock()
+                phase_s[self.phase] = phase_s.get(self.phase, 0.0) + max(
+                    0.0, now - self._phase_t0)
+                phase_wall += now - self._phase_wall_t0
             wall = self._wall_s
             s_wall, stall, sampled = (self._sampled_wall_s, self._stall_s,
                                       self._sampled)
@@ -628,6 +795,12 @@ class StepProfiler:
             "compiles": compiles,
             "compile_s": round(compile_s, 4),
             "mem": mem,
+            "phase_s": {k: round(v, 6) for k, v in phase_s.items()},
+            "phase_wall_s": round(phase_wall, 6),
+            # the decode dispatches' counts, summed (note_decode)
+            "decode": decode,
+            "store": {k: dict(t) for k, t in
+                      self._store_totals(self._transfer).items()},
         }
         # speculation economy: accepted tokens per fused dispatch, the
         # read that explains a slowdown at high acceptance (up is
@@ -643,6 +816,7 @@ class StepProfiler:
         with self._lock:
             recs = [
                 {k: v for k, v in r.items() if k not in ("t0", "t1")}
+                | {"phases": {k: round(v, 6) for k, v in r["phases"].items()}}
                 for r in self._ring
             ]
         if limit is not None and limit >= 0:
@@ -676,30 +850,52 @@ class StepProfiler:
         }
 
 
-# -- legacy jax.profiler capture, folded into the plane ---------------------
+# -- the profiler's own trace ------------------------------------------------
 
-@contextlib.contextmanager
-def device_trace(log_dir: Optional[str] = None):
-    """Capture device activity for the enclosed block.
+_TRACE_ANNOTATION = None
 
-    The legacy helper (``utils.profiling.device_trace``, kept as a thin
-    alias) wrapped ``jax.profiler`` alone; folded into this plane it
-    ALSO records a ``device_trace`` span in the active istpu trace, so a
-    capture shows up in the same Perfetto export as the step records.
-    ``log_dir=None`` skips the (heavyweight) ``jax.profiler`` capture
-    and keeps just the span — the mode ``bench_tpu.py --trace-out``
-    uses."""
-    started = False
-    if log_dir:
+
+def _annotation(name: str):
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:      # jax stays a lazy import here
         import jax
 
-        jax.profiler.start_trace(log_dir)
-        started = True
-    try:
-        with tracing.span("device_trace", log_dir=log_dir or ""):
-            yield
-    finally:
-        if started:
-            import jax
+        _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
+    return _TRACE_ANNOTATION(name)
 
+
+def start_capture(log_dir: str, seconds: float) -> bool:
+    """Start ``jax.profiler`` into ``log_dir`` and stop it ``seconds``
+    later from a side thread (``POST /debug/profile``).  Host tracer level
+    2 and the Python tracer off: what the benchmark's own capture uses —
+    the engine's Python frames would swamp the trace and slow the host.
+    False when a capture is already running, whoever started it; a
+    ``log_dir`` that cannot be made or written raises ``OSError`` here
+    (the profiler itself would only find out when it stops)."""
+    import jax
+
+    os.makedirs(log_dir, exist_ok=True)
+    if not os.access(log_dir, os.W_OK | os.X_OK):
+        raise PermissionError(f"cannot write to {log_dir!r}")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    try:
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+    except RuntimeError as e:
+        if "Only one profile" in str(e):
+            return False
+        raise
+
+    def stop() -> None:
+        try:
             jax.profiler.stop_trace()
+        except Exception:  # noqa: BLE001 — a Timer thread has no caller
+            # someone else stopped it, or the export failed
+            logging.getLogger("infinistore_tpu").exception(
+                "profiler capture into %s did not stop cleanly", log_dir)
+
+    timer = threading.Timer(seconds, stop)
+    timer.daemon = True
+    timer.start()
+    return True

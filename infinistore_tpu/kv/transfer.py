@@ -16,6 +16,7 @@ layer-by-layer streaming (reference design.rst prefill flow) stays possible.
 
 from __future__ import annotations
 
+import threading
 import time
 from functools import partial
 from typing import List, Optional, Sequence, Tuple
@@ -120,6 +121,25 @@ class KVTransferEngine:
         # load_pages — the engine step records attach both dicts when a
         # step moved pages (engine/stepprof.py)
         self.last_load_stages: dict = {}
+        # running totals beside the two "last" dicts, for readers that take
+        # deltas (engine/stepprof.py).  Each is REPLACED whole under the
+        # lock, never mutated: a reader holding one holds a consistent
+        # snapshot.  ``submit_to_commit_s`` runs from push_begin on the
+        # submitting thread to the acknowledged COMMIT_PUT on the worker,
+        # so a push's wait in the streamer's queue is inside it.
+        self._totals_lock = threading.Lock()
+        self.push_totals: dict = dict.fromkeys(
+            ("pushes", "tokens", "bytes"), 0) | dict.fromkeys(
+            ("d2h_s", "pool_copy_s", "alloc_s", "wire_s", "commit_s",
+             "submit_to_commit_s"), 0.0)
+        self.load_totals: dict = dict.fromkeys(
+            ("loads", "tokens", "bytes"), 0) | dict.fromkeys(
+            ("fetch_s", "scatter_s"), 0.0)
+
+    def _add_totals(self, which: str, **add) -> None:
+        with self._totals_lock:
+            old = getattr(self, which)
+            setattr(self, which, {k: v + add.get(k, 0) for k, v in old.items()})
 
     @property
     def conn(self):
@@ -262,7 +282,7 @@ class KVTransferEngine:
         parts = [pages[l0 : l0 + Lg] for l0 in range(0, L, Lg)]
         for p in parts:
             p.copy_to_host_async()
-        return parts, list(chunk_keys_)
+        return parts, list(chunk_keys_), time.perf_counter()
 
     def push_commit(self, token) -> int:
         """Off-critical-path half of a push: materialize each band —
@@ -270,7 +290,7 @@ class KVTransferEngine:
         alloc-first descriptors, through the pinned staging ring on
         TCP/native — and COMMIT_PUT.  Per-stage seconds land in
         ``last_push_stages``.  Returns bytes written."""
-        parts, chunk_keys_ = token
+        parts, chunk_keys_, t_begin = token
         L = self.cfg.n_layers
         pb = self.wire_page_bytes
         stages = {"d2h_s": 0.0, "pool_copy_s": 0.0, "wire_s": 0.0,
@@ -280,6 +300,11 @@ class KVTransferEngine:
                           bytes=len(chunk_keys_) * L * pb):
             total = self._push_banded(parts, chunk_keys_, stages)
         self.last_push_stages = stages
+        self._add_totals(
+            "push_totals", pushes=1, bytes=total,
+            tokens=len(chunk_keys_) * self.cfg.block_tokens,
+            submit_to_commit_s=time.perf_counter() - t_begin,
+            **{k: v for k, v in stages.items() if k.endswith("_s")})
         return total
 
     def _push_banded(self, parts, chunk_keys_: Sequence[str],
@@ -478,6 +503,10 @@ class KVTransferEngine:
             "pages": self.cfg.n_layers * n,
             "bytes": self.cfg.n_layers * n * self.wire_page_bytes,
         }
+        self._add_totals(
+            "load_totals", loads=1, tokens=n * self.cfg.block_tokens,
+            bytes=self.last_load_stages["bytes"], fetch_s=t1 - t0,
+            scatter_s=t2 - t1)
         return out
 
     def lookup_prefix(self, chunk_keys_: Sequence[str]) -> int:

@@ -110,6 +110,31 @@ def build_record(req, outcome: str,
     # waterfall rather than inside it).  0.0 for direct library callers.
     t_stage = getattr(req, "t_stage", 0.0)
     admission_wait_s = max(0.0, t_submit - t_stage) if t_stage else 0.0
+    # the TTFT waterfall: disjoint slices from the handler's staging to the
+    # first visible token (to the exit, for a request that never had one),
+    # which sum to ``total_s`` = ttft_s + admission_wait_s.  The own_*
+    # seconds are the engine-thread phases that ran on this request's
+    # behalf (engine/stepprof.py); prefill_wait_s is the rest of admit ->
+    # decode-ready: parked behind the batch's decode dispatches and other
+    # requests' chunks.  It is taken as the remainder of the ROUNDED
+    # slices (never below zero) and ``total_s`` as their sum, so the block
+    # sums exactly as printed, and to the true total within the rounding.
+    t_end = t_first or t_done or t_submit
+    t_ready = min(getattr(req, "t_prefill_done", 0.0) or t_end, t_end)
+    ttft_block = {
+        "stage_wait_s": _r(admission_wait_s),
+        "queue_s": _r((t_admit or t_end) - t_submit),
+        "lookup_s": _r(getattr(req, "own_lookup_s", 0.0)),
+        "load_s": _r(getattr(req, "own_load_s", 0.0)),
+        "prefill_own_s": _r(getattr(req, "own_prefill_s", 0.0)),
+        "first_burst_s": _r(t_end - t_ready),
+    }
+    rest = admission_wait_s + (t_end - t_submit) - sum(ttft_block.values())
+    ttft_block["prefill_wait_s"] = max(0.0, _r(rest))
+    ttft_block.update(
+        total_s=_r(sum(ttft_block.values()), 9),
+        prefill_chunks=getattr(req, "prefill_chunks", 0),
+        steps_to_first=getattr(req, "steps_to_first", 0))
     return {
         "req_id": req.req_id,
         "trace_id": getattr(req, "trace_id", None),
@@ -130,6 +155,7 @@ def build_record(req, outcome: str,
             "store_chunks": store, "hit": store > 0, "load_s": _r(store_s),
         },
         "waterfall": waterfall,
+        "ttft": ttft_block,
         "shares": shares,
         "events": events,
         "token_stamps": list(getattr(req, "stamps", ())),

@@ -512,8 +512,13 @@ class ServingServer:
     # -- engine thread --
 
     def _engine_loop(self) -> None:
+        # this thread's time is partitioned into flat phases
+        # (StepProfiler.enter): the loop's own here, the scheduler's, the
+        # engine's and the transfer's inside sched.step()
+        phase = self.stepprof.enter
         while True:
             if not self.sched.has_work and self.engine.transfer is not None:
+                phase("kv.push_wait")
                 # the batch just drained: join the store streamer so
                 # relaxed-durability pushes land and their errors SURFACE
                 # here (logged) instead of parking in the streamer until
@@ -540,8 +545,10 @@ class ServingServer:
             with self._cv:
                 while not (self._staged or self._cancels or self._stop
                            or self.sched.has_work):
+                    phase("idle")
                     self._cv.wait()
                 if self._stop:
+                    phase(None)
                     # second abort sweep: items this loop popped from
                     # _staged before close() snapshotted (and registered
                     # into _queues since) were invisible to close()'s
@@ -556,6 +563,7 @@ class ServingServer:
                 # popped items keep counting toward the admission depth
                 # until the scheduler owns them (see _over_depth_locked)
                 self._submitting += len(staged)
+            phase("intake")
             for rid in cancels:
                 self.sched.cancel(rid)
                 self._queues.pop(rid, None)
@@ -572,6 +580,7 @@ class ServingServer:
                     # into a step-granular timeline in /debug/traces
                     with tracing.trace("engine.step"):
                         retired = self.sched.step()
+                    phase("retire_stream")
                     for req in retired:
                         with self.metrics.lock:
                             # handler threads increment completed too (the
@@ -1743,6 +1752,37 @@ def _make_handler(server: ServingServer):
                     self._json(400, {"error": str(e)})
                     return
                 self._json(200, {"armed": armed})
+                return
+            if self.path.split("?", 1)[0] == "/debug/profile":
+                # operators' capture: jax.profiler for {"seconds", "dir"}
+                # on a side thread; the engine thread's istpu.* phases
+                # land in its host plane beside the device's operations.
+                # 409 while any capture runs (one profile per process).
+                from .engine.stepprof import start_capture
+
+                try:
+                    n = int(self.headers.get("Content-Length", 0))
+                    body = json.loads(self.rfile.read(n) or b"{}")
+                    seconds, log_dir = float(body["seconds"]), body["dir"]
+                    if not (0 < seconds <= 600 and isinstance(log_dir, str)
+                            and log_dir):
+                        raise ValueError("seconds in (0, 600], dir a path")
+                except (ValueError, TypeError, KeyError) as e:
+                    self._json(400, {"error": f"bad request: {e}"})
+                    return
+                try:
+                    started = start_capture(log_dir, seconds)
+                except OSError as e:       # dir cannot be made or written
+                    self._json(400, {"error": f"bad dir: {e}"})
+                    return
+                except RuntimeError as e:  # the profiler's own refusal
+                    self._json(500, {"error": str(e)})
+                    return
+                if not started:
+                    self._json(409, {"error": "a capture is already "
+                                              "running"})
+                    return
+                self._json(200, {"dir": log_dir, "seconds": seconds})
                 return
             if self.path.split("?", 1)[0] == "/debug/cluster":
                 # live membership control: join/drain one store node

@@ -2,11 +2,8 @@
 
 ``LatencyStats`` backs the client-side per-op latency counters
 (lib.py Connection.latency_stats — the client's side of the story next to
-the server's ``/metrics``).  ``device_trace`` is kept as a thin alias of
-``engine.stepprof.device_trace`` (the per-step engine/device attribution
-plane): same public name and ``jax.profiler`` capture, but the capture
-now ALSO lands as a span in the active istpu trace, so one Perfetto
-export shows it next to the step records.
+the server's ``/metrics``).  (A ``jax.profiler`` capture of a running
+server is ``POST /debug/profile``, ``engine.stepprof.start_capture``.)
 
 ``LatencyStats`` is one leg of the unified observability plane: every
 sample it takes is simultaneously (a) accumulated into its own
@@ -91,14 +88,3 @@ class LatencyStats:
                     "max_ms": round(mx * 1e3, 3),
                 }
             return out
-
-
-def device_trace(log_dir: Optional[str] = None):
-    """Thin alias of ``engine.stepprof.device_trace`` (the legacy public
-    name): capture a jax.profiler trace of the enclosed block into
-    ``log_dir`` (TensorBoard profile plugin / xprof) AND record a
-    ``device_trace`` span in the active istpu trace.  ``log_dir=None``
-    keeps just the span."""
-    from ..engine.stepprof import device_trace as _impl
-
-    return _impl(log_dir)

@@ -59,8 +59,9 @@ class _Clock:
 def test_record_shape_with_injected_clock():
     from infinistore_tpu.engine import stepprof
 
-    # calls: t0 (begin), t1 (end), tb (before block), after block
-    clock = _Clock([10.0, 11.0, 11.0, 11.25])
+    # calls: t0 (begin), t1 (end), tb (the probe phase's start, before the
+    # block), after block, and the switch that ends the probe phase
+    clock = _Clock([10.0, 11.0, 11.0, 11.25, 11.25])
     prof = _prof(sample=1, clock=clock, block=lambda x: None,
                  sentinel=lambda: object(),
                  mem_reader=lambda: {"live_bytes": 10, "peak_bytes": 20})
@@ -171,7 +172,9 @@ def test_spec_and_store_stage_attribution_deltas():
     """Speculation counters and transfer stage dicts attach as PER-STEP
     deltas (fake scheduler: no device needed)."""
     spec = SimpleNamespace(rounds=10, proposed=40, accepted=30)
-    transfer = SimpleNamespace(last_push_stages={}, last_load_stages={})
+    transfer = SimpleNamespace(
+        push_totals={"pushes": 3, "d2h_s": 1.0, "zero_copy_bands": 9},
+        load_totals={"loads": 1, "fetch_s": 0.5, "scatter_s": 0.25})
     sched = SimpleNamespace(
         spec=spec, engine=SimpleNamespace(transfer=transfer, cache=None),
         active=[1, 2], _prefilling=[], pending=[3],
@@ -181,28 +184,118 @@ def test_spec_and_store_stage_attribution_deltas():
         spec.rounds += 2
         spec.proposed += 8
         spec.accepted += 5
-        transfer.last_push_stages = {"d2h_s": 0.1, "zero_copy_bands": 4}
-        transfer.last_load_stages = {"fetch_s": 0.2, "scatter_s": 0.05}
+        # the transfer replaces its running totals whole on every update
+        transfer.push_totals = {"pushes": 4, "d2h_s": 1.1,
+                                "zero_copy_bands": 13}
+        transfer.load_totals = {"loads": 2, "fetch_s": 0.7,
+                                "scatter_s": 0.3}
     assert rec["batch"] == {"active": 2, "prefilling": 0, "pending": 1}
     assert rec["spec"] == {"rounds": 2, "proposed": 8, "accepted": 5}
-    assert rec["store"]["push"]["zero_copy_bands"] == 4
-    assert rec["store"]["load"]["fetch_s"] == 0.2
+    # the step's share is the totals' DELTA, not the last push's stages
+    assert rec["store"]["push"] == {"pushes": 1, "d2h_s": 0.1,
+                                    "zero_copy_bands": 4}
+    assert rec["store"]["load"]["fetch_s"] == pytest.approx(0.2)
+    assert prof.summary()["store"]["push"]["pushes"] == 4
     # a step that moved nothing attaches neither block
     with prof.step(sched) as rec2:
         pass
     assert "spec" not in rec2 and "store" not in rec2
 
 
-def test_device_trace_alias_lands_in_the_plane():
-    """The legacy ``utils.profiling.device_trace`` name survives as a
-    thin alias whose capture shows as a span in the active trace."""
-    from infinistore_tpu.utils import tracing
-    from infinistore_tpu.utils.profiling import device_trace
+def _serving(tiny_engine_parts):
+    from infinistore_tpu.engine import InferenceEngine
+    from infinistore_tpu.serve import ServingServer
 
-    with tracing.trace("alias.check") as tr:
-        with device_trace():  # no log_dir: span only, no jax.profiler
-            pass
-    assert any(ev[0] == "device_trace" for ev in tr.events)
+    cfg, params, make_pc = tiny_engine_parts
+    eng = InferenceEngine(params, cfg, make_pc())
+    eng.decode_chunk = 4
+    srv = ServingServer(eng, port=0, max_batch=2, model_id="prof-capture")
+    srv.start()
+    return srv
+
+
+def _wait_capture_over(tmp_path):
+    """Until the endpoint's capture has stopped AND written its file: the
+    export runs under the lock ``start_trace`` takes, so a capture of our
+    own can start only once it is whole."""
+    import jax
+
+    deadline = time.time() + 60
+    while time.time() < deadline:
+        try:
+            jax.profiler.start_trace(str(tmp_path / "after"))
+        except RuntimeError:
+            time.sleep(0.1)
+            continue
+        jax.profiler.stop_trace()
+        return
+    pytest.fail("the endpoint's capture never stopped")
+
+
+def test_profile_endpoint_captures_and_refuses_a_second(tiny_engine_parts,
+                                                        tmp_path):
+    """``POST /debug/profile`` (the operators' capture, in the place of the
+    old ``device_trace`` helper): 200 and an ``.xplane.pb`` whose host plane
+    holds the engine thread's ``istpu.*`` phases; 409 while it runs; 400 for
+    a body without ``seconds`` and ``dir``."""
+    import glob
+
+    srv = _serving(tiny_engine_parts)
+    try:
+        _post(srv.port, {"prompt": [1, 2, 3], "max_tokens": 4,
+                         "temperature": 0})          # compile first
+        body = {"seconds": 3.0, "dir": str(tmp_path / "ours")}
+        status, out = _post(srv.port, body, path="/debug/profile")
+        assert status == 200 and out["dir"] == str(tmp_path / "ours"), out
+        status, out = _post(srv.port, body, path="/debug/profile")
+        assert status == 409, out
+        _post(srv.port, {"prompt": [4, 5, 6], "max_tokens": 4,
+                         "temperature": 0})
+        for bad in ({}, {"seconds": -1, "dir": "x"}, {"seconds": 1}):
+            status, _out = _post(srv.port, bad, path="/debug/profile")
+            assert status == 400, bad
+        # a dir that cannot be made is the caller's mistake, not a conflict
+        status, out = _post(srv.port, {"seconds": 1, "dir": "/dev/null/x"},
+                            path="/debug/profile")
+        assert status == 400 and "dir" in out["error"], out
+        _wait_capture_over(tmp_path)
+        from jax.profiler import ProfileData
+
+        pat = os.path.join(str(tmp_path / "ours"), "plugins", "profile", "*",
+                           "*.xplane.pb")
+        data = ProfileData.from_file(glob.glob(pat)[0])
+        host = {e.name for p in data.planes if p.name.startswith("/host:")
+                for ln in p.lines for e in ln.events}
+        assert "istpu.decode.wait" in host, sorted(
+            n for n in host if n.startswith("istpu."))
+    finally:
+        srv.close()
+
+
+def test_profile_endpoint_refuses_while_another_capture_runs(
+        tiny_engine_parts, tmp_path):
+    """One profile per process: a capture started by anyone else (the
+    benchmark's own side thread calls ``jax.profiler.start_trace`` itself)
+    makes the endpoint answer 409, and it works again afterwards."""
+    import jax
+
+    srv = _serving(tiny_engine_parts)
+    try:
+        jax.profiler.start_trace(str(tmp_path / "theirs"))
+        try:
+            status, out = _post(
+                srv.port, {"seconds": 0.2, "dir": str(tmp_path / "ours")},
+                path="/debug/profile")
+            assert status == 409, out
+        finally:
+            jax.profiler.stop_trace()
+        status, out = _post(
+            srv.port, {"seconds": 0.2, "dir": str(tmp_path / "ours")},
+            path="/debug/profile")
+        assert status == 200, out
+        _wait_capture_over(tmp_path)     # leave no capture running behind
+    finally:
+        srv.close()
 
 
 def test_transfer_records_load_stages(tmp_path):
