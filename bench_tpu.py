@@ -1000,7 +1000,7 @@ def leg_invocation_overhead(out: dict) -> None:
     @jax.jit
     def xla(qs):
         outs = [
-            paged_decode_attention_xla(qs[l], cache[l], table, lens)
+            paged_decode_attention_xla(qs[l], cache, l, table, lens)
             for l in range(L)
         ]
         return qs * 0.999 + 0.001 * jnp.stack(outs)

@@ -280,9 +280,36 @@ def causal_attention(
     return out
 
 
+def gather_layer_kv(
+    cache: jax.Array, layer: int, block_table: jax.Array
+) -> tuple[jax.Array, jax.Array]:
+    """One layer's keys and values for a block table, gathered by index
+    straight out of the whole cache.
+
+    cache: [L, 2, H_kv, n_blocks, T, D]; layer: static; block_table:
+    [B, max_pages] int32 -> k, v: [B, max_pages * T, H_kv, D].
+
+    Layer, K|V and page id are all indices of the gather, as in
+    kv/cache.py:write_token_kv.  A ``cache[layer]`` formed first is a
+    slice, and XLA:TPU does not fuse a slice into a gather's operand: it
+    copies the layer's slab, and K's and V's halves of it, in every layer
+    of every step (PERF.md, PR 27).  The advanced indices are split by a
+    slice, so the table's dims land in front: [B, max_pages, H_kv, T, D].
+    Out-of-bounds page ids (pad rows) clamp; callers mask by length."""
+    B, max_pages = block_table.shape
+    Hkv, _, T, D = cache.shape[2:]
+    k, v = (
+        jnp.moveaxis(cache[layer, kv, :, block_table], 2, 3).reshape(
+            B, max_pages * T, Hkv, D)
+        for kv in (0, 1)
+    )
+    return k, v
+
+
 def paged_decode_attention_xla(
     q: jax.Array,
-    layer_cache: jax.Array,
+    cache: jax.Array,
+    layer: int,
     block_table: jax.Array,
     seq_lens: jax.Array,
     window: int | None = None,
@@ -291,25 +318,21 @@ def paged_decode_attention_xla(
     """One-token decode attention against the paged cache (XLA gather path).
 
     q: [B, H, D] (current token, RoPE already applied)
-    layer_cache: [2, H_kv, n_blocks, T, D] (one layer's pages)
+    cache: [L, 2, H_kv, n_blocks, T, D] (the whole cache); layer: the
+    layer whose pages are read (static)
     block_table: [B, max_pages] int32
     seq_lens: [B] int32 -- number of valid tokens (including current)
     """
     B, H, D = q.shape
-    Hkv, _, T = layer_cache.shape[1:4]
-    max_pages = block_table.shape[1]
-    # gather pages: [Hkv, B, max_pages, T, D] -> [B, S_max, Hkv, D]
-    k = layer_cache[0][:, block_table]
-    v = layer_cache[1][:, block_table]
-    k = jnp.moveaxis(k, 0, 3).reshape(B, max_pages * T, Hkv, D)
-    v = jnp.moveaxis(v, 0, 3).reshape(B, max_pages * T, Hkv, D)
+    k, v = gather_layer_kv(cache, layer, block_table)
+    S_max, Hkv = k.shape[1:3]
     k = repeat_kv(k, H // Hkv)
     v = repeat_kv(v, H // Hkv)
     scale = 1.0 / np.sqrt(D)
     logits = jnp.einsum("bhd,bkhd->bhk", q, k).astype(jnp.float32) * scale
     if softcap is not None:  # Gemma-2 logit soft-capping
         logits = softcap * jnp.tanh(logits / softcap)
-    pos = jnp.arange(max_pages * T)
+    pos = jnp.arange(S_max)
     mask = pos[None, :] < seq_lens[:, None]  # [B, S_max]
     if window is not None:
         # current token sits at seq_lens-1; window covers (q - W, q]
@@ -321,7 +344,8 @@ def paged_decode_attention_xla(
 
 def paged_multitoken_attention_xla(
     q: jax.Array,
-    layer_cache: jax.Array,
+    cache: jax.Array,
+    layer: int,
     block_table: jax.Array,
     positions: jax.Array,
     window: int | None = None,
@@ -331,27 +355,24 @@ def paged_multitoken_attention_xla(
     (the speculative-decode verify step: S proposal tokens attend to the
     whole paged history plus themselves, causally by absolute position).
 
-    q: [B, S, H, D] (RoPE applied); layer_cache: [2, H_kv, n_blocks, T, D]
-    — the new tokens' K/V must already be scattered into the pages;
+    q: [B, S, H, D] (RoPE applied); cache: [L, 2, H_kv, n_blocks, T, D]
+    and the (static) layer to read — the new tokens' K/V must already be
+    scattered into the pages;
     block_table: [B, max_pages] int32; positions: [B, S] int32 absolute
     positions of the new tokens.  Masking is purely positional: a key in a
     gathered page is visible iff its absolute position <= the query's, which
     also hides stale slots past the sequence end.  Returns [B, S, H, D].
     """
     B, S, H, D = q.shape
-    Hkv, _, T = layer_cache.shape[1:4]
-    max_pages = block_table.shape[1]
-    k = layer_cache[0][:, block_table]
-    v = layer_cache[1][:, block_table]
-    k = jnp.moveaxis(k, 0, 3).reshape(B, max_pages * T, Hkv, D)
-    v = jnp.moveaxis(v, 0, 3).reshape(B, max_pages * T, Hkv, D)
+    k, v = gather_layer_kv(cache, layer, block_table)
+    S_max, Hkv = k.shape[1:3]
     k = repeat_kv(k, H // Hkv)
     v = repeat_kv(v, H // Hkv)
     scale = 1.0 / np.sqrt(D)
     logits = jnp.einsum("bshd,bkhd->bhsk", q, k).astype(jnp.float32) * scale
     if softcap is not None:  # Gemma-2 logit soft-capping
         logits = softcap * jnp.tanh(logits / softcap)
-    k_pos = jnp.arange(max_pages * T)
+    k_pos = jnp.arange(S_max)
     mask = k_pos[None, None, :] <= positions[:, :, None]  # [B, S, S_max]
     if window is not None:
         mask &= k_pos[None, None, :] > positions[:, :, None] - window
@@ -414,7 +435,8 @@ def paged_decode_attention_tp(
 
 def paged_decode_attention(
     q: jax.Array,
-    layer_cache: jax.Array,
+    cache: jax.Array,
+    layer: int,
     block_table: jax.Array,
     seq_lens: jax.Array,
     allow_pallas: bool = True,
@@ -422,11 +444,15 @@ def paged_decode_attention(
     window: int | None = None,
     softcap: float | None = None,
 ) -> jax.Array:
-    """Paged decode attention; Pallas kernel on TPU, XLA gather elsewhere.
+    """Paged decode attention: the XLA gather path unless a Pallas kernel
+    is opted into.
 
-    Same signature/layout as ``paged_decode_attention_xla`` -- the cache
-    layout [2, H_kv, n_blocks, T, D] IS the Pallas kernel layout, so the
-    kernel streams pages by block-table lookup with no shuffle.  Set
+    Same signature as ``paged_decode_attention_xla``: the whole cache
+    [L, 2, H_kv, n_blocks, T, D] and the (static) layer to read.  The XLA
+    path gathers that layer's pages by index out of ``cache`` and never
+    forms ``cache[layer]``; the opt-in Pallas paths below still take the
+    layer's slice [2, H_kv, n_blocks, T, D], which IS their kernel layout
+    (pages stream by block-table lookup with no shuffle).  Set
     ``ISTPU_NO_PALLAS=1`` to force the XLA path.
 
     ``allow_pallas=False`` MUST be passed when tracing under a
@@ -445,7 +471,7 @@ def paged_decode_attention(
         # the XLA path partitions fine under GSPMD, so those models always
         # take it
         return paged_decode_attention_xla(
-            q, layer_cache, block_table, seq_lens, window=window,
+            q, cache, layer, block_table, seq_lens, window=window,
             softcap=softcap,
         )
     if tp_mesh is not None:
@@ -457,10 +483,10 @@ def paged_decode_attention(
         )
         if on_tpu or interp:
             return paged_decode_attention_tp(
-                q, layer_cache, block_table, seq_lens, tp_mesh,
+                q, cache[layer], block_table, seq_lens, tp_mesh,
                 interpret=interp,
             )
-        return paged_decode_attention_xla(q, layer_cache, block_table, seq_lens)
+        return paged_decode_attention_xla(q, cache, layer, block_table, seq_lens)
     if (
         allow_pallas
         and os.environ.get("ISTPU_PALLAS_DECODE")  # opt-in, see below
@@ -480,12 +506,13 @@ def paged_decode_attention(
             D = q.shape[-1]
             return _jax_paged_attention(
                 q * jnp.asarray(D ** -0.5, q.dtype),
-                layer_cache[0], layer_cache[1], seq_lens, block_table,
+                cache[layer, 0], cache[layer, 1], seq_lens, block_table,
                 pages_per_compute_block=min(8, block_table.shape[1]),
             )
         from ..ops.pallas_attention import paged_decode_attention_pallas
 
-        return paged_decode_attention_pallas(q, layer_cache, block_table, seq_lens)
+        return paged_decode_attention_pallas(
+            q, cache[layer], block_table, seq_lens)
     # DEFAULT: the XLA gather path.  Measured in-model on a v5e with
     # right-sized (pow2-bucketed) block tables, the Pallas kernel is
     # SLOWER than XLA's fused gather at every context tried (0.7x at
@@ -495,4 +522,4 @@ def paged_decode_attention(
     # stays available (ISTPU_PALLAS_DECODE=1) for future retuning; the
     # flash PREFILL kernels remain the default — measured 1.13x at 2k and
     # they keep the [S, S] score matrix out of HBM.
-    return paged_decode_attention_xla(q, layer_cache, block_table, seq_lens)
+    return paged_decode_attention_xla(q, cache, layer, block_table, seq_lens)

@@ -415,10 +415,16 @@ def decode_forward(
     path.
 
     tokens/positions: [B]; cache: [L, 2, Hkv, n_blocks, T, D]
-    (kv/cache.py layout -- heads outside blocks so the Pallas decode kernel
-    streams [T, D] tiles); block_table: [B, max_pages]; seq_lens: [B]
+    (kv/cache.py layout -- heads outside blocks, so a (head, page) tile
+    [T, D] is contiguous); block_table: [B, max_pages]; seq_lens: [B]
     (*including* this token); slot_block_ids/slot_ids: [B] where to scatter
     this token's K/V.  Returns (logits [B, V], updated cache).
+
+    The layers are unrolled and each hands the WHOLE cache and its index to
+    the write and to the attention, which gather that layer's pages by
+    index (write_token_kv, attention.gather_layer_kv): ``cache[li]`` is
+    never formed, because XLA:TPU copies the layer's slab when a slice
+    feeds a gather.
     """
     from ..kv.cache import write_token_kv
 
@@ -434,7 +440,7 @@ def decode_forward(
         # scatter this token's kv into its page slot
         cache = write_token_kv(cache, li, slot_block_ids, slot_ids, k[:, 0], v[:, 0])
         attn = paged_decode_attention(
-            q[:, 0], cache[li], block_table, seq_lens, allow_pallas=use_pallas,
+            q[:, 0], cache, li, block_table, seq_lens, allow_pallas=use_pallas,
             tp_mesh=tp_mesh, window=_window_for(cfg, li),
             softcap=cfg.attn_softcap,
         )
@@ -500,7 +506,7 @@ def verify_forward(
                             adapter_ids=adapter_ids, lora_scale=lora_scale)
         cache = write_tokens_kv(cache, li, slot_block_ids, slot_ids, k, v)
         attn = paged_multitoken_attention_xla(
-            q, cache[li], block_table, positions, window=_window_for(cfg, li),
+            q, cache, li, block_table, positions, window=_window_for(cfg, li),
             softcap=cfg.attn_softcap,
         )
         a = attn.reshape(B, S, -1)

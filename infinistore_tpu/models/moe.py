@@ -210,7 +210,7 @@ def moe_decode_forward(
         q, k, v = _attn_qkv(layer, cfg, h, pos)
         cache = write_token_kv(cache, li, slot_block_ids, slot_ids, k[:, 0], v[:, 0])
         attn = paged_decode_attention(
-            q[:, 0], cache[li], block_table, seq_lens, allow_pallas=use_pallas,
+            q[:, 0], cache, li, block_table, seq_lens, allow_pallas=use_pallas,
             window=cfg.sliding_window,
         )
         x = x + (attn.reshape(B, -1) @ layer["wo"])[:, None, :]
@@ -243,7 +243,7 @@ def moe_verify_forward(
         q, k, v = _attn_qkv(layer, cfg, h, positions)
         cache = write_tokens_kv(cache, li, slot_block_ids, slot_ids, k, v)
         attn = paged_multitoken_attention_xla(
-            q, cache[li], block_table, positions, window=cfg.sliding_window
+            q, cache, li, block_table, positions, window=cfg.sliding_window
         )
         x = x + attn.reshape(B, S, -1) @ layer["wo"]
         h = rmsnorm(x, layer["ln_mlp"], cfg.norm_eps)

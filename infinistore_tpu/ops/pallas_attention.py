@@ -10,8 +10,9 @@ the entry, and flips a default only on a replicated win.
 
 The decode hot loop reads every cached K/V page of every active sequence per
 token -- purely HBM-bandwidth-bound.  The XLA version
-(models/attention.py:paged_decode_attention) materializes the page gather
-([B, S_max, H, D]) before attending; this kernel instead streams pages
+(models/attention.py:paged_decode_attention_xla) gathers the table's pages
+by (layer, page) index out of the whole cache and writes them out as K and
+V ([B, S_max, H_kv, D]) before attending; this kernel instead streams pages
 HBM->VMEM by block-table lookup (PrefetchScalarGridSpec: the table is
 available to BlockSpec index_maps, so the pipeline's double-buffered DMAs
 chase the page table directly -- no gathered copy is ever written back).
@@ -22,8 +23,8 @@ on TPU the cache is already in HBM and the analog is the HBM->VMEM stream.
 
 Cache layout: [2(K|V), H_kv, n_blocks, T, D] -- a (head, page) tile
 [T=16, D=128] is contiguous and exactly the bf16 min tile (16, 128).  This
-IS the serving layout (kv/cache.py), so no shuffle happens on the decode
-path.
+is one layer of the serving layout (kv/cache.py), so no shuffle happens on
+the decode path; the kernels are handed ``cache[layer]``.
 
 Grid: (B, H_kv, max_pages); the page axis is innermost so the flash-style
 online-softmax accumulators (m/l/acc in VMEM scratch, fp32) carry across

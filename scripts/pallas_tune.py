@@ -164,7 +164,7 @@ def sweep_decode(interpret: bool, small: bool, iters: int, repeats: int):
 
         def xla_step(x):
             return paged_decode_attention_xla(
-                q + x[0, 0, 0] * 1e-6, cache, table, lens)
+                q + x[0, 0, 0] * 1e-6, cache[None], 0, table, lens)
 
         def pl_step(x):
             return paged_decode_attention_pallas(

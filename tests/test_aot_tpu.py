@@ -1,0 +1,155 @@
+"""What XLA:TPU makes of the decode step, asked of the TPU compiler without
+a chip: a compile-only ``v5e:2x2`` topology, as ``benchmarks/aot_check.py``
+uses it.  Nothing runs and nothing is timed.
+
+The CPU tests cannot see this.  On the chip a slice of the cache that feeds
+a gather is materialised: when the attention was handed ``cache[layer]``
+the optimized program copied that layer's slab (and K's and V's halves of
+it) in every layer of every step, two thirds of the decode scan (PERF.md,
+PR 27).  Every compile-only test lives in this file, and the topology is
+described inside a fixture, so only the worker that runs the file loads the
+TPU's library; where the topology cannot be had the tests skip."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from infinistore_tpu import models
+from infinistore_tpu.kv import PagedCacheConfig, init_cache
+from infinistore_tpu.parallel.sharding import (
+    llama_inference_specs,
+    make_tp_decode,
+    shardings_for,
+)
+
+T, N_BLOCKS, WIDTH = 16, 384, 64
+
+# name, result type, opcode of every instruction of an optimized module
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?(\w+\[[\d,]*\])\S* ([\w\-]+)\(", re.M)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def _family(preset):
+    cfg = models.scaled(getattr(models, preset), n_layers=2)
+    pc = PagedCacheConfig(
+        n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
+        head_dim=cfg.head_dim, n_blocks=N_BLOCKS, block_tokens=T,
+        dtype=cfg.dtype,
+    )
+    return cfg, pc
+
+
+def _shaped(tree, sharding):
+    """Abstract arrays of ``tree``'s shapes, placed by ``sharding`` (one
+    sharding, or a pytree of them)."""
+    if not isinstance(sharding, dict):
+        sharding = jax.tree.map(lambda _: sharding, tree)
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, sharding,
+    )
+
+
+def _slab_copies(text, heads, pc):
+    """Instructions of the optimized program whose result is one layer's
+    slab of the cache, or K's or V's half of it, and that are neither a
+    parameter nor a bitcast."""
+    dims = (heads, pc.n_blocks, pc.block_tokens, pc.head_dim)
+    slabs = {"bf16[%s]" % ",".join(map(str, d)) for d in (dims, (2,) + dims)}
+    found = _INSTRUCTION.findall(text)
+    assert len(found) > 100, "the optimized program did not parse"
+    return [
+        f"{name} = {shape} {op}" for name, shape, op in found
+        if shape in slabs and op not in ("parameter", "bitcast")
+    ]
+
+
+@pytest.mark.parametrize("batch", [2, 8])
+@pytest.mark.parametrize("preset", ["QWEN3_8B", "QWEN25_7B"])
+def test_decode_scan_on_tpu_copies_no_slab_of_the_cache(preset, batch, v5e):
+    """The benchmark's two families at their published widths (two layers,
+    a small cache), ``decode_forward`` in a short scan as the engine runs
+    it: nothing of a slab's shape is computed, and the cache that comes
+    out is the donated one."""
+    cfg, pc = _family(preset)
+    chip = SingleDeviceSharding(v5e[0])
+    params = _shaped(jax.eval_shape(
+        lambda: models.init_params(cfg, jax.random.PRNGKey(0))), chip)
+    cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
+
+    def decode_scan(params, logits, pos, cache, table):
+        def step(carry, i):
+            logits, cache = carry
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            p = pos + i
+            blocks = jnp.take_along_axis(table, (p // T)[:, None], axis=1)[:, 0]
+            logits, cache = models.decode_forward(
+                params, cfg, tok, p, cache, table, p + 1, blocks, p % T,
+                use_pallas=False)
+            return (logits, cache), tok
+
+        (logits, cache), toks = jax.lax.scan(
+            step, (logits, cache), jnp.arange(3))
+        return toks, logits, cache
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    compiled = jax.jit(decode_scan, donate_argnums=(3,)).lower(
+        params, sds((batch, cfg.vocab_size), cfg.dtype),
+        sds((batch,), jnp.int32), cache, sds((batch, WIDTH), jnp.int32),
+    ).compile()
+    copies = _slab_copies(compiled.as_text(), pc.n_kv_heads, pc)
+    assert not copies, copies
+    cache_bytes = int(np.prod(cache.shape)) * cache.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes, (
+        "the cache output no longer aliases the donated input", mem)
+
+
+def test_tp_decode_on_tpu_copies_no_slab_and_gathers_no_cache(v5e):
+    """``make_tp_decode`` over four chips (GSPMD over the KV-head axis):
+    no chip copies its share of a slab, the cache is not all-gathered, and
+    the only collectives are the all-reduces after ``wo`` and the MLP."""
+    cfg, pc = _family("QWEN3_8B")
+    tp, batch = len(v5e), 4
+    mesh = Mesh(np.array(v5e).reshape(1, tp), ("dp", "tp"))
+    repl = NamedSharding(mesh, P())
+    cache_sharding = NamedSharding(mesh, P(None, None, "tp"))
+    params = _shaped(
+        jax.eval_shape(lambda: models.init_params(cfg, jax.random.PRNGKey(0))),
+        shardings_for(mesh, llama_inference_specs(cfg=cfg)),
+    )
+    cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), cache_sharding)
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=repl)
+    compiled = make_tp_decode(cfg, mesh).lower(
+        params, ints(batch), ints(batch), cache, ints(batch, WIDTH),
+        ints(batch), ints(batch), ints(batch),
+    ).compile()
+    text = compiled.as_text()
+    copies = _slab_copies(text, pc.n_kv_heads // tp, pc)
+    assert not copies, copies
+    collectives = [
+        op for _, _, op in _INSTRUCTION.findall(text)
+        if op.split("-start")[0] in (
+            "all-gather", "all-reduce", "all-to-all", "collective-permute",
+            "reduce-scatter")
+    ]
+    assert collectives and set(collectives) <= {"all-reduce", "all-reduce-start"}, (
+        collectives)
+    shard_bytes = int(np.prod(cache.shape)) * cache.dtype.itemsize // tp
+    assert compiled.memory_analysis().alias_size_in_bytes >= shard_bytes

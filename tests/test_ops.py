@@ -8,12 +8,18 @@ import pytest
 from infinistore_tpu.models.attention import paged_decode_attention_xla
 from infinistore_tpu.ops import paged_decode_attention_pallas
 
+# the XLA path reads layer LAYER out of a two-layer cache by index (a path
+# that ignored the index would read the other layer's pages); the Pallas
+# kernels take that layer's slice
+LAYER = 1
+
 
 def _setup(B, H, Hkv, D, T, n_blocks, max_pages, seed=0, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((B, H, D)), dtype)
-    # serving layout == kernel layout: [2, H_kv, n_blocks, T, D]
-    cache = jnp.asarray(rng.standard_normal((2, Hkv, n_blocks, T, D)), dtype)
+    # serving layout [L, 2, H_kv, n_blocks, T, D]; a layer of it is the
+    # kernel layout
+    cache = jnp.asarray(rng.standard_normal((2, 2, Hkv, n_blocks, T, D)), dtype)
     # each sequence gets distinct pages; lengths straddle page boundaries
     table = np.zeros((B, max_pages), dtype=np.int32)
     lens = np.zeros((B,), dtype=np.int32)
@@ -35,8 +41,9 @@ def test_paged_decode_kernel_matches_xla(n_rep, dtype):
     q, cache, table, lens = _setup(
         B, Hkv * n_rep, Hkv, D, T, n_blocks, max_pages, dtype=dtype
     )
-    want = paged_decode_attention_xla(q, cache, table, lens)
-    got = paged_decode_attention_pallas(q, cache, table, lens, interpret=True)
+    want = paged_decode_attention_xla(q, cache, LAYER, table, lens)
+    got = paged_decode_attention_pallas(
+        q, cache[LAYER], table, lens, interpret=True)
     tol = 5e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -49,8 +56,9 @@ def test_paged_decode_kernel_single_token():
     Hkv, D, T = 2, 128, 16
     q, cache, table, lens = _setup(1, 8, Hkv, D, T, 8, 2)
     lens = jnp.asarray([1], jnp.int32)
-    want = paged_decode_attention_xla(q, cache, table, lens)
-    got = paged_decode_attention_pallas(q, cache, table, lens, interpret=True)
+    want = paged_decode_attention_xla(q, cache, LAYER, table, lens)
+    got = paged_decode_attention_pallas(
+        q, cache[LAYER], table, lens, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=5e-6, atol=5e-6
     )
@@ -194,8 +202,8 @@ def test_paged_decode_kernel_mosaic_on_tpu():
     q, cache, table, lens = _setup(
         4, 32, Hkv, D, T, 64, 8, dtype=jnp.bfloat16
     )
-    want = paged_decode_attention_xla(q, cache, table, lens)
-    got = paged_decode_attention_pallas(q, cache, table, lens)
+    want = paged_decode_attention_xla(q, cache, LAYER, table, lens)
+    got = paged_decode_attention_pallas(q, cache[LAYER], table, lens)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         rtol=3e-2, atol=3e-2,

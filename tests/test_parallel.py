@@ -299,11 +299,12 @@ def test_tp_pallas_decode_matches_xla():
     rng = np.random.RandomState(0)
     B, H, Hkv, D, T, n_blocks, max_pages = 2, 8, 4, 16, 4, 16, 3
     q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
-    cache_l = jnp.asarray(rng.randn(2, Hkv, n_blocks, T, D), jnp.float32)
+    cache = jnp.asarray(rng.randn(2, 2, Hkv, n_blocks, T, D), jnp.float32)
     table = jnp.asarray(rng.randint(0, n_blocks, size=(B, max_pages)), jnp.int32)
     lens = jnp.asarray([11, 5], jnp.int32)
 
-    ref = paged_decode_attention_xla(q, cache_l, table, lens)
+    # the XLA path reads layer 1 by index; the kernel takes its slice
+    ref = paged_decode_attention_xla(q, cache, 1, table, lens)
     with jax.set_mesh(mesh):
         # jitted, as on the real decode path (eager shard_map with a
         # partially-manual mesh is not a supported composition)
@@ -311,7 +312,7 @@ def test_tp_pallas_decode_matches_xla():
             lambda q, c, t, s: paged_decode_attention_tp(
                 q, c, t, s, mesh, interpret=True
             )
-        )(q, cache_l, table, lens)
+        )(q, cache[1], table, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
