@@ -10,6 +10,11 @@ It compiles the program's own ``decode_forward`` in a 32-step scan (as
 ``engine._decode_many`` runs it) at batch B and block-table width W, and its
 ``prefill_forward`` on a 512-token chunk over the largest prefix buffer, and
 prints the compiler's memory analysis.  It measures no time.
+
+The programs it compiles are the dense decoder's: a configuration that is not
+served from a dense preset (it carries a ``model`` block) is refused here, and
+its family brings a compile check of its own as a new file beside this one.
+Weights and cache are counted as run.py counts them (``harness/family.py``).
 """
 
 from __future__ import annotations
@@ -36,13 +41,19 @@ def main() -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    import costs
+    import family
     from infinistore_tpu import models
     from infinistore_tpu.kv import PagedCacheConfig, init_cache
 
     jax.config.update("jax_enable_compilation_cache", False)
     with open(os.path.join(HERE, "configs", f"{args.config}.json")) as f:
         spec = json.load(f)
+    if "model" in spec or "preset" not in spec:
+        print(f"{args.config} is not served from a dense preset: this tool compiles "
+              f"the dense programs; its family brings its own compile check",
+              file=sys.stderr)
+        return 2
+    counts = family.counts(spec)
     cfg = models.scaled(getattr(models, spec["preset"]),
                         n_layers=spec["reduced"]["n_layers"])
     sv = spec["serve"]
@@ -75,8 +86,8 @@ def main() -> int:
         return toks, logits, cache
 
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    weights = costs.weight_bytes(spec)
-    cache_b = sv["n_blocks"] * T * costs.kv_bytes_per_token(spec)
+    weights = counts.weight_bytes(spec)
+    cache_b = sv["n_blocks"] * T * counts.cache_bytes_per_token(spec)
     limit = 15.75 * 2**30        # what XLA:TPU reported as usable on a v5e (PR 21)
     print(f"weights {weights / 1e9:.2f} GB + cache {cache_b / 1e9:.2f} GB; "
           f"compiler's HBM limit {limit / 1e9:.2f} GB")

@@ -1,6 +1,12 @@
 """Operations and bytes the algorithm needs, from shapes alone, and the table
 of peaks.  Undercounting keeps a share honest (under 100%); overcounting
-does not, so nothing here counts padding, re-reads or recomputation."""
+does not, so nothing here counts padding, re-reads or recomputation.
+
+``peaks`` and ``share_pct`` are common to every model family.  The counts
+below them are the dense grouped-query decoder's: the default of a
+configuration that names no ``costs`` module.  Another family's counts are a
+module of their own under ``benchmarks/counts/``, found by ``family.counts``;
+nothing here is edited for one."""
 
 from __future__ import annotations
 
@@ -9,6 +15,11 @@ import os
 from typing import Dict, Sequence, Tuple
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class NotCounted(AttributeError):
+    """A family's count module does not count this quantity: the metric that
+    needs it is left out of the cell, never read with another family's count."""
 
 
 def peaks(device_kind: str) -> dict:
@@ -43,6 +54,15 @@ def layer_matmul_params(s: dict) -> int:
 def kv_bytes_per_token(cfg: dict, dtype_bytes: int = 2) -> int:
     s = sizes(cfg)
     return 2 * s["L"] * s["kv"] * s["hd"] * dtype_bytes
+
+
+cache_bytes_per_token = kv_bytes_per_token    # the name every family's module gives it
+
+
+def store_page_bytes(cfg: dict, block_tokens: int) -> int:
+    """Bytes of one page as it goes to the store: one layer's K and V of one
+    block of tokens."""
+    return kv_bytes_per_token(cfg) * block_tokens // cfg["num_hidden_layers"]
 
 
 def weight_bytes(cfg: dict, dtype_bytes: int = 2) -> int:

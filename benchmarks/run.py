@@ -16,9 +16,13 @@ Which files it reads is decided by names in ``BENCHMARK.json`` alone: the
 cell's file under ``workloads/``, its configuration under ``configs/``, its
 mix under ``traffic/`` (read by the generator the mix names, under
 ``generators/``), and each per-layer metric's file under ``metrics/`` with its
-reader under ``readers/``.  A new cell, mix or metric is new files.
+reader under ``readers/``.  What depends on the model's family (the reference,
+the counts, the server's model file, the rehearsal's toy) is named by the
+configuration's own file and found by ``harness/family.py``.  A new cell, mix,
+metric or family is new files.
 
-``--rehearse 1`` walks the same sequence with the tiny preset on the CPU, to
+``--rehearse 1`` walks the same sequence on the CPU with the toy configuration
+the cell's configuration names (``rehearse``; the tiny preset by default), to
 debug the harness off the chip.  It says ``platform: cpu``, prints no result
 line and exits 3: a CPU number is never written under a device metric's name.
 The knee sweep and the check's controls are tools of their own beside this
@@ -31,7 +35,6 @@ import argparse
 import asyncio
 import contextlib
 import glob
-import importlib.util
 import json
 import math
 import os
@@ -49,6 +52,7 @@ sys.path[:0] = [os.path.join(HERE, "harness")]
 
 import client  # noqa: E402
 import costs  # noqa: E402
+import family  # noqa: E402
 import promtext  # noqa: E402
 import stats  # noqa: E402
 
@@ -72,14 +76,6 @@ def load_json(*parts: str):
         return json.load(f)
 
 
-def load_module(path: str):
-    spec = importlib.util.spec_from_file_location(
-        os.path.basename(path)[:-3], path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
@@ -87,6 +83,8 @@ def free_port() -> int:
 
 
 def start(name: str, argv: list, env: dict, run_dir: str) -> subprocess.Popen:
+    with open(os.path.join(run_dir, f"{name}.argv.json"), "w") as f:
+        json.dump(argv, f)
     with open(os.path.join(run_dir, f"{name}.log"), "w") as f:
         proc = subprocess.Popen(argv, cwd=ROOT, env=env, stdout=f,
                                 stderr=subprocess.STDOUT)
@@ -128,7 +126,7 @@ def wait_for(what: str, ready, procs: dict, run_dir: str, timeout_s: float):
 
 
 def rehearsal_scale(traffic: dict, cell: dict) -> None:
-    """Lengths / 8, at most 8 in flight: the tiny preset's sizes."""
+    """Lengths / 8, at most 8 in flight: a toy configuration's sizes."""
     def grid(g):
         return {str(max(8, int(k) // 8)): w for k, w in g.items()}
     traffic["tails"] = grid(traffic["tails"])
@@ -154,13 +152,15 @@ def load_cell(workload: str, rehearse: int) -> dict:
     cell = load_json(HERE, "workloads", f"{wl['name']}.json")
     traffic = load_json(HERE, "traffic", f"{wl['traffic']}.json")
     config_file = os.path.join(ROOT, cfg_entry["file"])
-    if rehearse:
-        config_file = os.path.join(HERE, "configs", "tiny.json")
-        rehearsal_scale(traffic, cell)
     config = load_json(config_file)
-    gen = load_module(os.path.join(HERE, "generators", f"{traffic['generator']}.py"))
+    if rehearse:
+        config_file = family.rehearsal_file(config)
+        config = load_json(config_file)
+        rehearsal_scale(traffic, cell)
+    gen = family.load_module(os.path.join(HERE, "generators", f"{traffic['generator']}.py"))
     return {"manifest": manifest, "wl": wl, "cell": cell, "traffic": traffic,
             "config": config, "config_file": config_file, "rehearse": rehearse,
+            "counts": family.counts(config),
             "generate": lambda seed, seconds, **kw: gen.generate(
                 traffic, cell, config, seed, seconds, **kw)}
 
@@ -174,7 +174,10 @@ def make_run_dir(c: dict, tag: str) -> str:
 
 def pool_gib(c: dict, plans: list) -> int:
     """The store pool: every token the run pushes, plus the store's overhead."""
-    kv_tok = costs.kv_bytes_per_token(c["config"])
+    config = c["config"]
+    block = config["serve"]["block_tokens"]
+    kv_tok = (c["counts"].store_page_bytes(config, block)
+              * config["num_hidden_layers"] // block)
     pushed = 0
     for plan in plans:
         pushed += sum(len(b["prompt"]) for b in plan["fill"]) + sum(
@@ -200,8 +203,7 @@ def servers(c: dict, run_dir: str, seed: int, pool: int, *, kv_quant: str = "non
     weight_seed = seed % (2**31 - 1)
     model_file = os.path.join(run_dir, "model.json")
     with open(model_file, "w") as f:
-        json.dump({k: config[k] for k in ("preset", "published", "reduced")}
-                  | {"seed": weight_seed}, f)
+        json.dump(family.model_file(config, weight_seed), f)
     env = dict(os.environ, PYTHONUNBUFFERED="1", ISTPU_CLIENT="python",
                # every program into the persistent cache, not only those that
                # took over a second to compile (PR 21: 123 of 140 did not)
@@ -214,8 +216,8 @@ def servers(c: dict, run_dir: str, seed: int, pool: int, *, kv_quant: str = "non
     if shm_free < (pool + 1) << 30:
         raise RunFailure(f"/dev/shm has {shm_free} bytes free, the store pool needs "
                          f"{pool} GiB: not shrinking the population")
-    page_kb = max(16, costs.kv_bytes_per_token(config) * serve_cfg["block_tokens"]
-                  // config["num_hidden_layers"] // 1024)
+    page_kb = max(16, c["counts"].store_page_bytes(
+        config, serve_cfg["block_tokens"]) // 1024)
     svc, mng, port = free_port(), free_port(), free_port()
     shm_prefix = f"istpu_bench_{os.getpid()}"
     try:
@@ -474,6 +476,32 @@ def main() -> int:
         return serve.returncode if serve and serve.returncode in (2, 4) else 1
 
 
+def in_cell(metric: dict, cell_name: str) -> bool:
+    return "workloads" not in metric or cell_name in metric["workloads"]
+
+
+def read_layer_metrics(manifest: dict, cell_name: str, ctx: dict) -> dict:
+    """One reader each.  A metric is left out of the cell where its reader
+    finds nothing to read, and where the configuration's count module does
+    not count what it needs (``costs.NotCounted``)."""
+    reported = {m["name"] for m in manifest["end_to_end"] if in_cell(m, cell_name)}
+    layer = {}
+    for m in manifest["per_layer"]:
+        # with no list of its own a metric belongs to every cell that reports
+        # the end-to-end metric it moves, those that later PRs add too
+        if not in_cell(m, cell_name) or m["moves"] not in reported:
+            continue
+        spec = load_json(HERE, "metrics", f"{m['name']}.json")
+        try:
+            value = ctx["reader"](spec["reader"]).read(ctx)
+        except costs.NotCounted as e:
+            say(f"{m['name']} left out: {e}")
+            continue
+        if value is not None:
+            layer[m["name"]] = {"value": value, "unit": m["unit"]}
+    return layer
+
+
 def report(args, c, device, plan, res, chk, setup_s, run_dir) -> int:
     manifest, wl, cell = c["manifest"], c["wl"], c["cell"]
     traffic, config = c["traffic"], c["config"]
@@ -499,17 +527,18 @@ def report(args, c, device, plan, res, chk, setup_s, run_dir) -> int:
     e2e["setup_s"] = setup_s
 
     # -- correct: exact things inside the window, the probes outside it -----------
-    checks = []   # (what, value, limit, ok)
+    checks = []   # (name, what, value, limit, ok)
 
-    def check(what, value, limit, ok):
-        checks.append((what, value, limit, ok))
+    def check(name, what, value, limit, ok):
+        checks.append((name, what, value, limit, bool(ok)))
         say(f"check {'ok  ' if ok else 'FAIL'} {what}: {value} (limit {limit})")
 
-    check("requests failed (non-200, short stream, error)", len(failed), 0, not failed)
+    check("requests_failed", "requests failed (non-200, short stream, error)",
+          len(failed), 0, not failed)
     for fam in ("dropped", "degraded"):
         bad = {k: v for k, v in res["after"][fam].items() if v}
-        check(f"store {fam} counters", bad or 0, 0, not bad)
-    check("store circuit", res["health"].get("store_circuit"), "closed",
+        check(f"store_{fam}", f"store {fam} counters", bad or 0, 0, not bad)
+    check("store_circuit", "store circuit", res["health"].get("store_circuit"), "closed",
           res["health"].get("store_circuit") == "closed")
     load_delta = promtext.delta(res["after"]["prefix"], res["before_load"]["prefix"])
     sent = sum(r["prompt_tokens"] for r in rows)
@@ -519,19 +548,24 @@ def report(args, c, device, plan, res, chk, setup_s, run_dir) -> int:
         # prompts reuse nothing, exactly
         top = sent + sum(r["prompt_tokens"] for r in dropped)
         got = sum(load_delta.values())
-        check("local + store + computed prompt tokens", got, f"{sent}..{top}",
-              sent <= got <= top)
+        check("prompt_tokens", "local + store + computed prompt tokens", got,
+              f"{sent}..{top}", sent <= got <= top)
         reused = load_delta.get("local", 0) + load_delta.get("store", 0)
-        check("prompt tokens reused by unshared prompts", reused, 0, reused == 0)
+        check("unshared_reused", "prompt tokens reused by unshared prompts", reused, 0,
+              reused == 0)
     else:
-        check("local + store + computed prompt tokens", sum(load_delta.values()),
-              sent, sum(load_delta.values()) == sent)
-    check("platform", device["platform"], "tpu", device["platform"] == "tpu")
+        check("prompt_tokens", "local + store + computed prompt tokens",
+              sum(load_delta.values()), sent, sum(load_delta.values()) == sent)
+    # a rehearsal prints no result line whatever it finds; its `correct` says
+    # whether every other comparison held on the CPU
+    want = "cpu" if args.rehearse else "tpu"
+    check("platform", "platform", device["platform"], want, device["platform"] == want)
     limit = config["check"]["logprob_rms_limit"]
     f32 = chk["f32"]
-    check("probe log-probabilities compared", f32["n_values"], ">= 160",
+    check("probe_values", "probe log-probabilities compared", f32["n_values"], ">= 160",
           f32["n_values"] >= 160)
-    check("RMS(server logprob - f32 reference logprob)", f32["rms"], limit,
+    check("logprob_rms", f"RMS(server logprob - f32 reference logprob), reference "
+          f"{chk['reference']}", f32["rms"], limit,
           limit is not None and f32["rms"] <= limit)
     # not part of `correct`: with seeded weights the top logits lie within
     # bf16 rounding of each other, and a sound run read 1 of 32 (PR 24)
@@ -542,16 +576,18 @@ def report(args, c, device, plan, res, chk, setup_s, run_dir) -> int:
     want_store = traffic.get("min_store_probes", 0)
     if want_store:
         pairs = pair_check(plan, res)
-        check("re-ask probes paired (first ask from HBM, second from the store)",
-              pairs["formed"], f">= {want_store}", pairs["formed"] >= want_store)
+        check("pairs_formed", "re-ask probes paired (first ask from HBM, second from "
+              "the store)", pairs["formed"], f">= {want_store}",
+              pairs["formed"] >= want_store)
         lim = config["check"]["pair_logprob_max_abs_limit"]
-        check("max |logprob from store pages - logprob from HBM pages|, "
-              f"{pairs['n_values']} values", pairs["max_abs"], lim,
+        check("pair_logprob_max_abs", "max |logprob from store pages - logprob from "
+              f"HBM pages|, {pairs['n_values']} values", pairs["max_abs"], lim,
               lim is not None and pairs["max_abs"] <= lim)
-        check("top-5 tokens of one answer missing from the other", pairs["unmatched"],
-              0, pairs["unmatched"] == 0)
+        check("pair_unmatched", "top-5 tokens of one answer missing from the other",
+              pairs["unmatched"], 0, pairs["unmatched"] == 0)
         say(f"pair max_abs per pair: {pairs['per_pair_max_abs']}")
     correct = all(ok for *_, ok in checks)
+    say(f"correct: {correct}")
 
     # -- per-layer metrics: one reader each, from counters, rows and the trace ----
     post = load_json(run_dir, "post.json")
@@ -564,33 +600,21 @@ def report(args, c, device, plan, res, chk, setup_s, run_dir) -> int:
             say(f"trace (rehearsal): {trace['error']}")
             trace = None
     ctx = {"cell": cell, "traffic": traffic, "config": config, "stats": stats,
-           "costs": costs, "peaks": costs.peaks(device["kind"]) if not args.rehearse else {},
+           "costs": c["counts"], "peaks": costs.peaks(device["kind"]) if not args.rehearse else {},
            "rows": measured, "all_rows": rows, "window": (w0, w1),
            "prefix_delta": promtext.delta(res["after"]["prefix"], res["before"]["prefix"]),
            "engine_before": res["before"]["engine"], "engine_after": res["after"]["engine"],
            "server_rows": res["server_rows"], "trace": trace,
            "trace_span": res["trace_span"], "prefill_chunk": prefill_chunk,
-           "reader": lambda name: load_module(os.path.join(HERE, "readers", f"{name}.py"))}
-    def in_cell(m):
-        return "workloads" not in m or wl["name"] in m["workloads"]
-
-    reported = {m["name"] for m in manifest["end_to_end"] if in_cell(m)}
-    layer = {}
-    for m in manifest["per_layer"]:
-        # with no list of its own a metric belongs to every cell that reports
-        # the end-to-end metric it moves, those that later PRs add too
-        if not in_cell(m) or m["moves"] not in reported:
-            continue
-        spec = load_json(HERE, "metrics", f"{m['name']}.json")
-        value = ctx["reader"](spec["reader"]).read(ctx)
-        if value is not None:
-            layer[m["name"]] = {"value": value, "unit": m["unit"]}
+           "reader": lambda name: family.load_module(
+               os.path.join(HERE, "readers", f"{name}.py"))}
+    layer = read_layer_metrics(manifest, wl["name"], ctx)
     say(f"end to end: {json.dumps(e2e)}")
     say(f"per layer: {json.dumps({k: v['value'] for k, v in layer.items()})}")
     with open(os.path.join(run_dir, "rows.json"), "w") as f:
         json.dump({"rows": [{k: v for k, v in r.items() if k != "payload"}
                             for r in rows], "w0": w0, "w1": w1,
-                   "server_rows": res["server_rows"], "checks": checks,
+                   "server_rows": res["server_rows"], "checks": checks, "correct": correct,
                    "e2e": e2e, "layer": layer, "trace": trace,
                    "trace_span": res["trace_span"],
                    "engine_after": res["after"]["engine"]}, f)
@@ -603,7 +627,7 @@ def report(args, c, device, plan, res, chk, setup_s, run_dir) -> int:
     else:
         metrics = {}
         for m in manifest["end_to_end"]:
-            if not in_cell(m):
+            if not in_cell(m, wl["name"]):
                 continue
             if m["name"] not in e2e:
                 raise RunFailure(f"nothing to compute {m['name']} from")
@@ -615,6 +639,15 @@ def report(args, c, device, plan, res, chk, setup_s, run_dir) -> int:
     if args.trace:
         dev["busy_s"], dev["window_s"] = trace["busy_s"], trace["window_s"]
         result["breakdown"] = trace["breakdown"]
+    # every number compared beside its limit: last in the line, and the last
+    # lines on standard error (what the driver keeps of a run that is not correct)
+    result["compared"] = {name: {"value": value, "limit": limit, "ok": ok}
+                          for name, _, value, limit, ok in checks}
+    sys.stdout.flush()
+    for name, _, value, limit, ok in checks:
+        print(f"compared {name}: {value} limit {limit} {'ok' if ok else 'FAIL'}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -13,6 +13,9 @@ the main thread, and adds the three things only the chip's own process can do:
   memory, free the server's arrays, run the plain reference on the probes and
   reduce the trace.  No second runtime start, and nothing timed.
 
+The reference and the counts are the configuration's own
+(``harness/family.py``): this file knows of no model family.
+
 Everything goes to files in ``--run-dir``; run.py reads them.
 """
 
@@ -27,7 +30,7 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [os.path.dirname(HERE), os.path.join(HERE, "harness"),
-                os.path.join(HERE, "reference"), os.path.join(HERE, "trace")]
+                os.path.join(HERE, "trace")]
 
 
 def write_json(path: str, obj) -> None:
@@ -68,7 +71,9 @@ def main() -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--chips", type=int, default=1)
     ap.add_argument("--rehearse", type=int, default=0)
-    ap.add_argument("--control", default="none", choices=["none", "ref-int8"])
+    ap.add_argument("--control", default="none",
+                    help="none, or ref-<precision>: the reference recomputed in "
+                         "that lower precision, in the program's place")
     ap.add_argument("serve_argv", nargs=argparse.REMAINDER)
     args = ap.parse_args()
     serve_argv = [a for a in args.serve_argv if a != "--"]
@@ -78,7 +83,9 @@ def main() -> int:
     import jax
 
     import costs
+    import family
 
+    counts = family.counts(config)
     devs = jax.devices()
     stats = devs[0].memory_stats() or {}
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
@@ -90,8 +97,8 @@ def main() -> int:
             return 2
         costs.peaks(device["kind"])        # an unknown device is an error
         sv = config["serve"]
-        need = (costs.weight_bytes(config) + sv["n_blocks"] * sv["block_tokens"]
-                * costs.kv_bytes_per_token(config))
+        need = (counts.weight_bytes(config) + sv["n_blocks"] * sv["block_tokens"]
+                * counts.cache_bytes_per_token(config))
         device["fill"] = need / device["bytes_limit"]
         if device["fill"] < sv["min_fill"]:
             print(f"serve_proc: weights + cache fill {device['fill']:.1%} of "
@@ -118,23 +125,24 @@ def main() -> int:
     # -- the output check, outside any timed window ------------------------
     probes_f = os.path.join(args.run_dir, "probes.json")
     if os.path.exists(probes_f):
-        import dense
-
+        ref_mod = family.reference(config)
         for a in jax.live_arrays():    # the server is gone; its weights and
             a.delete()                 # cache must not sit beside the reference's
         with open(probes_f) as f:
             probes = json.load(f)
-        s = costs.sizes(config)
+        s = counts.sizes(config)
         t0 = time.time()
-        params = dense.draw_weights(s, args.seed)
-        ref = dense.reference_logprobs(dense.make_forward(s, "f32"), params, probes)
-        check = {"f32": dense.compare(probes, ref)}
-        if args.control == "ref-int8":
+        params = ref_mod.draw_weights(s, args.seed)
+        ref = ref_mod.reference_logprobs(ref_mod.make_forward(s, "f32"), params, probes)
+        check = {"reference": family.reference_name(config),
+                 "f32": ref_mod.compare(probes, ref)}
+        if args.control.startswith("ref-"):
             # the control: the reference itself one precision down, put in the
             # program's place and held to the same comparison
-            low = dense.reference_logprobs(dense.make_forward(s, "int8"), params, probes)
-            check["control_ref_int8"] = dense.compare(
-                dense.control_answers(low, probes), ref)
+            low_p = args.control[len("ref-"):]
+            low = ref_mod.reference_logprobs(ref_mod.make_forward(s, low_p), params, probes)
+            check[f"control_ref_{low_p}"] = ref_mod.compare(
+                ref_mod.control_answers(low, probes), ref)
         check["seconds"] = time.time() - t0
         write_json(os.path.join(args.run_dir, "check.json"), check)
 

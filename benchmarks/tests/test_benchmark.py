@@ -25,6 +25,7 @@ for sub in ("harness", "generators", "reference", "trace"):
     sys.path.insert(0, os.path.join(BENCH, sub))
 
 import costs  # noqa: E402
+import family  # noqa: E402
 import mix  # noqa: E402
 import reduce as trace_reduce  # noqa: E402
 import stats  # noqa: E402
@@ -223,6 +224,30 @@ def test_the_program_serving_int8_store_pages_is_refused_and_sound_pages_read_ze
         assert g["max_abs"] > max(limit, 0.005)
 
 
+def test_a_run_whose_store_reads_are_broken_underneath_is_not_correct():
+    """The rest of a run without the look for a chip (``--rehearse 1``), with
+    the timed path broken underneath: the store flips a byte of every page it
+    is asked to read back (its own fault injector, through the environment),
+    so the client's checksum refuses the pages and the server recomputes.
+    Every request still succeeds with sound tokens; `correct` must be false
+    all the same.  (test_family.py sees it true on a sound rehearsal.)"""
+    cell, seed = next(c for c in CELLS if c.endswith("doc-reask")), 2**31 + 5
+    env = dict(os.environ, JAX_PLATFORMS="cpu", ISTPU_FAULTS=json.dumps(
+        [{"op": "GET_DESC", "action": "corrupt", "times": -1}]))
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell, "--seed",
+         str(seed), "--seconds", "6", "--trace", "0", "--rehearse", "1"],
+        capture_output=True, text=True, timeout=900, env=env)
+    assert out.returncode == 3, out.stdout[-3000:] + out.stderr[-2000:]
+    with open(os.path.join(ROOT, "chiprun_out", "bench", f"{cell}.s{seed}.t0",
+                           "rows.json")) as f:
+        rows = json.load(f)
+    assert rows["correct"] is False
+    failed = {name for name, *_, ok in rows["checks"] if not ok}
+    assert failed == {"store_degraded", "pairs_formed"}, failed
+    assert "correct: False" in out.stdout
+
+
 # -- percentile and TPOT arithmetic on hand-made rows ----------------------------------
 
 def row(due, first, last, tokens, events=None, ok=True):
@@ -359,6 +384,15 @@ def test_manifest_names_resolve_to_files_and_use_allowed_characters():
         spec = load("configs", f"{c['name']}.json")
         assert spec["source"] == c["source"]
         assert all(NAME.match(k) for k in c["reduced"])
+        # the cut: depth, the experts held, the vocabulary; a share cut states
+        # what was published and the deployment (test_family.py has the cases)
+        assert family.cut_problems(c, spec) == []
+        for key, where in (("costs", "counts"), ("rehearse", "configs")):
+            if key in spec:
+                assert os.path.exists(os.path.join(
+                    BENCH, where, spec[key] + ("" if key == "rehearse" else ".py")))
+        assert os.path.exists(os.path.join(
+            BENCH, "reference", f"{family.reference_name(spec)}.py"))
     used = set()
     for w in m["workloads"]:
         assert NAME.match(w["name"]) and NAME.match(w["traffic"]) and len(w["why"]) <= 200

@@ -11,7 +11,8 @@ What a v5e trace holds (looked at by hand, PR 24): one plane per chip named
 program, named ``jit_<function>(<fingerprint>)``, and whose line ``XLA Ops``
 has one event per device operation; host threads are lines of the plane
 ``/host:CPU``.  ``programs.json`` maps program names to the classes the
-readers ask for.  A program in flight when the profiler starts or stops is
+readers ask for; a family whose programs have other names adds a file of the
+same form under ``programs.d/`` (``load_table``).  A program in flight when the profiler starts or stops is
 recorded cut: it begins at the trace's first instant or ends at its last, and
 is shorter than it ran.  Such events count as busy time but not as
 executions of a program: a time per execution divides whole runs only.
@@ -103,10 +104,22 @@ def strip_id(name: str) -> str:
     return re.sub(r"\(\d+\)$", "", name)
 
 
+def load_table(here: str = HERE) -> dict:
+    """``programs.json``, then every ``programs.d/*.json`` in the order of
+    their names: each adds patterns to the classes (or a class); none takes a
+    pattern away."""
+    table: Dict[str, list] = {}
+    for path in [os.path.join(here, "programs.json")] + sorted(
+            glob.glob(os.path.join(here, "programs.d", "*.json"))):
+        with open(path) as f:
+            for cls, patterns in json.load(f).items():
+                table.setdefault(cls, []).extend(patterns)
+    return table
+
+
 def reduce(trace: dict, table: dict = None) -> dict:
     if table is None:
-        with open(os.path.join(HERE, "programs.json")) as f:
-            table = json.load(f)
+        table = load_table()
     devices = [p for p in trace["planes"] if p["name"].startswith("/device:TPU")]
     if not devices:
         raise ValueError("the trace has no /device:TPU plane: nothing ran on "
