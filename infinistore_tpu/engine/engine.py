@@ -629,6 +629,12 @@ class InferenceEngine:
         (parallel/sharding.py rationale).  Page bookkeeping, the store
         protocol, and the scheduler are unchanged: they never see the mesh."""
         assert pc.n_layers == cfg.n_layers
+        if mesh is not None and pc.planes != 2:
+            # the mesh path shards the cache over its KV-head axis and the
+            # weights by the dense specs: a page of one plane has neither
+            raise ValueError(
+                f"mesh serving shards K and V by head; a page of "
+                f"{pc.planes} plane(s) is served on one device")
         self.mesh = mesh
         self.cfg = cfg
         self.pc = pc
@@ -768,10 +774,14 @@ class InferenceEngine:
         self._lora_tree = lora.tree if lora is not None else None
         lora_kw = {}
         if lora is not None:
-            assert prefill_fn is None and decode_fn is None and verify_fn is None, (
-                "LoRA composes the built-in Llama family; custom families "
-                "must thread lora/adapter_ids through their own forwards"
-            )
+            if not (prefill_fn is None and decode_fn is None
+                    and verify_fn is None):
+                # a real error, not an assert: under python -O the bank
+                # would be handed to forwards that do not take it
+                raise ValueError(
+                    "LoRA composes the built-in Llama family; custom families "
+                    "must thread lora/adapter_ids through their own forwards"
+                )
             lora_kw = {"lora_scale": lora.scale}
         # pallas_tp: attention runs the Pallas kernels head-locally inside
         # a shard_map over tp instead of the partitioned XLA paths — the
@@ -1824,6 +1834,7 @@ class InferenceEngine:
                 steps=chunk, rows=B, padded_rows=Bp,
                 width_pages=block_table.shape[1], block_tokens=T,
                 live_tokens=int(pos[:B].sum()),
+                expert_routing=getattr(self.cfg, "expert_routing", None),
             )
             _stepprof.note_tokens(chunk * B)
             if penalized:

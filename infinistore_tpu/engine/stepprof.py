@@ -93,7 +93,7 @@ MAX_STEP_IDS = 64
 
 # what ``note_decode`` sums, per step record and over the lifetime
 DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
-                 "table_token_steps")
+                 "table_token_steps", "expert_pairs", "experts_expected")
 
 # the key that counts operations in the transfer's running totals
 _STORE_COUNT = {"push": "pushes", "load": "loads"}
@@ -232,13 +232,21 @@ def note_sync(kind: str, n: int = 1) -> None:
 
 
 def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
-                block_tokens: int, live_tokens: int) -> None:
+                block_tokens: int, live_tokens: int,
+                expert_routing: Optional[tuple] = None) -> None:
     """Count ONE decode-scan dispatch with what the engine knows at the
     call: ``steps`` scan steps over ``rows`` live rows in a batch bucket
     of ``padded_rows``, a block table ``width_pages`` wide, and
     ``live_tokens`` = the rows' context lengths summed at the dispatch's
     start.  ``table_token_steps`` is what the XLA attention reads
     whatever ``seq_lens`` says (every padded row, the whole width).
+    ``expert_routing`` = (expert layers, experts a token, experts a layer)
+    of a model with routed experts: ``expert_pairs`` counts the (token,
+    expert) pairs the steps route (exact: k a row a layer, no token is
+    dropped) and ``experts_expected`` the distinct experts they touch, a
+    layer a step summed, as the EXPECTATION ``E * (1 - (1 - k/E) ** rows)``
+    from the counted rows: the choice is made on the device and reading it
+    back would be a sync in the decode loop.  Both stay 0 for a dense model.
     Summed under ``rec["decode"]``; the benchmark's ``engine.decode_*``
     readers take the window's gain of each."""
     rec = _ACTIVE.get()
@@ -251,6 +259,11 @@ def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
     b["row_steps"] += rows * steps
     b["live_token_steps"] += live_tokens * steps
     b["table_token_steps"] += padded_rows * width_pages * block_tokens * steps
+    if expert_routing is not None:
+        layers, k, n_experts = expert_routing
+        b["expert_pairs"] += rows * k * layers * steps
+        b["experts_expected"] += (
+            n_experts * (1.0 - (1.0 - k / n_experts) ** rows) * layers * steps)
 
 
 def enter(name: Optional[str]) -> float:

@@ -7,6 +7,15 @@ under ``jit``:
 
     kv : [n_layers, 2(K|V), n_kv_heads, n_blocks, block_tokens, head_dim]
 
+``PagedCacheConfig`` states the page once -- ``planes`` x ``n_kv_heads`` x
+``block_tokens`` x ``head_dim`` -- and the cache, the token and chunk writes,
+the gather, the transfer engine, the store's page size and the prefix keys all
+follow from it.  The dense grouped-query families keep the two planes K and V
+by head shown above.  A latent-attention family (models/mla_moe.py) keeps ONE
+plane of one row per token, the normalised latent and the rotated key all
+heads share: ``[n_layers, 1, 1, n_blocks, block_tokens, 576]``, a page of
+``[T, 576]`` and half the bytes that "one head of 576" under K|V would take.
+
 Heads sit OUTSIDE the block axis so a (head, page) tile [block_tokens,
 head_dim] = [16, 128] is contiguous -- exactly the bf16 min tile.  The decode
 step reads and writes this one array by index and never slices a layer out
@@ -45,25 +54,39 @@ class PagedCacheConfig:
     n_blocks: int
     block_tokens: int = 16
     dtype: jnp.dtype = jnp.bfloat16
+    # planes of a page: 2 = K and V by head (the dense families); 1 = one
+    # row per token and no K|V split (a latent page)
+    planes: int = 2
+
+    @classmethod
+    def for_model(cls, cfg, n_blocks: int, block_tokens: int = 16
+                  ) -> "PagedCacheConfig":
+        """The cache of a model: the page is what the model's config says
+        it writes per token and layer (``cfg.kv_page`` = planes, heads,
+        width), so no caller rebuilds it from head counts."""
+        planes, heads, width = cfg.kv_page
+        return cls(n_layers=cfg.n_layers, n_kv_heads=heads, head_dim=width,
+                   n_blocks=n_blocks, block_tokens=block_tokens,
+                   dtype=cfg.dtype, planes=planes)
 
     @property
     def page_bytes(self) -> int:
-        """Bytes of one (layer, chunk) page: K+V, all heads."""
-        return 2 * self.block_tokens * self.n_kv_heads * self.head_dim * np.dtype(
-            jnp.dtype(self.dtype)
-        ).itemsize
+        """Bytes of one (layer, chunk) page: every plane, all heads."""
+        return (self.planes * self.block_tokens * self.n_kv_heads
+                * self.head_dim * np.dtype(jnp.dtype(self.dtype)).itemsize)
 
     @property
     def page_shape(self) -> Tuple[int, ...]:
-        """Shape of one (layer, chunk) page as stored: [2, H_kv, T, D]."""
-        return (2, self.n_kv_heads, self.block_tokens, self.head_dim)
+        """Shape of one (layer, chunk) page as stored: [planes, H_kv, T, D]."""
+        return (self.planes, self.n_kv_heads, self.block_tokens, self.head_dim)
 
 
 def init_cache(cfg: PagedCacheConfig, sharding=None) -> jax.Array:
     """Zeroed cache; with ``sharding`` it is created in its shards (a cache
     sized for a mesh need not fit one device first)."""
     return jnp.zeros(
-        (cfg.n_layers, 2, cfg.n_kv_heads, cfg.n_blocks, cfg.block_tokens, cfg.head_dim),
+        (cfg.n_layers, cfg.planes, cfg.n_kv_heads, cfg.n_blocks,
+         cfg.block_tokens, cfg.head_dim),
         dtype=cfg.dtype, device=sharding,
     )
 
@@ -81,20 +104,20 @@ def read_pages(cache: jax.Array, block_ids: jax.Array) -> jax.Array:
     return cache[:, :, :, block_ids]
 
 
-def write_token_kv(
+def write_token_rows(
     cache: jax.Array,
     layer: int,
     block_ids: jax.Array,
     slot_ids: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
+    rows: jax.Array,
 ) -> jax.Array:
     """Write one token per sequence into layer ``layer``.
 
     block_ids/slot_ids: [B] page id and in-page slot for each sequence's
-    current position; k/v: [B, n_kv_heads, head_dim].  The page ids must be
-    distinct (each sequence appends to a page of its own) or out of bounds
-    (pad rows: their write is dropped).
+    current position; rows: [B, planes, n_kv_heads, head_dim], the token's
+    row of every plane (K and V, or the one latent row).  The page ids must
+    be distinct (each sequence appends to a page of its own) or out of
+    bounds (pad rows: their write is dropped).
 
     The write is a read-modify-write of WHOLE pages: gather the B pages,
     put each token in its slot, scatter the pages back.  A scatter of the
@@ -106,14 +129,27 @@ def write_token_kv(
     donated cache is updated in place, and a step moves 16x the bytes of
     the rows it writes — tens of MB next to GBs of weights."""
     T = cache.shape[4]
-    kv = jnp.stack([k, v], axis=1)  # [B, 2, H, D]
     # advanced indices (layer, block_ids) are separated by slices, so the
-    # batch dim lands in FRONT: [B, 2, H, T, D]; out-of-bounds ids clamp
-    # on the gather and are dropped by the scatter
+    # batch dim lands in FRONT: [B, planes, H, T, D]; out-of-bounds ids
+    # clamp on the gather and are dropped by the scatter
     pages = cache[layer, :, :, block_ids]
     here = jnp.arange(T)[None, :] == slot_ids[:, None]  # [B, T]
-    pages = jnp.where(here[:, None, None, :, None], kv[:, :, :, None, :], pages)
+    pages = jnp.where(here[:, None, None, :, None], rows[:, :, :, None, :], pages)
     return cache.at[layer, :, :, block_ids].set(pages)
+
+
+def write_token_kv(
+    cache: jax.Array,
+    layer: int,
+    block_ids: jax.Array,
+    slot_ids: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+) -> jax.Array:
+    """``write_token_rows`` for the two planes K and V: k/v
+    [B, n_kv_heads, head_dim]."""
+    return write_token_rows(cache, layer, block_ids, slot_ids,
+                            jnp.stack([k, v], axis=1))  # [B, 2, H, D]
 
 
 def write_tokens_kv(
@@ -126,13 +162,13 @@ def write_tokens_kv(
 ) -> jax.Array:
     """Scatter a run of tokens per sequence into layer ``layer`` (the
     multi-token sibling of write_token_kv; used by the speculative-decode
-    verify step).
+    verify step, which only the K|V families have).
 
     block_ids/slot_ids: [B, S]; k/v: [B, S, n_kv_heads, head_dim].
     Distinct (page, slot) targets per token, so the flat scatter is exact.
     A run's tokens share pages, so this stays a scatter of token rows (a
     page-wise read-modify-write would lose all but one of them) and keeps
-    the relayout cost write_token_kv describes: speculation at a
+    the relayout cost write_token_rows describes: speculation at a
     deployment-sized cache is not measured (ROADMAP A5)."""
     B, S = block_ids.shape
     kv = jnp.stack([k, v], axis=2).reshape((B * S, 2) + k.shape[2:])
@@ -143,8 +179,8 @@ def write_tokens_kv(
 
 
 def prefill_to_pages(kv: jax.Array, n_pages: int, block_tokens: int) -> jax.Array:
-    """Reshape prefill KV [L, 2, S, H, D] (S = n_pages*block_tokens) into
-    pages [L, 2, H, n_pages, T, D]."""
+    """Reshape prefill KV [L, planes, S, H, D] (S = n_pages*block_tokens)
+    into pages [L, planes, H, n_pages, T, D]."""
     L, two, S, H, D = kv.shape
     assert S == n_pages * block_tokens, (S, n_pages, block_tokens)
     kv = kv.reshape(L, two, n_pages, block_tokens, H, D)
@@ -152,7 +188,8 @@ def prefill_to_pages(kv: jax.Array, n_pages: int, block_tokens: int) -> jax.Arra
 
 
 def pages_to_seq_kv(pages: jax.Array) -> jax.Array:
-    """[L, 2, H, n, T, D] -> [L, 2, 1, n*T, H, D] (batch-1 sequence KV)."""
+    """[L, planes, H, n, T, D] -> [L, planes, 1, n*T, H, D] (batch-1
+    sequence KV)."""
     L, two, H, n, T, D = pages.shape
     return jnp.transpose(pages, (0, 1, 3, 4, 2, 5)).reshape(L, two, 1, n * T, H, D)
 

@@ -15,6 +15,10 @@ time), so quantization error never crosses heads.  Payload layout per page::
 
     [2*H float32 scales][2*H*T*D int8 values]      (page_quant_bytes total)
 
+Only pages of two planes, K and V by head, have this scale: the transfer
+engine refuses ``quant`` for a page of any other make (a latent page of one
+plane) when it is built, so such a page never meets a wrong scale.
+
 Accuracy: KV values are post-RMSNorm projections with small dynamic range;
 per-head int8 keeps relative error ~1e-2, which leaves greedy decode tokens
 unchanged on every model we test (tests/test_kv.py::test_quantized_*).

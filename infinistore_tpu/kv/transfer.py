@@ -35,7 +35,7 @@ from .quant import dequantize_pages_jit, page_quant_bytes, quantize_pages
 @partial(jax.jit, donate_argnums=(0,))
 def _scatter_stacked(cache: jax.Array, block_ids: jax.Array,
                      stacked: jax.Array) -> jax.Array:
-    """Store-layout pages [L, n, 2, H, T, D] into ``block_ids``'s slots of
+    """Store-layout pages [L, n, planes, H, T, D] into ``block_ids``'s slots of
     the DONATED cache: a store hit updates the cache in place.  Eager, the
     scatter needs a second whole cache, and a cache sized like a deployment
     (most of HBM) cannot exist twice."""
@@ -85,6 +85,14 @@ class KVTransferEngine:
         self.pipeline_groups = pipeline_groups
         if quant not in (None, "int8"):
             raise ValueError(f"unsupported quant mode: {quant!r}")
+        if quant and cfg.planes != 2:
+            # kv/quant.py scales per (K|V, head); a page of another make
+            # (one latent plane) has no such scale, and a wrong one would
+            # be served: refuse here, at start-up
+            raise ValueError(
+                f"kv quant {quant!r} scales pages per (K|V, head); a page "
+                f"of {cfg.planes} plane(s) goes to the store as it is "
+                f"(--kv-quant none)")
         self.quant = quant
         # bytes of one page as it crosses the wire / sits in the pool
         self.wire_page_bytes = page_quant_bytes(cfg) if quant else cfg.page_bytes
@@ -226,8 +234,8 @@ class KVTransferEngine:
         (jax arrays are immutable) and hand them to a background pusher
         while the next chunk computes."""
         ids = jnp.asarray(np.asarray(block_ids, dtype=np.int32))
-        gathered = read_pages(cache, ids)  # [L, 2, H, n, T, D]
-        # -> [L, n, 2, H, T, D] so each (layer, chunk) page is contiguous
+        gathered = read_pages(cache, ids)  # [L, planes, H, n, T, D]
+        # -> [L, n, planes, H, T, D]: each (layer, chunk) page contiguous
         pages = jnp.transpose(gathered, (0, 3, 1, 2, 4, 5))
         if self.quant:
             # fuse quantize+pack on device; the D2H then moves half the

@@ -30,6 +30,12 @@ from .moe import (
     moe_verify_forward,
     scaled_moe,
 )
+from .mla_moe import (
+    MlaMoeConfig,
+    init_mla_moe_params,
+    mla_moe_decode_forward,
+    mla_moe_prefill_forward,
+)
 from .attention import (
     apply_rope,
     causal_attention,
@@ -43,7 +49,24 @@ from .hf import (
     params_from_hf,
 )
 
+def family_of(cfg) -> dict:
+    """What a model's config type brings besides the dense defaults: its
+    ``init`` (weights from a key) and the engine's ``fns`` (the forwards the
+    engine's hooks take).  A family with ``fns`` has no verify step, no LoRA
+    threading and no mesh specs: ``serve`` refuses those at start-up."""
+    if isinstance(cfg, MlaMoeConfig):
+        return {"init": init_mla_moe_params,
+                "fns": {"prefill_fn": mla_moe_prefill_forward,
+                        "decode_fn": mla_moe_decode_forward}}
+    return {"init": init_params, "fns": {}}
+
+
 __all__ = [
+    "MlaMoeConfig",
+    "init_mla_moe_params",
+    "mla_moe_prefill_forward",
+    "mla_moe_decode_forward",
+    "family_of",
     "MoEConfig",
     "MIXTRAL_8X7B",
     "TINY_MOE",

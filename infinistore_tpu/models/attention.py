@@ -282,15 +282,16 @@ def causal_attention(
 
 def gather_layer_kv(
     cache: jax.Array, layer: int, block_table: jax.Array
-) -> tuple[jax.Array, jax.Array]:
-    """One layer's keys and values for a block table, gathered by index
+) -> tuple[jax.Array, ...]:
+    """One layer's pages for a block table, one array per plane of the
+    page (keys and values; or the one latent plane), gathered by index
     straight out of the whole cache.
 
-    cache: [L, 2, H_kv, n_blocks, T, D]; layer: static; block_table:
-    [B, max_pages] int32 -> k, v: [B, max_pages * T, H_kv, D].
+    cache: [L, planes, H_kv, n_blocks, T, D]; layer: static; block_table:
+    [B, max_pages] int32 -> planes x [B, max_pages * T, H_kv, D].
 
-    Layer, K|V and page id are all indices of the gather, as in
-    kv/cache.py:write_token_kv.  A ``cache[layer]`` formed first is a
+    Layer, plane and page id are all indices of the gather, as in
+    kv/cache.py:write_token_rows.  A ``cache[layer]`` formed first is a
     slice, and XLA:TPU does not fuse a slice into a gather's operand: it
     copies the layer's slab, and K's and V's halves of it, in every layer
     of every step (PERF.md, PR 27).  The advanced indices are split by a
@@ -298,12 +299,88 @@ def gather_layer_kv(
     Out-of-bounds page ids (pad rows) clamp; callers mask by length."""
     B, max_pages = block_table.shape
     Hkv, _, T, D = cache.shape[2:]
-    k, v = (
-        jnp.moveaxis(cache[layer, kv, :, block_table], 2, 3).reshape(
+    return tuple(
+        jnp.moveaxis(cache[layer, plane, :, block_table], 2, 3).reshape(
             B, max_pages * T, Hkv, D)
-        for kv in (0, 1)
+        for plane in range(cache.shape[1])
     )
-    return k, v
+
+
+def _latent_kvb(w_kvb: jax.Array, n_heads: int, nope: int):
+    """The up-projection [rank, H * (nope + v)] as its keys' and its values'
+    halves by head: [rank, H, nope], [rank, H, v]."""
+    w = w_kvb.reshape(w_kvb.shape[0], n_heads, -1)
+    return w[..., :nope], w[..., nope:]
+
+
+def latent_expanded_attention(
+    q: jax.Array,
+    ckr: jax.Array,
+    w_kvb: jax.Array,
+    rank: int,
+    nope: int,
+    q_offset: int = 0,
+    prefix_pad: int | None = None,
+    prefix_len: jax.Array | None = None,
+) -> jax.Array:
+    """Latent attention, EXPANDED: every row of the page is up-projected to
+    its key and value by head, then plain causal attention (the prefill
+    path: many queries share the up-projection's cost).
+
+    q: [B, Sq, H, nope + rope] (rope part rotated); ckr: [B, Sk, rank +
+    rope], the page's rows (normalised latent; the rotated key all heads
+    share) of the prefix and of the queries' own tokens; w_kvb: [rank,
+    H * (nope + v)].  k_i = [c W^K_i; k_r], v_i = c W^V_i, scores over
+    sqrt(nope + rope).  Masking as ``causal_attention``.  -> [B, Sq, H, v]."""
+    B, Sk, _ = ckr.shape
+    H = q.shape[2]
+    with jax.named_scope("istpu.mla.expand"):
+        wk, wv = _latent_kvb(w_kvb, H, nope)
+        c, k_r = ckr[..., :rank], ckr[..., rank:]
+        k = jnp.concatenate(
+            [jnp.einsum("bsr,rhn->bshn", c, wk),
+             jnp.broadcast_to(k_r[:, :, None, :], (B, Sk, H, k_r.shape[-1]))],
+            axis=-1)
+        v = jnp.einsum("bsr,rhv->bshv", c, wv)
+        return causal_attention(q, k, v, q_offset=q_offset,
+                                prefix_pad=prefix_pad, prefix_len=prefix_len)
+
+
+def latent_absorbed_decode_attention(
+    q: jax.Array,
+    cache: jax.Array,
+    layer: int,
+    block_table: jax.Array,
+    seq_lens: jax.Array,
+    w_kvb: jax.Array,
+    rank: int,
+    nope: int,
+) -> jax.Array:
+    """Latent attention, ABSORBED: one token's queries against the paged
+    one-plane cache without expanding a single key (the decode path: one
+    query per head, so the up-projection is moved onto the query and onto
+    the weighted sum).
+
+    q: [B, H, nope + rope]; cache: [L, 1, 1, n_blocks, T, rank + rope];
+    q~_i = q_n,i (W^K_i)^T; scores (q~_i . c + q_r,i . k_r) / sqrt(nope +
+    rope): H queries over ONE key row per token, read once for all heads;
+    o_i = (sum p c) W^V_i.  The same function of the page as
+    ``latent_expanded_attention``.  -> [B, H, v]."""
+    B, H, D = q.shape
+    with jax.named_scope("istpu.mla.absorb"):
+        wk, wv = _latent_kvb(w_kvb, H, nope)
+        (rows,) = gather_layer_kv(cache, layer, block_table)
+        rows = rows[:, :, 0]                               # [B, S_max, W]
+        q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :nope], wk)
+        qq = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)  # [B, H, W]
+        logits = jnp.einsum("bhw,bsw->bhs", qq, rows,
+                            preferred_element_type=jnp.float32)
+        logits = logits * (1.0 / np.sqrt(D))
+        mask = jnp.arange(rows.shape[1])[None, :] < seq_lens[:, None]
+        logits = jnp.where(mask[:, None, :], logits, -jnp.inf)
+        probs = jax.nn.softmax(logits, axis=-1).astype(rows.dtype)
+        o_lat = jnp.einsum("bhs,bsr->bhr", probs, rows[..., :rank])
+        return jnp.einsum("bhr,rhv->bhv", o_lat, wv)
 
 
 def paged_decode_attention_xla(

@@ -83,6 +83,12 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.head_dim_override or self.dim // self.n_heads
 
+    @property
+    def kv_page(self) -> Tuple[int, int, int]:
+        """(planes, heads, width) of what a token writes per layer: K and V
+        by KV head (kv/cache.py ``PagedCacheConfig.for_model``)."""
+        return (2, self.n_kv_heads, self.head_dim)
+
 
 # -- presets (Llama-3 shapes) --
 LLAMA3_8B = LlamaConfig()
@@ -123,9 +129,12 @@ def scaled(cfg: LlamaConfig, **kw) -> LlamaConfig:
     return replace(cfg, **kw)
 
 
-def load_config_file(path: str) -> Tuple[str, LlamaConfig, int]:
+def load_config_file(path: str) -> Tuple[str, Any, int]:
     """Resolve a checked-in model config file (``configs/*.json``) to
-    ``(model_id, cfg, seed)``: a preset of this module by name, the
+    ``(model_id, cfg, seed)``.  A file that names a ``family`` states the
+    source's sizes itself and is read by that family's module
+    (``models/mla_moe.py:config_from_file``); every other file names a dense
+    preset: a preset of this module by name, the
     ``published`` sizes it must agree with (so a jax-free launcher can read
     them from the file, and a file cannot quietly serve other widths), a
     ``reduced`` block that may cut ``n_layers`` only (widths are never cut
@@ -138,6 +147,13 @@ def load_config_file(path: str) -> Tuple[str, LlamaConfig, int]:
 
     with open(path) as f:
         spec = json.load(f)
+    if "family" in spec:
+        if spec["family"] != "deepseek_v3":
+            raise ValueError(f"{path}: family {spec['family']!r} is not one "
+                             f"infinistore_tpu.models computes")
+        from .mla_moe import config_from_file
+
+        return config_from_file(path, spec)
     base = globals().get(spec.get("preset"))
     if type(base) is not LlamaConfig:
         raise ValueError(f"{path}: preset {spec.get('preset')!r} is not a "

@@ -5,13 +5,18 @@ loads; a standalone framework owns its model zoo).  Design mirrors
 models/llama.py: params are a plain pytree with a stacked [n_layers] leaf
 axis, forwards are pure functions, bf16 matmuls sized for the MXU.
 
-The expert FFN is computed DENSELY here -- every expert runs on every token
-and the top-k gate zeros the rest.  That keeps shapes static and the XLA
-program branch-free (no capacity overflow, no token dropping), and it is the
-exact math the expert-parallel path (parallel/moe.py) reproduces with each
-device computing only its local experts and one psum over the ``ep`` axis.
-Top-k sparsity as a FLOP saving (all_to_all dispatch with capacity) is a
-serving-scale optimization layered on the same layout later.
+The module has ONE expert layer, ``routed_experts``: each token's k
+(token, expert) pairs are sorted by expert and the three matrices of the
+SwiGLU run as grouped matrix products over the experts that have tokens
+(``jax.lax.ragged_dot``), so a step reads the experts it touches and a
+prefill chunk spends k/E of the all-experts FLOPs.  Shapes stay static (k
+pairs a token, whatever the load), no token is dropped and no capacity is
+set.  ``moe_ffn`` (Mixtral: softmax over the top-k logits) and
+models/mla_moe.py's expert layer (sigmoid scores, a selection bias,
+normalised and scaled) differ in their gates only.  ``all_experts_ffn`` runs
+every expert on every token and lets the gate zero the rest: the CPU tests'
+oracle, and the math the expert-parallel path (parallel/moe.py) reproduces
+with each device computing its local experts and one psum over ``ep``.
 """
 
 from __future__ import annotations
@@ -37,6 +42,12 @@ class MoEConfig(LlamaConfig):
     # of n independent FFNs of the same input is exactly that).  0 =
     # Mixtral-style pure routing (param structure unchanged).
     n_shared_experts: int = 0
+
+    @property
+    def expert_routing(self) -> Tuple[int, int, int]:
+        """(expert layers, experts a token, experts a layer), for the step
+        profiler's routed-pair counts (engine/stepprof.note_decode)."""
+        return (self.n_layers, self.top_k, self.n_experts)
 
 
 MIXTRAL_8X7B = MoEConfig(
@@ -107,21 +118,71 @@ def top_k_gates(router_logits: jax.Array, top_k: int) -> jax.Array:
     return jnp.einsum("...k,...ke->...e", probs, onehot)
 
 
+def sigmoid_top_k(scores: jax.Array, bias: jax.Array, top_k: int,
+                  scaling: float) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores [N, E] (float32) -> (experts [N, k], weights [N, k]):
+    the k largest of ``scores + bias`` are chosen (the bias steers the choice
+    only), and their OWN scores, normalised to sum to one and times
+    ``scaling``, weigh them (``noaux_tc`` with one group)."""
+    _, idx = jax.lax.top_k(scores + bias, top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scaling
+    return idx.astype(jnp.int32), w
+
+
+def routed_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
+                   w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array
+                   ) -> jax.Array:
+    """Experts computed for the tokens routed to them.
+
+    x: [N, dim]; idx: [N, k] the expert of each of a token's k pairs;
+    weights: [N, k] float32; w_gate/w_up: [E, dim, ffn]; w_down: [E, ffn,
+    dim] -> [N, dim] = sum_k weights * SwiGLU_idx(x).
+
+    The N * k pairs are sorted by expert, so each expert's rows are one run;
+    ``ragged_dot`` multiplies each run by its expert's matrix and visits no
+    matrix whose run is empty.  The weighted pairs are summed per token in
+    float32.  Static shapes: N * k rows whatever the load."""
+    N, k = idx.shape
+    E = w_gate.shape[0]
+    flat = idx.reshape(N * k)
+    order = jnp.argsort(flat)                    # stable: pairs by expert
+    sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    xs = x[order // k]                           # [N * k, dim]
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, sizes))
+    h = h * jax.lax.ragged_dot(xs, w_up, sizes)
+    y = jax.lax.ragged_dot(h, w_down, sizes,
+                           preferred_element_type=jnp.float32)
+    y = y * weights.reshape(N * k)[order][:, None]
+    out = jnp.zeros((N * k, y.shape[-1]), jnp.float32).at[order].set(y)
+    return out.reshape(N, k, -1).sum(axis=1).astype(x.dtype)
+
+
+def all_experts_ffn(x: jax.Array, gates: jax.Array, w_gate: jax.Array,
+                    w_up: jax.Array, w_down: jax.Array) -> jax.Array:
+    """The oracle: every expert on every token, ``gates`` [N, E] (zeros off
+    the chosen experts) weighing them.  x: [N, dim] -> [N, dim]."""
+    h = jax.nn.silu(jnp.einsum("nd,edf->nef", x, w_gate))
+    h = h * jnp.einsum("nd,edf->nef", x, w_up)
+    out = jnp.einsum("nef,efd->ned", h, w_down)
+    return jnp.einsum("ned,ne->nd", out, gates.astype(x.dtype))
+
+
 def moe_ffn(layer: Params, x: jax.Array, top_k: int) -> jax.Array:
-    """Dense-compute MoE FFN.  x: [B, S, dim] -> [B, S, dim].
+    """Mixtral's MoE FFN.  x: [B, S, dim] -> [B, S, dim]: softmax over the
+    top-k router logits, the chosen experts computed for their tokens
+    (``routed_experts``).
 
     When the layer carries shared-expert weights (``ws_*``,
     DeepSeek-MoE style), their always-on FFN output adds to the routed
     sum UNGATED — the branch is static at trace time (pytree
     structure), so Mixtral-style layers compile exactly as before."""
-    gates = top_k_gates(
-        x.astype(jnp.float32) @ layer["router"], top_k
-    )  # [B, S, E] fp32
-    # all experts on all tokens: [B, S, E, ffn]
-    h = jax.nn.silu(jnp.einsum("bsd,edf->bsef", x, layer["w_gate"]))
-    h = h * jnp.einsum("bsd,edf->bsef", x, layer["w_up"])
-    out = jnp.einsum("bsef,efd->bsed", h, layer["w_down"])  # [B, S, E, dim]
-    routed = jnp.einsum("bsed,bse->bsd", out, gates.astype(x.dtype))
+    B, S, d = x.shape
+    flat = x.reshape(B * S, d)
+    vals, idx = jax.lax.top_k(flat.astype(jnp.float32) @ layer["router"], top_k)
+    routed = routed_experts(
+        flat, idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1),
+        layer["w_gate"], layer["w_up"], layer["w_down"]).reshape(B, S, d)
     if "ws_gate" in layer:
         routed = routed + _shared_expert_ffn(layer, x)
     return routed

@@ -2650,7 +2650,7 @@ def main(argv: Optional[List[str]] = None) -> None:
 
     from .engine import InferenceEngine
     from .kv import PagedCacheConfig
-    from .models import TINY, init_params, load_config_file
+    from .models import TINY, family_of, init_params, load_config_file
 
     mesh = None
     if args.tp < 1 or args.pp < 1:
@@ -2673,6 +2673,22 @@ def main(argv: Optional[List[str]] = None) -> None:
     def seeded(name: str) -> bool:
         return name == "tiny" or name.endswith(".json")
 
+    def refuse_for_family(name: str) -> None:
+        """A model family with its own forwards (a latent page, routed
+        experts) has no verify step, no mesh specs and no int8 page scale:
+        say so at start-up, never serve a wrong result."""
+        bad = [flag for flag, on in (
+            ("--tp/--pp", mesh is not None),
+            ("--kv-quant int8", args.kv_quant != "none"),
+            ("--draft-model", args.draft_model is not None),
+            ("--ngram-spec", args.ngram_spec)) if on]
+        if bad:
+            raise SystemExit(
+                f"{name}: this model family is served without "
+                f"{', '.join(bad)} (its page is not K and V by head and it "
+                f"has no verify step); pass --kv-quant none and drop the "
+                f"rest")
+
     def load_model(name: str, seed: int = 0, mesh=None):
         """Returns (model_id, cfg, params, engine_fns) — engine_fns routes
         MoE checkpoints (Mixtral) through the MoE forwards.  With ``mesh``,
@@ -2682,6 +2698,14 @@ def main(argv: Optional[List[str]] = None) -> None:
             model_id, cfg = name, TINY
             if name != "tiny":
                 model_id, cfg, seed = load_config_file(name)
+            fam = family_of(cfg)
+            if fam["fns"]:
+                # a family with forwards of its own: what it cannot do is
+                # refused before a weight is drawn
+                refuse_for_family(name)
+                return (model_id, cfg,
+                        fam["init"](cfg, jax.random.PRNGKey(seed)),
+                        fam["fns"])
             shardings = None
             if mesh is not None:
                 from .parallel.sharding import (
@@ -2736,11 +2760,7 @@ def main(argv: Optional[List[str]] = None) -> None:
             Logger.warn(
                 f"no usable tokenizer in {tok_src!r}; serving token ids only"
             )
-    pc = PagedCacheConfig(
-        n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, n_blocks=args.n_blocks,
-        block_tokens=args.block_tokens, dtype=cfg.dtype,
-    )
+    pc = PagedCacheConfig.for_model(cfg, args.n_blocks, args.block_tokens)
     conn = None
     endpoints_spec = args.store_endpoints or os.environ.get(
         "ISTPU_STORE_ENDPOINTS"
@@ -2805,12 +2825,8 @@ def main(argv: Optional[List[str]] = None) -> None:
                 f"--draft-model vocab {dcfg.vocab_size} != target vocab "
                 f"{cfg.vocab_size}; speculation needs a shared vocabulary"
             )
-        dpc = PagedCacheConfig(
-            n_layers=dcfg.n_layers, n_kv_heads=dcfg.n_kv_heads,
-            head_dim=dcfg.head_dim,
-            n_blocks=args.draft_n_blocks or args.n_blocks,
-            block_tokens=args.block_tokens, dtype=dcfg.dtype,
-        )
+        dpc = PagedCacheConfig.for_model(
+            dcfg, args.draft_n_blocks or args.n_blocks, args.block_tokens)
         draft_engine = InferenceEngine(dparams, dcfg, dpc, **dfns)
     if args.ngram_spec and draft_engine is not None:
         raise SystemExit("--ngram-spec and --draft-model are mutually "

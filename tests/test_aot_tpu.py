@@ -153,3 +153,59 @@ def test_tp_decode_on_tpu_copies_no_slab_and_gathers_no_cache(v5e):
         collectives)
     shard_bytes = int(np.prod(cache.shape)) * cache.dtype.itemsize // tp
     assert compiled.memory_analysis().alias_size_in_bytes >= shard_bytes
+
+
+def test_latent_decode_scan_on_tpu_copies_no_expert_leaf_and_no_slab(v5e):
+    """The latent-attention, routed-expert family at its published widths
+    (the leading dense layer and one expert layer, the benchmark's 10,240
+    blocks and 1,024-page tables: a cache of a few MB is moved into fast
+    memory whole and tells nothing): the
+    decode scan computes nothing of the shape of a layer's expert matrices
+    (a slice of a leaf stacked over layers feeding the grouped product was
+    copied in every step: 384 MB a leaf; the leaves are a layer's own now),
+    nothing of a slab's shape, and the one-plane cache that comes out is the
+    donated one."""
+    cfg = models.MlaMoeConfig(n_layers=2)
+    pc = PagedCacheConfig.for_model(cfg, 10240, T)
+    assert (pc.planes, pc.n_kv_heads, pc.head_dim) == (1, 1, 576)
+    chip = SingleDeviceSharding(v5e[0])
+    params = _shaped(jax.eval_shape(
+        lambda: models.init_mla_moe_params(cfg, jax.random.PRNGKey(0))), chip)
+    cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
+    batch = 8
+
+    def decode_scan(params, logits, pos, cache, table):
+        def step(carry, i):
+            logits, cache = carry
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            p = pos + i
+            blocks = jnp.take_along_axis(table, (p // T)[:, None], axis=1)[:, 0]
+            logits, cache = models.mla_moe_decode_forward(
+                params, cfg, tok, p, cache, table, p + 1, blocks, p % T)
+            return (logits, cache), tok
+
+        (logits, cache), toks = jax.lax.scan(
+            step, (logits, cache), jnp.arange(3))
+        return toks, logits, cache
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    compiled = jax.jit(decode_scan, donate_argnums=(3,)).lower(
+        params, sds((batch, cfg.vocab_size), cfg.dtype),
+        sds((batch,), jnp.int32), cache, sds((batch, 1024), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    E, d, f = cfg.n_experts, cfg.dim, cfg.moe_ffn_dim
+    leaves = {f"bf16[{E},{d},{f}]", f"bf16[{E},{f},{d}]"}
+    found = _INSTRUCTION.findall(text)
+    assert len(found) > 100, "the optimized program did not parse"
+    copies = [f"{n} = {shape} {op}" for n, shape, op in found
+              if shape in leaves and op not in (
+                  "parameter", "bitcast", "get-tuple-element")]
+    assert not copies, copies
+    assert not _slab_copies(text, pc.n_kv_heads, pc)
+    mem = compiled.memory_analysis()
+    cache_bytes = int(np.prod(cache.shape)) * cache.dtype.itemsize
+    assert mem.alias_size_in_bytes >= cache_bytes, mem
+    # the gathered rows of 8 x 1,024 pages and the vocabulary's logits, not
+    # a second cache and not a copy of the experts (1.2 GB a layer)
+    assert mem.temp_size_in_bytes < 800 << 20, mem
