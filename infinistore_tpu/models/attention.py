@@ -5,9 +5,12 @@ TPU-first design notes:
   onto the MXU and fuses mask+softmax; a Pallas flash kernel can drop in
   behind the same signature (``ops/pallas_attention.py``).
 * decode attention reads K/V straight from the paged HBM cache via a
-  static-shape page-table gather: [B, max_pages] int32 -> [B, S_max, H, D].
+  static-shape page-table gather: [B, max_pages] int32 -> [B, S_max, H_kv, D].
   No dynamic shapes: padding slots are masked by sequence length.
-* GQA repeats KV heads with a reshape (broadcast), not a materialized tile.
+* GQA in the paged readers (decode, speculative verify) views the query as
+  [.., H_kv, G, D] and contracts each group against its KV head's pages as
+  gathered: nothing of [B, S, H, D] exists.  Prefill (``causal_attention``)
+  still calls ``repeat_kv``, which XLA:TPU materialises (below).
 """
 
 from __future__ import annotations
@@ -71,7 +74,15 @@ def apply_rope(
 
 
 def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
-    """[..., S, H_kv, D] -> [..., S, H_kv*n_rep, D] (broadcast, no copy)."""
+    """[..., S, H_kv, D] -> [..., S, H_kv*n_rep, D]: query head h reads KV
+    head h // n_rep.
+
+    A broadcast in the traced program, a COPY on the chip: XLA:TPU writes
+    the ``n_rep``-fold array out and the einsums read it back (in the paged
+    decode that was 235 MB per K and per V per layer at 8 rows x 256 pages
+    and groups of 7, half the step: PERF.md, PR 31).  Only prefill
+    (``causal_attention``) still calls it; the copy is materialised there
+    too and is not priced."""
     if n_rep == 1:
         return x
     shape = x.shape
@@ -403,10 +414,11 @@ def paged_decode_attention_xla(
     B, H, D = q.shape
     k, v = gather_layer_kv(cache, layer, block_table)
     S_max, Hkv = k.shape[1:3]
-    k = repeat_kv(k, H // Hkv)
-    v = repeat_kv(v, H // Hkv)
+    # query head h pairs with KV head h // G: [B, H_kv, G, D] is that
+    # pairing as a reshape, and the pages are contracted as gathered
+    q = q.reshape(B, Hkv, H // Hkv, D)
     scale = 1.0 / np.sqrt(D)
-    logits = jnp.einsum("bhd,bkhd->bhk", q, k).astype(jnp.float32) * scale
+    logits = jnp.einsum("bhgd,bkhd->bhgk", q, k).astype(jnp.float32) * scale
     if softcap is not None:  # Gemma-2 logit soft-capping
         logits = softcap * jnp.tanh(logits / softcap)
     pos = jnp.arange(S_max)
@@ -414,9 +426,10 @@ def paged_decode_attention_xla(
     if window is not None:
         # current token sits at seq_lens-1; window covers (q - W, q]
         mask &= pos[None, :] >= seq_lens[:, None] - window
-    logits = jnp.where(mask[:, None, :], logits, -jnp.inf)
+    logits = jnp.where(mask[:, None, None, :], logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhk,bkhd->bhd", probs.astype(v.dtype), v)
+    out = jnp.einsum("bhgk,bkhd->bhgd", probs.astype(v.dtype), v)
+    return out.reshape(B, H, D)
 
 
 def paged_multitoken_attention_xla(
@@ -443,19 +456,20 @@ def paged_multitoken_attention_xla(
     B, S, H, D = q.shape
     k, v = gather_layer_kv(cache, layer, block_table)
     S_max, Hkv = k.shape[1:3]
-    k = repeat_kv(k, H // Hkv)
-    v = repeat_kv(v, H // Hkv)
+    # grouped as in paged_decode_attention_xla: the pages as gathered
+    q = q.reshape(B, S, Hkv, H // Hkv, D)
     scale = 1.0 / np.sqrt(D)
-    logits = jnp.einsum("bshd,bkhd->bhsk", q, k).astype(jnp.float32) * scale
+    logits = jnp.einsum("bshgd,bkhd->bhgsk", q, k).astype(jnp.float32) * scale
     if softcap is not None:  # Gemma-2 logit soft-capping
         logits = softcap * jnp.tanh(logits / softcap)
     k_pos = jnp.arange(S_max)
     mask = k_pos[None, None, :] <= positions[:, :, None]  # [B, S, S_max]
     if window is not None:
         mask &= k_pos[None, None, :] > positions[:, :, None] - window
-    logits = jnp.where(mask[:, None], logits, -jnp.inf)
+    logits = jnp.where(mask[:, None, None], logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhsk,bkhd->bshd", probs.astype(v.dtype), v)
+    out = jnp.einsum("bhgsk,bkhd->bshgd", probs.astype(v.dtype), v)
+    return out.reshape(B, S, H, D)
 
 
 def paged_decode_attention_tp(

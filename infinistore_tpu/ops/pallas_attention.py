@@ -12,7 +12,8 @@ The decode hot loop reads every cached K/V page of every active sequence per
 token -- purely HBM-bandwidth-bound.  The XLA version
 (models/attention.py:paged_decode_attention_xla) gathers the table's pages
 by (layer, page) index out of the whole cache and writes them out as K and
-V ([B, S_max, H_kv, D]) before attending; this kernel instead streams pages
+V ([B, S_max, H_kv, D]) before contracting the query, by group, against
+them (no repeat of the KV heads); this kernel instead streams pages
 HBM->VMEM by block-table lookup (PrefetchScalarGridSpec: the table is
 available to BlockSpec index_maps, so the pipeline's double-buffered DMAs
 chase the page table directly -- no gathered copy is ever written back).

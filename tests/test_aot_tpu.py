@@ -10,6 +10,7 @@ PR 27).  Every compile-only test lives in this file, and the topology is
 described inside a fixture, so only the worker that runs the file loads the
 TPU's library; where the topology cannot be had the tests skip."""
 
+import functools
 import re
 
 import jax
@@ -80,15 +81,14 @@ def _slab_copies(text, heads, pc):
     ]
 
 
-@pytest.mark.parametrize("batch", [2, 8])
-@pytest.mark.parametrize("preset", ["QWEN3_8B", "QWEN25_7B"])
-def test_decode_scan_on_tpu_copies_no_slab_of_the_cache(preset, batch, v5e):
-    """The benchmark's two families at their published widths (two layers,
-    a small cache), ``decode_forward`` in a short scan as the engine runs
-    it: nothing of a slab's shape is computed, and the cache that comes
-    out is the donated one."""
+@functools.cache
+def _decode_scan_on_tpu(preset, batch, device):
+    """``decode_forward`` in a short scan as the engine runs it, at the
+    family's published widths (two layers, a small cache), compiled for one
+    described chip; compiled once for the tests that read it.
+    -> (cfg, pc, the parameters' and the cache's abstract arrays, compiled)"""
     cfg, pc = _family(preset)
-    chip = SingleDeviceSharding(v5e[0])
+    chip = SingleDeviceSharding(device)
     params = _shaped(jax.eval_shape(
         lambda: models.init_params(cfg, jax.random.PRNGKey(0))), chip)
     cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
@@ -113,12 +113,59 @@ def test_decode_scan_on_tpu_copies_no_slab_of_the_cache(preset, batch, v5e):
         params, sds((batch, cfg.vocab_size), cfg.dtype),
         sds((batch,), jnp.int32), cache, sds((batch, WIDTH), jnp.int32),
     ).compile()
+    return cfg, pc, params, cache, compiled
+
+
+dense_cells = pytest.mark.parametrize(
+    "preset,batch",
+    [(p, b) for p in ("QWEN3_8B", "QWEN25_7B") for b in (2, 8)])
+
+
+@dense_cells
+def test_decode_scan_on_tpu_copies_no_slab_of_the_cache(preset, batch, v5e):
+    """The benchmark's two families at their published widths (two layers,
+    a small cache), ``decode_forward`` in a short scan as the engine runs
+    it: nothing of a slab's shape is computed, and the cache that comes
+    out is the donated one."""
+    _, pc, _, cache, compiled = _decode_scan_on_tpu(preset, batch, v5e[0])
     copies = _slab_copies(compiled.as_text(), pc.n_kv_heads, pc)
     assert not copies, copies
     cache_bytes = int(np.prod(cache.shape)) * cache.dtype.itemsize
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cache_bytes, (
         "the cache output no longer aliases the donated input", mem)
+
+
+@dense_cells
+def test_decode_scan_on_tpu_repeats_no_keys_or_values(preset, batch, v5e):
+    """The same programs hold no bf16 result of B x S x H x D elements
+    (S the table's tokens, H the QUERY heads), in any order of dimensions,
+    that is neither a parameter nor a bitcast: the gathered keys and values
+    are contracted against the grouped query as they are, [B, S, H_kv, D].
+    ``repeat_kv`` before the einsums was written out by XLA:TPU and read
+    back, G times the gathered bytes per K and per V per layer (PERF.md,
+    PR 31).  The instructions inside fusions are read too; a result of a
+    weight's own shape is a weight (Qwen3's two layers of ``wq`` have as
+    many elements as 8 rows x 1,024 tokens x 32 heads x 128)."""
+    cfg, pc, params, _, compiled = _decode_scan_on_tpu(preset, batch, v5e[0])
+    assert cfg.n_heads > cfg.n_kv_heads
+    weights = {"bf16[%s]" % ",".join(map(str, w.shape))
+               for w in jax.tree.leaves(params)}
+    repeated = batch * WIDTH * T * cfg.n_heads * cfg.head_dim
+    gathered = batch * WIDTH * T * cfg.n_kv_heads * cfg.head_dim
+    found = [
+        (name, shape, op,
+         int(np.prod([int(d) for d in shape[5:-1].split(",")])))
+        for name, shape, op in _INSTRUCTION.findall(compiled.as_text())
+        if shape.startswith("bf16[") and shape != "bf16[]"
+        and op not in ("parameter", "bitcast") and shape not in weights
+    ]
+    assert len(found) > 100, "the optimized program did not parse"
+    # the reader sees this program's pages: the gather's result is there
+    assert any(n == gathered for *_, n in found)
+    copies = [f"{name} = {shape} {op}" for name, shape, op, n in found
+              if n == repeated]
+    assert not copies, copies
 
 
 def test_tp_decode_on_tpu_copies_no_slab_and_gathers_no_cache(v5e):
