@@ -403,8 +403,8 @@ def main(argv=None):
         **lat,
         **cluster,
     }
-    # extra keys: the TPU-in-the-loop numbers (HBM<->store hop, Pallas vs
-    # XLA decode attention on chip, engine tokens/s) when a TPU answered
+    # extra keys: the TPU-in-the-loop numbers (HBM<->store hop, engine
+    # tokens/s) when a TPU answered
     result.update({f"tpu_{k}": v for k, v in tpu.items()})
     print(json.dumps(result))
     if args.json_out:

@@ -7,19 +7,16 @@ Measures the paths the host-only bench can't (VERDICT round-1 weak #2/#4/#5):
    get -> H2D -> fused scatter (``load_pages``) — against a live server
    (reference analog: benchmark.py src/dst cuda device selection,
    reference infinistore/benchmark.py:144-247);
-2. the Pallas paged-decode attention kernel and the flash prefill kernel vs
-   their XLA paths on the real chip (compile acceptance + us/step +
-   effective HBM GB/s);
-3. end-to-end decode tokens/s for the TINY model through the engine's
+2. end-to-end decode tokens/s for the TINY model through the engine's
    compiled scan loop.
 
-Each leg runs independently: a kernel Mosaic rejection or a store hiccup is
+Each leg runs independently: a compile failure or a store hiccup is
 recorded as ``<leg>_error`` in the JSON instead of sinking the other
 numbers.  Prints the cumulative JSON after every leg; exits non-zero when
 no TPU is reachable or when any leg raised (bench.py then exits non-zero
 too — a number measured without the chip, or next to a failed leg, is not
 passed off as a clean run).  ``ISTPU_TPU_FORCE=1`` runs the leg code on
-whatever backend is present, kernels interpreted, to debug it off the chip.
+whatever backend is present, to debug it off the chip.
 """
 
 from __future__ import annotations
@@ -79,161 +76,6 @@ def _timeit_chained(step, x0, n=20, budget_s: float = 10.0):
         x = step(x, i + 1)
     _fetch(x)
     return (time.perf_counter() - t0) / n
-
-
-def leg_decode_kernel(out: dict) -> None:
-    """Paged-decode attention kernel measured IN MODEL: the same
-    head_dim-128 engine decoding with the Pallas kernel vs forced-XLA
-    attention (ISTPU_NO_PALLAS).  The kernel's value is measured where it
-    runs: inside the compiled decode scan."""
-    import os
-
-    import jax
-    import numpy as np
-
-    from infinistore_tpu.engine import engine as eng_mod
-    from infinistore_tpu.engine.engine import InferenceEngine
-    from infinistore_tpu.kv.cache import PagedCacheConfig
-    from infinistore_tpu.models.llama import scaled, init_params
-
-    cfg = scaled(_bench_model(), n_heads=16, n_kv_heads=8,
-                 head_dim_override=128)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    jax.block_until_ready(params)
-    rng = np.random.RandomState(0)
-
-    def tok_s():
-        """Median-of-3 decode tok/s on ONE warmed engine; each repeat
-        decodes fresh sequences (evolving state defeats memoization)."""
-        eng = InferenceEngine(params, cfg, PagedCacheConfig(
-            n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim, block_tokens=16, n_blocks=512,
-            dtype="bfloat16",
-        ))
-        B, n = 8, eng.decode_chunk * 2
-        warm = [eng.prefill([int(x) for x in rng.randint(1, cfg.vocab_size, size=64)])
-                for _ in range(B)]
-        eng.decode_batch(warm, eng.decode_chunk)
-        eng.decode_batch(warm, n)
-        for s in warm:
-            eng.release(s)
-
-        def one() -> float:
-            sts = [eng.prefill(
-                [int(x) for x in rng.randint(1, cfg.vocab_size, size=64)])
-                for _ in range(B)]
-            eng.decode_batch(sts, eng.decode_chunk)
-            t0 = time.perf_counter()
-            eng.decode_batch(sts, n)  # host tokens: ground-truth sync
-            r = B * n / (time.perf_counter() - t0)
-            for s in sts:
-                eng.release(s)
-            return r
-
-        return _median_spread(one, 3)
-
-    xla_tok_s, xla_sp = tok_s()  # the default path
-    os.environ["ISTPU_PALLAS_DECODE"] = "1"
-    eng_mod._JIT_CACHE.clear()  # env is read at trace time; force re-trace
-    try:
-        pallas_tok_s, pallas_sp = tok_s()
-    finally:
-        del os.environ["ISTPU_PALLAS_DECODE"]
-        eng_mod._JIT_CACHE.clear()
-    out["decode128_pallas_tok_s"] = round(pallas_tok_s, 1)
-    out["decode128_pallas_spread"] = pallas_sp
-    out["decode128_xla_tok_s"] = round(xla_tok_s, 1)
-    out["decode128_xla_spread"] = xla_sp
-    out["pallas_speedup_vs_xla"] = round(pallas_tok_s / xla_tok_s, 2)
-
-
-def leg_flash_kernel(out: dict) -> None:
-    """Flash prefill kernel measured IN MODEL: TTFT for a 2048-token
-    prompt on the head_dim-128 engine with the Pallas flash kernel vs
-    forced-XLA attention (same methodology note as leg_decode_kernel)."""
-    import os
-
-    import jax
-    import numpy as np
-
-    from infinistore_tpu.engine import engine as eng_mod
-    from infinistore_tpu.engine.engine import InferenceEngine
-    from infinistore_tpu.kv.cache import PagedCacheConfig
-    from infinistore_tpu.models.llama import scaled, init_params
-
-    cfg = scaled(_bench_model(), n_heads=16, n_kv_heads=8,
-                 head_dim_override=128)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    jax.block_until_ready(params)
-    rng = np.random.RandomState(1)
-
-    def bench_backend(S: int):
-        """Median-of-3 TTFT (ms) for S-token prompts on ONE warmed
-        engine; each repeat prefills a FRESH prompt (memoization trap)
-        and releases it (pool stays level)."""
-        eng = InferenceEngine(params, cfg, PagedCacheConfig(
-            n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim, block_tokens=16, n_blocks=768,
-            dtype="bfloat16",
-        ))
-        w = eng.prefill([int(x) for x in rng.randint(1, cfg.vocab_size, size=S)])
-        _fetch(w.last_logits)
-        eng.release(w)
-
-        def one() -> float:
-            p = [int(x) for x in rng.randint(1, cfg.vocab_size, size=S)]
-            t0 = time.perf_counter()
-            st = eng.prefill(p)
-            _fetch(st.last_logits)
-            ms = (time.perf_counter() - t0) * 1e3
-            eng.release(st)
-            return ms
-
-        return _median_spread(one, 3)
-
-    # smoke runs (ISTPU_BENCH_MODEL=tiny on CPU) shrink the prompt sizes
-    # ~8x — same code path, feasible wall time on a 1-core host
-    smoke = os.environ.get("ISTPU_BENCH_MODEL") == "tiny"
-    sizes = ((256, "2k"), (1024, "8k")) if smoke else (
-        (2048, "2k"), (8192, "8k"))
-    import contextlib
-
-    @contextlib.contextmanager
-    def env_var(name: str, value):
-        """Set (value=str) or unset (value=None) ``name`` for the block,
-        restore the operator's own value after, and clear the jit cache
-        on BOTH transitions — trace-time env reads demand a retrace, and
-        a leaked override would silently flip every later leg."""
-        prior = os.environ.get(name)
-        if value is None:
-            os.environ.pop(name, None)
-        else:
-            os.environ[name] = value
-        eng_mod._JIT_CACHE.clear()
-        try:
-            yield
-        finally:
-            if prior is None:
-                os.environ.pop(name, None)
-            else:
-                os.environ[name] = prior
-            eng_mod._JIT_CACHE.clear()
-
-    for S, tag in sizes:
-        # flash is OPT-IN now (the r4-recorded number favored XLA and
-        # the default follows the bench); this leg measures both sides
-        # regardless of how the operator set the flag globally
-        with env_var("ISTPU_PALLAS_PREFILL", "1"):
-            flash_ms, flash_sp = bench_backend(S)
-        with env_var("ISTPU_PALLAS_PREFILL", None):
-            xla_ms, xla_sp = bench_backend(S)
-        out[f"flash_prefill_{tag}_ms"] = round(flash_ms, 1)
-        out[f"flash_prefill_{tag}_spread"] = flash_sp
-        out[f"xla_prefill_{tag}_ms"] = round(xla_ms, 1)
-        out[f"xla_prefill_{tag}_spread"] = xla_sp
-        out[f"flash_speedup_vs_xla_{tag}"] = round(xla_ms / flash_ms, 2)
-    # legacy key (round-4 comparisons)
-    out["flash_speedup_vs_xla"] = out["flash_speedup_vs_xla_2k"]
 
 
 def leg_store_hop(out: dict) -> None:
@@ -729,11 +571,9 @@ def leg_prefill_breakdown(out: dict) -> None:
     @jax.jit
     def attn_step(q):
         def body(qc, _):
-            # same attention entry AND the same default path prefill
-            # uses (flash is opt-in; env controls it here as there)
+            # the attention entry prefill uses
             o = causal_attention(qc, qc[:, :, : cfg.n_kv_heads],
-                                 qc[:, :, : cfg.n_kv_heads],
-                                 allow_pallas=True)
+                                 qc[:, :, : cfg.n_kv_heads])
             return qc * 0.999 + 0.001 * o, None
 
         qc, _ = jax.lax.scan(body, q, None, length=cfg.n_layers)
@@ -933,89 +773,6 @@ def leg_distilled_spec(out: dict) -> None:
     out["distilled_spec_tok_s"] = round(spec_tok_s, 1)
     out["distilled_spec_spread"] = spec_sp
     out["distilled_spec_speedup"] = round(spec_tok_s / plain_tok_s, 2)
-
-
-def leg_invocation_overhead(out: dict) -> None:
-    """Quantify the per-``pallas_call`` overhead hypothesis (VERDICT r4
-    next #5) with a controlled experiment: the SAME total decode-
-    attention work (16 layers, B=8, 1024-token context) compiled as
-
-    * one jit containing 16 single-layer pallas custom calls (the shape
-      a real decode step has), vs
-    * one jit containing ONE all-layers pallas call
-      (``paged_decode_attention_pallas_alllayers`` — identical HBM
-      traffic and FLOPs, 1/16th the invocations), vs
-    * the XLA gather-then-attend path (the shipping default).
-
-    If the fused call is ~16x cheaper per layer, the overhead theory is
-    CONFIRMED and quantified (the difference / 15 is the per-call cost);
-    if not, the kernels lose for some other reason and kernel work on
-    this platform should stop chasing invocation counts."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from infinistore_tpu.models.attention import paged_decode_attention_xla
-    from infinistore_tpu.ops.pallas_attention import (
-        paged_decode_attention_pallas,
-        paged_decode_attention_pallas_alllayers,
-    )
-
-    # interpret mode (and token shapes) only under the explicit
-    # ISTPU_TPU_FORCE=1 debugging switch: timings are meaningless there,
-    # and finding no TPU must never select it silently
-    interp = os.environ.get("ISTPU_TPU_FORCE") == "1"
-    if interp:
-        L, B, H, Hkv, D, T, PAGES = 2, 2, 4, 2, 128, 16, 4
-    else:
-        L, B, H, Hkv, D, T = 16, 8, 16, 8, 128, 16
-        PAGES = 64  # 1024-token context
-    rng = np.random.RandomState(0)
-    cache = jnp.asarray(
-        rng.randn(L, 2, Hkv, PAGES + 1, T, D), jnp.bfloat16
-    )
-    table = jnp.asarray(
-        np.tile(np.arange(1, PAGES + 1, dtype=np.int32), (B, 1))
-    )
-    lens = jnp.full((B,), PAGES * T, jnp.int32)
-
-    @jax.jit
-    def per_layer(qs):
-        outs = [
-            paged_decode_attention_pallas(
-                qs[l], cache[l], table, lens, interpret=interp)
-            for l in range(L)
-        ]
-        o = jnp.stack(outs)
-        # chain: next iteration's queries derive from this output, so
-        # repeated dispatches can't be memoized
-        return qs * 0.999 + 0.001 * o
-
-    @jax.jit
-    def fused(qs):
-        o = paged_decode_attention_pallas_alllayers(
-            qs, cache, table, lens, interpret=interp)
-        return qs * 0.999 + 0.001 * o
-
-    @jax.jit
-    def xla(qs):
-        outs = [
-            paged_decode_attention_xla(qs[l], cache, l, table, lens)
-            for l in range(L)
-        ]
-        return qs * 0.999 + 0.001 * jnp.stack(outs)
-
-    qs0 = jnp.asarray(rng.randn(L, B, H, D), jnp.bfloat16)
-    t16 = _timeit_chained(lambda x, i: per_layer(x), qs0, n=30)
-    t1 = _timeit_chained(lambda x, i: fused(x), qs0, n=30)
-    txla = _timeit_chained(lambda x, i: xla(x), qs0, n=30)
-    out["invoc_16calls_ms"] = round(t16 * 1e3, 3)
-    out["invoc_1call_ms"] = round(t1 * 1e3, 3)
-    out["invoc_xla_ms"] = round(txla * 1e3, 3)
-    out["invoc_per_call_overhead_ms"] = round(
-        (t16 - t1) / (L - 1) * 1e3, 4
-    )
-    out["invoc_fused_speedup"] = round(t16 / t1, 2)
 
 
 def _chip_peak_flops_bf16(device_kind: str) -> float:
@@ -1299,54 +1056,6 @@ def leg_prefill_stream(out: dict) -> None:
         )
 
 
-def leg_mosaic_tests(out: dict) -> None:
-    """Fold the TPU-gated Mosaic acceptance tests into the bench attempt:
-    the kernels' real-compile path rides along with every chip run.  Runs
-    pytest IN-PROCESS: this process holds the chip, a chip belongs to one
-    process at a time, and a child that needed it would fail or hang.
-    Ordered last in the leg list: in-process pytest imports the test
-    modules into this interpreter, which must not perturb earlier legs."""
-    import pytest
-
-    fails: list = []
-    counts = {"passed": 0, "failed": 0, "skipped": 0}
-
-    class _Count:
-        def pytest_runtest_logreport(self, report):
-            if report.when == "call":
-                if report.passed:
-                    counts["passed"] += 1
-                elif report.failed:
-                    counts["failed"] += 1
-                    fails.append(
-                        f"{report.nodeid}: {report.longreprtext[-400:]}"
-                    )
-                elif report.skipped:  # pytest.skip() inside the test body
-                    counts["skipped"] += 1
-            elif report.when == "setup":
-                if report.skipped:
-                    counts["skipped"] += 1
-                elif report.failed:
-                    counts["failed"] += 1
-                    fails.append(
-                        f"{report.nodeid}: {report.longreprtext[-400:]}"
-                    )
-
-    os.environ["ISTPU_TEST_TPU"] = "1"
-    repo = os.path.dirname(os.path.abspath(__file__))
-    pytest.main(
-        [os.path.join(repo, "tests", "test_ops.py"), "-k", "on_tpu",
-         "-q", "--no-header", "-p", "no:cacheprovider"],
-        plugins=[_Count()],
-    )
-    out["mosaic_tests_passed"] = counts["passed"]
-    if counts["skipped"]:
-        out["mosaic_tests_skipped"] = counts["skipped"]
-    if counts["failed"]:
-        out["mosaic_tests_failed"] = counts["failed"]
-        out["mosaic_tests_tail"] = " || ".join(fails)[:1500]
-
-
 def main() -> int:
     import argparse
 
@@ -1391,15 +1100,9 @@ def main() -> int:
         ("serving", leg_serving),
         ("speculative", leg_speculative),
         ("distilled_spec", leg_distilled_spec),
-        ("decode_kernel", leg_decode_kernel),
-        ("invocation_overhead", leg_invocation_overhead),
         ("prefill_breakdown", leg_prefill_breakdown),
-        ("flash_kernel", leg_flash_kernel),
         ("store_hop", leg_store_hop),
         ("prefill_stream", leg_prefill_stream),
-        # real chip only (the tests skip elsewhere), and LAST (in-process
-        # pytest imports test modules)
-        *([("mosaic_tests", leg_mosaic_tests)] if platform == "tpu" else []),
     ]
     from infinistore_tpu.utils import tracing as _tracing
 
@@ -1426,7 +1129,7 @@ def main() -> int:
     # executed on a real chip.  A miss is recorded in the JSON (and on
     # stderr); it is a verdict on a code path, not a failed leg.
     if platform == "tpu":
-        floors = {"spec_speedup": 1.3, "pallas_speedup_vs_xla": 1.0}
+        floors = {"spec_speedup": 1.3}
         checks = {
             key: {"value": out[key], "floor": floor,
                   "ok": out[key] >= floor}

@@ -36,7 +36,6 @@ import http.client
 import json
 import os
 import random
-import re
 import shutil
 import signal
 import socket
@@ -324,25 +323,6 @@ def check_tier_clean(tag: str, run: dict) -> None:
         check(not bad, f"{tag}: store {fam} counters are not 0: {bad}")
 
 
-def kernel_tests(env: dict) -> int:
-    """The four Mosaic-compiled kernel tests, in a child of their own (the
-    servers have exited, the chip is free).  A skip is a failure: it means
-    the child did not see a TPU."""
-    out = subprocess.run(
-        [sys.executable, "-m", "pytest", "tests/test_ops.py", "-k", "on_tpu",
-         "-q", "-p", "no:cacheprovider"],
-        cwd=REPO, env={**env, "ISTPU_TEST_TPU": "1"}, capture_output=True,
-        text=True, timeout=900)
-    tail = out.stdout[-3000:] + out.stderr[-1500:]
-    passed = re.search(r"(\d+) passed", out.stdout)
-    check(out.returncode == 0 and passed is not None
-          and not re.search(r"skipped|failed|error", out.stdout.splitlines()[-1]),
-          f"kernel tests did not all pass:\n{tail}")
-    check(int(passed.group(1)) == 4,
-          f"expected 4 kernel tests, {passed.group(1)} passed:\n{tail}")
-    return int(passed.group(1))
-
-
 def run(args) -> dict:
     dry = args.dry_run
     env = dict(os.environ, PYTHONUNBUFFERED="1")
@@ -498,18 +478,12 @@ def run(args) -> dict:
                   f"{tag} reported no device memory: {r['mem']}")
     stop(store)
 
-    # -- 8. the kernels compile ----------------------------------------------------
-    n_kernels = None
-    if not dry:
-        n_kernels = kernel_tests(env)
-        say(f"kernel tests: {n_kernels} passed, 0 skipped")
     strip = ("first", "rows", "health")
     return {"device": {"platform": first["device"]["platform"],
                        "kind": first["device"]["device_kind"],
                        "count": first["device"]["count"]},
             "model": model_file, "tp": args.tp, "n_blocks": n_blocks,
             "kvmap_len": kvmap, "first_logprob_worst": worst,
-            "kernel_tests_passed": n_kernels,
             "serve1": {k: v for k, v in first.items() if k not in strip},
             "serve2": {k: v for k, v in second.items() if k not in strip}}
 

@@ -580,7 +580,6 @@ class InferenceEngine:
         kv_quant: Optional[str] = "int8",
         mesh=None,
         param_specs=None,
-        pallas_tp: bool = False,
         lora=None,
         decode_chunk: int = 32,
         store_durability: str = "strict",
@@ -762,11 +761,6 @@ class InferenceEngine:
         self.max_pages = pc.n_blocks
         self.seqs: Dict[int, SequenceState] = {}
         self._next_id = 0
-        # under a mesh every step is GSPMD-partitioned: the Pallas kernels
-        # are opaque custom calls with no partitioning rule, so force the
-        # XLA attention path (models/attention.py rationale); prefill/decode
-        # of every family take use_pallas for this reason
-        pallas_kw = {"use_pallas": False} if mesh is not None else {}
         self.lora = lora
         # the bank TENSORS enter every dispatch as traced args (jit would
         # constant-fold a closed-over bank into the program); only the
@@ -783,28 +777,13 @@ class InferenceEngine:
                     "must thread lora/adapter_ids through their own forwards"
                 )
             lora_kw = {"lora_scale": lora.scale}
-        # pallas_tp: attention runs the Pallas kernels head-locally inside
-        # a shard_map over tp instead of the partitioned XLA paths — the
-        # flash kernels for prefill (models/attention.py
-        # flash_causal_attention_tp), the paged kernel for decode
-        # (paged_decode_attention_tp); default-family only — custom
-        # forwards bring their own sharded kernels
-        prefill_kw = dict(pallas_kw)
-        decode_kw = dict(pallas_kw)
-        if mesh is not None and pallas_tp:
-            assert decode_fn is None and prefill_fn is None, (
-                "pallas_tp composes the built-in kernels; custom forwards"
-                " must handle their own tp kernel dispatch"
-            )
-            prefill_kw["tp_mesh"] = mesh
-            decode_kw["tp_mesh"] = mesh
         self._prefill_jit = _shared_jit(
             prefill_fn or prefill_forward,
-            {"cfg": self.cfg, **prefill_kw, **lora_kw},
+            {"cfg": self.cfg, **lora_kw},
         )
         self._decode_raw = _shared_partial(
             decode_fn or decode_forward,
-            {"cfg": self.cfg, **decode_kw, **lora_kw},
+            {"cfg": self.cfg, **lora_kw},
         )
         # a custom model family must bring its own verify step: silently
         # binding llama's verify_forward to foreign params would die deep in
@@ -812,17 +791,9 @@ class InferenceEngine:
         self._has_verify = verify_fn is not None or (
             decode_fn is None and prefill_fn is None
         )
-        # same GSPMD rule for a custom verify step; the built-in
-        # verify_forward is XLA-only and takes no use_pallas
-        verify_kw = {}
-        if mesh is not None and verify_fn is not None:
-            import inspect
-
-            if "use_pallas" in inspect.signature(verify_fn).parameters:
-                verify_kw = {"use_pallas": False}
         self._verify_jit = _shared_jit(
             verify_fn or verify_forward,
-            {"cfg": self.cfg, **verify_kw, **lora_kw},
+            {"cfg": self.cfg, **lora_kw},
             donate=("cache",),
         )
         # the last-row-only verify variant: a resync/refresh step that
@@ -842,8 +813,7 @@ class InferenceEngine:
         ):
             self._verify_last_jit = _shared_jit(
                 _vfn,
-                {"cfg": self.cfg, "last_only": True,
-                 **verify_kw, **lora_kw},
+                {"cfg": self.cfg, "last_only": True, **lora_kw},
                 donate=("cache",),
             )
         else:

@@ -1,6 +1,6 @@
 """Process-wide JAX configuration for the TPU serving stack.
 
-Imported by every jax-touching subpackage (engine/models/kv/ops/parallel)
+Imported by every jax-touching subpackage (engine/models/kv/parallel)
 before any tracing happens.  The store tier (config/protocol/lib/server)
 stays jax-free and must not import this.
 

@@ -22,9 +22,7 @@ step reads and writes this one array by index and never slices a layer out
 of it: the attention gathers ``kv[layer, k|v, :, block_table]``
 (models/attention.py:gather_layer_kv) and ``write_token_kv`` below gathers and
 scatters ``kv[layer, :, :, block_ids]``, so the donated cache is updated in
-place and no layer's slab is copied.  The opt-in Pallas decode kernel
-(ops/pallas_attention.py) streams the same tiles HBM->VMEM by block-table
-lookup with no layout shuffle.
+place and no layer's slab is copied.
 
 A page is ``block_tokens`` consecutive tokens of one layer's K+V (all heads)
 -- the unit that maps 1:1 onto a store key (kv/hashing.chunk_keys x layer).

@@ -1,9 +1,7 @@
 """Attention ops: causal prefill attention and paged decode attention.
+One implementation of each per program, in plain ``jax.numpy``: XLA tiles
+the matmuls onto the MXU and fuses mask and softmax.
 
-TPU-first design notes:
-* prefill attention is a plain fused SDPA in bf16 -- XLA tiles the matmuls
-  onto the MXU and fuses mask+softmax; a Pallas flash kernel can drop in
-  behind the same signature (``ops/pallas_attention.py``).
 * decode attention reads K/V straight from the paged HBM cache via a
   static-shape page-table gather: [B, max_pages] int32 -> [B, S_max, H_kv, D].
   No dynamic shapes: padding slots are masked by sequence length.
@@ -91,81 +89,15 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
     return x.reshape(shape[:-2] + (shape[-2] * n_rep, shape[-1]))
 
 
-def flash_causal_attention_tp(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    mesh,
-    q_offset: int = 0,
-    prefix_pad: int | None = None,
-    prefix_len: jax.Array | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Tensor-parallel flash PREFILL attention: the Pallas kernel inside a
-    ``shard_map`` over the mesh's ``tp`` axis (VERDICT r3 weak #6 — the
-    mesh path previously forced XLA attention for the compute-bound
-    phase; decode already had this composition in
-    ``paged_decode_attention_tp``).
-
-    Prefill attention is head-local exactly like paged decode: with
-    ``tp | H_kv`` (the weights' GQA-group sharding rule) each shard holds
-    whole (q-head group, kv-head) families, so the flash kernel runs on
-    local shards with NO collectives and GSPMD stitches the head axis.
-
-    q: [B, Sq, H, D]; k/v: [B, Sk, H_kv, D].  ``prefix_pad``/``prefix_len``
-    select the padded-prefix kernel (chunked prefill over a reused
-    prefix); the traced ``prefix_len`` scalar rides in replicated.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    from ..ops.pallas_attention import (
-        flash_causal_attention_pallas,
-        flash_prefix_attention_pallas,
-    )
-
-    tp = mesh.shape["tp"]
-    assert k.shape[2] % tp == 0 and q.shape[2] % tp == 0, (
-        q.shape, k.shape, tp
-    )
-    if prefix_len is None:
-        def local(q, k, v):
-            return flash_causal_attention_pallas(
-                q, k, v, q_offset=q_offset, interpret=interpret
-            )
-
-        args, specs = (q, k, v), (P(None, None, "tp", None),) * 3
-    else:
-        def local(q, k, v, plen):
-            return flash_prefix_attention_pallas(
-                q, k, v, prefix_pad=prefix_pad, prefix_len=plen,
-                interpret=interpret,
-            )
-
-        args = (q, k, v, prefix_len)
-        specs = (P(None, None, "tp", None),) * 3 + (P(),)
-    return jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=specs,
-        out_specs=P(None, None, "tp", None),
-        axis_names={"tp"},
-        # pallas_call declares no varying-mesh-axes metadata; the specs
-        # above are the full contract
-        check_vma=False,
-    )(*args)
-
-
 def causal_attention(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
     q_offset: jax.Array | int = 0,
-    allow_pallas: bool = False,
     prefix_pad: int | None = None,
     prefix_len: jax.Array | None = None,
     window: int | None = None,
     softcap: float | None = None,
-    tp_mesh=None,
 ) -> jax.Array:
     """Causal SDPA.  q: [B, Sq, H, D]; k/v: [B, Sk, H_kv, D].
 
@@ -179,84 +111,10 @@ def causal_attention(
     capacities keeps chunked prefill's compile count logarithmic
     (engine/engine.py) while this mask hides the slack.
 
-    ``allow_pallas=True`` makes the flash kernel
-    (ops/pallas_attention.py) ELIGIBLE on TPU when the head dim is
-    lane-aligned — actually engaging it additionally requires the
-    ``ISTPU_PALLAS_PREFILL`` opt-in (the recorded bench favors the XLA
-    path on this platform; see the gate comment below).  It must stay
-    False under a GSPMD-partitioned jit (same rule as
-    ``paged_decode_attention`` below) — which is why the sharded callers
-    in parallel/ use the default.  ``ISTPU_NO_PALLAS=1`` forces the XLA
-    path on hardware; the one exception is ``ISTPU_PALLAS_INTERPRET=1``
-    (the CPU-mesh test path), which runs the tp flash kernel in
-    interpret mode by explicit request.
-
     ``window``: sliding-window attention (Mistral) — a key is visible iff
-    ``q_pos - window < k_pos <= q_pos`` (HF convention).  Forces the XLA
-    path: the flash kernels carry no window mask.
-
-    ``tp_mesh``: under a GSPMD mesh, routes to the shard_map'd flash
-    kernel (``flash_causal_attention_tp``) instead — head-local, no
-    collectives — on TPU, or in interpret mode with
-    ``ISTPU_PALLAS_INTERPRET=1`` (the CPU-mesh test path).
+    ``q_pos - window < k_pos <= q_pos`` (HF convention).
     """
-    import os
-
     B, Sq, H, D = q.shape
-    if (
-        tp_mesh is not None
-        and window is None
-        and softcap is None
-        and D % 128 == 0  # D=64 lowers on Mosaic but measured SLOWER than
-        # the XLA path inside the full model (half-empty lanes + sublane
-        # padding): 1B/B=8 decode 46->70 ms/step, TTFT 6.8->83 ms on a v5e
-        and (prefix_len is None or (prefix_pad or 0) % 128 == 0)
-        and isinstance(q_offset, int)
-    ):
-        # this branch is already an engine-level OPT-IN: tp_mesh is only
-        # non-None when the engine was built with pallas_tp=True, so no
-        # additional env gate — the operator explicitly chose the
-        # shard_map'd flash kernels over the partitioned XLA paths
-        interp = bool(os.environ.get("ISTPU_PALLAS_INTERPRET"))
-        on_tpu = (
-            jax.default_backend() == "tpu"
-            and not os.environ.get("ISTPU_NO_PALLAS")
-        )
-        if on_tpu or interp:
-            return flash_causal_attention_tp(
-                q, k, v, tp_mesh, q_offset=q_offset,
-                prefix_pad=prefix_pad if prefix_len is not None else None,
-                prefix_len=prefix_len, interpret=interp,
-            )
-    if (
-        allow_pallas
-        and window is None
-        and softcap is None  # the flash kernels carry no logit softcap
-        and D % 128 == 0  # D=64 lowers on Mosaic but measured SLOWER than
-        # the XLA path inside the full model (half-empty lanes + sublane
-        # padding): 1B/B=8 decode 46->70 ms/step, TTFT 6.8->83 ms on a v5e
-        and jax.default_backend() == "tpu"
-        # OPT-IN (ISTPU_PALLAS_PREFILL, any truthy value — same parsing
-        # as ISTPU_PALLAS_DECODE), same policy as the decode kernel:
-        # flash against XLA is not measured on a directly attached
-        # chip, so the default is the simpler XLA path (ROADMAP A6).
-        and bool(os.environ.get("ISTPU_PALLAS_PREFILL"))
-        and not os.environ.get("ISTPU_NO_PALLAS")
-    ):
-        if prefix_len is None and isinstance(q_offset, int):
-            from ..ops.pallas_attention import flash_causal_attention_pallas
-
-            return flash_causal_attention_pallas(q, k, v, q_offset=q_offset)
-        if (
-            prefix_len is not None
-            and prefix_pad is not None
-            and prefix_pad % 128 == 0
-        ):
-            from ..ops.pallas_attention import flash_prefix_attention_pallas
-
-            return flash_prefix_attention_pallas(
-                q, k, v, prefix_pad=prefix_pad, prefix_len=prefix_len
-            )
     Hkv = k.shape[2]
     k = repeat_kv(k, H // Hkv)
     v = repeat_kv(v, H // Hkv)
@@ -394,7 +252,7 @@ def latent_absorbed_decode_attention(
         return jnp.einsum("bhr,rhv->bhv", o_lat, wv)
 
 
-def paged_decode_attention_xla(
+def paged_decode_attention(
     q: jax.Array,
     cache: jax.Array,
     layer: int,
@@ -403,7 +261,7 @@ def paged_decode_attention_xla(
     window: int | None = None,
     softcap: float | None = None,
 ) -> jax.Array:
-    """One-token decode attention against the paged cache (XLA gather path).
+    """One-token decode attention against the paged cache.
 
     q: [B, H, D] (current token, RoPE already applied)
     cache: [L, 2, H_kv, n_blocks, T, D] (the whole cache); layer: the
@@ -456,7 +314,7 @@ def paged_multitoken_attention_xla(
     B, S, H, D = q.shape
     k, v = gather_layer_kv(cache, layer, block_table)
     S_max, Hkv = k.shape[1:3]
-    # grouped as in paged_decode_attention_xla: the pages as gathered
+    # grouped as in paged_decode_attention: the pages as gathered
     q = q.reshape(B, S, Hkv, H // Hkv, D)
     scale = 1.0 / np.sqrt(D)
     logits = jnp.einsum("bshgd,bkhd->bhgsk", q, k).astype(jnp.float32) * scale
@@ -470,147 +328,3 @@ def paged_multitoken_attention_xla(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgsk,bkhd->bshgd", probs.astype(v.dtype), v)
     return out.reshape(B, S, H, D)
-
-
-def paged_decode_attention_tp(
-    q: jax.Array,
-    layer_cache: jax.Array,
-    block_table: jax.Array,
-    seq_lens: jax.Array,
-    mesh,
-    interpret: bool = False,
-) -> jax.Array:
-    """Tensor-parallel Pallas decode attention: the kernel inside a
-    ``shard_map`` over the mesh's ``tp`` axis.
-
-    Paged attention is head-local (each q-head group reads only its own KV
-    head's pages), so splitting q over H and the cache over H_kv needs NO
-    collectives — each shard streams its local pages with the same kernel
-    the single-chip path uses, and GSPMD stitches the head axis back.  This
-    is the composition models/attention.py's GSPMD caveat calls the planned
-    path: the opaque pallas_call never meets the partitioner because
-    shard_map hands it already-local shards.
-
-    Requires tp | H_kv (same grouping rule as the weights: tp shards whole
-    GQA groups).  q: [B, H, D]; layer_cache: [2, H_kv, n_blocks, T, D].
-    """
-    from jax.sharding import PartitionSpec as P
-
-    from ..ops.pallas_attention import paged_decode_attention_pallas
-
-    tp = mesh.shape["tp"]
-    Hkv = layer_cache.shape[1]
-    assert Hkv % tp == 0 and q.shape[1] % tp == 0, (q.shape, Hkv, tp)
-
-    def local(q, cache, table, lens):
-        return paged_decode_attention_pallas(
-            q, cache, table, lens, interpret=interpret
-        )
-
-    return jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(
-            P(None, "tp", None),
-            P(None, "tp", None, None, None),
-            P(None, None),
-            P(None),
-        ),
-        out_specs=P(None, "tp", None),
-        axis_names={"tp"},
-        # pallas_call declares no varying-mesh-axes metadata; the specs
-        # above are the full contract
-        check_vma=False,
-    )(q, layer_cache, block_table, seq_lens)
-
-
-def paged_decode_attention(
-    q: jax.Array,
-    cache: jax.Array,
-    layer: int,
-    block_table: jax.Array,
-    seq_lens: jax.Array,
-    allow_pallas: bool = True,
-    tp_mesh=None,
-    window: int | None = None,
-    softcap: float | None = None,
-) -> jax.Array:
-    """Paged decode attention: the XLA gather path unless a Pallas kernel
-    is opted into.
-
-    Same signature as ``paged_decode_attention_xla``: the whole cache
-    [L, 2, H_kv, n_blocks, T, D] and the (static) layer to read.  The XLA
-    path gathers that layer's pages by index out of ``cache`` and never
-    forms ``cache[layer]``; the opt-in Pallas paths below still take the
-    layer's slice [2, H_kv, n_blocks, T, D], which IS their kernel layout
-    (pages stream by block-table lookup with no shuffle).  Set
-    ``ISTPU_NO_PALLAS=1`` to force the XLA path.
-
-    ``allow_pallas=False`` MUST be passed when tracing under a
-    GSPMD-partitioned jit (parallel/sharding.py make_tp_decode): pallas_call
-    is an opaque custom call with no SPMD partitioning rule, so the
-    partitioner would replicate (all-gather) the sharded cache around it.
-    ``tp_mesh`` is the sharded-kernel composition that lifts this limit:
-    ``paged_decode_attention_tp`` wraps the kernel in a shard_map over tp
-    (on TPU; set ISTPU_PALLAS_INTERPRET=1 to exercise it in interpret mode
-    on the CPU mesh).
-    """
-    import os
-
-    if window is not None or softcap is not None:
-        # the Pallas kernels carry no sliding-window mask or logit softcap;
-        # the XLA path partitions fine under GSPMD, so those models always
-        # take it
-        return paged_decode_attention_xla(
-            q, cache, layer, block_table, seq_lens, window=window,
-            softcap=softcap,
-        )
-    if tp_mesh is not None:
-        interp = bool(os.environ.get("ISTPU_PALLAS_INTERPRET"))
-        on_tpu = (
-            q.shape[-1] % 128 == 0
-            and jax.default_backend() == "tpu"
-            and not os.environ.get("ISTPU_NO_PALLAS")
-        )
-        if on_tpu or interp:
-            return paged_decode_attention_tp(
-                q, cache[layer], block_table, seq_lens, tp_mesh,
-                interpret=interp,
-            )
-        return paged_decode_attention_xla(q, cache, layer, block_table, seq_lens)
-    if (
-        allow_pallas
-        and os.environ.get("ISTPU_PALLAS_DECODE")  # opt-in, see below
-        and q.shape[-1] % 128 == 0  # see D % 128 note above (D=64 measured slower)
-        and jax.default_backend() == "tpu"
-        and not os.environ.get("ISTPU_NO_PALLAS")
-    ):
-        if os.environ["ISTPU_PALLAS_DECODE"] == "jax":
-            # jax's bundled multi-page-per-program paged-attention kernel
-            # (per-(b, h) grid, looped double-buffered page copies); our
-            # cache layout IS its k_pages/v_pages layout, so the slices
-            # are free.  It applies no q scale internally.
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                paged_attention as _jax_paged_attention,
-            )
-
-            D = q.shape[-1]
-            return _jax_paged_attention(
-                q * jnp.asarray(D ** -0.5, q.dtype),
-                cache[layer, 0], cache[layer, 1], seq_lens, block_table,
-                pages_per_compute_block=min(8, block_table.shape[1]),
-            )
-        from ..ops.pallas_attention import paged_decode_attention_pallas
-
-        return paged_decode_attention_pallas(
-            q, cache[layer], block_table, seq_lens)
-    # DEFAULT: the XLA gather path.  Measured in-model on a v5e with
-    # right-sized (pow2-bucketed) block tables, the Pallas kernel is
-    # SLOWER than XLA's fused gather at every context tried (0.7x at
-    # ctx=64, 0.58x at 512, 0.40x at 1536, B=8, D=128): its
-    # (B, H_kv, max_pages) grid does tiny (16, 128) blocks of work per
-    # program and the grid overhead swamps the saved gather.  The kernel
-    # stays available (ISTPU_PALLAS_DECODE=1) for future retuning; the
-    # flash PREFILL kernels remain the default — measured 1.13x at 2k and
-    # they keep the [S, S] score matrix out of HBM.
-    return paged_decode_attention_xla(q, cache, layer, block_table, seq_lens)

@@ -343,7 +343,6 @@ def prefill_forward(
     lora=None,
     adapter_ids: jax.Array | None = None,
     lora_scale: float = 1.0,
-    tp_mesh=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """tokens: [B, S] -> (logits [B, S, V], kv [L, 2, B, S, Hkv, D]).
 
@@ -358,9 +357,8 @@ def prefill_forward(
     the buffer at a few bucketed capacities bounds chunked prefill's
     compile count (engine/engine.py).
 
-    ``use_pallas=False`` forces the XLA attention path; required when this
-    function is traced under a GSPMD-partitioned jit (see loss_fn and
-    parallel/sharding.py — same rule as decode_forward).
+    ``use_pallas`` is accepted and unused: benchmarks/aot_check.py and
+    benchmarks/tests/test_benchmark.py still pass it (ROADMAP C13).
     """
     B, S = tokens.shape
     P = 0 if prefix_kv is None else prefix_kv.shape[3]
@@ -379,17 +377,16 @@ def prefill_forward(
         kvs.append(jnp.stack([k, v], axis=0))  # [2, B, S, Hkv, D]
         if prefix_kv is None:
             attn = causal_attention(
-                q, k, v, allow_pallas=use_pallas, window=win,
-                softcap=cfg.attn_softcap, tp_mesh=tp_mesh,
+                q, k, v, window=win, softcap=cfg.attn_softcap,
             )
         else:
             k_full = jnp.concatenate([prefix_kv[li, 0], k], axis=1)
             v_full = jnp.concatenate([prefix_kv[li, 1], v], axis=1)
             attn = causal_attention(
-                q, k_full, v_full, q_offset=P, allow_pallas=use_pallas,
+                q, k_full, v_full, q_offset=P,
                 prefix_pad=P if prefix_len is not None else None,
                 prefix_len=prefix_len, window=win,
-                softcap=cfg.attn_softcap, tp_mesh=tp_mesh,
+                softcap=cfg.attn_softcap,
             )
         a = attn.reshape(B, S, -1)
         a = a @ layer["wo"] + _lora_term(a, ll, "wo", adapter_ids, lora_scale)
@@ -416,19 +413,14 @@ def decode_forward(
     slot_block_ids: jax.Array,
     slot_ids: jax.Array,
     use_pallas: bool = True,
-    tp_mesh=None,
     lora=None,
     adapter_ids: jax.Array | None = None,
     lora_scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
     """Single-token paged decode.
 
-    ``use_pallas=False`` forces the XLA attention path; required when this
-    function is traced under a GSPMD-partitioned jit (see
-    models/attention.py:paged_decode_attention).  ``tp_mesh`` instead runs
-    the Pallas kernel head-locally inside a shard_map over the mesh's tp
-    axis (paged_decode_attention_tp) — the tensor-parallel serving fast
-    path.
+    ``use_pallas`` is accepted and unused: benchmarks/aot_check.py and
+    benchmarks/tests/test_benchmark.py still pass it (ROADMAP C13).
 
     tokens/positions: [B]; cache: [L, 2, Hkv, n_blocks, T, D]
     (kv/cache.py layout -- heads outside blocks, so a (head, page) tile
@@ -456,9 +448,8 @@ def decode_forward(
         # scatter this token's kv into its page slot
         cache = write_token_kv(cache, li, slot_block_ids, slot_ids, k[:, 0], v[:, 0])
         attn = paged_decode_attention(
-            q[:, 0], cache, li, block_table, seq_lens, allow_pallas=use_pallas,
-            tp_mesh=tp_mesh, window=_window_for(cfg, li),
-            softcap=cfg.attn_softcap,
+            q[:, 0], cache, li, block_table, seq_lens,
+            window=_window_for(cfg, li), softcap=cfg.attn_softcap,
         )
         a = attn.reshape(B, -1)[:, None, :]
         a = a @ layer["wo"] + _lora_term(a, ll, "wo", adapter_ids, lora_scale)
@@ -543,8 +534,7 @@ def verify_forward(
 
 def loss_fn(params: Params, cfg: LlamaConfig, tokens: jax.Array) -> jax.Array:
     """Next-token cross entropy over [B, S] tokens."""
-    # XLA path: the train step runs under GSPMD-partitioned jit
-    logits, _ = prefill_forward(params, cfg, tokens, use_pallas=False)
+    logits, _ = prefill_forward(params, cfg, tokens)
     logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
     tgt = tokens[:, 1:]
     nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]

@@ -19,8 +19,7 @@ What differs from models/llama.py, and where it lives:
   for the tokens routed to them, shared experts beside them.  No token is
   dropped and no capacity is set.
 
-Same contracts as ``models.llama.prefill_forward`` / ``decode_forward`` (less
-``use_pallas``: one XLA path, and the engine passes it under a mesh only), so
+Same contracts as ``models.llama.prefill_forward`` / ``decode_forward``, so
 the engine, the scheduler, chunked prefill and the decode scan run it
 unchanged.  There is no verify step (speculation), no LoRA and no mesh path
 for this family: ``serve`` refuses them at start-up.

@@ -206,15 +206,14 @@ def moe_prefill_forward(
     cfg: MoEConfig,
     tokens: jax.Array,
     prefix_kv: jax.Array | None = None,
-    use_pallas: bool = True,
     prefix_len: jax.Array | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """tokens: [B, S] -> (logits [B, S, V], kv [L, 2, B, S, Hkv, D]).
 
     Same contract as models.llama.prefill_forward (including chunked
-    prefill on a padded/bucketed ``prefix_kv`` with traced ``prefix_len``
-    and the ``use_pallas=False`` requirement under GSPMD), so the serving
-    engines and KV paging work unchanged for MoE models.
+    prefill on a padded/bucketed ``prefix_kv`` with traced
+    ``prefix_len``), so the serving engines and KV paging work unchanged
+    for MoE models.
     """
     B, S = tokens.shape
     Pfx = 0 if prefix_kv is None else prefix_kv.shape[3]
@@ -228,14 +227,12 @@ def moe_prefill_forward(
         q, k, v = _attn_qkv(layer, cfg, h, positions)
         kvs.append(jnp.stack([k, v], axis=0))
         if prefix_kv is None:
-            attn = causal_attention(
-                q, k, v, allow_pallas=use_pallas, window=cfg.sliding_window
-            )
+            attn = causal_attention(q, k, v, window=cfg.sliding_window)
         else:
             k_full = jnp.concatenate([prefix_kv[li, 0], k], axis=1)
             v_full = jnp.concatenate([prefix_kv[li, 1], v], axis=1)
             attn = causal_attention(
-                q, k_full, v_full, q_offset=Pfx, allow_pallas=use_pallas,
+                q, k_full, v_full, q_offset=Pfx,
                 prefix_pad=Pfx if prefix_len is not None else None,
                 prefix_len=prefix_len, window=cfg.sliding_window,
             )
@@ -256,7 +253,6 @@ def moe_decode_forward(
     seq_lens: jax.Array,
     slot_block_ids: jax.Array,
     slot_ids: jax.Array,
-    use_pallas: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """Single-token paged MoE decode; contract of models.llama.decode_forward."""
     from ..kv.cache import write_token_kv
@@ -271,7 +267,7 @@ def moe_decode_forward(
         q, k, v = _attn_qkv(layer, cfg, h, pos)
         cache = write_token_kv(cache, li, slot_block_ids, slot_ids, k[:, 0], v[:, 0])
         attn = paged_decode_attention(
-            q[:, 0], cache, li, block_table, seq_lens, allow_pallas=use_pallas,
+            q[:, 0], cache, li, block_table, seq_lens,
             window=cfg.sliding_window,
         )
         x = x + (attn.reshape(B, -1) @ layer["wo"])[:, None, :]
@@ -314,8 +310,7 @@ def moe_verify_forward(
 
 
 def moe_loss_fn(params: Params, cfg: MoEConfig, tokens: jax.Array) -> jax.Array:
-    # XLA path: the train step runs under GSPMD-partitioned jit
-    logits, _ = moe_prefill_forward(params, cfg, tokens, use_pallas=False)
+    logits, _ = moe_prefill_forward(params, cfg, tokens)
     logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
     tgt = tokens[:, 1:]
     nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]

@@ -92,8 +92,7 @@ def make_tp_prefill(cfg: LlamaConfig, mesh: Mesh):
     logits_sharding = NamedSharding(mesh, P("dp", None, "tp"))
 
     def fn(params, tokens):
-        # XLA attention path: this jit is GSPMD-partitioned
-        return prefill_forward(params, cfg, tokens, use_pallas=False)
+        return prefill_forward(params, cfg, tokens)
 
     return jax.jit(
         fn,
@@ -191,11 +190,8 @@ def make_tp_decode(cfg: LlamaConfig, mesh: Mesh):
 
     def fn(params, tokens, positions, cache, block_table, seq_lens,
            slot_block_ids, slot_ids):
-        # use_pallas=False: this jit is GSPMD-partitioned and pallas_call has
-        # no SPMD partitioning rule (see models/attention.py)
         return decode_forward(params, cfg, tokens, positions, cache,
-                              block_table, seq_lens, slot_block_ids, slot_ids,
-                              use_pallas=False)
+                              block_table, seq_lens, slot_block_ids, slot_ids)
 
     # donate the cache: it dominates HBM, and the functional update must not
     # allocate a second copy per token

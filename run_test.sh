@@ -10,6 +10,5 @@ cd "$(dirname "$0")"
 make -C src
 
 # JAX surfaces run on a virtual 8-device CPU mesh (conftest pins the
-# platform); the real-TPU kernel tests skip unless ISTPU_TEST_TPU is set
-# on a machine with a chip (chip_smoke.py runs them there).
+# platform unless ISTPU_TEST_TPU is set on a machine with a chip).
 exec python -m pytest tests/ -q "$@"

@@ -54,7 +54,6 @@ METRICS = {
     "tpu_serving_ttft_p50_ms": ("down", "serving TTFT p50 ms"),
     "tpu_serving_ttft_p99_ms": ("down", "serving TTFT p99 ms"),
     "tpu_spec_speedup": ("up", "speculation speedup"),
-    "tpu_pallas_speedup_vs_xla": ("up", "pallas vs XLA"),
     "goodput_rps": ("up", "serve goodput req/s"),
     "slo_attainment": ("up", "serve SLO attainment"),
     # the step profiler's serving-leg attribution (engine/stepprof.py):

@@ -100,8 +100,7 @@ def _decode_scan_on_tpu(preset, batch, device):
             p = pos + i
             blocks = jnp.take_along_axis(table, (p // T)[:, None], axis=1)[:, 0]
             logits, cache = models.decode_forward(
-                params, cfg, tok, p, cache, table, p + 1, blocks, p % T,
-                use_pallas=False)
+                params, cfg, tok, p, cache, table, p + 1, blocks, p % T)
             return (logits, cache), tok
 
         (logits, cache), toks = jax.lax.scan(

@@ -98,8 +98,8 @@ def test_decode_attention_is_the_repeated_form(n_kv_heads, groups, window,
     lens = np.array([PAGES * T - 1, 2 * T + 1, 0, 0], np.int32)
     got = jax.jit(
         lambda q, c: attention.paged_decode_attention(
-            q, c, LAYER, table, jnp.asarray(lens), allow_pallas=False,
-            window=window, softcap=softcap)
+            q, c, LAYER, table, jnp.asarray(lens), window=window,
+            softcap=softcap)
     )(q, cache)
     assert got.shape == (ROWS, H, D) and got.dtype == q.dtype
     key_mask = _decode_mask(lens, window)
@@ -157,7 +157,7 @@ def test_result_head_h_is_query_head_h_on_its_own_kv_head(n_kv_heads, groups):
     want = np.arange(H) // groups + 1.0
     q = jnp.asarray(rng.standard_normal((ROWS, 2, H, D)), jnp.bfloat16)
     lens = jnp.asarray([PAGES * T - 1, 2 * T + 1, 1, 1], jnp.int32)
-    one = attention.paged_decode_attention_xla(q[:, 0], cache, LAYER, table, lens)
+    one = attention.paged_decode_attention(q[:, 0], cache, LAYER, table, lens)
     np.testing.assert_allclose(
         _f32(one), np.broadcast_to(want[None, :, None], one.shape), **TOL)
     positions = lens[:, None] - 1 + jnp.arange(2)[None, :]

@@ -76,8 +76,7 @@ def test_decode_attention_bit_for_bit(n_kv_heads, groups, window, softcap,
     def run():
         return jax.jit(
             lambda q, c: attention.paged_decode_attention(
-                q, c, LAYER, table, lens, allow_pallas=False, window=window,
-                softcap=softcap)
+                q, c, LAYER, table, lens, window=window, softcap=softcap)
         )(q, cache)
 
     got = run()
@@ -143,7 +142,7 @@ def test_forwards_only_gather_from_and_scatter_into_the_cache(forward):
     ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     if forward == "decode_forward":
         fn = lambda p, c, tok, tab: models.decode_forward(
-            p, cfg, tok, tok, c, tab, tok + 1, tok, tok, use_pallas=False)
+            p, cfg, tok, tok, c, tab, tok + 1, tok, tok)
         args = (params, cache, ints(B), ints(B, 2))
     else:
         fn = lambda p, c, tok, tab: models.verify_forward(
