@@ -266,7 +266,7 @@ class AdmissionController:
     Consulted by ``Scheduler.submit`` (shed/throttle new work with 429 +
     Retry-After) and by the scheduler's step loop (cap prefill tokens
     per step while burning; queued work always drains — see
-    ``Scheduler._admit``).  Reads live state only: the health
+    ``Scheduler._prefill_burst``).  Reads live state only: the health
     sampler's firing watchdogs and flight-recorder ring, the
     scheduler's queue depths, and the engine's KV-pool pressure.
 

@@ -563,6 +563,12 @@ class PartialPrefill:
     launch_s: float = 0.0
     chunks: int = 0
 
+    @property
+    def chunks_left(self) -> int:
+        """Chunk forwards this prefill still needs (what the scheduler
+        orders newcomers by)."""
+        return -(-(len(self.padded) - self.off) // self.C)
+
 
 class InferenceEngine:
     def __init__(

@@ -12,15 +12,27 @@ TPU analog of vLLM's CUDA-graph batch-size buckets.  A request whose budget
 ends mid-chunk decodes to the boundary and is trimmed at retirement.
 
 Flow per ``step()``:
-1. admit pending requests up to ``max_batch``: with an EMPTY batch a whole
-   wave prefills at once (one padded forward per length bucket); with a
-   batch already decoding, up to ``prefill_concurrency`` newcomers ingest
-   via chunked prefill — one prefill chunk EACH per step, interleaved with
-   the batch's decode chunks (vLLM chunked-prefill continuous batching), so
-   neither a long prompt nor a deep queue of long prompts can stall
-   in-flight requests or serialize admission one-completion-at-a-time;
-2. advance every in-progress chunked prefill by one chunk;
-3. decode one chunk for the active batch — through the SPECULATIVE fast
+1. with an EMPTY batch and no prefill in progress, admit pending requests
+   up to ``max_batch`` as one wave (one padded forward per length bucket);
+2. otherwise start the newcomers the FREE decode slots take, in the
+   queue's order (``prefill_start``: store lookup and load, pages; a
+   prompt whose prefix was found starts only when the one before it has
+   run, since it holds that prefix's buffer from the start), and spend the
+   step's PREFILL TOKEN BUDGET of ``max_batch`` chunks (the admission
+   controller's degraded-mode throttle where that is smaller): the first
+   chunk on the OLDEST started prompt, so none is ever passed over for a
+   whole step, the rest on the highest priority and in it on whoever has
+   the FEWEST CHUNKS LEFT, several chunks of one request back to back, so
+   a request joins the batch as early as it can and a short re-ask never
+   queues behind a long new prompt.  How much a step prefills is thus
+   read from its own state, never from an option: a full batch prefills
+   nothing, an emptier one is refilled at once (a free slot is a row's
+   share of a weight read the next dispatch pays for and does not use),
+   and the rows decoding wait at most ``max_batch`` chunks for their next
+   dispatch;
+3. decode one chunk for the active batch in the same step, so prefill never
+   runs two steps without a decode between (vLLM chunked-prefill
+   continuous batching) — through the SPECULATIVE fast
    path when a draft engine is attached and exactly one request is active
    (the configuration where speculation pays: the chip is latency-bound,
    not batch-saturated, cf. vLLM's speculative serving mode);
@@ -82,9 +94,10 @@ class Request:
     # OpenAI logit_bias: token id -> additive bias (densified on device)
     logit_bias: Optional[Dict[int, float]] = None
     # admission priority (vLLM priority scheduling): higher admits first;
-    # FIFO within a priority level.  Affects ADMISSION order only — an
-    # admitted request is never preempted by a later high-priority one
-    # (page backpressure/shedding still applies uniformly).
+    # FIFO within a priority level.  Affects ADMISSION order only (who
+    # starts, and whose prefill chunks a step runs first) — a request that
+    # decodes is never preempted by a later high-priority one (page
+    # backpressure/shedding still applies uniformly).
     priority: int = 0
     # tenant label (usage-attribution plane): the lane label used for
     # metrics, quotas, and the store usage ledger.  None = integer lane
@@ -168,7 +181,7 @@ class Scheduler:
     def __init__(self, engine: InferenceEngine, max_batch: int = 8,
                  rng: Optional[jax.Array] = None,
                  draft_engine: Optional[InferenceEngine] = None,
-                 spec_k: int = 4, prefill_concurrency: int = 4,
+                 spec_k: int = 4,
                  spec_batch: int = 1,
                  ngram_spec: bool = False, spec_g: int = 2,
                  metrics: Optional[MetricsRegistry] = None,
@@ -180,9 +193,9 @@ class Scheduler:
         # SLO-aware admission control (infinistore_tpu/admission.py):
         # when attached, submit() sheds/throttles over-budget or
         # shed-lane work with AdmissionShed (429 + Retry-After at the
-        # serving layer), and _step_inner caps prefill chunk tokens per
-        # step in degraded mode (queued work always drains — see the
-        # note in _admit).  None (the library default) = every
+        # serving layer), and _prefill_budget caps prefill chunk tokens
+        # per step in degraded mode (queued work always drains — see the
+        # note in _prefill_burst).  None (the library default) = every
         # submission admitted, zero overhead.  ServingServer attaches
         # its controller right after construction.
         self.admission = admission
@@ -265,12 +278,10 @@ class Scheduler:
         self._steps = 0  # scheduler steps run (Request.steps_to_first)
         self.pending: List[Request] = []
         self.active: List[Request] = []
-        # chunked-prefill admission: up to ``prefill_concurrency`` newcomers
-        # ingest their prompts one chunk each per step, interleaved with the
-        # active batch's decode chunks (vLLM chunked-prefill continuous
-        # batching)
+        # chunked-prefill admission: the newcomers started into free decode
+        # slots whose prompts are not ingested yet, in the order they were
+        # started (see ``_prefill_burst``)
         self._prefilling: List[Tuple[Request, PartialPrefill]] = []
-        self.prefill_concurrency = max(1, prefill_concurrency)
         self._next_id = 0
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
         # set when decode sheds a request for lack of KV pages: admission
@@ -516,65 +527,159 @@ class Scheduler:
     def has_work(self) -> bool:
         return bool(self.pending or self.active or self._prefilling)
 
-    def _admit(self) -> None:
+    def _prefill_budget(self) -> int:
+        """The most prefill tokens this step may spend before its decode
+        dispatch: ``max_batch`` chunks, so the rows already decoding never
+        wait longer than that for their next dispatch.  What the step
+        SPENDS under it is read from the step's own state: the chunks of
+        the prefills in progress, and newcomers start only into FREE decode
+        slots, pages allowing (``_start_prefill``) — a full batch prefills
+        nothing, one row decoding alone lets ``max_batch - 1`` newcomers
+        in.  The admission controller's degraded-mode throttle wins where
+        it is smaller, rounded up to the one chunk a step can always run.
+        Counted in ``engine.prefill_chunk`` tokens a chunk; without chunked
+        prefill one advance (a whole prompt) counts 1.
+
+        Not rationed by occupancy: the chunks a newcomer needs stall the
+        running rows the same whichever step they run in, and every step
+        it waits half-prefilled is a dispatch whose weight read it misses."""
+        cost = self.engine.prefill_chunk or 1
+        budget = cost * self.max_batch
+        cap = (self.admission.prefill_token_budget()
+               if self.admission is not None else None)
+        if cap is not None:
+            budget = min(budget, max(cap, cost))
+        return budget
+
+    def _start_prefill(self) -> bool:
+        """Begin ``pending[0]``'s chunked prefill (``prefill_start``: prefix
+        lookup, page acquisition, store load) if a decode slot and the
+        pages for it are free, and no prefill started earlier still sits on
+        a loaded prefix it has not run a chunk on: a prompt with a prefix
+        hit takes its bucketed prefix buffer at the start, so those start
+        one at a time, each when the budget has reached the one before; a
+        prompt without a hit holds no buffer until its first chunk has run.
+        False = nobody was started."""
+        if (not self.pending
+                or (self._admission_hold and self.active)
+                or len(self.active) + len(self._prefilling) >= self.max_batch
+                or any(pp.buf is not None and not pp.chunks
+                       for _req, pp in self._prefilling)):
+            return False
+        req = self.pending[0]
+        T = self.engine.pc.block_tokens
+        need = -(-(len(req.tokens) + len(req.output)) // T)
+        if need > self.engine.free_pages:
+            return False  # wait for a retirement to free pages
+        self.pending.pop(0)
+        # queue-wait ends when prefill work BEGINS — stamped BEFORE the
+        # call so prefill_start's store prefix lookup/load I/O counts as
+        # prefill, matching the wave path's t_wave placement (first
+        # admission only; a shed request's re-prefill keeps its original
+        # stamps)
+        first_admission = not req.t_admit
+        if first_admission:
+            req.t_admit = time.perf_counter()
+            req._admit_step = self._steps
+        try:
+            # bound to the REQUEST's own trace: the admission store hops
+            # (kv.lookup_prefix, kv.load_pages) are this request's cost,
+            # not the ambient engine.step's
+            with tracing.bind(req.trace_id), \
+                    _usage.bind_account(self._lane_label(req)):
+                pp = self.engine.prefill_start(
+                    req.tokens + req.output, adapter_id=req.adapter_id,
+                )
+        except MemoryError:
+            if first_admission:
+                req.t_admit = 0.0  # nothing ran; still queued
+            self._enqueue(req, front=True)
+            self._admission_hold = True
+            return False
+        self._prefilling.append((req, pp))
+        return True
+
+    def _prefill_burst(self) -> List[Request]:
+        """A batch is decoding (or prefills are in progress): spend this
+        step's prefill budget, chunk by chunk.  Before every chunk the
+        newcomers the FREE decode slots take are started, in ``pending``'s
+        order (priority, then oldest; ``_start_prefill``).  The step's
+        FIRST chunk goes to the OLDEST started prefill, so every started
+        prompt gains a chunk in every step it is the oldest, whatever
+        arrives behind it.
+        Every other chunk goes to the highest priority, and within it to
+        whoever has the FEWEST CHUNKS LEFT (the oldest among equals),
+        chunks of one request back to back: a request joins the decode
+        batch as early as the budget allows, and a re-ask whose prefix came
+        from the store passes a long new prompt instead of queueing behind
+        its chunks.  A prefill that has run is passed only by a shorter or
+        a more urgent one, so few unfinished ones hold a buffer of computed
+        prefix.  Returns the requests cancelled mid-prefill (always
+        processed: they FREE resources).
+
+        NOTE on degraded mode: work already in ``pending`` is never held
+        back by lane here — freezing shed-lane backlog would only let it
+        age into guaranteed SLO violations that re-ignite the burn the
+        moment it clears (a fire/clear oscillation).  The throttle's one
+        chunk a step is the step's first, so it goes to the oldest started
+        prefill of any lane; protected lanes come
+        first where ``pending`` is sorted (who STARTS next) and in every
+        chunk after a step's first.  The admission controller acts at the
+        submit boundary (shed new work) and through the budget's cap;
+        queued work always drains."""
+        cancelled: List[Request] = []
+
+        def drop(i: int) -> None:
+            req, pp = self._prefilling.pop(i)
+            self.engine.abandon_prefill(pp)
+            req.done = True
+            self._stream(req, done=True)
+            self._finish(req, "cancelled")
+            cancelled.append(req)
+
+        for i in reversed(range(len(self._prefilling))):
+            if self._prefilling[i][0].cancelled:
+                drop(i)
+        granted = self._prefill_budget()
+        cost = self.engine.prefill_chunk or 1
+        spent = 0
+        while granted - spent >= cost:
+            while self._start_prefill():
+                pass
+            if not self._prefilling:
+                break
+            i = 0 if not spent else min(
+                range(len(self._prefilling)),
+                key=lambda j: (-self._prefilling[j][0].priority,
+                               self._prefilling[j][1].chunks_left))
+            req, pp = self._prefilling[i]
+            if req.cancelled:      # another thread's cancel, mid-burst
+                drop(i)
+                continue
+            with tracing.bind(req.trace_id), \
+                    _usage.bind_account(self._lane_label(req)):
+                st = self.engine.prefill_step(pp)
+            spent += cost
+            if st is not None:
+                self._prefilling.pop(i)
+                self._prefilled(req, st)
+        _stepprof.note_prefill_budget(granted, spent)
+        return cancelled
+
+    def _admit(self) -> List[Request]:
+        """Admission for one step: the budgeted chunked-prefill burst while
+        anything is in flight, else the whole pending wave at once.
+        Returns the requests cancelled mid-prefill."""
         # sampling params are per-row traced vectors in the compiled decode
-        # (engine._decode_many), so admission is pure FIFO — a greedy request
-        # and a top-p request share one lockstep batch
-        if not self.pending:
-            return
-        # NOTE on degraded mode: work already in ``pending`` is never
-        # held back by lane here — the queue is priority-sorted, so
-        # protected lanes admit first anyway, and freezing shed-lane
-        # backlog would only let it age into guaranteed SLO violations
-        # that re-ignite the burn the moment it clears (a fire/clear
-        # oscillation).  The admission controller acts at the submit
-        # boundary (shed new work) and via the per-step prefill token
-        # budget (_step_inner); queued work always drains.
+        # (engine._decode_many), so admission never sorts by them — a greedy
+        # request and a top-p request share one lockstep batch
         if self.active or self._prefilling:
-            # a batch is decoding (or newcomers are already ingesting):
-            # admit newcomers via CHUNKED prefill — prefill_start here, one
-            # prefill_step each per step() interleaved with the batch's
-            # decode chunks.  Up to ``prefill_concurrency`` ingest
-            # concurrently so a deep queue of long prompts doesn't
-            # serialize admission one-completion-at-a-time while decode
-            # slots sit idle.
-            T = self.engine.pc.block_tokens
-            while (self.pending
-                   and len(self._prefilling) < self.prefill_concurrency
-                   and (len(self.active) + len(self._prefilling)
-                        < self.max_batch)):
-                req = self.pending[0]
-                need = -(-(len(req.tokens) + len(req.output)) // T)
-                if need > self.engine.free_pages:
-                    return  # wait for a retirement to free pages
-                self.pending.pop(0)
-                # queue-wait ends when prefill work BEGINS — stamped
-                # BEFORE the call so prefill_start's store prefix
-                # lookup/load I/O counts as prefill, matching the wave
-                # path's t_wave placement (first admission only; a shed
-                # request's re-prefill keeps its original stamps)
-                first_admission = not req.t_admit
-                if first_admission:
-                    req.t_admit = time.perf_counter()
-                    req._admit_step = self._steps
-                try:
-                    # bound to the REQUEST's own trace: the admission
-                    # store hops (kv.lookup_prefix, kv.load_pages) are
-                    # this request's cost, not the ambient engine.step's
-                    with tracing.bind(req.trace_id), \
-                            _usage.bind_account(self._lane_label(req)):
-                        pp = self.engine.prefill_start(
-                            req.tokens + req.output,
-                            adapter_id=req.adapter_id,
-                        )
-                except MemoryError:
-                    if first_admission:
-                        req.t_admit = 0.0  # nothing ran; still queued
-                    self._enqueue(req, front=True)
-                    self._admission_hold = True
-                    return
-                self._prefilling.append((req, pp))
-            return
+            return self._prefill_burst()
+        if self.pending:
+            self._admit_wave()
+        return []
+
+    def _admit_wave(self) -> None:
         admit: List[Request] = []
         while self.pending and len(self.active) + len(admit) < self.max_batch:
             admit.append(self.pending.pop(0))
@@ -933,40 +1038,8 @@ class Scheduler:
         # (kv.*, prefill.launch, decode.*), ``retire_stream`` ends the step
         self._steps += 1
         _stepprof.enter("admit")
-        if not (self._admission_hold and self.active):
-            self._admit()
+        cancelled_prefill = self._admit()
         _stepprof.enter("sched")
-        cancelled_prefill: List[Request] = []
-        still: List[Tuple[Request, PartialPrefill]] = []
-        # degraded-mode chunked-prefill throttle: while a burn watchdog
-        # fires, only this many prefill chunk tokens advance per step
-        # (None = no cap) — decode keeps its TPOT for the protected
-        # lane, prefill queues.  Cancellations always process (they FREE
-        # resources).
-        pf_budget = (self.admission.prefill_token_budget()
-                     if self.admission is not None else None)
-        chunk_cost = self.engine.prefill_chunk or 1
-        for req, pp in self._prefilling:
-            if req.cancelled:
-                self.engine.abandon_prefill(pp)
-                req.done = True
-                self._stream(req, done=True)
-                self._finish(req, "cancelled")
-                cancelled_prefill.append(req)
-                continue
-            if pf_budget is not None and pf_budget <= 0:
-                still.append((req, pp))  # over budget: hold this step
-                continue
-            with tracing.bind(req.trace_id), \
-                    _usage.bind_account(self._lane_label(req)):
-                st = self.engine.prefill_step(pp)  # ONE chunk per step each
-            if pf_budget is not None:
-                pf_budget -= chunk_cost
-            if st is not None:
-                self._prefilled(req, st)
-            else:
-                still.append((req, pp))
-        self._prefilling = still
         if not self.active:
             return cancelled_prefill
         if any(r.cancelled for r in self.active):

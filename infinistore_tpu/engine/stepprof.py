@@ -51,7 +51,9 @@ structured record per scheduler step:
   of it, so an enclosing annotation would name every gap;
 * **counts at the dispatch** — ``note_decode`` records what the program
   knows when it launches the decode scan: steps, live rows, the context
-  tokens they hold and the table tokens the attention reads.
+  tokens they hold and the table tokens the attention reads;
+  ``note_prefill_budget`` records, at the scheduler's call, the prefill
+  chunk tokens a step was granted and the ones it spent.
 
 Records live in a bounded ring (``ISTPU_STEPPROF_RING``, default 256),
 exported at the serving front-end's ``GET /debug/engine`` (``?limit=``),
@@ -94,6 +96,9 @@ MAX_STEP_IDS = 64
 # what ``note_decode`` sums, per step record and over the lifetime
 DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
                  "table_token_steps", "expert_pairs", "experts_expected")
+
+# what ``note_prefill_budget`` sums, per step record and over the lifetime
+PREFILL_COUNTS = ("granted_tokens", "spent_tokens")
 
 # the key that counts operations in the transfer's running totals
 _STORE_COUNT = {"push": "pushes", "load": "loads"}
@@ -266,6 +271,23 @@ def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
             n_experts * (1.0 - (1.0 - k / n_experts) ** rows) * layers * steps)
 
 
+def note_prefill_budget(granted_tokens: int, spent_tokens: int) -> None:
+    """Count ONE step's prefill token budget at the scheduler's call: the
+    chunk tokens the step was GRANTED (``max_batch`` chunks, or the
+    degraded-mode cap where smaller) and the chunk tokens it SPENT
+    (chunks run x ``prefill_chunk``; never more than granted).  Only
+    steps with a batch in flight have a budget: an idle engine admits a
+    whole wave and counts nothing here.  ``spent / granted`` near 1 says
+    admission is held by the budget; far below it, by arrivals, slots or
+    pages.  Summed under ``rec["prefill"]``."""
+    rec = _ACTIVE.get()
+    if rec is None:
+        return
+    b = rec.setdefault("prefill", dict.fromkeys(PREFILL_COUNTS, 0))
+    b["granted_tokens"] += granted_tokens
+    b["spent_tokens"] += spent_tokens
+
+
 def enter(name: Optional[str]) -> float:
     """``StepProfiler.enter`` on the profiler driving this thread's step,
     and the switch's clock stamp: two of them time a site once.  A plain
@@ -407,6 +429,8 @@ class StepProfiler:
         self.tokens = 0
         # lifetime sums of the decode dispatches' counts (note_decode)
         self._decode_totals = dict.fromkeys(DECODE_COUNTS, 0)
+        # lifetime sums of the steps' prefill budgets (note_prefill_budget)
+        self._prefill_totals = dict.fromkeys(PREFILL_COUNTS, 0)
         # flat phases of the driving thread (see ``enter``)
         self.phase: Optional[str] = None
         self._phase_t0 = 0.0
@@ -676,6 +700,8 @@ class StepProfiler:
             self.tokens += rec["tokens"]
             for k, n in rec.get("decode", {}).items():
                 self._decode_totals[k] += n
+            for k, n in rec.get("prefill", {}).items():
+                self._prefill_totals[k] += n
             self._wall_s += dur
             if sampled:
                 self._sampled += 1
@@ -762,6 +788,7 @@ class StepProfiler:
             spec_tot = dict(self._spec_totals)
             tokens = self.tokens
             decode = dict(self._decode_totals)
+            prefill = dict(self._prefill_totals)
             # the open phase counts up to this moment: a scrape in the
             # middle of a long decode.wait loses nothing
             phase_s = dict(self._phase_s)
@@ -812,6 +839,8 @@ class StepProfiler:
             "phase_wall_s": round(phase_wall, 6),
             # the decode dispatches' counts, summed (note_decode)
             "decode": decode,
+            # the steps' prefill token budgets, summed (note_prefill_budget)
+            "prefill": prefill,
             "store": {k: dict(t) for k, t in
                       self._store_totals(self._transfer).items()},
         }

@@ -69,7 +69,6 @@ class ServingServer:
                  tokenizer=None, draft_engine=None, spec_k: int = 4,
                  max_queue: Optional[int] = None, spec_batch: int = 1,
                  ngram_spec: bool = False, spec_g: int = 2,
-                 prefill_concurrency: int = 4,
                  slo_ttft_s: Optional[float] = None,
                  slo_tpot_s: Optional[float] = None,
                  ledger_ring: Optional[int] = None,
@@ -171,7 +170,6 @@ class ServingServer:
                                draft_engine=draft_engine, spec_k=spec_k,
                                spec_batch=spec_batch,
                                ngram_spec=ngram_spec, spec_g=spec_g,
-                               prefill_concurrency=prefill_concurrency,
                                metrics=self.metrics, ledger=self.ledger,
                                session_ledger=self.sessions,
                                slo_ttft_s=slo_ttft_s, slo_tpot_s=slo_tpot_s,
@@ -2542,10 +2540,6 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument("--n-blocks", type=int, default=512)
     ap.add_argument("--block-tokens", type=int, default=16)
     ap.add_argument("--prefill-chunk", type=int, default=None)
-    ap.add_argument("--prefill-concurrency", type=int, default=4,
-                    help="newcomers ingesting one prompt chunk each per "
-                         "scheduler step, interleaved with decode; raise "
-                         "it when TTFT queue-wait dominates /metrics")
     ap.add_argument("--decode-chunk", type=int, default=32,
                     help="tokens per compiled decode dispatch: 32 favors "
                     "streaming granularity, 64/128 trade it for throughput "
@@ -2842,7 +2836,6 @@ def main(argv: Optional[List[str]] = None) -> None:
                         spec_k=args.spec_k, max_queue=args.max_queue,
                         spec_batch=args.spec_batch,
                         ngram_spec=args.ngram_spec, spec_g=args.spec_g,
-                        prefill_concurrency=args.prefill_concurrency,
                         slo_ttft_s=args.slo_ttft, slo_tpot_s=args.slo_tpot,
                         ledger_ring=args.ledger_ring,
                         session_ring=args.session_ring,

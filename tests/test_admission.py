@@ -321,6 +321,32 @@ def test_prefill_budget_degraded_mode():
     assert c3.prefill_token_budget() == 256
 
 
+@pytest.mark.parametrize("active,burn,cap,want", [
+    (1, None, None, 8 * 64),          # healthy: max_batch chunks a step
+    (1, "ttft_burn", None, 8 * 64),   # a TTFT burn does not arm the throttle
+    (1, "tpot_burn", None, 64),       # degraded: one chunk, the smaller wins
+    (1, "tpot_burn", 256, 256),       # the explicit cap, still the smaller
+    (1, "tpot_burn", 4096, 8 * 64),   # a cap over the step's budget: the step's
+    (8, "tpot_burn", 256, 256),       # occupancy bounds WHO starts, not this
+    (1, "tpot_burn", 16, 64),         # under one chunk: the one a step can run
+])
+def test_scheduler_budget_is_the_smaller_of_state_and_throttle(
+        active, burn, cap, want):
+    """The scheduler's per-step prefill budget against the degraded-mode
+    throttle: the seam is ``prefill_token_budget()`` and the smaller of
+    the two rules."""
+    from infinistore_tpu.engine.scheduler import Scheduler
+
+    c = _ctrl(engine=StubEngine(prefill_chunk=64), prefill_cap_tokens=cap)
+    c.check_submit(0, 1)
+    if burn:
+        c.sampler.fire_burn(2.5, rule=burn)
+    sched = StubSched()
+    sched.active = [None] * active
+    sched.max_batch, sched.engine, sched.admission = 8, c.engine, c
+    assert Scheduler._prefill_budget(sched) == want
+
+
 def test_kill_switch_and_snapshot_shape():
     c = _ctrl(enabled=False)
     c.sampler.fire_burn(99.0)

@@ -224,68 +224,387 @@ def test_scheduler_continuous_batching():
     assert eng.free_pages == eng.pc.n_blocks
 
 
-def test_scheduler_interleaves_chunked_prefill_with_decode():
-    """A newcomer's long prompt must NOT stall the active batch: with a
-    batch decoding, admission runs ONE prefill chunk per step interleaved
-    with decode chunks, and both requests still produce exact greedy
-    output."""
-    from infinistore_tpu.engine import Scheduler
+LONG_PROMPT = PROMPT + PROMPT + PROMPT     # 33 tokens -> 9 chunks at T=4
 
-    eng = InferenceEngine(PARAMS, CFG, make_pc(), prefill_chunk=T)
+
+def _spy_sched(max_batch, admission=None):
+    """A chunked-prefill scheduler whose engine logs one letter a call:
+    ``p`` a prefill chunk, ``s`` a prefill start, ``d`` a decode dispatch,
+    ``|`` the end of a scheduler step (the caller appends it)."""
+    from infinistore_tpu.engine import Scheduler
+    from infinistore_tpu.engine.stepprof import StepProfiler
+
+    eng = InferenceEngine(PARAMS, CFG, make_pc(256), prefill_chunk=T)
     eng.decode_chunk = 2
     calls = []
-    orig_step, orig_decode = eng.prefill_step, eng.decode_batch
-    eng.prefill_step = lambda pp: (calls.append("p"), orig_step(pp))[1]
-    eng.decode_batch = lambda *a, **k: (calls.append("d"),
-                                        orig_decode(*a, **k))[1]
-    sched = Scheduler(eng, max_batch=4)
-    first = sched.submit(PROMPT[:5], 10)      # starts decoding immediately
-    sched.step()                              # wave-prefill + first chunk
-    long_prompt = PROMPT + PROMPT + PROMPT    # 33 tokens -> 9 chunks at T=4
-    second = sched.submit(long_prompt, 4)
-    out = sched.run()
-    assert out[first] == dense_greedy(PROMPT[:5], 10)
-    assert out[second] == dense_greedy(long_prompt, 4)
-    # the newcomer's prefill chunks were interleaved with decode chunks,
-    # not run back to back before the batch could decode again
-    joined = "".join(calls)
-    assert "pd" in joined and "dp" in joined, joined
+    for letter, name in (("p", "prefill_step"), ("s", "prefill_start"),
+                         ("d", "decode_batch")):
+        def spy(*a, _f=getattr(eng, name), _l=letter, **k):
+            calls.append(_l)
+            return _f(*a, **k)
+        setattr(eng, name, spy)
+    sched = Scheduler(eng, max_batch=max_batch, admission=admission,
+                      stepprof=StepProfiler())
+    return eng, sched, calls
 
 
-def test_scheduler_concurrent_chunked_prefills_fill_idle_slots():
-    """Deep queue of long prompts behind a decoding batch (VERDICT r3 weak
-    #7): up to ``prefill_concurrency`` newcomers ingest CONCURRENTLY (one
-    chunk each per step), so the batch fills in ~one prompt's worth of
-    chunks instead of serializing one admission per completion — and every
-    request still matches its solo greedy decode."""
-    from infinistore_tpu.engine import Scheduler
-
-    eng = InferenceEngine(PARAMS, CFG, make_pc(), prefill_chunk=T)
-    eng.decode_chunk = 2
-    sched = Scheduler(eng, max_batch=8, prefill_concurrency=4)
-    first = sched.submit(PROMPT[:5], 28)   # long-running active request
-    sched.step()                           # wave prefill + first chunk
-    long_prompt = PROMPT + PROMPT + PROMPT  # 33 tokens -> 9 chunks at T=4
-    newcomers = [sched.submit(long_prompt, 4) for _ in range(5)]
-    sched.step()
-    # admission did NOT serialize: several newcomers are mid-ingestion at
-    # once (the old scheduler held exactly one) — and the concurrency CAP
-    # held the fifth back in the queue
-    assert len(sched._prefilling) == 4
-    assert len(sched.pending) == 1
-    peak_active = 0
+def _run_steps(sched, calls):
     results = {}
     while sched.has_work:
         for r in sched.step():
             results[r.req_id] = r.output
+        calls.append("|")
+    return results
+
+
+def test_scheduler_interleaves_chunked_prefill_with_decode():
+    """A newcomer's long prompt must NOT stall the active batch for more
+    than the step's budget: its chunks run back to back, ``max_batch`` of
+    them at most, and a decode dispatch follows in the SAME step; both
+    requests still produce exact greedy output."""
+    eng, sched, calls = _spy_sched(max_batch=4)
+    first = sched.submit(PROMPT[:5], 10)      # starts decoding immediately
+    sched.step()                              # wave-prefill + first chunk
+    del calls[:]
+    second = sched.submit(LONG_PROMPT, 4)
+    out = _run_steps(sched, calls)
+    assert out[first] == dense_greedy(PROMPT[:5], 10)
+    assert out[second] == dense_greedy(LONG_PROMPT, 4)
+    steps = "".join(calls).split("|")
+    # a batch of four: four chunks a step (the first page is a local
+    # prefix hit, eight remain), each burst followed by the batch's decode
+    # dispatch before the step ends
+    assert steps[:2] == ["sppppd", "ppppd"], steps
+    assert all(st.endswith("d") for st in steps if "p" in st), steps
+    assert eng.free_pages == eng.pc.n_blocks
+
+
+@pytest.mark.parametrize("max_batch,n_active,want", [
+    (2, 2, ""),               # a full batch: no slot, nobody is started
+    (8, 1, "spspppppppd"),    # one row of eight: the budget's eight chunks
+    (4, 1, "spspppd"),        # four of the older newcomer's eight
+    (8, 6, "spspppppppd"),    # two slots, two started; eight chunks all the same
+    (8, 7, "sppppppppd"),     # one slot: only the OLDEST newcomer is started
+])
+def test_scheduler_prefill_budget_and_free_slots(max_batch, n_active, want):
+    """What a step prefills is read from its own state: newcomers start
+    only into FREE decode slots, oldest first (the second once the first,
+    which sits on a prefix hit, has run a chunk), and the burst ends after
+    ``max_batch`` chunks (``_prefill_budget``), with the decode dispatch
+    behind it."""
+    eng, sched, calls = _spy_sched(max_batch=max_batch)
+    sched.submit(PROMPT[:5], 40)
+    for i in range(1, n_active):
+        sched.submit([60 + i] + PROMPT[:4 + i], 40)   # no shared pages
+    sched.step()                              # the wave; all decode now
+    assert len(sched.active) == n_active
+    assert sched._prefill_budget() == max_batch * T
+    sched.submit(LONG_PROMPT, 2)              # eight chunks after its hit
+    sched.submit(LONG_PROMPT[1:], 2)          # eight, no hit
+    del calls[:]
+    sched.step()
+    assert "".join(calls) == want + "d" * (not want), calls
+    rec = sched.stepprof.tail(1)[0]
+    assert rec["prefill"]["granted_tokens"] == max_batch * T
+    assert rec["prefill"]["spent_tokens"] == want.count("p") * T
+    assert rec["dispatches"].get("prefill", 0) == want.count("p")
+
+
+def _ran_unfinished(sched):
+    """In-progress prefills that have run a chunk: the ones that hold a
+    buffer of computed prefix KV."""
+    return [r.req_id for r, pp in sched._prefilling if pp.chunks]
+
+
+def test_scheduler_prefill_burst_back_to_back_deep_queue():
+    """Deep queue of equally long prompts behind a decoding batch: the free
+    slots take them all at once, the budget goes to ONE of them at a time,
+    chunks back to back, oldest first among equals — so requests join the
+    batch in submission order, at most one unfinished prefill has run (and
+    holds a buffer), and every request still matches its solo greedy
+    decode with all pages free at the end."""
+    eng, sched, calls = _spy_sched(max_batch=8)
+    first = sched.submit(PROMPT[:5], 28)   # long-running active request
+    sched.step()                           # wave prefill + first chunk
+    del calls[:]
+    wave = sched.stepprof.summary()["dispatches"]["prefill"]
+    prompts = [[70 + i] + LONG_PROMPT[:-1] for i in range(5)]   # 9 chunks each
+    newcomers = [sched.submit(p, 12) for p in prompts]
+    sched.step()
+    calls.append("|")
+    # five of the seven free slots taken; the budget's eight chunks all
+    # went to the OLDEST newcomer (equal lengths), which has one left
+    assert "".join(calls) == "sssssppppppppd|", calls
+    assert [r.req_id for r, _pp in sched._prefilling] == newcomers
+    assert _ran_unfinished(sched) == [newcomers[0]]
+    joined, peak_active = [], 0
+    results = {}
+    while sched.has_work:
+        for r in sched.step():
+            results[r.req_id] = r.output
+        calls.append("|")
+        assert len(_ran_unfinished(sched)) <= 1
+        for r in sched.active:
+            if r.req_id not in joined:
+                joined.append(r.req_id)
         peak_active = max(peak_active, len(sched.active))
-    # the batch actually filled past the serialized-admission ceiling of 2
+    assert [j for j in joined if j != first] == newcomers   # oldest first
+    # the batch filled past the one-admission-per-completion ceiling of 2
     assert peak_active >= 4, peak_active
-    want_long = dense_greedy(long_prompt, 4)
-    for rid in newcomers:
-        assert results[rid] == want_long
+    for rid, p in zip(newcomers, prompts):
+        assert results[rid] == dense_greedy(p, 12)
     assert results[first] == dense_greedy(PROMPT[:5], 28)
     assert eng.free_pages == eng.pc.n_blocks
+    # the counts add up: never more spent than granted, and what was spent
+    # is the chunks that ran
+    tot = sched.stepprof.summary()["prefill"]
+    n_chunks = "".join(calls).count("p")
+    assert tot["spent_tokens"] == n_chunks * T
+    assert tot["spent_tokens"] <= tot["granted_tokens"]
+    assert (sched.stepprof.summary()["dispatches"]["prefill"] - wave
+            == n_chunks)
+    for rec in sched.stepprof.tail():
+        b = rec.get("prefill")
+        assert b is None or b["spent_tokens"] <= b["granted_tokens"]
+
+
+def test_scheduler_short_newcomer_passes_a_long_prefill():
+    """Fewest chunks left first: a short prompt that arrives while a long
+    one is mid-ingestion is started into a free slot, prefilled and
+    decoding in its FIRST step, instead of queueing behind the long one's
+    chunks (a re-ask whose prefix came from the store, behind a new
+    document); the long one goes on with the rest of the budget."""
+    eng, sched, calls = _spy_sched(max_batch=4)
+    first = sched.submit(PROMPT[:5], 30)
+    sched.step()
+    long_p = [80] + LONG_PROMPT + LONG_PROMPT       # 67 tokens: 17 chunks
+    big = sched.submit(long_p, 4)
+    del calls[:]
+    sched.step()
+    assert "".join(calls) == "sppppd", calls        # four of seventeen
+    short = sched.submit([81, 5, 9], 4)             # one chunk
+    del calls[:]
+    done = sched.step()
+    # the short one first (one chunk), then three more of the long one's
+    assert "".join(calls) == "sppppd", calls
+    assert short in [r.req_id for r in sched.active + done]
+    assert [r.req_id for r, _pp in sched._prefilling] == [big]
+    assert sched._prefilling[0][1].chunks == 7
+    out = _run_steps(sched, calls)
+    out.update({r.req_id: r.output for r in done})
+    assert out[short] == dense_greedy([81, 5, 9], 4)
+    assert out[big] == dense_greedy(long_p, 4)
+    assert out[first] == dense_greedy(PROMPT[:5], 30)
+    assert eng.free_pages == eng.pc.n_blocks
+
+
+def test_scheduler_long_prefill_gains_a_chunk_every_step():
+    """A steady stream of short newcomers whose chunks alone exceed the
+    budget must not starve a long prompt: the step's first chunk goes to
+    the OLDEST started prefill, so the long one's chunk count grows in
+    EVERY step (the progress the one-chunk-each rotation gave), and the
+    short ones still pass it with the rest of the budget."""
+    eng, sched, calls = _spy_sched(max_batch=4)          # four chunks a step
+    first = sched.submit(PROMPT[:5], 60)
+    sched.step()
+    long_p = [80] + LONG_PROMPT + LONG_PROMPT            # 17 chunks
+    big = sched.submit(long_p, 4)
+    shorts, passed, results = {}, 0, {}
+    for n in range(40):
+        if not any(r.req_id == big for r, _pp in sched._prefilling) and n:
+            break
+        # two free slots, two short newcomers of two chunks each: four
+        # chunks wanted by the short ones alone, in every step
+        while len(sched.pending) < 2:
+            p = [100 + len(shorts), 7, 9, 11, 13]
+            shorts[sched.submit(p, 2)] = p
+        before = {r.req_id: pp.chunks for r, pp in sched._prefilling}
+        del calls[:]
+        for r in sched.step():
+            results[r.req_id] = r.output
+        after = {r.req_id: pp.chunks for r, pp in sched._prefilling}
+        if big in after:
+            assert after[big] > before.get(big, 0), (n, before, after)
+        assert "".join(calls).count("p") == 4, calls     # the budget, spent
+        passed = sum(1 for rid in results if rid in shorts)
+    else:
+        raise AssertionError("the long prompt never finished")
+    assert passed >= 8        # short ones passed it all the while
+    results.update(_run_steps(sched, calls))
+    assert results[big] == dense_greedy(long_p, 4)
+    assert results[first] == dense_greedy(PROMPT[:5], 60)
+    for rid, p in shorts.items():
+        assert results[rid] == dense_greedy(p, 2)
+    assert eng.free_pages == eng.pc.n_blocks
+
+
+def test_scheduler_prefill_order_is_priority_then_fewest_chunks_left():
+    """After the oldest's chunk the budget goes by (priority, chunks left):
+    a protected-lane long prompt runs before a one-chunk prompt of a lower
+    lane, and within a lane the shorter first."""
+    eng, sched, calls = _spy_sched(max_batch=8)
+    first = sched.submit(PROMPT[:5], 40)
+    sched.step()
+    a = sched.submit([80] + LONG_PROMPT + LONG_PROMPT, 8)           # 17
+    sched.step()                                  # eight of them; nine left
+    b = sched.submit([81] + LONG_PROMPT + PROMPT, 8, priority=1)    # 12
+    c = sched.submit([82, 5, 9], 8)                                 # one
+    d = sched.submit([83] + PROMPT, 8, priority=1)                  # three
+    sched.step()
+    # the oldest (a) one chunk; then lane 1: d's three, four of b's; c none
+    ran = {r.req_id: pp.chunks for r, pp in sched._prefilling}
+    assert ran == {a: 9, b: 4, c: 0}, ran
+    assert d in [r.req_id for r in sched.active]
+    sched.step()
+    # a one more; b's eight left take the other seven; c still waits
+    ran = {r.req_id: pp.chunks for r, pp in sched._prefilling}
+    assert ran == {a: 10, b: 11, c: 0}, ran
+    sched.step()
+    # a, then b's last, then lane 0 by chunks left: c's one, five more of a
+    assert c in [r.req_id for r in sched.active]
+    assert b in [r.req_id for r in sched.active]
+    assert [(r.req_id, pp.chunks) for r, pp in sched._prefilling] == [(a, 16)]
+    out = _run_steps(sched, calls)
+    assert out[c] == dense_greedy([82, 5, 9], 8)
+    assert out[b] == dense_greedy([81] + LONG_PROMPT + PROMPT, 8)
+    assert eng.free_pages == eng.pc.n_blocks
+
+
+def test_scheduler_one_loaded_prefix_waits_at_a_time():
+    """A prompt with a prefix hit takes its prefix buffer when it STARTS,
+    so such prompts start one at a time, each once the one before has run
+    a chunk; prompts without a hit hold nothing until they run and start
+    into every free slot at once."""
+    eng, sched, calls = _spy_sched(max_batch=8)
+    first = sched.submit(PROMPT[:5], 40)          # registers PROMPT[:4]'s page
+    sched.step()
+    inner, waiting = eng.prefill_start, []
+
+    def watch(*a, **k):
+        waiting.append(sum(1 for _r, pp in sched._prefilling
+                           if pp.buf is not None and not pp.chunks))
+        return inner(*a, **k)
+
+    eng.prefill_start = watch
+    reasks = [PROMPT[:4] + [70 + i, 3, 5] for i in range(4)]  # a hit, one chunk
+    ids = [sched.submit(p, 2) for p in reasks]
+    fresh = [sched.submit([90 + i] + PROMPT, 2) for i in range(2)]  # no hit
+    del calls[:]
+    done = sched.step()
+    # each re-ask runs before the next starts; the fresh ones start together
+    assert "".join(calls) == "spspspspssppppd", calls
+    assert waiting == [0] * 6
+    assert not {r.req_id for r, _pp in sched._prefilling} & set(ids)
+    out = _run_steps(sched, calls)
+    out.update({r.req_id: r.output for r in done})
+    for rid, p in zip(ids, reasks):
+        assert out[rid] == dense_greedy(p, 2)
+    for i, rid in enumerate(fresh):
+        assert out[rid] == dense_greedy([90 + i] + PROMPT, 2)
+    assert eng.free_pages == eng.pc.n_blocks
+
+
+class _Throttle:
+    """The admission controller's seam as the scheduler sees it."""
+
+    def __init__(self, cap):
+        self.cap = cap
+
+    def check_submit(self, **kw):
+        import types
+        return types.SimpleNamespace(admitted=True)
+
+    def prefill_token_budget(self):
+        return self.cap
+
+
+@pytest.mark.parametrize("cap,want_chunks", [
+    (T, 1),          # degraded mode: one chunk a step, seven slots free
+    (3 * T, 3),
+    (1, 1),          # smaller than a chunk: the one chunk a step can run
+    (100 * T, 8),    # larger than the step's own budget: that one wins
+    (None, 8),       # healthy: no throttle
+])
+def test_scheduler_degraded_throttle_wins_when_smaller(cap, want_chunks):
+    eng, sched, calls = _spy_sched(max_batch=8, admission=_Throttle(cap))
+    first = sched.submit(PROMPT[:5], 12)
+    sched.step()
+    del calls[:]
+    second = sched.submit(LONG_PROMPT, 6)
+    sched.step()
+    assert "".join(calls) == "s" + "p" * want_chunks + "d", calls
+    out = _run_steps(sched, calls)
+    assert out[second] == dense_greedy(LONG_PROMPT, 6)
+    assert out[first] == dense_greedy(PROMPT[:5], 12)
+    assert eng.free_pages == eng.pc.n_blocks
+
+
+def test_scheduler_degraded_mode_mixed_lanes_every_started_prompt_drains():
+    """Degraded mode, one chunk a step, two lanes: the chunk is the step's
+    first, so it goes to the OLDEST started prefill whatever its lane (as
+    it did before the budget) and a protected-lane long prompt is never
+    starved by one-chunk newcomers; where both wait in ``pending`` the
+    protected lane STARTS first."""
+    eng, sched, calls = _spy_sched(max_batch=8, admission=_Throttle(T))
+    first = sched.submit(PROMPT[:5], 60)
+    sched.step()
+    low = sched.submit([80] + PROMPT, 2)                     # three chunks
+    prot = sched.submit([81] + LONG_PROMPT, 2, priority=1)   # nine
+    order = []
+    for n in range(40):
+        if n < 20:   # one-chunk newcomers of either lane keep arriving
+            sched.submit([100 + n, 5, 9], 2, priority=n % 2)
+        before = {r.req_id: pp.chunks for r, pp in sched._prefilling}
+        del calls[:]
+        sched.step()
+        assert "".join(calls).count("p") <= 1, calls
+        for r, pp in sched._prefilling:
+            if pp.chunks > before.get(r.req_id, 0):
+                order.append(r.req_id)
+        oldest = next(iter(before), None)
+        if oldest is not None and "p" in calls:
+            still = {r.req_id: pp.chunks for r, pp in sched._prefilling}
+            assert oldest not in still or still[oldest] > before[oldest]
+        if not sched._prefilling and not sched.pending:
+            break
+    # the protected prompt started first and took the first eight steps'
+    # chunks (its ninth finished it); the low lane's prompt came next
+    assert order[:8] == [prot] * 8, order
+    assert order[8:10] == [low] * 2, order
+    out = _run_steps(sched, calls)
+    assert eng.free_pages == eng.pc.n_blocks
+
+
+def test_scheduler_cancel_mid_burst_frees_pages():
+    """A cancellation that lands while the request's chunks are running
+    back to back (another thread's ``cancel``) stops the burst at the next
+    chunk: its pages go back, the budget moves on to the next newcomer in
+    the same step, and the batch keeps decoding."""
+    eng, sched, calls = _spy_sched(max_batch=8)
+    first = sched.submit(PROMPT[:5], 8)
+    sched.step()
+    del calls[:]
+    victim = sched.submit(LONG_PROMPT, 4)           # eight chunks left
+    nxt = sched.submit([90] + LONG_PROMPT, 4)       # nine
+    inner = eng.prefill_step
+
+    def cancel_after_two(pp):
+        st = inner(pp)
+        if "".join(calls).count("p") == 2:
+            assert sched.cancel(victim)
+        return st
+
+    eng.prefill_step = cancel_after_two
+    done = sched.step()
+    # two chunks of the victim, then the rest of the eight went to the next
+    assert "".join(calls) == "spspppppppd", calls
+    assert [r.req_id for r in done] == [victim] and done[0].cancelled
+    assert [(r.req_id, pp.chunks) for r, pp in sched._prefilling] == [(nxt, 6)]
+    out = _run_steps(sched, calls)
+    assert out[first] == dense_greedy(PROMPT[:5], 8)
+    assert out[nxt] == dense_greedy([90] + LONG_PROMPT, 4)
+    assert victim not in out
+    assert eng.free_pages == eng.pc.n_blocks  # nothing leaked
 
 
 def test_scheduler_cancel_mid_chunked_prefill():

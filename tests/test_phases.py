@@ -257,8 +257,10 @@ def test_decode_counts_equal_the_tokens_and_never_exceed_the_table(tiny):
     assert d["row_steps"] == s["tokens"] > 0
     assert d["steps"] == 4 * s["dispatches"]["decode"]
     assert 0 < d["live_token_steps"] <= d["table_token_steps"]
-    # only what a metric reads is summed: the benchmark's engine.decode_*
-    assert sorted(d) == sorted(stepprof.DECODE_COUNTS) and "prefill" not in s
+    # only what a metric reads is summed: the benchmark's engine.decode_*,
+    # and the steps' prefill budgets beside them
+    assert sorted(d) == sorted(stepprof.DECODE_COUNTS)
+    assert sorted(s["prefill"]) == sorted(stepprof.PREFILL_COUNTS)
     assert s["dispatches"]["prefill"] >= 3
     recs = [r for r in prof.tail() if "decode" in r]
     assert recs and all(r["decode"]["row_steps"] == r["tokens"] for r in recs)
@@ -380,13 +382,13 @@ def _run(tiny, prompts, *, blocker=False, store_conn=None, **eng_kw):
 
 
 @pytest.mark.parametrize("case,chunks", [
-    ("wave", None), ("chunked-1", 1), ("chunked-3", 3)])
+    ("wave", None), ("chunked-1", 1), ("chunked-3", 3), ("chunked-6", 6)])
 def test_ttft_slices_sum_for_wave_and_chunked_admission(tiny, case, chunks):
-    prompt = list(range(3, 3 + 20))          # 20 tokens, chunks of 8: three
+    prompt = list(range(3, 3 + 44))          # 44 tokens, chunks of 8: six
     if case == "wave":
-        rows, _ = _run(tiny, [prompt, prompt[:9]])
+        rows, _ = _run(tiny, [prompt[:20], prompt[:9]])
     else:
-        rows, _ = _run(tiny, [prompt if chunks == 3 else prompt[:7]],
+        rows, _ = _run(tiny, [prompt[:8 * chunks - 4]],
                        blocker=True, prefill_chunk=8)
     for row in rows:
         t = _check_row(row)
@@ -396,9 +398,10 @@ def test_ttft_slices_sum_for_wave_and_chunked_admission(tiny, case, chunks):
     if chunks:
         t = rows[0]["ttft"]
         assert t["prefill_chunks"] == chunks
-        # one chunk per scheduler step, the first token one dispatch later
-        assert t["steps_to_first"] == chunks
-        if chunks > 1:   # parked behind the blocker's dispatches in between
+        # max_batch = 4 chunks a scheduler step, back to back, the first
+        # token one dispatch later
+        assert t["steps_to_first"] == -(-chunks // 4)
+        if chunks > 4:   # parked behind the blocker's dispatch in between
             assert t["prefill_wait_s"] > 0
 
 

@@ -601,3 +601,25 @@ def test_summary_spec_accept_per_dispatch():
         _Spec.rounds, _Spec.proposed, _Spec.accepted = 16, 64, 38
     s = prof.summary()
     assert s.get("spec_accept_per_dispatch") == round(38 / 2, 3)
+
+
+def test_note_prefill_budget_sums_per_step_and_lifetime():
+    """The scheduler's per-step prefill budget lands on the active record
+    under ``prefill`` and sums into ``summary()['prefill']``; a step with
+    no budget (an idle engine admits a whole wave) carries no block, and a
+    call outside a step is dropped."""
+    from infinistore_tpu.engine import stepprof as sp
+
+    prof = _prof(sample=1000)
+    with prof.step(kind_hint="mixed") as rec:
+        sp.note_prefill_budget(7 * 512, 5 * 512)
+    assert rec["prefill"] == {"granted_tokens": 3584, "spent_tokens": 2560}
+    with prof.step(kind_hint="decode") as rec2:
+        sp.note_dispatch("decode")
+    assert "prefill" not in rec2
+    with prof.step(kind_hint="mixed"):
+        sp.note_prefill_budget(512, 512)
+    sp.note_prefill_budget(512, 512)
+    assert prof.summary()["prefill"] == {"granted_tokens": 4096,
+                                         "spent_tokens": 3072}
+    assert prof.tail()[0]["prefill"]["spent_tokens"] == 2560
