@@ -81,6 +81,32 @@ _PREFIX_TOKENS_TENANT = _metrics.default_registry().counter(
 )
 
 
+# what a stack of mixed attention kinds asks of the store when it adopts a
+# prefix: pages fetched, by the kind of layer that owns them, and the
+# sliding-window layers' pages NOT fetched because no query can read them
+_STORE_PREFIX_PAGES = _metrics.default_registry().counter(
+    "istpu_engine_store_prefix_pages_total",
+    "(layer, chunk) pages of adopted store prefixes by the kind of layer "
+    "(full / window) and outcome: fetched, or skipped as lying wholly "
+    "below the window",
+    labelnames=("layers", "outcome"),
+)
+# a sequence's pages of a sliding-window pool (or of a stack whose every
+# layer is windowed): taken into its table, and given back BEFORE its
+# release because their last token has left every window to come
+_WINDOW_PAGES = _metrics.default_registry().counter(
+    "istpu_engine_window_pages_total",
+    "Sliding-window layers' pages a sequence acquired (fresh or a pinned "
+    "local hit) and returned to their pool before its release",
+    labelnames=("event",),
+)
+_EXPERT_PAIRS_LOCAL = _metrics.default_registry().counter(
+    "istpu_engine_expert_pairs_local_total",
+    "Decode (token, expert) pairs whose expert this chip holds, summed on "
+    "the device (a model that holds a share of its routed experts)",
+)
+
+
 def _truncate_logits(l: jax.Array, top_k: jax.Array, top_p: jax.Array) -> jax.Array:
     """Apply per-row top-k and top-p (nucleus) truncation to f32 logits
     ``l`` [B, V] (already temperature-scaled): tokens outside the kept set
@@ -247,6 +273,18 @@ def _write_prefill_pages(cache, block_ids, kv, block_tokens):
     )
 
 
+@partial(jax.jit, donate_argnums=(0,), static_argnums=(3, 4))
+def _write_prefill_pages_by_pool(caches, block_ids, kv, block_tokens,
+                                 pool_layers):
+    """``_write_prefill_pages`` for a cache of one pool a layer kind: each
+    pool takes its own layers' rows of ``kv`` under its own page ids
+    (``block_ids`` one vector a pool, the same chunks in each)."""
+    return tuple(
+        write_pages(c, ids, prefill_to_pages(
+            kv[np.asarray(layers), :, 0], ids.shape[0], block_tokens))
+        for c, ids, layers in zip(caches, block_ids, pool_layers))
+
+
 @partial(jax.jit, static_argnums=(1,))
 def _pad_seq_axis(kv, cap):
     """Pad the sequence axis (index 3) of [L, 2, B, S, H, D] up to ``cap``
@@ -260,6 +298,23 @@ def _pad_seq_axis(kv, cap):
 def _read_prefix_kv(cache, block_ids):
     """Fused gather of a reused prefix: pages -> [L, 2, 1, n*T, H, D]."""
     return pages_to_seq_kv(read_pages(cache, block_ids))
+
+
+@partial(jax.jit, static_argnums=(2,))
+def _read_prefix_kv_by_pool(caches, block_ids, order):
+    """``_read_prefix_kv`` from a pool per layer kind: the first pool's ids
+    name every chunk of the prefix, the window pool's its LAST chunks only
+    (those inside the window); a window layer's rows below them are zeros
+    that no query reads (the layer slices its window out of the buffer).
+    ``order``: ``PagedCacheConfig.stack_order``."""
+    T = caches[0].shape[4]
+    n = block_ids[0].shape[0]
+    parts = []
+    for c, ids in zip(caches, block_ids):
+        kv = pages_to_seq_kv(read_pages(c, ids))
+        lead = (n - ids.shape[0]) * T
+        parts.append(jnp.pad(kv, ((0, 0),) * 3 + ((lead, 0),) + ((0, 0),) * 2))
+    return jnp.concatenate(parts, axis=0)[np.asarray(order)]
 
 
 @partial(jax.jit, donate_argnums=(0,), static_argnums=(4,))
@@ -518,6 +573,12 @@ class SequenceState:
     # leading pages already returned to the pool by SWA window reclamation
     # (ids stay in block_ids — masked off — so table math is unchanged)
     reclaimed_pages: int = 0
+    # a stack with a pool per layer kind (``PagedCacheConfig.window_layers``):
+    # the table of the sliding-window layers' pool, as long as ``block_ids``;
+    # its first ``window_reclaimed`` entries name no page this sequence
+    # holds (never taken, or returned: stale ids, never gathered)
+    window_ids: List[int] = field(default_factory=list)
+    window_reclaimed: int = 0
     # prefix provenance for the request ledger: of ``reused_chunks``, how
     # many came from the local HBM prefix cache vs the store tier, and
     # the wall seconds the store hops (lookup + load) took — the
@@ -555,6 +616,9 @@ class PartialPrefill:
     off_last: int = 0
     logits: Optional[jax.Array] = None
     adapter_id: int = 0  # LoRA adapter slot (0 = base model)
+    # the window pool's table (SequenceState.window_ids / window_reclaimed)
+    window_ids: List[int] = field(default_factory=list)
+    window_reclaimed: int = 0
     # provenance carried onto the SequenceState (see its fields)
     local_chunks: int = 0
     store_chunks: int = 0
@@ -634,6 +698,10 @@ class InferenceEngine:
         (parallel/sharding.py rationale).  Page bookkeeping, the store
         protocol, and the scheduler are unchanged: they never see the mesh."""
         assert pc.n_layers == cfg.n_layers
+        if mesh is not None and pc.window_layers:
+            raise ValueError(
+                "mesh serving shards ONE cache array; a stack with a pool of "
+                "pages per layer kind is served on one device")
         if mesh is not None and pc.planes != 2:
             # the mesh path shards the cache over its KV-head axis and the
             # weights by the dense specs: a page of one plane has neither
@@ -718,6 +786,16 @@ class InferenceEngine:
         # addressed by their prefix-commitment key and shared across
         # sequences (kv/cache.py PrefixPageCache)
         self.pages = PrefixPageCache(self.alloc)
+        # PAGES BY LAYER KIND (pc.window_layers): the sliding-window layers'
+        # pages live in a pool of their own, with its own allocator and
+        # content-addressed residency under the same chunk keys; a sequence
+        # holds pages of it for its window only (``_acquire_window``,
+        # ``_reclaim_window_pages``).  ``self.pages`` is then the pool of the
+        # layers that read everything, and the one a prefix hit is matched
+        # in.  None: one pool, one table (every other family).
+        self.wpages = (PrefixPageCache(BlockAllocator(pc.window_blocks))
+                       if pc.window_layers else None)
+        self._pool_layers = tuple(layers for layers, _ in pc.pools)
         # ``conn`` may be a single store connection (the classic
         # one-node path, byte-identical to every prior release) OR a
         # cluster.RoutedStorePool — then every store hop routes
@@ -758,6 +836,27 @@ class InferenceEngine:
             )
             if self.transfer is not None else None
         )
+        # the window a page is held for: the sliding-window pool's layers'
+        # (cfg.layer_windows; they GATHER their window's pages,
+        # models/cohere2_moe.py), or the whole stack's where every layer is
+        # windowed and the one pool is the window's (the Mistral stack:
+        # window_pattern 1).  None: pages are held until release.
+        self._window = None
+        if self.wpages is not None:
+            sizes = {cfg.layer_windows[li] for li in pc.window_layers}
+            if len(sizes) != 1:
+                raise ValueError(
+                    f"sliding-window layers of different windows {sorted(sizes)}"
+                    f": one window a stack is what the page rules cover")
+            self._window = sizes.pop()
+            if self.transfer is not None and not getattr(
+                    self.transfer, "loads_by_layer", False):
+                raise ValueError(
+                    "a stack with a pool of pages per layer kind loads a "
+                    "stored prefix layer group by layer group; "
+                    f"{type(self.transfer).__name__} does not")
+        elif getattr(cfg, "window_pattern", 1) == 1:
+            self._window = getattr(cfg, "sliding_window", None)
         self.max_seqs = max_seqs
         if prefill_chunk is not None:
             assert prefill_chunk % pc.block_tokens == 0, (
@@ -902,49 +1001,93 @@ class InferenceEngine:
         # zero transfer), then the store (zero compute, one load).
         max_reuse = (S_total - 1) // T
         local_ids = self.pages.match_prefix(keys[:max_reuse])  # pins hits
-        reused = len(local_ids)
+        n_local = reused = len(local_ids)
         lookup_s = load_s = 0.0  # wall seconds of the store hops (ledger)
-        if self.transfer is not None and keys and reused < max_reuse:
+        two = self.wpages is not None
+        # a local hit whose window pages are gone is filled from the store
+        # or cut to ``usable``: the store is asked then too
+        usable = self._usable_local(keys, n_local) if two else n_local
+        if self.transfer is not None and keys and (
+                reused < max_reuse or usable < n_local):
             # breaker-guarded: a dead/hung store (or an open circuit)
             # reports 0 — a prefix-cache miss, never a failed request
             with _stepprof.phase("kv.lookup") as ph:
-                reused = max(
-                    reused,
-                    min(self.transfer.guarded_lookup_prefix(keys), max_reuse),
-                )
+                # a stack with a window pool probes a layer whose page of
+                # every chunk it needs: a window layer's early pages may be
+                # gone from the store, and are not needed
+                kw = ({"probe_layer": self._pool_layers[0][0]} if two else {})
+                n_store = min(self.transfer.guarded_lookup_prefix(keys, **kw),
+                              max_reuse)
             lookup_s = ph.s
-        P = reused * T
+            reused = max(reused, n_store)
+            if two and any(i >= n_store
+                           for i in self._window_missing(keys, reused)):
+                # the store cannot fill the window of the longer prefix
+                reused = max(usable, n_store)
+        else:
+            reused = usable
 
         # pages for the rest of the sequence (incl. a partial tail page)
         n_pages_total = -(-S_total // T)
+        block_ids = list(local_ids)
+        window_ids: List[int] = []
         try:
-            fresh_ids = self.pages.acquire(n_pages_total - len(local_ids))
+            # a hit that was cut keeps the pages it still adopts: those
+            # beyond are other sequences' to read, not this one's to write
+            self.pages.unpin(block_ids[reused:])
+            del block_ids[reused:]
+            n_local = len(block_ids)
+            block_ids += self.pages.acquire(n_pages_total - n_local)
+            if two:
+                window_ids, missing = self._acquire_window(
+                    keys, reused, n_pages_total)
         except MemoryError:
-            self.pages.unpin(local_ids)
+            self.pages.unpin(block_ids)
             raise
-        block_ids = local_ids + fresh_ids
 
         prefix_kv = None
-        if reused > len(local_ids):  # store hop for the non-local part
+        if reused > n_local or (two and missing):  # store hop
             # guarded: BOTH the eviction race (a matched page vanished
             # between lookup_prefix and the load — reads are
             # all-or-nothing, reference 404 semantics, VERDICT r2 missing
             # #4) and a transport failure mid-load leave the cache
             # untouched; fall back to the locally-resident prefix and
             # recompute the rest instead of failing the request
+            kw = {}
+            if two:
+                # by layer kind: the full layers' pages of every chunk not
+                # held, the window layers' of the chunks inside the window
+                # that are not held; each into its own pool's table
+                kw["layer_chunks"] = [
+                    (self._pool_layers[0], range(n_local, reused), block_ids),
+                    (self._pool_layers[1], missing, window_ids)]
+                args = (block_ids[:reused], keys[:reused])
+            else:
+                args = (block_ids[n_local:reused], keys[n_local:reused])
             with _stepprof.phase("kv.load") as ph:
                 self.cache, ok = self.transfer.guarded_load(
-                    self.cache,
-                    block_ids[len(local_ids):reused],
-                    keys[len(local_ids):reused],
-                )
+                    self.cache, *args, **kw)
             load_s = ph.s
-            if not ok:
-                reused = len(local_ids)
-                P = reused * T
+            if ok and two:
+                self._window_loaded(keys, window_ids, n_local, reused, missing)
+            elif not ok and two:
+                # what is held locally, cut to where its window is held
+                try:
+                    reused, window_ids = self._window_fallback(
+                        keys, block_ids, window_ids, n_local, reused,
+                        n_pages_total)
+                except MemoryError:
+                    self.pages.unpin(block_ids)
+                    raise
+            elif not ok:
+                reused = n_local
+        P = reused * T
+        if two:
+            self._note_window_pages(
+                "acquired", n_pages_total - self._dead_chunks(reused))
         # provenance accounting AFTER the load settled (a failed store
         # load degrades those chunks back to computed, and must count so)
-        local_chunks = min(len(local_ids), reused)
+        local_chunks = min(n_local, reused)
         if local_chunks:
             _PREFIX_TOKENS.labels("local").inc(local_chunks * T)
         if reused > local_chunks:
@@ -964,7 +1107,14 @@ class InferenceEngine:
             _PREFIX_TOKENS_TENANT.labels(tenant, "computed").inc(
                 S_total - P)
 
-        if reused:
+        if reused and two:
+            prefix_kv = _read_prefix_kv_by_pool(
+                self.cache,
+                (jnp.asarray(block_ids[:reused]),
+                 jnp.asarray(window_ids[self._dead_chunks(reused):reused],
+                             dtype=jnp.int32)),
+                self.pc.stack_order)
+        elif reused:
             prefix_kv = _read_prefix_kv(
                 self.cache, jnp.asarray(block_ids[:reused])
             )  # [L, 2, 1, n*T, H, D]
@@ -1003,9 +1153,106 @@ class InferenceEngine:
             tokens=tokens, keys=keys, block_ids=block_ids, reused=reused,
             done=reused, n_complete=S_total // T, padded=padded, C=C,
             single=single, buf=buf, plen=plen, S=S, adapter_id=adapter_id,
+            window_ids=window_ids,
+            window_reclaimed=self._dead_chunks(reused) if two else 0,
             local_chunks=local_chunks, store_chunks=reused - local_chunks,
             store_load_s=lookup_s + load_s, lookup_s=lookup_s,
         )
+
+    # ---- pages by layer kind: the sliding-window layers' pool ----
+    #
+    # A window layer reads, of a prefix of P tokens, the pages that can hold
+    # a key at a position > P - window; the ``(P - window) // T`` pages below
+    # are dead to it for good (positions only grow).  A sequence takes pages
+    # of the window pool for the chunks from there on, and gives them back
+    # as its positions pass them (``_reclaim_window_pages``).  Of an adopted
+    # prefix the dead chunks' window pages are neither held, nor looked up,
+    # nor fetched; those inside the window come from the window pool's own
+    # residency (same chunk keys) or from the store; where neither has them
+    # the hit is cut to where they are held.
+
+    def _dead_chunks(self, n_chunks: int) -> int:
+        """Leading chunks of an ``n_chunks``-chunk prefix that no query of a
+        window layer can read."""
+        T = self.pc.block_tokens
+        return max(0, (n_chunks * T - self._window) // T)
+
+    def _window_missing(self, keys, reused: int) -> List[int]:
+        """Chunks inside the window of an adopted prefix ``[0, reused)``
+        whose page the window pool does not hold."""
+        return [i for i in range(self._dead_chunks(reused), reused)
+                if keys[i] not in self.wpages]
+
+    def _usable_local(self, keys, n_local: int) -> int:
+        """The longest prefix of a local hit of ``n_local`` chunks whose
+        window the window pool holds whole: what a hit is cut to when the
+        store cannot fill the rest."""
+        best = run = 0      # run: chunks held in a row, ending below n
+        for n in range(1, n_local + 1):
+            run = run + 1 if keys[n - 1] in self.wpages else 0
+            if n - self._dead_chunks(n) <= run:
+                best = n
+        return best
+
+    def _acquire_window(self, keys, reused: int, n_pages: int):
+        """The window pool's table of a sequence of ``n_pages`` pages that
+        adopts ``[0, reused)``: ``(window_ids, missing)``.  Chunks below the
+        adopted prefix's window get no page (a placeholder id that is never
+        gathered); those inside it the pool's resident page (pinned) or a
+        fresh one, ``missing``, for the store to fill; every later chunk a
+        fresh one.  All or nothing."""
+        dead = self._dead_chunks(reused)
+        hits = self.wpages.match_each(keys[dead:reused])
+        held = [h for h in hits if h is not None]
+        try:
+            fresh = iter(self.wpages.acquire(n_pages - dead - len(held)))
+        except MemoryError:
+            self.wpages.unpin(held)
+            raise
+        ids = [0] * dead + [next(fresh) if h is None else h for h in hits]
+        ids.extend(fresh)
+        return ids, [dead + j for j, h in enumerate(hits) if h is None]
+
+    def _window_loaded(self, keys, window_ids, n_local, reused, missing
+                       ) -> None:
+        """After a load by layer kind has landed: the fetched window pages
+        are resident under their keys, and the counts of what was fetched
+        and what was not."""
+        self.wpages.register([keys[i] for i in missing],
+                             [window_ids[i] for i in missing])
+        n_full, n_win = (len(ls) for ls in self._pool_layers)
+        skipped = max(0, min(self._dead_chunks(reused), reused) - n_local)
+        counts = {"store_pages_full": n_full * (reused - n_local),
+                  "store_pages_window": n_win * len(missing),
+                  "store_pages_window_skipped": n_win * skipped}
+        _stepprof.note_kv_pages(**counts)
+        _STORE_PREFIX_PAGES.labels("full", "fetched").inc(
+            counts["store_pages_full"])
+        _STORE_PREFIX_PAGES.labels("window", "fetched").inc(
+            counts["store_pages_window"])
+        _STORE_PREFIX_PAGES.labels("window", "skipped").inc(
+            counts["store_pages_window_skipped"])
+
+    def _window_fallback(self, keys, block_ids, window_ids, n_local: int,
+                         reused: int, n_pages: int):
+        """A load that failed, for a sequence that had planned to adopt
+        ``[0, reused)``: it keeps the local hit as far as the window pool
+        holds its window, ``(kept, window_ids)``.  The window table is taken
+        anew for that prefix; the hit's pages beyond it go back (they are
+        other sequences' to read) and fresh ones take their place in
+        ``block_ids``."""
+        self.wpages.unpin(window_ids[self._dead_chunks(reused):])
+        keep = self._usable_local(keys, n_local)
+        fresh = self.pages.acquire(n_local - keep)
+        self.pages.unpin(block_ids[keep:n_local])
+        block_ids[keep:n_local] = fresh
+        window_ids, _ = self._acquire_window(keys, keep, n_pages)
+        return keep, window_ids
+
+    def _note_window_pages(self, event: str, n: int) -> None:
+        if n:
+            _stepprof.note_kv_pages(**{f"window_pages_{event}": n})
+            _WINDOW_PAGES.labels(event).inc(n)
 
     def prefill_step(self, pp: "PartialPrefill") -> Optional[SequenceState]:
         """One prefill chunk forward (+ cache scatter + store streaming).
@@ -1042,12 +1289,20 @@ class InferenceEngine:
         # unit for the step profiler's attribution
         _stepprof.note_dispatch("prefill")
         n_pg = len(chunk) // T
-        self.cache = _write_prefill_pages(
-            self.cache,
-            jnp.asarray(pp.block_ids[pp.done : pp.done + n_pg]),
-            kv,
-            T,
-        )
+        if self.wpages is None:
+            self.cache = _write_prefill_pages(
+                self.cache,
+                jnp.asarray(pp.block_ids[pp.done : pp.done + n_pg]),
+                kv,
+                T,
+            )
+        else:
+            self.cache = _write_prefill_pages_by_pool(
+                self.cache,
+                tuple(jnp.asarray(ids[pp.done : pp.done + n_pg], jnp.int32)
+                      for ids in (pp.block_ids, pp.window_ids)),
+                kv, T, self._pool_layers,
+            )
         prev_done, pp.done = pp.done, pp.done + n_pg
         pp.off_last = off
         # stream this chunk's complete pages to the store NOW — the
@@ -1062,9 +1317,16 @@ class InferenceEngine:
                 with _stepprof.phase("kv.push_submit"):
                     self._streamer.submit(
                         self.transfer.gather_pages(
-                            self.cache, pp.block_ids[lo:hi]),
+                            self.cache,
+                            pp.block_ids[lo:hi] if self.wpages is None
+                            else (pp.block_ids[lo:hi], pp.window_ids[lo:hi])),
                         pp.keys[lo:hi],
                     )
+        if self.wpages is not None:
+            # the push holds a snapshot: window pages that lie below the
+            # window of every position still to come go back now
+            self._reclaim_window_pages(
+                pp, min(pp.done * T, len(pp.tokens)))
         pp.off = off + C
         if pp.off < len(pp.padded):
             # another chunk still attends to this KV: grow the bucketed
@@ -1095,6 +1357,9 @@ class InferenceEngine:
         self.pages.register(
             pp.keys[: pp.n_complete], pp.block_ids[: pp.n_complete]
         )
+        if self.wpages is not None:
+            held = slice(pp.window_reclaimed, pp.n_complete)
+            self.wpages.register(pp.keys[held], pp.window_ids[held])
 
         state = SequenceState(
             seq_id=self._next_id,
@@ -1104,6 +1369,7 @@ class InferenceEngine:
             reused_chunks=pp.reused,
             last_logits=_LAST_ROW(pp.logits, (pp.S - 1) - pp.off_last),
             adapter_id=pp.adapter_id,
+            window_ids=pp.window_ids, window_reclaimed=pp.window_reclaimed,
             local_chunks=pp.local_chunks, store_chunks=pp.store_chunks,
             store_load_s=pp.store_load_s, lookup_s=pp.lookup_s,
         )
@@ -1131,6 +1397,10 @@ class InferenceEngine:
         nothing streams to the store: external KV carries no
         prefix-commitment chain, so it is private to this sequence."""
         T = self.pc.block_tokens
+        if self.wpages is not None:
+            raise ValueError(
+                "adopt_prefill lands KV in one pool; this stack keeps a pool "
+                "of pages per layer kind (prefill it through the engine)")
         assert kv.ndim == 6 and kv.shape[2] == 1, kv.shape
         S = kv.shape[3]
         if S % T != 0 or S < len(tokens):
@@ -1190,6 +1460,9 @@ class InferenceEngine:
         push errors, breaking the next store_flush()'s contract.)"""
         self.pages.unpin(pp.block_ids)
         pp.block_ids = []
+        if self.wpages is not None:
+            self.wpages.unpin(pp.window_ids[pp.window_reclaimed:])
+            pp.window_ids = []
 
     def prefill_batch(
         self,
@@ -1205,6 +1478,8 @@ class InferenceEngine:
         short ones' padding — a group mixes LoRA adapters freely (the
         forward takes a per-row adapter-id vector).  Per-sequence fallback
         when a store is attached (each sequence's reusable prefix differs),
+        for a stack with a pool of pages per layer kind (its tables are
+        taken in ``prefill_start``),
         for singleton groups, and when a group's total padded tokens would
         exceed ``prefill_chunk`` (the configured prefill memory bound).
 
@@ -1226,7 +1501,7 @@ class InferenceEngine:
         out: List[Optional[SequenceState]] = [None] * len(prompts)
         created: List[SequenceState] = []
         try:
-            if self.transfer is not None:
+            if self.transfer is not None or self.wpages is not None:
                 for i, p in enumerate(prompts):
                     st = self.prefill(p, adapter_id=aids[i])
                     created.append(st)
@@ -1471,10 +1746,13 @@ class InferenceEngine:
                         jnp.arange(tok.shape[0]), tok
                     ].add(1)
                 page_idx = pos // T
-                slot_blocks = jnp.take_along_axis(
-                    block_table, page_idx[:, None], axis=1
-                )[:, 0]
-                logits2, cache = decode_fn(
+                # a table (and so a slot) per pool where the stack has a
+                # pool of pages per layer kind; one leaf otherwise
+                slot_blocks = jax.tree.map(
+                    lambda table: jnp.take_along_axis(
+                        table, page_idx[:, None], axis=1)[:, 0],
+                    block_table)
+                logits2, cache, *aux = decode_fn(
                     params,
                     tokens=tok,
                     positions=pos,
@@ -1496,6 +1774,10 @@ class InferenceEngine:
                     y = (tok, probs)
                 else:
                     y = tok
+                # a family's decode step may return a third value, a count
+                # to be summed over the scan (the pairs whose expert this
+                # chip holds): it rides back with the tokens
+                y = (y, *aux)
                 if penalized:
                     return (logits2, cache, gen_counts), y
                 return (logits2, cache), y
@@ -1504,11 +1786,11 @@ class InferenceEngine:
                 (logits0, cache, gen_counts0) if penalized
                 else (logits0, cache)
             )
-            carry, ys = jax.lax.scan(step, init, jnp.arange(n_steps))
+            carry, (ys, *aux) = jax.lax.scan(step, init, jnp.arange(n_steps))
             logits, cache = carry[0], carry[1]
             parts = ys if (collect or logprobs_k) else (ys,)
             tail = (carry[2],) if penalized else ()  # final gen counts
-            return (*parts, logits, cache, *tail)
+            return (*parts, logits, cache, *tail, *(a.sum() for a in aux))
 
         fn = jax.jit(_stepprof.traced(many, "decode_many"),
                      donate_argnums=(3,))
@@ -1729,6 +2011,10 @@ class InferenceEngine:
             need = -(-(len(st.tokens) + n_steps) // T)
             if need > len(st.block_ids):
                 st.block_ids.extend(self.pages.acquire(need - len(st.block_ids)))
+            if self.wpages is not None and need > len(st.window_ids):
+                grow = need - len(st.window_ids)
+                st.window_ids.extend(self.wpages.acquire(grow))
+                self._note_window_pages("acquired", grow)
         block_table = self._block_table(states, pad_to=Bp)
         if rng is None:
             # advance the engine's own stream: repeated sampling calls must
@@ -1808,23 +2094,32 @@ class InferenceEngine:
             # one compiled scan dispatch advanced the whole batch a chunk
             _stepprof.note_decode(
                 steps=chunk, rows=B, padded_rows=Bp,
-                width_pages=block_table.shape[1], block_tokens=T,
+                width_pages=jax.tree.leaves(block_table)[0].shape[1],
+                block_tokens=T,
                 live_tokens=int(pos[:B].sum()),
                 expert_routing=getattr(self.cfg, "expert_routing", None),
             )
             _stepprof.note_tokens(chunk * B)
+            if logprobs:
+                toks, chosen, top_id, top_lp, logits, self.cache, *rest = res
+            else:
+                toks, logits, self.cache, *rest = res
             if penalized:
                 # thread the device-side counts into the next chunk
-                *res, counts_d = res
+                counts_d, *rest = rest
                 pen = (counts_d,) + pen[1:]
-            if logprobs:
-                toks, chosen, top_id, top_lp, logits, self.cache = res
-            else:
-                toks, logits, self.cache = res
+            # what is left is a family's own count (its held experts' pairs)
+            pairs_local = rest[0] if rest else None
             _stepprof.enter("decode.wait")
             _stepprof.note_sync("decode_tokens")
             host_toks = np.asarray(toks)  # [chunk, Bp]; one sync/chunk
             _stepprof.enter("decode.unpack")
+            if pairs_local is not None:
+                # computed by the dispatch the tokens came from: read, not
+                # waited for
+                n = int(pairs_local)
+                _stepprof.note_expert_pairs_local(n)
+                _EXPERT_PAIRS_LOCAL.inc(n)
             if logprobs:
                 h_ch = np.asarray(chosen)   # [chunk, B]
                 h_ti = np.asarray(top_id)   # [chunk, B, k]
@@ -2005,11 +2300,27 @@ class InferenceEngine:
         while width < need:
             width *= 2
         rows = pad_to if pad_to is not None else len(states)
-        table = np.zeros((rows, width), dtype=np.int32)
-        table[len(states):] = self.pc.n_blocks
-        for b, st in enumerate(states):
-            table[b, : len(st.block_ids)] = st.block_ids
-        return jnp.asarray(table)
+
+        def build(n_blocks: int, ids_of, own_tail: bool = False) -> jax.Array:
+            table = np.zeros((rows, width), dtype=np.int32)
+            table[len(states):] = n_blocks
+            for b, st in enumerate(states):
+                ids = ids_of(st)
+                table[b, : len(ids)] = ids
+                if own_tail and ids:
+                    table[b, len(ids):] = ids[-1]
+            return jnp.asarray(table)
+
+        full = build(self.pc.n_blocks, lambda st: st.block_ids)
+        if self.wpages is None:
+            return full
+        # a table per pool, of one width: a window layer picks its window's
+        # slots out of its own by each row's length, so the entries below
+        # (never taken, or returned: stale) are not gathered; the slots past
+        # a row's pages name its own last page (masked by length), so that
+        # layer reads pages its row holds and no others
+        return full, build(self.pc.window_blocks, lambda st: st.window_ids,
+                           own_tail=True)
 
     def prompt_logprobs(
         self, tokens: Sequence[int], k: int = 0, adapter_id: int = 0
@@ -2091,41 +2402,58 @@ class InferenceEngine:
 
     @property
     def free_pages(self) -> int:
-        """Pages a new sequence can obtain (fresh + reclaimable cached)."""
-        return self.pages.available
+        """Pages a new sequence can obtain (fresh + reclaimable cached); of
+        a stack with a window pool, what BOTH pools can give: a sequence
+        takes at most as many window pages as pages of the other kind."""
+        if self.wpages is None:
+            return self.pages.available
+        return min(self.pages.available, self.wpages.available)
 
-    def _reclaim_window_pages(self, st: SequenceState) -> None:
-        """SWA page reclamation (VERDICT r3 weak #4): when EVERY layer is
-        windowed (``window_pattern == 1``, the Mistral stack), a page whose
-        last token has aged out of the attention window of every current
-        and future position is handed back to the pool, so long
-        generations hold ~window/block_tokens live pages instead of
-        growing without bound (the vLLM out-of-window block-reclaim
-        analog).  Mixed local/global stacks (Gemma-2, pattern 2) keep all
-        pages: blocks span the whole layer stack and the global layers
-        attend everything.
+    def _reclaim_window_pages(self, st, n_tokens: Optional[int] = None) -> None:
+        """Window page reclamation: a page of sliding-window layers whose
+        last token has aged out of the attention window of every position
+        from ``n_tokens`` (default: the sequence's length) on is handed back to
+        its pool, so a sequence holds
+        ~window/block_tokens live pages of those layers instead of growing
+        without bound (the vLLM out-of-window block-reclaim analog).  The
+        pages are the window POOL's where the stack keeps a pool per layer
+        kind (``st.window_ids``; the layers that read everything keep all of
+        theirs), and the one pool's where EVERY layer is windowed
+        (``window_pattern == 1``, the Mistral stack: ``st.block_ids``), which
+        is the same rule with one kind.  A mixed stack on ONE pool (Gemma-2,
+        pattern 2) keeps all pages: its blocks span the whole layer stack.
 
-        The stale ids stay in ``block_ids`` so table construction and the
-        page-need arithmetic are unchanged — the window mask makes those
-        table slots unreadable even after the pool hands the page to
-        another sequence.  ``reclaimed_pages`` marks the returned prefix
-        so ``release`` doesn't double-unpin.
+        The stale ids stay in the table so table construction and the
+        page-need arithmetic are unchanged: a window layer gathers (or,
+        in the one-pool stacks, masks) by each row's length, so those slots
+        are unreadable even after the pool hands the page to another
+        sequence.  The table's reclaimed count marks the returned prefix so
+        ``release`` doesn't double-unpin.
 
-        Called at decode entry ONLY: decode never rewinds below its entry
-        length (speculative trimming lands at entry+n_steps), so a page
-        dead at entry stays dead; a verify-entry reclaim would NOT be
-        trim-safe."""
-        W = getattr(self.cfg, "sliding_window", None)
-        if W is None or getattr(self.cfg, "window_pattern", 1) != 1:
+        Called at decode entry (decode never rewinds below its entry
+        length: speculative trimming lands at entry+n_steps, so a page dead
+        at entry stays dead; a verify-entry reclaim would NOT be trim-safe)
+        and, for a window pool, after a prefill chunk's pages have been
+        gathered for their push (``n_tokens`` = the tokens in pages so
+        far)."""
+        if self._window is None:
             return
+        if n_tokens is None:
+            n_tokens = len(st.tokens)
+        pages, ids, count = (
+            (self.pages, st.block_ids, "reclaimed_pages")
+            if self.wpages is None
+            else (self.wpages, st.window_ids, "window_reclaimed"))
         T = self.pc.block_tokens
         # page i holds positions [i*T, (i+1)*T); every position >= len-W
         # stays attendable under either window-inclusion convention, so
         # pages 0..n_dead-1 with n_dead*T + W <= len are dead for good
-        n_dead = min((len(st.tokens) - W) // T, len(st.block_ids))
-        if n_dead > st.reclaimed_pages:
-            self.pages.unpin(st.block_ids[st.reclaimed_pages:n_dead])
-            st.reclaimed_pages = n_dead
+        n_dead = min((n_tokens - self._window) // T, len(ids))
+        done = getattr(st, count)
+        if n_dead > done:
+            pages.unpin(ids[done:n_dead])
+            setattr(st, count, n_dead)
+            self._note_window_pages("returned", n_dead - done)
 
     def release(self, state: SequenceState) -> None:
         # shared pages just lose a ref; this sequence's registered pages
@@ -2133,4 +2461,8 @@ class InferenceEngine:
         self.pages.unpin(state.block_ids[state.reclaimed_pages:])
         state.block_ids = []
         state.reclaimed_pages = 0
+        if self.wpages is not None:
+            self.wpages.unpin(state.window_ids[state.window_reclaimed:])
+            state.window_ids = []
+            state.window_reclaimed = 0
         self.seqs.pop(state.seq_id, None)

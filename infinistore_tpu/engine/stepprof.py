@@ -95,10 +95,16 @@ MAX_STEP_IDS = 64
 
 # what ``note_decode`` sums, per step record and over the lifetime
 DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
-                 "table_token_steps", "expert_pairs", "experts_expected")
+                 "table_token_steps", "expert_pairs", "experts_expected",
+                 "expert_pairs_local")
 
 # what ``note_prefill_budget`` sums, per step record and over the lifetime
 PREFILL_COUNTS = ("granted_tokens", "spent_tokens")
+
+# what ``note_kv_pages`` sums, per step record and over the lifetime
+KV_COUNTS = ("store_pages_full", "store_pages_window",
+             "store_pages_window_skipped", "window_pages_acquired",
+             "window_pages_returned")
 
 # the key that counts operations in the transfer's running totals
 _STORE_COUNT = {"push": "pushes", "load": "loads"}
@@ -271,6 +277,36 @@ def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
             n_experts * (1.0 - (1.0 - k / n_experts) ** rows) * layers * steps)
 
 
+def note_expert_pairs_local(n: int) -> None:
+    """Add the (token, expert) pairs of ONE decode-scan dispatch whose
+    expert this chip holds, as the dispatch itself summed them on the device
+    (a model that holds a share of its routed experts; exact, where
+    ``experts_expected`` is an expectation).  Read after the dispatch's
+    tokens have landed, so it costs no sync of its own.  Summed under
+    ``rec["decode"]`` beside ``expert_pairs``."""
+    rec = _ACTIVE.get()
+    if rec is not None:
+        b = rec.setdefault("decode", dict.fromkeys(DECODE_COUNTS, 0))
+        b["expert_pairs_local"] += n
+
+
+def note_kv_pages(**counts: int) -> None:
+    """Count pages by layer kind (``KV_COUNTS``).  Of ONE adopted store
+    prefix under a stack of mixed attention kinds, the (layer, chunk) pages
+    fetched for the full layers, fetched for the sliding-window layers, and
+    the window layers' pages NOT fetched because they lie wholly below the
+    window (engine.prefill_start).  Of the sliding-window layers' pool, the
+    pages a sequence took into its table and those it returned BEFORE its
+    release, their last token having left every window to come
+    (engine._reclaim_window_pages).  Summed under ``rec["kv"]``."""
+    rec = _ACTIVE.get()
+    if rec is None:
+        return
+    b = rec.setdefault("kv", dict.fromkeys(KV_COUNTS, 0))
+    for k, n in counts.items():
+        b[k] += n
+
+
 def note_prefill_budget(granted_tokens: int, spent_tokens: int) -> None:
     """Count ONE step's prefill token budget at the scheduler's call: the
     chunk tokens the step was GRANTED (``max_batch`` chunks, or the
@@ -431,6 +467,7 @@ class StepProfiler:
         self._decode_totals = dict.fromkeys(DECODE_COUNTS, 0)
         # lifetime sums of the steps' prefill budgets (note_prefill_budget)
         self._prefill_totals = dict.fromkeys(PREFILL_COUNTS, 0)
+        self._kv_totals = dict.fromkeys(KV_COUNTS, 0)
         # flat phases of the driving thread (see ``enter``)
         self.phase: Optional[str] = None
         self._phase_t0 = 0.0
@@ -702,6 +739,8 @@ class StepProfiler:
                 self._decode_totals[k] += n
             for k, n in rec.get("prefill", {}).items():
                 self._prefill_totals[k] += n
+            for k, n in rec.get("kv", {}).items():
+                self._kv_totals[k] += n
             self._wall_s += dur
             if sampled:
                 self._sampled += 1
@@ -789,6 +828,7 @@ class StepProfiler:
             tokens = self.tokens
             decode = dict(self._decode_totals)
             prefill = dict(self._prefill_totals)
+            kv = dict(self._kv_totals)
             # the open phase counts up to this moment: a scrape in the
             # middle of a long decode.wait loses nothing
             phase_s = dict(self._phase_s)
@@ -841,6 +881,8 @@ class StepProfiler:
             "decode": decode,
             # the steps' prefill token budgets, summed (note_prefill_budget)
             "prefill": prefill,
+            # adopted store prefixes' pages by layer kind (note_kv_pages)
+            "kv": kv,
             "store": {k: dict(t) for k, t in
                       self._store_totals(self._transfer).items()},
         }

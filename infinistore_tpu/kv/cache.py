@@ -29,6 +29,13 @@ A page is ``block_tokens`` consecutive tokens of one layer's K+V (all heads)
 With Llama-3-8B shapes (8 kv-heads x 128 dim, 16-token pages, bf16) a page
 is 64 KiB.
 
+A stack that mixes sliding-window layers with layers that read everything
+keeps one such array PER LAYER KIND (``PagedCacheConfig.pools``): the window
+layers' pages are a pool of their own blocks, each pool with its own
+allocator, residency and block table, so that a sequence holds window-layer
+pages for its window only (engine.py).  Every other stack is the case "one
+kind": one array, one block id across every layer.
+
 Static shapes everywhere: gathers/scatters take fixed-width index vectors so
 XLA compiles one program per (n_pages,) width; the host-side ``BlockAllocator``
 is plain Python (never traced).
@@ -37,7 +44,7 @@ is plain Python (never traced).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,17 +62,69 @@ class PagedCacheConfig:
     # planes of a page: 2 = K and V by head (the dense families); 1 = one
     # row per token and no K|V split (a latent page)
     planes: int = 2
+    # PAGES BY LAYER KIND.  A stack that mixes sliding-window layers with
+    # layers that read everything (a model config's ``layer_windows``) keeps
+    # the window layers' pages in a POOL OF THEIR OWN, ``window_blocks``
+    # blocks over ``window_layers``; the other layers' pages are the pool of
+    # ``n_blocks``.  A sequence then has a block table per pool and holds
+    # window-pool pages for its window only (engine.py).  Empty: one pool,
+    # one block id across every layer.
+    window_layers: Tuple[int, ...] = ()
+    window_blocks: int = 0
 
     @classmethod
-    def for_model(cls, cfg, n_blocks: int, block_tokens: int = 16
-                  ) -> "PagedCacheConfig":
+    def for_model(cls, cfg, n_blocks: int, block_tokens: int = 16,
+                  window_blocks: Optional[int] = None) -> "PagedCacheConfig":
         """The cache of a model: the page is what the model's config says
         it writes per token and layer (``cfg.kv_page`` = planes, heads,
-        width), so no caller rebuilds it from head counts."""
+        width), so no caller rebuilds it from head counts.  Where the
+        model's layers are of two kinds (``cfg.layer_windows`` names a
+        window for some and None for others) the window layers get a pool
+        of ``window_blocks`` blocks; left out, as many as ``n_blocks``: a
+        sequence never needs more window pages than pages of the other
+        kind, so that pool can never be the one that runs out first."""
         planes, heads, width = cfg.kv_page
+        windows = tuple(getattr(cfg, "layer_windows", ()) or ())
+        window_layers = tuple(li for li, w in enumerate(windows)
+                              if w is not None)
+        if len(window_layers) == len(windows):
+            window_layers = ()      # every layer windowed: one kind, one pool
+        if window_blocks is not None and not window_layers:
+            raise ValueError(
+                "window_blocks sizes the pool of a stack's sliding-window "
+                "layers beside its full ones; this model's layers are of "
+                "one kind")
         return cls(n_layers=cfg.n_layers, n_kv_heads=heads, head_dim=width,
                    n_blocks=n_blocks, block_tokens=block_tokens,
-                   dtype=cfg.dtype, planes=planes)
+                   dtype=cfg.dtype, planes=planes,
+                   window_layers=window_layers,
+                   window_blocks=((window_blocks or n_blocks)
+                                  if window_layers else 0))
+
+    @property
+    def pools(self) -> Tuple[Tuple[Tuple[int, ...], int], ...]:
+        """``(layers, blocks)`` of each pool: the pool of ``n_blocks`` first
+        (every layer, where the stack has one kind), then the window
+        layers' pool."""
+        if not self.window_layers:
+            return ((tuple(range(self.n_layers)), self.n_blocks),)
+        rest = tuple(li for li in range(self.n_layers)
+                     if li not in self.window_layers)
+        return ((rest, self.n_blocks),
+                (self.window_layers, self.window_blocks))
+
+    @property
+    def stack_order(self) -> Tuple[int, ...]:
+        """Where each layer of the stack sits in the pools' arrays laid end
+        to end on the layer axis: ``concatenate(pool arrays)[stack_order]``
+        is in stack order (the identity for one pool)."""
+        flat = [li for layers, _ in self.pools for li in layers]
+        return tuple(sorted(range(len(flat)), key=flat.__getitem__))
+
+    @property
+    def cache_bytes(self) -> int:
+        """Bytes of the cache as ``init_cache`` allocates it, every pool."""
+        return sum(len(ls) * n * self.page_bytes for ls, n in self.pools)
 
     @property
     def page_bytes(self) -> int:
@@ -79,14 +138,17 @@ class PagedCacheConfig:
         return (self.planes, self.n_kv_heads, self.block_tokens, self.head_dim)
 
 
-def init_cache(cfg: PagedCacheConfig, sharding=None) -> jax.Array:
+def init_cache(cfg: PagedCacheConfig, sharding=None):
     """Zeroed cache; with ``sharding`` it is created in its shards (a cache
-    sized for a mesh need not fit one device first)."""
-    return jnp.zeros(
-        (cfg.n_layers, cfg.planes, cfg.n_kv_heads, cfg.n_blocks,
-         cfg.block_tokens, cfg.head_dim),
-        dtype=cfg.dtype, device=sharding,
-    )
+    sized for a mesh need not fit one device first).  One array; for a
+    stack with a pool per layer kind a tuple of one array a pool
+    (``cfg.pools``), each over its own layers and blocks."""
+    arrays = tuple(
+        jnp.zeros((len(layers), cfg.planes, cfg.n_kv_heads, n_blocks,
+                   cfg.block_tokens, cfg.head_dim),
+                  dtype=cfg.dtype, device=sharding)
+        for layers, n_blocks in cfg.pools)
+    return arrays if cfg.window_layers else arrays[0]
 
 
 def write_pages(cache: jax.Array, block_ids: jax.Array, pages: jax.Array) -> jax.Array:
@@ -271,6 +333,21 @@ class PrefixPageCache:
                 break
             n += 1
         return n
+
+    def __contains__(self, key: str) -> bool:
+        return key in self._key_to_block
+
+    def match_each(self, keys: Sequence[str]) -> List[Optional[int]]:
+        """Each key's resident page or None, every hit pinned (+1 ref): a
+        pool whose sequences hold a WINDOW of their pages is hit key by
+        key, not as a run from the first chunk."""
+        ids: List[Optional[int]] = []
+        for k in keys:
+            bid = self._key_to_block.get(k)
+            if bid is not None:
+                self._pin(bid)
+            ids.append(bid)
+        return ids
 
     def match_prefix(self, keys: Sequence[str]) -> List[int]:
         """Longest resident run of ``keys``; pins every hit (+1 ref)."""

@@ -44,6 +44,27 @@ def _scatter_stacked(cache: jax.Array, block_ids: jax.Array,
     )
 
 
+@partial(jax.jit, donate_argnums=(0,))
+def _scatter_layers(cache: jax.Array, layer_ids: jax.Array,
+                    block_ids: jax.Array, stacked: jax.Array) -> jax.Array:
+    """``_scatter_stacked`` for SOME layers: store-layout pages [l, n,
+    planes, H, T, D] of layers ``layer_ids`` into ``block_ids``'s slots of
+    the donated cache.  Layer and page id are both indices of the scatter
+    (split by slices, so their dims land in front, in store layout as it
+    is); no other layer's slot of those blocks is written."""
+    return cache.at[layer_ids[:, None], :, :, block_ids[None, :]].set(stacked)
+
+
+@partial(jax.jit, static_argnums=(2,))
+def _gather_by_pool(caches, block_ids, order):
+    """``read_pages`` over a cache of one pool a layer kind: each pool's
+    pages of the same chunks under its own ids, the layers back in stack
+    order (``order``: ``PagedCacheConfig.stack_order``)."""
+    return jnp.concatenate(
+        [read_pages(c, ids) for c, ids in zip(caches, block_ids)],
+        axis=0)[np.asarray(order)]
+
+
 class KVTransferEngine:
     """Moves pages between a paged HBM cache and an infinistore-tpu server.
 
@@ -52,6 +73,11 @@ class KVTransferEngine:
     and DCN link touch; quantized pages live under a distinct key namespace
     (``...#L{i}:q8``) so they can never be misread as bf16 pages.
     """
+
+    # ``load_pages`` takes ``layer_chunks`` and ``lookup_prefix`` a
+    # ``probe_layer``: a stack whose layers need different chunks of a
+    # stored prefix is loaded layer group by layer group
+    loads_by_layer = True
 
     def __init__(
         self,
@@ -214,11 +240,18 @@ class KVTransferEngine:
         """The store layout, defined once for both directions: layer-major,
         chunk-minor ``(key, offset)`` pairs for layers [l0, l1), offsets
         relative to a buffer that starts at layer ``l0``."""
+        return self._layer_blocks(chunk_keys_, range(l0, l1))
+
+    def _layer_blocks(
+        self, chunk_keys_: Sequence[str], layers: Sequence[int]
+    ) -> List[Tuple[str, int]]:
+        """``_page_blocks`` for any layers, in the order given: which
+        layers own a page of each chunk is the caller's to say."""
         pb = self.wire_page_bytes
         n = len(chunk_keys_)
         return [
-            (layer_key(ck, layer) + self._key_suffix, ((layer - l0) * n + i) * pb)
-            for layer in range(l0, l1)
+            (layer_key(ck, layer) + self._key_suffix, (j * n + i) * pb)
+            for j, layer in enumerate(layers)
             for i, ck in enumerate(chunk_keys_)
         ]
 
@@ -227,14 +260,21 @@ class KVTransferEngine:
             k for k, _ in self._page_blocks(chunk_keys_, 0, self.cfg.n_layers)
         ]
 
-    def gather_pages(self, cache: jax.Array, block_ids: Sequence[int]) -> jax.Array:
+    def gather_pages(self, cache, block_ids) -> jax.Array:
         """Device-side half of a save: fused gather (+ transpose, + int8
         quantize) of ``block_ids``'s pages — dispatch-only, returns a small
         device array [L, n, ...] so a caller can snapshot pages mid-prefill
         (jax arrays are immutable) and hand them to a background pusher
-        while the next chunk computes."""
-        ids = jnp.asarray(np.asarray(block_ids, dtype=np.int32))
-        gathered = read_pages(cache, ids)  # [L, planes, H, n, T, D]
+        while the next chunk computes.  A cache of one pool a layer kind
+        (a tuple, ``cfg.pools``) takes one id list a pool, the same chunks
+        in each; what is pushed is every layer's page, in stack order."""
+        if isinstance(cache, tuple):
+            gathered = _gather_by_pool(
+                cache, tuple(jnp.asarray(np.asarray(ids, dtype=np.int32))
+                             for ids in block_ids), self.cfg.stack_order)
+        else:
+            ids = jnp.asarray(np.asarray(block_ids, dtype=np.int32))
+            gathered = read_pages(cache, ids)  # [L, planes, H, n, T, D]
         # -> [L, n, planes, H, T, D]: each (layer, chunk) page contiguous
         pages = jnp.transpose(gathered, (0, 3, 1, 2, 4, 5))
         if self.quant:
@@ -402,8 +442,10 @@ class KVTransferEngine:
         )
 
     def load_pages(
-        self, cache: jax.Array, block_ids: Sequence[int], chunk_keys_: Sequence[str]
-    ) -> jax.Array:
+        self, cache: jax.Array, block_ids: Sequence[int],
+        chunk_keys_: Sequence[str],
+        layer_chunks: Optional[Sequence[Tuple]] = None,
+    ):
         """Get pages from the store and scatter them into HBM.
 
         Mirror image of ``push_pages``'s banding: the read splits into
@@ -412,6 +454,16 @@ class KVTransferEngine:
         socket/pool copy rides behind the host→device DMA instead of
         serializing with it.  Bands write to DISTINCT staging offsets,
         so an in-flight upload never races the next read.
+
+        ``layer_chunks``: which layers need which chunks, as groups
+        ``(layers, indices into chunk_keys_, table)``; a (layer, chunk)
+        page that no group names is not asked of the store, not fetched and
+        not scattered.  ``table[i]`` is chunk ``i``'s page id in the pool
+        that holds the group's layers (``cfg.pools``), in ``block_ids``'s
+        place.  A stack whose sliding-window layers cannot read a
+        prefix's early pages names them in no group, and lands each kind's
+        pages in its own pool (engine.prefill_start).  Default: every
+        layer, every chunk.
 
         Returns the updated cache array; ``cache`` itself is donated to
         it once every byte has landed (a failed fetch raises before that
@@ -424,11 +476,59 @@ class KVTransferEngine:
             return cache
         pb = self.wire_page_bytes
         L = self.cfg.n_layers
+        if layer_chunks is not None:
+            groups = [(list(ls), list(cs), table)
+                      for ls, cs, table in layer_chunks if len(ls) and len(cs)]
+            pages = sum(len(ls) * len(cs) for ls, cs, _ in groups)
+            with tracing.span("kv.load_pages", pages=pages, bytes=pages * pb):
+                return self._load_layer_groups(cache, chunk_keys_, groups,
+                                               pages)
         nbytes = L * n * pb
         with tracing.span("kv.load_pages", pages=L * n, bytes=nbytes):
             return self._load_pages_banded(cache, block_ids, chunk_keys_, n)
 
-    def fetch_pages(self, chunk_keys_: Sequence[str]) -> jax.Array:
+    def _pool_of(self, layers: Sequence[int]) -> Tuple[int, List[int]]:
+        """``(pool, the layers' indices in that pool's array)`` for layers
+        of one kind."""
+        for p, (pool_layers, _) in enumerate(self.cfg.pools):
+            if layers[0] in pool_layers:
+                return p, [pool_layers.index(li) for li in layers]
+        raise ValueError(f"layers {layers} are in no pool of the cache")
+
+    def _load_layer_groups(self, cache, chunk_keys_, groups, pages: int):
+        """Every group fetched (the all-or-nothing half: a missing page
+        raises here, before the cache is touched), then every group
+        scattered into the donated cache, or into its layers' pool of it."""
+        t0 = time.perf_counter()
+        fetched = [self.fetch_pages([chunk_keys_[i] for i in cs], layers=ls)
+                   for ls, cs, _ in groups]
+        t1 = time.perf_counter()
+        pools = list(cache) if isinstance(cache, tuple) else [cache]
+        for (ls, cs, table), stacked in zip(groups, fetched):
+            if self.quant:
+                stacked = dequantize_pages_jit(stacked, self.cfg)
+            p, ls = self._pool_of(ls)
+            pools[p] = _scatter_layers(
+                pools[p], jnp.asarray(np.asarray(ls, dtype=np.int32)),
+                jnp.asarray(np.asarray([table[i] for i in cs],
+                                       dtype=np.int32)), stacked)
+        cache = tuple(pools) if isinstance(cache, tuple) else pools[0]
+        jax.block_until_ready(cache)
+        t2 = time.perf_counter()
+        self.last_load_stages = {
+            "fetch_s": round(t1 - t0, 6), "scatter_s": round(t2 - t1, 6),
+            "pages": pages, "bytes": pages * self.wire_page_bytes,
+        }
+        self._add_totals(
+            "load_totals", loads=1,
+            tokens=len({i for _, cs, _ in groups for i in cs})
+            * self.cfg.block_tokens,
+            bytes=self.last_load_stages["bytes"], fetch_s=t1 - t0,
+            scatter_s=t2 - t1)
+        return cache
+
+    def fetch_pages(self, chunk_keys_: Sequence[str],
+                    layers: Optional[Sequence[int]] = None) -> jax.Array:
         """Wire half of a load: read every (layer, chunk) page of
         ``chunk_keys_`` into this engine's staging ring and hand each
         band to an async H2D upload.  Returns the stacked device array
@@ -437,10 +537,12 @@ class KVTransferEngine:
         caller scatters via ``scatter_pages``.  Split out so the
         cluster layer can fetch different chunks from different nodes
         concurrently (each node engine owns its own staging) and
-        scatter once all bytes verified."""
+        scatter once all bytes verified.  ``layers``: those layers' pages
+        only, stacked in the order given (default: every layer)."""
         n = len(chunk_keys_)
         pb = self.wire_page_bytes
-        L = self.cfg.n_layers
+        layers = list(range(self.cfg.n_layers) if layers is None else layers)
+        L = len(layers)
         nbytes = L * n * pb
         staging = self._ensure_staging(nbytes)
         G = max(1, min(self.pipeline_groups, L))
@@ -449,7 +551,7 @@ class KVTransferEngine:
         meta = []  # (staging offset, span, n_layers) per band
         for l0 in range(0, L, Lg):
             l1 = min(l0 + Lg, L)
-            blocks = self._page_blocks(chunk_keys_, l0, l1)
+            blocks = self._layer_blocks(chunk_keys_, layers[l0:l1])
             off = l0 * n * pb
             bands.append((blocks, pb, staging.ctypes.data + off))
             meta.append((off, (l1 - l0) * n * pb, l1 - l0))
@@ -517,15 +619,21 @@ class KVTransferEngine:
             scatter_s=t2 - t1)
         return out
 
-    def lookup_prefix(self, chunk_keys_: Sequence[str]) -> int:
-        """Longest store-resident prefix, in chunks.  Probes layer 0 keys
-        (a chunk is only readable if every layer committed; layer 0 is
-        written first, so verify the last layer before trusting a hit)."""
+    def lookup_prefix(self, chunk_keys_: Sequence[str],
+                      probe_layer: int = 0) -> int:
+        """Longest store-resident prefix, in chunks.  Probes one layer's
+        keys (a chunk is only readable if every layer committed; layers are
+        written in order, so verify the last layer before trusting a hit).
+        ``probe_layer``: layer 0 unless the caller needs another layer's
+        page of EVERY chunk and layer 0's of only some (a stack that opens
+        with sliding-window layers probes its first full layer: a window
+        layer's early pages may be gone from the store and are not
+        needed)."""
         if not chunk_keys_:
             return 0
         with tracing.span("kv.lookup_prefix", chunks=len(chunk_keys_)):
             sfx = self._key_suffix
-            probe = [layer_key(ck, 0) + sfx for ck in chunk_keys_]
+            probe = [layer_key(ck, probe_layer) + sfx for ck in chunk_keys_]
             idx = self._call("get_match_last_index", probe)
             while idx >= 0:
                 last = layer_key(chunk_keys_[idx], self.cfg.n_layers - 1) + sfx
@@ -548,14 +656,15 @@ class KVTransferEngine:
     # transport is healthy, the BYTES were bad, so the hop degrades to a
     # miss without touching the circuit.
 
-    def guarded_lookup_prefix(self, chunk_keys_: Sequence[str]) -> int:
+    def guarded_lookup_prefix(self, chunk_keys_: Sequence[str],
+                              **kw) -> int:
         """``lookup_prefix`` degraded to 0 (miss) on store failure or an
         open circuit."""
         if not self.breaker.allow():
             _resilience.count_degraded("lookup")
             return 0
         try:
-            n = self.lookup_prefix(chunk_keys_)
+            n = self.lookup_prefix(chunk_keys_, **kw)
         except _resilience.transport_errors():
             self.breaker.record_failure()
             _resilience.count_degraded("lookup")
@@ -568,7 +677,7 @@ class KVTransferEngine:
 
     def guarded_load(
         self, cache: jax.Array, block_ids: Sequence[int],
-        chunk_keys_: Sequence[str],
+        chunk_keys_: Sequence[str], **kw,
     ) -> Tuple[jax.Array, bool]:
         """``load_pages`` degraded to ``(cache-unchanged, False)`` on any
         failure.  Loads are all-or-nothing (the donating scatter runs
@@ -580,7 +689,7 @@ class KVTransferEngine:
         from ..lib import InfiniStoreIntegrityError, InfiniStoreKeyNotFound
 
         try:
-            out = self.load_pages(cache, block_ids, chunk_keys_)
+            out = self.load_pages(cache, block_ids, chunk_keys_, **kw)
         except InfiniStoreKeyNotFound:
             # a matched page was evicted between lookup and load (the
             # server LRU evicts per PAGE key, so a chunk can lose a

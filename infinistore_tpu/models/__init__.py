@@ -36,6 +36,12 @@ from .mla_moe import (
     mla_moe_decode_forward,
     mla_moe_prefill_forward,
 )
+from .cohere2_moe import (
+    Cohere2MoeConfig,
+    cohere2_moe_decode_forward,
+    cohere2_moe_prefill_forward,
+    init_cohere2_moe_params,
+)
 from .attention import (
     apply_rope,
     causal_attention,
@@ -58,6 +64,10 @@ def family_of(cfg) -> dict:
         return {"init": init_mla_moe_params,
                 "fns": {"prefill_fn": mla_moe_prefill_forward,
                         "decode_fn": mla_moe_decode_forward}}
+    if isinstance(cfg, Cohere2MoeConfig):
+        return {"init": init_cohere2_moe_params,
+                "fns": {"prefill_fn": cohere2_moe_prefill_forward,
+                        "decode_fn": cohere2_moe_decode_forward}}
     return {"init": init_params, "fns": {}}
 
 
@@ -67,6 +77,10 @@ __all__ = [
     "mla_moe_prefill_forward",
     "mla_moe_decode_forward",
     "family_of",
+    "Cohere2MoeConfig",
+    "init_cohere2_moe_params",
+    "cohere2_moe_prefill_forward",
+    "cohere2_moe_decode_forward",
     "MoEConfig",
     "MIXTRAL_8X7B",
     "TINY_MOE",

@@ -328,3 +328,101 @@ def paged_multitoken_attention_xla(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgsk,bkhd->bshgd", probs.astype(v.dtype), v)
     return out.reshape(B, S, H, D)
+
+
+def window_page_span(window: int, block_tokens: int) -> int:
+    """Pages that can hold a key visible through a window of ``window``
+    tokens ending at any position: the window's tokens lie in at most
+    ``ceil(window / T) + 1`` consecutive pages, whatever its alignment."""
+    return -(-window // block_tokens) + 1
+
+
+def paged_window_decode_attention(
+    q: jax.Array,
+    cache: jax.Array,
+    layer: int,
+    block_table: jax.Array,
+    seq_lens: jax.Array,
+    window: int,
+) -> jax.Array:
+    """One-token decode attention of a sliding-window layer: the row's
+    WINDOW'S pages are gathered, and no others.
+
+    ``paged_decode_attention`` with a window gathers the whole table and
+    masks what lies outside; here the table's slots are picked first, by
+    index arithmetic on each row's length: the first page that can hold a
+    visible key is ``max(seq_len - window, 0) // T`` and the window spans at
+    most ``window_page_span`` pages from it (one static width).  A page
+    below it is never read, so what it holds, or whether it was ever
+    filled, cannot reach the arithmetic.  Same arguments and the same
+    function of the visible keys: a key at ``j`` is visible to the token at
+    ``i = seq_len - 1`` iff ``i - window < j <= i``."""
+    B, H, D = q.shape
+    T = cache.shape[4]
+    width = block_table.shape[1]
+    span = min(window_page_span(window, T), width)
+    first = jnp.maximum(seq_lens - window, 0) // T               # [B]
+    slots = first[:, None] + jnp.arange(span)[None, :]           # [B, span]
+    sub = jnp.take_along_axis(block_table, jnp.minimum(slots, width - 1),
+                              axis=1)
+    k, v = gather_layer_kv(cache, layer, sub)
+    Hkv = k.shape[2]
+    q = q.reshape(B, Hkv, H // Hkv, D)
+    scale = 1.0 / np.sqrt(D)
+    logits = jnp.einsum("bhgd,bkhd->bhgk", q, k).astype(jnp.float32) * scale
+    # absolute position of each gathered key, from the slot it was taken
+    # from (a slot past the table's end reads the last page again and lies
+    # past the row's length, so it is masked)
+    pos = first[:, None] * T + jnp.arange(span * T)[None, :]     # [B, span*T]
+    mask = (pos < seq_lens[:, None]) & (pos >= seq_lens[:, None] - window)
+    logits = jnp.where(mask[:, None, None, :], logits, -jnp.inf)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhgk,bkhd->bhgd", probs.astype(v.dtype), v)
+    return out.reshape(B, H, D)
+
+
+def grouped_chunk_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    q_pos: jax.Array,
+    k_pos: jax.Array,
+    k_valid: jax.Array | None = None,
+    window: int | None = None,
+) -> jax.Array:
+    """Attention of a prefill chunk by ABSOLUTE positions, one key/value
+    head at a time.
+
+    q: [B, Sq, H, D]; k/v: [B, Sk, H_kv, D] (whatever rows the caller chose
+    to hand over: a whole prefix buffer or a window's slice of one, then the
+    chunk's own); q_pos [Sq], k_pos [Sk] absolute positions; k_valid [Sk]
+    marks rows that hold a key at all (a padded buffer's slack is not one).
+    A key is visible iff valid, ``k_pos <= q_pos`` and, with ``window``,
+    ``k_pos > q_pos - window``.
+
+    The query is viewed [.., H_kv, G, D] and each group is contracted with
+    its head's keys as they are (no ``repeat_kv`` copy), under ``lax.map``
+    over the KV heads: the scores that exist at once are [G, Sq, Sk], an
+    H_kv-th of all heads' (at 128 query heads over 8, a 512-token chunk
+    over a 16k prefix: 0.55 GB in float32 where all heads' are 4.4 GB)."""
+    B, Sq, H, D = q.shape
+    Hkv = k.shape[2]
+    G = H // Hkv
+    scale = 1.0 / np.sqrt(D)
+    mask = k_pos[None, :] <= q_pos[:, None]
+    if window is not None:
+        mask &= k_pos[None, :] > q_pos[:, None] - window
+    if k_valid is not None:
+        mask &= k_valid[None, :]
+
+    def one_head(args):
+        qh, kh, vh = args           # [B, Sq, G, D], [B, Sk, D], [B, Sk, D]
+        logits = jnp.einsum("bsgd,bkd->bgsk", qh, kh).astype(jnp.float32) * scale
+        probs = jax.nn.softmax(jnp.where(mask[None, None], logits, -jnp.inf),
+                               axis=-1)
+        return jnp.einsum("bgsk,bkd->bsgd", probs.astype(vh.dtype), vh)
+
+    out = jax.lax.map(one_head, (
+        jnp.moveaxis(q.reshape(B, Sq, Hkv, G, D), 2, 0),
+        jnp.moveaxis(k, 2, 0), jnp.moveaxis(v, 2, 0)))   # [Hkv, B, Sq, G, D]
+    return jnp.moveaxis(out, 0, 2).reshape(B, Sq, H, D)

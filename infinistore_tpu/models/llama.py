@@ -60,8 +60,12 @@ class LlamaConfig:
     qk_norm: bool = False  # per-head RMSNorm on Q/K before RoPE (Qwen3)
     # attend only to the last N positions (Mistral SWA).  When EVERY layer
     # is windowed (pattern 1) the engine returns window-dead pages to the
-    # pool (engine._reclaim_window_pages); mixed local/global stacks keep
-    # all pages (blocks span the layer stack) and the mask hides them.
+    # pool (engine._reclaim_window_pages).  A mixed local/global stack of
+    # THIS module (Gemma-2) keeps and gathers all pages (one pool, a block
+    # spans the layer stack) and the mask hides them; models/cohere2_moe.py
+    # names its window layers (cfg.layer_windows), which then have a page
+    # pool of their own: a sequence holds their pages for its window only,
+    # gathers those, and fetches no others from the store.
     sliding_window: int | None = None
     # the window applies to layers with ``li % window_pattern == 0``
     # (Gemma-2 alternates local/global attention: pattern 2); pattern 1 =
@@ -133,7 +137,8 @@ def load_config_file(path: str) -> Tuple[str, Any, int]:
     """Resolve a checked-in model config file (``configs/*.json``) to
     ``(model_id, cfg, seed)``.  A file that names a ``family`` states the
     source's sizes itself and is read by that family's module
-    (``models/mla_moe.py:config_from_file``); every other file names a dense
+    (``config_from_file`` of ``models/mla_moe.py`` or
+    ``models/cohere2_moe.py``); every other file names a dense
     preset: a preset of this module by name, the
     ``published`` sizes it must agree with (so a jax-free launcher can read
     them from the file, and a file cannot quietly serve other widths), a
@@ -148,12 +153,15 @@ def load_config_file(path: str) -> Tuple[str, Any, int]:
     with open(path) as f:
         spec = json.load(f)
     if "family" in spec:
-        if spec["family"] != "deepseek_v3":
+        import importlib
+
+        module = {"deepseek_v3": "mla_moe",
+                  "cohere2_moe": "cohere2_moe"}.get(spec["family"])
+        if module is None:
             raise ValueError(f"{path}: family {spec['family']!r} is not one "
                              f"infinistore_tpu.models computes")
-        from .mla_moe import config_from_file
-
-        return config_from_file(path, spec)
+        return importlib.import_module(
+            f".{module}", __package__).config_from_file(path, spec)
     base = globals().get(spec.get("preset"))
     if type(base) is not LlamaConfig:
         raise ValueError(f"{path}: preset {spec.get('preset')!r} is not a "

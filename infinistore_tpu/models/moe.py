@@ -131,8 +131,8 @@ def sigmoid_top_k(scores: jax.Array, bias: jax.Array, top_k: int,
 
 
 def routed_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
-                   w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array
-                   ) -> jax.Array:
+                   w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                   held_from: int | None = None) -> jax.Array:
     """Experts computed for the tokens routed to them.
 
     x: [N, dim]; idx: [N, k] the expert of each of a token's k pairs;
@@ -142,10 +142,21 @@ def routed_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
     The N * k pairs are sorted by expert, so each expert's rows are one run;
     ``ragged_dot`` multiplies each run by its expert's matrix and visits no
     matrix whose run is empty.  The weighted pairs are summed per token in
-    float32.  Static shapes: N * k rows whatever the load."""
+    float32.  Static shapes: N * k rows whatever the load.
+
+    ``held_from``: the layer holds only the E experts ``[held_from,
+    held_from + E)`` of those ``idx`` chooses among (one chip's share of an
+    expert-parallel layer).  Pairs whose expert is absent sort behind every
+    run and belong to none, so the grouped products skip them, and their
+    term is left out of the sum: the result is this share's part of the
+    layer, which the shares of all chips add up to.  ``None`` (every expert
+    is held) lowers as it always has."""
     N, k = idx.shape
     E = w_gate.shape[0]
     flat = idx.reshape(N * k)
+    if held_from is not None:
+        local = flat - held_from
+        flat = jnp.where((local >= 0) & (local < E), local, E)
     order = jnp.argsort(flat)                    # stable: pairs by expert
     sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
     xs = x[order // k]                           # [N * k, dim]
@@ -154,6 +165,10 @@ def routed_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
     y = jax.lax.ragged_dot(h, w_down, sizes,
                            preferred_element_type=jnp.float32)
     y = y * weights.reshape(N * k)[order][:, None]
+    if held_from is not None:
+        # rows behind the last run: whatever the product left there is not
+        # a term of the sum
+        y = jnp.where((flat[order] < E)[:, None], y, 0.0)
     out = jnp.zeros((N * k, y.shape[-1]), jnp.float32).at[order].set(y)
     return out.reshape(N, k, -1).sum(axis=1).astype(x.dtype)
 

@@ -653,10 +653,11 @@ class ServingServer:
             raise ValueError(f"max_tokens must be >= {floor}")
         T = self.engine.pc.block_tokens
         need = -(-(len(prompt) + max_tokens) // T)
-        if need > self.engine.pc.n_blocks:
+        have = min(n for _, n in self.engine.pc.pools)
+        if need > have:
             raise ValueError(
                 f"prompt + max_tokens needs {need} KV pages; this engine "
-                f"has {self.engine.pc.n_blocks}"
+                f"has {have}"
             )
         temperature = float(body.get("temperature", 1.0))
         if not 0.0 <= temperature <= 100.0:
@@ -2538,6 +2539,15 @@ def main(argv: Optional[List[str]] = None) -> None:
                          "env ISTPU_QUOTAS; ISTPU_ADMISSION=0 disables "
                          "the whole admission controller")
     ap.add_argument("--n-blocks", type=int, default=512)
+    ap.add_argument("--window-blocks", type=int, default=None,
+                    help="for a model whose stack mixes sliding-window "
+                    "layers with layers that read everything: the blocks of "
+                    "the WINDOW layers' own page pool (--n-blocks stays the "
+                    "other layers'; a sequence holds window pages for its "
+                    "window only, and for a prompt it computes until each "
+                    "chunk is pushed).  Default: as many as --n-blocks, with "
+                    "which that pool never runs out first; any other model "
+                    "refuses the option")
     ap.add_argument("--block-tokens", type=int, default=16)
     ap.add_argument("--prefill-chunk", type=int, default=None)
     ap.add_argument("--decode-chunk", type=int, default=32,
@@ -2667,21 +2677,24 @@ def main(argv: Optional[List[str]] = None) -> None:
     def seeded(name: str) -> bool:
         return name == "tiny" or name.endswith(".json")
 
-    def refuse_for_family(name: str) -> None:
+    def refuse_for_family(name: str, cfg) -> None:
         """A model family with its own forwards (a latent page, routed
-        experts) has no verify step, no mesh specs and no int8 page scale:
-        say so at start-up, never serve a wrong result."""
+        experts, layers of two attention kinds) has no verify step and no
+        mesh specs, and a page that is not K and V by head has no int8
+        page scale: say so at start-up, never serve a wrong result."""
         bad = [flag for flag, on in (
             ("--tp/--pp", mesh is not None),
-            ("--kv-quant int8", args.kv_quant != "none"),
+            ("--kv-quant int8",
+             args.kv_quant != "none" and cfg.kv_page[0] != 2),
             ("--draft-model", args.draft_model is not None),
             ("--ngram-spec", args.ngram_spec)) if on]
         if bad:
             raise SystemExit(
                 f"{name}: this model family is served without "
-                f"{', '.join(bad)} (its page is not K and V by head and it "
-                f"has no verify step); pass --kv-quant none and drop the "
-                f"rest")
+                f"{', '.join(bad)} (it has no verify step and no mesh "
+                f"specs, and only a page of K and V by head has an int8 "
+                f"scale); pass --kv-quant none for such a page and drop "
+                f"the rest")
 
     def load_model(name: str, seed: int = 0, mesh=None):
         """Returns (model_id, cfg, params, engine_fns) — engine_fns routes
@@ -2696,7 +2709,7 @@ def main(argv: Optional[List[str]] = None) -> None:
             if fam["fns"]:
                 # a family with forwards of its own: what it cannot do is
                 # refused before a weight is drawn
-                refuse_for_family(name)
+                refuse_for_family(name, cfg)
                 return (model_id, cfg,
                         fam["init"](cfg, jax.random.PRNGKey(seed)),
                         fam["fns"])
@@ -2754,7 +2767,11 @@ def main(argv: Optional[List[str]] = None) -> None:
             Logger.warn(
                 f"no usable tokenizer in {tok_src!r}; serving token ids only"
             )
-    pc = PagedCacheConfig.for_model(cfg, args.n_blocks, args.block_tokens)
+    try:
+        pc = PagedCacheConfig.for_model(cfg, args.n_blocks, args.block_tokens,
+                                        window_blocks=args.window_blocks)
+    except ValueError as e:
+        raise SystemExit(f"--window-blocks: {e}")
     conn = None
     endpoints_spec = args.store_endpoints or os.environ.get(
         "ISTPU_STORE_ENDPOINTS"

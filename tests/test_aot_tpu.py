@@ -255,3 +255,65 @@ def test_latent_decode_scan_on_tpu_copies_no_expert_leaf_and_no_slab(v5e):
     # the gathered rows of 8 x 1,024 pages and the vocabulary's logits, not
     # a second cache and not a copy of the experts (1.2 GB a layer)
     assert mem.temp_size_in_bytes < 800 << 20, mem
+
+
+def test_window_layers_on_tpu_gather_their_window_and_no_expert_leaf_is_copied(v5e):
+    """The window/full parallel-block family at its published widths (one
+    period of four layers, 16 of 128 experts, the benchmark's two pools of
+    10,240 and 8,192 blocks, batch 8 over tables of 2,048 pages): in the
+    decode scan a window layer gathers ``window_page_span`` = 257 pages a row
+    out of its pool and only the full layer its table's 2,048 (a window layer
+    that gathered the table to mask seven eighths of it would show four
+    gathers of the table's shape); no held expert leaf is copied; both pools
+    that come out are the donated ones."""
+    from infinistore_tpu.models.attention import window_page_span
+
+    cfg = models.Cohere2MoeConfig(n_layers=4, n_experts_held=16, vocab_size=32768)
+    pc = PagedCacheConfig.for_model(cfg, 10240, T, window_blocks=8192)
+    chip = SingleDeviceSharding(v5e[0])
+    params = _shaped(jax.eval_shape(
+        lambda: models.init_cohere2_moe_params(cfg, jax.random.PRNGKey(0))), chip)
+    cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
+    batch, width = 8, 2048
+    span = window_page_span(cfg.sliding_window, T)
+    assert span == 257
+
+    def decode_scan(params, logits, pos, cache, table):
+        def step(carry, i):
+            logits, cache = carry
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            p = pos + i
+            blocks = jax.tree.map(lambda t: jnp.take_along_axis(
+                t, (p // T)[:, None], axis=1)[:, 0], table)
+            logits, cache, n = models.cohere2_moe_decode_forward(
+                params, cfg, tok, p, cache, table, p + 1, blocks, p % T)
+            return (logits, cache), (tok, n)
+
+        (logits, cache), (toks, n) = jax.lax.scan(
+            step, (logits, cache), jnp.arange(3))
+        return toks, n.sum(), logits, cache
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    compiled = jax.jit(decode_scan, donate_argnums=(3,)).lower(
+        params, sds((batch, cfg.vocab_size), cfg.dtype),
+        sds((batch,), jnp.int32), cache,
+        (sds((batch, width), jnp.int32), sds((batch, width), jnp.int32)),
+    ).compile()
+    text = compiled.as_text()
+    found = _INSTRUCTION.findall(text)
+    assert len(found) > 100, "the optimized program did not parse"
+    Eh, d, f = cfg.n_experts_held, cfg.dim, cfg.ffn_dim
+    copies = [f"{n} = {shape} {op}" for n, shape, op in found
+              if shape in (f"bf16[{Eh},{d},{f}]", f"bf16[{Eh},{f},{d}]")
+              and op not in ("parameter", "bitcast", "get-tuple-element")]
+    assert not copies, copies
+    # gathered pages by the number of (row, page) pairs they hold: K and V of
+    # three window layers at batch x span, of one full layer at batch x width
+    shape = lambda n: f"bf16[{n},{cfg.n_kv_heads},{T},{cfg.head_dim}]"
+    pages = lambda n: [op for _, s, op in found if s == shape(n)
+                       and op in ("gather", "fusion")]
+    # K and V of the one full layer against K and V of three window layers
+    assert 0 < len(pages(batch * width)) * 3 <= len(pages(batch * span)), (
+        pages(batch * width), pages(batch * span))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pc.cache_bytes, mem

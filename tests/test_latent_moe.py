@@ -404,9 +404,15 @@ def test_benchmark_configuration_resolves(entry, tmp_path):
         lambda: family_of(cfg)["init"](cfg, jax.random.PRNGKey(0)))
     held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
     assert counts.weight_bytes(spec) == held
-    pc = PagedCacheConfig.for_model(cfg, 8, spec["serve"]["block_tokens"])
-    assert counts.cache_bytes_per_token(spec) * pc.block_tokens == (
-        pc.page_bytes * pc.n_layers)
+    # the fill check's product is the cache as the server allocates it: one
+    # array, or one pool a layer kind (--window-blocks in serve.args)
+    sv = spec["serve"]
+    window_blocks = (int(sv["args"][sv["args"].index("--window-blocks") + 1])
+                     if "--window-blocks" in sv["args"] else None)
+    pc = PagedCacheConfig.for_model(cfg, sv["n_blocks"], sv["block_tokens"],
+                                    window_blocks=window_blocks)
+    assert (counts.cache_bytes_per_token(spec) * pc.block_tokens * pc.n_blocks
+            == pc.cache_bytes)
     assert counts.store_page_bytes(spec, pc.block_tokens) == pc.page_bytes
     s = counts.sizes(spec)
     assert {"L", "d", "V"} <= set(s) and s["L"] == cfg.n_layers
