@@ -19,6 +19,7 @@ stays in Python.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import time
 from collections import OrderedDict
@@ -42,6 +43,7 @@ from ..kv.cache import (
 )
 from ..kv.hashing import chunk_keys
 from ..kv.transfer import KVTransferEngine
+from ..models.attention import decode_kernel_engages
 from ..models.llama import (
     LlamaConfig,
     decode_forward,
@@ -199,6 +201,24 @@ def _shared_partial(fn, bound: Dict[str, Any]):
     got = _JIT_CACHE.get(key)
     if got is None:
         got = _JIT_CACHE[key] = partial(fn, **bound)
+    return got
+
+
+def _traced_under(fn, mesh):
+    """``fn``, traced with ``mesh`` named (``use_abstract_mesh``).  A program
+    partitioned over a mesh cannot split the TPU's decode-attention kernel
+    by itself; with the mesh named while the model is traced the kernel goes
+    under a shard_map over ``tp`` (models/paged_decode_kernel.py).
+    Memoized, so that the caches keyed on the function hit across engines
+    of one mesh and never across a mesh and a single device."""
+    key = ("under_mesh", fn, mesh)
+    got = _JIT_CACHE.get(key)
+    if got is None:
+        def named(*args, **kwargs):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return fn(*args, **kwargs)
+
+        got = _JIT_CACHE[key] = named
     return got
 
 
@@ -890,6 +910,9 @@ class InferenceEngine:
             decode_fn or decode_forward,
             {"cfg": self.cfg, **lora_kw},
         )
+        if mesh is not None:
+            self._decode_raw = _traced_under(self._decode_raw, mesh)
+        self._attn_in_kernel = self._dense_attention_in_kernel()
         # a custom model family must bring its own verify step: silently
         # binding llama's verify_forward to foreign params would die deep in
         # jit tracing instead of at the call site
@@ -2098,6 +2121,7 @@ class InferenceEngine:
                 block_tokens=T,
                 live_tokens=int(pos[:B].sum()),
                 expert_routing=getattr(self.cfg, "expert_routing", None),
+                attn_kernel=self._attn_in_kernel,
             )
             _stepprof.note_tokens(chunk * B)
             if logprobs:
@@ -2275,26 +2299,57 @@ class InferenceEngine:
         )
         return _ROW0(logits)
 
+    def _dense_attention_in_kernel(self) -> bool:
+        """Whether this engine's decode scan reads its dense layers' pages
+        through the TPU's kernel (models/paged_decode_kernel.py), by the
+        test ``paged_decode_attention`` makes when the program is lowered:
+        the cache on a TPU, a layer that attends to every live key with no
+        soft cap (the models' own rule for which layers have a window), and
+        a page and a mesh the kernel takes.  For
+        ``decode.attn_kernel_steps``; it steers nothing."""
+        cfg, pool = self.cfg, jax.tree.leaves(self.cache)[0]
+        if (next(iter(pool.devices())).platform != "tpu"
+                or getattr(cfg, "attn_softcap", None) is not None):
+            return False
+        windows = getattr(cfg, "layer_windows", None)
+        if windows is None:
+            window = getattr(cfg, "sliding_window", None)
+            every = getattr(cfg, "window_pattern", 1)
+            windows = [window if li % every == 0 else None
+                       for li in range(cfg.n_layers)]
+        q = jax.ShapeDtypeStruct((1, cfg.n_heads, pool.shape[-1]), cfg.dtype)
+        ctx = (jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh)
+               if self.mesh is not None else contextlib.nullcontext())
+        with ctx:
+            return (any(w is None for w in windows)
+                    and decode_kernel_engages(q, pool))
+
     def _block_table(self, states: Sequence[SequenceState],
                      pad_to: Optional[int] = None) -> jax.Array:
         # Width = the LONGEST active sequence's page count, in power-of-two
         # buckets (at most log2 table shapes in the jit cache).  It must
-        # NOT default to the pool size: the XLA decode-attention path
-        # gathers width*T tokens of K and V per row per layer whatever
-        # seq_lens says, so a full-pool table made every decode step pay
-        # the whole pool's gather traffic (measured ~4x per-step cost at
-        # B=8/512 blocks; scaled linearly with n_blocks).  Logical pages
-        # may exceed the physical pool under SWA reclamation (window-dead
-        # prefix pages recycle while their table slots live on, masked) —
-        # ``need`` already counts those slots.
+        # NOT default to the pool size: the XLA readers (the CPU's decode
+        # attention, a window's or a soft cap's, the verify step) gather
+        # width*T tokens of K and V per row per layer whatever seq_lens
+        # says, so a full-pool table made every decode step pay the whole
+        # pool's gather traffic (measured ~4x per-step cost at B=8/512
+        # blocks; scaled linearly with n_blocks).  The TPU's dense decode
+        # kernel copies ceil(seq_len / T) pages a row whatever the width
+        # (models/paged_decode_kernel.py): there the width costs the
+        # table's own bytes only.  Logical pages may exceed the physical
+        # pool under SWA reclamation (window-dead prefix pages recycle
+        # while their table slots live on, masked) — ``need`` already
+        # counts those slots.
         #
         # ``pad_to`` > len(states) appends PAD rows (the decode batch-dim
         # bucket) whose every entry is ``n_blocks`` — one past the pool.
         # Out-of-bounds scatter indices are DROPPED under jit, so a pad
         # row's per-step KV write lands nowhere (a 0-filled row would
         # silently corrupt whatever sequence owns block 0); out-of-bounds
-        # gather indices clamp, so the pad row's attention reads garbage
-        # it then discards.
+        # gather indices clamp, so a pad row's XLA attention reads garbage
+        # it then discards; a copy does not clamp, so the TPU's kernel
+        # takes a first page id past the pool for a pad row and reads
+        # nothing of it.
         need = max((len(st.block_ids) for st in states), default=0)
         width = 8
         while width < need:

@@ -95,8 +95,8 @@ MAX_STEP_IDS = 64
 
 # what ``note_decode`` sums, per step record and over the lifetime
 DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
-                 "table_token_steps", "expert_pairs", "experts_expected",
-                 "expert_pairs_local")
+                 "table_token_steps", "attn_kernel_steps", "expert_pairs",
+                 "experts_expected", "expert_pairs_local")
 
 # what ``note_prefill_budget`` sums, per step record and over the lifetime
 PREFILL_COUNTS = ("granted_tokens", "spent_tokens")
@@ -244,13 +244,17 @@ def note_sync(kind: str, n: int = 1) -> None:
 
 def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
                 block_tokens: int, live_tokens: int,
-                expert_routing: Optional[tuple] = None) -> None:
+                expert_routing: Optional[tuple] = None,
+                attn_kernel: bool = False) -> None:
     """Count ONE decode-scan dispatch with what the engine knows at the
     call: ``steps`` scan steps over ``rows`` live rows in a batch bucket
     of ``padded_rows``, a block table ``width_pages`` wide, and
     ``live_tokens`` = the rows' context lengths summed at the dispatch's
-    start.  ``table_token_steps`` is what the XLA attention reads
-    whatever ``seq_lens`` says (every padded row, the whole width).
+    start.  ``table_token_steps`` is the table as dispatched (every
+    padded row, the whole width): what the XLA attention reads whatever
+    ``seq_lens`` says, and what the TPU's kernel does NOT (it copies the
+    live pages; ``attn_kernel`` says this dispatch's dense attention is
+    that kernel, and ``attn_kernel_steps`` sums its steps).
     ``expert_routing`` = (expert layers, experts a token, experts a layer)
     of a model with routed experts: ``expert_pairs`` counts the (token,
     expert) pairs the steps route (exact: k a row a layer, no token is
@@ -270,6 +274,7 @@ def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
     b["row_steps"] += rows * steps
     b["live_token_steps"] += live_tokens * steps
     b["table_token_steps"] += padded_rows * width_pages * block_tokens * steps
+    b["attn_kernel_steps"] += steps * bool(attn_kernel)
     if expert_routing is not None:
         layers, k, n_experts = expert_routing
         b["expert_pairs"] += rows * k * layers * steps

@@ -19,10 +19,12 @@ heads share: ``[n_layers, 1, 1, n_blocks, block_tokens, 576]``, a page of
 Heads sit OUTSIDE the block axis so a (head, page) tile [block_tokens,
 head_dim] = [16, 128] is contiguous -- exactly the bf16 min tile.  The decode
 step reads and writes this one array by index and never slices a layer out
-of it: the attention gathers ``kv[layer, k|v, :, block_table]``
-(models/attention.py:gather_layer_kv) and ``write_token_kv`` below gathers and
-scatters ``kv[layer, :, :, block_ids]``, so the donated cache is updated in
-place and no layer's slab is copied.
+of it: on a TPU the dense attention's kernel copies ``kv[layer, :, :, page]``,
+2 x H_kv such tiles a page, for each row's live pages
+(models/paged_decode_kernel.py); the XLA readers gather ``kv[layer, k|v, :,
+block_table]`` (models/attention.py:gather_layer_kv); and ``write_token_kv``
+below gathers and scatters ``kv[layer, :, :, block_ids]``, so the donated
+cache is updated in place and no layer's slab is copied.
 
 A page is ``block_tokens`` consecutive tokens of one layer's K+V (all heads)
 -- the unit that maps 1:1 onto a store key (kv/hashing.chunk_keys x layer).

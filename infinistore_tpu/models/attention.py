@@ -1,10 +1,15 @@
 """Attention ops: causal prefill attention and paged decode attention.
-One implementation of each per program, in plain ``jax.numpy``: XLA tiles
-the matmuls onto the MXU and fuses mask and softmax.
+One implementation of each per program.  In plain ``jax.numpy`` (XLA tiles
+the matmuls onto the MXU and fuses mask and softmax) but for one: a TPU's
+program reads the dense decode attention's pages through the kernel of
+``paged_decode_kernel`` (each row's live pages, copied out of the whole
+cache by the block table), chosen when the program is lowered.
 
-* decode attention reads K/V straight from the paged HBM cache via a
-  static-shape page-table gather: [B, max_pages] int32 -> [B, S_max, H_kv, D].
-  No dynamic shapes: padding slots are masked by sequence length.
+* the XLA decode attention (every other platform, a window, a soft cap, a
+  page that is not bf16 K|V by head) reads K/V straight from the paged HBM
+  cache via a static-shape page-table gather: [B, max_pages] int32 ->
+  [B, S_max, H_kv, D].  No dynamic shapes: padding slots are masked by
+  sequence length.
 * GQA in the paged readers (decode, speculative verify) views the query as
   [.., H_kv, G, D] and contracts each group against its KV head's pages as
   gathered: nothing of [B, S, H, D] exists.  Prefill (``causal_attention``)
@@ -12,6 +17,8 @@ the matmuls onto the MXU and fuses mask and softmax.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -252,6 +259,26 @@ def latent_absorbed_decode_attention(
         return jnp.einsum("bhr,rhv->bhv", o_lat, wv)
 
 
+def decode_kernel_engages(q, cache, window=None, softcap=None) -> bool:
+    """Whether the TPU's kernel (``paged_decode_kernel``) can read this page
+    for this query: bf16 K and V
+    by head in ``[T, D]`` tiles the TPU copies whole, a bf16 query, and
+    attention over every live key (a window or a soft cap keeps the XLA
+    form).  Under a named mesh, only where ``tp`` alone divides the
+    program: the KV heads divide over it; a cache whose layers are spread
+    over another axis (``pp``) keeps the XLA form.  Static: shapes, dtypes
+    and the mesh named while tracing; arrays or their abstract values."""
+    if window is not None or softcap is not None or len(cache.shape) != 6:
+        return False
+    _, planes, Hkv, _, T, D = cache.shape
+    axes = dict(jax.sharding.get_abstract_mesh().shape)
+    tp = axes.pop("tp", 1)
+    return (planes == 2 and T % 16 == 0 and D % 128 == 0
+            and q.shape[-2] % Hkv == 0 and Hkv % tp == 0
+            and all(n == 1 for n in axes.values())
+            and cache.dtype == jnp.bfloat16 and q.dtype == jnp.bfloat16)
+
+
 def paged_decode_attention(
     q: jax.Array,
     cache: jax.Array,
@@ -268,7 +295,32 @@ def paged_decode_attention(
     layer whose pages are read (static)
     block_table: [B, max_pages] int32
     seq_lens: [B] int32 -- number of valid tokens (including current)
+
+    One reader per program, chosen by what is known when the program is
+    lowered: for a TPU, where the page is bf16 K and V by head and every
+    live key is attended (no window, no soft cap), the kernel of
+    ``paged_decode_kernel`` copies each row's live pages out of the cache
+    by the table; anywhere else the XLA form below, which gathers the
+    whole padded table (``gather_layer_kv``) and masks by length.  The XLA
+    form is the kernel's oracle in the tests.
     """
+    xla = functools.partial(_paged_decode_attention_xla, layer=layer,
+                            window=window, softcap=softcap)
+    if not decode_kernel_engages(q, cache, window, softcap):
+        return xla(q, cache, block_table, seq_lens)
+    # Pallas is a second of import: paid by the programs that can hold the kernel
+    from . import paged_decode_kernel
+
+    return jax.lax.platform_dependent(
+        q, cache, block_table, seq_lens, default=xla,
+        tpu=functools.partial(
+            paged_decode_kernel.paged_decode_attention_kernel, layer=layer))
+
+
+def _paged_decode_attention_xla(q, cache, block_table, seq_lens, *, layer,
+                                window=None, softcap=None):
+    """``paged_decode_attention`` in plain ``jax.numpy``: the table's pages
+    gathered, contracted against the grouped query, masked by length."""
     B, H, D = q.shape
     k, v = gather_layer_kv(cache, layer, block_table)
     S_max, Hkv = k.shape[1:3]

@@ -437,10 +437,12 @@ def decode_forward(
     this token's K/V.  Returns (logits [B, V], updated cache).
 
     The layers are unrolled and each hands the WHOLE cache and its index to
-    the write and to the attention, which gather that layer's pages by
-    index (write_token_kv, attention.gather_layer_kv): ``cache[li]`` is
-    never formed, because XLA:TPU copies the layer's slab when a slice
-    feeds a gather.
+    the write and to the attention, which find that layer's pages by index
+    (write_token_kv; attention.paged_decode_attention: on a TPU the kernel
+    that copies each row's live pages by the table, elsewhere and under a
+    window or a soft cap the gather of the whole table,
+    attention.gather_layer_kv): ``cache[li]`` is never formed, because
+    XLA:TPU copies the layer's slab when a slice feeds a gather or a call.
     """
     from ..kv.cache import write_token_kv
 

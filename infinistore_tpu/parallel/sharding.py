@@ -190,8 +190,14 @@ def make_tp_decode(cfg: LlamaConfig, mesh: Mesh):
 
     def fn(params, tokens, positions, cache, block_table, seq_lens,
            slot_block_ids, slot_ids):
-        return decode_forward(params, cfg, tokens, positions, cache,
-                              block_table, seq_lens, slot_block_ids, slot_ids)
+        # the mesh is named while the model is traced: the partitioner
+        # cannot split the TPU's decode-attention kernel by itself, so the
+        # kernel goes under a shard_map over ``tp``
+        # (models/paged_decode_kernel.py)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return decode_forward(params, cfg, tokens, positions, cache,
+                                  block_table, seq_lens, slot_block_ids,
+                                  slot_ids)
 
     # donate the cache: it dominates HBM, and the functional update must not
     # allocate a second copy per token

@@ -82,7 +82,7 @@ def _slab_copies(text, heads, pc):
 
 
 @functools.cache
-def _decode_scan_on_tpu(preset, batch, device):
+def _decode_scan_on_tpu(preset, batch, device, width=WIDTH):
     """``decode_forward`` in a short scan as the engine runs it, at the
     family's published widths (two layers, a small cache), compiled for one
     described chip; compiled once for the tests that read it.
@@ -110,9 +110,33 @@ def _decode_scan_on_tpu(preset, batch, device):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     compiled = jax.jit(decode_scan, donate_argnums=(3,)).lower(
         params, sds((batch, cfg.vocab_size), cfg.dtype),
-        sds((batch,), jnp.int32), cache, sds((batch, WIDTH), jnp.int32),
+        sds((batch,), jnp.int32), cache, sds((batch, width), jnp.int32),
     ).compile()
     return cfg, pc, params, cache, compiled
+
+
+def _bf16_results_of(text, elements, weights):
+    """Instructions of the optimized program whose result is a bf16 array of
+    ``elements`` elements, in any order of dimensions, that are neither a
+    parameter, a bitcast nor of a weight's own shape."""
+    return [
+        f"{name} = {shape} {op}" for name, shape, op in _INSTRUCTION.findall(text)
+        if shape.startswith("bf16[") and shape != "bf16[]"
+        and op not in ("parameter", "bitcast") and shape not in weights
+        and int(np.prod([int(d) for d in shape[5:-1].split(",")])) == elements
+    ]
+
+
+def _weight_shapes(params):
+    return {"bf16[%s]" % ",".join(map(str, w.shape))
+            for w in jax.tree.leaves(params)}
+
+
+def _kernel_calls(text):
+    """The optimized program's calls of the decode-attention kernel
+    (models/paged_decode_kernel.py), by the name it gives them."""
+    return [name for name, _, op in _INSTRUCTION.findall(text)
+            if op == "custom-call" and "paged_decode_attention" in name]
 
 
 dense_cells = pytest.mark.parametrize(
@@ -160,11 +184,42 @@ def test_decode_scan_on_tpu_repeats_no_keys_or_values(preset, batch, v5e):
         and op not in ("parameter", "bitcast") and shape not in weights
     ]
     assert len(found) > 100, "the optimized program did not parse"
-    # the reader sees this program's pages: the gather's result is there
-    assert any(n == gathered for *_, n in found)
+    # the reader sees this program's attention: since PR 36 the kernel that
+    # copies live pages, a layer each; not even the gathered table is there
+    assert len(_kernel_calls(compiled.as_text())) == cfg.n_layers
     copies = [f"{name} = {shape} {op}" for name, shape, op, n in found
-              if n == repeated]
+              if n in (repeated, gathered)]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("preset,batch,width", [
+    ("QWEN3_8B", 2, 256), ("QWEN3_8B", 8, 256), ("QWEN25_7B", 32, 256)])
+def test_decode_scan_on_tpu_at_the_cells_tables_gathers_no_table(
+        preset, batch, width, v5e):
+    """The dense cells' widest programs (``doc-reask``: up to 8 rows x 256
+    pages; ``batch-summarize``: 32 x 256): the optimized program holds no
+    bf16 result with the elements of the gathered table, [B * pages, H_kv,
+    T, D] or its layout copy [B, pages, T, H_kv, D] (63% of the scan at 32
+    rows: PERF.md, PR 33), nor a slab; a layer's attention is one call of the
+    kernel, which is handed the whole cache where it lies; the cache that
+    comes out is the donated one; and the scan's temporaries are the logits'
+    and the two layers' re-laid weights, not the table's (the compiler's
+    count, PR 35's tree -> PR 36's: 111 -> 111 MB at 2 x 256, 192 -> 104 at
+    8 x 256, 361 -> 61 at 32 x 256 and 668 -> 61 at 32 x 512)."""
+    cfg, pc, params, cache, compiled = _decode_scan_on_tpu(
+        preset, batch, v5e[0], width)
+    text = compiled.as_text()
+    assert len(_INSTRUCTION.findall(text)) > 100, "the optimized program did not parse"
+    tables = _bf16_results_of(
+        text, batch * width * T * cfg.n_kv_heads * cfg.head_dim,
+        _weight_shapes(params))
+    assert not tables, tables
+    assert not _slab_copies(text, pc.n_kv_heads, pc)
+    assert len(_kernel_calls(text)) == cfg.n_layers
+    mem = compiled.memory_analysis()
+    cache_bytes = int(np.prod(cache.shape)) * cache.dtype.itemsize
+    assert mem.alias_size_in_bytes >= cache_bytes, mem
+    assert mem.temp_size_in_bytes < 128 << 20, mem
 
 
 def test_tp_decode_on_tpu_copies_no_slab_and_gathers_no_cache(v5e):
@@ -257,26 +312,19 @@ def test_latent_decode_scan_on_tpu_copies_no_expert_leaf_and_no_slab(v5e):
     assert mem.temp_size_in_bytes < 800 << 20, mem
 
 
-def test_window_layers_on_tpu_gather_their_window_and_no_expert_leaf_is_copied(v5e):
-    """The window/full parallel-block family at its published widths (one
-    period of four layers, 16 of 128 experts, the benchmark's two pools of
-    10,240 and 8,192 blocks, batch 8 over tables of 2,048 pages): in the
-    decode scan a window layer gathers ``window_page_span`` = 257 pages a row
-    out of its pool and only the full layer its table's 2,048 (a window layer
-    that gathered the table to mask seven eighths of it would show four
-    gathers of the table's shape); no held expert leaf is copied; both pools
-    that come out are the donated ones."""
-    from infinistore_tpu.models.attention import window_page_span
-
+@functools.cache
+def _window_full_scan_on_tpu(device, batch=8, width=2048):
+    """The window/full parallel-block family's decode scan at its published
+    widths (one period of four layers, 16 of 128 experts, the benchmark's
+    two pools of 10,240 and 8,192 blocks, batch 8 over tables of 2,048
+    pages), compiled for one described chip, once for the tests that read
+    it.  -> (cfg, pc, the parameters' abstract arrays, compiled)"""
     cfg = models.Cohere2MoeConfig(n_layers=4, n_experts_held=16, vocab_size=32768)
     pc = PagedCacheConfig.for_model(cfg, 10240, T, window_blocks=8192)
-    chip = SingleDeviceSharding(v5e[0])
+    chip = SingleDeviceSharding(device)
     params = _shaped(jax.eval_shape(
         lambda: models.init_cohere2_moe_params(cfg, jax.random.PRNGKey(0))), chip)
     cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
-    batch, width = 8, 2048
-    span = window_page_span(cfg.sliding_window, T)
-    assert span == 257
 
     def decode_scan(params, logits, pos, cache, table):
         def step(carry, i):
@@ -299,6 +347,25 @@ def test_window_layers_on_tpu_gather_their_window_and_no_expert_leaf_is_copied(v
         sds((batch,), jnp.int32), cache,
         (sds((batch, width), jnp.int32), sds((batch, width), jnp.int32)),
     ).compile()
+    return cfg, pc, params, compiled
+
+
+def test_window_layers_on_tpu_gather_their_window_and_no_expert_leaf_is_copied(v5e):
+    """The window/full parallel-block family at its published widths (one
+    period of four layers, 16 of 128 experts, the benchmark's two pools of
+    10,240 and 8,192 blocks, batch 8 over tables of 2,048 pages): in the
+    decode scan a window layer gathers ``window_page_span`` = 257 pages a row
+    out of its pool and NO layer its table's 2,048 (a window layer that
+    gathered the table to mask seven eighths of it would show gathers of the
+    table's shape; the full layer's were there until PR 36 and are the
+    kernel's live pages now); no held expert leaf is copied; both pools that
+    come out are the donated ones."""
+    from infinistore_tpu.models.attention import window_page_span
+
+    batch, width = 8, 2048
+    cfg, pc, _, compiled = _window_full_scan_on_tpu(v5e[0], batch, width)
+    span = window_page_span(cfg.sliding_window, T)
+    assert span == 257
     text = compiled.as_text()
     found = _INSTRUCTION.findall(text)
     assert len(found) > 100, "the optimized program did not parse"
@@ -308,12 +375,36 @@ def test_window_layers_on_tpu_gather_their_window_and_no_expert_leaf_is_copied(v
               and op not in ("parameter", "bitcast", "get-tuple-element")]
     assert not copies, copies
     # gathered pages by the number of (row, page) pairs they hold: K and V of
-    # three window layers at batch x span, of one full layer at batch x width
+    # three window layers at batch x span, of no layer at batch x width
     shape = lambda n: f"bf16[{n},{cfg.n_kv_heads},{T},{cfg.head_dim}]"
     pages = lambda n: [op for _, s, op in found if s == shape(n)
                        and op in ("gather", "fusion")]
-    # K and V of the one full layer against K and V of three window layers
-    assert 0 < len(pages(batch * width)) * 3 <= len(pages(batch * span)), (
-        pages(batch * width), pages(batch * span))
+    assert not pages(batch * width), pages(batch * width)
+    assert len(pages(batch * span)) >= 2 * 3, pages(batch * span)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pc.cache_bytes, mem
+
+
+def test_full_layer_on_tpu_reads_live_pages_through_the_kernel(v5e):
+    """The same program (groups of 16 query heads over 8 KV heads, 8 rows x
+    2,048 pages): the one full layer's attention is one call of the kernel
+    and nothing holds the elements of its gathered table ([B * pages, H_kv,
+    T, D], or the layout copy ``bf16[8,2048,16,8,128]`` that was 18% of this
+    cell's scan: PERF.md, PR 35), in any order of dimensions; the decode
+    scan's temporaries are under the 2.22 GB they were with it."""
+    batch, width = 8, 2048
+    cfg, pc, params, compiled = _window_full_scan_on_tpu(v5e[0], batch, width)
+    text = compiled.as_text()
+    assert len(_kernel_calls(text)) == 1
+    # a held expert leaf, [16, 4096, 4096], has as many elements as the table
+    tables = _bf16_results_of(
+        text, batch * width * T * cfg.n_kv_heads * cfg.head_dim,
+        _weight_shapes(params))
+    assert not tables, tables
+    # (a slab of the full layer's pool of ONE layer is that pool, written in
+    # place; the window layers' pool of three has slabs, and none is copied)
+    window_pool = PagedCacheConfig(
+        n_layers=len(pc.window_layers), n_kv_heads=pc.n_kv_heads,
+        head_dim=pc.head_dim, n_blocks=pc.window_blocks, block_tokens=T)
+    assert not _slab_copies(text, pc.n_kv_heads, window_pool)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -1,0 +1,236 @@
+"""The TPU's dense decode-attention kernel (models/paged_decode_kernel.py),
+run on the CPU in Pallas' interpret mode against the XLA form it replaces on
+the chip (``attention._paged_decode_attention_xla``, the oracle).
+
+What the chip's program relies on and the CPU cannot time: the kernel reads
+a row's LIVE pages and no others (every other page of the pool is NaN here),
+a pad row reads nothing, and a row's arithmetic depends on that row alone
+(bit-equal alone, in a batch of 32, under a table twice as wide: the paired
+probes of the benchmark rest on it)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from infinistore_tpu.engine import stepprof
+from infinistore_tpu.models import attention
+from infinistore_tpu.utils.metrics import MetricsRegistry
+from infinistore_tpu.models.paged_decode_kernel import (
+    PAGES_PER_BLOCK,
+    paged_decode_attention_kernel,
+)
+
+T, D, L, LAYER = 16, 128, 2, 1
+WIDTH = 2 * PAGES_PER_BLOCK
+# 1; one under and one over a page boundary; an exact multiple of the pages
+# a block; one over it; the table's full width
+LENGTHS = (1, T - 1, T + 1, PAGES_PER_BLOCK * T, PAGES_PER_BLOCK * T + 1,
+           WIDTH * T)
+GROUPS = [(8, 4), (4, 7), (8, 16)]       # (H_kv, G) of the benchmark's cells
+
+
+def _case(h_kv, group, lengths, width, pad_rows=2, seed=0):
+    """A cache whose pages are random, a table that gives each row its own
+    pages in a shuffled order, ``pad_rows`` rows whose table is ``n_blocks``
+    (``engine._block_table``'s pad), and a second cache in which every page
+    past a row's length and every page no row names is NaN."""
+    rng = np.random.default_rng(seed)
+    need = [-(-n // T) for n in lengths]
+    n_blocks = sum(need) + 7
+    cache = rng.standard_normal((L, 2, h_kv, n_blocks, T, D)).astype(np.float32)
+    order = rng.permutation(n_blocks)
+    B = len(lengths) + pad_rows
+    table = np.full((B, width), n_blocks, np.int32)
+    named, at = [], 0
+    for b, n in enumerate(need):
+        ids = order[at:at + n]
+        at += n
+        table[b, :n] = ids
+        # slots past the row's pages name a page of ANOTHER row's, as a
+        # recycled table does: the XLA form gathers it and masks it
+        table[b, n:] = order[0]
+        named.extend(ids)
+    lens = np.asarray(list(lengths) + list(range(1, pad_rows + 1)), np.int32)
+    poisoned = np.full_like(cache, np.nan)
+    poisoned[:, :, :, named] = cache[:, :, :, named]
+    q = rng.standard_normal((B, h_kv * group, D)).astype(np.float32)
+    bf = lambda x: jnp.asarray(x, jnp.bfloat16)
+    return bf(q), bf(cache), bf(poisoned), jnp.asarray(table), jnp.asarray(lens)
+
+
+def _kernel(q, cache, table, lens):
+    return np.asarray(paged_decode_attention_kernel(
+        q, cache, table, lens, layer=LAYER, interpret=True), np.float32)
+
+
+@pytest.mark.parametrize("h_kv,group", GROUPS)
+def test_kernel_reads_live_pages_only_and_agrees_with_the_xla_form(h_kv, group):
+    q, cache, poisoned, table, lens = _case(h_kv, group, LENGTHS, WIDTH)
+    want = np.asarray(attention._paged_decode_attention_xla(
+        q, cache, table, lens, layer=LAYER), np.float32)
+    got = _kernel(q, poisoned, table, lens)
+    assert np.isfinite(got).all()
+    live = len(LENGTHS)
+    # bf16 rounding of values of order 1: the XLA form rounds its scores to
+    # bf16 before the softmax, the kernel keeps them in float32
+    np.testing.assert_allclose(got[:live], want[:live], atol=2e-2)
+    # and to the float32 arithmetic of the same bf16 pages, closer
+    exact = _float32_reference(q, cache, table, lens)
+    np.testing.assert_allclose(got[:live], exact[:live], atol=8e-3)
+    assert np.abs(got[:live] - exact[:live]).max() <= np.abs(
+        want[:live] - exact[:live]).max() + 4e-3
+    # a pad row reads nothing
+    assert (got[live:] == 0).all()
+
+
+def _float32_reference(q, cache, table, lens):
+    q, cache = np.asarray(q, np.float32), np.asarray(cache, np.float32)
+    table, lens = np.asarray(table), np.asarray(lens)
+    B, H, _ = q.shape
+    h_kv = cache.shape[2]
+    out = np.zeros((B, H, D), np.float32)
+    for b in range(B):
+        if table[b, 0] >= cache.shape[3]:
+            continue
+        ids = table[b, :-(-lens[b] // T)]
+        k = cache[LAYER, 0][:, ids].reshape(h_kv, -1, D)[:, :lens[b]]
+        v = cache[LAYER, 1][:, ids].reshape(h_kv, -1, D)[:, :lens[b]]
+        qg = q[b].reshape(h_kv, H // h_kv, D)
+        s = np.einsum("hgd,hkd->hgk", qg, k) / np.sqrt(D)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[b] = np.einsum("hgk,hkd->hgd", p, v).reshape(H, D)
+    return out
+
+
+@pytest.mark.parametrize("h_kv,group", GROUPS)
+def test_a_row_is_bit_equal_alone_in_a_batch_of_32_and_under_a_wider_table(
+        h_kv, group):
+    """The probes' ``pairs exactly 0``: what a sequence reads back does not
+    depend on what it is batched with, on where it stands in the batch, or
+    on the table's width bucket."""
+    rng = np.random.default_rng(3)
+    others = [int(n) for n in rng.integers(1, 3 * T, 31)]
+    n = PAGES_PER_BLOCK * T + 5                     # two blocks, the last ragged
+    lengths = others[:11] + [n] + others[11:]
+    q, _, poisoned, table, lens = _case(h_kv, group, lengths, WIDTH, pad_rows=0)
+    batch = _kernel(q, poisoned, table, lens)[11]
+    alone = _kernel(q[11:12], poisoned, table[11:12], lens[11:12])[0]
+    wide = jnp.concatenate(
+        [table[11:12], jnp.full((1, WIDTH), poisoned.shape[3], jnp.int32)], axis=1)
+    wider = _kernel(q[11:12], poisoned, wide, lens[11:12])[0]
+    assert np.isfinite(batch).all()
+    assert (batch == alone).all() and (batch == wider).all()
+
+
+def test_a_batch_of_pad_rows_only_reads_nothing():
+    q, _, poisoned, table, lens = _case(4, 7, (), WIDTH, pad_rows=3)
+    assert (_kernel(q, poisoned, table, lens) == 0).all()
+
+
+def _page(dtype=jnp.bfloat16, planes=2, h_kv=4, tokens=T, width=D):
+    return jax.ShapeDtypeStruct((L, planes, h_kv, 64, tokens, width), dtype)
+
+
+def _query(dtype=jnp.bfloat16, heads=28):
+    return jax.ShapeDtypeStruct((2, heads, D), dtype)
+
+
+@pytest.mark.parametrize("q,cache,kwargs,engages", [
+    (_query(), _page(), {}, True),
+    (_query(heads=128), _page(h_kv=8), {}, True),
+    (_query(), _page(), {"window": 4096}, False),        # Mistral, Gemma-2
+    (_query(), _page(), {"softcap": 50.0}, False),       # Gemma-2
+    (_query(), _page(dtype=jnp.int8), {}, False),        # --kv-quant int8
+    (_query(jnp.float32), _page(jnp.float32), {}, False),  # the reference's neighbour
+    (_query(jnp.float32), _page(), {}, False),
+    (_query(), _page(planes=1, h_kv=1, width=576), {}, False),  # a latent page
+    (_query(), _page(tokens=8), {}, False),              # half a bf16 tile
+    (_query(), _page(width=64), {}, False),              # half a lane row
+    (_query(heads=30), _page(), {}, False),
+], ids=["qwen2.5", "command-a", "window", "softcap", "int8-page", "float32",
+        "float32-query", "latent-page", "8-token-page", "64-wide-head",
+        "ragged-groups"])
+def test_the_kernel_is_offered_by_shapes_and_dtypes_alone(q, cache, kwargs, engages):
+    assert attention.decode_kernel_engages(q, cache, **kwargs) is engages
+
+
+def test_under_a_named_mesh_the_kernel_is_offered_where_tp_alone_divides():
+    mesh = lambda **axes: jax.sharding.use_abstract_mesh(
+        jax.sharding.AbstractMesh(tuple(axes.values()), tuple(axes)))
+    with mesh(dp=1, tp=4):
+        assert attention.decode_kernel_engages(_query(), _page())
+    with mesh(dp=1, tp=8):                              # 4 KV heads over 8
+        assert not attention.decode_kernel_engages(_query(), _page())
+    with mesh(pp=2, tp=2):                              # layers spread over pp
+        assert not attention.decode_kernel_engages(_query(), _page())
+
+
+def test_on_the_cpu_the_program_holds_the_xla_form_and_no_kernel():
+    """``paged_decode_attention`` offers the kernel to a TPU's lowering only:
+    the CPU's program is the XLA form to the bit, and holds no custom call."""
+    q, cache, _, table, lens = _case(4, 7, (1, 40, 100), 8)
+    fn = jax.jit(lambda q, c, t, n: attention.paged_decode_attention(
+        q, c, LAYER, t, n))
+    want = attention._paged_decode_attention_xla(q, cache, table, lens, layer=LAYER)
+    assert (np.asarray(fn(q, cache, table, lens), np.float32)
+            == np.asarray(want, np.float32)).all()
+    text = fn.lower(q, cache, table, lens).as_text()
+    assert "custom_call" not in text and "gather" in text
+
+
+@pytest.mark.parametrize("attn_kernel,steps", [(True, 32), (False, 0)])
+def test_attn_kernel_steps_counts_the_dispatched_steps_of_a_kernel_program(
+        attn_kernel, steps):
+    prof = stepprof.StepProfiler(metrics=MetricsRegistry(), sample=10**9)
+    with prof.step():
+        stepprof.note_decode(steps=32, rows=3, padded_rows=4, width_pages=8,
+                             block_tokens=T, live_tokens=100,
+                             attn_kernel=attn_kernel)
+    d = prof.summary()["decode"]
+    assert d["steps"] == 32 and d["attn_kernel_steps"] == steps
+
+
+class _OnTpu:
+    """An array as the engine sees its cache on a chip: the shape and dtype
+    of a real pool, a device whose platform is ``tpu``."""
+
+    class _Device:
+        platform = "tpu"
+
+    def __init__(self, pool):
+        self.shape, self.dtype = pool.shape, pool.dtype
+
+    def devices(self):
+        return {self._Device()}
+
+
+@pytest.mark.parametrize("cfg_kwargs,block_tokens,dtype,engaged", [
+    ({}, 16, jnp.bfloat16, True),
+    ({"sliding_window": 64}, 16, jnp.bfloat16, False),       # every layer windowed
+    ({"sliding_window": 64, "window_pattern": 2}, 16, jnp.bfloat16, True),
+    ({"attn_softcap": 30.0}, 16, jnp.bfloat16, False),
+    ({}, 4, jnp.bfloat16, False),                             # a page under a tile
+    ({}, 16, jnp.float32, False),
+], ids=["dense", "mistral", "alternating", "softcap", "4-token-page", "float32"])
+def test_the_engine_counts_kernel_steps_by_the_attentions_own_test(
+        cfg_kwargs, block_tokens, dtype, engaged, monkeypatch):
+    """``decode.attn_kernel_steps`` is the engine's reading of the test
+    ``paged_decode_attention`` makes at lowering.  On the CPU it is 0 for
+    every model; with the cache on a TPU it follows the model's layers
+    (one that attends to every live key is enough), the page and the dtype."""
+    from infinistore_tpu import models
+    from infinistore_tpu.engine import InferenceEngine
+    from infinistore_tpu.kv import PagedCacheConfig
+
+    cfg = models.scaled(models.TINY, head_dim_override=128, dtype=dtype,
+                        **cfg_kwargs)
+    pc = PagedCacheConfig(
+        n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
+        head_dim=cfg.head_dim, n_blocks=8, block_tokens=block_tokens,
+        dtype=dtype)
+    eng = InferenceEngine(models.init_params(cfg, jax.random.PRNGKey(0)), cfg, pc)
+    assert eng._attn_in_kernel is False                      # the CPU's program
+    monkeypatch.setattr(eng, "cache", _OnTpu(eng.cache))
+    assert eng._dense_attention_in_kernel() is engaged
