@@ -599,6 +599,9 @@ class SequenceState:
     # holds (never taken, or returned: stale ids, never gathered)
     window_ids: List[int] = field(default_factory=list)
     window_reclaimed: int = 0
+    # a cache of state slots (engine/state_engine.py): the slot this
+    # sequence's running state lives in; no pages
+    slot: int = -1
     # prefix provenance for the request ledger: of ``reused_chunks``, how
     # many came from the local HBM prefix cache vs the store tier, and
     # the wall seconds the store hops (lookup + load) took — the
@@ -639,6 +642,10 @@ class PartialPrefill:
     # the window pool's table (SequenceState.window_ids / window_reclaimed)
     window_ids: List[int] = field(default_factory=list)
     window_reclaimed: int = 0
+    # a cache of state slots: the row's slot, and the position at which this
+    # prompt's one checkpoint is taken (0: none)
+    slot: int = -1
+    ckpt_at: int = 0
     # provenance carried onto the SequenceState (see its fields)
     local_chunks: int = 0
     store_chunks: int = 0
@@ -655,6 +662,14 @@ class PartialPrefill:
 
 
 class InferenceEngine:
+    # what a subclass for another kind of cache replaces
+    # (engine/state_engine.py): the transfer engine of a single store
+    # connection, what the prefill program donates, and whether prompts
+    # without a store may share one padded forward
+    transfer_cls = KVTransferEngine
+    prefill_donates: tuple = ()
+    batched_prefill = True
+
     def __init__(
         self,
         params,
@@ -830,7 +845,7 @@ class InferenceEngine:
             if isinstance(conn, RoutedStorePool):
                 self.transfer = ClusterTransferEngine(conn, pc, quant=kv_quant)
             else:
-                self.transfer = KVTransferEngine(conn, pc, quant=kv_quant)
+                self.transfer = self.transfer_cls(conn, pc, quant=kv_quant)
         if store_durability not in ("strict", "relaxed"):
             # a real error, not an assert: under python -O a typo would
             # otherwise silently behave as relaxed and drop the strict
@@ -905,6 +920,7 @@ class InferenceEngine:
         self._prefill_jit = _shared_jit(
             prefill_fn or prefill_forward,
             {"cfg": self.cfg, **lora_kw},
+            donate=self.prefill_donates,
         )
         self._decode_raw = _shared_partial(
             decode_fn or decode_forward,
@@ -1524,7 +1540,8 @@ class InferenceEngine:
         out: List[Optional[SequenceState]] = [None] * len(prompts)
         created: List[SequenceState] = []
         try:
-            if self.transfer is not None or self.wpages is not None:
+            if (self.transfer is not None or self.wpages is not None
+                    or not self.batched_prefill):
                 for i, p in enumerate(prompts):
                     st = self.prefill(p, adapter_id=aids[i])
                     created.append(st)
@@ -2027,17 +2044,7 @@ class InferenceEngine:
                        jnp.asarray(pres), jnp.asarray(freq),
                        jnp.asarray(rep), jnp.asarray(bias))
         T = self.pc.block_tokens
-        for st in states:
-            # return window-dead pages first so the run's new tail pages
-            # can come straight from them under memory pressure
-            self._reclaim_window_pages(st)
-            need = -(-(len(st.tokens) + n_steps) // T)
-            if need > len(st.block_ids):
-                st.block_ids.extend(self.pages.acquire(need - len(st.block_ids)))
-            if self.wpages is not None and need > len(st.window_ids):
-                grow = need - len(st.window_ids)
-                st.window_ids.extend(self.wpages.acquire(grow))
-                self._note_window_pages("acquired", grow)
+        self._grow_tables(states, n_steps)
         block_table = self._block_table(states, pad_to=Bp)
         if rng is None:
             # advance the engine's own stream: repeated sampling calls must
@@ -2119,7 +2126,7 @@ class InferenceEngine:
                 steps=chunk, rows=B, padded_rows=Bp,
                 width_pages=jax.tree.leaves(block_table)[0].shape[1],
                 block_tokens=T,
-                live_tokens=int(pos[:B].sum()),
+                live_tokens=self._live_tokens(pos[:B]),
                 expert_routing=getattr(self.cfg, "expert_routing", None),
                 attn_kernel=self._attn_in_kernel,
             )
@@ -2298,6 +2305,27 @@ class InferenceEngine:
             **self._lora_args([state.adapter_id]),
         )
         return _ROW0(logits)
+
+    def _grow_tables(self, states: Sequence[SequenceState],
+                     n_steps: int) -> None:
+        """Pages for ``n_steps`` more tokens of every row, before the run."""
+        T = self.pc.block_tokens
+        for st in states:
+            # return window-dead pages first so the run's new tail pages
+            # can come straight from them under memory pressure
+            self._reclaim_window_pages(st)
+            need = -(-(len(st.tokens) + n_steps) // T)
+            if need > len(st.block_ids):
+                st.block_ids.extend(self.pages.acquire(need - len(st.block_ids)))
+            if self.wpages is not None and need > len(st.window_ids):
+                grow = need - len(st.window_ids)
+                st.window_ids.extend(self.wpages.acquire(grow))
+                self._note_window_pages("acquired", grow)
+
+    def _live_tokens(self, lens: np.ndarray) -> int:
+        """What ``decode.live_token_steps`` counts a step: the rows' context
+        lengths, the tokens a paged attention has to read."""
+        return int(lens.sum())
 
     def _dense_attention_in_kernel(self) -> bool:
         """Whether this engine's decode scan reads its dense layers' pages

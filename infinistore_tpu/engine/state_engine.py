@@ -1,0 +1,372 @@
+"""The engine over a cache whose unit is a STATE, not a page.
+
+A power-retention layer (models/retention.py) keeps no key or value per
+token: a sequence's whole past in one layer is one array of fixed size, and
+every step WRITES it.  What that forces on the cache tier, and what of
+``InferenceEngine`` this subclass replaces for it:
+
+* **Slots** (kv/cache.py ``StateCacheConfig`` / ``StateSlots``).  ``self.cache``
+  is ``(S [slots, L, H_kv, F, D], z [slots, L, H_kv, F])``, float32, donated
+  through the prefill chunk and the decode scan.  A running row owns one slot
+  and writes it (``SequenceState.slot``); a resident checkpoint owns one and is
+  never written; ``release`` returns the row's.  No pages, no block table:
+  the scan's ``block_table`` is the rows' slot ids ``[B, 1]``.
+* **A checkpoint a prompt.**  A state summarises everything before it, so a
+  prefix is reusable only at a position at which a state was KEPT: of a
+  prompt the deepest multiple of ``pc.stride`` that at least one token
+  follows.  The state there is copied into a resident slot under that
+  position's chunk key (kv/hashing.chunk_keys, unchanged) and pushed to the
+  store, every layer, flushed before the prefill returns under strict
+  durability.  Decode takes none.
+* **A hit COPIES.**  ``prefill_start`` finds the deepest stride-aligned
+  position whose key is resident in HBM, else in the store, copies or loads
+  that checkpoint into the row's own slot (a loaded one is kept resident
+  too, as a computed one is) and prefills from there: chunk
+  boundaries then fall where they fell when the prompt was first computed,
+  so a re-ask's logits are bit for bit the computed prompt's.  "The longest
+  matching prefix" became "the deepest checkpoint whose key matches"; what a
+  prompt shares with an earlier one beyond that is recomputed
+  (``shared_tokens_recomputed`` counts it).  A store failure costs a miss and
+  a recompute, never a request (``guarded_*``).  A row that adopts nothing
+  starts from a slot zeroed for it: what a slot held before never reaches
+  the arithmetic.
+
+``reused_chunks`` / ``local_chunks`` / ``store_chunks`` / ``store_load_s`` and
+``istpu_engine_prefix_tokens_total`` keep their meaning: 16-token chunks (and
+tokens) of the prompt not recomputed, by where their checkpoint came from.
+"""
+
+from __future__ import annotations
+
+from collections import OrderedDict
+from functools import partial
+from typing import List, Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+
+from ..kv.cache import StateCacheConfig, StateSlots
+from ..kv.hashing import chunk_keys
+from ..kv.transfer import StateTransferEngine
+from ..utils import metrics as _metrics
+from . import stepprof as _stepprof
+from .engine import (
+    _LAST_ROW,
+    _PREFIX_TOKENS,
+    _PREFIX_TOKENS_TENANT,
+    InferenceEngine,
+    PartialPrefill,
+    SequenceState,
+)
+from .. import usage as _usage
+
+_CHECKPOINTS = _metrics.default_registry().counter(
+    "istpu_engine_state_checkpoints_total",
+    "State checkpoints of prompts: taken into a resident slot, pushed to the "
+    "store, or not pushed because the store had their key",
+    labelnames=("event",),
+)
+_BYTES_PUSHED = _metrics.default_registry().counter(
+    "istpu_engine_state_bytes_pushed_total",
+    "Bytes of state checkpoints handed to the store",
+)
+_ADOPTIONS = _metrics.default_registry().counter(
+    "istpu_engine_state_adoptions_total",
+    "Prompts that started from a checkpoint, by where it came from",
+    labelnames=("source",),
+)
+_SHARED_RECOMPUTED = _metrics.default_registry().counter(
+    "istpu_engine_state_shared_tokens_recomputed_total",
+    "Prompt tokens shared with an earlier prompt but recomputed because no "
+    "checkpoint was kept that deep",
+)
+_EVICTED = _metrics.default_registry().counter(
+    "istpu_engine_state_resident_evicted_total",
+    "Resident checkpoints evicted from their slot for a newer one",
+)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _copy_slot(cache, src, dst):
+    """Slot ``src`` over slot ``dst`` of the donated slots, every layer."""
+    return tuple(a.at[dst].set(a[src]) for a in cache)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _zero_slot(cache, dst):
+    return tuple(a.at[dst].set(0.0) for a in cache)
+
+
+class StateEngine(InferenceEngine):
+    transfer_cls = StateTransferEngine
+    prefill_donates = ("cache",)
+    batched_prefill = False      # a row's slot is taken in ``prefill_start``
+
+    # chunk keys of prompts seen, to count what a prompt shares with an
+    # earlier one beyond the checkpoint it could adopt; host strings only
+    SEEN_KEYS = 1 << 16
+
+    def __init__(self, params, cfg, pc: StateCacheConfig, **kw):
+        if kw.get("kv_quant") is not None:
+            raise ValueError(
+                f"kv quant {kw['kv_quant']!r} scales pages per (K|V, head); "
+                f"a state has no such scale and goes to the store as it is")
+        kw["kv_quant"] = None
+        for name in ("mesh", "lora", "verify_fn"):
+            if kw.get(name) is not None:
+                raise ValueError(f"a cache of state slots is served without "
+                                 f"{name}")
+        if kw.get("conn") is not None:
+            from ..cluster import RoutedStorePool
+
+            if isinstance(kw["conn"], RoutedStorePool):
+                raise ValueError(
+                    "state checkpoints go to ONE store connection; the "
+                    "clustered transfer routes pages by chunk")
+        kw.setdefault("max_seqs", pc.max_rows)
+        super().__init__(params, cfg, pc, **kw)
+        if self.prefill_chunk is None or pc.stride % self.prefill_chunk:
+            raise ValueError(
+                f"a checkpoint is taken at the end of a prefill chunk: the "
+                f"stride {pc.stride} must be a multiple of prefill_chunk "
+                f"({self.prefill_chunk})")
+        self.slots = StateSlots(pc.n_slots, pc.max_rows)
+        self._seen: "OrderedDict[str, None]" = OrderedDict()
+
+    def _dense_attention_in_kernel(self) -> bool:
+        return False
+
+    def _count(self, **counts: int) -> None:
+        """One event into every sink: /debug/engine's ``summary.state`` and
+        the /metrics families."""
+        _stepprof.note_state(**counts)
+        for k, n in counts.items():
+            if k.startswith("checkpoints_"):
+                _CHECKPOINTS.labels(k[len("checkpoints_"):]).inc(n)
+            elif k.startswith("adopted_"):
+                _ADOPTIONS.labels(k[len("adopted_"):]).inc(n)
+            else:
+                {"bytes_pushed": _BYTES_PUSHED,
+                 "shared_tokens_recomputed": _SHARED_RECOMPUTED,
+                 "resident_evicted": _EVICTED}[k].inc(n)
+
+    # ---- prefill ----
+
+    def prefill_start(self, tokens: Sequence[int],
+                      adapter_id: int = 0) -> PartialPrefill:
+        """Admission half of a prefill: a row's slot (``MemoryError`` where
+        every one is taken), the deepest checkpoint this prompt can start
+        from, its copy into that slot, and the chunking."""
+        assert adapter_id == 0 and len(tokens) >= 1, adapter_id
+        tokens = list(tokens)
+        row = self.slots.take_row()
+        try:
+            return self._start_in_row(tokens, row)
+        except BaseException:
+            self.slots.free_row(row)
+            raise
+
+    def _start_in_row(self, tokens: List[int], row: int) -> PartialPrefill:
+        T, n = self.pc.block_tokens, len(tokens)
+        keys = chunk_keys(tokens, self.model_id, chunk_tokens=T)
+        # where a checkpoint may lie and leave a token to compute (the last
+        # token's logits start the decode): stride, 2 x stride, .. <= n - 1
+        aligned = list(range(self.pc.stride, n, self.pc.stride))
+
+        def key_at(p: int) -> str:
+            return keys[p // T - 1]
+
+        P, source = 0, None
+        lookup_s = load_s = 0.0
+        for p in reversed(aligned):          # deepest resident in HBM
+            src = self.slots.match(key_at(p))
+            if src is not None:
+                # copied, not shared: the row will write its slot
+                self.cache = _copy_slot(self.cache, src, row)
+                self.slots.unpin(src)
+                P, source = p, "local"
+                break
+        deeper = [p for p in aligned if p > P]
+        if self.transfer is not None and deeper:
+            # breaker-guarded: a dead store reports a miss, never a failure
+            with _stepprof.phase("kv.lookup") as ph:
+                hit = self.transfer.guarded_lookup_prefix(
+                    [key_at(p) for p in deeper])
+            lookup_s = ph.s
+            if hit:
+                p = deeper[hit - 1]
+                with _stepprof.phase("kv.load") as ph:
+                    self.cache, ok = self.transfer.guarded_load(
+                        self.cache, [row], [key_at(p)], tokens=p)
+                load_s = ph.s
+                if ok:
+                    P, source = p, "store"
+                    # a store hit becomes resident as a computed checkpoint
+                    # does (its copy leaves HBM again by the same LRU)
+                    self._keep_resident(key_at(p), row)
+        if source is None:
+            self.cache = _zero_slot(self.cache, row)
+        else:
+            self._count(**{f"adopted_{source}": 1})
+        shared = self._shared_with_earlier(keys) * T
+        if shared > P:
+            self._count(shared_tokens_recomputed=min(shared, n - 1) - P)
+
+        local = P if source == "local" else 0
+        store = P if source == "store" else 0
+        tenant = _usage.current_account()
+        for label, count in (("local", local), ("store", store),
+                             ("computed", n - P)):
+            if count:
+                _PREFIX_TOKENS.labels(label).inc(count)
+                if tenant is not None:
+                    _PREFIX_TOKENS_TENANT.labels(tenant, label).inc(count)
+
+        suffix = tokens[P:]
+        S = len(suffix)
+        padded = suffix + [0] * ((-S) % T)
+        C = self.prefill_chunk
+        return PartialPrefill(
+            tokens=tokens, keys=keys, block_ids=[], reused=P // T,
+            done=P // T, n_complete=n // T, padded=padded, C=C,
+            single=C >= len(padded), buf=None, plen=P, S=S,
+            slot=row, ckpt_at=aligned[-1] if aligned and aligned[-1] > P else 0,
+            local_chunks=local // T, store_chunks=store // T,
+            store_load_s=lookup_s + load_s, lookup_s=lookup_s,
+        )
+
+    def _shared_with_earlier(self, keys: List[str]) -> int:
+        """Leading chunks of ``keys`` that an earlier prompt had too (a key
+        commits to its whole prefix); then these are remembered."""
+        shared = 0
+        for k in keys:
+            if k not in self._seen:
+                break
+            shared += 1
+        for k in keys:
+            self._seen[k] = None
+            self._seen.move_to_end(k)
+        while len(self._seen) > self.SEEN_KEYS:
+            self._seen.popitem(last=False)
+        return shared
+
+    def _prefill_chunk(self, pp: PartialPrefill) -> Optional[SequenceState]:
+        off, C = pp.off, pp.C
+        chunk = pp.padded[off: off + C]
+        start = pp.plen + off
+        pp.logits, self.cache = self._prefill_jit(
+            self.params, tokens=jnp.asarray(chunk, dtype=jnp.int32)[None],
+            cache=self.cache, slot=jnp.asarray(pp.slot, jnp.int32),
+            start=jnp.asarray(start, jnp.int32),
+            n_valid=jnp.asarray(min(len(chunk), pp.S - off), jnp.int32))
+        _stepprof.note_dispatch("prefill")
+        pp.off_last, pp.off = off, off + C
+        pp.done = (start + len(chunk)) // self.pc.block_tokens
+        if pp.ckpt_at and start + len(chunk) == pp.ckpt_at:
+            self._checkpoint(pp)
+        if pp.off < len(pp.padded):
+            return None
+
+        if self.transfer is not None and self.store_durability == "strict":
+            with _stepprof.phase("kv.push_wait"):
+                self._streamer.flush()
+        state = SequenceState(
+            seq_id=self._next_id, tokens=pp.tokens, block_ids=[],
+            chunk_keys=pp.keys, reused_chunks=pp.reused,
+            last_logits=_LAST_ROW(pp.logits, (pp.S - 1) - pp.off_last),
+            slot=pp.slot, local_chunks=pp.local_chunks,
+            store_chunks=pp.store_chunks, store_load_s=pp.store_load_s,
+            lookup_s=pp.lookup_s,
+        )
+        pp.slot = -1
+        self._next_id += 1
+        self.seqs[state.seq_id] = state
+        return state
+
+    def _checkpoint(self, pp: PartialPrefill) -> None:
+        """The row's state, now that of position ``pp.ckpt_at``, kept: a copy
+        in a resident slot under that position's key, and a push of every
+        layer unless the store has the key.  Both read the row's slot as it
+        is NOW (a copy and a gather, enqueued before the next chunk's
+        write), so the row goes on at once."""
+        key = pp.keys[pp.ckpt_at // self.pc.block_tokens - 1]
+        if self._keep_resident(key, pp.slot):
+            self._count(checkpoints_taken=1)
+        if self.transfer is None:
+            return
+        with _stepprof.phase("kv.lookup"):
+            stored = self.transfer.guarded_lookup_prefix([key])
+        if stored:
+            self._count(checkpoints_skipped_stored=1)
+            return
+        with _stepprof.phase("kv.push_submit"):
+            self.transfer.covers(key, pp.ckpt_at)
+            self._streamer.submit(
+                self.transfer.gather_pages(self.cache, pp.slot), [key])
+        self._count(checkpoints_pushed=1, bytes_pushed=self.pc.slot_bytes)
+
+    def _keep_resident(self, key: str, row: int) -> bool:
+        """Row ``row``'s state as it is now, copied into a resident slot under
+        ``key`` (the least recently used unpinned checkpoint goes); False
+        where the key is resident already or every slot is pinned."""
+        if key in self.slots:
+            return False
+        before = self.slots.evicted
+        dst = self.slots.keep()
+        if dst is None:
+            return False
+        self.cache = _copy_slot(self.cache, row, dst)
+        self.slots.register(key, dst)
+        self.slots.unpin(dst)
+        if self.slots.evicted > before:
+            self._count(resident_evicted=1)
+        return True
+
+    def abandon_prefill(self, pp: PartialPrefill) -> None:
+        if pp.slot >= 0:
+            self.slots.free_row(pp.slot)
+            pp.slot = -1
+
+    def adopt_prefill(self, tokens, kv, last_logits):
+        raise ValueError("adopt_prefill lands K and V in pages; this model "
+                         "keeps a state (prefill it through the engine)")
+
+    def prompt_logprobs(self, tokens, k: int = 0, adapter_id: int = 0):
+        raise ValueError("prompt scoring runs a paged family's dense "
+                         "forward; this model's prefill runs through its "
+                         "state slots")
+
+    def propose(self, *a, **kw):
+        raise ValueError("a cache of state slots drafts nothing: a rejected "
+                         "token cannot be taken out of a state")
+
+    # ---- decode ----
+
+    def _grow_tables(self, states, n_steps: int) -> None:
+        pass                    # a state does not grow with the sequence
+
+    def _live_tokens(self, lens) -> int:
+        # a row's read is its state, whatever its length: one table entry of
+        # ``block_tokens`` a row, so that ``live / table`` is the share of
+        # the states read that are real rows' and not pad rows'
+        return len(lens) * self.pc.block_tokens
+
+    def _block_table(self, states, pad_to: Optional[int] = None) -> jax.Array:
+        """The rows' slots ``[rows, 1]`` in the block table's place; a pad
+        row's is one past the slots (its read clamps, its write is
+        dropped)."""
+        pad = (pad_to or len(states)) - len(states)
+        return jnp.asarray([[st.slot] for st in states]
+                           + [[self.pc.n_slots]] * pad, dtype=jnp.int32)
+
+    @property
+    def free_pages(self) -> int:
+        """What admission compares a request's pages with: any request fits
+        while a row's slot is free (``serve`` bounds one by ``n_blocks``)."""
+        return self.slots.rows_free * self.pc.n_blocks
+
+    def release(self, state: SequenceState) -> None:
+        if state.slot >= 0:
+            self.slots.free_row(state.slot)
+            state.slot = -1
+        self.seqs.pop(state.seq_id, None)

@@ -106,6 +106,12 @@ KV_COUNTS = ("store_pages_full", "store_pages_window",
              "store_pages_window_skipped", "window_pages_acquired",
              "window_pages_returned")
 
+# what ``note_state`` sums, per step record and over the lifetime
+STATE_COUNTS = ("checkpoints_taken", "checkpoints_pushed",
+                "checkpoints_skipped_stored", "bytes_pushed",
+                "adopted_local", "adopted_store", "shared_tokens_recomputed",
+                "resident_evicted")
+
 # the key that counts operations in the transfer's running totals
 _STORE_COUNT = {"push": "pushes", "load": "loads"}
 
@@ -312,6 +318,23 @@ def note_kv_pages(**counts: int) -> None:
         b[k] += n
 
 
+def note_state(**counts: int) -> None:
+    """Count what a cache of STATE SLOTS does (``STATE_COUNTS``;
+    engine/state_engine.py): checkpoints taken into a resident slot, pushed
+    to the store, and not pushed because the store had their key; the bytes
+    pushed; prompts that adopted a checkpoint, by where it came from; the
+    tokens a prompt shared with an earlier one BEYOND the checkpoint it could
+    adopt (shared, yet recomputed: a state is reusable only where one was
+    kept); resident checkpoints evicted for a newer one.  Summed under
+    ``rec["state"]``."""
+    rec = _ACTIVE.get()
+    if rec is None:
+        return
+    b = rec.setdefault("state", dict.fromkeys(STATE_COUNTS, 0))
+    for k, n in counts.items():
+        b[k] += n
+
+
 def note_prefill_budget(granted_tokens: int, spent_tokens: int) -> None:
     """Count ONE step's prefill token budget at the scheduler's call: the
     chunk tokens the step was GRANTED (``max_batch`` chunks, or the
@@ -473,6 +496,7 @@ class StepProfiler:
         # lifetime sums of the steps' prefill budgets (note_prefill_budget)
         self._prefill_totals = dict.fromkeys(PREFILL_COUNTS, 0)
         self._kv_totals = dict.fromkeys(KV_COUNTS, 0)
+        self._state_totals = dict.fromkeys(STATE_COUNTS, 0)
         # flat phases of the driving thread (see ``enter``)
         self.phase: Optional[str] = None
         self._phase_t0 = 0.0
@@ -746,6 +770,8 @@ class StepProfiler:
                 self._prefill_totals[k] += n
             for k, n in rec.get("kv", {}).items():
                 self._kv_totals[k] += n
+            for k, n in rec.get("state", {}).items():
+                self._state_totals[k] += n
             self._wall_s += dur
             if sampled:
                 self._sampled += 1
@@ -834,6 +860,7 @@ class StepProfiler:
             decode = dict(self._decode_totals)
             prefill = dict(self._prefill_totals)
             kv = dict(self._kv_totals)
+            state = dict(self._state_totals)
             # the open phase counts up to this moment: a scrape in the
             # middle of a long decode.wait loses nothing
             phase_s = dict(self._phase_s)
@@ -888,6 +915,8 @@ class StepProfiler:
             "prefill": prefill,
             # adopted store prefixes' pages by layer kind (note_kv_pages)
             "kv": kv,
+            # a cache of state slots: checkpoints and adoptions (note_state)
+            "state": state,
             "store": {k: dict(t) for k, t in
                       self._store_totals(self._transfer).items()},
         }

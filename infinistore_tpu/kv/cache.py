@@ -38,6 +38,15 @@ allocator, residency and block table, so that a sequence holds window-layer
 pages for its window only (engine.py).  Every other stack is the case "one
 kind": one array, one block id across every layer.
 
+A family whose layers keep NO key or value per token (power retention,
+models/retention.py) has no page at all: a sequence's whole past in one layer
+is a STATE of fixed size.  ``StateCacheConfig`` states that unit, ``init_cache``
+gives its slots, and ``StateSlots`` is their host-side bookkeeping: a running
+row owns a slot and writes it; a checkpoint of a prefix is a slot that is never
+written, held by the prefix's chunk key and COPIED into a row's slot when
+adopted (a state summarises everything before it, so it cannot be shared the
+way a read-only page is).
+
 Static shapes everywhere: gathers/scatters take fixed-width index vectors so
 XLA compiles one program per (n_pages,) width; the host-side ``BlockAllocator``
 is plain Python (never traced).
@@ -140,11 +149,166 @@ class PagedCacheConfig:
         return (self.planes, self.n_kv_heads, self.block_tokens, self.head_dim)
 
 
-def init_cache(cfg: PagedCacheConfig, sharding=None):
+@dataclass(frozen=True)
+class StateCacheConfig:
+    """The cache of a family whose unit is a STATE, not a page: ``n_slots``
+    slots of ``[n_layers, n_kv_heads, state_dim, head_dim]`` (``S``) and
+    ``[n_layers, n_kv_heads, state_dim]`` (``z``), float32.  ``max_rows`` of
+    them are the running rows' (each writes its own), the rest hold resident
+    checkpoints (never written).  A checkpoint is kept every ``stride``
+    tokens at most; ``block_tokens`` is the chunk of the prefix keys
+    (kv/hashing.chunk_keys) and the unit ``n_blocks`` is counted in, so that
+    ``n_blocks x block_tokens`` is the tokens the slots stand for, as for a
+    paged cache: ``n_slots = n_blocks x block_tokens / stride``.
+
+    The transfer engine moves ONE LAYER'S STATE where it moves a page
+    (``page_shape`` / ``page_bytes``: ``S`` and ``z`` of one layer laid end
+    to end by head), so the store, its keys and its staging need nothing
+    new."""
+
+    n_layers: int
+    n_kv_heads: int
+    state_dim: int
+    head_dim: int
+    n_blocks: int
+    stride: int
+    max_rows: int
+    block_tokens: int = 16
+    dtype: jnp.dtype = jnp.float32
+    planes: int = 1             # no K|V split: int8 pages refuse it
+    window_layers: Tuple[int, ...] = ()
+
+    @classmethod
+    def for_model(cls, cfg, n_blocks: int, block_tokens: int, stride: int,
+                  max_rows: int) -> "StateCacheConfig":
+        heads, width, head_dim = cfg.state_shape
+        pc = cls(n_layers=cfg.n_layers, n_kv_heads=heads, state_dim=width,
+                 head_dim=head_dim, n_blocks=n_blocks, stride=stride,
+                 max_rows=max_rows, block_tokens=block_tokens)
+        if stride % block_tokens or stride <= 0:
+            raise ValueError(f"a checkpoint lies at the end of a chunk of "
+                             f"{block_tokens} tokens: stride {stride} is no "
+                             f"multiple of it")
+        if pc.n_slots < max_rows:
+            raise ValueError(
+                f"{n_blocks} blocks of {block_tokens} tokens at a stride of "
+                f"{stride} are {pc.n_slots} state slots: fewer than the "
+                f"{max_rows} rows that run together")
+        return pc
+
+    @property
+    def n_slots(self) -> int:
+        return self.n_blocks * self.block_tokens // self.stride
+
+    @property
+    def pools(self):
+        """``serve`` bounds a request by the smallest pool's blocks."""
+        return ((tuple(range(self.n_layers)), self.n_blocks),)
+
+    @property
+    def page_shape(self) -> Tuple[int, ...]:
+        """One layer's state as it goes to the store: by head, ``S`` then
+        ``z``, ``state_dim x (head_dim + 1)`` values."""
+        return (self.n_kv_heads, self.state_dim * (self.head_dim + 1))
+
+    @property
+    def page_bytes(self) -> int:
+        return int(np.prod(self.page_shape)) * np.dtype(
+            jnp.dtype(self.dtype)).itemsize
+
+    @property
+    def slot_bytes(self) -> int:
+        """One slot: every layer's state."""
+        return self.n_layers * self.page_bytes
+
+    @property
+    def cache_bytes(self) -> int:
+        return self.n_slots * self.slot_bytes
+
+
+class StateSlots:
+    """Host-side bookkeeping of a ``StateCacheConfig``'s slots.  Slots
+    ``[0, max_rows)`` are the running rows': ``take_row`` / ``free_row``,
+    each slot out once.  The rest hold RESIDENT CHECKPOINTS by key, least
+    recently used first out: ``keep`` gives a key a slot (evicting the
+    oldest unpinned one) that ``register`` then names, ``match`` finds and
+    pins one while it is copied, ``unpin`` lets it go again."""
+
+    def __init__(self, n_slots: int, max_rows: int):
+        from collections import OrderedDict
+
+        self.n_slots, self.max_rows = n_slots, max_rows
+        self._rows = list(range(max_rows - 1, -1, -1))
+        self._free = list(range(n_slots - 1, max_rows - 1, -1))
+        self._by_key: "OrderedDict[str, int]" = OrderedDict()   # LRU order
+        self._pins: dict = {}
+        self.evicted = 0
+
+    @property
+    def rows_free(self) -> int:
+        return len(self._rows)
+
+    def take_row(self) -> int:
+        if not self._rows:
+            raise MemoryError("out of state slots: every running row's is taken")
+        return self._rows.pop()
+
+    def free_row(self, slot: int) -> None:
+        assert 0 <= slot < self.max_rows and slot not in self._rows, slot
+        self._rows.append(slot)
+
+    def __contains__(self, key: str) -> bool:
+        return key in self._by_key
+
+    def match(self, key: str) -> Optional[int]:
+        """The resident checkpoint of ``key``, pinned and marked used, or
+        None."""
+        slot = self._by_key.get(key)
+        if slot is not None:
+            self._by_key.move_to_end(key)
+            self._pins[slot] = self._pins.get(slot, 0) + 1
+        return slot
+
+    def unpin(self, slot: int) -> None:
+        n = self._pins[slot] - 1
+        if n:
+            self._pins[slot] = n
+        else:
+            del self._pins[slot]
+
+    def keep(self) -> Optional[int]:
+        """A resident slot to copy a checkpoint into, pinned: a free one,
+        else the least recently used unpinned one's (its key forgotten).
+        None where every one is pinned, or there is none."""
+        if self._free:
+            slot = self._free.pop()
+        else:
+            key = next((k for k, s in self._by_key.items()
+                        if s not in self._pins), None)
+            if key is None:
+                return None
+            slot = self._by_key.pop(key)
+            self.evicted += 1
+        self._pins[slot] = 1
+        return slot
+
+    def register(self, key: str, slot: int) -> None:
+        """Name the checkpoint ``keep``'s slot now holds; the slot stays
+        pinned until ``unpin``."""
+        assert key not in self._by_key and slot >= self.max_rows
+        self._by_key[key] = slot
+
+
+def init_cache(cfg, sharding=None):
     """Zeroed cache; with ``sharding`` it is created in its shards (a cache
     sized for a mesh need not fit one device first).  One array; for a
     stack with a pool per layer kind a tuple of one array a pool
-    (``cfg.pools``), each over its own layers and blocks."""
+    (``cfg.pools``), each over its own layers and blocks; for a
+    ``StateCacheConfig`` the slots ``(S, z)``."""
+    if isinstance(cfg, StateCacheConfig):
+        lead = (cfg.n_slots, cfg.n_layers, cfg.n_kv_heads, cfg.state_dim)
+        return (jnp.zeros(lead + (cfg.head_dim,), cfg.dtype, device=sharding),
+                jnp.zeros(lead, cfg.dtype, device=sharding))
     arrays = tuple(
         jnp.zeros((len(layers), cfg.planes, cfg.n_kv_heads, n_blocks,
                    cfg.block_tokens, cfg.head_dim),

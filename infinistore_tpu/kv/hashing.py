@@ -8,6 +8,15 @@ into fixed-size chunks and each chunk's key commits to the *entire prefix*
 up to and including that chunk, so a key match implies a full prefix match
 and ``get_match_last_index`` (reference: src/infinistore.cpp:786-802) finds
 the longest reusable prefix with one round-trip.
+
+What a match lets a cache REUSE depends on what it holds.  Pages of keys and
+values are reusable chunk by chunk: every matching key is 16 more tokens not
+recomputed.  A model that keeps a STATE and no key or value per token
+(models/retention.py) can start only from a position whose state was kept,
+so the same keys name checkpoints there: the key of the chunk that ends at a
+checkpoint's position names it, a match means "this exact prefix's state",
+and the keys in between match nothing that is held
+(engine/state_engine.py).
 """
 
 from __future__ import annotations

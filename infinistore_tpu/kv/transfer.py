@@ -170,6 +170,10 @@ class KVTransferEngine:
             ("loads", "tokens", "bytes"), 0) | dict.fromkeys(
             ("fetch_s", "scatter_s"), 0.0)
 
+    def _tokens_of(self, chunk_keys_: Sequence[str]) -> int:
+        """Tokens a push of these keys stands for in ``push_totals``."""
+        return len(chunk_keys_) * self.cfg.block_tokens
+
     def _add_totals(self, which: str, **add) -> None:
         with self._totals_lock:
             old = getattr(self, which)
@@ -350,7 +354,7 @@ class KVTransferEngine:
         self.last_push_stages = stages
         self._add_totals(
             "push_totals", pushes=1, bytes=total,
-            tokens=len(chunk_keys_) * self.cfg.block_tokens,
+            tokens=self._tokens_of(chunk_keys_),
             submit_to_commit_s=time.perf_counter() - t_begin,
             **{k: v for k, v in stages.items() if k.endswith("_s")})
         return total
@@ -763,3 +767,96 @@ class KVTransferEngine:
             return None
         self.breaker.record_success()
         return bytes(bytearray(arr))
+
+
+# -- a cache whose unit is a state (kv/cache.py StateCacheConfig) --
+
+
+@jax.jit
+def _state_to_wire(S: jax.Array, z: jax.Array, slot: jax.Array) -> jax.Array:
+    """Slot ``slot`` in store layout ``[L, 1, H, F * (D + 1)]``: one layer's
+    state a page, ``S`` then ``z`` by head."""
+    s = S[slot]
+    L, H, F, D = s.shape
+    return jnp.concatenate([s.reshape(L, H, F * D), z[slot]], axis=-1)[:, None]
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def _wire_to_state(S: jax.Array, z: jax.Array, slot: jax.Array,
+                   stacked: jax.Array):
+    """``_state_to_wire``'s inverse into slot ``slot`` of the donated slots."""
+    L, H, F, D = S.shape[1:]
+    w = stacked[:, 0]
+    return (S.at[slot].set(w[..., : F * D].reshape(L, H, F, D)),
+            z.at[slot].set(w[..., F * D:]))
+
+
+class StateTransferEngine(KVTransferEngine):
+    """``KVTransferEngine`` for a cache of state slots: what crosses is a
+    CHECKPOINT, every layer's state of one slot, under the chunk key of the
+    position it was taken at.  One layer's state stands where a page stood
+    (``StateCacheConfig.page_shape``: 34 MB at the published widths where a
+    page is 64 KB), so the banded push, the staging ring, the keys by layer
+    and the store itself are the parent's; bit for bit what was written is
+    what is read back.  What differs:
+
+    * ``gather_pages(cache, slot)`` snapshots one slot and ``load_pages(cache,
+      [slot], [key])`` fills one;
+    * ``lookup_prefix(keys)`` is handed the keys of the positions at which a
+      checkpoint MAY lie (most are absent by design: one checkpoint a
+      prompt), so it cannot bisect as a run of pages is bisected: it asks
+      for the last layer's state of each, deepest first, and the deepest
+      prompt that was asked before answers in one round trip;
+    * ``push_totals["tokens"]`` counts the tokens a checkpoint stands for
+      (``covers``), not a chunk's.
+    """
+
+    loads_by_layer = False
+
+    def __init__(self, conn, cfg, **kw):
+        super().__init__(conn, cfg, **kw)
+        self._covers: dict = {}
+
+    def covers(self, key: str, tokens: int) -> None:
+        """Say how many tokens the checkpoint about to be pushed under
+        ``key`` stands for."""
+        self._covers[key] = tokens
+
+    def _tokens_of(self, chunk_keys_: Sequence[str]) -> int:
+        return sum(self._covers.pop(k, 0) for k in chunk_keys_)
+
+    def gather_pages(self, cache, slot: int) -> jax.Array:
+        return _state_to_wire(*cache, jnp.asarray(slot, jnp.int32))
+
+    def load_pages(self, cache, block_ids: Sequence[int],
+                   chunk_keys_: Sequence[str], tokens: int = 0):
+        """The checkpoint ``chunk_keys_[0]`` into slot ``block_ids[0]`` of the
+        donated slots; every byte has landed when this returns."""
+        (slot,), (key,) = block_ids, chunk_keys_
+        nbytes = self.cfg.n_layers * self.wire_page_bytes
+        with tracing.span("kv.load_pages", pages=self.cfg.n_layers,
+                          bytes=nbytes):
+            t0 = time.perf_counter()
+            stacked = self.fetch_pages([key])
+            t1 = time.perf_counter()
+            out = _wire_to_state(*cache, jnp.asarray(slot, jnp.int32), stacked)
+            jax.block_until_ready(out)
+            t2 = time.perf_counter()
+        self.last_load_stages = {
+            "fetch_s": round(t1 - t0, 6), "scatter_s": round(t2 - t1, 6),
+            "pages": self.cfg.n_layers, "bytes": nbytes}
+        self._add_totals("load_totals", loads=1, tokens=tokens, bytes=nbytes,
+                         fetch_s=t1 - t0, scatter_s=t2 - t1)
+        return out
+
+    def lookup_prefix(self, chunk_keys_: Sequence[str]) -> int:
+        """``i + 1`` for the deepest ``chunk_keys_[i]`` whose checkpoint the
+        store holds whole (its last layer: layers are written in order), 0
+        for none."""
+        last = self.cfg.n_layers - 1
+        with tracing.span("kv.lookup_prefix", chunks=len(chunk_keys_)):
+            for i in range(len(chunk_keys_) - 1, -1, -1):
+                if self._call("check_exist",
+                              layer_key(chunk_keys_[i], last)) == 0:
+                    return i + 1
+        return 0

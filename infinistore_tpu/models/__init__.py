@@ -42,6 +42,12 @@ from .cohere2_moe import (
     cohere2_moe_prefill_forward,
     init_cohere2_moe_params,
 )
+from .retention import (
+    RetentionConfig,
+    init_retention_params,
+    retention_decode_forward,
+    retention_prefill_forward,
+)
 from .attention import (
     apply_rope,
     causal_attention,
@@ -68,6 +74,12 @@ def family_of(cfg) -> dict:
         return {"init": init_cohere2_moe_params,
                 "fns": {"prefill_fn": cohere2_moe_prefill_forward,
                         "decode_fn": cohere2_moe_decode_forward}}
+    if isinstance(cfg, RetentionConfig):
+        # a state a layer and no pages: ``serve`` gives it the engine over
+        # state slots (engine/state_engine.py) by ``cfg.state_shape``
+        return {"init": init_retention_params,
+                "fns": {"prefill_fn": retention_prefill_forward,
+                        "decode_fn": retention_decode_forward}}
     return {"init": init_params, "fns": {}}
 
 
@@ -77,6 +89,10 @@ __all__ = [
     "mla_moe_prefill_forward",
     "mla_moe_decode_forward",
     "family_of",
+    "RetentionConfig",
+    "init_retention_params",
+    "retention_prefill_forward",
+    "retention_decode_forward",
     "Cohere2MoeConfig",
     "init_cohere2_moe_params",
     "cohere2_moe_prefill_forward",

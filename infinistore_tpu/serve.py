@@ -2548,6 +2548,16 @@ def main(argv: Optional[List[str]] = None) -> None:
                     "chunk is pushed).  Default: as many as --n-blocks, with "
                     "which that pool never runs out first; any other model "
                     "refuses the option")
+    ap.add_argument("--state-stride", type=int, default=None,
+                    help="for a model whose layers keep a STATE and no key "
+                    "or value per token (power retention): a prompt's state "
+                    "is checkpointed, in a resident slot and in the store, "
+                    "at its deepest multiple of this many tokens, and a "
+                    "later prompt can start from there; a multiple of "
+                    "--prefill-chunk.  The device holds --n-blocks x "
+                    "--block-tokens / --state-stride slots, --max-batch of "
+                    "them the running rows'.  Any other model refuses the "
+                    "option")
     ap.add_argument("--block-tokens", type=int, default=16)
     ap.add_argument("--prefill-chunk", type=int, default=None)
     ap.add_argument("--decode-chunk", type=int, default=32,
@@ -2767,11 +2777,40 @@ def main(argv: Optional[List[str]] = None) -> None:
             Logger.warn(
                 f"no usable tokenizer in {tok_src!r}; serving token ids only"
             )
-    try:
-        pc = PagedCacheConfig.for_model(cfg, args.n_blocks, args.block_tokens,
-                                        window_blocks=args.window_blocks)
-    except ValueError as e:
-        raise SystemExit(f"--window-blocks: {e}")
+    engine_cls = InferenceEngine
+    if hasattr(cfg, "state_shape"):
+        # the cache's unit is a state, not a page (engine/state_engine.py)
+        from .engine.state_engine import StateEngine
+        from .kv.cache import StateCacheConfig
+
+        if args.state_stride is None or args.window_blocks is not None:
+            raise SystemExit(
+                f"{args.model}: this model keeps a state a layer and no "
+                f"pages: pass --state-stride (and --prefill-chunk, which it "
+                f"is a multiple of), and no --window-blocks")
+        if (args.prefill_chunk is None
+                or args.state_stride % args.prefill_chunk):
+            raise SystemExit(
+                f"--state-stride {args.state_stride} must be a multiple of "
+                f"--prefill-chunk ({args.prefill_chunk}): a checkpoint is "
+                f"taken at the end of a chunk")
+        try:
+            pc = StateCacheConfig.for_model(
+                cfg, args.n_blocks, args.block_tokens, args.state_stride,
+                max_rows=args.max_batch)
+        except ValueError as e:
+            raise SystemExit(f"--state-stride: {e}")
+        engine_cls = StateEngine
+    elif args.state_stride is not None:
+        raise SystemExit("--state-stride is the checkpoint stride of a model "
+                         "whose layers keep a state; this model keeps pages")
+    else:
+        try:
+            pc = PagedCacheConfig.for_model(
+                cfg, args.n_blocks, args.block_tokens,
+                window_blocks=args.window_blocks)
+        except ValueError as e:
+            raise SystemExit(f"--window-blocks: {e}")
     conn = None
     endpoints_spec = args.store_endpoints or os.environ.get(
         "ISTPU_STORE_ENDPOINTS"
@@ -2818,13 +2857,13 @@ def main(argv: Optional[List[str]] = None) -> None:
     # (reference parity, default WARNING): without this a store-attached
     # server never shows its own start-up line
     Logger.set_log_level(args.log_level)
-    engine = InferenceEngine(params, cfg, pc, prefill_chunk=args.prefill_chunk,
-                             decode_chunk=args.decode_chunk, conn=conn,
-                             model_id=model_id, mesh=mesh,
-                             kv_quant=(None if args.kv_quant == "none"
-                                       else args.kv_quant),
-                             store_durability=args.store_durability,
-                             **engine_fns)
+    engine = engine_cls(params, cfg, pc, prefill_chunk=args.prefill_chunk,
+                        decode_chunk=args.decode_chunk, conn=conn,
+                        model_id=model_id, mesh=mesh,
+                        kv_quant=(None if args.kv_quant == "none"
+                                  else args.kv_quant),
+                        store_durability=args.store_durability,
+                        **engine_fns)
     draft_engine = None
     if args.draft_model is not None:
         # the draft proposes tokens the target verifies, so the vocabs must

@@ -408,3 +408,43 @@ def test_full_layer_on_tpu_reads_live_pages_through_the_kernel(v5e):
         head_dim=pc.head_dim, n_blocks=pc.window_blocks, block_tokens=T)
     assert not _slab_copies(text, pc.n_kv_heads, window_pool)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_retention_decode_scan_on_tpu_moves_each_rows_state_in_place(v5e):
+    """The power-retention family at its published widths (two layers, the
+    benchmark's 16 state slots, 8 rows): the decode scan's slots that come out
+    are the donated ones, and what is live beside them is a row's state of a
+    layer and the vocabulary's logits, not every row's state gathered at once
+    (270 MB a layer at 8 rows; the compiler kept several layers' in flight,
+    4 GB) and not a second copy of the slots."""
+    from infinistore_tpu.kv.cache import StateCacheConfig
+
+    cfg = models.RetentionConfig(n_layers=2)
+    pc = StateCacheConfig.for_model(cfg, 4096, T, 4096, max_rows=8)
+    assert (pc.n_slots, pc.page_bytes) == (16, 34080768)
+    chip = SingleDeviceSharding(v5e[0])
+    params = _shaped(jax.eval_shape(
+        lambda: models.init_retention_params(cfg, jax.random.PRNGKey(0))), chip)
+    cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
+    batch = 8
+
+    def decode_scan(params, logits, pos, cache, table):
+        def step(carry, i):
+            logits, cache = carry
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            logits, cache = models.retention_decode_forward(
+                params, cfg, tok, pos + i, cache, table, None, None, None)
+            return (logits, cache), tok
+
+        (logits, cache), toks = jax.lax.scan(
+            step, (logits, cache), jnp.arange(3))
+        return toks, logits, cache
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    compiled = jax.jit(decode_scan, donate_argnums=(3,)).lower(
+        params, sds((batch, cfg.vocab_size), cfg.dtype),
+        sds((batch,), jnp.int32), cache, sds((batch, 1), jnp.int32),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pc.cache_bytes, mem
+    assert mem.temp_size_in_bytes < 5 * pc.page_bytes * 2, mem

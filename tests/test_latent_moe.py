@@ -405,8 +405,13 @@ def test_benchmark_configuration_resolves(entry, tmp_path):
     held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
     assert counts.weight_bytes(spec) == held
     # the fill check's product is the cache as the server allocates it: one
-    # array, or one pool a layer kind (--window-blocks in serve.args)
+    # array, or one pool a layer kind (--window-blocks in serve.args); a
+    # family whose cache is state slots is held to its own shapes in
+    # tests/test_retention.py::test_allocated_bytes_equal_the_counts
     sv = spec["serve"]
+    if hasattr(cfg, "state_shape"):
+        assert "--state-stride" in sv["args"]
+        return
     window_blocks = (int(sv["args"][sv["args"].index("--window-blocks") + 1])
                      if "--window-blocks" in sv["args"] else None)
     pc = PagedCacheConfig.for_model(cfg, sv["n_blocks"], sv["block_tokens"],
