@@ -20,6 +20,7 @@ stays in Python.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import queue
 import time
 from collections import OrderedDict
@@ -368,12 +369,15 @@ class _StoreStreamer:
     pages with a device-side fused gather (dispatch-only, and jax arrays
     are immutable so later cache writes can't corrupt the snapshot) and
     hands them here; this thread does the D2H + pool writes.  A single
-    worker serializes store ops (one connection, no interleaving), and
-    ``flush()`` joins the queue so prefill still returns with every page
-    durably in the store.  The first push error parks, skips the rest
-    (fail-fast on a dead store), and re-raises at the next flush — which
-    also CLEARS it, so pushes resume afterwards (the serving layer
-    flushes whenever the batch drains).
+    worker serializes store ops (one connection, no interleaving).  Strict
+    durability awaits ONE prefill's pushes (``await_prefill``, by the
+    prefill's own marker) before that prefill's state becomes visible, so a
+    prefill still returns with every page durably in the store;
+    ``flush()`` joins the whole queue.  The first push error parks, skips
+    the rest (fail-fast on a dead store), and re-raises at the next flush
+    (or at the wait of the prefill it belongs to) — which also CLEARS it,
+    so pushes resume afterwards (the serving layer flushes whenever the
+    batch drains).
 
     Failure semantics (docs/robustness.md): every skipped or failed push
     is COUNTED (``istpu_store_push_dropped_total{reason=}``) and the
@@ -402,16 +406,19 @@ class _StoreStreamer:
         # submitting request's trace id, and ``flush(marker=...)`` waits
         # ONLY on that request's pushes — without this, concurrent
         # PD-handoff flush barriers join the WHOLE queue and serialize
-        # on each other's pushes.  Counts are guarded by the condition;
-        # per-marker errors are bounded (a marker's error is consumed by
-        # its own flush or aged out by the cap).
+        # on each other's pushes.  A prefill's submits carry its OWN marker
+        # besides (``PartialPrefill.marker``; untraced requests share no
+        # trace id to tell them apart): ``await_prefill`` waits on that
+        # alone.  Counts are guarded by the condition; per-marker errors
+        # are bounded (a marker's error is consumed by its own wait or
+        # aged out by the cap).
         self._cond = threading.Condition()
         self._pending: Dict[object, int] = {}
         self._marker_errs: "OrderedDict[object, BaseException]" = (
             OrderedDict()
         )
 
-    def submit(self, pages, chunk_keys_) -> None:
+    def submit(self, pages, chunk_keys_, marker=None) -> None:
         if not self._started:
             import threading
 
@@ -429,44 +436,52 @@ class _StoreStreamer:
         # the request trace around prefill work, so the worker thread can
         # attribute the push to the REQUEST that paid for it (the PD
         # handoff chain needs store pushes under one trace id end to end)
-        # — and the same id is the per-request flush marker.
+        # — and the same id is the per-request flush marker, beside the
+        # submitting prefill's own ``marker``.
         tid = tracing.current_trace_id()
+        marks = (tid,) if marker is None else (tid, marker)
         # the submitting request's ACCOUNT rides along the same way: the
         # worker re-binds it around push_commit, so the store's ALLOC_PUT
         # frames bill the tenant whose prefill produced the pages
         acct = _usage.current_account()
         with self._cond:
-            self._pending[tid] = self._pending.get(tid, 0) + 1
+            for m in marks:
+                self._pending[m] = self._pending.get(m, 0) + 1
         item = (self._transfer.push_begin(pages, chunk_keys_),
-                chunk_keys_, tid, acct)
+                chunk_keys_, marks, acct)
         try:
             self._q.put_nowait(item)
-        except queue.Full:     # two chunks already wait: this is a wait too
-            with _stepprof.phase("kv.push_wait"):
+        except queue.Full:
+            # two chunks already wait: this is a wait too, of the HOST (it
+            # ran ahead of the pusher; the device still has chunks to run)
+            with _stepprof.phase("kv.push_wait") as ph:
                 self._q.put(item)
+            _stepprof.note_push_wait(push_queue_full_waits=1,
+                                     push_queue_full_s=ph.s)
 
-    def _record_marker_err(self, tid, err: BaseException) -> None:
-        if tid is None or err is None:
-            return
+    def _record_marker_err(self, marks, err: BaseException) -> None:
         with self._cond:
-            self._marker_errs[tid] = err
+            for m in marks:
+                if m is not None:
+                    self._marker_errs[m] = err
             while len(self._marker_errs) > 256:
                 self._marker_errs.popitem(last=False)
 
-    def _settle(self, tid) -> None:
+    def _pushed(self, marks) -> None:
         with self._cond:
-            n = self._pending.get(tid, 1) - 1
-            if n > 0:
-                self._pending[tid] = n
-            else:
-                self._pending.pop(tid, None)
+            for m in marks:
+                n = self._pending.get(m, 1) - 1
+                if n > 0:
+                    self._pending[m] = n
+                else:
+                    self._pending.pop(m, None)
             self._cond.notify_all()
 
     def _run(self) -> None:
         from ..utils import resilience as _res
 
         while True:
-            token, keys, tid, acct = self._q.get()
+            token, keys, marks, acct = self._q.get()
             try:
                 if self._err is not None:
                     # parked error: skip queued items until the next
@@ -479,7 +494,7 @@ class _StoreStreamer:
                     # barrier must see the failure too (its handoff
                     # contract says "flushed" means durable).
                     self._dropped += 1
-                    self._record_marker_err(tid, self._err)
+                    self._record_marker_err(marks, self._err)
                     _res.count_push_dropped("parked_error")
                 elif not self._transfer.breaker.allow():
                     # open circuit: don't even touch the wire
@@ -487,13 +502,14 @@ class _StoreStreamer:
                     _res.count_push_dropped("circuit_open")
                 else:
                     with _usage.bind_account(acct):
-                        self._push_one(token, keys, tid, _res)
+                        self._push_one(token, keys, marks, _res)
             finally:
-                self._settle(tid)
+                self._pushed(marks)
                 self._q.task_done()
 
-    def _push_one(self, token, keys, tid, _res) -> None:
+    def _push_one(self, token, keys, marks, _res) -> None:
         breaker = self._transfer.breaker
+        tid = marks[0]
         attempts = 2 if self._durability == "strict" else 1
         for attempt in range(attempts):
             try:
@@ -531,7 +547,7 @@ class _StoreStreamer:
                     continue
                 self._err = e
                 self._dropped += 1
-                self._record_marker_err(tid, e)
+                self._record_marker_err(marks, e)
                 _res.count_push_dropped("push_error")
                 import logging
 
@@ -568,17 +584,33 @@ class _StoreStreamer:
                     )
                 raise err
             return
-        with self._cond:
-            # None-marked pushes come from multi-request prefill waves
-            # (genuinely shared work bound to no single trace) — a
-            # request's barrier must cover those too, conservatively;
-            # what it skips is only OTHER requests' tagged pushes
-            while (self._pending.get(marker, 0) > 0
-                   or self._pending.get(None, 0) > 0):
-                self._cond.wait()
-            err = self._marker_errs.pop(marker, None)
+        # None-marked pushes come from multi-request prefill waves
+        # (genuinely shared work bound to no single trace) — a
+        # request's barrier must cover those too, conservatively;
+        # what it skips is only OTHER requests' tagged pushes
+        err = self._wait(marker, None)
         if err is not None:
             raise err
+
+    def await_prefill(self, marker) -> None:
+        """Strict durability's barrier of ONE prefill: wait for the pushes
+        submitted under its own ``marker`` (every push names its prefill,
+        so no other push is waited for) and raise its error.  An error
+        that is the parked one is consumed, as the whole-queue flush this
+        wait replaced consumed it: pushes resume afterwards."""
+        err = self._wait(marker)
+        if err is not None:
+            if err is self._err:
+                self._err, self._dropped = None, 0
+            raise err
+
+    def _wait(self, *markers) -> Optional[BaseException]:
+        """Block until no push tagged with any of ``markers`` is
+        outstanding; the error recorded for the first of them, taken."""
+        with self._cond:
+            while any(self._pending.get(m, 0) > 0 for m in markers):
+                self._cond.wait()
+            return self._marker_errs.pop(markers[0], None)
 
 
 @dataclass
@@ -614,6 +646,9 @@ class SequenceState:
     lookup_s: float = 0.0
     launch_s: float = 0.0
     chunks: int = 0
+
+
+_MARKERS = itertools.count()
 
 
 @dataclass
@@ -653,12 +688,24 @@ class PartialPrefill:
     lookup_s: float = 0.0
     launch_s: float = 0.0
     chunks: int = 0
+    # the push marker of this prefill alone: every chunk it hands the
+    # streamer is tagged with it, and strict durability awaits it
+    # (``prefill_settle``); a push error found there stays with the prefill
+    marker: str = field(default_factory=lambda: f"prefill-{next(_MARKERS)}")
+    push_error: Optional[BaseException] = None
 
     @property
     def chunks_left(self) -> int:
         """Chunk forwards this prefill still needs (what the scheduler
         orders newcomers by)."""
         return -(-(len(self.padded) - self.off) // self.C)
+
+    @property
+    def finished(self) -> bool:
+        """Every chunk has run.  The prefill may still be UNSETTLED: under
+        strict durability its state is not visible before
+        ``prefill_settle``."""
+        return self.off >= len(self.padded)
 
 
 class InferenceEngine:
@@ -862,7 +909,8 @@ class InferenceEngine:
         self.breaker = self.transfer.breaker if self.transfer else None
         # relaxed mode must not backpressure prefill on a slow store, so
         # its queue is deep enough to hold a long prompt's chunks; strict
-        # keeps the 2-chunk HBM-footprint bound (flush joins anyway)
+        # keeps the 2-chunk HBM-footprint bound (every prefill's pushes are
+        # awaited before its state is visible anyway)
         self._streamer = (
             _StoreStreamer(
                 self.transfer,
@@ -1011,10 +1059,12 @@ class InferenceEngine:
         crosses adapters."""
         with tracing.span("engine.prefill", tokens=len(tokens)):
             pp = self.prefill_start(tokens, adapter_id=adapter_id)
-            while True:
+            while not pp.finished:
                 st = self.prefill_step(pp)
-                if st is not None:
-                    return st
+            if st is None:      # strict durability: a blocking prefill's one wait
+                _stepprof.note_push_wait(settle_waits=1)
+                st = self.prefill_settle(pp)
+            return st
 
     def prefill_start(
         self, tokens: Sequence[int], adapter_id: int = 0
@@ -1293,21 +1343,52 @@ class InferenceEngine:
             _stepprof.note_kv_pages(**{f"window_pages_{event}": n})
             _WINDOW_PAGES.labels(event).inc(n)
 
+    def _awaits_push(self) -> bool:
+        """Strict durability with a store attached: a prefill's state is
+        visible only once the store has acknowledged every page of it."""
+        return self.transfer is not None and self.store_durability == "strict"
+
     def prefill_step(self, pp: "PartialPrefill") -> Optional[SequenceState]:
         """One prefill chunk forward (+ cache scatter + store streaming).
         Returns the finished SequenceState on the last chunk, else None.
         The phase times a LAUNCH: the forward and the scatter are enqueued,
-        not finished, when it ends (strict durability's last-chunk flush,
-        its own phase inside, is the one wait)."""
+        not finished, when it ends, and nothing here waits for the store.
+        Under strict durability with a store the LAST chunk returns None
+        too and leaves ``pp.finished`` set: the prefill is handed back
+        UNSETTLED (no page named, no state, nothing in ``seqs``), and
+        ``prefill_settle`` makes it visible once its pushes are
+        acknowledged; the caller chooses where that wait stands (the
+        scheduler: once a step, before the decode dispatch)."""
         with _stepprof.phase("prefill.launch") as ph:
-            state = self._prefill_chunk(pp)
+            self._prefill_chunk(pp)
         pp.launch_s += ph.s
         pp.chunks += 1
-        if state is not None:
-            state.launch_s, state.chunks = pp.launch_s, pp.chunks
+        if pp.finished and not self._awaits_push():
+            return self.prefill_settle(pp)
+        return None
+
+    def prefill_settle(self, pp: "PartialPrefill") -> SequenceState:
+        """The point of visibility of a finished prefill.  Under strict
+        durability with a store: wait (phase ``kv.push_wait``) until the
+        store has acknowledged every push of THIS prefill, no other's, and
+        raise its push error (then, and whenever asked again: a prefill
+        whose push failed never becomes visible).  Then, and at once where
+        nothing is awaited, its pages are named for sharing and its
+        decode-ready state is made."""
+        if pp.push_error is None and self._awaits_push():
+            with _stepprof.phase("kv.push_wait") as ph:
+                try:
+                    self._streamer.await_prefill(pp.marker)
+                except Exception as e:  # noqa: BLE001 — raised below
+                    pp.push_error = e
+            _stepprof.note_push_wait(settled_prompts=1, settle_wait_s=ph.s)
+        if pp.push_error is not None:
+            raise pp.push_error
+        state = self._make_visible(pp)
+        state.launch_s, state.chunks = pp.launch_s, pp.chunks
         return state
 
-    def _prefill_chunk(self, pp: "PartialPrefill") -> Optional[SequenceState]:
+    def _prefill_chunk(self, pp: "PartialPrefill") -> None:
         T = self.pc.block_tokens
         off, C = pp.off, pp.C
         chunk = pp.padded[off : off + C]
@@ -1359,7 +1440,7 @@ class InferenceEngine:
                             self.cache,
                             pp.block_ids[lo:hi] if self.wpages is None
                             else (pp.block_ids[lo:hi], pp.window_ids[lo:hi])),
-                        pp.keys[lo:hi],
+                        pp.keys[lo:hi], marker=pp.marker,
                     )
         if self.wpages is not None:
             # the push holds a snapshot: window pages that lie below the
@@ -1381,16 +1462,19 @@ class InferenceEngine:
                     pp.buf, kv, jnp.asarray(pp.plen, dtype=jnp.int32)
                 )
             pp.plen = need
-            return None
+        else:
+            # finished: a prefill that waits to be settled holds neither
+            # its prefix buffer nor a chunk's logits, only the row the
+            # decode starts from
+            pp.buf = None
+            pp.logits = _LAST_ROW(pp.logits, (pp.S - 1) - pp.off_last)
 
-        # finished.  Strict durability joins the pusher so the pages are
-        # durably in the store before the state is visible (the
-        # reference's prefill-node contract, design.rst); relaxed returns
-        # now — pushes drain behind decode, store_flush() is the barrier
-        if self.transfer is not None and self.store_durability == "strict":
-            with _stepprof.phase("kv.push_wait"):
-                self._streamer.flush()
-
+    def _make_visible(self, pp: "PartialPrefill") -> SequenceState:
+        """A finished prefill's decode-ready state.  Under strict durability
+        ``prefill_settle`` has awaited the pusher first, so the pages are
+        durably in the store before the state is visible (the reference's
+        prefill-node contract, design.rst); relaxed comes here at once —
+        pushes drain behind decode, store_flush() is the barrier."""
         # name this sequence's complete-chunk pages so later prefills can
         # share them in place (no-op for keys already resident)
         self.pages.register(
@@ -1406,7 +1490,7 @@ class InferenceEngine:
             block_ids=pp.block_ids,
             chunk_keys=pp.keys,
             reused_chunks=pp.reused,
-            last_logits=_LAST_ROW(pp.logits, (pp.S - 1) - pp.off_last),
+            last_logits=pp.logits,
             adapter_id=pp.adapter_id,
             window_ids=pp.window_ids, window_reclaimed=pp.window_reclaimed,
             local_chunks=pp.local_chunks, store_chunks=pp.store_chunks,

@@ -267,7 +267,7 @@ class Scheduler:
             "istpu_serve_inflight",
             "Requests holding engine resources (active batch + chunked "
             "prefills)",
-            fn=lambda: len(self.active) + len(self._prefilling),
+            fn=lambda: self._in_flight,
         )
         self.metrics.gauge(
             "istpu_serve_queue_depth",
@@ -282,6 +282,12 @@ class Scheduler:
         # slots whose prompts are not ingested yet, in the order they were
         # started (see ``_prefill_burst``)
         self._prefilling: List[Tuple[Request, PartialPrefill]] = []
+        # finished prefills the engine handed back UNSETTLED (strict
+        # durability with a store: ``engine.prefill_step``), in the order
+        # they finished: each holds its decode slot and its pages until the
+        # step settles it (``_settle_parked``); empty between steps unless a
+        # push error left the step
+        self._parked: List[Tuple[Request, PartialPrefill]] = []
         self._next_id = 0
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
         # set when decode sheds a request for lack of KV pages: admission
@@ -456,7 +462,7 @@ class Scheduler:
                 self._stream(req, done=True)
                 self._finish(req, "cancelled")
                 return True
-        for req, _pp in self._prefilling:
+        for req, _pp in self._prefilling + self._parked:
             if req.req_id == req_id and not req.cancelled:
                 req.cancelled = True
                 return True
@@ -524,8 +530,15 @@ class Scheduler:
             req.t_stream_s += time.perf_counter() - t0
 
     @property
+    def _in_flight(self) -> int:
+        """Requests that hold a decode slot: decoding, prefilling, or
+        finished and parked until the store has acknowledged them."""
+        return len(self.active) + len(self._prefilling) + len(self._parked)
+
+    @property
     def has_work(self) -> bool:
-        return bool(self.pending or self.active or self._prefilling)
+        return bool(self.pending or self.active or self._prefilling
+                    or self._parked)
 
     def _prefill_budget(self) -> int:
         """The most prefill tokens this step may spend before its decode
@@ -562,7 +575,7 @@ class Scheduler:
         False = nobody was started."""
         if (not self.pending
                 or (self._admission_hold and self.active)
-                or len(self.active) + len(self._prefilling) >= self.max_batch
+                or self._in_flight >= self.max_batch
                 or any(pp.buf is not None and not pp.chunks
                        for _req, pp in self._prefilling)):
             return False
@@ -614,8 +627,12 @@ class Scheduler:
         from the store passes a long new prompt instead of queueing behind
         its chunks.  A prefill that has run is passed only by a shorter or
         a more urgent one, so few unfinished ones hold a buffer of computed
-        prefix.  Returns the requests cancelled mid-prefill (always
-        processed: they FREE resources).
+        prefix.  A prompt the engine hands back finished but UNSETTLED
+        (strict durability with a store) is parked, slot and pages held,
+        and the burst goes on; the parked ones are settled when the budget
+        loop ends, before the caller builds the decode dispatch
+        (``_settle_parked``).  Returns the requests cancelled mid-prefill
+        (always processed: they FREE resources).
 
         NOTE on degraded mode: work already in ``pending`` is never held
         back by lane here — freezing shed-lane backlog would only let it
@@ -628,18 +645,9 @@ class Scheduler:
         submit boundary (shed new work) and through the budget's cap;
         queued work always drains."""
         cancelled: List[Request] = []
-
-        def drop(i: int) -> None:
-            req, pp = self._prefilling.pop(i)
-            self.engine.abandon_prefill(pp)
-            req.done = True
-            self._stream(req, done=True)
-            self._finish(req, "cancelled")
-            cancelled.append(req)
-
         for i in reversed(range(len(self._prefilling))):
             if self._prefilling[i][0].cancelled:
-                drop(i)
+                cancelled.append(self._drop_cancelled(self._prefilling, i))
         granted = self._prefill_budget()
         cost = self.engine.prefill_chunk or 1
         spent = 0
@@ -654,7 +662,7 @@ class Scheduler:
                                self._prefilling[j][1].chunks_left))
             req, pp = self._prefilling[i]
             if req.cancelled:      # another thread's cancel, mid-burst
-                drop(i)
+                cancelled.append(self._drop_cancelled(self._prefilling, i))
                 continue
             with tracing.bind(req.trace_id), \
                     _usage.bind_account(self._lane_label(req)):
@@ -663,7 +671,43 @@ class Scheduler:
             if st is not None:
                 self._prefilling.pop(i)
                 self._prefilled(req, st)
+            elif pp.finished:
+                self._parked.append(self._prefilling.pop(i))
         _stepprof.note_prefill_budget(granted, spent)
+        return cancelled + self._settle_parked()
+
+    def _drop_cancelled(self, held: list, i: int) -> Request:
+        """``held[i]`` (a prefill in progress, or parked) was cancelled: its
+        pages and slot go back and the request leaves the scheduler."""
+        req, pp = held.pop(i)
+        self.engine.abandon_prefill(pp)
+        req.done = True
+        self._stream(req, done=True)
+        self._finish(req, "cancelled")
+        return req
+
+    def _settle_parked(self) -> List[Request]:
+        """The step's ONE wait for the store: each parked prefill's own
+        acknowledgements, in the order the prefills finished
+        (``engine.prefill_settle``), and each joins the batch as it is
+        settled; one that was cancelled meanwhile gives its slot and pages
+        back unawaited (those are returned).  A push error leaves ``step()``
+        from here: the prompts settled before it have joined, the failed
+        one and those behind it stay parked for ``fault_reset`` (asked
+        again, the failed one raises again: it never joins)."""
+        cancelled: List[Request] = []
+        if self._parked:
+            _stepprof.note_push_wait(settle_waits=1)
+        while self._parked:
+            req, pp = self._parked[0]
+            if req.cancelled:
+                cancelled.append(self._drop_cancelled(self._parked, 0))
+                continue
+            with tracing.bind(req.trace_id), \
+                    _usage.bind_account(self._lane_label(req)):
+                st = self.engine.prefill_settle(pp)
+            self._parked.pop(0)
+            self._prefilled(req, st)
         return cancelled
 
     def _admit(self) -> List[Request]:
@@ -673,7 +717,7 @@ class Scheduler:
         # sampling params are per-row traced vectors in the compiled decode
         # (engine._decode_many), so admission never sorts by them — a greedy
         # request and a top-p request share one lockstep batch
-        if self.active or self._prefilling:
+        if self.active or self._prefilling or self._parked:
             return self._prefill_burst()
         if self.pending:
             self._admit_wave()
@@ -1013,7 +1057,7 @@ class Scheduler:
         t0, t1 = rec.get("t0"), rec.get("t1")
         participants = (
             list(self.active)
-            + [r for r, _pp in self._prefilling]
+            + [r for r, _pp in self._prefilling + self._parked]
             + retired
         )
         for req in participants:
@@ -1148,13 +1192,14 @@ class Scheduler:
         Returns the dropped requests — the serving layer tells their
         clients the truth (an error, not a completion)."""
         dropped: List[Request] = []
-        for req, pp in self._prefilling:
+        for req, pp in self._prefilling + self._parked:
             try:
                 self.engine.abandon_prefill(pp)
             except Exception:  # noqa: BLE001 — already faulting
                 pass
             dropped.append(req)
         self._prefilling = []
+        self._parked = []
         dropped.extend(self.active)
         dropped.extend(self.pending)
         self.active = []

@@ -16,8 +16,8 @@ every step WRITES it.  What that forces on the cache tier, and what of
   prompt the deepest multiple of ``pc.stride`` that at least one token
   follows.  The state there is copied into a resident slot under that
   position's chunk key (kv/hashing.chunk_keys, unchanged) and pushed to the
-  store, every layer, flushed before the prefill returns under strict
-  durability.  Decode takes none.
+  store, every layer, acknowledged before the prompt's state is visible
+  under strict durability (``prefill_settle``).  Decode takes none.
 * **A hit COPIES.**  ``prefill_start`` finds the deepest stride-aligned
   position whose key is resident in HBM, else in the store, copies or loads
   that checkpoint into the row's own slot (a loaded one is kept resident
@@ -250,7 +250,7 @@ class StateEngine(InferenceEngine):
             self._seen.popitem(last=False)
         return shared
 
-    def _prefill_chunk(self, pp: PartialPrefill) -> Optional[SequenceState]:
+    def _prefill_chunk(self, pp: PartialPrefill) -> None:
         off, C = pp.off, pp.C
         chunk = pp.padded[off: off + C]
         start = pp.plen + off
@@ -264,17 +264,16 @@ class StateEngine(InferenceEngine):
         pp.done = (start + len(chunk)) // self.pc.block_tokens
         if pp.ckpt_at and start + len(chunk) == pp.ckpt_at:
             self._checkpoint(pp)
-        if pp.off < len(pp.padded):
-            return None
+        if pp.finished:     # kept until it is settled: one row, not a chunk's
+            pp.logits = _LAST_ROW(pp.logits, (pp.S - 1) - pp.off_last)
 
-        if self.transfer is not None and self.store_durability == "strict":
-            with _stepprof.phase("kv.push_wait"):
-                self._streamer.flush()
+    def _make_visible(self, pp: PartialPrefill) -> SequenceState:
+        """The row's slot handed over to a decode-ready state (under strict
+        durability ``prefill_settle`` has awaited the checkpoint's push)."""
         state = SequenceState(
             seq_id=self._next_id, tokens=pp.tokens, block_ids=[],
             chunk_keys=pp.keys, reused_chunks=pp.reused,
-            last_logits=_LAST_ROW(pp.logits, (pp.S - 1) - pp.off_last),
-            slot=pp.slot, local_chunks=pp.local_chunks,
+            last_logits=pp.logits, slot=pp.slot, local_chunks=pp.local_chunks,
             store_chunks=pp.store_chunks, store_load_s=pp.store_load_s,
             lookup_s=pp.lookup_s,
         )
@@ -302,7 +301,8 @@ class StateEngine(InferenceEngine):
         with _stepprof.phase("kv.push_submit"):
             self.transfer.covers(key, pp.ckpt_at)
             self._streamer.submit(
-                self.transfer.gather_pages(self.cache, pp.slot), [key])
+                self.transfer.gather_pages(self.cache, pp.slot), [key],
+                marker=pp.marker)
         self._count(checkpoints_pushed=1, bytes_pushed=self.pc.slot_bytes)
 
     def _keep_resident(self, key: str, row: int) -> bool:

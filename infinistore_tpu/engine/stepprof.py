@@ -53,7 +53,9 @@ structured record per scheduler step:
   knows when it launches the decode scan: steps, live rows, the context
   tokens they hold and the table tokens the attention reads;
   ``note_prefill_budget`` records, at the scheduler's call, the prefill
-  chunk tokens a step was granted and the ones it spent.
+  chunk tokens a step was granted and the ones it spent; ``note_push_wait``
+  tells the two causes of the ``kv.push_wait`` phase apart (the streamer's
+  full queue, and strict durability's wait for acknowledgements).
 
 Records live in a bounded ring (``ISTPU_STEPPROF_RING``, default 256),
 exported at the serving front-end's ``GET /debug/engine`` (``?limit=``),
@@ -98,8 +100,11 @@ DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
                  "table_token_steps", "attn_kernel_steps", "expert_pairs",
                  "experts_expected", "expert_pairs_local")
 
-# what ``note_prefill_budget`` sums, per step record and over the lifetime
-PREFILL_COUNTS = ("granted_tokens", "spent_tokens")
+# what ``note_prefill_budget`` and ``note_push_wait`` sum, per step record
+# and over the lifetime
+PREFILL_COUNTS = ("granted_tokens", "spent_tokens",
+                  "settle_waits", "settled_prompts", "settle_wait_s",
+                  "push_queue_full_waits", "push_queue_full_s")
 
 # what ``note_kv_pages`` sums, per step record and over the lifetime
 KV_COUNTS = ("store_pages_full", "store_pages_window",
@@ -301,6 +306,17 @@ def note_expert_pairs_local(n: int) -> None:
         b["expert_pairs_local"] += n
 
 
+def _sum_into(block: str, names: tuple, counts: Dict[str, float]) -> None:
+    """Add ``counts`` to the active step record's ``block`` (made with every
+    one of ``names`` at 0 when first touched); nothing without a record."""
+    rec = _ACTIVE.get()
+    if rec is None:
+        return
+    b = rec.setdefault(block, dict.fromkeys(names, 0))
+    for k, n in counts.items():
+        b[k] += n
+
+
 def note_kv_pages(**counts: int) -> None:
     """Count pages by layer kind (``KV_COUNTS``).  Of ONE adopted store
     prefix under a stack of mixed attention kinds, the (layer, chunk) pages
@@ -310,12 +326,7 @@ def note_kv_pages(**counts: int) -> None:
     pages a sequence took into its table and those it returned BEFORE its
     release, their last token having left every window to come
     (engine._reclaim_window_pages).  Summed under ``rec["kv"]``."""
-    rec = _ACTIVE.get()
-    if rec is None:
-        return
-    b = rec.setdefault("kv", dict.fromkeys(KV_COUNTS, 0))
-    for k, n in counts.items():
-        b[k] += n
+    _sum_into("kv", KV_COUNTS, counts)
 
 
 def note_state(**counts: int) -> None:
@@ -327,12 +338,7 @@ def note_state(**counts: int) -> None:
     adopt (shared, yet recomputed: a state is reusable only where one was
     kept); resident checkpoints evicted for a newer one.  Summed under
     ``rec["state"]``."""
-    rec = _ACTIVE.get()
-    if rec is None:
-        return
-    b = rec.setdefault("state", dict.fromkeys(STATE_COUNTS, 0))
-    for k, n in counts.items():
-        b[k] += n
+    _sum_into("state", STATE_COUNTS, counts)
 
 
 def note_prefill_budget(granted_tokens: int, spent_tokens: int) -> None:
@@ -344,12 +350,22 @@ def note_prefill_budget(granted_tokens: int, spent_tokens: int) -> None:
     whole wave and counts nothing here.  ``spent / granted`` near 1 says
     admission is held by the budget; far below it, by arrivals, slots or
     pages.  Summed under ``rec["prefill"]``."""
-    rec = _ACTIVE.get()
-    if rec is None:
-        return
-    b = rec.setdefault("prefill", dict.fromkeys(PREFILL_COUNTS, 0))
-    b["granted_tokens"] += granted_tokens
-    b["spent_tokens"] += spent_tokens
+    _sum_into("prefill", PREFILL_COUNTS, {"granted_tokens": granted_tokens,
+                                          "spent_tokens": spent_tokens})
+
+
+def note_push_wait(**counts: float) -> None:
+    """Count why the engine thread stood in ``kv.push_wait``
+    (``PREFILL_COUNTS``), beside the step's budget under ``rec["prefill"]``.
+    Strict durability's barrier: ``settle_waits`` times the thread awaited
+    acknowledgements (once per blocking ``prefill``, once per scheduler step
+    that finished prompts), ``settled_prompts`` the prefills those waits made
+    visible and ``settle_wait_s`` their seconds; ``settled_prompts /
+    settle_waits`` is how many prompts share one drain of the device.  The
+    streamer's bound: ``push_queue_full_waits`` / ``push_queue_full_s``, a
+    ``submit`` whose ``put`` found two chunks queued: the HOST runs ahead of
+    the pusher there, the device still has chunks to run."""
+    _sum_into("prefill", PREFILL_COUNTS, counts)
 
 
 def enter(name: Optional[str]) -> float:
@@ -493,7 +509,8 @@ class StepProfiler:
         self.tokens = 0
         # lifetime sums of the decode dispatches' counts (note_decode)
         self._decode_totals = dict.fromkeys(DECODE_COUNTS, 0)
-        # lifetime sums of the steps' prefill budgets (note_prefill_budget)
+        # lifetime sums of the steps' prefill budgets and push waits
+        # (note_prefill_budget, note_push_wait)
         self._prefill_totals = dict.fromkeys(PREFILL_COUNTS, 0)
         self._kv_totals = dict.fromkeys(KV_COUNTS, 0)
         self._state_totals = dict.fromkeys(STATE_COUNTS, 0)
@@ -858,7 +875,8 @@ class StepProfiler:
             spec_tot = dict(self._spec_totals)
             tokens = self.tokens
             decode = dict(self._decode_totals)
-            prefill = dict(self._prefill_totals)
+            prefill = {k: round(v, 6) if isinstance(v, float) else v
+                       for k, v in self._prefill_totals.items()}
             kv = dict(self._kv_totals)
             state = dict(self._state_totals)
             # the open phase counts up to this moment: a scrape in the
@@ -911,7 +929,8 @@ class StepProfiler:
             "phase_wall_s": round(phase_wall, 6),
             # the decode dispatches' counts, summed (note_decode)
             "decode": decode,
-            # the steps' prefill token budgets, summed (note_prefill_budget)
+            # the steps' prefill token budgets and what their push waits
+            # were for, summed (note_prefill_budget, note_push_wait)
             "prefill": prefill,
             # adopted store prefixes' pages by layer kind (note_kv_pages)
             "kv": kv,

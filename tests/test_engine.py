@@ -1570,3 +1570,62 @@ def test_scheduler_zero_retraces_after_warmup_under_churn():
     summ = prof.snapshot(limit=0)["summary"]  # the /debug/engine payload
     assert summ["steps"] > 0
     assert summ["retraces_per_100_steps"] == 0.0, summ["retraces"]
+
+
+# -- strict durability: the acknowledgement is awaited once a step, per request --
+
+import strict_settle  # noqa: E402
+
+
+@pytest.fixture
+def settle_kit(server):
+    """``strict_settle``'s kit over pages: prompts of 6 tokens at pages of 4
+    push one page (the complete chunk) and need two chunk forwards."""
+    import itertools
+    import types
+
+    from infinistore_tpu.kv.hashing import chunk_keys
+
+    conns, rng = [], np.random.RandomState(38)
+    ids = itertools.count()
+
+    def engine(durability="strict", store=True):
+        if store:
+            conns.append(_conn(server))
+        eng = InferenceEngine(
+            PARAMS, CFG, make_pc(256), conn=conns[-1] if store else None,
+            model_id=f"settle-{os.getpid()}-{time.time_ns()}-{next(ids)}",
+            prefill_chunk=T, store_durability=durability)
+        eng.decode_chunk = 4
+        return eng
+
+    def unnamed(eng, prompt):
+        keys = chunk_keys(prompt, eng.model_id, chunk_tokens=T)
+        return eng.pages.peek_prefix(keys[:1]) == 0
+
+    yield types.SimpleNamespace(
+        engine=engine, max_batch=8, first=[200, 201, 202, 203, 204],
+        prompts=lambda n: [[int(x) for x in rng.randint(1, 190, size=6)]
+                           for _ in range(n)],
+        solo=dense_greedy, unnamed=unnamed, names_pages=True)
+    for c in conns:
+        c.close()
+
+
+@pytest.mark.parametrize("case", strict_settle.CASES,
+                         ids=lambda c: c.__name__[5:])
+def test_strict_settle_over_pages(settle_kit, case):
+    case(settle_kit)
+
+
+@pytest.mark.parametrize("form", strict_settle.FORMS)
+def test_strict_blocking_prefill_returns_after_the_acknowledgement(
+        settle_kit, form):
+    strict_settle.case_blocking_forms_return_after_the_acknowledgement(
+        settle_kit, form)
+
+
+@pytest.mark.parametrize("mode", strict_settle.MODES)
+def test_strict_burst_outputs_equal_solo_runs_and_only_strict_parks(
+        settle_kit, mode):
+    strict_settle.case_burst_outputs_equal_solo_runs(settle_kit, mode)

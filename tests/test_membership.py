@@ -603,6 +603,77 @@ def test_streamer_marker_flush_surfaces_own_error():
     st.flush()
 
 
+class _FakePushes:
+    """A transfer's two push halves; a key that starts with ``slow`` takes a
+    second to commit, one that starts with ``boom`` fails."""
+
+    class breaker:
+        allow = staticmethod(lambda: True)
+        record_success = record_failure = staticmethod(lambda: None)
+
+    def push_begin(self, pages, keys):
+        return ("tok", list(keys))
+
+    def push_commit(self, token):
+        if token[1][0].startswith("slow"):
+            time.sleep(1.0)
+        if token[1][0].startswith("boom"):
+            raise RuntimeError("store died")
+        return 1
+
+
+def test_streamer_await_prefill_waits_on_its_own_marker_only():
+    """A prefill's own marker: untraced submits (trace id ``None``, which a
+    trace's barrier treats as everyone's) are told apart by it, so awaiting
+    B's pushes, which have landed, does not wait for A's push in flight."""
+    from infinistore_tpu.engine.engine import _StoreStreamer
+    from infinistore_tpu.utils import tracing
+
+    assert tracing.current_trace_id() is None
+    st = _StoreStreamer(_FakePushes(), maxsize=8, durability="strict")
+    st.submit(None, ["fast:1"], marker="prefill-b")
+    deadline = time.time() + 5
+    while st._pending and time.time() < deadline:
+        time.sleep(0.01)         # B's push lands
+    st.submit(None, ["slow:1"], marker="prefill-a")
+    time.sleep(0.05)
+    assert st._pending == {None: 1, "prefill-a": 1}
+    t0 = time.perf_counter()
+    st.await_prefill("prefill-b")
+    dt_b = time.perf_counter() - t0
+    st.await_prefill("prefill-a")
+    dt_a = time.perf_counter() - t0
+    assert dt_b < 0.3, f"B's wait joined A's push ({dt_b:.2f}s)"
+    assert dt_a > 0.3 and not st._pending
+    # a TRACE's barrier still covers the pushes bound to no trace
+    st.submit(None, ["slow:2"], marker="prefill-c")
+    t0 = time.perf_counter()
+    st.flush(marker="some-request's-trace")
+    assert time.perf_counter() - t0 > 0.3
+    st.flush()
+
+
+def test_streamer_await_prefill_raises_its_error_and_pushes_resume():
+    """Strict durability's wait raises the error of ITS prefill's pushes
+    (those skipped behind a parked error too) and, where that error is the
+    parked one, consumes it as the whole-queue flush it replaced did: the
+    next prefill's pushes are tried again."""
+    from infinistore_tpu.engine.engine import _StoreStreamer
+
+    st = _StoreStreamer(_FakePushes(), maxsize=8, durability="relaxed")
+    st.submit(None, ["boom:1"], marker="prefill-x")
+    st.submit(None, ["fast:skipped"], marker="prefill-y")    # behind the parked error
+    st.submit(None, ["fast:1"], marker="prefill-y")
+    with pytest.raises(RuntimeError, match="store died"):
+        st.await_prefill("prefill-y")
+    with pytest.raises(RuntimeError, match="store died"):
+        st.await_prefill("prefill-x")
+    st.await_prefill("prefill-x")      # taken once
+    st.submit(None, ["fast:2"], marker="prefill-z")
+    st.await_prefill("prefill-z")      # pushed, not skipped
+    st.flush()                         # nothing parked is left
+
+
 @pytest.fixture(scope="module")
 def handoff_stack():
     """A serving server with a single-node store, relaxed durability,

@@ -146,6 +146,44 @@ def test_phase_context_reenters_the_outer_phase_and_steps_split(monkeypatch):
     assert ph.s >= 0.001
 
 
+def test_push_queue_full_counts_only_a_submit_whose_put_blocked():
+    """``push_queue_full_*`` is place (a) of ``kv.push_wait`` alone: a
+    ``submit`` that found the bounded queue full, timed by its own phase.  A
+    submit that found room counts nothing, and neither does a wait for
+    acknowledgements (``await_prefill`` / ``flush``: the engine counts those
+    as ``settle_*``), though both stand in the same phase."""
+    from infinistore_tpu.engine.engine import _StoreStreamer
+
+    class Slow:
+        class breaker:
+            allow = staticmethod(lambda: True)
+            record_success = record_failure = staticmethod(lambda: None)
+
+        def push_begin(self, pages, keys):
+            return ("tok", list(keys))
+
+        def push_commit(self, token):
+            time.sleep(0.15)
+
+    prof = _prof()
+    st = _StoreStreamer(Slow(), maxsize=1, durability="strict")
+    with prof.step() as rec:
+        stepprof.enter("admit")
+        st.submit(None, ["k1"], marker="m")      # taken by the worker
+        time.sleep(0.05)
+        st.submit(None, ["k2"], marker="m")      # the queue's one place
+        assert "prefill" not in rec
+        st.submit(None, ["k3"], marker="m")      # full: waits for k1's commit
+        full = dict(rec["prefill"])
+        with stepprof.phase("kv.push_wait"):
+            st.await_prefill("m")                # k2 and k3: not the queue's
+    assert full["push_queue_full_waits"] == 1
+    assert 0.02 < full["push_queue_full_s"] < 0.15
+    assert rec["prefill"] == full and full["settle_waits"] == 0
+    assert rec["phases"]["kv.push_wait"] > full["push_queue_full_s"] + 0.25
+    assert prof.summary()["prefill"]["push_queue_full_waits"] == 1
+
+
 def test_fifteen_enters_and_a_count_cost_under_50_microseconds():
     """What a scheduler step pays for the timeline with the profiler off:
     about fifteen ``enter`` calls and one ``note_decode``, inside a step

@@ -630,3 +630,59 @@ def test_the_controls_and_a_zeroed_checkpoint_fail_the_limit(toy):
     assert st.local_chunks == 5 * STRIDE // T
     want = np.asarray(toy.f32(toy.ref_params, prompt + out[:-1], 4))
     assert top5_rms(logprobs(np.stack(rows[:4])), want) > 10 * RMS_LIMIT
+
+
+# -- strict durability: the acknowledgement is awaited once a step, per request ------
+
+import strict_settle  # noqa: E402
+
+
+@pytest.fixture
+def settle_kit(toy, store):
+    """``strict_settle``'s kit over state slots: a prompt of 150 tokens at a
+    stride of 128 pushes ONE checkpoint (every layer, one push) at the end of
+    its second chunk of 64, and runs a third."""
+    import itertools
+
+    conns, ids, solo = [], itertools.count(), {}
+
+    def build(durability="strict", store_=True):
+        if store_:
+            conns.append(connect(store))
+        return engine(
+            toy, f32=True, chunk=64, max_rows=12, n_blocks=192,
+            conn=conns[-1] if store_ else None, store_durability=durability,
+            model_id=f"settle-{os.getpid()}-{time.time_ns()}-{next(ids)}")
+
+    def alone(prompt, n):
+        if tuple(prompt) not in solo:
+            eng = build(store_=False)
+            solo[tuple(prompt)] = eng.decode(eng.prefill(prompt), n)
+        return solo[tuple(prompt)]
+
+    yield types.SimpleNamespace(
+        engine=lambda durability="strict", store=True: build(durability, store),
+        max_batch=12, first=tokens(20, 380),
+        prompts=lambda n: [tokens(150, 381 + next(ids)) for _ in range(n)],
+        solo=alone, unnamed=lambda eng, prompt: True, names_pages=False)
+    for c in conns:
+        c.close()
+
+
+@pytest.mark.parametrize("case", strict_settle.CASES,
+                         ids=lambda c: c.__name__[5:])
+def test_strict_settle_over_state_slots(settle_kit, case):
+    case(settle_kit)
+
+
+@pytest.mark.parametrize("form", strict_settle.FORMS)
+def test_strict_blocking_prefill_returns_after_the_acknowledgement(
+        settle_kit, form):
+    strict_settle.case_blocking_forms_return_after_the_acknowledgement(
+        settle_kit, form)
+
+
+@pytest.mark.parametrize("mode", strict_settle.MODES)
+def test_strict_burst_outputs_equal_solo_runs_and_only_strict_parks(
+        settle_kit, mode):
+    strict_settle.case_burst_outputs_equal_solo_runs(settle_kit, mode)

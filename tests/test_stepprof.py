@@ -613,13 +613,46 @@ def test_note_prefill_budget_sums_per_step_and_lifetime():
     prof = _prof(sample=1000)
     with prof.step(kind_hint="mixed") as rec:
         sp.note_prefill_budget(7 * 512, 5 * 512)
-    assert rec["prefill"] == {"granted_tokens": 3584, "spent_tokens": 2560}
+    assert rec["prefill"] == dict(dict.fromkeys(sp.PREFILL_COUNTS, 0),
+                                  granted_tokens=3584, spent_tokens=2560)
     with prof.step(kind_hint="decode") as rec2:
         sp.note_dispatch("decode")
     assert "prefill" not in rec2
     with prof.step(kind_hint="mixed"):
         sp.note_prefill_budget(512, 512)
     sp.note_prefill_budget(512, 512)
-    assert prof.summary()["prefill"] == {"granted_tokens": 4096,
-                                         "spent_tokens": 3072}
+    assert prof.summary()["prefill"] == dict(
+        dict.fromkeys(sp.PREFILL_COUNTS, 0),
+        granted_tokens=4096, spent_tokens=3072)
     assert prof.tail()[0]["prefill"]["spent_tokens"] == 2560
+
+
+def test_note_push_wait_sums_per_step_and_lifetime():
+    """The two causes of ``kv.push_wait`` land beside the step's budget under
+    ``prefill`` and sum into ``summary()['prefill']``: a burst that finishes
+    two prompts reads ``settled_prompts / settle_waits`` above 1; a call
+    outside a step is dropped."""
+    from infinistore_tpu.engine import stepprof as sp
+
+    prof = _prof(sample=1000)
+    with prof.step(kind_hint="mixed") as rec:
+        sp.note_prefill_budget(4 * 512, 3 * 512)
+        sp.note_push_wait(push_queue_full_waits=1, push_queue_full_s=0.25)
+        sp.note_push_wait(settle_waits=1)                # the step's one wait
+        sp.note_push_wait(settled_prompts=1, settle_wait_s=0.5)
+        sp.note_push_wait(settled_prompts=1, settle_wait_s=0.125)
+    assert rec["prefill"] == {
+        "granted_tokens": 2048, "spent_tokens": 1536, "settle_waits": 1,
+        "settled_prompts": 2, "settle_wait_s": 0.625,
+        "push_queue_full_waits": 1, "push_queue_full_s": 0.25}
+    with prof.step(kind_hint="prefill") as wave:    # a blocking prefill: no budget
+        sp.note_push_wait(settle_waits=1)
+        sp.note_push_wait(settled_prompts=1, settle_wait_s=0.25)
+    assert wave["prefill"]["granted_tokens"] == 0
+    assert wave["prefill"]["settled_prompts"] == 1
+    sp.note_push_wait(settle_waits=1, settled_prompts=1, settle_wait_s=9.0)
+    tot = prof.summary()["prefill"]
+    assert sorted(tot) == sorted(sp.PREFILL_COUNTS)
+    assert (tot["settle_waits"], tot["settled_prompts"]) == (2, 3)
+    assert tot["settle_wait_s"] == 0.875 and tot["push_queue_full_s"] == 0.25
+    assert tot["settled_prompts"] / tot["settle_waits"] > 1
