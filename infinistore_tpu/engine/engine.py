@@ -426,7 +426,8 @@ class _StoreStreamer:
                 target=self._run, name="istpu-kv-stream", daemon=True
             ).start()
             self._started = True
-        # the critical-path half runs HERE, on the submitting thread:
+        # the critical-path half runs HERE, on the submitting thread
+        # (phase ``kv.push_begin``, in whatever phase the caller stands):
         # push_begin only slices the gathered snapshot into bands and
         # kicks their D2H DMAs (dispatch-only), so the prefill thread
         # pays microseconds while the transfers overlap the next chunk's
@@ -447,8 +448,9 @@ class _StoreStreamer:
         with self._cond:
             for m in marks:
                 self._pending[m] = self._pending.get(m, 0) + 1
-        item = (self._transfer.push_begin(pages, chunk_keys_),
-                chunk_keys_, marks, acct)
+        with _stepprof.phase("kv.push_begin"):
+            token = self._transfer.push_begin(pages, chunk_keys_)
+        item = (token, chunk_keys_, marks, acct)
         try:
             self._q.put_nowait(item)
         except queue.Full:
@@ -1432,16 +1434,17 @@ class InferenceEngine:
         if self.transfer is not None:
             lo, hi = max(prev_done, pp.reused), min(pp.done, pp.n_complete)
             if hi > lo:
-                # gather + push_begin + the bounded queue's put (where it
-                # blocks, two chunks already waiting, it is kv.push_wait)
-                with _stepprof.phase("kv.push_submit"):
-                    self._streamer.submit(
-                        self.transfer.gather_pages(
-                            self.cache,
-                            pp.block_ids[lo:hi] if self.wpages is None
-                            else (pp.block_ids[lo:hi], pp.window_ids[lo:hi])),
-                        pp.keys[lo:hi], marker=pp.marker,
-                    )
+                # the gather's launch, then the submit: push_begin (its own
+                # phase, kv.push_begin) and the bounded queue's put (where
+                # it blocks, two chunks already waiting, it is kv.push_wait)
+                with _stepprof.phase("kv.push_gather"):
+                    pages = self.transfer.gather_pages(
+                        self.cache,
+                        pp.block_ids[lo:hi] if self.wpages is None
+                        else (pp.block_ids[lo:hi], pp.window_ids[lo:hi]))
+                    _stepprof.enter("kv.push_submit")
+                    self._streamer.submit(pages, pp.keys[lo:hi],
+                                          marker=pp.marker)
         if self.wpages is not None:
             # the push holds a snapshot: window pages that lie below the
             # window of every position still to come go back now

@@ -298,11 +298,11 @@ class StateEngine(InferenceEngine):
         if stored:
             self._count(checkpoints_skipped_stored=1)
             return
-        with _stepprof.phase("kv.push_submit"):
+        with _stepprof.phase("kv.push_gather"):
+            pages = self.transfer.gather_pages(self.cache, pp.slot)
+            _stepprof.enter("kv.push_submit")
             self.transfer.covers(key, pp.ckpt_at)
-            self._streamer.submit(
-                self.transfer.gather_pages(self.cache, pp.slot), [key],
-                marker=pp.marker)
+            self._streamer.submit(pages, [key], marker=pp.marker)
         self._count(checkpoints_pushed=1, bytes_pushed=self.pc.slot_bytes)
 
     def _keep_resident(self, key: str, row: int) -> bool:

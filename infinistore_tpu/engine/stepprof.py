@@ -56,6 +56,10 @@ structured record per scheduler step:
   chunk tokens a step was granted and the ones it spent; ``note_push_wait``
   tells the two causes of the ``kv.push_wait`` phase apart (the streamer's
   full queue, and strict durability's wait for acknowledgements).
+* **stages of other threads** — ``stage(name)`` is the same bracket for a
+  thread that is not the engine's: an annotation on that thread's line of
+  the profiler's trace and its seconds, which the KV transfer sums into
+  ``push_totals`` (the streamer's worker: ``istpu.stream.*``).
 
 Records live in a bounded ring (``ISTPU_STEPPROF_RING``, default 256),
 exported at the serving front-end's ``GET /debug/engine`` (``?limit=``),
@@ -84,6 +88,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from ..utils import metrics as _metrics
 from ..utils import tracing
+from ..utils.profiling import Timer
 
 # -- knobs ------------------------------------------------------------------
 
@@ -405,6 +410,32 @@ class phase:
             self.s = self.prof.enter(self.prev) - self.t0
         else:
             self.s = time.perf_counter() - self.t0
+        return False
+
+
+class stage(Timer):
+    """``with stage("istpu.stream.d2h") as st:`` — the timeline of a thread
+    that is NOT the engine's (the streamer's worker: ``StepProfiler.enter``
+    belongs to one thread, and no other may call it).  The block is a
+    ``jax.profiler.TraceAnnotation(name)``, so a capture shows it on that
+    thread's own line of the host plane, on the clock of the device's
+    operations; ``st.s`` is its seconds, for whatever total the caller keeps.
+    The site is timed ONCE: the annotation and the total are one bracket.
+    With no capture running the annotation is a flag test."""
+
+    __slots__ = ("ann",)
+
+    def __init__(self, name: str):
+        self.ann = _annotation(name)
+        self.s = 0.0
+
+    def __enter__(self) -> "stage":
+        self.ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        self.ann.__exit__(*exc)
         return False
 
 

@@ -32,6 +32,20 @@ from .hashing import layer_key
 from .quant import dequantize_pages_jit, page_quant_bytes, quantize_pages
 
 
+# a load's stages inside its ``fetch_s``, as ``load_totals`` sums them
+LOAD_STAGES = ("desc_s", "pool_copy_s", "upload_s")
+
+
+def _stage(name: str):
+    """``stepprof.stage("istpu.stream.<name>")``: one stage of a push, on the
+    thread that commits it (the streamer's worker), in the profiler's trace
+    and as seconds.  Looked up at the call: the engine package imports this
+    module."""
+    from ..engine.stepprof import stage
+
+    return stage("istpu.stream." + name)
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _scatter_stacked(cache: jax.Array, block_ids: jax.Array,
                      stacked: jax.Array) -> jax.Array:
@@ -159,16 +173,22 @@ class KVTransferEngine:
         # deltas (engine/stepprof.py).  Each is REPLACED whole under the
         # lock, never mutated: a reader holding one holds a consistent
         # snapshot.  ``submit_to_commit_s`` runs from push_begin on the
-        # submitting thread to the acknowledged COMMIT_PUT on the worker,
-        # so a push's wait in the streamer's queue is inside it.
+        # submitting thread to the acknowledged COMMIT_PUT on the worker:
+        # ``queue_s`` (push_begin's end to push_commit's entry: behind
+        # earlier pushes) + ``commit_wall_s`` (push_commit, entry to
+        # return); the five stages are timed inside ``commit_wall_s`` and
+        # what they leave of it is the worker's Python between them.
         self._totals_lock = threading.Lock()
         self.push_totals: dict = dict.fromkeys(
             ("pushes", "tokens", "bytes"), 0) | dict.fromkeys(
             ("d2h_s", "pool_copy_s", "alloc_s", "wire_s", "commit_s",
-             "submit_to_commit_s"), 0.0)
+             "queue_s", "commit_wall_s", "submit_to_commit_s"), 0.0)
+        # ``fetch_s`` holds ``LOAD_STAGES`` and the loop's Python;
+        # ``scatter_s`` the scatter's launch and ``sync_s``, the closing
+        # ``block_until_ready`` alone
         self.load_totals: dict = dict.fromkeys(
             ("loads", "tokens", "bytes"), 0) | dict.fromkeys(
-            ("fetch_s", "scatter_s"), 0.0)
+            ("fetch_s", "scatter_s", "sync_s") + LOAD_STAGES, 0.0)
 
     def _tokens_of(self, chunk_keys_: Sequence[str]) -> int:
         """Tokens a push of these keys stands for in ``push_totals``."""
@@ -310,15 +330,14 @@ class KVTransferEngine:
         the whole HBM→pool journey."""
 
         def fill(dst: np.ndarray) -> None:
-            t0 = time.perf_counter()
-            host = np.asarray(p)
-            if not host.flags["C_CONTIGUOUS"]:
-                host = np.ascontiguousarray(host)
-            t1 = time.perf_counter()
-            np.copyto(dst, host.reshape(-1).view(np.uint8))
-            t2 = time.perf_counter()
-            stages["d2h_s"] += t1 - t0
-            stages["pool_copy_s"] += t2 - t1
+            with _stage("d2h") as st:
+                host = np.asarray(p)
+                if not host.flags["C_CONTIGUOUS"]:
+                    host = np.ascontiguousarray(host)
+            stages["d2h_s"] += st.s
+            with _stage("pool_copy") as st:
+                np.copyto(dst, host.reshape(-1).view(np.uint8))
+            stages["pool_copy_s"] += st.s
 
         return fill
 
@@ -341,7 +360,12 @@ class KVTransferEngine:
         straight into the shm pool on connections that negotiated
         alloc-first descriptors, through the pinned staging ring on
         TCP/native — and COMMIT_PUT.  Per-stage seconds land in
-        ``last_push_stages``.  Returns bytes written."""
+        ``last_push_stages`` and ``push_totals``, each stage an
+        ``istpu.stream.*`` annotation on the calling thread; the call
+        stamps its own entry (the push's wait since ``push_begin`` is
+        ``queue_s``) and return (``commit_wall_s``).  Returns bytes
+        written."""
+        t_in = time.perf_counter()
         parts, chunk_keys_, t_begin = token
         L = self.cfg.n_layers
         pb = self.wire_page_bytes
@@ -352,10 +376,12 @@ class KVTransferEngine:
                           bytes=len(chunk_keys_) * L * pb):
             total = self._push_banded(parts, chunk_keys_, stages)
         self.last_push_stages = stages
+        t_out = time.perf_counter()
         self._add_totals(
             "push_totals", pushes=1, bytes=total,
             tokens=self._tokens_of(chunk_keys_),
-            submit_to_commit_s=time.perf_counter() - t_begin,
+            queue_s=t_in - t_begin, commit_wall_s=t_out - t_in,
+            submit_to_commit_s=t_out - t_begin,
             **{k: v for k, v in stages.items() if k.endswith("_s")})
         return total
 
@@ -379,8 +405,10 @@ class KVTransferEngine:
                  self._band_fill(p, stages))
                 for l0, p in zip(l0s, parts)
             ]
-            info = self._src.write_cache_into(bands)
+            info = self._src.write_cache_into(bands, _stage)
             stages["alloc_s"] += info.get("alloc_s", 0.0)
+            # a band whose allocation came back in pieces: scratch to pool
+            stages["pool_copy_s"] += info.get("copy_s", 0.0)
             stages["commit_s"] += info.get("commit_s", 0.0)
             stages["zero_copy_bands"] += info.get("zero_copy_bands", 0)
             stages["staged_bands"] += info.get("staged_bands", 0)
@@ -398,9 +426,9 @@ class KVTransferEngine:
                 slot = self._ensure_push_staging(nbytes)
                 self._band_fill(p, stages)(slot[:nbytes])
                 stages["staged_bands"] += 1
-                t0 = time.perf_counter()
-                self._call("write_cache", blocks, pb, slot.ctypes.data)
-                stages["wire_s"] += time.perf_counter() - t0
+                with _stage("wire") as st:
+                    self._call("write_cache", blocks, pb, slot.ctypes.data)
+                stages["wire_s"] += st.s
                 total += nbytes
             return total
         # legacy path (push_mode="legacy", or an shm peer that did not
@@ -503,8 +531,10 @@ class KVTransferEngine:
         """Every group fetched (the all-or-nothing half: a missing page
         raises here, before the cache is touched), then every group
         scattered into the donated cache, or into its layers' pool of it."""
+        stages = dict.fromkeys(LOAD_STAGES, 0.0)
         t0 = time.perf_counter()
-        fetched = [self.fetch_pages([chunk_keys_[i] for i in cs], layers=ls)
+        fetched = [self.fetch_pages([chunk_keys_[i] for i in cs], layers=ls,
+                                    stages=stages)
                    for ls, cs, _ in groups]
         t1 = time.perf_counter()
         pools = list(cache) if isinstance(cache, tuple) else [cache]
@@ -517,22 +547,35 @@ class KVTransferEngine:
                 jnp.asarray(np.asarray([table[i] for i in cs],
                                        dtype=np.int32)), stacked)
         cache = tuple(pools) if isinstance(cache, tuple) else pools[0]
-        jax.block_until_ready(cache)
-        t2 = time.perf_counter()
-        self.last_load_stages = {
-            "fetch_s": round(t1 - t0, 6), "scatter_s": round(t2 - t1, 6),
-            "pages": pages, "bytes": pages * self.wire_page_bytes,
-        }
-        self._add_totals(
-            "load_totals", loads=1,
-            tokens=len({i for _, cs, _ in groups for i in cs})
-            * self.cfg.block_tokens,
-            bytes=self.last_load_stages["bytes"], fetch_s=t1 - t0,
-            scatter_s=t2 - t1)
+        self._landed(cache, t0, t1, stages, pages,
+                     len({i for _, cs, _ in groups for i in cs})
+                     * self.cfg.block_tokens)
         return cache
 
+    def _landed(self, out, t0: float, t1: float, stages: dict, pages: int,
+                tokens: int) -> None:
+        """The end of every load: wait until ``out`` has materialized (every
+        read of this call's staging buffer must complete before a LATER
+        call can rewrite it: with the double buffer, a stale optimistic
+        sync would need two further loads to become dangerous), then the
+        load's record.  ``t0`` .. ``t1`` was the fetch, whose ``stages``
+        (``LOAD_STAGES``) were timed where they happened; the scatter's
+        launch follows it and the wait here is ``sync_s``."""
+        ts = time.perf_counter()
+        jax.block_until_ready(out)
+        t2 = time.perf_counter()
+        nbytes = pages * self.wire_page_bytes
+        self.last_load_stages = {
+            "fetch_s": round(t1 - t0, 6), "scatter_s": round(t2 - t1, 6),
+            "pages": pages, "bytes": nbytes,
+        }
+        self._add_totals(
+            "load_totals", loads=1, tokens=tokens, bytes=nbytes,
+            fetch_s=t1 - t0, scatter_s=t2 - t1, sync_s=t2 - ts, **stages)
+
     def fetch_pages(self, chunk_keys_: Sequence[str],
-                    layers: Optional[Sequence[int]] = None) -> jax.Array:
+                    layers: Optional[Sequence[int]] = None,
+                    stages: Optional[dict] = None) -> jax.Array:
         """Wire half of a load: read every (layer, chunk) page of
         ``chunk_keys_`` into this engine's staging ring and hand each
         band to an async H2D upload.  Returns the stacked device array
@@ -542,7 +585,13 @@ class KVTransferEngine:
         cluster layer can fetch different chunks from different nodes
         concurrently (each node engine owns its own staging) and
         scatter once all bytes verified.  ``layers``: those layers' pages
-        only, stacked in the order given (default: every layer)."""
+        only, stacked in the order given (default: every layer).
+        ``stages``: a dict whose ``LOAD_STAGES`` gain this fetch's seconds,
+        each timed where it happens: ``desc_s`` and ``pool_copy_s`` inside
+        the client (the python client on a mapped pool; 0 elsewhere),
+        ``upload_s`` the ``device_put`` calls and the ``concatenate``."""
+        if stages is None:
+            stages = dict.fromkeys(LOAD_STAGES, 0.0)
         n = len(chunk_keys_)
         pb = self.wire_page_bytes
         layers = list(range(self.cfg.n_layers) if layers is None else layers)
@@ -562,6 +611,7 @@ class KVTransferEngine:
         devs: list = [None] * len(bands)
 
         def upload(i: int) -> None:
+            t0 = time.perf_counter()
             off, span, nl = meta[i]
             band = staging[off : off + span]
             if self.quant:
@@ -574,16 +624,21 @@ class KVTransferEngine:
             # async H2D: returns immediately; the next band's pool copy
             # (and its prefetched GET_DESC) overlaps this band's DMA
             devs[i] = jax.device_put(host)
+            stages["upload_s"] += time.perf_counter() - t0
 
         reader = getattr(self._src, "read_cache_pipelined", None)
         if reader is not None:
-            reader(bands, on_band=upload)
+            reader(bands, upload, stages)
         else:  # bare native client: per-band reads, same upload overlap
             for i, (blocks, _pb, ptr) in enumerate(bands):
                 self._call("read_cache", blocks, pb, ptr)
                 upload(i)
-        # single band: already [L, n, ...] — don't pay a concat copy
-        return devs[0] if len(devs) == 1 else jnp.concatenate(devs, axis=0)
+        if len(devs) == 1:   # already [L, n, ...] — don't pay a concat copy
+            return devs[0]
+        t0 = time.perf_counter()
+        out = jnp.concatenate(devs, axis=0)
+        stages["upload_s"] += time.perf_counter() - t0
+        return out
 
     def scatter_pages(
         self, cache: jax.Array, block_ids: Sequence[int], stacked: jax.Array
@@ -602,25 +657,13 @@ class KVTransferEngine:
         self, cache: jax.Array, block_ids: Sequence[int],
         chunk_keys_: Sequence[str], n: int
     ) -> jax.Array:
+        stages = dict.fromkeys(LOAD_STAGES, 0.0)
         t0 = time.perf_counter()
-        stacked = self.fetch_pages(chunk_keys_)
+        stacked = self.fetch_pages(chunk_keys_, stages=stages)
         t1 = time.perf_counter()
         out = self.scatter_pages(cache, block_ids, stacked)
-        # materialize before returning: every read of this call's staging
-        # buffer must complete before a LATER call can rewrite it (with
-        # the double buffer above, a stale optimistic sync would need two
-        # further loads to become dangerous)
-        jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        self.last_load_stages = {
-            "fetch_s": round(t1 - t0, 6), "scatter_s": round(t2 - t1, 6),
-            "pages": self.cfg.n_layers * n,
-            "bytes": self.cfg.n_layers * n * self.wire_page_bytes,
-        }
-        self._add_totals(
-            "load_totals", loads=1, tokens=n * self.cfg.block_tokens,
-            bytes=self.last_load_stages["bytes"], fetch_s=t1 - t0,
-            scatter_s=t2 - t1)
+        self._landed(out, t0, t1, stages, self.cfg.n_layers * n,
+                     n * self.cfg.block_tokens)
         return out
 
     def lookup_prefix(self, chunk_keys_: Sequence[str],
@@ -836,17 +879,12 @@ class StateTransferEngine(KVTransferEngine):
         nbytes = self.cfg.n_layers * self.wire_page_bytes
         with tracing.span("kv.load_pages", pages=self.cfg.n_layers,
                           bytes=nbytes):
+            stages = dict.fromkeys(LOAD_STAGES, 0.0)
             t0 = time.perf_counter()
-            stacked = self.fetch_pages([key])
+            stacked = self.fetch_pages([key], stages=stages)
             t1 = time.perf_counter()
             out = _wire_to_state(*cache, jnp.asarray(slot, jnp.int32), stacked)
-            jax.block_until_ready(out)
-            t2 = time.perf_counter()
-        self.last_load_stages = {
-            "fetch_s": round(t1 - t0, 6), "scatter_s": round(t2 - t1, 6),
-            "pages": self.cfg.n_layers, "bytes": nbytes}
-        self._add_totals("load_totals", loads=1, tokens=tokens, bytes=nbytes,
-                         fetch_s=t1 - t0, scatter_s=t2 - t1)
+            self._landed(out, t0, t1, stages, self.cfg.n_layers, tokens)
         return out
 
     def lookup_prefix(self, chunk_keys_: Sequence[str]) -> int:

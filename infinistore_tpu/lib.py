@@ -51,7 +51,7 @@ from .utils import metrics as _metrics
 from .utils import resilience as _resilience
 from .utils import tracing as _tracing
 from .utils.logging import Logger
-from .utils.profiling import LatencyStats
+from .utils.profiling import LatencyStats, Timer
 
 # one shared client-side histogram for every connection in the process:
 # the op label carries both whole ops (write_cache, read_cache, w_tcp ...)
@@ -1139,7 +1139,7 @@ class Connection:
         return buf
 
     @_timed_op("write_cache_into")
-    def write_cache_into(self, bands) -> dict:
+    def write_cache_into(self, bands, stage=None) -> dict:
         """Alloc-first, fill-in-place put — the zero-copy half of the
         HBM→pool push path.
 
@@ -1156,12 +1156,20 @@ class Connection:
         peers) degrade to one staging copy through a reusable scratch
         buffer.
 
+        ``stage``: ``stage("alloc")`` / ``stage("commit")`` give the context
+        manager that times the ALLOC_PUT and COMMIT_PUT round trips, and
+        ``stage("pool_copy")`` a staged band's copy from the scratch buffer
+        into the pool (its seconds as ``.s``); default a plain clock pair.
+
         Returns ``{"bytes", "zero_copy_bands", "staged_bands", "alloc_s",
-        "commit_s"}`` — the band counters the structural perf guard
-        asserts on, plus the phase seconds the bench breakdown reads."""
+        "copy_s", "commit_s"}`` — the band counters the structural perf
+        guard asserts on, plus the phase seconds the bench breakdown reads
+        (``copy_s``: the staged bands' second copy alone; ``fill`` times
+        its own)."""
+        stage = stage or Timer
         bands = [b for b in bands if b[0]]
         info = {"bytes": 0, "zero_copy_bands": 0, "staged_bands": 0,
-                "alloc_s": 0.0, "commit_s": 0.0}
+                "alloc_s": 0.0, "copy_s": 0.0, "commit_s": 0.0}
         if not bands:
             return info
         if not (self.shm_mode and self.alloc_first):
@@ -1180,8 +1188,7 @@ class Connection:
         acct = self._account()
         enc = [P.encode_keys([k for k, _ in blocks])
                for blocks, _, _ in bands]
-        t_alloc = time.perf_counter()
-        with self.latency.timed("write_cache.alloc"):
+        with self.latency.staged("write_cache.alloc", stage("alloc")) as st:
             # all bands' ALLOC_PUTs pipelined on one channel: the
             # descriptors come back while the payload is still being
             # produced (this is what "alloc-first" buys)
@@ -1199,7 +1206,7 @@ class Connection:
                 else:
                     _raise_for_status(status, "alloc_put")
                 descs_per.append(P.unpack_descs(memoryview(body)))
-        info["alloc_s"] = time.perf_counter() - t_alloc
+        info["alloc_s"] = st.s
         all_keys: List[bytes] = []
         for i, (blocks, block_size, fill) in enumerate(bands):
             descs = descs_per[i]
@@ -1218,26 +1225,32 @@ class Connection:
                 else:
                     scratch = self._fill_scratch(nbytes)
                     fill(scratch[:nbytes])
-                    self._copy_descs(descs, offsets,
-                                     memoryview(scratch)[:nbytes],
-                                     to_pool=True)
+                    with stage("pool_copy") as st:
+                        self._copy_descs(descs, offsets,
+                                         memoryview(scratch)[:nbytes],
+                                         to_pool=True)
+                    info["copy_s"] += st.s
                     info["staged_bands"] += 1
             all_keys.extend(enc[i])
             info["bytes"] += nbytes
-        t_commit = time.perf_counter()
-        with self.latency.timed("write_cache.commit"):
+        with self.latency.staged("write_cache.commit", stage("commit")) as st:
             status, _ = self._request(P.OP_COMMIT_PUT, P.pack_keys(all_keys))
             _raise_for_status(status, "commit_put")
-        info["commit_s"] = time.perf_counter() - t_commit
+        info["commit_s"] = st.s
         return info
 
     @_timed_op("read_cache_pipelined")
-    def read_cache_pipelined(self, bands, on_band: Optional[Callable] = None) -> int:
+    def read_cache_pipelined(self, bands, on_band: Optional[Callable] = None,
+                             stages: Optional[dict] = None) -> int:
         """Mirror image of ``write_cache_pipelined``: band i+1's GET_DESC
         round-trip rides behind band i's pool copy.  ``bands``: sequence
         of ``(blocks, block_size, ptr)``.  ``on_band(i)`` fires once band
         i's bytes are in place (the KV load path hands each band to an
-        async H2D there).  Returns bytes read."""
+        async H2D there).  ``stages``: a dict whose ``desc_s`` (the waits
+        for GET_DESC answers) and ``pool_copy_s`` (pool to ``ptr``, and the
+        verification where integrity is on) gain this call's seconds, on
+        the shm path (the inline path has no such stages).  Returns bytes
+        read."""
         live = [(i, b) for i, b in enumerate(bands) if b[0]]
         if not live:
             return 0
@@ -1255,10 +1268,13 @@ class Connection:
         enc = [P.encode_keys([k for k, _ in b[0]]) for _, b in live]
         slot = ch.submit(P.OP_GET_DESC, P.pack_alloc_put(enc[0], live[0][1][1]),
                          trace_id=tid, account=acct)
+        if stages is None:
+            stages = {"desc_s": 0.0, "pool_copy_s": 0.0}
         for j, (i, (blocks, block_size, ptr)) in enumerate(live):
-            with self.latency.timed("read_cache.desc"):
+            with self.latency.staged("read_cache.desc", Timer()) as st:
                 status, body = ch.wait(slot)
                 _raise_for_status(status, "get_desc")
+            stages["desc_s"] += st.s
             t_desc = time.monotonic()
             if j + 1 < len(live):
                 slot = ch.submit(
@@ -1275,16 +1291,18 @@ class Connection:
                 descs = P.unpack_descs(memoryview(body))
             offsets = [off for _, off in blocks]
             view = _ptr_view(ptr, max(offsets) + block_size)
-            with self.latency.timed("read_cache.copy"):
+            with self.latency.staged("read_cache.copy", Timer()) as st:
                 self._copy_descs(descs, offsets, view, to_pool=False)
+            stages["pool_copy_s"] += st.s
             if self.integrity:
                 # verify BEFORE on_band fires: a band is only handed to
                 # the H2D upload once its bytes checked out — corrupt
                 # pages must never be admitted into the paged cache
                 try:
-                    with self.latency.timed("read_cache.verify"):
+                    with self.latency.staged("read_cache.verify", Timer()) as st:
                         self._verify_descs(descs_ex, offsets, view, enc[j],
                                            t_desc)
+                    stages["pool_copy_s"] += st.s
                 finally:
                     self._release_descs(enc[j])
             total += sum(s for _, _, s in descs)
@@ -1614,12 +1632,13 @@ class InfinityConnection:
             total += block_size * len(blocks)
         return total
 
-    def write_cache_into(self, bands) -> dict:
+    def write_cache_into(self, bands, stage=None) -> dict:
         """Alloc-first fill-in-place put (see ``Connection``): clients
         without the entry point (native) stage each band through a
-        scratch buffer and ride the plain batched put."""
+        scratch buffer and ride the plain batched put (their stages are
+        timed in C: ``stage`` is not called)."""
         if hasattr(self.conn, "write_cache_into"):
-            return self._call("write_cache_into", bands)
+            return self._call("write_cache_into", bands, stage)
         info = {"bytes": 0, "zero_copy_bands": 0, "staged_bands": 0}
         for blocks, block_size, fill in bands:
             if not blocks:
@@ -1632,11 +1651,12 @@ class InfinityConnection:
             info["bytes"] += nbytes
         return info
 
-    def read_cache_pipelined(self, bands, on_band=None) -> int:
+    def read_cache_pipelined(self, bands, on_band=None, stages=None) -> int:
         """Banded get with desc-prefetch overlap; ``on_band(i)`` fires as
-        each band's bytes land (same fallback rule as the write side)."""
+        each band's bytes land (same fallback rule as the write side, and
+        there ``stages`` gains nothing)."""
         if hasattr(self.conn, "read_cache_pipelined"):
-            return self._call("read_cache_pipelined", bands, on_band)
+            return self._call("read_cache_pipelined", bands, on_band, stages)
         total = 0
         for i, (blocks, block_size, ptr) in enumerate(bands):
             if blocks:

@@ -26,6 +26,25 @@ from . import tracing
 from .metrics import nearest_rank
 
 
+class Timer:
+    """``with Timer() as t:`` — ``t.s`` is the block's seconds: the plain
+    form of a stage's bracket (``engine.stepprof.stage`` is the one that is
+    also an annotation in the profiler's trace)."""
+
+    __slots__ = ("t0", "s")
+
+    def __init__(self, name: str = ""):
+        self.s = 0.0
+
+    def __enter__(self) -> "Timer":
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.s = time.perf_counter() - self.t0
+        return False
+
+
 class LatencyStats:
     """Per-op latency accumulator: count / total / max plus a bounded
     ring of recent samples for percentiles (thread-safe, cheap enough for
@@ -50,6 +69,19 @@ class LatencyStats:
                 yield
             finally:
                 self._record(name, time.perf_counter() - t0)
+
+    @contextlib.contextmanager
+    def staged(self, name: str, st):
+        """``timed`` for a block that ``st`` times (a context manager with
+        the block's seconds as ``.s`` on the way out: a ``Timer``, or a
+        caller's own bracket): the block is timed ONCE, the sample lands
+        here whether or not the block raised, and the caller reads
+        ``st.s`` for the totals it keeps."""
+        try:
+            with st:
+                yield st
+        finally:
+            self.record(name, st.s)
 
     def record(self, name: str, seconds: float) -> None:
         """Accumulate one externally-timed sample (the data plane's

@@ -75,6 +75,10 @@ SCRIPTS = {
     "repeats": ["admit", "kv.lookup", "admit", "kv.load", "admit", "admit",
                 "sched"],
     "closes": ["intake", None, "admit", "sched", None],
+    # a pushed chunk: the gather, push_begin, the submit's rest, a full queue
+    "push": ["prefill.launch", "kv.push_gather", "kv.push_begin",
+             "kv.push_submit", "kv.push_wait", "kv.push_submit",
+             "prefill.launch", "sched"],
 }
 
 
@@ -192,10 +196,10 @@ def test_fifteen_enters_and_a_count_cost_under_50_microseconds():
     from infinistore_tpu.utils import tracing
 
     prof = _prof()
-    names = ["admit", "kv.lookup", "admit", "kv.load", "admit", "sched",
-             "prefill.launch", "kv.push_submit", "prefill.launch", "sched",
-             "decode.launch", "decode.wait", "decode.unpack",
-             "retire_stream", "idle"]
+    names = ["admit", "kv.lookup", "admit", "kv.load", "sched",
+             "prefill.launch", "kv.push_gather", "kv.push_begin",
+             "kv.push_submit", "prefill.launch", "decode.launch",
+             "decode.wait", "decode.unpack", "retire_stream", "idle"]
     assert len(names) == 15
     best = float("inf")
     prof.enter("intake")
@@ -208,6 +212,28 @@ def test_fifteen_enters_and_a_count_cost_under_50_microseconds():
                 stepprof.note_decode(32, 3, 4, 256, 16, 9000)
             best = min(best, (time.perf_counter() - t0) / 200)
     assert best < 50e-6, f"{best * 1e6:.1f} us per step"
+
+
+def test_a_pushs_ten_stages_cost_under_50_microseconds_with_no_capture():
+    """The twin of the guard above for ``stepprof.stage``, the bracket of a
+    thread that is not the engine's: what the streamer's worker pays a push
+    of four bands (ALLOC_PUT, four waits for a band's D2H and four pool
+    copies, COMMIT_PUT) while no capture runs, when each annotation is a
+    flag test."""
+    names = ["alloc"] + ["d2h", "pool_copy"] * 4 + ["commit"]
+    assert len(names) == 10
+    best, total, t_first = float("inf"), 0.0, time.perf_counter()
+    for _ in range(7):
+        t0 = time.perf_counter()
+        for _ in range(200):
+            for n in names:
+                with stepprof.stage("istpu.stream." + n) as st:
+                    pass
+                total += st.s
+        best = min(best, (time.perf_counter() - t0) / 200)
+    # each stage's own seconds: inside the bracket, so inside the loop's
+    assert 0 < total < time.perf_counter() - t_first
+    assert best < 50e-6, f"{best * 1e6:.1f} us per push"
 
 
 # ---------------------------------------------------------------------------
@@ -524,6 +550,178 @@ def test_ttft_slices_sum_for_a_store_hit_and_the_store_totals(tiny, store_port):
 
 
 # ---------------------------------------------------------------------------
+# D2. a push and a load timed where they happen (the python client: the
+# native one keeps its stage timings in C)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def py_conn(store_port, monkeypatch):
+    import infinistore_tpu as ist
+
+    monkeypatch.setenv("ISTPU_CLIENT", "python")
+    conns = []
+
+    def conn():
+        c = ist.InfinityConnection(ist.ClientConfig(
+            host_addr="127.0.0.1", service_port=store_port,
+            connection_type=ist.TYPE_SHM))
+        c.connect()
+        conns.append(c)
+        return c
+
+    yield conn
+    for c in conns:
+        c.close()
+
+
+def test_a_capture_holds_the_workers_stages_on_a_line_of_their_own(
+        tiny, py_conn, tmp_path):
+    """A store-attached prefill under a real capture: the streamer's worker
+    writes ``istpu.stream.*`` on a line of the host plane that holds none of
+    the engine thread's phases, and the engine's line holds the two phases
+    cut out of ``kv.push_submit``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from infinistore_tpu.engine.scheduler import Scheduler
+
+    cfg, params, make_engine = tiny
+    eng = make_engine(conn=py_conn(), kv_quant=None, prefill_chunk=8)
+    sched = Scheduler(eng, max_batch=2, stepprof=_prof())
+    sched.submit(list(range(1, 30)), max_new_tokens=4)
+    sched.run()                        # compile outside the capture
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        sched.submit(list(range(40, 70)), max_new_tokens=4)
+        sched.run()
+    finally:
+        jax.profiler.stop_trace()
+    reduce = _load(os.path.join(BENCH, "trace", "reduce.py"))
+    data = ProfileData.from_file(reduce.find_xplane(str(tmp_path)))
+    lines = [{e.name for e in ln.events if e.name.startswith("istpu.")}
+             for p in data.planes if p.name.startswith("/host:")
+             for ln in p.lines]
+    engine = [ln for ln in lines if "istpu.prefill.launch" in ln]
+    worker = [ln for ln in lines if any(n.startswith("istpu.stream.")
+                                        for n in ln)]
+    assert len(engine) == 1 and len(worker) == 1, lines
+    assert {"istpu.kv.push_gather", "istpu.kv.push_begin",
+            "istpu.kv.push_submit"} <= engine[0], sorted(engine[0])
+    # a mapped pool: no wire stage; and the worker never enters a phase
+    assert worker[0] == {"istpu.stream.d2h", "istpu.stream.pool_copy",
+                         "istpu.stream.alloc", "istpu.stream.commit"}
+
+
+PUSH_STAGES = ("d2h_s", "pool_copy_s", "alloc_s", "commit_s", "wire_s")
+
+
+def test_a_pushs_queue_and_commit_wall_sum_to_submit_to_commit(py_conn):
+    """Over a slowed store (every ``write_cache_into`` sleeps first): a lone
+    push waits about nothing in the queue, one submitted behind another
+    waits for it; ``queue_s + commit_wall_s`` is ``submit_to_commit_s`` and
+    the stages, timed inside ``push_commit``, never exceed its wall.  Both
+    ride on the step record with the other totals."""
+    from types import SimpleNamespace
+
+    from infinistore_tpu.engine.engine import _StoreStreamer
+    from infinistore_tpu.kv import KVTransferEngine, PagedCacheConfig
+    from infinistore_tpu.kv.cache import init_cache
+
+    pc = PagedCacheConfig(n_layers=4, n_kv_heads=2, head_dim=8, n_blocks=16,
+                          block_tokens=4)
+    tr = KVTransferEngine(py_conn(), pc)
+    cache = init_cache(pc) + 1.0
+    into = tr._src.write_cache_into
+
+    def slowed(bands, stage=None):
+        time.sleep(0.12)
+        return into(bands, stage)
+
+    tr._src.write_cache_into = slowed
+    st = _StoreStreamer(tr, maxsize=2, durability="strict")
+
+    def check(t):
+        assert t["queue_s"] + t["commit_wall_s"] == pytest.approx(
+            t["submit_to_commit_s"], abs=1e-6)
+        assert sum(t[k] for k in PUSH_STAGES) <= t["commit_wall_s"]
+        assert t["d2h_s"] > 0 and t["alloc_s"] > 0 and t["commit_s"] > 0
+
+    prof = _prof()
+    sched = SimpleNamespace(engine=SimpleNamespace(transfer=tr, cache=None))
+    with prof.step(sched) as rec:
+        st.submit(tr.gather_pages(cache, [1, 2]), ["lone-a", "lone-b"])
+        st.flush()
+    lone = tr.push_totals
+    check(lone)
+    assert lone["pushes"] == 1 and lone["queue_s"] < 0.05
+    assert lone["commit_wall_s"] >= 0.12
+    assert rec["store"]["push"]["queue_s"] == round(lone["queue_s"], 6)
+    assert rec["store"]["push"]["commit_wall_s"] == round(
+        lone["commit_wall_s"], 6)
+    st.submit(tr.gather_pages(cache, [3]), ["first"])
+    st.submit(tr.gather_pages(cache, [4]), ["behind-it"])
+    st.flush()
+    both = tr.push_totals
+    check(both)
+    assert both["pushes"] == 3
+    assert both["queue_s"] - lone["queue_s"] >= 0.1     # the second's wait
+    assert prof.summary()["store"]["push"]["queue_s"] == both["queue_s"]
+
+
+@pytest.mark.parametrize("path", ["banded", "layer-groups", "state"])
+def test_a_loads_stages_lie_inside_its_fetch_and_its_sync_inside_its_scatter(
+        py_conn, path):
+    """Each of the three load paths counts ``desc_s`` (the waits for GET_DESC
+    answers), ``pool_copy_s`` (pool to staging) and ``upload_s`` (the
+    ``device_put`` calls) where they happen, inside ``fetch_s``, and the
+    closing ``block_until_ready`` alone as ``sync_s``, inside ``scatter_s``."""
+    import numpy as np
+
+    from infinistore_tpu.kv import KVTransferEngine, PagedCacheConfig
+    from infinistore_tpu.kv.cache import StateCacheConfig, init_cache
+    from infinistore_tpu.kv.transfer import LOAD_STAGES, StateTransferEngine
+
+    if path == "state":
+        pc = StateCacheConfig(n_layers=4, n_kv_heads=2, state_dim=8,
+                              head_dim=4, n_blocks=8, stride=16, max_rows=1,
+                              block_tokens=4)
+        tr = StateTransferEngine(py_conn(), pc)
+        S, z = init_cache(pc)
+        cache = (S.at[0].set(1.5), z.at[0].set(2.5))
+        key = f"ckpt-{path}"
+        tr.covers(key, 16)
+        tr.push_pages(tr.gather_pages(cache, 0), [key])
+        S, z = tr.load_pages(cache, [1], [key], tokens=16)
+        assert np.array_equal(S[1], S[0]) and np.array_equal(z[1], z[0])
+        tokens = 16
+    else:
+        pc = PagedCacheConfig(n_layers=4, n_kv_heads=2, head_dim=8,
+                              n_blocks=16, block_tokens=4)
+        tr = KVTransferEngine(py_conn(), pc)
+        cache = init_cache(pc) + 1.0
+        keys = [f"{path}-{i}" for i in range(3)]
+        tr.save_pages(cache, [1, 2, 3], keys)
+        ids = [5, 6, 7]
+        if path == "banded":
+            out = tr.load_pages(init_cache(pc), ids, keys)
+        else:     # layers 2 and 3 need the last two chunks only
+            out = tr.load_pages(
+                init_cache(pc), ids, keys,
+                layer_chunks=[([0, 1], [0, 1, 2], ids), ([2, 3], [1, 2], ids)])
+        assert np.array_equal(out[:2, :, :, 5:8], cache[:2, :, :, 1:4])
+        assert np.array_equal(out[2:, :, :, 6:8], cache[2:, :, :, 2:4])
+        tokens = 12
+    t = tr.load_totals
+    assert t["loads"] == 1 and t["tokens"] == tokens
+    assert all(t[k] > 0 for k in LOAD_STAGES), t
+    assert sum(t[k] for k in LOAD_STAGES) <= t["fetch_s"]
+    assert 0 < t["sync_s"] <= t["scatter_s"]
+
+
+# ---------------------------------------------------------------------------
 # E. the benchmark's new readers
 # ---------------------------------------------------------------------------
 
@@ -531,9 +729,9 @@ def _reader(name):
     return _load(os.path.join(BENCH, "readers", f"{name}.py"))
 
 
-def _summary(steps, phase_s, decode, push):
+def _summary(steps, phase_s, decode, push, load):
     return {"steps": steps, "compiles": 0, "phase_s": phase_s,
-            "decode": decode, "store": {"push": push}}
+            "decode": decode, "store": {"push": push, "load": load}}
 
 
 FULL = {
@@ -550,14 +748,25 @@ FULL = {
         10, {"idle": 5.0, "decode.wait": 10.0, "admit": 1.0, "sched": 0.5},
         {"steps": 320, "row_steps": 400, "live_token_steps": 1000,
          "table_token_steps": 4000},
-        {"tokens": 1000, "submit_to_commit_s": 0.05}),
+        {"pushes": 10, "tokens": 1000, "submit_to_commit_s": 0.05,
+         "queue_s": 0.01, "d2h_s": 0.01, "pool_copy_s": 0.01,
+         "alloc_s": 0.005, "commit_s": 0.005, "wire_s": 0.0},
+        {"tokens": 2000, "desc_s": 0.01, "pool_copy_s": 0.02,
+         "upload_s": 0.01, "sync_s": 0.03}),
     "engine_after": _summary(
         20, {"idle": 6.0, "decode.wait": 23.0, "admit": 1.25, "sched": 0.75,
-             "kv.push_submit": 0.5,        # work: counted
+             # work: counted.  One phase until the gather and push_begin
+             # were cut out of it: the three sum to what it was
+             "kv.push_gather": 0.25, "kv.push_begin": 0.125,
+             "kv.push_submit": 0.125,
              "kv.load": 0.5, "kv.push_wait": 0.3, "probe": 0.1},   # waits
         {"steps": 640, "row_steps": 880, "live_token_steps": 3000,
          "table_token_steps": 12000},
-        {"tokens": 4000, "submit_to_commit_s": 0.5}),
+        {"pushes": 60, "tokens": 4000, "submit_to_commit_s": 0.5,
+         "queue_s": 0.2, "d2h_s": 0.1, "pool_copy_s": 0.06,
+         "alloc_s": 0.04, "commit_s": 0.05, "wire_s": 0.01},
+        {"tokens": 10000, "desc_s": 0.05, "pool_copy_s": 0.1,
+         "upload_s": 0.05, "sync_s": 0.11}),
 }
 # a program that has none of it: the parent commit's rows and summaries
 BARE = {
@@ -573,6 +782,17 @@ WANT = {
     "decode_pad_pct": 75.0,
     "host_ms_per_step": 1e3 * (0.25 + 0.25 + 0.5) / 10,
     "push_ms_per_ktok": 1e3 * 0.5 / 4.0,
+    # the four parts of it, on its own basis (the last scrape) ...
+    "push_queue_ms_per_ktok": 1e3 * 0.2 / 4.0,
+    "push_d2h_ms_per_ktok": 1e3 * 0.1 / 4.0,
+    "push_copy_ms_per_ktok": 1e3 * 0.06 / 4.0,
+    "push_store_ms_per_ktok": 1e3 * (0.04 + 0.05 + 0.01) / 4.0,
+    # ... and the window's gains: two phases over 50 pushes, a load's stages
+    # over 8,000 tokens
+    "push_gather_ms_per_push": 1e3 * 0.25 / 50,
+    "push_begin_ms_per_push": 1e3 * 0.125 / 50,
+    "load_host_ms_per_ktok": 1e3 * (0.04 + 0.08 + 0.04) / 8.0,
+    "load_sync_ms_per_ktok": 1e3 * 0.08 / 8.0,
 }
 
 
@@ -586,6 +806,17 @@ def test_new_reader_finds_nothing_in_a_program_without_its_fields(name):
     assert _reader(name).read(BARE) is None
     assert _reader(name).read(dict(BARE, engine_before=None,
                                    engine_after=None, server_rows=[])) is None
+    # the parent commit's summaries: phases and store totals, but not the
+    # two phases, the queue or a load's stages
+    old = {"phase_s": {"kv.push_submit": 1.0}, "store": {
+        "push": {"pushes": 9, "tokens": 144, "submit_to_commit_s": 0.5,
+                 "d2h_s": 0.1, "pool_copy_s": 0.1, "alloc_s": 0.1,
+                 "commit_s": 0.1, "wire_s": 0.0},
+        "load": {"loads": 2, "tokens": 64, "fetch_s": 0.1, "scatter_s": 0.1}}}
+    if name.startswith(("push_", "load_")) and name != "push_ms_per_ktok":
+        assert _reader(name).read(dict(
+            BARE, engine_before=dict(BARE["engine_before"]),
+            engine_after=dict(BARE["engine_after"], **old))) is None
 
 
 def test_every_new_metric_has_its_file_its_reader_and_its_entry():
@@ -595,13 +826,21 @@ def test_every_new_metric_has_its_file_its_reader_and_its_entry():
            "sched.first_burst_wait_ms", "engine.decode_rows_counted",
            "engine.decode_rows_counted.batch", "engine.decode_pad_pct",
            "engine.decode_pad_pct.batch", "engine.host_ms_per_step",
-           "engine.host_ms_per_step.batch", "kv.push_ms_per_ktok"]
+           "engine.host_ms_per_step.batch", "kv.push_ms_per_ktok",
+           "kv.push_queue_ms_per_ktok", "kv.push_d2h_ms_per_ktok",
+           "kv.push_copy_ms_per_ktok", "kv.push_store_ms_per_ktok",
+           "engine.push_gather_ms_per_push", "engine.push_begin_ms_per_push",
+           "kv.load_host_ms_per_ktok", "kv.load_sync_ms_per_ktok"]
+    # the cells in which a window loads from the store: the two list them
+    listed = {n for n in new if n.startswith("kv.load_")}
     for name in new:
         with open(os.path.join(BENCH, "metrics", f"{name}.json")) as f:
             spec = json.load(f)
         reader = spec.pop("reader")
-        assert spec == entries[name] and "workloads" not in spec
+        assert spec == entries[name]
+        assert ("workloads" in spec) == (name in listed)
         assert callable(_reader(reader).read)
+        assert reader in WANT
     twins = [n for n in new if n.endswith(".batch")]
     for n in twins:       # twins share a reader and move the other metric
         assert entries[n]["moves"] == "out_tok_per_s"

@@ -308,8 +308,9 @@ def test_transfer_records_load_stages(tmp_path):
     # a fresh engine starts with empty stage dicts
     import inspect
 
-    src = inspect.getsource(KVTransferEngine._load_pages_banded)
-    assert "last_load_stages" in src
+    # every load path ends in ``_landed``, which writes the record
+    assert "_landed" in inspect.getsource(KVTransferEngine._load_pages_banded)
+    assert "last_load_stages" in inspect.getsource(KVTransferEngine._landed)
 
 
 # ---------------------------------------------------------------------------
