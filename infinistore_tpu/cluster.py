@@ -1160,11 +1160,12 @@ class ClusterTransferEngine:
 
     # -- push: route per chunk, fan out hot stems, commit concurrently --
 
-    def push_begin(self, pages, chunk_keys_: Sequence[str]):
+    def push_begin(self, bands, chunk_keys_: Sequence[str]):
         """Critical-path half: group chunks by write target (owner +
-        replicas for hot stems), slice the gathered pages per target
-        (device-side, dispatch-only) and kick every group's D2H.
-        Returns the token ``push_commit`` consumes off-thread."""
+        replicas for hot stems), take each target's chunks out of the
+        gathered layer bands (device-side, dispatch-only) and kick every
+        group's D2H.  Returns the token ``push_commit`` consumes
+        off-thread."""
         import jax.numpy as jnp
 
         chunk_keys_ = list(chunk_keys_)
@@ -1173,11 +1174,10 @@ class ClusterTransferEngine:
         for ep, idxs in groups.items():
             sub_keys = [chunk_keys_[i] for i in idxs]
             if len(idxs) == len(chunk_keys_):
-                sub_pages = pages
+                sub_pages = bands
             else:
-                sub_pages = jnp.take(
-                    pages, jnp.asarray(idxs, dtype=jnp.int32), axis=1
-                )
+                ids = jnp.asarray(idxs, dtype=jnp.int32)
+                sub_pages = [jnp.take(p, ids, axis=1) for p in bands]
             token.append(
                 (ep, self._engine(ep).push_begin(sub_pages, sub_keys),
                  len(idxs))
@@ -1242,8 +1242,8 @@ class ClusterTransferEngine:
         self.pool.record_outcome(ep, "ok")
         return written, None, node_stages
 
-    def push_pages(self, pages, chunk_keys_: Sequence[str]) -> int:
-        return self.push_commit(self.push_begin(pages, chunk_keys_))
+    def push_pages(self, bands, chunk_keys_: Sequence[str]) -> int:
+        return self.push_commit(self.push_begin(bands, chunk_keys_))
 
     def save_pages(self, cache, block_ids, chunk_keys_) -> int:
         assert len(block_ids) == len(chunk_keys_)

@@ -253,10 +253,6 @@ def row0(x):                            # [1, S, V] -> [S, V]
     return x[0]
 
 
-def last_row(l, i):                     # dynamic row pick
-    return l[0, i]
-
-
 def argmax_i32(l):
     return jnp.argmax(l, axis=-1).astype(jnp.int32)
 
@@ -277,7 +273,6 @@ _SPLIT2 = jax.jit(split2)
 _STACK_ROWS = jax.jit(stack_rows)
 _UNSTACK_ROWS = jax.jit(unstack_rows)
 _ROW0 = jax.jit(row0)
-_LAST_ROW = jax.jit(last_row)
 _ARGMAX_I32 = jax.jit(argmax_i32)
 _Q_COL0 = jax.jit(q_col0)
 _SPLIT3 = jax.jit(split3)
@@ -428,10 +423,12 @@ class _StoreStreamer:
             self._started = True
         # the critical-path half runs HERE, on the submitting thread
         # (phase ``kv.push_begin``, in whatever phase the caller stands):
-        # push_begin only slices the gathered snapshot into bands and
-        # kicks their D2H DMAs (dispatch-only), so the prefill thread
-        # pays microseconds while the transfers overlap the next chunk's
-        # compute; everything that can block — materialize, pool copy,
+        # the bands came out of the gather's one program already cut
+        # (transfer.gather_pages, phase ``kv.push_gather``: the one launch
+        # a push costs this thread), so push_begin only kicks their D2H
+        # DMAs (dispatch-only: a few tenths of a millisecond for the four
+        # calls) while the transfers overlap the next chunk's compute;
+        # everything that can block — materialize, pool copy,
         # COMMIT_PUT — happens in push_commit on the worker.  The
         # submitting request's trace id rides along: the scheduler binds
         # the request trace around prefill work, so the worker thread can
@@ -673,7 +670,6 @@ class PartialPrefill:
     plen: int            # valid prefix length inside buf
     S: int               # unpadded suffix length
     off: int = 0         # next chunk offset into padded
-    off_last: int = 0
     logits: Optional[jax.Array] = None
     adapter_id: int = 0  # LoRA adapter slot (0 = base model)
     # the window pool's table (SequenceState.window_ids / window_reclaimed)
@@ -967,11 +963,25 @@ class InferenceEngine:
                     "must thread lora/adapter_ids through their own forwards"
                 )
             lora_kw = {"lora_scale": lora.scale}
-        self._prefill_jit = _shared_jit(
-            prefill_fn or prefill_forward,
-            {"cfg": self.cfg, **lora_kw},
-            donate=self.prefill_donates,
-        )
+        import inspect as _inspect
+
+        _pfn = prefill_fn or prefill_forward
+
+        def _prefill_form(**head):
+            return _shared_jit(_pfn, {"cfg": self.cfg, **head, **lora_kw},
+                               donate=self.prefill_donates)
+
+        # the whole form: logits of every position (``prompt_logprobs``,
+        # and every chunk of a custom family that has no other)
+        self._prefill_jit = _prefill_form()
+        # the forms a served prompt runs (``_prefill``): the output head on
+        # the one row a prompt's last chunk keeps, and no head at all for a
+        # chunk that another follows (models/llama.py head_logits).  A custom
+        # family opts in by taking the keyword, as for ``last_only`` below
+        self._prefill_heads = "head" in _inspect.signature(_pfn).parameters
+        if self._prefill_heads:
+            self._prefill_row_jit = _prefill_form(head="row")
+            self._prefill_nohead_jit = _prefill_form(head="none")
         self._decode_raw = _shared_partial(
             decode_fn or decode_forward,
             {"cfg": self.cfg, **lora_kw},
@@ -996,8 +1006,6 @@ class InferenceEngine:
         # families opt in by accepting the kwarg; otherwise the full
         # verify serves both roles (correct either way — callers of the
         # last-only form read logits[:, -1]).
-        import inspect as _inspect
-
         _vfn = verify_fn or (
             verify_forward if self._has_verify else None
         )
@@ -1026,6 +1034,31 @@ class InferenceEngine:
         self._rng = jax.random.PRNGKey(0)
         # in-place append into the bucketed chunked-prefill KV buffer
         self._kv_append = _KV_APPEND
+
+    def _prefill(self, head_row: Optional[Sequence[int]], **kw):
+        """The prefill program in the form that computes what its caller
+        keeps: ``(rows, kv)``.  ``head_row`` None: no logits are kept (a
+        chunk that another follows), the program runs no output head and
+        ``rows`` is None.  Else one position a row of the batch, its first
+        ``len(head_row)`` rows: the head runs on those positions alone and
+        ``rows`` is their logits, ``len(head_row)`` x [V].  A custom family
+        that has the whole form only runs that, and the rows are picked out
+        of it.  Counted here, a launch of the program a count
+        (``summary.prefill``: ``chunks``, and ``head_chunks``, those that
+        ran a head)."""
+        _stepprof.note_prefill_chunk(
+            head=head_row is not None or not self._prefill_heads)
+        if head_row is not None:    # goes in with the call, as numpy
+            head_row = np.asarray(head_row, dtype=np.int32)
+        if not self._prefill_heads:
+            logits, kv = self._prefill_jit(self.params, **kw)
+            return (None if head_row is None
+                    else _PICK_LAST(logits, head_row)), kv
+        if head_row is None:
+            return self._prefill_nohead_jit(self.params, **kw)
+        logits, kv = self._prefill_row_jit(self.params, head_row=head_row,
+                                           **kw)
+        return _UNSTACK_ROWS(logits), kv
 
     def _lora_args(self, adapter_ids) -> Dict[str, Any]:
         """Per-dispatch LoRA kwargs: the bank tree + a per-row adapter-id
@@ -1395,18 +1428,17 @@ class InferenceEngine:
         off, C = pp.off, pp.C
         chunk = pp.padded[off : off + C]
         arr = jnp.asarray(chunk, dtype=jnp.int32)[None]
-        lkw = self._lora_args([pp.adapter_id])
-        if pp.buf is None:
-            pp.logits, kv = self._prefill_jit(self.params, tokens=arr, **lkw)
-        elif pp.single:
-            pp.logits, kv = self._prefill_jit(
-                self.params, tokens=arr, prefix_kv=pp.buf, **lkw
-            )
-        else:
-            pp.logits, kv = self._prefill_jit(
-                self.params, tokens=arr, prefix_kv=pp.buf,
-                prefix_len=jnp.asarray(pp.plen, dtype=jnp.int32), **lkw
-            )
+        kw = self._lora_args([pp.adapter_id])
+        if pp.buf is not None:
+            kw["prefix_kv"] = pp.buf
+            if not pp.single:
+                kw["prefix_len"] = jnp.asarray(pp.plen, dtype=jnp.int32)
+        # the head where a row is kept: the prompt's last position, in its
+        # last chunk; a chunk that another follows keeps none
+        last = off + C >= len(pp.padded)
+        rows, kv = self._prefill(
+            [(pp.S - 1) - off] if last else None, tokens=arr, **kw)
+        pp.logits = rows[0] if last else None
         # the chunk forward + its cache landing = one prefill dispatch
         # unit for the step profiler's attribution
         _stepprof.note_dispatch("prefill")
@@ -1426,7 +1458,6 @@ class InferenceEngine:
                 kv, T, self._pool_layers,
             )
         prev_done, pp.done = pp.done, pp.done + n_pg
-        pp.off_last = off
         # stream this chunk's complete pages to the store NOW — the
         # background pusher moves them D2H and into the pool while the
         # next chunk's forward runs on device (reference design.rst's
@@ -1434,9 +1465,11 @@ class InferenceEngine:
         if self.transfer is not None:
             lo, hi = max(prev_done, pp.reused), min(pp.done, pp.n_complete)
             if hi > lo:
-                # the gather's launch, then the submit: push_begin (its own
-                # phase, kv.push_begin) and the bounded queue's put (where
-                # it blocks, two chunks already waiting, it is kv.push_wait)
+                # the push's one launch (gather, wire layout and layer
+                # bands are one program), then the submit: push_begin (its
+                # own phase, kv.push_begin: the bands' D2H kicks) and the
+                # bounded queue's put (where it blocks, two chunks already
+                # waiting, it is kv.push_wait)
                 with _stepprof.phase("kv.push_gather"):
                     pages = self.transfer.gather_pages(
                         self.cache,
@@ -1466,11 +1499,9 @@ class InferenceEngine:
                 )
             pp.plen = need
         else:
-            # finished: a prefill that waits to be settled holds neither
-            # its prefix buffer nor a chunk's logits, only the row the
-            # decode starts from
+            # finished: a prefill that waits to be settled holds no prefix
+            # buffer, only the row of logits the decode starts from
             pp.buf = None
-            pp.logits = _LAST_ROW(pp.logits, (pp.S - 1) - pp.off_last)
 
     def _make_visible(self, pp: "PartialPrefill") -> SequenceState:
         """A finished prefill's decode-ready state.  Under strict durability
@@ -1704,8 +1735,8 @@ class InferenceEngine:
             tokens[b, : len(p)] = p
         lkw = self._lora_args(aids + [0] * (Bp - B)) if self.lora else {}
         _stepprof.note_dispatch("prefill")  # one padded group forward
-        logits, kv = self._prefill_jit(
-            self.params, tokens=jnp.asarray(tokens), **lkw
+        last_rows, kv = self._prefill(
+            [len(p) - 1 for p in group], tokens=jnp.asarray(tokens), **lkw
         )
         full = bucket // T
         sel = np.concatenate([
@@ -1713,9 +1744,6 @@ class InferenceEngine:
         ]).astype(np.int32)
         self.cache = _write_group_pages(
             self.cache, jnp.asarray(ids_all), kv, jnp.asarray(sel), T
-        )
-        last_rows = _PICK_LAST(
-            logits, jnp.asarray([len(p) - 1 for p in group], jnp.int32)
         )
         states = []
         off = 0

@@ -51,7 +51,6 @@ from ..kv.transfer import StateTransferEngine
 from ..utils import metrics as _metrics
 from . import stepprof as _stepprof
 from .engine import (
-    _LAST_ROW,
     _PREFIX_TOKENS,
     _PREFIX_TOKENS_TENANT,
     InferenceEngine,
@@ -254,18 +253,20 @@ class StateEngine(InferenceEngine):
         off, C = pp.off, pp.C
         chunk = pp.padded[off: off + C]
         start = pp.plen + off
-        pp.logits, self.cache = self._prefill_jit(
-            self.params, tokens=jnp.asarray(chunk, dtype=jnp.int32)[None],
+        # the head on the prompt's last position alone, in its last chunk
+        last = off + C >= len(pp.padded)
+        rows, self.cache = self._prefill(
+            [(pp.S - 1) - off] if last else None,
+            tokens=jnp.asarray(chunk, dtype=jnp.int32)[None],
             cache=self.cache, slot=jnp.asarray(pp.slot, jnp.int32),
             start=jnp.asarray(start, jnp.int32),
             n_valid=jnp.asarray(min(len(chunk), pp.S - off), jnp.int32))
+        pp.logits = rows[0] if last else None
         _stepprof.note_dispatch("prefill")
-        pp.off_last, pp.off = off, off + C
+        pp.off = off + C
         pp.done = (start + len(chunk)) // self.pc.block_tokens
         if pp.ckpt_at and start + len(chunk) == pp.ckpt_at:
             self._checkpoint(pp)
-        if pp.finished:     # kept until it is settled: one row, not a chunk's
-            pp.logits = _LAST_ROW(pp.logits, (pp.S - 1) - pp.off_last)
 
     def _make_visible(self, pp: PartialPrefill) -> SequenceState:
         """The row's slot handed over to a decode-ready state (under strict

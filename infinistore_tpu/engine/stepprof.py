@@ -55,7 +55,9 @@ structured record per scheduler step:
   ``note_prefill_budget`` records, at the scheduler's call, the prefill
   chunk tokens a step was granted and the ones it spent; ``note_push_wait``
   tells the two causes of the ``kv.push_wait`` phase apart (the streamer's
-  full queue, and strict durability's wait for acknowledgements).
+  full queue, and strict durability's wait for acknowledgements);
+  ``note_prefill_chunk`` counts the prefill chunks run and those whose
+  program ran an output head.
 * **stages of other threads** — ``stage(name)`` is the same bracket for a
   thread that is not the engine's: an annotation on that thread's line of
   the profiler's trace and its seconds, which the KV transfer sums into
@@ -105,11 +107,13 @@ DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
                  "table_token_steps", "attn_kernel_steps", "expert_pairs",
                  "experts_expected", "expert_pairs_local")
 
-# what ``note_prefill_budget`` and ``note_push_wait`` sum, per step record
+# what ``note_prefill_budget``, ``note_push_wait`` and ``note_prefill_chunk``
+# sum, per step record
 # and over the lifetime
 PREFILL_COUNTS = ("granted_tokens", "spent_tokens",
                   "settle_waits", "settled_prompts", "settle_wait_s",
-                  "push_queue_full_waits", "push_queue_full_s")
+                  "push_queue_full_waits", "push_queue_full_s",
+                  "chunks", "head_chunks")
 
 # what ``note_kv_pages`` sums, per step record and over the lifetime
 KV_COUNTS = ("store_pages_full", "store_pages_window",
@@ -373,6 +377,18 @@ def note_push_wait(**counts: float) -> None:
     _sum_into("prefill", PREFILL_COUNTS, counts)
 
 
+def note_prefill_chunk(head: bool) -> None:
+    """Count ONE prefill chunk at the engine's launch of its program
+    (``InferenceEngine._prefill``: a chunk of a chunked prefill, of either
+    engine; a padded group's one forward): ``chunks`` run, and
+    ``head_chunks``, those whose program ran an output head (a prompt's last
+    chunk, on the one row it keeps; every chunk of a custom family that has
+    the whole form only).  ``head_chunks / chunks`` is one over the chunks a
+    prompt computes.  Summed under ``rec["prefill"]``."""
+    _sum_into("prefill", PREFILL_COUNTS,
+              {"chunks": 1, "head_chunks": int(head)})
+
+
 def enter(name: Optional[str]) -> float:
     """``StepProfiler.enter`` on the profiler driving this thread's step,
     and the switch's clock stamp: two of them time a site once.  A plain
@@ -541,7 +557,7 @@ class StepProfiler:
         # lifetime sums of the decode dispatches' counts (note_decode)
         self._decode_totals = dict.fromkeys(DECODE_COUNTS, 0)
         # lifetime sums of the steps' prefill budgets and push waits
-        # (note_prefill_budget, note_push_wait)
+        # (note_prefill_budget, note_push_wait, note_prefill_chunk)
         self._prefill_totals = dict.fromkeys(PREFILL_COUNTS, 0)
         self._kv_totals = dict.fromkeys(KV_COUNTS, 0)
         self._state_totals = dict.fromkeys(STATE_COUNTS, 0)
@@ -960,8 +976,9 @@ class StepProfiler:
             "phase_wall_s": round(phase_wall, 6),
             # the decode dispatches' counts, summed (note_decode)
             "decode": decode,
-            # the steps' prefill token budgets and what their push waits
-            # were for, summed (note_prefill_budget, note_push_wait)
+            # the steps' prefill token budgets, what their push waits were
+            # for and their chunks with and without an output head, summed
+            # (note_prefill_budget, note_push_wait, note_prefill_chunk)
             "prefill": prefill,
             # adopted store prefixes' pages by layer kind (note_kv_pages)
             "kv": kv,

@@ -3,9 +3,9 @@
 The reference moves KV between GPU memory and the store pool with GPUDirect
 RDMA against ``tensor.data_ptr()`` offsets (reference: infinistore/lib.py:425-
 542, benchmark.py:163-247).  On a TPU-VM the device side is a ``jax.Array``
-in HBM, so the path is: one fused gather on device -> a single device-to-host
-transfer -> zero-copy batched put straight from that host array into the
-store's shm pool (one host copy total; the mirror image for reads lands in a
+in HBM, so the path is: one fused gather on device -> a device-to-host
+transfer a layer band -> zero-copy batched put straight from that host array
+into the store's shm pool (one host copy total; the mirror image for reads lands in a
 reusable staging buffer — the "registered MR": allocated once, registered
 with the connection, reused).
 
@@ -69,14 +69,35 @@ def _scatter_layers(cache: jax.Array, layer_ids: jax.Array,
     return cache.at[layer_ids[:, None], :, :, block_ids[None, :]].set(stacked)
 
 
-@partial(jax.jit, static_argnums=(2,))
-def _gather_by_pool(caches, block_ids, order):
-    """``read_pages`` over a cache of one pool a layer kind: each pool's
-    pages of the same chunks under its own ids, the layers back in stack
-    order (``order``: ``PagedCacheConfig.stack_order``)."""
-    return jnp.concatenate(
-        [read_pages(c, ids) for c, ids in zip(caches, block_ids)],
-        axis=0)[np.asarray(order)]
+def _band_ranges(L: int, groups: int) -> List[Tuple[int, int]]:
+    """``L`` layers as at most ``groups`` bands ``(first layer, layers)``:
+    the unit a push materializes and writes, and a load reads and uploads."""
+    Lg = -(-L // max(1, min(groups, L)))
+    return [(l0, min(Lg, L - l0)) for l0 in range(0, L, Lg)]
+
+
+@partial(jax.jit, static_argnums=(2, 3, 4))
+def _gather_bands(cache, block_ids, order, quant, groups):
+    """The device half of a push as ONE program: ``block_ids``'s pages of
+    every layer gathered (``read_pages``), laid out as the store holds them,
+    [L, n, planes, H, T, D] with each (layer, chunk) page contiguous,
+    quantized and packed where ``quant`` (the packed rows ARE the wire
+    pages, half the bytes to move), and cut into ``groups`` layer bands.
+    A cache of one pool a layer kind (a tuple) takes one id array a pool,
+    the same chunks in each, and ``order`` (``PagedCacheConfig.
+    stack_order``) puts the layers back in stack order.  Eager, each of
+    these was a launch of its own, and a band's slice the costliest."""
+    if isinstance(cache, tuple):
+        gathered = jnp.concatenate(
+            [read_pages(c, ids) for c, ids in zip(cache, block_ids)],
+            axis=0)[np.asarray(order)]
+    else:
+        gathered = read_pages(cache, block_ids)  # [L, planes, H, n, T, D]
+    pages = jnp.transpose(gathered, (0, 3, 1, 2, 4, 5))
+    if quant:
+        pages = quantize_pages(pages)  # [L, n, wire_page_bytes] uint8
+    return tuple(pages[l0 : l0 + n]
+                 for l0, n in _band_ranges(pages.shape[0], groups))
 
 
 class KVTransferEngine:
@@ -284,28 +305,24 @@ class KVTransferEngine:
             k for k, _ in self._page_blocks(chunk_keys_, 0, self.cfg.n_layers)
         ]
 
-    def gather_pages(self, cache, block_ids) -> jax.Array:
-        """Device-side half of a save: fused gather (+ transpose, + int8
-        quantize) of ``block_ids``'s pages — dispatch-only, returns a small
-        device array [L, n, ...] so a caller can snapshot pages mid-prefill
-        (jax arrays are immutable) and hand them to a background pusher
-        while the next chunk computes.  A cache of one pool a layer kind
+    def gather_pages(self, cache, block_ids) -> Tuple[jax.Array, ...]:
+        """Device-side half of a save, ONE launch (``_gather_bands``): the
+        gather of ``block_ids``'s pages, the store's layout, the int8
+        quantize where ``self.quant`` and the cut into ``pipeline_groups``
+        layer bands.  Returns the bands, small device arrays [l, n, ...]:
+        a snapshot (jax arrays are immutable; the program is enqueued
+        behind the writes of the pages it reads), so a caller can hand
+        them to a background pusher while the next chunk computes and the
+        cache's pages are written again.  A cache of one pool a layer kind
         (a tuple, ``cfg.pools``) takes one id list a pool, the same chunks
         in each; what is pushed is every layer's page, in stack order."""
         if isinstance(cache, tuple):
-            gathered = _gather_by_pool(
-                cache, tuple(jnp.asarray(np.asarray(ids, dtype=np.int32))
-                             for ids in block_ids), self.cfg.stack_order)
+            ids = tuple(np.asarray(i, dtype=np.int32) for i in block_ids)
+            order = self.cfg.stack_order
         else:
-            ids = jnp.asarray(np.asarray(block_ids, dtype=np.int32))
-            gathered = read_pages(cache, ids)  # [L, planes, H, n, T, D]
-        # -> [L, n, planes, H, T, D]: each (layer, chunk) page contiguous
-        pages = jnp.transpose(gathered, (0, 3, 1, 2, 4, 5))
-        if self.quant:
-            # fuse quantize+pack on device; the D2H then moves half the
-            # bytes (the packed rows ARE the wire pages)
-            pages = quantize_pages(pages)  # [L, n, wire_page_bytes] uint8
-        return pages
+            ids, order = np.asarray(block_ids, dtype=np.int32), None
+        return _gather_bands(cache, ids, order, bool(self.quant),
+                             self.pipeline_groups)
 
     @staticmethod
     def _band_host(p: jax.Array):
@@ -341,19 +358,16 @@ class KVTransferEngine:
 
         return fill
 
-    def push_begin(self, pages: jax.Array, chunk_keys_: Sequence[str]):
-        """Critical-path half of a push: slice the gathered pages into
-        layer bands and KICK every band's device→host DMA
-        (``copy_to_host_async`` is dispatch-only) — the only store work
-        the prefill thread pays for.  Returns an opaque token for
-        ``push_commit``, the streamer-thread half."""
-        L = self.cfg.n_layers
-        G = max(1, min(self.pipeline_groups, L))
-        Lg = -(-L // G)
-        parts = [pages[l0 : l0 + Lg] for l0 in range(0, L, Lg)]
-        for p in parts:
+    def push_begin(self, bands: Sequence[jax.Array],
+                   chunk_keys_: Sequence[str]):
+        """Critical-path half of a push: KICK the device→host DMA of every
+        band ``gather_pages`` returned (``copy_to_host_async`` is
+        dispatch-only) and stamp the time: the only store work the prefill
+        thread pays for besides the gather's one launch.  Returns an opaque
+        token for ``push_commit``, the streamer-thread half."""
+        for p in bands:
             p.copy_to_host_async()
-        return parts, list(chunk_keys_), time.perf_counter()
+        return list(bands), list(chunk_keys_), time.perf_counter()
 
     def push_commit(self, token) -> int:
         """Off-critical-path half of a push: materialize each band —
@@ -449,14 +463,15 @@ class KVTransferEngine:
             total += host.nbytes
         return total
 
-    def push_pages(self, pages: jax.Array, chunk_keys_: Sequence[str]) -> int:
-        """Host-side half of a save: move gathered pages D2H and put
+    def push_pages(self, bands: Sequence[jax.Array],
+                   chunk_keys_: Sequence[str]) -> int:
+        """Host-side half of a save: move the gathered bands D2H and put
         them into the store — ``push_begin`` (kick every band's D2H)
         followed immediately by ``push_commit`` (materialize + commit).
         Callers that can afford to defer the commit half off their
         critical path (the engine's ``_StoreStreamer``) call the two
         halves separately."""
-        return self.push_commit(self.push_begin(pages, chunk_keys_))
+        return self.push_commit(self.push_begin(bands, chunk_keys_))
 
     def save_pages(
         self, cache: jax.Array, block_ids: Sequence[int], chunk_keys_: Sequence[str]
@@ -598,16 +613,13 @@ class KVTransferEngine:
         L = len(layers)
         nbytes = L * n * pb
         staging = self._ensure_staging(nbytes)
-        G = max(1, min(self.pipeline_groups, L))
-        Lg = -(-L // G)
         bands = []
         meta = []  # (staging offset, span, n_layers) per band
-        for l0 in range(0, L, Lg):
-            l1 = min(l0 + Lg, L)
-            blocks = self._layer_blocks(chunk_keys_, layers[l0:l1])
+        for l0, nl in _band_ranges(L, self.pipeline_groups):
+            blocks = self._layer_blocks(chunk_keys_, layers[l0 : l0 + nl])
             off = l0 * n * pb
             bands.append((blocks, pb, staging.ctypes.data + off))
-            meta.append((off, (l1 - l0) * n * pb, l1 - l0))
+            meta.append((off, nl * n * pb, nl))
         devs: list = [None] * len(bands)
 
         def upload(i: int) -> None:
@@ -815,13 +827,20 @@ class KVTransferEngine:
 # -- a cache whose unit is a state (kv/cache.py StateCacheConfig) --
 
 
-@jax.jit
-def _state_to_wire(S: jax.Array, z: jax.Array, slot: jax.Array) -> jax.Array:
+@partial(jax.jit, static_argnums=(3,))
+def _state_to_wire(S: jax.Array, z: jax.Array, slot: jax.Array, groups: int):
     """Slot ``slot`` in store layout ``[L, 1, H, F * (D + 1)]``: one layer's
-    state a page, ``S`` then ``z`` by head."""
-    s = S[slot]
-    L, H, F, D = s.shape
-    return jnp.concatenate([s.reshape(L, H, F * D), z[slot]], axis=-1)[:, None]
+    state a page, ``S`` then ``z`` by head; as ``groups`` layer bands, each
+    laid out straight from its layers of the slot (``_gather_bands``'s twin:
+    one program, and no whole slot is formed beside its bands)."""
+    L, H, F, D = S.shape[1:]
+    bands = []
+    for l0, n in _band_ranges(L, groups):
+        s = jax.lax.dynamic_slice(S, (slot, l0, 0, 0, 0), (1, n, H, F, D))
+        zs = jax.lax.dynamic_slice(z, (slot, l0, 0, 0), (1, n, H, F))
+        bands.append(jnp.concatenate(
+            [s.reshape(n, H, F * D), zs[0]], axis=-1)[:, None])
+    return tuple(bands)
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
@@ -868,8 +887,8 @@ class StateTransferEngine(KVTransferEngine):
     def _tokens_of(self, chunk_keys_: Sequence[str]) -> int:
         return sum(self._covers.pop(k, 0) for k in chunk_keys_)
 
-    def gather_pages(self, cache, slot: int) -> jax.Array:
-        return _state_to_wire(*cache, jnp.asarray(slot, jnp.int32))
+    def gather_pages(self, cache, slot: int) -> Tuple[jax.Array, ...]:
+        return _state_to_wire(*cache, np.int32(slot), self.pipeline_groups)
 
     def load_pages(self, cache, block_ids: Sequence[int],
                    chunk_keys_: Sequence[str], tokens: int = 0):

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, replace
+from functools import partial
 from typing import Any, Optional, Tuple
 
 import jax
@@ -51,7 +52,7 @@ from .attention import (
     paged_decode_attention,
     paged_window_decode_attention,
 )
-from .llama import Params, _mlp
+from .llama import Params, _mlp, head_logits
 from .moe import routed_experts
 
 
@@ -329,7 +330,9 @@ def cohere2_moe_prefill_forward(
     tokens: jax.Array,
     prefix_kv: jax.Array | None = None,
     prefix_len: jax.Array | None = None,
-) -> Tuple[jax.Array, jax.Array]:
+    head: str = "all",
+    head_row: jax.Array | None = None,
+) -> Tuple[jax.Array | None, jax.Array]:
     """tokens [B, S] -> (logits [B, S, V held], kv [L, 2, B, S, H_kv, D]).
 
     The contract of ``models.llama.prefill_forward``: ``prefix_kv`` [L, 2,
@@ -338,7 +341,8 @@ def cohere2_moe_prefill_forward(
     tokens.  A full layer attends to the whole buffer; a window layer to the
     ``sliding_window`` rows that end at the prefix's end, SLICED out of the
     buffer (one static width), then to the chunk's own: rows of the buffer
-    below the slice are not read."""
+    below the slice are not read.  ``head`` / ``head_row``: where the norm
+    and the head run, as there (``llama.head_logits``)."""
     B, S = tokens.shape
     P = 0 if prefix_kv is None else prefix_kv.shape[3]
     start = P if prefix_len is None else prefix_len
@@ -374,7 +378,8 @@ def cohere2_moe_prefill_forward(
                                            window)
         ffn, _ = expert_layer(layer, cfg, h)
         x = x + attn.reshape(B, S, -1) @ layer["wo"] + ffn
-    return _head(params, cfg, x), jnp.stack(kvs)
+    return head_logits(x, head, head_row, partial(_head, params, cfg)
+                       ), jnp.stack(kvs)
 
 
 def cohere2_moe_decode_forward(

@@ -282,6 +282,24 @@ def _final_logits(params: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     return logits
 
 
+def head_logits(x: jax.Array, head: str, head_row, project):
+    """A prefill's logits where a row of them is kept: ``project`` (a
+    family's final norm and output head) over what ``head`` names of the
+    last hidden states ``x`` [B, S, D].  ``head`` is STATIC: ``"all"``, every
+    position (logits [B, S, V]); ``"row"``, one position a row, ``x[b,
+    head_row[b]]`` for the ``n <= B`` rows that the TRACED int32 ``head_row``
+    [n] names (logits [n, V]: a prompt's last chunk keeps one row, the
+    others would be thrown away); ``"none"``, nothing (``None``: a chunk
+    that another follows runs no norm and no head)."""
+    if head == "none":
+        return None
+    if head == "row":
+        x = x[jnp.arange(head_row.shape[0]), head_row]
+    elif head != "all":
+        raise ValueError(f"prefill head {head!r}: 'all', 'row' or 'none'")
+    return project(x)
+
+
 def _lora_term(x, lora, name, ids, scale):
     """Batched adapter delta for one projection (models/lora.py), or 0."""
     if lora is None or name not in lora:
@@ -352,8 +370,16 @@ def prefill_forward(
     lora=None,
     adapter_ids: jax.Array | None = None,
     lora_scale: float = 1.0,
-) -> Tuple[jax.Array, jax.Array]:
+    head: str = "all",
+    head_row: jax.Array | None = None,
+) -> Tuple[jax.Array | None, jax.Array]:
     """tokens: [B, S] -> (logits [B, S, V], kv [L, 2, B, S, Hkv, D]).
+
+    ``head`` (static) says where the final norm and the output head run
+    (``head_logits``): ``"all"`` positions, the default and the form above;
+    ``"row"``, at ``head_row`` alone (traced int32 [n]: logits [n, V], the
+    same norm and head over one position a row); ``"none"``, nowhere
+    (logits ``None``).  The KV is the same in all three.
 
     ``prefix_kv`` ([L, 2, B, P, Hkv, D], RoPE already applied) enables
     chunked prefill on top of a reused prefix: ``tokens`` are positions
@@ -407,8 +433,10 @@ def prefill_forward(
         if cfg.post_norms:
             m = _norm(cfg, m, layer["ln_post_mlp"])
         x = x + m
-    x = _norm(cfg, x, params["ln_out"])
-    return _final_logits(params, cfg, x), jnp.stack(kvs)
+    return head_logits(
+        x, head, head_row,
+        lambda x: _final_logits(params, cfg, _norm(cfg, x, params["ln_out"])),
+    ), jnp.stack(kvs)
 
 
 def decode_forward(

@@ -44,7 +44,7 @@ from .attention import (
     latent_absorbed_decode_attention,
     latent_expanded_attention,
 )
-from .llama import Params, _mlp, rmsnorm
+from .llama import Params, _mlp, head_logits, rmsnorm
 from .moe import routed_experts, sigmoid_top_k
 
 
@@ -287,7 +287,9 @@ def mla_moe_prefill_forward(
     tokens: jax.Array,
     prefix_kv: jax.Array | None = None,
     prefix_len: jax.Array | None = None,
-) -> Tuple[jax.Array, jax.Array]:
+    head: str = "all",
+    head_row: jax.Array | None = None,
+) -> Tuple[jax.Array | None, jax.Array]:
     """tokens [B, S] -> (logits [B, S, V], rows [L, 1, B, S, 1, rank+rope]).
 
     The contract of ``models.llama.prefill_forward`` with the page's one
@@ -295,7 +297,8 @@ def mla_moe_prefill_forward(
     reused prefix's rows (a padded buffer of which ``prefix_len`` are valid),
     the returned rows cover the new tokens only.  Attention runs EXPANDED:
     the prefix's and the chunk's rows are up-projected to keys and values
-    by head (attention.latent_expanded_attention)."""
+    by head (attention.latent_expanded_attention).  ``head`` / ``head_row``:
+    where the norm and the head run, as there (``llama.head_logits``)."""
     B, S = tokens.shape
     P = 0 if prefix_kv is None else prefix_kv.shape[3]
     start = P if prefix_len is None else prefix_len
@@ -315,8 +318,10 @@ def mla_moe_prefill_forward(
         x = x + attn.reshape(B, S, -1) @ layer["wo"]
         h = rmsnorm(x, layer["ln_mlp"], cfg.norm_eps)
         x = x + _ffn(layer, cfg, h)
-    x = rmsnorm(x, params["ln_out"], cfg.norm_eps)
-    return x @ params["lm_head"], jnp.stack(rows)
+    return head_logits(
+        x, head, head_row,
+        lambda x: rmsnorm(x, params["ln_out"], cfg.norm_eps)
+        @ params["lm_head"]), jnp.stack(rows)
 
 
 def mla_moe_decode_forward(

@@ -38,13 +38,14 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, replace
+from functools import partial
 from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .llama import Params, _attn_qkv, _layer, _mlp, rmsnorm
+from .llama import Params, _attn_qkv, _layer, _mlp, head_logits, rmsnorm
 
 
 @dataclass(frozen=True)
@@ -354,13 +355,17 @@ def retention_prefill_forward(
     slot: jax.Array,
     start: jax.Array,
     n_valid: jax.Array,
-) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    head: str = "all",
+    head_row: jax.Array | None = None,
+) -> Tuple[jax.Array | None, Tuple[jax.Array, jax.Array]]:
     """One prefill chunk of one row.  tokens [1, C] at positions ``start ..``
     of which the first ``n_valid`` are the prompt's (the rest pad a last
     chunk to whole pages); ``cache`` the state slots ``(S [slots, L, H_kv,
     F, D], z [slots, L, H_kv, F])``, donated; ``slot`` the row's.  The
     chunk starts from the slot's state and leaves the state after its last
-    valid token there.  Returns (logits [1, C, V], cache)."""
+    valid token there.  Returns (logits [1, C, V], cache); ``head`` /
+    ``head_row``: where the norm and the head run, as in
+    ``llama.prefill_forward`` (``llama.head_logits``)."""
     S_all, z_all = cache
     C = tokens.shape[1]
     G = cfg.n_heads // cfg.n_kv_heads
@@ -394,7 +399,8 @@ def retention_prefill_forward(
     (x, S_all, z_all), _ = jax.lax.scan(
         one_layer, (x, S_all, z_all),
         (jnp.arange(cfg.n_layers), params["layers"]))
-    return _head(params, cfg, x), (S_all, z_all)
+    return head_logits(x, head, head_row, partial(_head, params, cfg)
+                       ), (S_all, z_all)
 
 
 def retention_decode_forward(

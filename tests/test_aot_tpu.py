@@ -448,3 +448,85 @@ def test_retention_decode_scan_on_tpu_moves_each_rows_state_in_place(v5e):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pc.cache_bytes, mem
     assert mem.temp_size_in_bytes < 5 * pc.page_bytes * 2, mem
+
+
+# the largest push of each cell: a 512-token chunk's 32 pages of every layer
+# (the cells' caches as BENCHMARK.json's configurations size them), and
+# what the TPU compiler says the one program keeps live beside its bands
+_PUSH_CELLS = {
+    # cell: (pools of (layers, planes, heads, blocks, width), stack order,
+    #        temporaries allowed, bytes of the bands)
+    "qwen2.5-7b-l12": ([(12, 2, 4, 12288, 128)], None, 1 << 20, 12582912),
+    "qwen3-8b-l12": ([(12, 2, 8, 6144, 128)], None, 1 << 20, 25165824),
+    # the full layer's pool, then the three window layers'
+    "command-a-plus-l4-e16": ([(1, 2, 8, 10240, 128), (3, 2, 8, 8192, 128)],
+                              (1, 2, 3, 0), 1 << 20, 8388608),
+    # the latent page: ONE copy of the whole cache, 1.51 GB padded to
+    # [16, 640]-wide tiles, which the bare gather by ids has too (below)
+    "kanana-2-30b-a3b-l8": ([(8, 1, 1, 10240, 576)], None, 1700 << 20,
+                            5242880),
+}
+
+
+def _whole_cache_results(text, shape):
+    want = "bf16[%s]" % ",".join(map(str, shape))
+    return [f"{name} = {s} {op}" for name, s, op in _INSTRUCTION.findall(text)
+            if s == want
+            and op not in ("parameter", "bitcast", "get-tuple-element")]
+
+
+@pytest.mark.parametrize("cell", list(_PUSH_CELLS))
+def test_push_program_on_tpu_adds_no_copy_of_the_cache(cell, v5e):
+    """The push's one program (gather by ids, the store's layout, the layer
+    bands: ``kv/transfer.py:_gather_bands``) at each cell's cache and a
+    512-token chunk's 32 pages: its temporaries, and that no instruction's
+    result is a whole pool of the cache, but for the latent cache, where the
+    gather by ids alone (the program the eager path launched first,
+    ``jit_gather``: 5 ms a push on the chip) already re-lays the cache out
+    once (its default layout puts the blocks innermost, PERF.md section 5):
+    the one program has that ONE copy and adds none."""
+    from infinistore_tpu.kv import read_pages
+    from infinistore_tpu.kv.transfer import _gather_bands
+
+    pools, order, temp_limit, band_bytes = _PUSH_CELLS[cell]
+    chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    shapes = [(n, planes, heads, blocks, T, width)
+              for n, planes, heads, blocks, width in pools]
+    caches = tuple(sds(s, jnp.bfloat16) for s in shapes)
+    ids = tuple(sds((32,), jnp.int32) for _ in shapes)
+    if order is None:
+        caches, ids = caches[0], ids[0]
+    compiled = _gather_bands.lower(caches, ids, order, False, 4).compile()
+    mem = compiled.memory_analysis()
+    assert band_bytes <= mem.output_size_in_bytes < band_bytes + 4096, mem
+    assert mem.temp_size_in_bytes < temp_limit, mem
+    text = compiled.as_text()
+    copies = [c for s in shapes for c in _whole_cache_results(text, s)]
+    if cell != "kanana-2-30b-a3b-l8":
+        assert not copies, copies
+        return
+    bare = jax.jit(read_pages).lower(caches, ids).compile()
+    assert len(copies) == len(_whole_cache_results(bare.as_text(),
+                                                   shapes[0])) == 1, copies
+    assert mem.temp_size_in_bytes <= (
+        bare.memory_analysis().temp_size_in_bytes + (1 << 20))
+
+
+def test_state_push_program_on_tpu_forms_no_whole_slot_beside_its_bands(v5e):
+    """Brumby's largest push, a slot of 272.6 MB in four bands of two layers:
+    each band is laid out from its own layers of the slot, so what is live
+    beside the bands is a band's worth (68 MB), where the slot gathered and
+    concatenated first was another 272.6 MB and its slices a third."""
+    from infinistore_tpu.kv.cache import StateCacheConfig
+    from infinistore_tpu.kv.transfer import _state_to_wire
+
+    cfg = models.RetentionConfig(n_layers=8)
+    pc = StateCacheConfig.for_model(cfg, 4096, T, 4096, max_rows=8)
+    chip = SingleDeviceSharding(v5e[0])
+    S, z = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
+    compiled = _state_to_wire.lower(
+        S, z, jax.ShapeDtypeStruct((), jnp.int32, sharding=chip), 4).compile()
+    mem = compiled.memory_analysis()
+    assert pc.slot_bytes <= mem.output_size_in_bytes < pc.slot_bytes + (1 << 16)
+    assert mem.temp_size_in_bytes < pc.slot_bytes // 4 + (1 << 20), mem

@@ -299,7 +299,7 @@ def test_programs_are_named_and_the_committed_table_classes_them(
     from infinistore_tpu.engine import engine as E
 
     for fn in (E._KV_APPEND, E._SPLIT2, E._STACK_ROWS, E._UNSTACK_ROWS,
-               E._ROW0, E._LAST_ROW, E._ARGMAX_I32, E._Q_COL0, E._SPLIT3,
+               E._ROW0, E._ARGMAX_I32, E._Q_COL0, E._SPLIT3,
                E._PICK_LAST):
         assert "lambda" not in fn.__name__, fn
 
@@ -669,6 +669,119 @@ def test_a_pushs_queue_and_commit_wall_sum_to_submit_to_commit(py_conn):
     assert both["pushes"] == 3
     assert both["queue_s"] - lone["queue_s"] >= 0.1     # the second's wait
     assert prof.summary()["store"]["push"]["queue_s"] == both["queue_s"]
+
+
+def _random_cache(pc, seed=0):
+    """``init_cache(pc)``'s arrays filled with values that differ page by
+    page (a value is its own index, folded into the type's exact range)."""
+    import jax.numpy as jnp
+
+    from infinistore_tpu.kv.cache import init_cache
+
+    def fill(a, salt):
+        n = int(jnp.size(a))
+        return ((jnp.arange(n, dtype=jnp.float32) * 7 + salt) % 251 - 125
+                ).reshape(a.shape).astype(a.dtype)
+
+    zero = init_cache(pc)
+    if isinstance(zero, tuple):
+        return tuple(fill(a, seed + 13 * i) for i, a in enumerate(zero))
+    return fill(zero, seed)
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent", "two-pools", "int8",
+                                  "state"])
+def test_the_pushs_one_program_holds_what_the_gather_and_the_slices_held(kind):
+    """``gather_pages`` is one program that returns the layer bands: byte for
+    byte the gather by ids, the transpose to ``[L, n, planes, H, T, D]``, the
+    int8 quantize and the ``pipeline_groups`` slices that were a launch each
+    (here in numpy, and ``quantize_pages`` alone), for a dense page, a latent
+    page, a cache of two pools (layers back in stack order, each pool under
+    its own ids), int8 pages and a state slot; five layers in four bands of
+    2 + 2 + 1."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from infinistore_tpu.kv import KVTransferEngine, PagedCacheConfig
+    from infinistore_tpu.kv.cache import StateCacheConfig
+    from infinistore_tpu.kv.quant import quantize_pages
+    from infinistore_tpu.kv.transfer import StateTransferEngine
+
+    L = 5
+    if kind == "state":
+        pc = StateCacheConfig(n_layers=L, n_kv_heads=2, state_dim=8,
+                              head_dim=4, n_blocks=16, stride=16, max_rows=1,
+                              block_tokens=4)
+        tr = StateTransferEngine(None, pc)
+        S, z = cache = _random_cache(pc)
+        bands = tr.gather_pages(cache, 2)
+        s = np.asarray(S)[2].reshape(L, 2, 8 * 4)
+        want = np.concatenate([s, np.asarray(z)[2]], axis=-1)[:, None]
+    else:
+        pc = PagedCacheConfig(
+            n_layers=L, n_kv_heads=1 if kind == "latent" else 2, head_dim=8,
+            n_blocks=16, block_tokens=4,
+            planes=1 if kind == "latent" else 2,
+            window_layers=(0, 1, 3) if kind == "two-pools" else (),
+            window_blocks=8 if kind == "two-pools" else 0)
+        tr = KVTransferEngine(None, pc,
+                              quant="int8" if kind == "int8" else None)
+        cache = _random_cache(pc)
+        if kind == "two-pools":
+            ids = ([9, 3, 12], [5, 0, 2])          # full pool, window pool
+            by_layer = {li: np.asarray(a, np.float32)[i][:, :, pool_ids]
+                        for (layers, _), a, pool_ids in zip(pc.pools, cache,
+                                                            ids)
+                        for i, li in enumerate(layers)}
+            gathered = np.stack([by_layer[li] for li in range(L)])
+        else:
+            ids = [9, 3, 12]
+            gathered = np.asarray(cache, np.float32)[:, :, :, ids]
+        bands = tr.gather_pages(cache, ids)
+        want = jnp.asarray(gathered.transpose(0, 3, 1, 2, 4, 5), pc.dtype)
+        if kind == "int8":
+            want = quantize_pages(want)
+        want = np.asarray(want)
+    assert [b.shape[0] for b in bands] == [2, 2, 1]
+    got = np.concatenate([np.asarray(b) for b in bands])
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+    if kind != "state":
+        assert got.shape[:2] == (L, 3)
+        assert got[0, 0].nbytes == tr.wire_page_bytes
+
+
+@pytest.mark.parametrize("quant", [None, "int8"])
+def test_pages_written_after_push_begin_do_not_change_what_is_pushed(
+        py_conn, quant):
+    """The bands are a snapshot: pages overwritten in the (donated) cache
+    after ``push_begin`` and before ``push_commit`` reach the store as they
+    were when gathered.  The engine leans on it twice: the next chunk's
+    page write and the window pool's reclaim both follow the submit."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from infinistore_tpu.kv import KVTransferEngine, PagedCacheConfig
+    from infinistore_tpu.kv.cache import init_cache
+    from infinistore_tpu.kv.transfer import _scatter_stacked
+
+    pc = PagedCacheConfig(n_layers=4, n_kv_heads=2, head_dim=8, n_blocks=16,
+                          block_tokens=4)
+    tr = KVTransferEngine(py_conn(), pc, quant=quant)
+    cache = _random_cache(pc)
+    ids, keys = [1, 2, 3], [f"snap-{quant}-{i}" for i in range(3)]
+    before = np.asarray(cache, np.float32)[:, :, :, ids]
+    token = tr.push_begin(tr.gather_pages(cache, ids), keys)
+    cache = _scatter_stacked(cache, jnp.asarray(ids, jnp.int32),
+                             jnp.full((4, 3) + pc.page_shape, 99.0, pc.dtype))
+    assert float(cache[0, 0, 0, 2, 0, 0]) == 99.0
+    tr.push_commit(token)
+    back = tr.load_pages(init_cache(pc), [7, 8, 9], keys)
+    got = np.asarray(back, np.float32)[:, :, :, [7, 8, 9]]
+    if quant:       # one int8 step of a page whose largest value is 125
+        np.testing.assert_allclose(got, before, atol=0.5)
+    else:
+        assert np.array_equal(got, before)
 
 
 @pytest.mark.parametrize("path", ["banded", "layer-groups", "state"])

@@ -629,8 +629,9 @@ def test_note_prefill_budget_sums_per_step_and_lifetime():
 
 
 def test_note_push_wait_sums_per_step_and_lifetime():
-    """The two causes of ``kv.push_wait`` land beside the step's budget under
-    ``prefill`` and sum into ``summary()['prefill']``: a burst that finishes
+    """The two causes of ``kv.push_wait`` and the chunks with and without an
+    output head land beside the step's budget under ``prefill`` and sum into
+    ``summary()['prefill']``: a burst that finishes
     two prompts reads ``settled_prompts / settle_waits`` above 1; a call
     outside a step is dropped."""
     from infinistore_tpu.engine import stepprof as sp
@@ -642,17 +643,22 @@ def test_note_push_wait_sums_per_step_and_lifetime():
         sp.note_push_wait(settle_waits=1)                # the step's one wait
         sp.note_push_wait(settled_prompts=1, settle_wait_s=0.5)
         sp.note_push_wait(settled_prompts=1, settle_wait_s=0.125)
+        for head in (False, False, True):   # a prompt of three chunks
+            sp.note_prefill_chunk(head=head)
     assert rec["prefill"] == {
         "granted_tokens": 2048, "spent_tokens": 1536, "settle_waits": 1,
         "settled_prompts": 2, "settle_wait_s": 0.625,
-        "push_queue_full_waits": 1, "push_queue_full_s": 0.25}
+        "push_queue_full_waits": 1, "push_queue_full_s": 0.25,
+        "chunks": 3, "head_chunks": 1}
     with prof.step(kind_hint="prefill") as wave:    # a blocking prefill: no budget
         sp.note_push_wait(settle_waits=1)
         sp.note_push_wait(settled_prompts=1, settle_wait_s=0.25)
     assert wave["prefill"]["granted_tokens"] == 0
     assert wave["prefill"]["settled_prompts"] == 1
     sp.note_push_wait(settle_waits=1, settled_prompts=1, settle_wait_s=9.0)
+    sp.note_prefill_chunk(head=True)
     tot = prof.summary()["prefill"]
+    assert (tot["chunks"], tot["head_chunks"]) == (3, 1)
     assert sorted(tot) == sorted(sp.PREFILL_COUNTS)
     assert (tot["settle_waits"], tot["settled_prompts"]) == (2, 3)
     assert tot["settle_wait_s"] == 0.875 and tot["push_queue_full_s"] == 0.25
