@@ -1035,6 +1035,17 @@ class InferenceEngine:
         # in-place append into the bucketed chunked-prefill KV buffer
         self._kv_append = _KV_APPEND
 
+    @property
+    def _page_pool(self):
+        """The array the one-pool page helpers read and write: the whole
+        cache, but where a sequence keeps a second kind of cache beside its
+        pages (engine/hybrid_engine.py)."""
+        return self.cache
+
+    @_page_pool.setter
+    def _page_pool(self, pages) -> None:
+        self.cache = pages
+
     def _prefill(self, head_row: Optional[Sequence[int]], **kw):
         """The prefill program in the form that computes what its caller
         keeps: ``(rows, kv)``.  ``head_row`` None: no logits are kept (a
@@ -1169,7 +1180,6 @@ class InferenceEngine:
             self.pages.unpin(block_ids)
             raise
 
-        prefix_kv = None
         if reused > n_local or (two and missing):  # store hop
             # guarded: BOTH the eviction race (a matched page vanished
             # between lookup_prefix and the load — reads are
@@ -1205,13 +1215,31 @@ class InferenceEngine:
                     raise
             elif not ok:
                 reused = n_local
+        return self._begin_chunks(
+            tokens, keys, block_ids, reused, min(n_local, reused),
+            lookup_s, load_s, adapter_id=adapter_id, window_ids=window_ids)
+
+    def _begin_chunks(self, tokens: List[int], keys: List[str],
+                      block_ids: List[int], reused: int, local_chunks: int,
+                      lookup_s: float, load_s: float, adapter_id: int = 0,
+                      window_ids: Sequence[int] = (), **more
+                      ) -> "PartialPrefill":
+        """The second half of ``prefill_start``, once what a prompt adopts has
+        settled (``reused`` chunks, ``local_chunks`` of them from HBM, the
+        rest from the store; ``block_ids`` the whole table): the provenance
+        counts, the prefix buffer and the chunking.  ``more``: further fields
+        of the ``PartialPrefill``."""
+        T = self.pc.block_tokens
+        S_total = len(tokens)
+        two = self.wpages is not None
+        n_pages_total = len(block_ids)
+        window_ids = list(window_ids)
         P = reused * T
         if two:
             self._note_window_pages(
                 "acquired", n_pages_total - self._dead_chunks(reused))
         # provenance accounting AFTER the load settled (a failed store
         # load degrades those chunks back to computed, and must count so)
-        local_chunks = min(n_local, reused)
         if local_chunks:
             _PREFIX_TOKENS.labels("local").inc(local_chunks * T)
         if reused > local_chunks:
@@ -1231,6 +1259,7 @@ class InferenceEngine:
             _PREFIX_TOKENS_TENANT.labels(tenant, "computed").inc(
                 S_total - P)
 
+        prefix_kv = None
         if reused and two:
             prefix_kv = _read_prefix_kv_by_pool(
                 self.cache,
@@ -1240,7 +1269,7 @@ class InferenceEngine:
                 self.pc.stack_order)
         elif reused:
             prefix_kv = _read_prefix_kv(
-                self.cache, jnp.asarray(block_ids[:reused])
+                self._page_pool, jnp.asarray(block_ids[:reused])
             )  # [L, 2, 1, n*T, H, D]
 
         # compute the tail; pad to a whole number of pages for paging.
@@ -1280,7 +1309,7 @@ class InferenceEngine:
             window_ids=window_ids,
             window_reclaimed=self._dead_chunks(reused) if two else 0,
             local_chunks=local_chunks, store_chunks=reused - local_chunks,
-            store_load_s=lookup_s + load_s, lookup_s=lookup_s,
+            store_load_s=lookup_s + load_s, lookup_s=lookup_s, **more,
         )
 
     # ---- pages by layer kind: the sliding-window layers' pool ----
@@ -1428,7 +1457,7 @@ class InferenceEngine:
         off, C = pp.off, pp.C
         chunk = pp.padded[off : off + C]
         arr = jnp.asarray(chunk, dtype=jnp.int32)[None]
-        kw = self._lora_args([pp.adapter_id])
+        kw = self._lora_args([pp.adapter_id]) | self._chunk_args(pp, len(chunk))
         if pp.buf is not None:
             kw["prefix_kv"] = pp.buf
             if not pp.single:
@@ -1439,13 +1468,14 @@ class InferenceEngine:
         rows, kv = self._prefill(
             [(pp.S - 1) - off] if last else None, tokens=arr, **kw)
         pp.logits = rows[0] if last else None
+        kv = self._chunk_landed(kv)
         # the chunk forward + its cache landing = one prefill dispatch
         # unit for the step profiler's attribution
         _stepprof.note_dispatch("prefill")
         n_pg = len(chunk) // T
         if self.wpages is None:
-            self.cache = _write_prefill_pages(
-                self.cache,
+            self._page_pool = _write_prefill_pages(
+                self._page_pool,
                 jnp.asarray(pp.block_ids[pp.done : pp.done + n_pg]),
                 kv,
                 T,
@@ -1471,10 +1501,7 @@ class InferenceEngine:
                 # bounded queue's put (where it blocks, two chunks already
                 # waiting, it is kv.push_wait)
                 with _stepprof.phase("kv.push_gather"):
-                    pages = self.transfer.gather_pages(
-                        self.cache,
-                        pp.block_ids[lo:hi] if self.wpages is None
-                        else (pp.block_ids[lo:hi], pp.window_ids[lo:hi]))
+                    pages = self._gather_push(pp, lo, hi)
                     _stepprof.enter("kv.push_submit")
                     self._streamer.submit(pages, pp.keys[lo:hi],
                                           marker=pp.marker)
@@ -1502,6 +1529,23 @@ class InferenceEngine:
             # finished: a prefill that waits to be settled holds no prefix
             # buffer, only the row of logits the decode starts from
             pp.buf = None
+
+    def _chunk_args(self, pp: "PartialPrefill", n_tokens: int) -> Dict[str, Any]:
+        """What a chunk's program takes besides its tokens and its prefix:
+        nothing, but where a sequence keeps a state beside its pages."""
+        return {}
+
+    def _chunk_landed(self, kv):
+        """What a chunk's program returned beside its logits, taken apart:
+        the chunk's K and V, to be written into its pages."""
+        return kv
+
+    def _gather_push(self, pp: "PartialPrefill", lo: int, hi: int):
+        """The snapshot of chunks ``[lo, hi)`` that goes to the store."""
+        return self.transfer.gather_pages(
+            self.cache,
+            pp.block_ids[lo:hi] if self.wpages is None
+            else (pp.block_ids[lo:hi], pp.window_ids[lo:hi]))
 
     def _make_visible(self, pp: "PartialPrefill") -> SequenceState:
         """A finished prefill's decode-ready state.  Under strict durability

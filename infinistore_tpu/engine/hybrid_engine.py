@@ -1,0 +1,273 @@
+"""The engine over a cache of TWO KINDS: pages for a stack's attention layers
+and, beside them in the same sequence, a state of fixed size for its other
+layers (gated short convolutions, models/lfm2_moe.py; kv/cache.py
+``HybridCacheConfig``).
+
+It is the paged engine (``InferenceEngine``: the allocator, the prefix page
+cache, the block table, the prefix buffer, chunked prefill's loop, the decode
+scan, the streamer, ``prefill_settle``) plus the slot bookkeeping of
+engine/state_engine.py (``SlotBook``, ``StateSlots``, ``_copy_slot``); what is
+its own is the rule that joins the two:
+
+* ``self.cache`` is ``(pages [attention layers, 2, H_kv, n_blocks, T, D], slots
+  [n_slots, state layers, width])``, both donated through the prefill chunk
+  and the decode scan.  A ``SequenceState`` holds ``block_ids`` AND a ``slot``;
+  the scan's block table is ``(the pages' table, the rows' slots [B, 1])``.
+* **A checkpoint at every multiple of the stride** a prompt's prefill passes
+  (``pc.stride``, a multiple of ``prefill_chunk``, so at a chunk's end): the
+  row's state there is copied into a resident slot under that position's
+  chunk key and rides to the store in the chunk's own push, behind its pages
+  (kv/transfer.py ``HybridTransferEngine``), so that strict durability's one
+  wait (``prefill_settle``) acknowledges both.  Decode takes none.
+* **A hit is the deepest position at which BOTH exist.**  ``prefill_start``
+  matches pages as the paged engine does (HBM, then the store), then takes the
+  deepest multiple of the stride at or below that match whose checkpoint is
+  resident or in the store; pages beyond it are not adopted, the row's slot is
+  a COPY of the checkpoint (a row that adopts none starts from a zeroed slot)
+  and the prefill goes on from there: chunk boundaries fall where they fell
+  when the prompt was computed, so a re-ask's logits are bit for bit the
+  computed prompt's.  Pages not held and a checkpoint not resident come back
+  in ONE load, both or neither; a load that fails costs a shallower hit (what
+  HBM holds of both) or a miss, never a request (``guarded_*``).  Tokens whose
+  pages matched and which were recomputed for want of a checkpoint are
+  counted (``shared_tokens_recomputed``).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List, Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+
+from ..kv.cache import HybridCacheConfig, StateSlots
+from ..kv.hashing import chunk_keys
+from ..kv.transfer import HybridTransferEngine
+from . import stepprof as _stepprof
+from .engine import InferenceEngine, PartialPrefill, SequenceState
+from .state_engine import SlotBook, refuse_for_slots
+
+
+class HybridEngine(SlotBook, InferenceEngine):
+    transfer_cls = HybridTransferEngine
+    prefill_donates = ("conv",)
+    batched_prefill = False      # a row's slot is taken in ``prefill_start``
+
+    def __init__(self, params, cfg, pc: HybridCacheConfig, **kw):
+        refuse_for_slots(kw)
+        kw.setdefault("max_seqs", pc.max_rows)
+        super().__init__(params, cfg, pc, **kw)
+        if self.prefill_chunk is None or pc.stride % self.prefill_chunk:
+            raise ValueError(
+                f"a checkpoint is taken at the end of a prefill chunk: the "
+                f"stride {pc.stride} must be a multiple of prefill_chunk "
+                f"({self.prefill_chunk})")
+        self.slots = StateSlots(pc.n_slots, pc.max_rows)
+
+    # the two kinds, each where its helpers look for it
+
+    @property
+    def _page_pool(self) -> jax.Array:
+        return self.cache[0]
+
+    @_page_pool.setter
+    def _page_pool(self, pages: jax.Array) -> None:
+        self.cache = (pages, self.cache[1])
+
+    @property
+    def _slot_arrays(self) -> tuple:
+        return (self.cache[1],)
+
+    @_slot_arrays.setter
+    def _slot_arrays(self, arrays: tuple) -> None:
+        self.cache = (self.cache[0], arrays[0])
+
+    # ---- prefill ----
+
+    def prefill_start(self, tokens: Sequence[int],
+                      adapter_id: int = 0) -> PartialPrefill:
+        """Admission half of a prefill: a row's slot (``MemoryError`` where
+        every one is taken), the deepest position at which this prompt finds
+        pages AND a checkpoint, both adopted, the rest of its pages, and the
+        chunking."""
+        assert adapter_id == 0 and len(tokens) >= 1, adapter_id
+        tokens = list(tokens)
+        keys = chunk_keys(tokens, self.model_id,
+                          chunk_tokens=self.pc.block_tokens)
+        row = self.slots.take_row()
+        block_ids: List[int] = []
+        try:
+            return self._start_in_row(tokens, keys, row, block_ids)
+        except BaseException:
+            self.pages.unpin(block_ids)
+            self.slots.free_row(row)
+            raise
+
+    def _start_in_row(self, tokens: List[int], keys: List[str], row: int,
+                      block_ids: List[int]) -> PartialPrefill:
+        """``block_ids`` is the caller's list, filled in place: what it holds
+        when this raises is what is pinned."""
+        T, n = self.pc.block_tokens, len(tokens)
+        per = self.pc.stride // T                 # chunks a stride
+        # the pages, as the paged engine matches them: HBM, then the store;
+        # capped so that a token is left to compute
+        max_reuse = (n - 1) // T
+        block_ids += self.pages.match_prefix(keys[:max_reuse])  # pins hits
+        n_local = len(block_ids)
+        lookup_s = load_s = 0.0
+        n_store = 0
+        if self.transfer is not None and n_local < max_reuse:
+            with _stepprof.phase("kv.lookup") as ph:
+                n_store = min(self.transfer.guarded_lookup_prefix(keys),
+                              max_reuse)
+            lookup_s = ph.s
+        matched = max(n_local, n_store)
+
+        # the checkpoints at or below the match, deepest first: resident, or
+        # in the store (asked only for the positions deeper than that)
+        def key_at(c: int) -> str:                # c chunks = position c x T
+            return keys[c - 1]
+
+        at_stride = range(per, matched + 1, per)
+        resident = next((c for c in reversed(at_stride)
+                         if key_at(c) in self.slots), 0)
+        cut, stored = resident, False
+        deeper = [c for c in at_stride if c > resident]
+        if self.transfer is not None and deeper:
+            with _stepprof.phase("kv.lookup") as ph:
+                hit = self.transfer.guarded_lookup_prefix(
+                    [key_at(c) for c in deeper], states=True)
+            lookup_s += ph.s
+            if hit:
+                cut, stored = deeper[hit - 1], True
+
+        # pages beyond the cut are other sequences' to read, not this one's
+        # to write; the rest of the table is fresh
+        def cut_table(c: int) -> None:
+            nonlocal n_local
+            beyond = block_ids[c:]
+            del block_ids[c:]
+            self.pages.unpin(beyond)
+            n_local = len(block_ids)
+            block_ids.extend(self.pages.acquire(-(-n // T) - n_local))
+
+        cut_table(cut)
+        if cut > n_local or stored:
+            # one load: the pages HBM does not hold and the checkpoint where
+            # it is not resident, both or neither
+            with _stepprof.phase("kv.load") as ph:
+                self.cache, ok = self.transfer.guarded_load(
+                    self.cache, block_ids[n_local:cut], keys[n_local:cut],
+                    state=(row, key_at(cut)) if stored else None)
+            load_s = ph.s
+            if ok and stored:
+                # a store hit becomes resident, as a computed checkpoint does
+                self._keep_resident(key_at(cut), row)
+            elif not ok:
+                # what HBM holds of both: the pages matched there, and the
+                # deepest resident checkpoint at or below them
+                self.pages.unpin(block_ids[n_local:])
+                del block_ids[n_local:]
+                cut, stored = next(
+                    (c for c in reversed(at_stride)
+                     if c <= n_local and key_at(c) in self.slots), 0), False
+                cut_table(cut)
+        if stored:
+            self._count(adopted_store=1)
+        elif cut and self._adopt_resident(key_at(cut), row):
+            self._count(adopted_local=1)
+        else:
+            assert cut == 0, cut
+            self._zero_row(row)
+        if matched > cut:
+            self._count(shared_tokens_recomputed=(matched - cut) * T)
+        if n_store > n_local and self.transfer is not None:
+            # full: the deepest stride the matched pages reach was adopted
+            self._count(store_hits=1,
+                        store_hits_full=int(cut == matched // per * per))
+        return self._begin_chunks(tokens, keys, block_ids, cut,
+                                  min(n_local, cut), lookup_s, load_s, slot=row)
+
+    def _chunk_args(self, pp: PartialPrefill, n_tokens: int) -> Dict[str, Any]:
+        return {"conv": self.cache[1],
+                "slot": jnp.asarray(pp.slot, jnp.int32),
+                # the chunk's tokens that are the prompt's: a padded tail
+                # enters no state
+                "n_valid": jnp.asarray(min(n_tokens, pp.S - pp.off), jnp.int32)}
+
+    def _chunk_landed(self, kv):
+        kv, conv = kv
+        self.cache = (self.cache[0], conv)
+        return kv
+
+    def _checkpoint_at(self, pp: PartialPrefill, chunks: int) -> bool:
+        """Whether the row's state after ``chunks`` chunks is one to keep: a
+        multiple of the stride, every token up to it the prompt's."""
+        return (chunks * self.pc.block_tokens % self.pc.stride == 0
+                and chunks <= pp.n_complete)
+
+    def _gather_push(self, pp: PartialPrefill, lo: int, hi: int):
+        # chunks ``[lo, hi)`` end where the chunk's program left the row's
+        # state; at a multiple of the stride it rides behind their pages
+        ckpt = hi == pp.done and self._checkpoint_at(pp, hi)
+        if ckpt:
+            self._count(checkpoints_pushed=1, bytes_pushed=self.pc.slot_bytes)
+        return self.transfer.gather_pages(
+            self.cache, pp.block_ids[lo:hi], slot=pp.slot if ckpt else None)
+
+    def _prefill_chunk(self, pp: PartialPrefill) -> None:
+        super()._prefill_chunk(pp)
+        if self._checkpoint_at(pp, pp.done):
+            # a copy, enqueued behind the chunk and before the next one's
+            # write of the row's slot
+            with _stepprof.phase("kv.checkpoint"):
+                if self._keep_resident(pp.keys[pp.done - 1], pp.slot):
+                    self._count(checkpoints_taken=1)
+
+    def _make_visible(self, pp: PartialPrefill) -> SequenceState:
+        state = super()._make_visible(pp)
+        state.slot, pp.slot = pp.slot, -1
+        return state
+
+    def abandon_prefill(self, pp: PartialPrefill) -> None:
+        super().abandon_prefill(pp)
+        if pp.slot >= 0:
+            self.slots.free_row(pp.slot)
+            pp.slot = -1
+
+    def adopt_prefill(self, tokens, kv, last_logits):
+        raise ValueError("adopt_prefill lands K and V in pages; this model's "
+                         "conv layers keep a state too (prefill it through "
+                         "the engine)")
+
+    def prompt_logprobs(self, tokens, k: int = 0, adapter_id: int = 0):
+        raise ValueError("prompt scoring runs a paged family's dense "
+                         "forward; this model's prefill runs through its "
+                         "state slots")
+
+    def propose(self, *a, **kw):
+        raise ValueError("a sequence that keeps a state drafts nothing: a "
+                         "rejected token cannot be taken out of a state")
+
+    # ---- decode ----
+
+    def _block_table(self, states, pad_to: Optional[int] = None):
+        """``(the pages' table, the rows' slots [rows, 1])``; a pad row's slot
+        is one past the slots, as its pages are one past the pool (its read
+        clamps, its write is dropped)."""
+        pad = (pad_to or len(states)) - len(states)
+        return (super()._block_table(states, pad_to=pad_to),
+                jnp.asarray([[st.slot] for st in states]
+                            + [[self.pc.n_slots]] * pad, dtype=jnp.int32))
+
+    @property
+    def free_pages(self) -> int:
+        """What admission compares a request's pages with: the pool's, while a
+        row's slot is free."""
+        return self.pages.available if self.slots.rows_free else 0
+
+    def release(self, state: SequenceState) -> None:
+        if state.slot >= 0:
+            self.slots.free_row(state.slot)
+            state.slot = -1
+        super().release(state)

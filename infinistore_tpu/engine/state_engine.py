@@ -79,6 +79,13 @@ _SHARED_RECOMPUTED = _metrics.default_registry().counter(
     "Prompt tokens shared with an earlier prompt but recomputed because no "
     "checkpoint was kept that deep",
 )
+_STORE_HITS = _metrics.default_registry().counter(
+    "istpu_engine_state_store_hits_total",
+    "Prompts whose pages the store matched deeper than HBM held them, where a "
+    "sequence keeps pages and a state: all of them, and those that adopted "
+    "pages and checkpoint at the matched depth (full)",
+    labelnames=("depth",),
+)
 _EVICTED = _metrics.default_registry().counter(
     "istpu_engine_state_resident_evicted_total",
     "Resident checkpoints evicted from their slot for a newer one",
@@ -96,7 +103,92 @@ def _zero_slot(cache, dst):
     return tuple(a.at[dst].set(0.0) for a in cache)
 
 
-class StateEngine(InferenceEngine):
+def refuse_for_slots(kw: dict) -> None:
+    """What no engine whose sequences keep a state in a slot serves, refused
+    in words where the engine is built (``kw``: its keyword arguments)."""
+    if kw.get("kv_quant") is not None:
+        raise ValueError(
+            f"kv quant {kw['kv_quant']!r} scales pages per (K|V, head); "
+            f"a state has no such scale and goes to the store as it is")
+    kw["kv_quant"] = None
+    for name in ("mesh", "lora", "verify_fn"):
+        if kw.get(name) is not None:
+            raise ValueError(f"a cache of state slots is served without "
+                             f"{name}")
+    if kw.get("conn") is not None:
+        from ..cluster import RoutedStorePool
+
+        if isinstance(kw["conn"], RoutedStorePool):
+            raise ValueError(
+                "state checkpoints go to ONE store connection; the "
+                "clustered transfer routes pages by chunk")
+
+
+class SlotBook:
+    """What the engines whose sequences keep a state share (this module's and
+    engine/hybrid_engine.py's): the counters' sinks and the resident
+    checkpoints' copies.  ``self.slots`` is the ``StateSlots``;
+    ``_slot_arrays`` the donated arrays whose first axis is the slot."""
+
+    @property
+    def _slot_arrays(self) -> tuple:
+        return self.cache
+
+    @_slot_arrays.setter
+    def _slot_arrays(self, arrays: tuple) -> None:
+        self.cache = arrays
+
+    def _count(self, **counts: int) -> None:
+        """One event into every sink: /debug/engine's ``summary.state`` and
+        the /metrics families."""
+        _stepprof.note_state(**counts)
+        for k, n in counts.items():
+            if k.startswith("checkpoints_"):
+                _CHECKPOINTS.labels(k[len("checkpoints_"):]).inc(n)
+            elif k.startswith("adopted_"):
+                _ADOPTIONS.labels(k[len("adopted_"):]).inc(n)
+            elif k in ("store_hits", "store_hits_full"):
+                _STORE_HITS.labels("full" if k.endswith("_full") else "all").inc(n)
+            else:
+                {"bytes_pushed": _BYTES_PUSHED,
+                 "shared_tokens_recomputed": _SHARED_RECOMPUTED,
+                 "resident_evicted": _EVICTED}[k].inc(n)
+
+    def _keep_resident(self, key: str, row: int) -> bool:
+        """Row ``row``'s state as it is now, copied into a resident slot under
+        ``key`` (the least recently used unpinned checkpoint goes); False
+        where the key is resident already or every slot is pinned."""
+        if key in self.slots:
+            return False
+        before = self.slots.evicted
+        dst = self.slots.keep()
+        if dst is None:
+            return False
+        self._slot_arrays = _copy_slot(self._slot_arrays, row, dst)
+        self.slots.register(key, dst)
+        self.slots.unpin(dst)
+        if self.slots.evicted > before:
+            self._count(resident_evicted=1)
+        return True
+
+    def _adopt_resident(self, key: str, row: int) -> bool:
+        """The resident checkpoint of ``key`` copied into row ``row``'s slot
+        (copied, not shared: the row will write its slot); False where none
+        is resident."""
+        src = self.slots.match(key)
+        if src is None:
+            return False
+        self._slot_arrays = _copy_slot(self._slot_arrays, src, row)
+        self.slots.unpin(src)
+        return True
+
+    def _zero_row(self, row: int) -> None:
+        """A row that adopts nothing starts from zeros: what a slot held
+        before never reaches the arithmetic."""
+        self._slot_arrays = _zero_slot(self._slot_arrays, row)
+
+
+class StateEngine(SlotBook, InferenceEngine):
     transfer_cls = StateTransferEngine
     prefill_donates = ("cache",)
     batched_prefill = False      # a row's slot is taken in ``prefill_start``
@@ -106,22 +198,7 @@ class StateEngine(InferenceEngine):
     SEEN_KEYS = 1 << 16
 
     def __init__(self, params, cfg, pc: StateCacheConfig, **kw):
-        if kw.get("kv_quant") is not None:
-            raise ValueError(
-                f"kv quant {kw['kv_quant']!r} scales pages per (K|V, head); "
-                f"a state has no such scale and goes to the store as it is")
-        kw["kv_quant"] = None
-        for name in ("mesh", "lora", "verify_fn"):
-            if kw.get(name) is not None:
-                raise ValueError(f"a cache of state slots is served without "
-                                 f"{name}")
-        if kw.get("conn") is not None:
-            from ..cluster import RoutedStorePool
-
-            if isinstance(kw["conn"], RoutedStorePool):
-                raise ValueError(
-                    "state checkpoints go to ONE store connection; the "
-                    "clustered transfer routes pages by chunk")
+        refuse_for_slots(kw)
         kw.setdefault("max_seqs", pc.max_rows)
         super().__init__(params, cfg, pc, **kw)
         if self.prefill_chunk is None or pc.stride % self.prefill_chunk:
@@ -134,20 +211,6 @@ class StateEngine(InferenceEngine):
 
     def _dense_attention_in_kernel(self) -> bool:
         return False
-
-    def _count(self, **counts: int) -> None:
-        """One event into every sink: /debug/engine's ``summary.state`` and
-        the /metrics families."""
-        _stepprof.note_state(**counts)
-        for k, n in counts.items():
-            if k.startswith("checkpoints_"):
-                _CHECKPOINTS.labels(k[len("checkpoints_"):]).inc(n)
-            elif k.startswith("adopted_"):
-                _ADOPTIONS.labels(k[len("adopted_"):]).inc(n)
-            else:
-                {"bytes_pushed": _BYTES_PUSHED,
-                 "shared_tokens_recomputed": _SHARED_RECOMPUTED,
-                 "resident_evicted": _EVICTED}[k].inc(n)
 
     # ---- prefill ----
 
@@ -178,11 +241,7 @@ class StateEngine(InferenceEngine):
         P, source = 0, None
         lookup_s = load_s = 0.0
         for p in reversed(aligned):          # deepest resident in HBM
-            src = self.slots.match(key_at(p))
-            if src is not None:
-                # copied, not shared: the row will write its slot
-                self.cache = _copy_slot(self.cache, src, row)
-                self.slots.unpin(src)
+            if self._adopt_resident(key_at(p), row):
                 P, source = p, "local"
                 break
         deeper = [p for p in aligned if p > P]
@@ -204,7 +263,7 @@ class StateEngine(InferenceEngine):
                     # does (its copy leaves HBM again by the same LRU)
                     self._keep_resident(key_at(p), row)
         if source is None:
-            self.cache = _zero_slot(self.cache, row)
+            self._zero_row(row)
         else:
             self._count(**{f"adopted_{source}": 1})
         shared = self._shared_with_earlier(keys) * T
@@ -305,23 +364,6 @@ class StateEngine(InferenceEngine):
             self.transfer.covers(key, pp.ckpt_at)
             self._streamer.submit(pages, [key], marker=pp.marker)
         self._count(checkpoints_pushed=1, bytes_pushed=self.pc.slot_bytes)
-
-    def _keep_resident(self, key: str, row: int) -> bool:
-        """Row ``row``'s state as it is now, copied into a resident slot under
-        ``key`` (the least recently used unpinned checkpoint goes); False
-        where the key is resident already or every slot is pinned."""
-        if key in self.slots:
-            return False
-        before = self.slots.evicted
-        dst = self.slots.keep()
-        if dst is None:
-            return False
-        self.cache = _copy_slot(self.cache, row, dst)
-        self.slots.register(key, dst)
-        self.slots.unpin(dst)
-        if self.slots.evicted > before:
-            self._count(resident_evicted=1)
-        return True
 
     def abandon_prefill(self, pp: PartialPrefill) -> None:
         if pp.slot >= 0:

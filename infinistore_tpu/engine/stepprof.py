@@ -124,7 +124,7 @@ KV_COUNTS = ("store_pages_full", "store_pages_window",
 STATE_COUNTS = ("checkpoints_taken", "checkpoints_pushed",
                 "checkpoints_skipped_stored", "bytes_pushed",
                 "adopted_local", "adopted_store", "shared_tokens_recomputed",
-                "resident_evicted")
+                "resident_evicted", "store_hits", "store_hits_full")
 
 # the key that counts operations in the transfer's running totals
 _STORE_COUNT = {"push": "pushes", "load": "loads"}
@@ -345,8 +345,11 @@ def note_state(**counts: int) -> None:
     pushed; prompts that adopted a checkpoint, by where it came from; the
     tokens a prompt shared with an earlier one BEYOND the checkpoint it could
     adopt (shared, yet recomputed: a state is reusable only where one was
-    kept); resident checkpoints evicted for a newer one.  Summed under
-    ``rec["state"]``."""
+    kept); resident checkpoints evicted for a newer one; where a sequence
+    keeps pages AND a state (engine/hybrid_engine.py), the prompts whose
+    pages the store matched deeper than HBM held them (``store_hits``) and
+    those of them that adopted pages and checkpoint at the deepest stride
+    that match reaches (``store_hits_full``).  Summed under ``rec["state"]``."""
     _sum_into("state", STATE_COUNTS, counts)
 
 

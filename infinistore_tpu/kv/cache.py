@@ -47,6 +47,13 @@ written, held by the prefix's chunk key and COPIED into a row's slot when
 adopted (a state summarises everything before it, so it cannot be shared the
 way a read-only page is).
 
+A stack that mixes layers that keep pages with layers that keep a state
+(gated short convolutions among attention layers, models/lfm2_moe.py) holds
+BOTH KINDS for one sequence: ``HybridCacheConfig`` is the paged cache over the
+attention layers alone and, beside it, state slots over the others under the
+same ``StateSlots`` bookkeeping; a prefix is reusable only at a position where
+the pages up to it AND the state at it exist (engine/hybrid_engine.py).
+
 Static shapes everywhere: gathers/scatters take fixed-width index vectors so
 XLA compiles one program per (n_pages,) width; the host-side ``BlockAllocator``
 is plain Python (never traced).
@@ -185,15 +192,7 @@ class StateCacheConfig:
         pc = cls(n_layers=cfg.n_layers, n_kv_heads=heads, state_dim=width,
                  head_dim=head_dim, n_blocks=n_blocks, stride=stride,
                  max_rows=max_rows, block_tokens=block_tokens)
-        if stride % block_tokens or stride <= 0:
-            raise ValueError(f"a checkpoint lies at the end of a chunk of "
-                             f"{block_tokens} tokens: stride {stride} is no "
-                             f"multiple of it")
-        if pc.n_slots < max_rows:
-            raise ValueError(
-                f"{n_blocks} blocks of {block_tokens} tokens at a stride of "
-                f"{stride} are {pc.n_slots} state slots: fewer than the "
-                f"{max_rows} rows that run together")
+        _check_slots(n_blocks, block_tokens, stride, max_rows)
         return pc
 
     @property
@@ -226,8 +225,82 @@ class StateCacheConfig:
         return self.n_slots * self.slot_bytes
 
 
+def _check_slots(n_blocks: int, block_tokens: int, stride: int,
+                 max_rows: int) -> None:
+    """``ValueError``, in words, for a cache of ``n_blocks x block_tokens /
+    stride`` state slots that cannot be served."""
+    if stride <= 0 or stride % block_tokens:
+        raise ValueError(
+            f"a checkpoint lies at the end of a chunk of {block_tokens} "
+            f"tokens: stride {stride} is no multiple of it")
+    n_slots = n_blocks * block_tokens // stride
+    if n_slots < max_rows:
+        raise ValueError(
+            f"{n_blocks} blocks of {block_tokens} tokens at a stride of "
+            f"{stride} are {n_slots} state slots: fewer than the "
+            f"{max_rows} rows that run together")
+
+
+@dataclass(frozen=True)
+class HybridCacheConfig(PagedCacheConfig):
+    """The cache of a stack whose sequence keeps TWO KINDS: pages for its
+    ``page_layers`` (the paged cache above, over those layers alone: one pool
+    of ``n_blocks`` blocks, its allocator, prefix page cache and block table
+    unchanged) and, for its ``state_layers``, a state of ``state_width``
+    values a layer in a slot (``StateSlots``: ``max_rows`` running rows'
+    slots, the rest resident checkpoints by chunk key; ``n_slots = n_blocks x
+    block_tokens / stride``, one every ``stride`` tokens the pages stand
+    for).  ``n_layers`` is the STACK's depth and the layer ids in the store's
+    keys are the stack's, so a page and a state never share a key."""
+
+    page_layers: Tuple[int, ...] = ()
+    state_layers: Tuple[int, ...] = ()
+    state_width: int = 0
+    stride: int = 0
+    max_rows: int = 0
+
+    @classmethod
+    def for_model(cls, cfg, n_blocks: int, block_tokens: int, stride: int,
+                  max_rows: int) -> "HybridCacheConfig":
+        planes, heads, width = cfg.kv_page
+        _check_slots(n_blocks, block_tokens, stride, max_rows)
+        return cls(n_layers=cfg.n_layers, n_kv_heads=heads, head_dim=width,
+                   n_blocks=n_blocks, block_tokens=block_tokens,
+                   dtype=cfg.dtype, planes=planes,
+                   page_layers=tuple(cfg.attn_layers),
+                   state_layers=tuple(cfg.conv_layers),
+                   state_width=int(np.prod(cfg.conv_state_shape)),
+                   stride=stride, max_rows=max_rows)
+
+    @property
+    def pools(self) -> Tuple[Tuple[Tuple[int, ...], int], ...]:
+        """One pool of pages, over the layers that keep them."""
+        return ((self.page_layers, self.n_blocks),)
+
+    @property
+    def n_slots(self) -> int:
+        return self.n_blocks * self.block_tokens // self.stride
+
+    @property
+    def state_bytes(self) -> int:
+        """One layer's state of one sequence, as it is held and as it goes
+        to the store."""
+        return self.state_width * np.dtype(jnp.dtype(self.dtype)).itemsize
+
+    @property
+    def slot_bytes(self) -> int:
+        """One slot: every state layer's state."""
+        return len(self.state_layers) * self.state_bytes
+
+    @property
+    def cache_bytes(self) -> int:
+        """Pages and slots, as ``init_cache`` allocates them."""
+        return super().cache_bytes + self.n_slots * self.slot_bytes
+
+
 class StateSlots:
-    """Host-side bookkeeping of a ``StateCacheConfig``'s slots.  Slots
+    """Host-side bookkeeping of a ``StateCacheConfig``'s slots (and of a
+    ``HybridCacheConfig``'s).  Slots
     ``[0, max_rows)`` are the running rows': ``take_row`` / ``free_row``,
     each slot out once.  The rest hold RESIDENT CHECKPOINTS by key, least
     recently used first out: ``keep`` gives a key a slot (evicting the
@@ -304,7 +377,9 @@ def init_cache(cfg, sharding=None):
     sized for a mesh need not fit one device first).  One array; for a
     stack with a pool per layer kind a tuple of one array a pool
     (``cfg.pools``), each over its own layers and blocks; for a
-    ``StateCacheConfig`` the slots ``(S, z)``."""
+    ``StateCacheConfig`` the slots ``(S, z)``; for a ``HybridCacheConfig``
+    ``(pages [page layers, ...], slots [n_slots, state layers,
+    state_width])``."""
     if isinstance(cfg, StateCacheConfig):
         lead = (cfg.n_slots, cfg.n_layers, cfg.n_kv_heads, cfg.state_dim)
         return (jnp.zeros(lead + (cfg.head_dim,), cfg.dtype, device=sharding),
@@ -314,6 +389,10 @@ def init_cache(cfg, sharding=None):
                    cfg.block_tokens, cfg.head_dim),
                   dtype=cfg.dtype, device=sharding)
         for layers, n_blocks in cfg.pools)
+    if isinstance(cfg, HybridCacheConfig):
+        return arrays[0], jnp.zeros(
+            (cfg.n_slots, len(cfg.state_layers), cfg.state_width),
+            cfg.dtype, device=sharding)
     return arrays if cfg.window_layers else arrays[0]
 
 

@@ -399,9 +399,16 @@ class KVTransferEngine:
             **{k: v for k, v in stages.items() if k.endswith("_s")})
         return total
 
+    def _part_blocks(self, chunk_keys_: Sequence[str], l0: int, part
+                     ) -> Tuple[List[Tuple[str, int]], int]:
+        """``(blocks, block size)`` of one band of a push: the band's layers'
+        pages of every chunk.  A cache of two kinds pushes a band of another
+        make beside them (``HybridTransferEngine``)."""
+        return (self._page_blocks(chunk_keys_, l0, l0 + part.shape[0]),
+                self.wire_page_bytes)
+
     def _push_banded(self, parts, chunk_keys_: Sequence[str],
                      stages: dict) -> int:
-        pb = self.wire_page_bytes
         raw = self.conn
         l0s = []
         l0 = 0
@@ -415,7 +422,7 @@ class KVTransferEngine:
             # fill targets the mapped pool itself (exactly one copy
             # between the device buffer and the pool)
             bands = [
-                (self._page_blocks(chunk_keys_, l0, l0 + p.shape[0]), pb,
+                (*self._part_blocks(chunk_keys_, l0, p),
                  self._band_fill(p, stages))
                 for l0, p in zip(l0s, parts)
             ]
@@ -435,7 +442,7 @@ class KVTransferEngine:
             # push_begin) is still in flight
             total = 0
             for l0, p in zip(l0s, parts):
-                blocks = self._page_blocks(chunk_keys_, l0, l0 + p.shape[0])
+                blocks, pb = self._part_blocks(chunk_keys_, l0, p)
                 nbytes = pb * len(blocks)
                 slot = self._ensure_push_staging(nbytes)
                 self._band_fill(p, stages)(slot[:nbytes])
@@ -449,15 +456,14 @@ class KVTransferEngine:
         # negotiate alloc-first): the pre-alloc-first banded pipelined
         # put, kept as the byte-parity reference and the old-server path
         bands = [
-            (self._page_blocks(chunk_keys_, l0, l0 + p.shape[0]), pb,
-             self._band_host(p))
+            (*self._part_blocks(chunk_keys_, l0, p), self._band_host(p))
             for l0, p in zip(l0s, parts)
         ]
         writer = getattr(self._src, "write_cache_pipelined", None)
         if writer is not None:
             return writer(bands)
         total = 0
-        for blocks, _pb, mat in bands:  # bare native client: per-band
+        for blocks, pb, mat in bands:  # bare native client: per-band
             host = mat()
             self._call("write_cache", blocks, pb, host.ctypes.data)
             total += host.nbytes
@@ -695,12 +701,26 @@ class KVTransferEngine:
             probe = [layer_key(ck, probe_layer) + sfx for ck in chunk_keys_]
             idx = self._call("get_match_last_index", probe)
             while idx >= 0:
-                last = layer_key(chunk_keys_[idx], self.cfg.n_layers - 1) + sfx
+                last = layer_key(chunk_keys_[idx], self._last_page_layer) + sfx
                 # 0 => exists (wire semantics)
                 if self._call("check_exist", last) == 0:
                     break
                 idx -= 1
             return idx + 1
+
+    @property
+    def _last_page_layer(self) -> int:
+        """The layer whose page of a chunk is written last."""
+        return self.cfg.n_layers - 1
+
+    def _deepest_whole(self, chunk_keys_: Sequence[str], layer: int) -> int:
+        """``i + 1`` for the deepest ``chunk_keys_[i]`` under which the store
+        holds ``layer``'s value (the last written: what lies under the key is
+        whole), 0 for none; deepest first, one round trip each."""
+        for i in range(len(chunk_keys_) - 1, -1, -1):
+            if self._call("check_exist", layer_key(chunk_keys_[i], layer)) == 0:
+                return i + 1
+        return 0
 
     # -- breaker-guarded hops (the degraded-serving contract) --
     #
@@ -910,10 +930,136 @@ class StateTransferEngine(KVTransferEngine):
         """``i + 1`` for the deepest ``chunk_keys_[i]`` whose checkpoint the
         store holds whole (its last layer: layers are written in order), 0
         for none."""
-        last = self.cfg.n_layers - 1
         with tracing.span("kv.lookup_prefix", chunks=len(chunk_keys_)):
-            for i in range(len(chunk_keys_) - 1, -1, -1):
-                if self._call("check_exist",
-                              layer_key(chunk_keys_[i], last)) == 0:
-                    return i + 1
-        return 0
+            return self._deepest_whole(chunk_keys_, self.cfg.n_layers - 1)
+
+
+# -- a cache of two kinds: pages and a state (kv/cache.py HybridCacheConfig) --
+
+
+@partial(jax.jit, static_argnums=(2,))
+def _gather_bands_and_state(pages, block_ids, groups, conv, slot):
+    """``_gather_bands`` over the page layers and, in the same program, slot
+    ``slot``'s state of every state layer ``[state layers, width]`` as one
+    band more: the one launch a push costs the engine thread carries both."""
+    return _gather_bands(pages, block_ids, None, False, groups) + (conv[slot],)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _set_slot(conv: jax.Array, slot: jax.Array, state: jax.Array) -> jax.Array:
+    """``state`` [state layers, width] into slot ``slot`` of the donated slots."""
+    return conv.at[slot].set(state)
+
+
+class HybridTransferEngine(KVTransferEngine):
+    """``KVTransferEngine`` for a cache of TWO KINDS (``HybridCacheConfig``):
+    under a prompt's chunk keys a push carries a page for each page layer of
+    each chunk and, where the chunks end at a multiple of the stride, the
+    state layers' states at that position under the LAST chunk's key; layer
+    ids in the keys are the stack's, so the two never share one.  The banded
+    push, the one-launch gather, the staging ring and the streamer's queue
+    are the parent's: the states are one band more of a block size of their
+    own (``cfg.state_bytes``), written after the pages in the same commit,
+    and a state is bfloat16 on the wire as in HBM, so what comes back is bit
+    for bit what was pushed.  What differs:
+
+    * ``gather_pages(cache, block_ids, slot=None)`` takes the engine's pair
+      ``(pages, slots)`` and, with ``slot``, snapshots that slot too;
+    * ``load_pages(cache, block_ids, keys, state=(slot, key))`` fetches the
+      pages not held and the checkpoint, BOTH before either is scattered: a
+      key missing of either kind raises with the cache untouched
+      (``guarded_load``: a miss);
+    * ``lookup_prefix(keys)`` probes the page layers; with ``states=True`` the
+      keys are positions at which a checkpoint MAY lie and the deepest that is
+      whole answers (``StateTransferEngine.lookup_prefix``'s rule)."""
+
+    loads_by_layer = False
+
+    def __init__(self, conn, cfg, **kw):
+        super().__init__(conn, cfg, **kw)
+        self._state_staging: Optional[np.ndarray] = None
+
+    def _page_blocks(self, chunk_keys_, l0: int, l1: int):
+        # a band's layers are the page pool's: name them as the stack does
+        return self._layer_blocks(chunk_keys_, self.cfg.page_layers[l0:l1])
+
+    def _state_blocks(self, key: str) -> List[Tuple[str, int]]:
+        sb = self.cfg.state_bytes
+        return [(layer_key(key, li), j * sb)
+                for j, li in enumerate(self.cfg.state_layers)]
+
+    def _part_blocks(self, chunk_keys_, l0: int, part):
+        if part.ndim == 2:          # the states, at the last chunk's end
+            return self._state_blocks(chunk_keys_[-1]), self.cfg.state_bytes
+        return super()._part_blocks(chunk_keys_, l0, part)
+
+    def gather_pages(self, cache, block_ids, slot: Optional[int] = None):
+        pages, conv = cache
+        ids = np.asarray(block_ids, dtype=np.int32)
+        if slot is None:
+            return _gather_bands(pages, ids, None, False, self.pipeline_groups)
+        return _gather_bands_and_state(pages, ids, self.pipeline_groups, conv,
+                                       np.int32(slot))
+
+    def _fetch_state(self, key: str, stages: dict) -> jax.Array:
+        """The checkpoint under ``key``, every state layer, as a device array
+        ``[state layers, width]``; a layer's state missing raises."""
+        cfg = self.cfg
+        nbytes = cfg.slot_bytes
+        if self._state_staging is None:
+            self._state_staging = np.empty(nbytes, dtype=np.uint8)
+            self._src.register_mr(self._state_staging.ctypes.data, nbytes)
+        buf = self._state_staging
+        band = (self._state_blocks(key), cfg.state_bytes, buf.ctypes.data)
+        reader = getattr(self._src, "read_cache_pipelined", None)
+        if reader is not None:
+            reader([band], None, stages)
+        else:
+            self._call("read_cache", *band)
+        t0 = time.perf_counter()
+        # a copy of its own: the staging buffer is the next load's too
+        out = jax.device_put(np.array(buf.view(jnp.dtype(cfg.dtype)).reshape(
+            len(cfg.state_layers), cfg.state_width)))
+        stages["upload_s"] += time.perf_counter() - t0
+        return out
+
+    def load_pages(self, cache, block_ids: Sequence[int],
+                   chunk_keys_: Sequence[str],
+                   state: Optional[Tuple[int, str]] = None):
+        assert len(block_ids) == len(chunk_keys_)
+        pages, conv = cache
+        n, L = len(block_ids), len(self.cfg.page_layers)
+        if n == 0 and state is None:
+            return cache
+        nbytes = L * n * self.wire_page_bytes + (
+            self.cfg.slot_bytes if state else 0)
+        with tracing.span("kv.load_pages", pages=L * n, bytes=nbytes):
+            stages = dict.fromkeys(LOAD_STAGES, 0.0)
+            t0 = time.perf_counter()
+            stacked = self.fetch_pages(
+                chunk_keys_, layers=self.cfg.page_layers, stages=stages
+            ) if n else None
+            loaded = self._fetch_state(state[1], stages) if state else None
+            t1 = time.perf_counter()
+            # every byte of both kinds is here: now the cache is written
+            if stacked is not None:
+                pages = self.scatter_pages(pages, block_ids, stacked)
+            if loaded is not None:
+                conv = _set_slot(conv, jnp.asarray(state[0], jnp.int32), loaded)
+            self._landed((pages, conv), t0, t1, stages, L * n,
+                         n * self.cfg.block_tokens)
+        return pages, conv
+
+    @property
+    def _last_page_layer(self) -> int:
+        return self.cfg.page_layers[-1]
+
+    def lookup_prefix(self, chunk_keys_: Sequence[str],
+                      states: bool = False) -> int:
+        if not states:
+            return super().lookup_prefix(chunk_keys_,
+                                         probe_layer=self.cfg.page_layers[0])
+        # written after the pages and in layer order: the last state layer's
+        # says the checkpoint is whole
+        with tracing.span("kv.lookup_prefix", chunks=len(chunk_keys_)):
+            return self._deepest_whole(chunk_keys_, self.cfg.state_layers[-1])

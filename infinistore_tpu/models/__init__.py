@@ -48,6 +48,12 @@ from .retention import (
     retention_decode_forward,
     retention_prefill_forward,
 )
+from .lfm2_moe import (
+    Lfm2MoeConfig,
+    init_lfm2_moe_params,
+    lfm2_moe_decode_forward,
+    lfm2_moe_prefill_forward,
+)
 from .attention import (
     apply_rope,
     causal_attention,
@@ -80,6 +86,13 @@ def family_of(cfg) -> dict:
         return {"init": init_retention_params,
                 "fns": {"prefill_fn": retention_prefill_forward,
                         "decode_fn": retention_decode_forward}}
+    if isinstance(cfg, Lfm2MoeConfig):
+        # pages for its attention layers AND a state for its conv layers:
+        # ``serve`` gives it the hybrid engine (engine/hybrid_engine.py) by
+        # ``cfg.conv_state_shape``
+        return {"init": init_lfm2_moe_params,
+                "fns": {"prefill_fn": lfm2_moe_prefill_forward,
+                        "decode_fn": lfm2_moe_decode_forward}}
     return {"init": init_params, "fns": {}}
 
 
@@ -89,6 +102,10 @@ __all__ = [
     "mla_moe_prefill_forward",
     "mla_moe_decode_forward",
     "family_of",
+    "Lfm2MoeConfig",
+    "init_lfm2_moe_params",
+    "lfm2_moe_prefill_forward",
+    "lfm2_moe_decode_forward",
     "RetentionConfig",
     "init_retention_params",
     "retention_prefill_forward",

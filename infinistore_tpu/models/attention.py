@@ -264,7 +264,11 @@ def decode_kernel_engages(q, cache, window=None, softcap=None) -> bool:
     for this query: bf16 K and V
     by head in ``[T, D]`` tiles the TPU copies whole, a bf16 query, and
     attention over every live key (a window or a soft cap keeps the XLA
-    form).  Under a named mesh, only where ``tp`` alone divides the
+    form).  The tile's 128 lanes are what the kernel needs of ``D``: a page
+    whose heads are narrower holds several side by side in one row (heads
+    of 64 in pairs, models/lfm2_moe.py ``kv_page``; ``paged_decode_attention``
+    reads them so), and the query's width divides the page's.  Under a
+    named mesh, only where ``tp`` alone divides the
     program: the KV heads divide over it; a cache whose layers are spread
     over another axis (``pp``) keeps the XLA form.  Static: shapes, dtypes
     and the mesh named while tracing; arrays or their abstract values."""
@@ -274,6 +278,7 @@ def decode_kernel_engages(q, cache, window=None, softcap=None) -> bool:
     axes = dict(jax.sharding.get_abstract_mesh().shape)
     tp = axes.pop("tp", 1)
     return (planes == 2 and T % 16 == 0 and D % 128 == 0
+            and D % q.shape[-1] == 0
             and q.shape[-2] % Hkv == 0 and Hkv % tp == 0
             and all(n == 1 for n in axes.values())
             and cache.dtype == jnp.bfloat16 and q.dtype == jnp.bfloat16)
@@ -303,6 +308,12 @@ def paged_decode_attention(
     by the table; anywhere else the XLA form below, which gathers the
     whole padded table (``gather_layer_kv``) and masks by length.  The XLA
     form is the kernel's oracle in the tests.
+
+    A page whose last axis is ``n x D`` holds ``n`` adjacent KV heads side by
+    side in one row (cache ``[L, 2, H_kv / n, n_blocks, T, n x D]``: heads of
+    64 fill a 128-lane tile in pairs).  The XLA form views the gathered rows
+    as ``H_kv`` heads of ``D`` again; the kernel is handed each query head in
+    its own KV head's lanes of a row of zeros (``lanes_of_own_head``).
     """
     xla = functools.partial(_paged_decode_attention_xla, layer=layer,
                             window=window, softcap=softcap)
@@ -311,10 +322,39 @@ def paged_decode_attention(
     # Pallas is a second of import: paid by the programs that can hold the kernel
     from . import paged_decode_kernel
 
+    kernel = functools.partial(
+        paged_decode_kernel.paged_decode_attention_kernel, layer=layer)
+    if cache.shape[-1] != q.shape[-1]:
+        kernel = functools.partial(_kernel_over_side_by_side_heads, kernel)
     return jax.lax.platform_dependent(
-        q, cache, block_table, seq_lens, default=xla,
-        tpu=functools.partial(
-            paged_decode_kernel.paged_decode_attention_kernel, layer=layer))
+        q, cache, block_table, seq_lens, default=xla, tpu=kernel)
+
+
+def lanes_of_own_head(x: jax.Array, n: int, heads: int, out: bool = False):
+    """Between a query by head ``[B, H, D]`` and the same query laid into rows
+    as wide as a page that holds ``n`` KV heads side by side ``[B, H, n x D]``:
+    a query head whose KV head is the ``j``-th of its row has its values in
+    lanes ``[j D, (j + 1) D)`` and zeros in the others, so that the row's dot
+    product with the page's row is its dot product with its own head's key.
+    ``heads``: the page's rows of heads (``H_kv / n``).  ``out``: the way back,
+    which keeps of each weighted sum of rows the lanes of the head's own
+    values."""
+    B, H = x.shape[:2]
+    own = jnp.eye(n, dtype=x.dtype)
+    if out:
+        D = x.shape[-1] // n
+        return jnp.einsum("bpjgkd,jk->bpjgd",
+                          x.reshape(B, heads, n, -1, n, D), own).reshape(B, H, D)
+    D = x.shape[-1]
+    return jnp.einsum("bpjgd,jk->bpjgkd",
+                      x.reshape(B, heads, n, -1, D), own).reshape(B, H, n * D)
+
+
+def _kernel_over_side_by_side_heads(kernel, q, cache, block_table, seq_lens):
+    n, heads = cache.shape[-1] // q.shape[-1], cache.shape[2]
+    o = kernel(lanes_of_own_head(q, n, heads), cache, block_table, seq_lens,
+               scale=1.0 / np.sqrt(q.shape[-1]))
+    return lanes_of_own_head(o, n, heads, out=True)
 
 
 def _paged_decode_attention_xla(q, cache, block_table, seq_lens, *, layer,
@@ -323,6 +363,8 @@ def _paged_decode_attention_xla(q, cache, block_table, seq_lens, *, layer,
     gathered, contracted against the grouped query, masked by length."""
     B, H, D = q.shape
     k, v = gather_layer_kv(cache, layer, block_table)
+    if k.shape[-1] != D:    # KV heads side by side in a row: by head again
+        k, v = (x.reshape(B, x.shape[1], -1, D) for x in (k, v))
     S_max, Hkv = k.shape[1:3]
     # query head h pairs with KV head h // G: [B, H_kv, G, D] is that
     # pairing as a reshape, and the pages are contracted as gathered

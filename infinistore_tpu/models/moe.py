@@ -119,14 +119,17 @@ def top_k_gates(router_logits: jax.Array, top_k: int) -> jax.Array:
 
 
 def sigmoid_top_k(scores: jax.Array, bias: jax.Array, top_k: int,
-                  scaling: float) -> Tuple[jax.Array, jax.Array]:
+                  scaling: float, eps: float = 1e-20
+                  ) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid scores [N, E] (float32) -> (experts [N, k], weights [N, k]):
     the k largest of ``scores + bias`` are chosen (the bias steers the choice
     only), and their OWN scores, normalised to sum to one and times
-    ``scaling``, weigh them (``noaux_tc`` with one group)."""
+    ``scaling``, weigh them (``noaux_tc`` with one group).  ``eps`` is what a
+    family's code adds to the chosen scores' sum (1e-6 makes the weights sum
+    to one less its share)."""
     _, idx = jax.lax.top_k(scores + bias, top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scaling
+    w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps) * scaling
     return idx.astype(jnp.int32), w
 
 

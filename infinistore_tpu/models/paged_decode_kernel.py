@@ -144,8 +144,8 @@ def _kernel(table_ref, lens_ref, layer_ref, q_ref, cache_ref, o_ref, buf, sems,
     lax.fori_loop(0, B, row, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _call(q, cache, table, seq_lens, layer, interpret=False):
+@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
+def _call(q, cache, table, seq_lens, layer, interpret=False, scale=None):
     """The kernel on one device's arrays.  q: [B, H_kv, G, D]; table: [B,
     width]; layer: int32[1] -> [B, H_kv, G, D] float32.
 
@@ -158,7 +158,7 @@ def _call(q, cache, table, seq_lens, layer, interpret=False):
     T = cache.shape[4]
     return pl.pallas_call(
         functools.partial(_kernel, width=table.shape[1],
-                          scale=1.0 / np.sqrt(D)),
+                          scale=scale or 1.0 / np.sqrt(D)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),
@@ -177,19 +177,21 @@ def _call(q, cache, table, seq_lens, layer, interpret=False):
 
 
 def paged_decode_attention_kernel(q, cache, block_table, seq_lens, *, layer,
-                                  interpret=False):
+                                  interpret=False, scale=None):
     """``attention.paged_decode_attention`` without window or soft cap, on
     the TPU (``interpret=True``: on any backend, for the tests).
 
     q: [B, H, D]; cache: [L, 2, H_kv, n_blocks, T, D] bf16, the whole
     cache, left in HBM; block_table: [B, width] int32; seq_lens: [B];
-    layer: a Python int.  -> [B, H, D] in q's dtype."""
+    layer: a Python int.  -> [B, H, D] in q's dtype.  ``scale``: the
+    scores' scale where it is not ``1 / sqrt(D)`` (a query laid into a row
+    wider than its head: attention.lanes_of_own_head)."""
     B, H, D = q.shape
     Hkv = cache.shape[2]
     args = (q.reshape(B, Hkv, H // Hkv, D), cache,
             block_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
             jnp.full((1,), layer, jnp.int32))
-    call = functools.partial(_call, interpret=interpret)
+    call = functools.partial(_call, interpret=interpret, scale=scale)
     mesh = jax.sharding.get_abstract_mesh()
     if dict(mesh.shape).get("tp", 1) > 1:
         # a program partitioned over a mesh (parallel/sharding.py, the

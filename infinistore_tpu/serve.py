@@ -2550,14 +2550,18 @@ def main(argv: Optional[List[str]] = None) -> None:
                     "refuses the option")
     ap.add_argument("--state-stride", type=int, default=None,
                     help="for a model whose layers keep a STATE and no key "
-                    "or value per token (power retention): a prompt's state "
-                    "is checkpointed, in a resident slot and in the store, "
-                    "at its deepest multiple of this many tokens, and a "
-                    "later prompt can start from there; a multiple of "
-                    "--prefill-chunk.  The device holds --n-blocks x "
-                    "--block-tokens / --state-stride slots, --max-batch of "
-                    "them the running rows'.  Any other model refuses the "
-                    "option")
+                    "or value per token, all of them (power retention) or "
+                    "some beside its attention layers' pages (gated short "
+                    "convolutions): a prompt's state is checkpointed, in a "
+                    "resident slot and in the store, at multiples of this "
+                    "many tokens (the first kind at a prompt's deepest, the "
+                    "second at every one its prefill passes), and a later "
+                    "prompt can start from there (the second kind: where "
+                    "its pages match too); a multiple of --prefill-chunk.  "
+                    "The device holds --n-blocks x --block-tokens / "
+                    "--state-stride slots, --max-batch of them the running "
+                    "rows'.  A model whose every layer keeps pages refuses "
+                    "the option")
     ap.add_argument("--block-tokens", type=int, default=16)
     ap.add_argument("--prefill-chunk", type=int, default=None)
     ap.add_argument("--decode-chunk", type=int, default=32,
@@ -2695,7 +2699,8 @@ def main(argv: Optional[List[str]] = None) -> None:
         bad = [flag for flag, on in (
             ("--tp/--pp", mesh is not None),
             ("--kv-quant int8",
-             args.kv_quant != "none" and cfg.kv_page[0] != 2),
+             args.kv_quant != "none" and (
+                 cfg.kv_page[0] != 2 or hasattr(cfg, "conv_state_shape"))),
             ("--draft-model", args.draft_model is not None),
             ("--ngram-spec", args.ngram_spec)) if on]
         if bad:
@@ -2703,8 +2708,8 @@ def main(argv: Optional[List[str]] = None) -> None:
                 f"{name}: this model family is served without "
                 f"{', '.join(bad)} (it has no verify step and no mesh "
                 f"specs, and only a page of K and V by head has an int8 "
-                f"scale); pass --kv-quant none for such a page and drop "
-                f"the rest")
+                f"scale, a state none); pass --kv-quant none for such a "
+                f"page or state and drop the rest")
 
     def load_model(name: str, seed: int = 0, mesh=None):
         """Returns (model_id, cfg, params, engine_fns) — engine_fns routes
@@ -2778,32 +2783,43 @@ def main(argv: Optional[List[str]] = None) -> None:
                 f"no usable tokenizer in {tok_src!r}; serving token ids only"
             )
     engine_cls = InferenceEngine
-    if hasattr(cfg, "state_shape"):
-        # the cache's unit is a state, not a page (engine/state_engine.py)
-        from .engine.state_engine import StateEngine
-        from .kv.cache import StateCacheConfig
-
+    # what a sequence keeps of this model: pages, a state a layer, or both
+    keeps_state = hasattr(cfg, "state_shape")
+    keeps_both = hasattr(cfg, "conv_state_shape")
+    if keeps_state or keeps_both:
+        # the cache's unit is a state, not a page (engine/state_engine.py), or
+        # a state for some layers beside pages for the others
+        # (engine/hybrid_engine.py)
+        what = ("keeps pages for its attention layers and a state for the "
+                "others" if keeps_both else "keeps a state a layer and no pages")
         if args.state_stride is None or args.window_blocks is not None:
             raise SystemExit(
-                f"{args.model}: this model keeps a state a layer and no "
-                f"pages: pass --state-stride (and --prefill-chunk, which it "
-                f"is a multiple of), and no --window-blocks")
+                f"{args.model}: this model {what}: pass --state-stride (and "
+                f"--prefill-chunk, which it is a multiple of), and no "
+                f"--window-blocks")
         if (args.prefill_chunk is None
                 or args.state_stride % args.prefill_chunk):
             raise SystemExit(
                 f"--state-stride {args.state_stride} must be a multiple of "
                 f"--prefill-chunk ({args.prefill_chunk}): a checkpoint is "
                 f"taken at the end of a chunk")
+        if keeps_both:
+            from .engine.hybrid_engine import HybridEngine as engine_cls
+            from .kv.cache import HybridCacheConfig as cache_cls
+        else:
+            from .engine.state_engine import StateEngine as engine_cls
+            from .kv.cache import StateCacheConfig as cache_cls
         try:
-            pc = StateCacheConfig.for_model(
+            pc = cache_cls.for_model(
                 cfg, args.n_blocks, args.block_tokens, args.state_stride,
                 max_rows=args.max_batch)
         except ValueError as e:
             raise SystemExit(f"--state-stride: {e}")
-        engine_cls = StateEngine
     elif args.state_stride is not None:
         raise SystemExit("--state-stride is the checkpoint stride of a model "
-                         "whose layers keep a state; this model keeps pages")
+                         "whose layers (all, or some beside its attention "
+                         "layers) keep a state; every layer of this model "
+                         "keeps pages")
     else:
         try:
             pc = PagedCacheConfig.for_model(

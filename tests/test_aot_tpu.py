@@ -450,6 +450,71 @@ def test_retention_decode_scan_on_tpu_moves_each_rows_state_in_place(v5e):
     assert mem.temp_size_in_bytes < 5 * pc.page_bytes * 2, mem
 
 
+def test_hybrid_decode_scan_on_tpu_reads_heads_of_64_through_the_kernel(v5e):
+    """The short-convolution / attention family at its published widths (its
+    first six layers: one attention layer of 32 query heads over 8 key/value
+    heads of 64, five conv layers; 8 rows x 2,048 pages).  A lone head of 64
+    does not lower in the kernel (the compiler's words below), so the page
+    holds the heads side by side in pairs: the attention layer is ONE call of
+    the kernel, no gathered table exists (4.3 GB of temporaries at 1,024
+    pages with the XLA form), and pages and state slots that come out are the
+    donated ones."""
+    from infinistore_tpu.kv.cache import HybridCacheConfig
+    from infinistore_tpu.models import attention, paged_decode_kernel
+
+    chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    batch, width = 8, 2048
+    lone = jax.jit(functools.partial(
+        paged_decode_kernel.paged_decode_attention_kernel, layer=0)).lower(
+        sds((batch, 32, 64), jnp.bfloat16),
+        sds((1, 2, 8, N_BLOCKS, T, 64), jnp.bfloat16),
+        sds((batch, width), jnp.int32), sds((batch,), jnp.int32))
+    with pytest.raises(Exception, match=r"aligned to tiling \(128\), but is 64"):
+        lone.compile()
+
+    cfg = models.Lfm2MoeConfig(n_layers=6, layer_types=(
+        "conv", "conv", "full_attention", "conv", "conv", "conv"))
+    pc = HybridCacheConfig.for_model(cfg, 10240, T, 512, max_rows=batch)
+    assert (pc.n_kv_heads, pc.head_dim, pc.page_bytes) == (4, 128, 32768)
+    assert attention.decode_kernel_engages(
+        jax.ShapeDtypeStruct((batch, 32, 64), cfg.dtype),
+        jax.ShapeDtypeStruct((1, 2, 4, 10240, T, 128), cfg.dtype))
+    params = _shaped(jax.eval_shape(
+        lambda: models.init_lfm2_moe_params(cfg, jax.random.PRNGKey(0))), chip)
+    cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
+
+    def decode_scan(params, logits, pos, cache, table):
+        def step(carry, i):
+            logits, cache = carry
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            p = pos + i
+            blocks = jnp.take_along_axis(table[0], (p // T)[:, None], axis=1)[:, 0]
+            logits, cache = models.lfm2_moe_decode_forward(
+                params, cfg, tok, p, cache, table, p + 1,
+                (blocks, table[1][:, 0]), p % T)
+            return (logits, cache), tok
+
+        (logits, cache), toks = jax.lax.scan(
+            step, (logits, cache), jnp.arange(3))
+        return toks, logits, cache
+
+    compiled = jax.jit(decode_scan, donate_argnums=(3,)).lower(
+        params, sds((batch, cfg.vocab_size), cfg.dtype),
+        sds((batch,), jnp.int32), cache,
+        (sds((batch, width), jnp.int32), sds((batch, 1), jnp.int32)),
+    ).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text)) == 1
+    tables = _bf16_results_of(
+        text, batch * width * T * cfg.n_kv_heads * cfg.head_dim,
+        _weight_shapes(params))
+    assert not tables, tables
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pc.cache_bytes, mem
+    assert mem.temp_size_in_bytes < 256 << 20, mem
+
+
 # the largest push of each cell: a 512-token chunk's 32 pages of every layer
 # (the cells' caches as BENCHMARK.json's configurations size them), and
 # what the TPU compiler says the one program keeps live beside its bands

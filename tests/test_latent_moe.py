@@ -407,9 +407,10 @@ def test_benchmark_configuration_resolves(entry, tmp_path):
     # the fill check's product is the cache as the server allocates it: one
     # array, or one pool a layer kind (--window-blocks in serve.args); a
     # family whose cache is state slots is held to its own shapes in
-    # tests/test_retention.py::test_allocated_bytes_equal_the_counts
+    # tests/test_retention.py::test_allocated_bytes_equal_the_counts, one
+    # whose sequence keeps pages AND a state in tests/test_lfm2_moe.py's
     sv = spec["serve"]
-    if hasattr(cfg, "state_shape"):
+    if hasattr(cfg, "state_shape") or hasattr(cfg, "conv_state_shape"):
         assert "--state-stride" in sv["args"]
         return
     window_blocks = (int(sv["args"][sv["args"].index("--window-blocks") + 1])

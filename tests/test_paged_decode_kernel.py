@@ -30,7 +30,7 @@ LENGTHS = (1, T - 1, T + 1, PAGES_PER_BLOCK * T, PAGES_PER_BLOCK * T + 1,
 GROUPS = [(8, 4), (4, 7), (8, 16)]       # (H_kv, G) of the benchmark's cells
 
 
-def _case(h_kv, group, lengths, width, pad_rows=2, seed=0):
+def _case(h_kv, group, lengths, width, pad_rows=2, seed=0, D=D):
     """A cache whose pages are random, a table that gives each row its own
     pages in a shuffled order, ``pad_rows`` rows whose table is ``n_blocks``
     (``engine._block_table``'s pad), and a second cache in which every page
@@ -124,6 +124,47 @@ def test_a_row_is_bit_equal_alone_in_a_batch_of_32_and_under_a_wider_table(
     assert (batch == alone).all() and (batch == wider).all()
 
 
+def _side_by_side(cache, n=2):
+    """[L, 2, H_kv, blocks, T, d] -> [L, 2, H_kv / n, blocks, T, n d]: ``n``
+    adjacent KV heads in one row, as models/lfm2_moe.py lays its pages out."""
+    L_, P, H, nb, T_, d = cache.shape
+    return cache.reshape(L_, P, H // n, n, nb, T_, d).transpose(
+        0, 1, 2, 4, 5, 3, 6).reshape(L_, P, H // n, nb, T_, n * d)
+
+
+def _kernel_by_pairs(q, cache, table, lens):
+    import functools
+
+    return np.asarray(attention._kernel_over_side_by_side_heads(
+        functools.partial(paged_decode_attention_kernel, layer=LAYER,
+                          interpret=True), q, cache, table, lens), np.float32)
+
+
+def test_heads_of_64_side_by_side_in_pairs_agree_with_the_xla_form_by_head():
+    """A head of 64 fills half a lane row and does not lower alone; two KV
+    heads side by side do (8 heads of 64, groups of 4: 4 rows of 128).  The
+    kernel over such a page, each query head laid into its own head's lanes,
+    against the XLA form over the SAME values by head; the XLA form over the
+    paired page is that to the bit; dead pages are NaN; a row is bit-equal
+    alone and in the batch."""
+    q, cache, poisoned, table, lens = _case(8, 4, LENGTHS, WIDTH, D=64)
+    assert attention.decode_kernel_engages(q, _side_by_side(cache))
+    assert not attention.decode_kernel_engages(q, cache)
+    want = np.asarray(attention._paged_decode_attention_xla(
+        q, cache, table, lens, layer=LAYER), np.float32)
+    paired = np.asarray(attention._paged_decode_attention_xla(
+        q, _side_by_side(cache), table, lens, layer=LAYER), np.float32)
+    live = len(LENGTHS)
+    assert np.array_equal(paired[:live], want[:live])
+    got = _kernel_by_pairs(q, _side_by_side(poisoned), table, lens)
+    assert np.isfinite(got).all() and got.shape == want.shape
+    np.testing.assert_allclose(got[:live], want[:live], atol=2e-2)
+    assert (got[live:] == 0).all()
+    alone = _kernel_by_pairs(q[4:5], _side_by_side(poisoned), table[4:5],
+                             lens[4:5])[0]
+    assert (alone == got[4]).all()
+
+
 def test_a_batch_of_pad_rows_only_reads_nothing():
     q, _, poisoned, table, lens = _case(4, 7, (), WIDTH, pad_rows=3)
     assert (_kernel(q, poisoned, table, lens) == 0).all()
@@ -149,9 +190,12 @@ def _query(dtype=jnp.bfloat16, heads=28):
     (_query(), _page(tokens=8), {}, False),              # half a bf16 tile
     (_query(), _page(width=64), {}, False),              # half a lane row
     (_query(heads=30), _page(), {}, False),
+    # heads of 64 in pairs: a query of 64 over rows of 128
+    (jax.ShapeDtypeStruct((2, 32, 64), jnp.bfloat16), _page(), {}, True),
+    (jax.ShapeDtypeStruct((2, 32, 96), jnp.bfloat16), _page(), {}, False),
 ], ids=["qwen2.5", "command-a", "window", "softcap", "int8-page", "float32",
         "float32-query", "latent-page", "8-token-page", "64-wide-head",
-        "ragged-groups"])
+        "ragged-groups", "heads-of-64-in-pairs", "a-width-that-does-not-divide"])
 def test_the_kernel_is_offered_by_shapes_and_dtypes_alone(q, cache, kwargs, engages):
     assert attention.decode_kernel_engages(q, cache, **kwargs) is engages
 

@@ -252,6 +252,10 @@ def scraped():
     out["shared_tokens_recomputed"] = val(
         "istpu_engine_state_shared_tokens_recomputed_total")
     out["resident_evicted"] = val("istpu_engine_state_resident_evicted_total")
+    # what an engine of pages AND slots counts besides (engine/hybrid_engine.py)
+    out["store_hits"] = val("istpu_engine_state_store_hits_total", depth="all")
+    out["store_hits_full"] = val("istpu_engine_state_store_hits_total",
+                                 depth="full")
     return out
 
 
