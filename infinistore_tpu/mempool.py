@@ -472,6 +472,19 @@ class MM:
     def view(self, pool_idx: int, offset: int, size: int) -> memoryview:
         return self.pools[pool_idx].buf[offset : offset + size]
 
+    def region_bytes(self, size: int) -> int:
+        """What one region of ``size`` bytes takes from a pool."""
+        if self.allocator == "sizeclass":
+            return self._class_of(size)
+        return _round_up(size, self.block_size)
+
+    def free_bytes(self) -> int:
+        free = sum((p.total_blocks - p.allocated_blocks) * p.block_size
+                   for p in self.pools)
+        if self.allocator == "sizeclass":
+            free += self._budget - self._carved  # uncarved is capacity too
+        return free
+
     def usage(self) -> float:
         used = sum(p.allocated_blocks * p.block_size for p in self.pools)
         if self.allocator == "sizeclass":

@@ -13,8 +13,13 @@ Semantics preserved from the reference:
   KEY_NOT_FOUND if *any* requested key is missing (src/infinistore.cpp:612-617);
 * stored size must fit the reader's block size (src/infinistore.cpp:620-624);
 * eviction pops from the LRU head until usage < min threshold
-  (src/infinistore.cpp:223-234), with the same on-demand thresholds before
-  allocation (0.8/0.95, src/infinistore.cpp:52-53);
+  (src/infinistore.cpp:223-234), with the same on-demand thresholds
+  (0.8/0.95, src/infinistore.cpp:52-53).  The on-demand pass is not made
+  inside the allocation that crossed 0.95, as the reference makes it: that
+  allocation marks the store DRAINING, and the same victims leave in the
+  same order in bounded slices (``drain_step``) that the server runs
+  between requests.  An allocation evicts on its own path only when it
+  would fail otherwise, and then only what it lacks;
 * ``get_match_last_index`` binary-searches for the last present key, which
   assumes present keys form a prefix of the list -- exactly the reference's
   algorithm (src/infinistore.cpp:786-802);
@@ -61,6 +66,13 @@ from .utils import checksum as _checksum
 
 ON_DEMAND_MIN_THRESHOLD = 0.8  # reference: src/infinistore.cpp:52
 ON_DEMAND_MAX_THRESHOLD = 0.95  # reference: src/infinistore.cpp:53
+# entries one slice of the on-demand drain examines before it yields to the
+# server's loop: one ALLOC_PUT's worth of a push.  Read on a 3 GiB pool of
+# 32 KB pages held full (CPU, PR 44): the one pass took 14,784 entries in
+# 82-204 ms; a slice of 96 takes 1.0 ms in the median and 1.9 ms at p99, the
+# usage meter 38% of an entry and the bitmap 15%.  So a request that arrives
+# mid-drain waits about a millisecond.
+DRAIN_SLICE_ENTRIES = 96
 READ_LEASE_S = 5.0
 # how long an allocated-but-uncommitted reservation may sit before the
 # store reaps it.  Alloc-first clients (HELLO_FLAG_ALLOC_FIRST) learn
@@ -112,6 +124,12 @@ class Stats:
     hits: int = 0
     misses: int = 0
     evicted: int = 0
+    # of those, by who paid: the on-demand drain's slices (between
+    # requests) against an allocation's own path (what it lacked, or the
+    # sizeclass allocator's pressure pops); the rest are evict() passes
+    evicted_drain: int = 0
+    evicted_inline: int = 0
+    drain_slices: int = 0
     bytes_in: int = 0
     bytes_out: int = 0
     spilled: int = 0    # DRAM -> disk tier at eviction (pressure)
@@ -846,6 +864,15 @@ class Store:
         # measured put path (the perf-smoke floor)
         self._unstamped: deque = deque()
         self._scrub_keys: List[bytes] = []  # current scrub pass snapshot
+        # on-demand eviction (here so hand-built test stores get it too):
+        # set by the allocation that finds usage at the high threshold,
+        # cleared by the drain_step that brings it under the low one;
+        # leased heads rotated past since the drain began (its one way to
+        # end with the pool still full); callable(seconds) for the time an
+        # allocation spent evicting on its own path
+        self.draining = False
+        self._drain_skipped = 0
+        self.evict_stall_sink: Optional[Callable[[float], None]] = None
         # spill-tier knobs (initialized here so hand-built test stores
         # get them too): an entry is DEMOTABLE once it has sat untouched
         # this long AND the pool is at least this full; the DOA gate
@@ -956,40 +983,84 @@ class Store:
 
     # ---- eviction / pool growth ----
 
-    def evict(self, min_threshold: float, max_threshold: float) -> int:
-        evicted = 0
-        # both reapers ride every evict pass (periodic loop + the
-        # on-demand pass _allocate runs): lapsed read leases free their
-        # deferred blocks, lapsed reservations free leaked pending ones
-        self._reap_deferred(self._clock())
-        self.reap_pending()
-        if self.mm.usage() >= max_threshold:
-            now = self._clock()
-            skipped = []
-            while self.mm.usage() >= min_threshold and self.kv:
-                key, e = next(iter(self.kv.items()))
-                if e.lease > now:
-                    # leased for an in-flight shm read; rotate past it
-                    self.kv.move_to_end(key)
-                    skipped.append(key)
-                    if len(skipped) >= len(self.kv):
-                        break
-                    continue
-                del self.kv[key]
-                self.analytics.on_evict(
-                    now - (e.last_access or now), e.hits == 0
-                )
-                self.usage_meter.on_evict(
-                    self._entry_accounts(e), e.account, e.size,
-                    never_read=e.hits == 0,
-                )
-                # spill before the blocks are reused: the entry is not
-                # leased (checked above), so the bytes are stable
-                if self._spill_entry(key, e):
-                    self.stats.spilled += 1
-                self._free(e)
+    def _evict_head(self, now: float) -> bool:
+        """Evict the LRU head, or rotate past it when an shm reader holds
+        its lease (False).  Every eviction path takes its victims here, so
+        the order, the attribution and the spill are the same whoever asks."""
+        key, e = next(iter(self.kv.items()))
+        if e.lease > now:
+            self.kv.move_to_end(key)
+            return False
+        del self.kv[key]
+        self.analytics.on_evict(now - (e.last_access or now), e.hits == 0)
+        self.usage_meter.on_evict(
+            self._entry_accounts(e), e.account, e.size,
+            never_read=e.hits == 0,
+        )
+        # spill before the blocks are reused: the entry is not leased
+        # (checked above), so the bytes are stable
+        if self._spill_entry(key, e):
+            self.stats.spilled += 1
+        self._free(e)
+        self.stats.evicted += 1
+        return True
+
+    def _evict_lru(self, more: Callable[[int], bool]) -> int:
+        """Evict LRU heads while ``more(evicted so far)``, until the map is
+        empty or every entry left is leased.  Returns entries evicted."""
+        now = self._clock()
+        evicted = skipped = 0
+        while self.kv and skipped < len(self.kv) and more(evicted):
+            if self._evict_head(now):
                 evicted += 1
-        self.stats.evicted += evicted
+            else:
+                skipped += 1
+        return evicted
+
+    def evict(self, min_threshold: float, max_threshold: float) -> int:
+        """One whole pass, as the reference makes it: the manage plane's
+        and the periodic loop's call (an operator asked for it)."""
+        # both reapers ride every evict pass: lapsed read leases free their
+        # deferred blocks, lapsed reservations free leaked pending ones
+        now = self._clock()
+        self._reap_deferred(now)
+        self.reap_pending(now)
+        if self.mm.usage() < max_threshold:
+            return 0
+        return self._evict_lru(lambda _: self.mm.usage() >= min_threshold)
+
+    def drain_step(self, max_entries: int = DRAIN_SLICE_ENTRIES) -> int:
+        """One slice of the on-demand pass an allocation asked for at
+        ``ON_DEMAND_MAX_THRESHOLD``: the same LRU heads ``evict`` would
+        take, at most ``max_entries`` examined, so the server's loop
+        answers requests between slices.  Clears ``draining`` once usage is
+        under ``ON_DEMAND_MIN_THRESHOLD`` (or nothing evictable is left:
+        every entry leased).  Returns entries evicted; a store that is not
+        draining is left alone."""
+        if not self.draining:
+            return 0
+        now = self._clock()
+        evicted = 0
+        for _ in range(max_entries):
+            if not (self.mm.usage() >= ON_DEMAND_MIN_THRESHOLD and self.kv
+                    and self._drain_skipped < len(self.kv)):
+                self.draining = False
+                break
+            if self._evict_head(now):
+                evicted += 1
+            else:
+                self._drain_skipped += 1
+        self.stats.evicted_drain += evicted
+        self.stats.drain_slices += 1
+        return evicted
+
+    def _evict_lacking(self, need: int) -> int:
+        """An allocation's own eviction while the store drains: LRU heads
+        until ``need`` bytes are free, and never past the drain's own end."""
+        evicted = self._evict_lru(
+            lambda _: self.mm.free_bytes() < need
+            and self.mm.usage() >= ON_DEMAND_MIN_THRESHOLD)
+        self.stats.evicted_inline += evicted
         return evicted
 
     def maybe_extend(self) -> bool:
@@ -1007,26 +1078,8 @@ class Store:
         own entries — instead of answering OUT_OF_MEMORY while evictable
         data sits in the way.  Leased entries are skipped; spill-to-disk
         semantics match evict()."""
-        now = self._clock()
-        evicted = 0
-        skipped = 0
-        while evicted < n and self.kv and skipped < len(self.kv):
-            key, e = next(iter(self.kv.items()))
-            if e.lease > now:
-                self.kv.move_to_end(key)
-                skipped += 1
-                continue
-            del self.kv[key]
-            self.analytics.on_evict(now - (e.last_access or now), e.hits == 0)
-            self.usage_meter.on_evict(
-                self._entry_accounts(e), e.account, e.size,
-                never_read=e.hits == 0,
-            )
-            if self._spill_entry(key, e):
-                self.stats.spilled += 1
-            self._free(e)
-            evicted += 1
-        self.stats.evicted += evicted
+        evicted = self._evict_lru(lambda done: done < n)
+        self.stats.evicted_inline += evicted
         return evicted
 
     # ---- spill tier: admission, demotion ----
@@ -1174,14 +1227,22 @@ class Store:
         return out
 
     def _allocate(self, size: int, n: int):
-        """On-demand-evict + allocate + auto-extend-retry (+ class-
-        pressure eviction for the sizeclass allocator).
+        """Allocate, with the on-demand eviction around it (+ auto-extend
+        retry, + class-pressure eviction for the sizeclass allocator).
+
+        At ``ON_DEMAND_MAX_THRESHOLD`` the store is marked DRAINING and the
+        pass down to ``ON_DEMAND_MIN_THRESHOLD`` is left to ``drain_step``'s
+        slices, between requests.  This call evicts only if it would fail
+        while that drain is still under way, and then what it lacks: the
+        pass itself took 82-199 ms of a 3 GiB pool inside one ALLOC_PUT.
 
         Batches (n > 1) first try ONE contiguous run so a batch put's
         descriptors coalesce into bulk memcpys client-side; a fragmented
         pool falls back to the per-region allocator, which only costs the
         batch its mergeability, never the allocation."""
-        self.evict(ON_DEMAND_MIN_THRESHOLD, ON_DEMAND_MAX_THRESHOLD)
+        now = self._clock()
+        self._reap_deferred(now)
+        self.reap_pending(now)
 
         def _try_alloc():
             if n > 1:
@@ -1192,6 +1253,23 @@ class Store:
             return self.mm.allocate(size, n)
 
         regions = _try_alloc()
+        if (not self.draining
+                and self.mm.usage() >= ON_DEMAND_MAX_THRESHOLD):
+            # this allocation crossed the mark, or found the pool there (a
+            # drain that ended on leases is asked again)
+            self.draining = True
+            self._drain_skipped = 0
+        if regions is None and self.draining:
+            # the drain has not come this far yet.  Free bytes are not a
+            # free run: a round that frees what is lacking and still finds
+            # no place frees one entry more each time after it
+            t0 = time.perf_counter()
+            need = n * self.mm.region_bytes(size)
+            while regions is None and self._evict_lacking(
+                    max(need, self.mm.free_bytes() + 1)):
+                regions = _try_alloc()
+            if self.evict_stall_sink is not None:
+                self.evict_stall_sink(time.perf_counter() - t0)
         if regions is None and self.maybe_extend():
             regions = _try_alloc()
         if (regions is None and self.mm.allocator == "sizeclass"
@@ -1587,7 +1665,7 @@ class Store:
         "disk_entries", "disk_bytes", "disk_degraded",
         "active_read_leases", "deferred_frees", "fragmentation",
         "free_bytes", "largest_free_run_bytes", "free_runs",
-        "epoch", "stamp_backlog",
+        "epoch", "stamp_backlog", "draining",
     })
 
     def cache_report(self, top_n: int = 10) -> dict:
@@ -1656,6 +1734,10 @@ class Store:
             "hits": s.hits,
             "misses": s.misses,
             "evicted": s.evicted,
+            "evicted_drain": s.evicted_drain,
+            "evicted_inline": s.evicted_inline,
+            "drain_slices": s.drain_slices,
+            "draining": int(self.draining),
             "bytes_in": s.bytes_in,
             "bytes_out": s.bytes_out,
             "contig_batches": s.contig_batches,
