@@ -1,6 +1,7 @@
 """The engine over a cache of TWO KINDS: pages for a stack's attention layers
 and, beside them in the same sequence, a state of fixed size for its other
-layers (gated short convolutions, models/lfm2_moe.py; kv/cache.py
+layers (gated short convolutions, models/lfm2_moe.py; Mamba selective-scan
+layers, whose state is float32, models/jamba.py; kv/cache.py
 ``HybridCacheConfig``).
 
 It is the paged engine (``InferenceEngine``: the allocator, the prefix page
@@ -10,7 +11,7 @@ engine/state_engine.py (``SlotBook``, ``StateSlots``, ``_copy_slot``); what is
 its own is the rule that joins the two:
 
 * ``self.cache`` is ``(pages [attention layers, 2, H_kv, n_blocks, T, D], slots
-  [n_slots, state layers, width])``, both donated through the prefill chunk
+  [n_slots] + pc.slot_shape)``, both donated through the prefill chunk
   and the decode scan.  A ``SequenceState`` holds ``block_ids`` AND a ``slot``;
   the scan's block table is ``(the pages' table, the rows' slots [B, 1])``.
 * **A checkpoint at every multiple of the stride** a prompt's prefill passes
@@ -173,7 +174,7 @@ class HybridEngine(SlotBook, InferenceEngine):
                      if c <= n_local and key_at(c) in self.slots), 0), False
                 cut_table(cut)
         if stored:
-            self._count(adopted_store=1)
+            self._count(adopted_store=1, bytes_loaded=self.pc.slot_bytes)
         elif cut and self._adopt_resident(key_at(cut), row):
             self._count(adopted_local=1)
         else:
@@ -216,6 +217,11 @@ class HybridEngine(SlotBook, InferenceEngine):
             self.cache, pp.block_ids[lo:hi], slot=pp.slot if ckpt else None)
 
     def _prefill_chunk(self, pp: PartialPrefill) -> None:
+        if getattr(self.cfg, "state_update", None) == "scan":
+            # the tokens the chunk's scan walks, its padding among them
+            n = min(pp.C, len(pp.padded) - pp.off)
+            self._count(scan_chunks=1, scan_tokens=n,
+                        scan_full_chunks=int(n == self.prefill_chunk))
         super()._prefill_chunk(pp)
         if self._checkpoint_at(pp, pp.done):
             # a copy, enqueued behind the chunk and before the next one's

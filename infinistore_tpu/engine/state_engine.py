@@ -69,6 +69,18 @@ _BYTES_PUSHED = _metrics.default_registry().counter(
     "istpu_engine_state_bytes_pushed_total",
     "Bytes of state checkpoints handed to the store",
 )
+_BYTES_LOADED = _metrics.default_registry().counter(
+    "istpu_engine_state_bytes_loaded_total",
+    "Bytes of state checkpoints that came back from the store into a row's "
+    "slot, where a sequence keeps pages and a state",
+)
+_SCAN = _metrics.default_registry().counter(
+    "istpu_engine_state_scan_total",
+    "Where a state's update is a scan over the chunk: the prefill chunks "
+    "that ran it, the whole chunks among them, and the tokens they walked, "
+    "padding included",
+    labelnames=("what",),
+)
 _ADOPTIONS = _metrics.default_registry().counter(
     "istpu_engine_state_adoptions_total",
     "Prompts that started from a checkpoint, by where it came from",
@@ -149,8 +161,10 @@ class SlotBook:
                 _ADOPTIONS.labels(k[len("adopted_"):]).inc(n)
             elif k in ("store_hits", "store_hits_full"):
                 _STORE_HITS.labels("full" if k.endswith("_full") else "all").inc(n)
+            elif k.startswith("scan_"):
+                _SCAN.labels(k[len("scan_"):]).inc(n)
             else:
-                {"bytes_pushed": _BYTES_PUSHED,
+                {"bytes_pushed": _BYTES_PUSHED, "bytes_loaded": _BYTES_LOADED,
                  "shared_tokens_recomputed": _SHARED_RECOMPUTED,
                  "resident_evicted": _EVICTED}[k].inc(n)
 

@@ -124,7 +124,9 @@ KV_COUNTS = ("store_pages_full", "store_pages_window",
 STATE_COUNTS = ("checkpoints_taken", "checkpoints_pushed",
                 "checkpoints_skipped_stored", "bytes_pushed",
                 "adopted_local", "adopted_store", "shared_tokens_recomputed",
-                "resident_evicted", "store_hits", "store_hits_full")
+                "resident_evicted", "store_hits", "store_hits_full",
+                "bytes_loaded", "scan_chunks", "scan_full_chunks",
+                "scan_tokens")
 
 # the key that counts operations in the transfer's running totals
 _STORE_COUNT = {"push": "pushes", "load": "loads"}
@@ -349,7 +351,12 @@ def note_state(**counts: int) -> None:
     keeps pages AND a state (engine/hybrid_engine.py), the prompts whose
     pages the store matched deeper than HBM held them (``store_hits``) and
     those of them that adopted pages and checkpoint at the deepest stride
-    that match reaches (``store_hits_full``).  Summed under ``rec["state"]``."""
+    that match reaches (``store_hits_full``), and the bytes of the checkpoints
+    that came back from the store (``bytes_loaded``); where the state's update
+    is a scan over the chunk (models/ssm_scan.py), the prefill chunks that ran
+    it, those of them that were whole chunks of ``prefill_chunk`` tokens, and
+    the tokens they walked, padding included (``scan_chunks``,
+    ``scan_full_chunks``, ``scan_tokens``).  Summed under ``rec["state"]``."""
     _sum_into("state", STATE_COUNTS, counts)
 
 

@@ -48,7 +48,8 @@ adopted (a state summarises everything before it, so it cannot be shared the
 way a read-only page is).
 
 A stack that mixes layers that keep pages with layers that keep a state
-(gated short convolutions among attention layers, models/lfm2_moe.py) holds
+(gated short convolutions among attention layers, models/lfm2_moe.py; Mamba
+selective-scan layers, whose state is float32, models/jamba.py) holds
 BOTH KINDS for one sequence: ``HybridCacheConfig`` is the paged cache over the
 attention layers alone and, beside it, state slots over the others under the
 same ``StateSlots`` bookkeeping; a prefix is reusable only at a position where
@@ -225,6 +226,16 @@ class StateCacheConfig:
         return self.n_slots * self.slot_bytes
 
 
+def cache_kind(cfg) -> str:
+    """What a sequence keeps of a model, asked of its config: ``"pages"`` for
+    every layer, ``"state"`` (a state a layer and no pages:
+    ``StateCacheConfig``) or ``"hybrid"`` (pages for its ``page_layers`` and a
+    state for its ``state_layers``: ``HybridCacheConfig``)."""
+    if hasattr(cfg, "state_layers"):
+        return "hybrid"
+    return "state" if hasattr(cfg, "state_shape") else "pages"
+
+
 def _check_slots(n_blocks: int, block_tokens: int, stride: int,
                  max_rows: int) -> None:
     """``ValueError``, in words, for a cache of ``n_blocks x block_tokens /
@@ -258,19 +269,35 @@ class HybridCacheConfig(PagedCacheConfig):
     state_width: int = 0
     stride: int = 0
     max_rows: int = 0
+    # the type a slot holds a state in and the store gets it in; None: the
+    # pages' (a shift register of activations); float32 where the state is a
+    # recurrence's accumulator (models/jamba.py)
+    state_dtype: Optional[jnp.dtype] = None
+    # 0: a slot holds a layer's state as one row ``[state_width]``.  n: as
+    # ``[state_width / n, n]``, so that the LAYER axis is no tiled axis of the
+    # slots: 26 layers on the sublanes would pad to 32, and the TPU's compact
+    # layout then puts the layers outermost, which every program that walks
+    # the slots by slot undoes with a copy of all of them, twice a launch
+    # (benchmarks/aot_check_jamba.py read 3.2 GB each way)
+    state_lanes: int = 0
 
     @classmethod
     def for_model(cls, cfg, n_blocks: int, block_tokens: int, stride: int,
                   max_rows: int) -> "HybridCacheConfig":
+        """From the kind's names, which every model that keeps both gives:
+        ``kv_page``, ``page_layers``, ``state_layers``, ``state_width`` and, where
+        a state is not held in the model's type, ``state_dtype``."""
         planes, heads, width = cfg.kv_page
         _check_slots(n_blocks, block_tokens, stride, max_rows)
         return cls(n_layers=cfg.n_layers, n_kv_heads=heads, head_dim=width,
                    n_blocks=n_blocks, block_tokens=block_tokens,
                    dtype=cfg.dtype, planes=planes,
-                   page_layers=tuple(cfg.attn_layers),
-                   state_layers=tuple(cfg.conv_layers),
-                   state_width=int(np.prod(cfg.conv_state_shape)),
-                   stride=stride, max_rows=max_rows)
+                   page_layers=tuple(cfg.page_layers),
+                   state_layers=tuple(cfg.state_layers),
+                   state_width=int(cfg.state_width),
+                   stride=stride, max_rows=max_rows,
+                   state_dtype=getattr(cfg, "state_dtype", None),
+                   state_lanes=getattr(cfg, "state_lanes", 0))
 
     @property
     def pools(self) -> Tuple[Tuple[Tuple[int, ...], int], ...]:
@@ -285,7 +312,20 @@ class HybridCacheConfig(PagedCacheConfig):
     def state_bytes(self) -> int:
         """One layer's state of one sequence, as it is held and as it goes
         to the store."""
-        return self.state_width * np.dtype(jnp.dtype(self.dtype)).itemsize
+        return self.state_width * self.slot_dtype.itemsize
+
+    @property
+    def slot_shape(self) -> Tuple[int, ...]:
+        """One slot as ``init_cache`` lays it out: every state layer's state."""
+        n = self.state_lanes
+        return (len(self.state_layers),) + (
+            (self.state_width // n, n) if n else (self.state_width,))
+
+    @property
+    def slot_dtype(self) -> jnp.dtype:
+        """The type the slots hold a state in."""
+        return jnp.dtype(self.dtype if self.state_dtype is None
+                         else self.state_dtype)
 
     @property
     def slot_bytes(self) -> int:
@@ -378,8 +418,7 @@ def init_cache(cfg, sharding=None):
     stack with a pool per layer kind a tuple of one array a pool
     (``cfg.pools``), each over its own layers and blocks; for a
     ``StateCacheConfig`` the slots ``(S, z)``; for a ``HybridCacheConfig``
-    ``(pages [page layers, ...], slots [n_slots, state layers,
-    state_width])``."""
+    ``(pages [page layers, ...], slots [n_slots] + ``cfg.slot_shape``)``."""
     if isinstance(cfg, StateCacheConfig):
         lead = (cfg.n_slots, cfg.n_layers, cfg.n_kv_heads, cfg.state_dim)
         return (jnp.zeros(lead + (cfg.head_dim,), cfg.dtype, device=sharding),
@@ -390,9 +429,8 @@ def init_cache(cfg, sharding=None):
                   dtype=cfg.dtype, device=sharding)
         for layers, n_blocks in cfg.pools)
     if isinstance(cfg, HybridCacheConfig):
-        return arrays[0], jnp.zeros(
-            (cfg.n_slots, len(cfg.state_layers), cfg.state_width),
-            cfg.dtype, device=sharding)
+        return arrays[0], jnp.zeros((cfg.n_slots,) + cfg.slot_shape,
+                                    cfg.slot_dtype, device=sharding)
     return arrays if cfg.window_layers else arrays[0]
 
 

@@ -942,12 +942,15 @@ def _gather_bands_and_state(pages, block_ids, groups, conv, slot):
     """``_gather_bands`` over the page layers and, in the same program, slot
     ``slot``'s state of every state layer ``[state layers, width]`` as one
     band more: the one launch a push costs the engine thread carries both."""
-    return _gather_bands(pages, block_ids, None, False, groups) + (conv[slot],)
+    bands = _gather_bands(pages, block_ids, None, False, groups)
+    state = conv[slot]
+    # a layer's state as one row, however the slots lay it out (slot_shape)
+    return bands + (state.reshape(state.shape[0], -1),)
 
 
 @partial(jax.jit, donate_argnums=(0,))
 def _set_slot(conv: jax.Array, slot: jax.Array, state: jax.Array) -> jax.Array:
-    """``state`` [state layers, width] into slot ``slot`` of the donated slots."""
+    """``state`` (``cfg.slot_shape``) into slot ``slot`` of the donated slots."""
     return conv.at[slot].set(state)
 
 
@@ -960,8 +963,10 @@ class HybridTransferEngine(KVTransferEngine):
     push, the one-launch gather, the staging ring and the streamer's queue
     are the parent's: the states are one band more of a block size of their
     own (``cfg.state_bytes``), written after the pages in the same commit,
-    and a state is bfloat16 on the wire as in HBM, so what comes back is bit
-    for bit what was pushed.  What differs:
+    and a state is on the wire in the type it has in HBM (``cfg.slot_dtype``:
+    the activations' for a shift register, float32 for a recurrence's
+    accumulator; no cast either way), so what comes back is bit for bit what
+    was pushed.  What differs:
 
     * ``gather_pages(cache, block_ids, slot=None)`` takes the engine's pair
       ``(pages, slots)`` and, with ``slot``, snapshots that slot too;
@@ -1018,8 +1023,8 @@ class HybridTransferEngine(KVTransferEngine):
             self._call("read_cache", *band)
         t0 = time.perf_counter()
         # a copy of its own: the staging buffer is the next load's too
-        out = jax.device_put(np.array(buf.view(jnp.dtype(cfg.dtype)).reshape(
-            len(cfg.state_layers), cfg.state_width)))
+        out = jax.device_put(np.array(buf.view(cfg.slot_dtype).reshape(
+            cfg.slot_shape)))
         stages["upload_s"] += time.perf_counter() - t0
         return out
 

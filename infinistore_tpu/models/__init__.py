@@ -54,6 +54,12 @@ from .lfm2_moe import (
     lfm2_moe_decode_forward,
     lfm2_moe_prefill_forward,
 )
+from .jamba import (
+    JambaConfig,
+    init_jamba_params,
+    jamba_decode_forward,
+    jamba_prefill_forward,
+)
 from .attention import (
     apply_rope,
     causal_attention,
@@ -89,10 +95,16 @@ def family_of(cfg) -> dict:
     if isinstance(cfg, Lfm2MoeConfig):
         # pages for its attention layers AND a state for its conv layers:
         # ``serve`` gives it the hybrid engine (engine/hybrid_engine.py) by
-        # ``cfg.conv_state_shape``
+        # ``kv.cache.cache_kind``
         return {"init": init_lfm2_moe_params,
                 "fns": {"prefill_fn": lfm2_moe_prefill_forward,
                         "decode_fn": lfm2_moe_decode_forward}}
+    if isinstance(cfg, JambaConfig):
+        # pages for its two attention layers AND a float32 state for its
+        # Mamba layers: the hybrid engine too
+        return {"init": init_jamba_params,
+                "fns": {"prefill_fn": jamba_prefill_forward,
+                        "decode_fn": jamba_decode_forward}}
     return {"init": init_params, "fns": {}}
 
 
@@ -102,6 +114,10 @@ __all__ = [
     "mla_moe_prefill_forward",
     "mla_moe_decode_forward",
     "family_of",
+    "JambaConfig",
+    "init_jamba_params",
+    "jamba_prefill_forward",
+    "jamba_decode_forward",
     "Lfm2MoeConfig",
     "init_lfm2_moe_params",
     "lfm2_moe_prefill_forward",

@@ -129,6 +129,14 @@ class Lfm2MoeConfig:
         positions."""
         return (self.conv_kernel - 1, self.dim)
 
+    # the kind's names (kv/cache.py ``HybridCacheConfig.for_model``)
+    page_layers = attn_layers
+    state_layers = conv_layers
+
+    @property
+    def state_width(self) -> int:
+        return int(np.prod(self.conv_state_shape))
+
     @property
     def expert_routing(self) -> Tuple[int, int, int]:
         """(expert layers, experts a token, experts a layer): what the step

@@ -138,8 +138,8 @@ def load_config_file(path: str) -> Tuple[str, Any, int]:
     ``(model_id, cfg, seed)``.  A file that names a ``family`` states the
     source's sizes itself and is read by that family's module
     (``config_from_file`` of ``models/mla_moe.py``,
-    ``models/cohere2_moe.py``, ``models/retention.py`` or
-    ``models/lfm2_moe.py``); every other file
+    ``models/cohere2_moe.py``, ``models/retention.py``,
+    ``models/lfm2_moe.py`` or ``models/jamba.py``); every other file
     names a dense
     preset: a preset of this module by name, the
     ``published`` sizes it must agree with (so a jax-free launcher can read
@@ -159,7 +159,8 @@ def load_config_file(path: str) -> Tuple[str, Any, int]:
 
         module = {"deepseek_v3": "mla_moe", "cohere2_moe": "cohere2_moe",
                   "brumby": "retention",
-                  "lfm2_moe": "lfm2_moe"}.get(spec["family"])
+                  "lfm2_moe": "lfm2_moe",
+                  "jamba": "jamba"}.get(spec["family"])
         if module is None:
             raise ValueError(f"{path}: family {spec['family']!r} is not one "
                              f"infinistore_tpu.models computes")

@@ -2668,6 +2668,7 @@ def main(argv: Optional[List[str]] = None) -> None:
 
     from .engine import InferenceEngine
     from .kv import PagedCacheConfig
+    from .kv.cache import cache_kind
     from .models import TINY, family_of, init_params, load_config_file
 
     mesh = None
@@ -2700,7 +2701,7 @@ def main(argv: Optional[List[str]] = None) -> None:
             ("--tp/--pp", mesh is not None),
             ("--kv-quant int8",
              args.kv_quant != "none" and (
-                 cfg.kv_page[0] != 2 or hasattr(cfg, "conv_state_shape"))),
+                 cfg.kv_page[0] != 2 or cache_kind(cfg) != "pages")),
             ("--draft-model", args.draft_model is not None),
             ("--ngram-spec", args.ngram_spec)) if on]
         if bad:
@@ -2784,8 +2785,8 @@ def main(argv: Optional[List[str]] = None) -> None:
             )
     engine_cls = InferenceEngine
     # what a sequence keeps of this model: pages, a state a layer, or both
-    keeps_state = hasattr(cfg, "state_shape")
-    keeps_both = hasattr(cfg, "conv_state_shape")
+    keeps_state = cache_kind(cfg) == "state"
+    keeps_both = cache_kind(cfg) == "hybrid"
     if keeps_state or keeps_both:
         # the cache's unit is a state, not a page (engine/state_engine.py), or
         # a state for some layers beside pages for the others

@@ -515,6 +515,105 @@ def test_hybrid_decode_scan_on_tpu_reads_heads_of_64_through_the_kernel(v5e):
     assert mem.temp_size_in_bytes < 256 << 20, mem
 
 
+def test_mamba_attention_programs_on_tpu_hold_both_kernels_and_copy_no_slots(v5e):
+    """The Mamba-1 / attention family at its published sizes, all 28 layers
+    (models/jamba.py: ONE ``lax.scan`` over the 26 Mamba layers' stacked
+    leaves, the two attention layers of 20 query heads over ONE key/value head
+    of 128 under a ``lax.switch`` in its body), beside its cell's cache: 10,240 blocks of pages
+    and 320 float32 slots of 10.1 MB.  The selective-scan kernel lowers for
+    the chip alone; the decode kernel takes a group of 20 query heads (no
+    multiple of 8 sublanes) over one key/value head; the decode scan at 8
+    rows x 1,024 pages holds it twice and gathers no table; the prefill chunk
+    over a 16k prefix holds the scan kernel ONCE; and neither program
+    copies the slots (with the layer axis on the sublanes each did, twice a
+    launch: 3.2 GB, kv/cache.py ``state_lanes``) nor, by the memory analysis,
+    a layer's matrices out of a run's stack."""
+    from infinistore_tpu.kv.cache import HybridCacheConfig
+    from infinistore_tpu.models import attention, ssm_scan
+
+    chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    f32 = lambda *shape: sds(shape, jnp.float32)
+    batch, width, chunk, prefix = 8, 1024, 512, 16384
+    cfg = models.JambaConfig()
+    N, di = cfg.d_state, cfg.d_inner
+    assert ssm_scan.kernel_engages(chunk, di, N)
+    alone = jax.jit(ssm_scan.selective_scan_kernel).lower(
+        f32(chunk, di), f32(chunk, di), f32(chunk, N), f32(chunk, N),
+        f32(N, di), f32(N, di)).compile().as_text()
+    assert "ssm_selective_scan" in alone
+
+    pc = HybridCacheConfig.for_model(cfg, 10240, T, 512, max_rows=batch)
+    assert (pc.n_kv_heads, pc.head_dim, pc.page_bytes) == (1, 128, 8192)
+    assert attention.decode_kernel_engages(
+        jax.ShapeDtypeStruct((batch, 20, 128), cfg.dtype),
+        jax.ShapeDtypeStruct((2, 2, 1, 10240, T, 128), cfg.dtype))
+    params = _shaped(jax.eval_shape(
+        lambda: models.init_jamba_params(cfg, jax.random.PRNGKey(0))), chip)
+    cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
+    slots = "f32[%s]" % ",".join(map(str, cache[1].shape))
+    weights = sum(w.size * w.dtype.itemsize for w in jax.tree.leaves(params))
+
+    def slot_copies(text):
+        return [f"{name} = {shape} {op}"
+                for name, shape, op in _INSTRUCTION.findall(text)
+                if shape == slots and op in ("copy", "transpose")]
+
+    def decode_scan(params, logits, pos, cache, table):
+        def step(carry, i):
+            logits, cache = carry
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            p = pos + i
+            blocks = jnp.take_along_axis(table[0], (p // T)[:, None], axis=1)[:, 0]
+            logits, cache = models.jamba_decode_forward(
+                params, cfg, tok, p, cache, table, p + 1,
+                (blocks, table[1][:, 0]), p % T)
+            return (logits, cache), tok
+
+        (logits, cache), toks = jax.lax.scan(
+            step, (logits, cache), jnp.arange(3))
+        return toks, logits, cache
+
+    compiled = jax.jit(decode_scan, donate_argnums=(3,)).lower(
+        params, sds((batch, cfg.vocab_size), cfg.dtype),
+        sds((batch,), jnp.int32), cache,
+        (sds((batch, width), jnp.int32), sds((batch, 1), jnp.int32)),
+    ).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text)) == 2
+    tables = _bf16_results_of(
+        text, batch * width * T * cfg.n_kv_heads * cfg.head_dim,
+        _weight_shapes(params))
+    assert not tables, tables
+    assert not slot_copies(text), slot_copies(text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pc.cache_bytes, mem
+    # a layer copied out of the stack would be 208 MB at a time, the pages
+    # copied through the switch 168 MB
+    assert mem.temp_size_in_bytes < 192 << 20, mem
+    assert mem.argument_size_in_bytes >= weights + pc.cache_bytes
+
+    def chunk_fn(p, t, conv, slot, n, buf, plen):
+        return models.jamba_prefill_forward(
+            p, cfg, t, conv, slot, n, prefix_kv=buf, prefix_len=plen,
+            head="none")
+
+    i32 = sds((), jnp.int32)
+    compiled = jax.jit(chunk_fn, donate_argnums=(2,)).lower(
+        params, sds((1, chunk), jnp.int32), cache[1], i32, i32,
+        sds((2, 2, 1, prefix, 1, 128), cfg.dtype), i32).compile()
+    text = compiled.as_text()
+    # its result is a pair (y, the state): counted by the line
+    scans = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if " custom-call(" in line and "%ssm_selective_scan" in line]
+    assert len(scans) == 1, scans              # one body for 26 layers
+    assert not slot_copies(text), slot_copies(text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pc.n_slots * pc.slot_bytes, mem
+    # the two attention layers' scores over 16.9k keys are the most of it
+    assert mem.temp_size_in_bytes < 640 << 20, mem
+
+
 # the largest push of each cell: a 512-token chunk's 32 pages of every layer
 # (the cells' caches as BENCHMARK.json's configurations size them), and
 # what the TPU compiler says the one program keeps live beside its bands

@@ -24,7 +24,7 @@ import pytest
 import infinistore_tpu as ist
 from infinistore_tpu.engine import InferenceEngine
 from infinistore_tpu.kv import PagedCacheConfig, init_cache, read_pages
-from infinistore_tpu.kv.cache import write_token_rows
+from infinistore_tpu.kv.cache import cache_kind, write_token_rows
 from infinistore_tpu.kv.transfer import KVTransferEngine
 from infinistore_tpu.models import family_of, load_config_file
 from infinistore_tpu.models.attention import (
@@ -408,9 +408,10 @@ def test_benchmark_configuration_resolves(entry, tmp_path):
     # array, or one pool a layer kind (--window-blocks in serve.args); a
     # family whose cache is state slots is held to its own shapes in
     # tests/test_retention.py::test_allocated_bytes_equal_the_counts, one
-    # whose sequence keeps pages AND a state in tests/test_lfm2_moe.py's
+    # whose sequence keeps pages AND a state in tests/test_lfm2_moe.py's and
+    # tests/test_jamba.py's
     sv = spec["serve"]
-    if hasattr(cfg, "state_shape") or hasattr(cfg, "conv_state_shape"):
+    if cache_kind(cfg) != "pages":
         assert "--state-stride" in sv["args"]
         return
     window_blocks = (int(sv["args"][sv["args"].index("--window-blocks") + 1])

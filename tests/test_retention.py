@@ -256,6 +256,10 @@ def scraped():
     out["store_hits"] = val("istpu_engine_state_store_hits_total", depth="all")
     out["store_hits_full"] = val("istpu_engine_state_store_hits_total",
                                  depth="full")
+    out["bytes_loaded"] = val("istpu_engine_state_bytes_loaded_total")
+    # and one whose state's update is a scan over the chunk (models/jamba.py)
+    out.update({f"scan_{w}": val("istpu_engine_state_scan_total", what=w)
+                for w in ("chunks", "full_chunks", "tokens")})
     return out
 
 
