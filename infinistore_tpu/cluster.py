@@ -1063,6 +1063,9 @@ class ClusterTransferEngine:
         self.quant = quant
         self.push_mode = push_mode
         self.breaker = FleetBreaker(pool)
+        # as KVTransferEngine's: what a load's landing stands through first
+        self.before_sync = None
+        self.held_s = 0.0
         # template engine for endpoint-independent halves (device-side
         # gather, key layout, scatter): same cfg/quant as every node
         self._tpl = self._engine(pool.endpoints[0])
@@ -1313,6 +1316,9 @@ class ClusterTransferEngine:
             cache = self._tpl.scatter_pages(
                 cache, [block_ids[i] for i in idxs], stacked
             )
+        # behind a decode dispatch in flight the scatters wait for it: its
+        # remainder is stood apart and is not the load's (transfer._landed)
+        self.held_s += self.before_sync() if self.before_sync else 0.0
         jax.block_until_ready(cache)
         return cache
 

@@ -408,6 +408,10 @@ class _StoreStreamer:
         # are bounded (a marker's error is consumed by its own wait or
         # aged out by the cap).
         self._cond = threading.Condition()
+        # called by the worker after every push it has finished with (the
+        # scheduler's: it sleeps on another condition while it waits for a
+        # prefill's acknowledgements, ``settled``)
+        self.wake: Optional[Any] = None
         self._pending: Dict[object, int] = {}
         self._marker_errs: "OrderedDict[object, BaseException]" = (
             OrderedDict()
@@ -475,6 +479,8 @@ class _StoreStreamer:
                 else:
                     self._pending.pop(m, None)
             self._cond.notify_all()
+        if self.wake is not None:
+            self.wake()
 
     def _run(self) -> None:
         from ..utils import resilience as _res
@@ -603,6 +609,17 @@ class _StoreStreamer:
                 self._err, self._dropped = None, 0
             raise err
 
+    def settled(self, marker) -> bool:
+        """Whether ``await_prefill(marker)`` would return (or raise) at
+        once: no push submitted under ``marker`` is outstanding."""
+        with self._cond:
+            return self._pending.get(marker, 0) <= 0
+
+    def room(self) -> bool:
+        """Whether a ``submit`` now would find the queue open (no
+        ``kv.push_wait`` for the submitting thread)."""
+        return not self._q.full()
+
     def _wait(self, *markers) -> Optional[BaseException]:
         """Block until no push tagged with any of ``markers`` is
         outstanding; the error recorded for the first of them, taken."""
@@ -610,6 +627,24 @@ class _StoreStreamer:
             while any(self._pending.get(m, 0) > 0 for m in markers):
                 self._cond.wait()
             return self._marker_errs.pop(markers[0], None)
+
+
+class DecodeFlight:
+    """One ``decode_launch`` until its ``decode_collect``: what the launch
+    built and the collect needs (the rows, the scan's device outputs, what
+    a call longer than ``decode_chunk`` launches next).  ``ready`` and
+    ``block`` look at the newest scan's tokens: the one array the collect
+    reads back."""
+
+    # stamped by whoever watched the dispatch end (the scheduler's watcher
+    # thread): the clock when ``block`` came back
+    t_ready: Optional[float] = None
+
+    def ready(self) -> bool:
+        return self.t_ready is not None or self.toks.is_ready()
+
+    def block(self) -> None:
+        self.toks.block_until_ready()
 
 
 @dataclass
@@ -917,6 +952,14 @@ class InferenceEngine:
             )
             if self.transfer is not None else None
         )
+        # the decode dispatch launched and not yet collected
+        # (``decode_launch`` / ``decode_collect``), None between them
+        self._flight: Optional[DecodeFlight] = None
+        if self.transfer is not None:
+            # a load's landing waits on a scatter that consumes the cache,
+            # so behind a dispatch in flight: the dispatch's remainder is
+            # stood first, apart, and is not the load's seconds
+            self.transfer.before_sync = self._await_flight
         # the window a page is held for: the sliding-window pool's layers'
         # (cfg.layer_windows; they GATHER their window's pages,
         # models/cohere2_moe.py), or the whole stack's where every layer is
@@ -1198,10 +1241,7 @@ class InferenceEngine:
                 args = (block_ids[:reused], keys[:reused])
             else:
                 args = (block_ids[n_local:reused], keys[n_local:reused])
-            with _stepprof.phase("kv.load") as ph:
-                self.cache, ok = self.transfer.guarded_load(
-                    self.cache, *args, **kw)
-            load_s = ph.s
+            ok, load_s = self._load(*args, **kw)
             if ok and two:
                 self._window_loaded(keys, window_ids, n_local, reused, missing)
             elif not ok and two:
@@ -1218,6 +1258,19 @@ class InferenceEngine:
         return self._begin_chunks(
             tokens, keys, block_ids, reused, min(n_local, reused),
             lookup_s, load_s, adapter_id=adapter_id, window_ids=window_ids)
+
+    def _load(self, *args, **kw) -> Tuple[bool, float]:
+        """``transfer.guarded_load`` into ``self.cache`` under phase
+        ``kv.load``: whether it landed, and its seconds.  Begun under a
+        decode dispatch in flight, its landing stands through the
+        dispatch's remainder first (``_await_flight``, phase
+        ``decode.wait``): those seconds are the dispatch's, not the load's
+        nor the request's ``store_load_s``."""
+        held0 = self.transfer.held_s
+        with _stepprof.phase("kv.load") as ph:
+            self.cache, ok = self.transfer.guarded_load(
+                self.cache, *args, **kw)
+        return ok, ph.s - (self.transfer.held_s - held0)
 
     def _begin_chunks(self, tokens: List[int], keys: List[str],
                       block_ids: List[int], reused: int, local_chunks: int,
@@ -1451,6 +1504,25 @@ class InferenceEngine:
         state = self._make_visible(pp)
         state.launch_s, state.chunks = pp.launch_s, pp.chunks
         return state
+
+    def prefill_settled(self, pp: "PartialPrefill") -> bool:
+        """Whether ``prefill_settle(pp)`` would return, or raise, without a
+        wait: nothing is awaited, or every push of this prefill has been
+        acknowledged or has failed."""
+        return (pp.push_error is not None or not self._awaits_push()
+                or self._streamer.settled(pp.marker))
+
+    def push_room(self) -> bool:
+        """Whether a chunk launched now hands its push over without
+        standing at the streamer's full queue."""
+        return self._streamer is None or self._streamer.room()
+
+    def set_wake(self, wake) -> None:
+        """``wake()`` is called, on the streamer's worker, whenever a push
+        has been acknowledged or given up: whoever sleeps until
+        ``prefill_settled`` turns looks again then."""
+        if self._streamer is not None:
+            self._streamer.wake = wake
 
     def _prefill_chunk(self, pp: "PartialPrefill") -> None:
         T = self.pc.block_tokens
@@ -2036,7 +2108,15 @@ class InferenceEngine:
             return arr
         return np.full(B, x, dtype=dtype)
 
-    def decode_batch(
+    def decode_batch(self, states: Sequence[SequenceState], n_steps: int,
+                     **kw
+                     ) -> Union[List[List[int]],
+                                Tuple[List[List[int]], List[List[tuple]]]]:
+        """``decode_launch`` and ``decode_collect`` back to back: the caller
+        stands in the read-back for as long as the dispatch takes."""
+        return self.decode_collect(self.decode_launch(states, n_steps, **kw))
+
+    def decode_launch(
         self,
         states: Sequence[SequenceState],
         n_steps: int,
@@ -2054,7 +2134,7 @@ class InferenceEngine:
         seed: Optional[Sequence[Optional[int]]] = None,
         logit_bias: Optional[Sequence[Optional[Dict[int, float]]]] = None,
         pen_cache: Optional[dict] = None,
-    ) -> Union[List[List[int]], Tuple[List[List[int]], List[List[tuple]]]]:
+    ) -> "DecodeFlight":
         """Decode ``n_steps`` tokens for a batch of sequences in lockstep
         (vLLM-style batched decode; sequences may have different lengths —
         positions, lengths, and scatter slots are per-row device values).
@@ -2093,9 +2173,18 @@ class InferenceEngine:
         stream: the row's base key is ``PRNGKey(seed)`` folded with each
         token's ABSOLUTE position, so a seeded request reproduces its
         tokens exactly regardless of batchmates, chunking, or scheduler
-        state (the vLLM per-request-seed contract)."""
+        state (the vLLM per-request-seed contract).
+
+        This is the LAUNCH half: it returns once the first scan is enqueued
+        and ``self.cache`` is rebound to what that scan will leave, with the
+        ``DecodeFlight`` that ``decode_collect`` turns into the rows' tokens.
+        Until then the rows' ``tokens``, ``last_logits`` and tables are the
+        dispatch's: nobody reads, extends or releases them.  What a caller
+        enqueues meanwhile (a prefill chunk, a store load's scatter)
+        consumes the rebound cache, so the device runs it behind the scan."""
         B = len(states)
         assert B >= 1
+        assert self._flight is None, "a decode dispatch is in flight"
         # flat phases, left to right: arguments and the jitted call
         # (decode.launch), the blocking read-back (decode.wait: the device
         # works, the host waits), the Python after it (decode.unpack) —
@@ -2255,67 +2344,94 @@ class InferenceEngine:
                 jnp.uint32,
             )
             mask_d = jnp.asarray(seeded_mask)
-        lps: List[List[tuple]] = [[] for _ in range(B)]
-        remaining = n_steps
-        while remaining > 0:
-            chunk = min(remaining, self.decode_chunk)
-            # row keys derive from ``rng`` INSIDE the compiled program; one
-            # key serves every chunk of this call because the scan folds by
-            # absolute position (draws never repeat across chunks)
-            res = self._decode_many(chunk, variant, logprobs_k=logprobs,
-                                    penalized=penalized, seeded=use_seeds)(
-                self.params,
-                logits,
-                jnp.asarray(pos),
-                self.cache,
-                block_table,
-                rng,
-                seeds_d,
-                mask_d,
-                greedy_d,
-                temp_d,
-                top_k_d,
-                top_p_d,
-                lora_t,
-                aid_d,
-                pen,
-            )
-            # one compiled scan dispatch advanced the whole batch a chunk
-            _stepprof.note_decode(
-                steps=chunk, rows=B, padded_rows=Bp,
-                width_pages=jax.tree.leaves(block_table)[0].shape[1],
-                block_tokens=T,
-                live_tokens=self._live_tokens(pos[:B]),
-                expert_routing=getattr(self.cfg, "expert_routing", None),
-                attn_kernel=self._attn_in_kernel,
-            )
-            _stepprof.note_tokens(chunk * B)
-            if logprobs:
-                toks, chosen, top_id, top_lp, logits, self.cache, *rest = res
-            else:
-                toks, logits, self.cache, *rest = res
-            if penalized:
-                # thread the device-side counts into the next chunk
-                counts_d, *rest = rest
-                pen = (counts_d,) + pen[1:]
-            # what is left is a family's own count (its held experts' pairs)
-            pairs_local = rest[0] if rest else None
+        fl = DecodeFlight()
+        fl.states, fl.B, fl.Bp, fl.T = list(states), B, Bp, T
+        fl.variant, fl.logprobs, fl.logprobs_rows = (
+            variant, logprobs, logprobs_rows)
+        fl.penalized, fl.use_seeds = penalized, use_seeds
+        fl.pen, fl.pen_key, fl.pen_cache = pen, pen_key, pen_cache
+        fl.block_table, fl.rng = block_table, rng
+        fl.seeds_d, fl.mask_d = seeds_d, mask_d
+        fl.sampling = (greedy_d, temp_d, top_k_d, top_p_d, lora_t, aid_d)
+        fl.logits, fl.pos, fl.remaining = logits, pos, n_steps
+        fl.out = out
+        fl.lps = [[] for _ in range(B)]
+        self._launch_scan(fl)
+        self._flight = fl
+        return fl
+
+    def _launch_scan(self, fl: "DecodeFlight") -> None:
+        """Enqueue the next scan of ``fl`` (``decode_chunk`` steps at most)
+        and rebind ``self.cache`` to its output."""
+        chunk = fl.chunk = min(fl.remaining, self.decode_chunk)
+        # row keys derive from ``rng`` INSIDE the compiled program; one
+        # key serves every chunk of this call because the scan folds by
+        # absolute position (draws never repeat across chunks)
+        res = self._decode_many(chunk, fl.variant, logprobs_k=fl.logprobs,
+                                penalized=fl.penalized, seeded=fl.use_seeds)(
+            self.params,
+            fl.logits,
+            jnp.asarray(fl.pos),
+            self.cache,
+            fl.block_table,
+            fl.rng,
+            fl.seeds_d,
+            fl.mask_d,
+            *fl.sampling,
+            fl.pen,
+        )
+        # one compiled scan dispatch advanced the whole batch a chunk
+        _stepprof.note_decode(
+            steps=chunk, rows=fl.B, padded_rows=fl.Bp,
+            width_pages=jax.tree.leaves(fl.block_table)[0].shape[1],
+            block_tokens=fl.T,
+            live_tokens=self._live_tokens(fl.pos[:fl.B]),
+            expert_routing=getattr(self.cfg, "expert_routing", None),
+            attn_kernel=self._attn_in_kernel,
+        )
+        _stepprof.note_tokens(chunk * fl.B)
+        if fl.logprobs:
+            (fl.toks, fl.chosen, fl.top_id, fl.top_lp, fl.logits, self.cache,
+             *rest) = res
+        else:
+            fl.toks, fl.logits, self.cache, *rest = res
+        if fl.penalized:
+            # thread the device-side counts into the next chunk
+            counts_d, *rest = rest
+            fl.pen = (counts_d,) + fl.pen[1:]
+        # what is left is a family's own count (its held experts' pairs)
+        fl.pairs_local = rest[0] if rest else None
+
+    def decode_collect(self, fl: "DecodeFlight"
+                       ) -> Union[List[List[int]],
+                                  Tuple[List[List[int]], List[List[tuple]]]]:
+        """The COLLECT half of ``decode_launch``: read the scan's tokens back
+        (phase ``decode.wait``), unpack them, run what is left of a call
+        longer than ``decode_chunk``, and write the rows' tokens and logits.
+        Ends in phase ``decode.unpack``, open for the caller to end."""
+        assert fl is self._flight, "not the dispatch in flight"
+        self._flight = None
+        B, logprobs = fl.B, fl.logprobs
+        out, lps = fl.out, fl.lps
+        while True:
+            chunk = fl.chunk
             _stepprof.enter("decode.wait")
             _stepprof.note_sync("decode_tokens")
-            host_toks = np.asarray(toks)  # [chunk, Bp]; one sync/chunk
+            host_toks = np.asarray(fl.toks)  # [chunk, Bp]; one sync/chunk
             _stepprof.enter("decode.unpack")
-            if pairs_local is not None:
+            if fl.pairs_local is not None:
                 # computed by the dispatch the tokens came from: read, not
                 # waited for
-                n = int(pairs_local)
+                n = int(fl.pairs_local)
                 _stepprof.note_expert_pairs_local(n)
                 _EXPERT_PAIRS_LOCAL.inc(n)
             if logprobs:
-                h_ch = np.asarray(chosen)   # [chunk, B]
-                h_ti = np.asarray(top_id)   # [chunk, B, k]
-                h_tl = np.asarray(top_lp)   # [chunk, B, k]
+                h_ch = np.asarray(fl.chosen)   # [chunk, B]
+                h_ti = np.asarray(fl.top_id)   # [chunk, B, k]
+                h_tl = np.asarray(fl.top_lp)   # [chunk, B, k]
                 for b in range(B):
-                    if logprobs_rows is not None and not logprobs_rows[b]:
+                    if (fl.logprobs_rows is not None
+                            and not fl.logprobs_rows[b]):
                         continue  # row didn't ask; skip the tuple building
                     lps[b].extend(
                         (float(h_ch[s, b]),
@@ -2325,23 +2441,47 @@ class InferenceEngine:
                     )
             for b in range(B):
                 out[b].extend(int(t) for t in host_toks[:, b])
-            pos += chunk
-            remaining -= chunk
-            if remaining > 0:
-                _stepprof.enter("decode.launch")
-        rows = _UNSTACK_ROWS(logits)  # one dispatch, not B eager slices
-        for b, st in enumerate(states):
+            fl.pos += chunk
+            fl.remaining -= chunk
+            if fl.remaining <= 0:
+                break
+            _stepprof.enter("decode.launch")
+            self._launch_scan(fl)
+        rows = _UNSTACK_ROWS(fl.logits)  # one dispatch, not B eager slices
+        for b, st in enumerate(fl.states):
             st.tokens.extend(out[b])
             st.last_logits = rows[b]
-        if penalized and pen_cache is not None:
+        if fl.penalized and fl.pen_cache is not None:
             # single-entry cache: one active batch composition at a time
-            pen_cache.clear()
-            pen_cache[pen_key] = (
-                tuple(len(st.tokens) for st in states), pen
+            fl.pen_cache.clear()
+            fl.pen_cache[fl.pen_key] = (
+                tuple(len(st.tokens) for st in fl.states), fl.pen
             )
         if logprobs:
             return out, lps
         return out
+
+    def decode_drop(self, fl: "DecodeFlight") -> None:
+        """Forget a dispatch in flight without reading it (a fault's
+        cleanup): its rows keep the tokens and logits they had at the launch
+        and are the caller's to release.  The scan itself runs to its end on
+        the device; whatever takes the rows' pages next consumes the cache
+        it leaves, and so runs behind it."""
+        if fl is self._flight:
+            self._flight = None
+
+    def _await_flight(self) -> float:
+        """Stand until the dispatch in flight, if any, has ended (phase
+        ``decode.wait``; the open phase comes back after) and return the
+        seconds stood.  For a caller about to block on something that
+        consumes the cache (a store load's landing): that wait is the
+        dispatch's remainder first, which is no cost of the caller's."""
+        fl = self._flight
+        if fl is None or fl.ready():
+            return 0.0
+        with _stepprof.phase("decode.wait") as ph:
+            fl.block()
+        return ph.s
 
     def propose(
         self,

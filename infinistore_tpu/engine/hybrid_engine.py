@@ -156,11 +156,9 @@ class HybridEngine(SlotBook, InferenceEngine):
         if cut > n_local or stored:
             # one load: the pages HBM does not hold and the checkpoint where
             # it is not resident, both or neither
-            with _stepprof.phase("kv.load") as ph:
-                self.cache, ok = self.transfer.guarded_load(
-                    self.cache, block_ids[n_local:cut], keys[n_local:cut],
-                    state=(row, key_at(cut)) if stored else None)
-            load_s = ph.s
+            ok, load_s = self._load(
+                block_ids[n_local:cut], keys[n_local:cut],
+                state=(row, key_at(cut)) if stored else None)
             if ok and stored:
                 # a store hit becomes resident, as a computed checkpoint does
                 self._keep_resident(key_at(cut), row)

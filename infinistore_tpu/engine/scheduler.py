@@ -39,6 +39,16 @@ Flow per ``step()``:
 4. retire requests that hit ``max_new_tokens`` or emitted a stop id
    (checked host-side at the chunk boundary), freeing their KV pages.
 
+A step stands twice: for the store's acknowledgements of the prompts it
+finished (2, strict durability) and for its dispatch's tokens (3).  Where a
+serving layer has attached its take-in (``attach_intake``) neither wait is
+deaf to arrivals: what the layer has staged meanwhile is submitted and its
+prefill is begun behind what the device is running, under the step's own
+budget at the first wait and under the NEXT step's at the second, so the
+device has work queued when the dispatch ends and a prompt joins the
+earliest dispatch it can.  ``run()`` and every caller that attaches nothing
+stand in both waits as before.
+
 ``fault_reset()`` is the one place engine-fault cleanup lives: it abandons
 partial prefills, releases every page (target and draft), fails out queued
 work, and returns the dropped requests for the serving layer to notify.
@@ -47,6 +57,7 @@ work, and returns the dropped requests for the serving layer to notify.
 from __future__ import annotations
 
 import os
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -58,7 +69,13 @@ from ..ledger import MAX_STAMPS
 from ..utils import tracing
 from ..utils.metrics import MetricsRegistry, default_registry, nearest_rank
 from . import stepprof as _stepprof
-from .engine import _SPLIT2, InferenceEngine, PartialPrefill, SequenceState
+from .engine import (
+    _SPLIT2,
+    DecodeFlight,
+    InferenceEngine,
+    PartialPrefill,
+    SequenceState,
+)
 
 
 def _env_float(name: str, default: float) -> float:
@@ -170,6 +187,30 @@ class Request:
     # kept) — the ledger's join key against the step profiler's
     # /debug/engine records
     step_ids: List[int] = field(default_factory=list)
+
+
+@dataclass
+class Intake:
+    """What a serving layer hands the scheduler so that the engine thread's
+    two waits inside a step are not deaf to arrivals (``Scheduler.
+    attach_intake``).  ``cv`` is the condition the layer notifies when it
+    stages a request or a cancellation; ``staged()``, called under it, says
+    whether anything waits to be taken in (or the layer is stopping);
+    ``take_in()`` pops what is staged and hands it to ``Scheduler.submit``
+    / ``cancel`` as the layer's own loop does at the top of a step, and
+    returns how many requests it submitted (None: the layer is stopping,
+    stand in the wait and leave)."""
+    cv: threading.Condition
+    staged: Callable[[], bool]
+    take_in: Callable[[], Optional[int]]
+
+
+@dataclass
+class _Budget:
+    """A step's prefill token budget as its pieces spend it: the burst's,
+    the settle wait's, and (ahead, for the next step) a dispatch's."""
+    granted: int
+    spent: int = 0
 
 
 class Scheduler:
@@ -288,6 +329,16 @@ class Scheduler:
         # step settles it (``_settle_parked``); empty between steps unless a
         # push error left the step
         self._parked: List[Tuple[Request, PartialPrefill]] = []
+        # the serving layer's take-in (``attach_intake``); None: a step
+        # takes nothing in and stands in its waits, as ``run()`` does
+        self.intake: Optional[Intake] = None
+        # the decode dispatch launched and not yet collected: its rows are
+        # the device's until ``decode_collect`` (``_under_dispatch``)
+        self._flight: Optional[DecodeFlight] = None
+        # prefill tokens launched under the last dispatch: spent from THIS
+        # step's budget already, so a step still holds at most
+        # ``max_batch`` chunks between two dispatches
+        self._spent_ahead = 0
         self._next_id = 0
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
         # set when decode sheds a request for lack of KV pages: admission
@@ -648,33 +699,58 @@ class Scheduler:
         for i in reversed(range(len(self._prefilling))):
             if self._prefilling[i][0].cancelled:
                 cancelled.append(self._drop_cancelled(self._prefilling, i))
-        granted = self._prefill_budget()
+        b = _Budget(self._prefill_budget())
+        # what ran under the last dispatch was this step's to spend
+        b.spent, self._spent_ahead = min(self._spent_ahead, b.granted), 0
+        while self._prefill_piece(b, cancelled):
+            pass
+        self._settle_parked(b, cancelled)
+        _stepprof.note_prefill_budget(b.granted, b.spent)
+        return cancelled
+
+    def _prefill_piece(self, b: _Budget, cancelled: List[Request],
+                       where: str = "") -> bool:
+        """ONE piece of a burst under budget ``b``: the next newcomer
+        started (``_start_prefill``), or, when none can start, one chunk
+        launched, by the order ``_prefill_burst`` states.  No longer than
+        one ``prefill_start`` or one chunk's launch, so a caller that does
+        this at a wait looks at what it waits for between two of them.
+        False: the budget is spent or nothing is left to prefill.
+        ``where`` names the wait it runs at ("dispatch", "settle") for the
+        counters; under a DISPATCH a chunk is launched only while the
+        streamer takes its push without a wait (the push's read-back stands
+        behind the dispatch: a full queue would hold the thread, and the
+        collect, until the device has drained it)."""
         cost = self.engine.prefill_chunk or 1
-        spent = 0
-        while granted - spent >= cost:
-            while self._start_prefill():
-                pass
-            if not self._prefilling:
-                break
-            i = 0 if not spent else min(
-                range(len(self._prefilling)),
-                key=lambda j: (-self._prefilling[j][0].priority,
-                               self._prefilling[j][1].chunks_left))
-            req, pp = self._prefilling[i]
-            if req.cancelled:      # another thread's cancel, mid-burst
-                cancelled.append(self._drop_cancelled(self._prefilling, i))
-                continue
-            with tracing.bind(req.trace_id), \
-                    _usage.bind_account(self._lane_label(req)):
-                st = self.engine.prefill_step(pp)
-            spent += cost
-            if st is not None:
-                self._prefilling.pop(i)
-                self._prefilled(req, st)
-            elif pp.finished:
-                self._parked.append(self._prefilling.pop(i))
-        _stepprof.note_prefill_budget(granted, spent)
-        return cancelled + self._settle_parked()
+        if b.granted - b.spent < cost:
+            return False
+        if self._start_prefill():
+            if where:
+                _stepprof.note_wait_work(**{"started_" + where: 1})
+            return True
+        if not self._prefilling or (
+                where == "dispatch" and not self.engine.push_room()):
+            return False
+        i = 0 if not b.spent else min(
+            range(len(self._prefilling)),
+            key=lambda j: (-self._prefilling[j][0].priority,
+                           self._prefilling[j][1].chunks_left))
+        req, pp = self._prefilling[i]
+        if req.cancelled:      # another thread's cancel, mid-burst
+            cancelled.append(self._drop_cancelled(self._prefilling, i))
+            return True
+        with tracing.bind(req.trace_id), \
+                _usage.bind_account(self._lane_label(req)):
+            st = self.engine.prefill_step(pp)
+        b.spent += cost
+        if where:
+            _stepprof.note_wait_work(**{"chunks_" + where: 1})
+        if st is not None:
+            self._prefilling.pop(i)
+            self._prefilled(req, st)
+        elif pp.finished:
+            self._parked.append(self._prefilling.pop(i))
+        return True
 
     def _drop_cancelled(self, held: list, i: int) -> Request:
         """``held[i]`` (a prefill in progress, or parked) was cancelled: its
@@ -686,29 +762,117 @@ class Scheduler:
         self._finish(req, "cancelled")
         return req
 
-    def _settle_parked(self) -> List[Request]:
+    # -- the engine thread's two waits inside a step --
+    #
+    # A step stands twice: for the store's acknowledgements of the prompts
+    # it finished (``_settle_parked``) and for its decode dispatch's tokens
+    # (``_under_dispatch``).  With an ``Intake`` attached neither is deaf:
+    # what the serving layer has staged is taken in and its prefill is
+    # begun, piece by piece, and between two pieces the thread looks at
+    # what it waits for.  With nothing to do it sleeps on the intake's
+    # condition, which the layer (an arrival), the streamer's worker (an
+    # acknowledgement) and the dispatch's watcher (its end) all notify.
+
+    def attach_intake(self, intake: Optional[Intake]) -> None:
+        self.intake = intake
+        self.engine.set_wake(self._wake if intake is not None else None)
+
+    def _wake(self) -> None:
+        """Any thread: the engine thread looks again at what it waits for."""
+        hook = self.intake
+        if hook is not None:
+            with hook.cv:
+                hook.cv.notify_all()
+
+    def _take_in(self, where: str) -> Optional[int]:
+        n = self.intake.take_in()
+        if n:
+            _stepprof.note_wait_work(**{"taken_in_" + where: n})
+        return n
+
+    def _sleep_until(self, done: Callable[[], bool]) -> None:
+        """Sleep until ``done()`` or something is staged (no spinning: the
+        condition is notified for both)."""
+        hook = self.intake
+        with hook.cv:
+            if not done() and not hook.staged():
+                hook.cv.wait()
+
+    def _watch(self, fl: DecodeFlight) -> None:
+        """The watcher of ONE dispatch (its own thread): stand in the
+        result, stamp its end, wake the engine thread."""
+        try:
+            fl.block()
+        except Exception:  # noqa: BLE001 — the collect raises it where it counts
+            pass
+        fl.t_ready = time.perf_counter()
+        self._wake()
+
+    def _under_dispatch(self, fl: DecodeFlight,
+                        cancelled: List[Request]) -> None:
+        """Between a dispatch's launch and its collect, with an intake:
+        take in what is staged and begin its prefill under the NEXT step's
+        budget (``_spent_ahead``), until the dispatch is ready; then the
+        collect goes first.  The rows in flight are not touched: a cancel
+        only flags them, a newcomer that finishes joins ``active`` behind
+        them and the collect writes ``fl``'s own rows."""
+        threading.Thread(target=self._watch, args=(fl,), daemon=True,
+                         name="istpu-decode-watch").start()
+        b = _Budget(self._prefill_budget(), self._spent_ahead)
+        while not fl.ready():
+            n = self._take_in("dispatch")
+            if n is None:
+                break
+            _stepprof.enter("admit")
+            if self._prefill_piece(b, cancelled, "dispatch") or n:
+                continue
+            _stepprof.enter("decode.wait")
+            self._sleep_until(fl.ready)
+        self._spent_ahead = b.spent
+        if fl.t_ready is not None:
+            # the price the rows in flight pay: from the dispatch's end to
+            # the collect's start (a piece that was running, a wake-up)
+            _stepprof.note_wait_work(
+                collect_lag_s=max(0.0, time.perf_counter() - fl.t_ready))
+
+    def _settle_parked(self, b: _Budget, cancelled: List[Request]) -> None:
         """The step's ONE wait for the store: each parked prefill's own
         acknowledgements, in the order the prefills finished
         (``engine.prefill_settle``), and each joins the batch as it is
         settled; one that was cancelled meanwhile gives its slot and pages
-        back unawaited (those are returned).  A push error leaves ``step()``
-        from here: the prompts settled before it have joined, the failed
-        one and those behind it stay parked for ``fault_reset`` (asked
-        again, the failed one raises again: it never joins)."""
-        cancelled: List[Request] = []
+        back unawaited (appended to ``cancelled``).  With an intake, while
+        the oldest parked prefill's pushes are outstanding the thread takes
+        in what is staged and goes on with the burst under the step's own
+        budget ``b`` (a prompt that finishes here is parked behind the
+        others and settled in this same pass), and sleeps only with nothing
+        to do: no prompt joins before ``prefill_settle`` has seen its OWN
+        acknowledgements.  A push error leaves ``step()`` from here: the
+        prompts settled before it have joined, the failed one and those
+        behind it stay parked for ``fault_reset`` (asked again, the failed
+        one raises again: it never joins)."""
         if self._parked:
             _stepprof.note_push_wait(settle_waits=1)
+        deaf = self.intake is None
         while self._parked:
             req, pp = self._parked[0]
             if req.cancelled:
                 cancelled.append(self._drop_cancelled(self._parked, 0))
+                continue
+            if not deaf and not self.engine.prefill_settled(pp):
+                n = self._take_in("settle")
+                if n is None:
+                    deaf = True     # stopping: stand in the wait and leave
+                elif not (self._prefill_piece(b, cancelled, "settle") or n):
+                    with _stepprof.phase("kv.push_wait") as ph:
+                        self._sleep_until(
+                            lambda: self.engine.prefill_settled(pp))
+                    _stepprof.note_push_wait(settle_wait_s=ph.s)
                 continue
             with tracing.bind(req.trace_id), \
                     _usage.bind_account(self._lane_label(req)):
                 st = self.engine.prefill_settle(pp)
             self._parked.pop(0)
             self._prefilled(req, st)
-        return cancelled
 
     def _admit(self) -> List[Request]:
         """Admission for one step: the budgeted chunked-prefill burst while
@@ -719,6 +883,7 @@ class Scheduler:
         # request and a top-p request share one lockstep batch
         if self.active or self._prefilling or self._parked:
             return self._prefill_burst()
+        self._spent_ahead = 0
         if self.pending:
             self._admit_wave()
         return []
@@ -1131,8 +1296,15 @@ class Scheduler:
         # two phase switches time the dispatch for the histogram, with or
         # without a profiler driving the step
         t_decode = _stepprof.enter("decode.launch")
+        rows = list(self.active)
+        # with an intake the dispatch is launched, the wait for it is spent
+        # on what is staged (``_under_dispatch``) and then it is collected;
+        # without one the thread stands in the one blocking call
+        hook = self.intake
+        decode = (self.engine.decode_batch if hook is None
+                  else self.engine.decode_launch)
         try:
-            outs = self.engine.decode_batch(
+            outs = decode(
                 [r.state for r in self.active], chunk,
                 sample=[r.sample for r in self.active],
                 temperature=[r.temperature for r in self.active],
@@ -1172,15 +1344,23 @@ class Scheduler:
             self._enqueue(victim, front=True)
             self._admission_hold = True
             return cancelled_prefill
+        if hook is not None:
+            # ``fault_reset`` drops the handle if anything here raises
+            fl = self._flight = outs
+            self._under_dispatch(fl, cancelled_prefill)
+            self._flight = None
+            outs = self.engine.decode_collect(fl)
         # ends the engine's decode.unpack
         self._h_decode_step.observe(
             _stepprof.enter("retire_stream") - t_decode)
         if want_lp:
             outs, lps = outs
-            for req, lp in zip(self.active, lps):
+            for req, lp in zip(rows, lps):
                 if req.logprobs:
                     req.lp_data.extend(lp)
-        for req, toks in zip(self.active, outs):
+        # ``rows``, not ``active``: a newcomer prefilled under the dispatch
+        # stands behind them and has no token yet
+        for req, toks in zip(rows, outs):
             req.output.extend(toks)
         return cancelled_prefill + self._retire()
 
@@ -1192,6 +1372,12 @@ class Scheduler:
         Returns the dropped requests — the serving layer tells their
         clients the truth (an error, not a completion)."""
         dropped: List[Request] = []
+        if self._flight is not None:
+            # a fault under a dispatch in flight: its rows are released
+            # below unread
+            self.engine.decode_drop(self._flight)
+            self._flight = None
+        self._spent_ahead = 0
         for req, pp in self._prefilling + self._parked:
             try:
                 self.engine.abandon_prefill(pp)

@@ -267,10 +267,7 @@ class StateEngine(SlotBook, InferenceEngine):
             lookup_s = ph.s
             if hit:
                 p = deeper[hit - 1]
-                with _stepprof.phase("kv.load") as ph:
-                    self.cache, ok = self.transfer.guarded_load(
-                        self.cache, [row], [key_at(p)], tokens=p)
-                load_s = ph.s
+                ok, load_s = self._load([row], [key_at(p)], tokens=p)
                 if ok:
                     P, source = p, "store"
                     # a store hit becomes resident as a computed checkpoint

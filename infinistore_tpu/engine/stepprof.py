@@ -113,7 +113,10 @@ DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
 PREFILL_COUNTS = ("granted_tokens", "spent_tokens",
                   "settle_waits", "settled_prompts", "settle_wait_s",
                   "push_queue_full_waits", "push_queue_full_s",
-                  "chunks", "head_chunks")
+                  "chunks", "head_chunks",
+                  "taken_in_dispatch", "started_dispatch", "chunks_dispatch",
+                  "taken_in_settle", "started_settle", "chunks_settle",
+                  "collect_lag_s")
 
 # what ``note_kv_pages`` sums, per step record and over the lifetime
 KV_COUNTS = ("store_pages_full", "store_pages_window",
@@ -399,6 +402,25 @@ def note_prefill_chunk(head: bool) -> None:
               {"chunks": 1, "head_chunks": int(head)})
 
 
+def note_wait_work(**counts: float) -> None:
+    """Count what the engine thread did at one of its two waits inside a
+    step instead of standing in it (``PREFILL_COUNTS``;
+    ``Scheduler._under_dispatch`` and ``_settle_parked`` with an intake
+    attached), beside ``chunks`` under ``rec["prefill"]``: requests
+    ``taken_in_*`` from the serving layer, prefills ``started_*``
+    (``prefill_start``: lookup, load, pages) and chunks launched
+    (``chunks_*``), under a decode DISPATCH in flight (``*_dispatch``: spent
+    from the next step's budget) and under the SETTLE wait for the store's
+    acknowledgements (``*_settle``: spent from the step's own).
+    ``(chunks_dispatch + chunks_settle) / chunks`` is the share of prefill
+    launches the waits hid.  ``collect_lag_s``: the seconds from a
+    dispatch's end (stamped by its watcher thread) to the start of its
+    collect, summed: what the rows in flight pay for a piece that was
+    running, or a wake-up, when their dispatch ended; over
+    ``dispatches.decode`` it is the mean a dispatch."""
+    _sum_into("prefill", PREFILL_COUNTS, counts)
+
+
 def enter(name: Optional[str]) -> float:
     """``StepProfiler.enter`` on the profiler driving this thread's step,
     and the switch's clock stamp: two of them time a site once.  A plain
@@ -644,6 +666,20 @@ class StepProfiler:
             "probe",
             labelnames=("kind",),
         )
+        self._c_wait_work = reg.counter(
+            "istpu_engine_wait_work_total",
+            "What the engine thread did at a wait inside a step instead of "
+            "standing in it (wait=dispatch: a decode dispatch in flight; "
+            "wait=settle: strict durability's wait for acknowledgements): "
+            "requests taken_in, prefills started, prefill chunks launched",
+            labelnames=("wait", "what"),
+        )
+        self._c_collect_lag = reg.counter(
+            "istpu_engine_collect_lag_seconds_total",
+            "Seconds from a decode dispatch's end to the start of its "
+            "collect, summed: what rows in flight pay for the work begun "
+            "under their dispatch",
+        )
         self._c_compiles = reg.counter(
             "istpu_engine_compiles_total",
             "Backend compiles observed process-wide via jax.monitoring "
@@ -863,6 +899,12 @@ class StepProfiler:
             self._c_sync.labels(k).inc(n)
         for fname, n in rec["retraces"].items():
             self._c_retrace.labels(fname).inc(n)
+        for k, n in rec.get("prefill", {}).items():
+            what, _, wait = k.rpartition("_")
+            if n and wait in ("dispatch", "settle"):
+                self._c_wait_work.labels(wait, what).inc(n)
+            elif n and k == "collect_lag_s":
+                self._c_collect_lag.inc(n)
         if sampled:
             stall = rec.get("host_stall_s", 0.0)
             self._h_step.labels(kind, "stall").observe(stall)

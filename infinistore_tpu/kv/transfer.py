@@ -190,6 +190,11 @@ class KVTransferEngine:
         # load_pages — the engine step records attach both dicts when a
         # step moved pages (engine/stepprof.py)
         self.last_load_stages: dict = {}
+        # what a load's landing stands through before its own wait (set by
+        # the engine: the decode dispatch in flight), and the seconds stood
+        # there so far, which the engine takes out of its ``kv.load`` times
+        self.before_sync = None
+        self.held_s = 0.0
         # running totals beside the two "last" dicts, for readers that take
         # deltas (engine/stepprof.py).  Each is REPLACED whole under the
         # lock, never mutated: a reader holding one holds a consistent
@@ -582,17 +587,24 @@ class KVTransferEngine:
         load's record.  ``t0`` .. ``t1`` was the fetch, whose ``stages``
         (``LOAD_STAGES``) were timed where they happened; the scatter's
         launch follows it and the wait here is ``sync_s``."""
+        # a decode dispatch in flight holds the cache the scatter consumes:
+        # its remainder is stood first and apart (``before_sync``, the
+        # engine's), and is in none of this load's seconds
+        held = self.before_sync() if self.before_sync is not None else 0.0
+        self.held_s += held
         ts = time.perf_counter()
         jax.block_until_ready(out)
         t2 = time.perf_counter()
         nbytes = pages * self.wire_page_bytes
         self.last_load_stages = {
-            "fetch_s": round(t1 - t0, 6), "scatter_s": round(t2 - t1, 6),
+            "fetch_s": round(t1 - t0, 6),
+            "scatter_s": round(t2 - t1 - held, 6),
             "pages": pages, "bytes": nbytes,
         }
         self._add_totals(
             "load_totals", loads=1, tokens=tokens, bytes=nbytes,
-            fetch_s=t1 - t0, scatter_s=t2 - t1, sync_s=t2 - ts, **stages)
+            fetch_s=t1 - t0, scatter_s=t2 - t1 - held, sync_s=t2 - ts,
+            **stages)
 
     def fetch_pages(self, chunk_keys_: Sequence[str],
                     layers: Optional[Sequence[int]] = None,

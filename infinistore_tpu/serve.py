@@ -245,6 +245,17 @@ class ServingServer:
         self._score_lock = threading.Lock()
         self._scoring = 0  # in-flight handler-thread scoring forwards
         self._submitting = 0  # popped from _staged, not yet in the scheduler
+        # the engine thread's waits inside a step (a decode dispatch's
+        # tokens, the store's acknowledgements) take in what is staged here
+        # meanwhile and begin its prefill (Scheduler._under_dispatch,
+        # _settle_parked); the speculative rounds keep their blocking call
+        from .engine.scheduler import Intake
+
+        self.sched.attach_intake(Intake(
+            cv=self._cv,
+            staged=lambda: bool(self._staged or self._cancels or self._stop),
+            take_in=self._take_in_at_wait,
+        ))
         self._engine_thread = threading.Thread(
             target=self._engine_loop, name="istpu-engine", daemon=True
         )
@@ -556,21 +567,9 @@ class ServingServer:
                               + [it["q"] for it in self._staged]):
                         q.put(("abort", "server restarting"))
                     return
-                staged, self._staged = self._staged, []
-                cancels, self._cancels = self._cancels, []
-                # popped items keep counting toward the admission depth
-                # until the scheduler owns them (see _over_depth_locked)
-                self._submitting += len(staged)
+                popped = self._pop_staged_locked()
             phase("intake")
-            for rid in cancels:
-                self.sched.cancel(rid)
-                self._queues.pop(rid, None)
-            for item in staged:
-                try:
-                    self._submit_to_sched(item)
-                finally:
-                    with self._cv:
-                        self._submitting -= 1
+            self._intake(*popped)
             if self.sched.has_work:
                 try:
                     # one trace per scheduler step: the prefill/decode
@@ -598,6 +597,45 @@ class ServingServer:
                         q = self._queues.pop(req.req_id, None)
                         if q is not None:
                             q.put(("error", f"engine fault: {e!r}"))
+
+    def _pop_staged_locked(self):
+        """What the handlers have staged, taken off their lists; caller
+        holds ``_cv``."""
+        staged, self._staged = self._staged, []
+        cancels, self._cancels = self._cancels, []
+        # popped items keep counting toward the admission depth
+        # until the scheduler owns them (see _over_depth_locked)
+        self._submitting += len(staged)
+        return staged, cancels
+
+    def _intake(self, staged, cancels) -> None:
+        """Engine thread, phase ``intake``: the popped cancellations and
+        submissions go to the scheduler."""
+        if not (staged or cancels):
+            return
+        self.stepprof.enter("intake")     # at a wait: only with work
+        for rid in cancels:
+            self.sched.cancel(rid)
+            self._queues.pop(rid, None)
+        for item in staged:
+            try:
+                self._submit_to_sched(item)
+            finally:
+                with self._cv:
+                    self._submitting -= 1
+
+    def _take_in_at_wait(self) -> Optional[int]:
+        """The scheduler's ``Intake.take_in``: the top of the loop's take-in,
+        done at one of the engine thread's waits INSIDE a step (for a decode
+        dispatch's tokens, for the store's acknowledgements), so a request
+        staged meanwhile starts its prefill behind what the device is
+        running.  None once the server is stopping."""
+        with self._cv:
+            if self._stop:
+                return None
+            popped = self._pop_staged_locked()
+        self._intake(*popped)
+        return len(popped[0])
 
     def _messages_to_ids(self, messages) -> List[int]:
         """Chat-completions prompt construction.  HF tokenizers bring their

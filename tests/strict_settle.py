@@ -302,9 +302,9 @@ def case_burst_outputs_equal_solo_runs(kit, mode):
     parked = []
     inner = b.sched._settle_parked
 
-    def settle():
+    def settle(*a):
         parked.append(len(b.sched._parked))
-        return inner()
+        return inner(*a)
 
     b.sched._settle_parked = settle
     out = run_out(b)

@@ -645,11 +645,21 @@ def test_note_push_wait_sums_per_step_and_lifetime():
         sp.note_push_wait(settled_prompts=1, settle_wait_s=0.125)
         for head in (False, False, True):   # a prompt of three chunks
             sp.note_prefill_chunk(head=head)
+        # two of them launched at the thread's waits (note_wait_work)
+        sp.note_wait_work(taken_in_dispatch=2)
+        sp.note_wait_work(started_dispatch=1)
+        sp.note_wait_work(chunks_dispatch=1)
+        sp.note_wait_work(taken_in_settle=1)
+        sp.note_wait_work(chunks_settle=1)
+        sp.note_wait_work(collect_lag_s=0.25)
     assert rec["prefill"] == {
         "granted_tokens": 2048, "spent_tokens": 1536, "settle_waits": 1,
         "settled_prompts": 2, "settle_wait_s": 0.625,
         "push_queue_full_waits": 1, "push_queue_full_s": 0.25,
-        "chunks": 3, "head_chunks": 1}
+        "chunks": 3, "head_chunks": 1,
+        "taken_in_dispatch": 2, "started_dispatch": 1, "chunks_dispatch": 1,
+        "taken_in_settle": 1, "started_settle": 0, "chunks_settle": 1,
+        "collect_lag_s": 0.25}
     with prof.step(kind_hint="prefill") as wave:    # a blocking prefill: no budget
         sp.note_push_wait(settle_waits=1)
         sp.note_push_wait(settled_prompts=1, settle_wait_s=0.25)
