@@ -647,6 +647,80 @@ def leg_prefill_breakdown(out: dict) -> None:
             out[f"prefill2k_chunk{chunk}_spread"] = sp
 
 
+def leg_chunk_attention(out: dict) -> None:
+    """The dense prefill chunk's attention ALONE, the TPU's kernel
+    (models/chunk_attention_kernel.py) against the XLA form it replaces, at
+    the shapes ``qwen2.5-7b-l12.batch-summarize``'s chunks have (a 512-token
+    chunk over prefix buffers of 0 / 512 / 1,024 / 2,048 / 2,048 / 4,096
+    rows holding 0 / 512 / 1,024 / 1,536 / 2,048 / 2,560) and at both dense
+    cells' heads (28 over 4, 32 over 8): twelve calls as a chunk makes them,
+    each layer's K and V the concatenation of its prefix buffer and its own
+    rows as ``prefill_forward`` forms it, each layer's query fed by the last
+    layer's output.  Milliseconds a chunk, and the mix's mean by the cell's
+    prompts (1,024 / 2,048 / 3,072 at 25 / 40 / 35%).  Alone, two minutes of
+    a chip: ``python -c "import bench_tpu, json; out = {};
+    bench_tpu.leg_chunk_attention(out); print(json.dumps(out))"``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from infinistore_tpu.models import attention
+
+    smoke = os.environ.get("ISTPU_BENCH_MODEL") == "tiny"
+    L, C, D = (2, 512, 128) if smoke else (12, 512, 128)
+    # (prefix buffer rows, prefix_len, chunks of this shape a prompt of the mix)
+    shapes = [(0, 0, 1.0), (512, 512, 1.0), (1024, 1024, 0.75),
+              (2048, 1536, 0.75), (2048, 2048, 0.35), (4096, 2560, 0.35)]
+    if smoke:
+        shapes = shapes[:2]
+    rng = np.random.RandomState(0)
+
+    def chunk(form, cap):
+        def run(q, prefix, own, n):
+            for li in range(L):
+                k, v = own[li]
+                kw = {}
+                if cap:
+                    k = jnp.concatenate([prefix[li, 0], k], axis=1)
+                    v = jnp.concatenate([prefix[li, 1], v], axis=1)
+                    kw = dict(q_offset=cap, prefix_pad=cap, prefix_len=n)
+                q = (q + form(q, k, v, **kw)) * jnp.asarray(0.5, q.dtype)
+            return q
+        return jax.jit(run)
+
+    def xla_form(q, k, v, q_offset=0, prefix_pad=None, prefix_len=None):
+        return attention._causal_attention_xla(
+            q, k, v, prefix_len, q_offset=q_offset, prefix_pad=prefix_pad)
+
+    res = {}
+    for H, Hkv in ((28, 4), (32, 8)):
+        rows, mean = [], {"kernel": 0.0, "xla": 0.0}
+        for cap, plen, share in shapes:
+            bf = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+            q0 = bf(1, C, H, D)
+            prefix = bf(L, 2, 1, max(cap, 1), Hkv, D)
+            own = bf(L, 2, 1, C, Hkv, D)
+            n = jnp.asarray(plen, jnp.int32)
+            keys = jax.ShapeDtypeStruct((1, cap + C, Hkv, D), jnp.bfloat16)
+            engages = attention.chunk_kernel_engages(
+                q0, keys, keys, cap, cap if cap else None, n if cap else None)
+            row = {"prefix_rows": cap, "prefix_len": plen,
+                   "kernel_engages": bool(engages)}
+            for name, form in (("kernel", attention.causal_attention),
+                               ("xla", xla_form)):
+                fn = chunk(form, cap)
+                ms = 1e3 * _timeit_chained(
+                    lambda q, i: fn(q, prefix, own, n), q0, n=30)
+                row[f"{name}_ms"] = round(ms, 4)
+                mean[name] += share * ms
+            rows.append(row)
+        chunks = sum(share for _, _, share in shapes)
+        res[f"h{H}_kv{Hkv}"] = {
+            "shapes": rows,
+            "mix_mean_ms": {k: round(v / chunks, 4) for k, v in mean.items()}}
+    out["chunk_attention"] = res
+
+
 def leg_distilled_spec(out: dict) -> None:
     """The VERDICT r4 next #1 configuration verbatim: a genuinely cheap
     draft "trained briefly on the target's outputs" vs the 1B target.
@@ -1100,6 +1174,7 @@ def main() -> int:
         ("speculative", leg_speculative),
         ("distilled_spec", leg_distilled_spec),
         ("prefill_breakdown", leg_prefill_breakdown),
+        ("chunk_attention", leg_chunk_attention),
         ("store_hop", leg_store_hop),
         ("prefill_stream", leg_prefill_stream),
     ]

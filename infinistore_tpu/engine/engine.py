@@ -25,7 +25,7 @@ import queue
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -207,14 +207,17 @@ def _shared_partial(fn, bound: Dict[str, Any]):
 
 def _traced_under(fn, mesh):
     """``fn``, traced with ``mesh`` named (``use_abstract_mesh``).  A program
-    partitioned over a mesh cannot split the TPU's decode-attention kernel
-    by itself; with the mesh named while the model is traced the kernel goes
-    under a shard_map over ``tp`` (models/paged_decode_kernel.py).
+    partitioned over a mesh cannot split a TPU kernel by itself; with the
+    mesh named while the model is traced the decode-attention kernel goes
+    under a shard_map over ``tp`` (models/paged_decode_kernel.py) and the
+    prefill chunk keeps its XLA attention (``chunk_kernel_engages``).
     Memoized, so that the caches keyed on the function hit across engines
-    of one mesh and never across a mesh and a single device."""
+    of one mesh and never across a mesh and a single device; the signature
+    stays inspectable (``donate_argnames``, the forms a forward takes)."""
     key = ("under_mesh", fn, mesh)
     got = _JIT_CACHE.get(key)
     if got is None:
+        @wraps(fn)
         def named(*args, **kwargs):
             with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
                 return fn(*args, **kwargs)
@@ -1009,6 +1012,12 @@ class InferenceEngine:
         import inspect as _inspect
 
         _pfn = prefill_fn or prefill_forward
+        # the model's own count of the layers whose attention a TPU runs in
+        # the chunk kernel, where its prefill forward brings one
+        # (``_chunk_attention_in_kernel``)
+        self._prefill_kernel_layers = getattr(_pfn, "kernel_layers", None)
+        if mesh is not None:
+            _pfn = _traced_under(_pfn, mesh)
 
         def _prefill_form(**head):
             return _shared_jit(_pfn, {"cfg": self.cfg, **head, **lora_kw},
@@ -1101,7 +1110,8 @@ class InferenceEngine:
         (``summary.prefill``: ``chunks``, and ``head_chunks``, those that
         ran a head)."""
         _stepprof.note_prefill_chunk(
-            head=head_row is not None or not self._prefill_heads)
+            head=head_row is not None or not self._prefill_heads,
+            attn_kernel=self._chunk_attention_in_kernel(**kw))
         if head_row is not None:    # goes in with the call, as numpy
             head_row = np.asarray(head_row, dtype=np.int32)
         if not self._prefill_heads:
@@ -2626,6 +2636,28 @@ class InferenceEngine:
         lengths, the tokens a paged attention has to read."""
         return int(lens.sum())
 
+    def _chunk_attention_in_kernel(self, **kw) -> bool:
+        """Whether the prefill program these arguments run holds the TPU's
+        chunk-attention kernel (models/chunk_attention_kernel.py) in some
+        layer: the cache on a TPU, and the model's own reading of the test
+        ``causal_attention`` makes when the program is lowered
+        (``prefill_forward.kernel_layers``, beside the forward whose calls it
+        reads), under the mesh the program is traced with
+        (``_traced_under``).  A family whose forward brings no such reading
+        has an attention of its own: 0.  For ``prefill.attn_kernel_chunks``;
+        it steers nothing."""
+        if (self._prefill_kernel_layers is None
+                or next(iter(jax.tree.leaves(self.cache)[0].devices())
+                        ).platform != "tpu"):
+            return False
+        with self._mesh_named():
+            return self._prefill_kernel_layers(self.cfg, **kw) > 0
+
+    def _mesh_named(self):
+        """The context a program of this engine is traced in."""
+        return (jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh)
+                if self.mesh is not None else contextlib.nullcontext())
+
     def _dense_attention_in_kernel(self) -> bool:
         """Whether this engine's decode scan reads its dense layers' pages
         through the TPU's kernel (models/paged_decode_kernel.py), by the
@@ -2645,9 +2677,7 @@ class InferenceEngine:
             windows = [window if li % every == 0 else None
                        for li in range(cfg.n_layers)]
         q = jax.ShapeDtypeStruct((1, cfg.n_heads, pool.shape[-1]), cfg.dtype)
-        ctx = (jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh)
-               if self.mesh is not None else contextlib.nullcontext())
-        with ctx:
+        with self._mesh_named():
             return (any(w is None for w in windows)
                     and decode_kernel_engages(q, pool))
 

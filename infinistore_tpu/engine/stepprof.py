@@ -56,8 +56,8 @@ structured record per scheduler step:
   chunk tokens a step was granted and the ones it spent; ``note_push_wait``
   tells the two causes of the ``kv.push_wait`` phase apart (the streamer's
   full queue, and strict durability's wait for acknowledgements);
-  ``note_prefill_chunk`` counts the prefill chunks run and those whose
-  program ran an output head.
+  ``note_prefill_chunk`` counts the prefill chunks run, those whose
+  program ran an output head and those whose attention is the TPU's kernel.
 * **stages of other threads** — ``stage(name)`` is the same bracket for a
   thread that is not the engine's: an annotation on that thread's line of
   the profiler's trace and its seconds, which the KV transfer sums into
@@ -113,7 +113,7 @@ DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
 PREFILL_COUNTS = ("granted_tokens", "spent_tokens",
                   "settle_waits", "settled_prompts", "settle_wait_s",
                   "push_queue_full_waits", "push_queue_full_s",
-                  "chunks", "head_chunks",
+                  "chunks", "head_chunks", "attn_kernel_chunks",
                   "taken_in_dispatch", "started_dispatch", "chunks_dispatch",
                   "taken_in_settle", "started_settle", "chunks_settle",
                   "collect_lag_s")
@@ -390,16 +390,21 @@ def note_push_wait(**counts: float) -> None:
     _sum_into("prefill", PREFILL_COUNTS, counts)
 
 
-def note_prefill_chunk(head: bool) -> None:
+def note_prefill_chunk(head: bool, attn_kernel: bool = False) -> None:
     """Count ONE prefill chunk at the engine's launch of its program
     (``InferenceEngine._prefill``: a chunk of a chunked prefill, of either
     engine; a padded group's one forward): ``chunks`` run, and
     ``head_chunks``, those whose program ran an output head (a prompt's last
     chunk, on the one row it keeps; every chunk of a custom family that has
     the whole form only).  ``head_chunks / chunks`` is one over the chunks a
-    prompt computes.  Summed under ``rec["prefill"]``."""
+    prompt computes.  ``attn_kernel_chunks``: those whose dense attention is
+    the TPU's kernel in some layer (models/chunk_attention_kernel.py; the
+    model's reading of its own program, ``prefill_forward.kernel_layers``,
+    through ``InferenceEngine._chunk_attention_in_kernel``).  Summed under
+    ``rec["prefill"]``."""
     _sum_into("prefill", PREFILL_COUNTS,
-              {"chunks": 1, "head_chunks": int(head)})
+              {"chunks": 1, "head_chunks": int(head),
+               "attn_kernel_chunks": int(attn_kernel)})
 
 
 def note_wait_work(**counts: float) -> None:
@@ -680,6 +685,13 @@ class StepProfiler:
             "collect, summed: what rows in flight pay for the work begun "
             "under their dispatch",
         )
+        self._c_attn_kernel_chunks = reg.counter(
+            "istpu_engine_prefill_attn_kernel_chunks_total",
+            "Prefill chunks whose dense attention ran as the TPU's kernel "
+            "(models/chunk_attention_kernel.py): against "
+            "istpu_engine_dispatches_total{kind=prefill}, the share of chunk "
+            "programs that write no score matrix",
+        )
         self._c_compiles = reg.counter(
             "istpu_engine_compiles_total",
             "Backend compiles observed process-wide via jax.monitoring "
@@ -905,6 +917,8 @@ class StepProfiler:
                 self._c_wait_work.labels(wait, what).inc(n)
             elif n and k == "collect_lag_s":
                 self._c_collect_lag.inc(n)
+            elif n and k == "attn_kernel_chunks":
+                self._c_attn_kernel_chunks.inc(n)
         if sampled:
             stall = rec.get("host_stall_s", 0.0)
             self._h_step.labels(kind, "stall").observe(stall)

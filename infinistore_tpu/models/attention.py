@@ -1,9 +1,13 @@
 """Attention ops: causal prefill attention and paged decode attention.
 One implementation of each per program.  In plain ``jax.numpy`` (XLA tiles
-the matmuls onto the MXU and fuses mask and softmax) but for one: a TPU's
-program reads the dense decode attention's pages through the kernel of
-``paged_decode_kernel`` (each row's live pages, copied out of the whole
-cache by the block table), chosen when the program is lowered.
+the matmuls onto the MXU and fuses mask and softmax) but for two, chosen by
+shapes and dtypes when a TPU's program is lowered: the dense decode
+attention reads its pages through the kernel of ``paged_decode_kernel``
+(each row's live pages, copied out of the whole cache by the block table),
+and a whole prefill chunk's attention is the kernel of
+``chunk_attention_kernel`` (the live rows of the prefix buffer and the
+chunk's own, no score matrix written).  The XLA forms stay every other
+platform's programs and the kernels' oracles.
 
 * the XLA decode attention (every other platform, a window, a soft cap, a
   page that is not bf16 K|V by head) reads K/V straight from the paged HBM
@@ -12,8 +16,9 @@ cache by the block table), chosen when the program is lowered.
   sequence length.
 * GQA in the paged readers (decode, speculative verify) views the query as
   [.., H_kv, G, D] and contracts each group against its KV head's pages as
-  gathered: nothing of [B, S, H, D] exists.  Prefill (``causal_attention``)
-  still calls ``repeat_kv``, which XLA:TPU materialises (below).
+  gathered: nothing of [B, S, H, D] exists.  The XLA prefill form
+  (``_causal_attention_xla``) still calls ``repeat_kv``, which XLA:TPU
+  materialises (below); the chunk kernel reads K and V as they are.
 """
 
 from __future__ import annotations
@@ -85,15 +90,79 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
     A broadcast in the traced program, a COPY on the chip: XLA:TPU writes
     the ``n_rep``-fold array out and the einsums read it back (in the paged
     decode that was 235 MB per K and per V per layer at 8 rows x 256 pages
-    and groups of 7, half the step: PERF.md, PR 31).  Only prefill
-    (``causal_attention``) still calls it; the copy is materialised there
-    too and is not priced."""
+    and groups of 7, half the step: PERF.md, PR 31).  Only the XLA form of
+    prefill (``_causal_attention_xla``) still calls it; the copy is
+    materialised there too (``bf16[4608, 28, 128]`` of K and of V a layer at
+    the largest bucket: PERF.md, PR 48)."""
     if n_rep == 1:
         return x
     shape = x.shape
     x = x[..., :, :, None, :]
     x = jnp.broadcast_to(x, shape[:-1] + (n_rep, shape[-1]))
     return x.reshape(shape[:-2] + (shape[-2] * n_rep, shape[-1]))
+
+
+def chunk_kernel_engages(q, k, v, q_offset=0, prefix_pad=None,
+                         prefix_len=None, window=None, softcap=None) -> bool:
+    """Whether the TPU's kernel (``chunk_attention_kernel``) can run this
+    call of ``causal_attention``: bf16 q, K and V with heads of whole 128-lane
+    rows, whole groups of query heads, attention over every live key (a
+    window or a soft cap keeps the XLA form), whole blocks of query rows (a
+    chunk of 512, or several), and one of the two forms a prompt of several
+    chunks runs: a padded prefix buffer of whole blocks with its
+    ``prefix_len``, or no prefix at all.  A chunk at a static ``q_offset``
+    over an exact prefix (a re-ask's short tail, compiled for its own length)
+    keeps the XLA form, and so does any program traced under a named mesh
+    with an axis larger than 1 (``--tp``, a shard_map).  Static: shapes,
+    dtypes and the mesh named while tracing; arrays or their abstract values.
+
+    A jit that partitions the model over a mesh by shardings alone (GSPMD)
+    names that mesh while it traces (``use_abstract_mesh``:
+    ``parallel/sharding.py:make_tp_prefill`` and ``make_tp_decode``, the
+    engine's ``_traced_under``): nothing else tells the trace that its
+    program will be split, and the partitioner cannot split a TPU kernel by
+    itself (the lowering refuses: tests/test_aot_tpu.py)."""
+    Sq, H, D = q.shape[1:]
+    if (window is not None or softcap is not None
+            or k.shape != v.shape or k.shape[-1] != D
+            or any(x.dtype != jnp.bfloat16 for x in (q, k, v))
+            or D % 128 or H % k.shape[2]
+            or any(n > 1 for n in jax.sharding.get_abstract_mesh().shape.values())):
+        return False
+    # Pallas is a second of import: paid by the programs that can hold the kernel
+    from .chunk_attention_kernel import BLOCK
+
+    if Sq % BLOCK:
+        return False
+    if prefix_len is not None:
+        return prefix_pad % BLOCK == 0 and k.shape[1] == prefix_pad + Sq
+    return isinstance(q_offset, int) and q_offset == 0 and k.shape[1] == Sq
+
+
+def prefix_attention_args(prefix_rows: int | None, prefix_len=None) -> dict:
+    """What ``causal_attention`` is told of a prefill chunk's prefix: a
+    buffer of ``prefix_rows`` rows in front of the chunk's own K and V (None:
+    the chunk has no prefix), of which the first ``prefix_len`` are live
+    (None: all of them, an exact prefix).  The models' prefill forwards and
+    ``chunk_kernel_layers`` both build the call from here."""
+    if prefix_rows is None:
+        return {}
+    return dict(q_offset=prefix_rows,
+                prefix_pad=prefix_rows if prefix_len is not None else None,
+                prefix_len=prefix_len)
+
+
+def chunk_kernel_layers(q, kv, prefix_rows, prefix_len, windows,
+                        softcap=None) -> int:
+    """Of a prefill program's layers (``windows``: a layer's window or None,
+    one entry a layer), those whose attention a TPU's lowering makes the
+    chunk kernel: ``causal_attention``'s own test on the call a forward
+    builds by ``prefix_attention_args``.  ``q`` [B, S, H, D] and ``kv`` [B,
+    prefix_rows + S, H_kv, D]: arrays or abstract values."""
+    return sum(
+        chunk_kernel_engages(q, kv, kv, window=w, softcap=softcap,
+                             **prefix_attention_args(prefix_rows, prefix_len))
+        for w in windows)
 
 
 def causal_attention(
@@ -120,7 +189,51 @@ def causal_attention(
 
     ``window``: sliding-window attention (Mistral) — a key is visible iff
     ``q_pos - window < k_pos <= q_pos`` (HF convention).
+
+    One attention per program, chosen by what is known when the program is
+    lowered (``chunk_kernel_engages``): for a TPU, a whole chunk of a prompt
+    of several (padded-prefix mode, or no prefix at all) in bf16 is ONE call
+    of the kernel of ``chunk_attention_kernel``, which visits the live key
+    blocks and writes no scores; anywhere else the XLA form below, which is
+    the kernel's oracle in the tests.
     """
+    xla = functools.partial(_causal_attention_xla, q_offset=q_offset,
+                            prefix_pad=prefix_pad, window=window,
+                            softcap=softcap)
+    if not chunk_kernel_engages(q, k, v, q_offset, prefix_pad, prefix_len,
+                                window, softcap):
+        return xla(q, k, v, prefix_len)
+    from .chunk_attention_kernel import chunk_attention_kernel
+
+    # ``prefix_len`` is an operand of both forms where there is one
+    n = () if prefix_len is None else (prefix_len,)
+    xla_form = lambda q, k, v, *n: xla(q, k, v, *(n or (None,)))
+    kernel = lambda q, k, v, *n: chunk_attention_kernel(
+        q, k, v, prefix_pad or 0, *n)
+    return jax.lax.platform_dependent(
+        q, k, v, *n, default=xla_form,
+        tpu=_with_derivative_of(xla_form, kernel))
+
+
+def _with_derivative_of(xla, kernel):
+    """``kernel``, which under differentiation IS the XLA form of the same
+    function: a Pallas call has no derivative of its own, and every branch of
+    a ``platform_dependent`` is differentiated wherever the program is lowered
+    (a train step through ``prefill_forward``, on any platform).  The forward
+    pass of a differentiated program is then the XLA form's too, its
+    residuals kept for the backward pass: the loss and its gradient come from
+    one set of scores, and nothing is computed twice.  A train step on a TPU
+    therefore writes its scores out as it did before the kernel; only a
+    program that is not differentiated runs the kernel."""
+    f = jax.custom_vjp(kernel)
+    f.defvjp(lambda *args: jax.vjp(xla, *args), lambda pull, g: pull(g))
+    return f
+
+
+def _causal_attention_xla(q, k, v, prefix_len, *, q_offset=0, prefix_pad=None,
+                          window=None, softcap=None):
+    """``causal_attention`` in plain ``jax.numpy``: K and V repeated by
+    group, every head's scores over every key row, masked."""
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     k = repeat_kv(k, H // Hkv)

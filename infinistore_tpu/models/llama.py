@@ -27,8 +27,10 @@ import numpy as np
 from .attention import (
     apply_rope,
     causal_attention,
+    chunk_kernel_layers,
     paged_decode_attention,
     paged_multitoken_attention_xla,
+    prefix_attention_args,
 )
 
 Params = Dict[str, Any]
@@ -421,10 +423,8 @@ def prefill_forward(
             k_full = jnp.concatenate([prefix_kv[li, 0], k], axis=1)
             v_full = jnp.concatenate([prefix_kv[li, 1], v], axis=1)
             attn = causal_attention(
-                q, k_full, v_full, q_offset=P,
-                prefix_pad=P if prefix_len is not None else None,
-                prefix_len=prefix_len, window=win,
-                softcap=cfg.attn_softcap,
+                q, k_full, v_full, window=win, softcap=cfg.attn_softcap,
+                **prefix_attention_args(P, prefix_len),
             )
         a = attn.reshape(B, S, -1)
         a = a @ layer["wo"] + _lora_term(a, ll, "wo", adapter_ids, lora_scale)
@@ -440,6 +440,30 @@ def prefill_forward(
         x, head, head_row,
         lambda x: _final_logits(params, cfg, _norm(cfg, x, params["ln_out"])),
     ), jnp.stack(kvs)
+
+
+def prefill_kernel_layers(cfg: LlamaConfig, tokens, prefix_kv=None,
+                          prefix_len=None, *, windows=None, **_) -> int:
+    """How many layers of the program ``prefill_forward`` makes of these
+    arguments (arrays or abstract values) run their attention in the TPU's
+    chunk kernel when the program is lowered for one: the attention's own
+    test, layer by layer, on the call the loop above builds (q of the
+    model's dtype, K and V the prefix buffer's rows and the chunk's own,
+    the layer's window, the model's soft cap).  ``windows``: another
+    forward's windows, a layer each (models/moe.py).  An engine finds it as
+    its prefill function's ``kernel_layers``."""
+    B, S = tokens.shape
+    P = None if prefix_kv is None else prefix_kv.shape[3]
+    q = jax.ShapeDtypeStruct((B, S, cfg.n_heads, cfg.head_dim), cfg.dtype)
+    kv = jax.ShapeDtypeStruct(
+        (B, (P or 0) + S, cfg.n_kv_heads, cfg.head_dim),
+        cfg.dtype if P is None else jnp.result_type(prefix_kv.dtype, cfg.dtype))
+    if windows is None:
+        windows = [_window_for(cfg, li) for li in range(cfg.n_layers)]
+    return chunk_kernel_layers(q, kv, P, prefix_len, windows, cfg.attn_softcap)
+
+
+prefill_forward.kernel_layers = prefill_kernel_layers
 
 
 def decode_forward(

@@ -28,8 +28,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .attention import causal_attention
-from .llama import LlamaConfig, Params, rmsnorm, _attn_qkv, _layer
+from .attention import causal_attention, prefix_attention_args
+from .llama import (
+    LlamaConfig,
+    Params,
+    _attn_qkv,
+    _layer,
+    prefill_kernel_layers,
+    rmsnorm,
+)
 
 
 @dataclass(frozen=True)
@@ -250,15 +257,26 @@ def moe_prefill_forward(
             k_full = jnp.concatenate([prefix_kv[li, 0], k], axis=1)
             v_full = jnp.concatenate([prefix_kv[li, 1], v], axis=1)
             attn = causal_attention(
-                q, k_full, v_full, q_offset=Pfx,
-                prefix_pad=Pfx if prefix_len is not None else None,
-                prefix_len=prefix_len, window=cfg.sliding_window,
+                q, k_full, v_full, window=cfg.sliding_window,
+                **prefix_attention_args(Pfx, prefix_len),
             )
         x = x + attn.reshape(B, S, -1) @ layer["wo"]
         h = rmsnorm(x, layer["ln_mlp"], cfg.norm_eps)
         x = x + moe_ffn(layer, h, cfg.top_k)
     x = rmsnorm(x, params["ln_out"], cfg.norm_eps)
     return x @ params["lm_head"], jnp.stack(kvs)
+
+
+def _moe_prefill_kernel_layers(cfg: MoEConfig, tokens, prefix_kv=None,
+                               prefix_len=None, **_) -> int:
+    """``llama.prefill_kernel_layers`` for the loop above: every layer under
+    the model's one window, and no soft cap."""
+    return prefill_kernel_layers(
+        replace(cfg, attn_softcap=None), tokens, prefix_kv, prefix_len,
+        windows=[cfg.sliding_window] * cfg.n_layers)
+
+
+moe_prefill_forward.kernel_layers = _moe_prefill_kernel_layers
 
 
 def moe_decode_forward(

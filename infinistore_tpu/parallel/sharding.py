@@ -92,7 +92,12 @@ def make_tp_prefill(cfg: LlamaConfig, mesh: Mesh):
     logits_sharding = NamedSharding(mesh, P("dp", None, "tp"))
 
     def fn(params, tokens):
-        return prefill_forward(params, cfg, tokens)
+        # the mesh is named while the model is traced, as in
+        # ``make_tp_decode``: the partitioner cannot split a TPU kernel by
+        # itself, and under a named axis larger than 1 a whole chunk's
+        # attention keeps its XLA form (attention.chunk_kernel_engages)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return prefill_forward(params, cfg, tokens)
 
     return jax.jit(
         fn,

@@ -42,6 +42,7 @@ boundary (pages freed, batchmates unaffected — scheduler.cancel semantics).
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import queue
@@ -256,6 +257,9 @@ class ServingServer:
             staged=lambda: bool(self._staged or self._cancels or self._stop),
             take_in=self._take_in_at_wait,
         ))
+        # set by ``main()``, which owns its process: see ``_freeze_traced``
+        self.freeze_traced_heap = False
+        self._traces_frozen = -1
         self._engine_thread = threading.Thread(
             target=self._engine_loop, name="istpu-engine", daemon=True
         )
@@ -586,6 +590,7 @@ class ServingServer:
                             self.stats["completed"] += 1
                             self.stats["tokens"] += len(req.output)
                         self._queues.pop(req.req_id, None)
+                    self._freeze_traced()
                 except Exception as e:
                     # last-resort fault path (validation keeps bad requests
                     # out, so this is an engine/runtime failure): the
@@ -597,6 +602,31 @@ class ServingServer:
                         q = self._queues.pop(req.req_id, None)
                         if q is not None:
                             q.put(("error", f"engine fault: {e!r}"))
+
+    def _freeze_traced(self) -> None:
+        """Engine thread, after a step that traced a program (and at the
+        first step): what tracing, lowering and compiling leave behind for
+        good (jaxprs, lowered modules, executables, the caches that hold
+        them: a million objects by the end of a warm-up at twelve layers)
+        is collected once, now, behind a step that cost seconds anyway, and
+        then moved out of the collector's reach (``gc.freeze``).  Left where
+        it was it is walked by every full collection, which stops every
+        thread of the server for 0.3-0.4 s and falls where the allocation
+        counts put it: on a request's store load, in that request's TTFT
+        (PERF.md, PR 48).  Later full collections walk what was allocated
+        since, the requests' own objects.  Only in a process the server owns
+        (``main()`` sets ``freeze_traced_heap``): a host that embeds the
+        class keeps its own collector policy."""
+        if not self.freeze_traced_heap:
+            return
+        from .engine.stepprof import total_traces
+
+        traces = total_traces()
+        if traces != self._traces_frozen:
+            self._traces_frozen = traces
+            self.stepprof.enter("gc.freeze")
+            gc.collect()
+            gc.freeze()
 
     def _pop_staged_locked(self):
         """What the handlers have staged, taken off their lists; caller
@@ -2963,9 +2993,12 @@ def main(argv: Optional[List[str]] = None) -> None:
     stop = threading.Event()
     for sig in (signal.SIGINT, signal.SIGTERM):
         signal.signal(sig, lambda *_: stop.set())
+    srv.freeze_traced_heap = True      # this process is the server's own
     srv.start()
     stop.wait()
     srv.close()
+    gc.unfreeze()       # a caller that goes on (benchmarks/serve_proc.py)
+                        # has the dead server collected like anything else
 
 
 if __name__ == "__main__":

@@ -25,6 +25,7 @@ from infinistore_tpu.kv import PagedCacheConfig, init_cache
 from infinistore_tpu.parallel.sharding import (
     llama_inference_specs,
     make_tp_decode,
+    make_tp_prefill,
     shardings_for,
 )
 
@@ -132,11 +133,12 @@ def _weight_shapes(params):
             for w in jax.tree.leaves(params)}
 
 
-def _kernel_calls(text):
-    """The optimized program's calls of the decode-attention kernel
-    (models/paged_decode_kernel.py), by the name it gives them."""
+def _kernel_calls(text, kernel="paged_decode_attention"):
+    """The optimized program's calls of a kernel, by the name it gives them:
+    the decode attention's (models/paged_decode_kernel.py) or the prefill
+    chunk's (``chunk_attention``, models/chunk_attention_kernel.py)."""
     return [name for name, _, op in _INSTRUCTION.findall(text)
-            if op == "custom-call" and "paged_decode_attention" in name]
+            if op == "custom-call" and kernel in name]
 
 
 dense_cells = pytest.mark.parametrize(
@@ -220,6 +222,153 @@ def test_decode_scan_on_tpu_at_the_cells_tables_gathers_no_table(
     cache_bytes = int(np.prod(cache.shape)) * cache.dtype.itemsize
     assert mem.alias_size_in_bytes >= cache_bytes, mem
     assert mem.temp_size_in_bytes < 128 << 20, mem
+
+
+@pytest.mark.parametrize("preset", ["QWEN25_7B", "QWEN3_8B"])
+def test_prefill_chunk_on_tpu_writes_no_scores_and_repeats_no_keys(preset, v5e):
+    """The dense cells' largest prefill program (a 512-token chunk over the
+    4,096-row prefix bucket, twelve layers at the published widths, the head
+    on one row so that the last layer's attention is live): every layer's
+    attention is one call of the chunk kernel; no instruction, inside a fusion
+    or out, has a result of ``[H, 512, Sk]`` scores in float32 or bf16 (the
+    XLA form wrote ``bf16[28,512,4608]`` and ``f32[28,512,4608]`` a layer: 792
+    MB of traffic; PERF.md, PR 48) nor of the ``repeat_kv`` broadcast's
+    ``Sk x H x D`` elements; the temporaries are the layers' own, not the
+    scores' (811 MB with the XLA form, 355 with the kernel at 28 heads).  The
+    compile's seconds are printed: the kernel is lowered once a program."""
+    import time
+
+    cfg = models.scaled(getattr(models, preset), n_layers=12)
+    chip = SingleDeviceSharding(v5e[0])
+    params = _shaped(jax.eval_shape(
+        lambda: models.init_params(cfg, jax.random.PRNGKey(0))), chip)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    chunk, bucket = 512, 4096
+    t0 = time.perf_counter()
+    compiled = jax.jit(
+        lambda p, t, kv, n, row: models.prefill_forward(
+            p, cfg, t, prefix_kv=kv, prefix_len=n, head="row", head_row=row)
+    ).lower(
+        params, sds((1, chunk), jnp.int32),
+        sds((cfg.n_layers, 2, 1, bucket, cfg.n_kv_heads, cfg.head_dim),
+            cfg.dtype),
+        sds((), jnp.int32), sds((1,), jnp.int32)).compile()
+    print(f"\n{preset}: the chunk's program over a {bucket}-row bucket lowered "
+          f"and compiled in {time.perf_counter() - t0:.1f} s")
+    text = compiled.as_text()
+    found = _INSTRUCTION.findall(text)
+    assert len(found) > 100, "the optimized program did not parse"
+    assert len(_kernel_calls(text, "chunk_attention")) == cfg.n_layers
+    # and the model's own count, which the engine's counter reads
+    # (``prefill.attn_kernel_chunks``), says what the compiler was given
+    assert models.prefill_forward.kernel_layers(
+        cfg, sds((1, chunk), jnp.int32),
+        prefix_kv=sds((cfg.n_layers, 2, 1, bucket, cfg.n_kv_heads,
+                       cfg.head_dim), cfg.dtype),
+        prefix_len=sds((), jnp.int32)) == cfg.n_layers
+    scores = re.compile(r"(?:f32|bf16)\[(?:1,)?%d,%d,\d+\]" % (cfg.n_heads, chunk))
+    written = [f"{name} = {shape} {op}" for name, shape, op in found
+               if scores.fullmatch(shape)]
+    assert not written, written
+    repeated = _bf16_results_of(
+        text, (bucket + chunk) * cfg.n_heads * cfg.head_dim,
+        _weight_shapes(params))
+    assert not repeated, repeated
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+@pytest.mark.parametrize("forward,cfg_kwargs,rows,prefix,padded,layers", [
+    ("prefill_forward", {}, 512, 1024, True, 4),
+    ("prefill_forward", {}, 512, None, False, 4),              # a first chunk
+    ("prefill_forward", {}, 1024, None, False, 4),             # two query blocks
+    ("prefill_forward", {}, 256, 1024, True, 0),               # a short last chunk
+    ("prefill_forward", {}, 512, 1024, False, 0),              # an exact prefix
+    ("prefill_forward", {"sliding_window": 256, "window_pattern": 2}, 512,
+     1024, True, 2),
+    ("prefill_forward", {"sliding_window": 256}, 512, 1024, True, 0),
+    ("prefill_forward", {"attn_softcap": 30.0}, 512, 1024, True, 0),
+    ("prefill_forward", {"dtype": jnp.float32}, 512, 1024, True, 0),
+    ("moe_prefill_forward", {}, 512, 1024, True, 4),
+    ("moe_prefill_forward", {"sliding_window": 256}, 512, 1024, True, 0),
+], ids=["padded-prefix", "first-chunk", "two-query-blocks", "short-chunk",
+        "exact-prefix", "alternating-windows", "window", "softcap", "float32",
+        "moe", "moe-window"])
+def test_the_models_count_of_kernel_layers_is_the_compiled_programs(
+        forward, cfg_kwargs, rows, prefix, padded, layers, v5e):
+    """``prefill.attn_kernel_chunks`` rests on the model's own reading of
+    its program (``prefill_forward.kernel_layers``, the engine's
+    ``_chunk_attention_in_kernel``): for a described v5e that reading IS the
+    number of ``chunk_attention`` calls in the compiled program, for the
+    dense forward and the routed-expert one (models/moe.py), over windows,
+    a soft cap, the dtype, the chunk's rows and the prefix's form."""
+    base = models.TINY_MOE if forward.startswith("moe") else models.TINY
+    cfg = models.scaled(base, **{"n_layers": 4, "head_dim_override": 128,
+                                 "dtype": jnp.bfloat16, **cfg_kwargs})
+    init = (models.init_moe_params if forward.startswith("moe")
+            else models.init_params)
+    fwd = getattr(models, forward)
+    chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    kw = {}
+    if prefix is not None:
+        kw["prefix_kv"] = sds((cfg.n_layers, 2, 1, prefix, cfg.n_kv_heads,
+                               cfg.head_dim), cfg.dtype)
+    if padded:
+        kw["prefix_len"] = sds((), jnp.int32)
+    params = _shaped(jax.eval_shape(
+        lambda: init(cfg, jax.random.PRNGKey(0))), chip)
+    tokens = sds((1, rows), jnp.int32)
+    text = jax.jit(lambda p, t, kw: fwd(p, cfg, t, **kw)).lower(
+        params, tokens, kw).compile().as_text()
+    assert fwd.kernel_layers(cfg, tokens, **kw) == layers
+    assert len(_kernel_calls(text, "chunk_attention")) == layers
+
+
+def test_a_differentiated_program_on_tpu_holds_the_xla_form_alone(v5e):
+    """``loss_fn`` at a whole chunk's shapes in bf16, compiled for one
+    described chip: the forward alone holds the chunk kernel, a layer each;
+    its gradient holds none (a Pallas call has no derivative: under
+    differentiation the kernel's branch is the XLA form, forward pass and
+    backward, so a train step's scores are computed once, by the form that
+    is differentiated)."""
+    cfg = models.scaled(models.TINY, n_layers=2, head_dim_override=128,
+                        dtype=jnp.bfloat16)
+    chip = SingleDeviceSharding(v5e[0])
+    params = _shaped(jax.eval_shape(
+        lambda: models.init_params(cfg, jax.random.PRNGKey(0))), chip)
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=chip)
+    loss = lambda p, t: models.loss_fn(p, cfg, t)
+    forward = jax.jit(loss).lower(params, tokens).compile().as_text()
+    assert len(_kernel_calls(forward, "chunk_attention")) == cfg.n_layers
+    grad = jax.jit(jax.grad(loss)).lower(params, tokens).compile().as_text()
+    assert len(_INSTRUCTION.findall(grad)) > 100, "the optimized program did not parse"
+    assert not _kernel_calls(grad, "chunk_attention")
+
+
+def test_tp_prefill_on_tpu_keeps_the_xla_attention_and_lowers(v5e):
+    """``make_tp_prefill`` over four chips (GSPMD over the heads) at a whole
+    chunk's shapes in bf16, where a single chip's program holds the chunk
+    kernel: it names its mesh while the model is traced, so the attention
+    keeps its XLA form and the program lowers.  The same jit WITHOUT the
+    mesh named does not lower at all: the partitioner cannot split a Mosaic
+    kernel by itself, which is why every jit of the model over a mesh names
+    it (``make_tp_decode``, the engine's ``_traced_under``)."""
+    cfg, _ = _family("QWEN3_8B")
+    mesh = Mesh(np.array(v5e).reshape(1, len(v5e)), ("dp", "tp"))
+    specs = shardings_for(mesh, llama_inference_specs(cfg=cfg))
+    params = _shaped(
+        jax.eval_shape(lambda: models.init_params(cfg, jax.random.PRNGKey(0))),
+        specs)
+    data = NamedSharding(mesh, P("dp", None))
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=data)
+    assert models.prefill_forward.kernel_layers(cfg, tokens) == cfg.n_layers
+    text = make_tp_prefill(cfg, mesh).lower(params, tokens).compile().as_text()
+    assert len(_INSTRUCTION.findall(text)) > 100, "the optimized program did not parse"
+    assert not _kernel_calls(text, "chunk_attention")
+    unnamed = jax.jit(lambda p, t: models.prefill_forward(p, cfg, t),
+                      in_shardings=(specs, data))
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        unnamed.lower(params, tokens)
 
 
 def test_tp_decode_on_tpu_copies_no_slab_and_gathers_no_cache(v5e):

@@ -211,6 +211,28 @@ def test_tp_prefill_matches_dense():
     np.testing.assert_allclose(np.asarray(kv), np.asarray(ref_kv), atol=2e-5)
 
 
+def test_tp_prefill_names_its_mesh_so_a_whole_chunk_is_offered_no_kernel():
+    """At a whole chunk's shapes in bf16 a single device's prefill program
+    offers the TPU its chunk-attention kernel (a choice by platform in the
+    traced program); ``make_tp_prefill`` names its mesh while it traces the
+    model, WITHOUT an ambient ``set_mesh``, so its program holds no such
+    choice: the partitioner could not split the kernel, and the lowering for
+    a TPU would refuse the program (tests/test_aot_tpu.py shows both)."""
+    import jax.numpy as jnp
+
+    from infinistore_tpu.models import scaled
+
+    mesh = make_mesh(tp=2)
+    cfg = scaled(CFG, head_dim_override=128, dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(7)))
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32)
+    assert prefill_forward.kernel_layers(cfg, tokens) == cfg.n_layers
+    alone = jax.make_jaxpr(lambda p, t: prefill_forward(p, cfg, t))(params, tokens)
+    assert "platform_index" in str(alone)
+    over_tp = jax.make_jaxpr(make_tp_prefill(cfg, mesh))(params, tokens)
+    assert "platform_index" not in str(over_tp)
+
+
 def test_tp_decode_matches_dense():
     from infinistore_tpu.kv.cache import PagedCacheConfig, init_cache
     from infinistore_tpu.models.llama import decode_forward

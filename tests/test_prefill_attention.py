@@ -102,7 +102,10 @@ def test_causal_attention_matches_float32_reference(hkv, g, dtype, form, sq,
 def test_the_package_has_one_attention_path():
     """No switch, mesh argument or engine option selects another attention
     implementation; ``use_pallas`` is a parameter of the two dense forwards
-    (two files under benchmarks/ still pass it) and nothing reads it."""
+    (two files under benchmarks/ still pass it) and nothing reads it.  The
+    two hand-written attention kernels (``paged_decode_kernel.py``,
+    ``chunk_attention_kernel.py``) are chosen by shapes when a TPU's program
+    is lowered, one attention a program."""
     pkg = pathlib.Path(__file__).resolve().parents[1] / "infinistore_tpu"
     takes, reads = [], []
     for path in sorted(pkg.rglob("*.py")):
@@ -123,3 +126,5 @@ def test_the_package_has_one_attention_path():
                              "llama.py:prefill_forward"]
     assert reads == []
     assert not (pkg / "ops").exists()
+    assert sorted(p.name for p in (pkg / "models").glob("*_kernel.py")) == [
+        "chunk_attention_kernel.py", "paged_decode_kernel.py"]

@@ -167,6 +167,53 @@ def test_max_queue_backpressure_429():
         srv.close()
 
 
+@pytest.mark.parametrize("owned", [True, False])
+def test_a_server_that_owns_its_process_freezes_what_a_traced_step_leaves(
+        owned, monkeypatch):
+    """``serve.main()`` owns its process and sets ``freeze_traced_heap``:
+    after a step that traced a program the engine thread collects once and
+    freezes the heap (the jaxprs and executables a warm-up leaves are walked
+    by no later full collection: a 0.3-0.4 s stop of every thread at twelve
+    layers, PERF.md PR 48); a step that traced nothing does neither, and a
+    server embedded in someone else's process (the default) never does."""
+    from infinistore_tpu import serve
+    from infinistore_tpu.engine import stepprof
+
+    calls = []
+
+    class _Gc:
+        collect = staticmethod(lambda: calls.append("collect"))
+        freeze = staticmethod(lambda: calls.append("freeze"))
+
+    monkeypatch.setattr(serve, "gc", _Gc)
+    eng = InferenceEngine(
+        PARAMS, CFG,
+        PagedCacheConfig(
+            n_layers=CFG.n_layers, n_kv_heads=CFG.n_kv_heads,
+            head_dim=CFG.head_dim, n_blocks=64, block_tokens=4,
+            dtype=CFG.dtype,
+        ),
+    )
+    srv = ServingServer(eng, port=0, max_batch=2, model_id="tiny-gc")
+    srv.freeze_traced_heap = owned
+    srv.start()
+    try:
+        body = {"prompt": PROMPT, "max_tokens": 3, "temperature": 0}
+        for _ in range(3):      # computed; from the pages in HBM; the same
+            assert _post(srv.port, body)[0] == 200
+        first, traces = list(calls), stepprof.total_traces()
+        assert _post(srv.port, body)[0] == 200
+        assert stepprof.total_traces() == traces
+    finally:
+        srv.close()
+    if not owned:
+        assert calls == []
+        return
+    assert first and first == ["collect", "freeze"] * (len(first) // 2)
+    assert calls == first           # the repeat traced nothing: no collection
+    assert "gc.freeze" in srv.stepprof.summary()["phase_s"]
+
+
 def test_admission_depth_accounting():
     """The two depth checks see the right state at each handoff stage:
     items the engine loop popped from _staged but has not yet handed to

@@ -656,7 +656,7 @@ def test_note_push_wait_sums_per_step_and_lifetime():
         "granted_tokens": 2048, "spent_tokens": 1536, "settle_waits": 1,
         "settled_prompts": 2, "settle_wait_s": 0.625,
         "push_queue_full_waits": 1, "push_queue_full_s": 0.25,
-        "chunks": 3, "head_chunks": 1,
+        "chunks": 3, "head_chunks": 1, "attn_kernel_chunks": 0,
         "taken_in_dispatch": 2, "started_dispatch": 1, "chunks_dispatch": 1,
         "taken_in_settle": 1, "started_settle": 0, "chunks_settle": 1,
         "collect_lag_s": 0.25}
