@@ -711,8 +711,7 @@ def main(argv=None) -> int:
         # the same way `slo`/`config` do — stable keys, documented in
         # docs/observability.md §engine-attribution
         record["stepprof"] = stepprof
-        # dispatch-economy mirrors for the trend table
-        # (scripts/bench_history.py): compiled programs per decoded
+        # dispatch-economy mirrors, top-level: compiled programs per decoded
         # token over the whole sweep (down is good) and accepted spec
         # tokens per fused dispatch (up is good; absent when the server
         # never speculated)
@@ -748,8 +747,8 @@ def main(argv=None) -> int:
             "quota_throttled": (admission_dbg.get("quota")
                                 or {}).get("throttled_total"),
         }
-    # mirrored top-level (0/1) for the scripts/bench_history.py trend
-    # table: an overload round whose plateau flag drops to 0 regressed
+    # mirrored top-level (0/1): an overload round whose plateau flag
+    # drops to 0 regressed
     record["goodput_plateau"] = int(plateau)
     # resumption block (docs/observability.md §Resumption): the
     # client-observed splice ledger over the whole sweep (resumed =
@@ -758,8 +757,7 @@ def main(argv=None) -> int:
     # = the worst client-visible gap), the router-merged server-side
     # view when a fleet answered /debug/fleet?merged=1, and the decode
     # pool's checkpoint-overhead counters.  stream_resumes mirrors
-    # top-level for scripts/bench_history.py (direction: down — a quiet
-    # fleet resumes nothing)
+    # top-level (direction: down — a quiet fleet resumes nothing)
     resumption = {
         "resumed": sum(p.get("resumed") or 0 for p in curve),
         "stalled": sum(p.get("stalled") or 0 for p in curve),
@@ -790,7 +788,7 @@ def main(argv=None) -> int:
         # among RE-visits (up is good; fallback is every session's first
         # placement, not a miss), and the client-observed per-turn TTFT
         # slope at the top offered rate — with the first two mirrored
-        # top-level for scripts/bench_history.py
+        # top-level
         record["config"]["conversation"] = {
             "sessions_per_rate": args.sessions,
             "turns": [list(t) for t in args.turns],
@@ -831,9 +829,8 @@ def main(argv=None) -> int:
         # disaggregation block (docs/observability.md): per-role worker
         # counts, handoff leg percentiles, decode-pool adoption hit
         # rate, and the TTFT/TPOT-vs-monolith ratios at the top offered
-        # rate — the headline ratios mirror top-level for
-        # scripts/bench_history.py (direction: down; < 1.0 means the
-        # fleet beat the same-decode-budget monolith)
+        # rate — the headline ratios mirror top-level (direction: down;
+        # < 1.0 means the fleet beat the same-decode-budget monolith)
         record["disagg"] = disagg
         if disagg.get("ttft_ratio") is not None:
             record["ttft_ratio"] = disagg["ttft_ratio"]
@@ -843,8 +840,8 @@ def main(argv=None) -> int:
         # usage block (docs/observability.md §Usage attribution): the
         # per-tenant ledger at sweep end — occupancy byte·seconds, token
         # provenance, economics — with the fleet-wide reuse ratio
-        # mirrored top-level for scripts/bench_history.py (up is good:
-        # more prompt tokens served from the store per byte held)
+        # mirrored top-level (up is good: more prompt tokens served from
+        # the store per byte held)
         tenants = usage_dbg.get("tenants") or {}
         tok_store = sum((t.get("tokens") or {}).get("store", 0.0)
                        for t in tenants.values())
@@ -862,16 +859,15 @@ def main(argv=None) -> int:
     if health is not None:
         # health-plane block (infinistore_tpu/health.py): alert
         # transitions + burn-rate peak during the run.  alerts_fired is
-        # ALSO mirrored top-level so scripts/bench_history.py trends it
-        # (direction: down) without digging into nested blocks
+        # ALSO mirrored top-level (direction: down), so that a reader
+        # need not dig into nested blocks
         record["health"] = health
         record["alerts_fired"] = health["alerts_fired"]
         record["burn_rate_peak"] = health["burn_rate_peak"]
     if cluster_dbg is not None:
-        # reshape throughput mirrored top-level for the trend table
-        # (up is good) — only when a migration actually ran: a sweep
-        # with no membership change emits no row, and bench_history
-        # skips absent keys
+        # reshape throughput mirrored top-level (up is good) — only when
+        # a migration actually ran: a sweep with no membership change
+        # emits no row
         mig = cluster_dbg.get("migration") or {}
         if mig.get("migrate_gbps") is not None:
             record["migrate_gbps"] = mig["migrate_gbps"]
@@ -880,7 +876,6 @@ def main(argv=None) -> int:
         # the per-stage TTFT decomposition at sweep end, row tail
         # dropped (the aggregates are the diffable artifact).  Each
         # stage's p99 mirrors top-level as stage_p99_<stage>_ms so
-        # scripts/bench_history.py trends the decomposition and
         # scripts/trace_diff.py names a regressed stage from two of
         # these captures
         overall = critpath_dbg.get("overall") or {}

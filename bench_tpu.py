@@ -303,8 +303,8 @@ def leg_serving(out: dict) -> None:
     # the measured pass runs under a StepProfiler at DEFAULT sampling —
     # the serving leg now reports the host-stall/device split and
     # retrace pressure next to its tokens/s, so "serving is slow" is
-    # attributable from bench output alone (scripts/bench_history.py
-    # trends host_stall_frac / retraces_per_100_steps)
+    # attributable from bench output alone (host_stall_frac /
+    # retraces_per_100_steps)
     from infinistore_tpu.engine.stepprof import StepProfiler
 
     prof = StepProfiler()
@@ -350,8 +350,8 @@ def leg_serving(out: dict) -> None:
     out["serving_prefill_p99_ms"] = lm["prefill_p99_ms"]
     # the step profiler's attribution block (engine/stepprof.py): the
     # sampled device-drain share of step time and the retrace pressure —
-    # trended by scripts/bench_history.py so a regression that turns the
-    # step loop host-bound (or shape-polymorphic) is flagged, not argued
+    # reported so a regression that turns the step loop host-bound (or
+    # shape-polymorphic) is read off, not argued
     s = prof.summary()
     out["host_stall_frac"] = s["host_stall_frac"]
     out["retraces_per_100_steps"] = s["retraces_per_100_steps"]

@@ -746,9 +746,11 @@ class PartialPrefill:
 
 class InferenceEngine:
     # what a subclass for another kind of cache replaces
-    # (engine/state_engine.py): the transfer engine of a single store
-    # connection, what the prefill program donates, and whether prompts
-    # without a store may share one padded forward
+    # (engine/state_engine.py; the kinds' table is engine/__init__.py): the
+    # cache config whose ``for_model`` sizes such a cache, the transfer
+    # engine of a single store connection, what the prefill program donates,
+    # and whether prompts without a store may share one padded forward
+    cache_cls = PagedCacheConfig
     transfer_cls = KVTransferEngine
     prefill_donates: tuple = ()
     batched_prefill = True

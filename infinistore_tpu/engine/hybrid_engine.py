@@ -36,34 +36,22 @@ its own is the rule that joins the two:
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 
-from ..kv.cache import HybridCacheConfig, StateSlots
-from ..kv.hashing import chunk_keys
+from ..kv.cache import HybridCacheConfig
 from ..kv.transfer import HybridTransferEngine
 from . import stepprof as _stepprof
-from .engine import InferenceEngine, PartialPrefill, SequenceState
-from .state_engine import SlotBook, refuse_for_slots
+from .engine import InferenceEngine, PartialPrefill
+from .state_engine import SlotBook
 
 
 class HybridEngine(SlotBook, InferenceEngine):
+    cache_cls = HybridCacheConfig
     transfer_cls = HybridTransferEngine
     prefill_donates = ("conv",)
-    batched_prefill = False      # a row's slot is taken in ``prefill_start``
-
-    def __init__(self, params, cfg, pc: HybridCacheConfig, **kw):
-        refuse_for_slots(kw)
-        kw.setdefault("max_seqs", pc.max_rows)
-        super().__init__(params, cfg, pc, **kw)
-        if self.prefill_chunk is None or pc.stride % self.prefill_chunk:
-            raise ValueError(
-                f"a checkpoint is taken at the end of a prefill chunk: the "
-                f"stride {pc.stride} must be a multiple of prefill_chunk "
-                f"({self.prefill_chunk})")
-        self.slots = StateSlots(pc.n_slots, pc.max_rows)
 
     # the two kinds, each where its helpers look for it
 
@@ -85,29 +73,12 @@ class HybridEngine(SlotBook, InferenceEngine):
 
     # ---- prefill ----
 
-    def prefill_start(self, tokens: Sequence[int],
-                      adapter_id: int = 0) -> PartialPrefill:
-        """Admission half of a prefill: a row's slot (``MemoryError`` where
-        every one is taken), the deepest position at which this prompt finds
-        pages AND a checkpoint, both adopted, the rest of its pages, and the
-        chunking."""
-        assert adapter_id == 0 and len(tokens) >= 1, adapter_id
-        tokens = list(tokens)
-        keys = chunk_keys(tokens, self.model_id,
-                          chunk_tokens=self.pc.block_tokens)
-        row = self.slots.take_row()
-        block_ids: List[int] = []
-        try:
-            return self._start_in_row(tokens, keys, row, block_ids)
-        except BaseException:
-            self.pages.unpin(block_ids)
-            self.slots.free_row(row)
-            raise
-
     def _start_in_row(self, tokens: List[int], keys: List[str], row: int,
                       block_ids: List[int]) -> PartialPrefill:
-        """``block_ids`` is the caller's list, filled in place: what it holds
-        when this raises is what is pinned."""
+        """The deepest position at which this prompt finds pages AND a
+        checkpoint, both adopted, and the rest of its pages.  ``block_ids`` is
+        the caller's list, filled in place: what it holds when this raises is
+        what is pinned."""
         T, n = self.pc.block_tokens, len(tokens)
         per = self.pc.stride // T                 # chunks a stride
         # the pages, as the paged engine matches them: HBM, then the store;
@@ -228,50 +199,16 @@ class HybridEngine(SlotBook, InferenceEngine):
                 if self._keep_resident(pp.keys[pp.done - 1], pp.slot):
                     self._count(checkpoints_taken=1)
 
-    def _make_visible(self, pp: PartialPrefill) -> SequenceState:
-        state = super()._make_visible(pp)
-        state.slot, pp.slot = pp.slot, -1
-        return state
-
-    def abandon_prefill(self, pp: PartialPrefill) -> None:
-        super().abandon_prefill(pp)
-        if pp.slot >= 0:
-            self.slots.free_row(pp.slot)
-            pp.slot = -1
-
-    def adopt_prefill(self, tokens, kv, last_logits):
-        raise ValueError("adopt_prefill lands K and V in pages; this model's "
-                         "conv layers keep a state too (prefill it through "
-                         "the engine)")
-
-    def prompt_logprobs(self, tokens, k: int = 0, adapter_id: int = 0):
-        raise ValueError("prompt scoring runs a paged family's dense "
-                         "forward; this model's prefill runs through its "
-                         "state slots")
-
-    def propose(self, *a, **kw):
-        raise ValueError("a sequence that keeps a state drafts nothing: a "
-                         "rejected token cannot be taken out of a state")
-
     # ---- decode ----
 
     def _block_table(self, states, pad_to: Optional[int] = None):
         """``(the pages' table, the rows' slots [rows, 1])``; a pad row's slot
-        is one past the slots, as its pages are one past the pool (its read
-        clamps, its write is dropped)."""
-        pad = (pad_to or len(states)) - len(states)
+        is one past the slots, as its pages are one past the pool."""
         return (super()._block_table(states, pad_to=pad_to),
-                jnp.asarray([[st.slot] for st in states]
-                            + [[self.pc.n_slots]] * pad, dtype=jnp.int32))
+                self._slot_column(states, pad_to))
 
     @property
     def free_pages(self) -> int:
         """What admission compares a request's pages with: the pool's, while a
         row's slot is free."""
         return self.pages.available if self.slots.rows_free else 0
-
-    def release(self, state: SequenceState) -> None:
-        if state.slot >= 0:
-            self.slots.free_row(state.slot)
-            state.slot = -1
-        super().release(state)

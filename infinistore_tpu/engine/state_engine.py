@@ -138,9 +138,88 @@ def refuse_for_slots(kw: dict) -> None:
 
 class SlotBook:
     """What the engines whose sequences keep a state share (this module's and
-    engine/hybrid_engine.py's): the counters' sinks and the resident
-    checkpoints' copies.  ``self.slots`` is the ``StateSlots``;
-    ``_slot_arrays`` the donated arrays whose first axis is the slot."""
+    engine/hybrid_engine.py's), mixed in before ``InferenceEngine``: what such
+    a cache refuses, a running row's slot from ``prefill_start`` to
+    ``release``, the counters' sinks and the resident checkpoints' copies.
+    ``self.slots`` is the ``StateSlots``; ``_slot_arrays`` the donated arrays
+    whose first axis is the slot.  An engine keeps how a hit is found
+    (``_start_in_row``), what a checkpoint is and what rides in a push."""
+
+    batched_prefill = False      # a row's slot is taken in ``prefill_start``
+
+    def __init__(self, params, cfg, pc, **kw):
+        refuse_for_slots(kw)
+        kw.setdefault("max_seqs", pc.max_rows)
+        super().__init__(params, cfg, pc, **kw)
+        if self.prefill_chunk is None or pc.stride % self.prefill_chunk:
+            raise ValueError(
+                f"a checkpoint is taken at the end of a prefill chunk: the "
+                f"stride {pc.stride} must be a multiple of prefill_chunk "
+                f"({self.prefill_chunk})")
+        self.slots = StateSlots(pc.n_slots, pc.max_rows)
+
+    def prefill_start(self, tokens: Sequence[int],
+                      adapter_id: int = 0) -> PartialPrefill:
+        """Admission half of a prefill: a row's slot (``MemoryError`` where
+        every one is taken), the deepest position this prompt can start from
+        (the engine's ``_start_in_row``), what is kept there adopted, and the
+        chunking.  ``_start_in_row`` fills ``block_ids`` in place: what the
+        list holds when it raises is what is pinned."""
+        assert adapter_id == 0 and len(tokens) >= 1, adapter_id
+        tokens = list(tokens)
+        keys = chunk_keys(tokens, self.model_id,
+                          chunk_tokens=self.pc.block_tokens)
+        row = self.slots.take_row()
+        block_ids: List[int] = []
+        try:
+            return self._start_in_row(tokens, keys, row, block_ids)
+        except BaseException:
+            self.pages.unpin(block_ids)
+            self.slots.free_row(row)
+            raise
+
+    def _make_visible(self, pp: PartialPrefill) -> SequenceState:
+        """The row's slot handed over to the decode-ready state (under strict
+        durability ``prefill_settle`` has awaited the checkpoint's push)."""
+        state = super()._make_visible(pp)
+        state.slot, pp.slot = pp.slot, -1
+        return state
+
+    def abandon_prefill(self, pp: PartialPrefill) -> None:
+        super().abandon_prefill(pp)
+        self._free_row(pp)
+
+    def release(self, state: SequenceState) -> None:
+        self._free_row(state)
+        super().release(state)
+
+    def _free_row(self, holder) -> None:
+        """The row's slot of a prefill or a sequence, back once."""
+        if holder.slot >= 0:
+            self.slots.free_row(holder.slot)
+            holder.slot = -1
+
+    def _slot_column(self, states, pad_to: Optional[int] = None) -> jax.Array:
+        """The rows' slots ``[rows, 1]``, as the decode scan's block table
+        takes them; a pad row's is one past the slots (its read clamps, its
+        write is dropped)."""
+        pad = (pad_to or len(states)) - len(states)
+        return jnp.asarray([[st.slot] for st in states]
+                           + [[self.pc.n_slots]] * pad, dtype=jnp.int32)
+
+    def adopt_prefill(self, tokens, kv, last_logits):
+        raise ValueError("adopt_prefill lands K and V in pages; layers of "
+                         "this model keep a state too (prefill it through "
+                         "the engine)")
+
+    def prompt_logprobs(self, tokens, k: int = 0, adapter_id: int = 0):
+        raise ValueError("prompt scoring runs a paged family's dense "
+                         "forward; this model's prefill runs through its "
+                         "state slots")
+
+    def propose(self, *a, **kw):
+        raise ValueError("a sequence that keeps a state drafts nothing: a "
+                         "rejected token cannot be taken out of a state")
 
     @property
     def _slot_arrays(self) -> tuple:
@@ -203,24 +282,16 @@ class SlotBook:
 
 
 class StateEngine(SlotBook, InferenceEngine):
+    cache_cls = StateCacheConfig
     transfer_cls = StateTransferEngine
     prefill_donates = ("cache",)
-    batched_prefill = False      # a row's slot is taken in ``prefill_start``
 
     # chunk keys of prompts seen, to count what a prompt shares with an
     # earlier one beyond the checkpoint it could adopt; host strings only
     SEEN_KEYS = 1 << 16
 
     def __init__(self, params, cfg, pc: StateCacheConfig, **kw):
-        refuse_for_slots(kw)
-        kw.setdefault("max_seqs", pc.max_rows)
         super().__init__(params, cfg, pc, **kw)
-        if self.prefill_chunk is None or pc.stride % self.prefill_chunk:
-            raise ValueError(
-                f"a checkpoint is taken at the end of a prefill chunk: the "
-                f"stride {pc.stride} must be a multiple of prefill_chunk "
-                f"({self.prefill_chunk})")
-        self.slots = StateSlots(pc.n_slots, pc.max_rows)
         self._seen: "OrderedDict[str, None]" = OrderedDict()
 
     def _dense_attention_in_kernel(self) -> bool:
@@ -228,23 +299,11 @@ class StateEngine(SlotBook, InferenceEngine):
 
     # ---- prefill ----
 
-    def prefill_start(self, tokens: Sequence[int],
-                      adapter_id: int = 0) -> PartialPrefill:
-        """Admission half of a prefill: a row's slot (``MemoryError`` where
-        every one is taken), the deepest checkpoint this prompt can start
-        from, its copy into that slot, and the chunking."""
-        assert adapter_id == 0 and len(tokens) >= 1, adapter_id
-        tokens = list(tokens)
-        row = self.slots.take_row()
-        try:
-            return self._start_in_row(tokens, row)
-        except BaseException:
-            self.slots.free_row(row)
-            raise
-
-    def _start_in_row(self, tokens: List[int], row: int) -> PartialPrefill:
+    def _start_in_row(self, tokens: List[int], keys: List[str], row: int,
+                      block_ids: List[int]) -> PartialPrefill:
+        """The deepest checkpoint this prompt can start from, copied into the
+        row's slot; a state keeps no pages, so ``block_ids`` stays empty."""
         T, n = self.pc.block_tokens, len(tokens)
-        keys = chunk_keys(tokens, self.model_id, chunk_tokens=T)
         # where a checkpoint may lie and leave a token to compute (the last
         # token's logits start the decode): stride, 2 x stride, .. <= n - 1
         aligned = list(range(self.pc.stride, n, self.pc.stride))
@@ -296,7 +355,7 @@ class StateEngine(SlotBook, InferenceEngine):
         padded = suffix + [0] * ((-S) % T)
         C = self.prefill_chunk
         return PartialPrefill(
-            tokens=tokens, keys=keys, block_ids=[], reused=P // T,
+            tokens=tokens, keys=keys, block_ids=block_ids, reused=P // T,
             done=P // T, n_complete=n // T, padded=padded, C=C,
             single=C >= len(padded), buf=None, plen=P, S=S,
             slot=row, ckpt_at=aligned[-1] if aligned and aligned[-1] > P else 0,
@@ -338,21 +397,6 @@ class StateEngine(SlotBook, InferenceEngine):
         if pp.ckpt_at and start + len(chunk) == pp.ckpt_at:
             self._checkpoint(pp)
 
-    def _make_visible(self, pp: PartialPrefill) -> SequenceState:
-        """The row's slot handed over to a decode-ready state (under strict
-        durability ``prefill_settle`` has awaited the checkpoint's push)."""
-        state = SequenceState(
-            seq_id=self._next_id, tokens=pp.tokens, block_ids=[],
-            chunk_keys=pp.keys, reused_chunks=pp.reused,
-            last_logits=pp.logits, slot=pp.slot, local_chunks=pp.local_chunks,
-            store_chunks=pp.store_chunks, store_load_s=pp.store_load_s,
-            lookup_s=pp.lookup_s,
-        )
-        pp.slot = -1
-        self._next_id += 1
-        self.seqs[state.seq_id] = state
-        return state
-
     def _checkpoint(self, pp: PartialPrefill) -> None:
         """The row's state, now that of position ``pp.ckpt_at``, kept: a copy
         in a resident slot under that position's key, and a push of every
@@ -376,24 +420,6 @@ class StateEngine(SlotBook, InferenceEngine):
             self._streamer.submit(pages, [key], marker=pp.marker)
         self._count(checkpoints_pushed=1, bytes_pushed=self.pc.slot_bytes)
 
-    def abandon_prefill(self, pp: PartialPrefill) -> None:
-        if pp.slot >= 0:
-            self.slots.free_row(pp.slot)
-            pp.slot = -1
-
-    def adopt_prefill(self, tokens, kv, last_logits):
-        raise ValueError("adopt_prefill lands K and V in pages; this model "
-                         "keeps a state (prefill it through the engine)")
-
-    def prompt_logprobs(self, tokens, k: int = 0, adapter_id: int = 0):
-        raise ValueError("prompt scoring runs a paged family's dense "
-                         "forward; this model's prefill runs through its "
-                         "state slots")
-
-    def propose(self, *a, **kw):
-        raise ValueError("a cache of state slots drafts nothing: a rejected "
-                         "token cannot be taken out of a state")
-
     # ---- decode ----
 
     def _grow_tables(self, states, n_steps: int) -> None:
@@ -406,21 +432,11 @@ class StateEngine(SlotBook, InferenceEngine):
         return len(lens) * self.pc.block_tokens
 
     def _block_table(self, states, pad_to: Optional[int] = None) -> jax.Array:
-        """The rows' slots ``[rows, 1]`` in the block table's place; a pad
-        row's is one past the slots (its read clamps, its write is
-        dropped)."""
-        pad = (pad_to or len(states)) - len(states)
-        return jnp.asarray([[st.slot] for st in states]
-                           + [[self.pc.n_slots]] * pad, dtype=jnp.int32)
+        """The rows' slots in the block table's place."""
+        return self._slot_column(states, pad_to)
 
     @property
     def free_pages(self) -> int:
         """What admission compares a request's pages with: any request fits
         while a row's slot is free (``serve`` bounds one by ``n_blocks``)."""
         return self.slots.rows_free * self.pc.n_blocks
-
-    def release(self, state: SequenceState) -> None:
-        if state.slot >= 0:
-            self.slots.free_row(state.slot)
-            state.slot = -1
-        self.seqs.pop(state.seq_id, None)

@@ -978,8 +978,7 @@ class StepProfiler:
         bench-JSON profiler block.  ``host_stall_frac`` is the sampled
         device-drain share of sampled step wall time — the one number
         that says device-bound vs host-bound; ``retraces_per_100_steps``
-        the steady-state retrace pressure (both trend in
-        scripts/bench_history.py)."""
+        the steady-state retrace pressure."""
         with self._lock:
             steps = self.steps
             by_kind = dict(self._by_kind)
@@ -1023,7 +1022,7 @@ class StepProfiler:
             "syncs_total": sum(syncs.values()),
             # dispatch economy: compiled programs launched per decoded
             # token — THE number the single-sync speculation work moves
-            # (directions in scripts/bench_history.py: down is good)
+            # (down is good)
             "dispatches_per_token": round(dispatch_total / tokens, 4)
             if tokens else 0.0,
             "tokens": tokens,

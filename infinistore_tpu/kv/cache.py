@@ -227,13 +227,12 @@ class StateCacheConfig:
 
 
 def cache_kind(cfg) -> str:
-    """What a sequence keeps of a model, asked of its config: ``"pages"`` for
-    every layer, ``"state"`` (a state a layer and no pages:
+    """What a sequence keeps of a model, as its config states it
+    (``cfg.cache_kind``): ``"pages"`` for every layer, which a config that
+    says nothing keeps, ``"state"`` (a state a layer and no pages:
     ``StateCacheConfig``) or ``"hybrid"`` (pages for its ``page_layers`` and a
     state for its ``state_layers``: ``HybridCacheConfig``)."""
-    if hasattr(cfg, "state_layers"):
-        return "hybrid"
-    return "state" if hasattr(cfg, "state_shape") else "pages"
+    return getattr(cfg, "cache_kind", "pages")
 
 
 def _check_slots(n_blocks: int, block_tokens: int, stride: int,

@@ -52,7 +52,7 @@ from .attention import (
     paged_decode_attention,
     paged_window_decode_attention,
 )
-from .llama import Params, _mlp, head_logits
+from .llama import Family, Params, _mlp, head_logits
 from .moe import routed_experts
 
 
@@ -431,3 +431,8 @@ def cohere2_moe_decode_forward(
         n_local = n_local + n
         x = x + (attn.reshape(B, -1) @ layer["wo"])[:, None, :] + ffn
     return _head(params, cfg, x)[:, 0], tuple(pools), n_local
+
+
+FAMILY = Family(name="cohere2_moe", config_cls=Cohere2MoeConfig,
+                config_from_file=config_from_file, init=init_cohere2_moe_params,
+                prefill_fn=cohere2_moe_prefill_forward, decode_fn=cohere2_moe_decode_forward)

@@ -61,7 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .attention import grouped_chunk_attention, paged_decode_attention
-from .llama import Params, _mlp, head_logits, rmsnorm
+from .llama import Family, Params, _mlp, head_logits, rmsnorm
 from .ssm_scan import selective_scan, selective_step
 
 # the seeded step: ``b_dt`` is the inverse softplus of a step drawn
@@ -112,6 +112,10 @@ class JambaConfig:
     def kv_page(self) -> Tuple[int, int, int]:
         """(planes, heads, width): K and V of the ATTENTION layers."""
         return (2, self.n_kv_heads, self.head_dim)
+
+    # what a sequence keeps (kv/cache.py ``cache_kind``): pages for the
+    # ``page_layers`` and a state for the ``state_layers``
+    cache_kind = "hybrid"
 
     @property
     def page_layers(self) -> Tuple[int, ...]:
@@ -544,3 +548,8 @@ def jamba_decode_forward(
     x, pages, conv = _walk_stack(cfg, params, mamba_layer, attn_layer,
                                  (x, pages, conv), ONE_BODY_DECODE)
     return _head(params, cfg, x[:, 0]), (pages, conv)
+
+
+FAMILY = Family(name="jamba", config_cls=JambaConfig,
+                config_from_file=config_from_file, init=init_jamba_params,
+                prefill_fn=jamba_prefill_forward, decode_fn=jamba_decode_forward)

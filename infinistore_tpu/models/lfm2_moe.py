@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .attention import grouped_chunk_attention, paged_decode_attention
-from .llama import Params, _attn_qkv, _mlp, head_logits, rmsnorm
+from .llama import Family, Params, _attn_qkv, _mlp, head_logits, rmsnorm
 from .moe import routed_experts, sigmoid_top_k
 
 # the epsilon of the chosen scores' normalisation, as the family's code has it
@@ -129,7 +129,9 @@ class Lfm2MoeConfig:
         positions."""
         return (self.conv_kernel - 1, self.dim)
 
-    # the kind's names (kv/cache.py ``HybridCacheConfig.for_model``)
+    # what a sequence keeps (kv/cache.py ``cache_kind``) and the kind's names
+    # (``HybridCacheConfig.for_model``)
+    cache_kind = "hybrid"
     page_layers = attn_layers
     state_layers = conv_layers
 
@@ -460,3 +462,8 @@ def lfm2_moe_decode_forward(
         x = x + op
         x = x + _ffn(layer, cfg, rmsnorm(x, layer["ln_mlp"], cfg.norm_eps))
     return _head(params, cfg, x[:, 0]), (pages, conv)
+
+
+FAMILY = Family(name="lfm2_moe", config_cls=Lfm2MoeConfig,
+                config_from_file=config_from_file, init=init_lfm2_moe_params,
+                prefill_fn=lfm2_moe_prefill_forward, decode_fn=lfm2_moe_decode_forward)

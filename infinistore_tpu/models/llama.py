@@ -18,7 +18,7 @@ Three entry points:
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +34,29 @@ from .attention import (
 )
 
 Params = Dict[str, Any]
+
+
+@dataclass(frozen=True)
+class Family:
+    """What a model family is to everything above its module, stated once
+    at the module's end (``FAMILY``) and held in ``models.FAMILIES``: the
+    ``family`` a config file names, the config's type, the reading of such a
+    file (``(path, spec) -> (model_id, cfg, seed)``), the weights from a key,
+    and the forwards the engine's hooks take.  The dense family's record has
+    no forwards of its own: the engine's defaults are its."""
+
+    name: Optional[str]
+    config_cls: type
+    config_from_file: Callable
+    init: Callable
+    prefill_fn: Optional[Callable] = None
+    decode_fn: Optional[Callable] = None
+
+    @property
+    def fns(self) -> Dict[str, Callable]:
+        """The engine's keyword arguments; empty for the dense family."""
+        return {k: f for k, f in (("prefill_fn", self.prefill_fn),
+                                  ("decode_fn", self.decode_fn)) if f}
 
 
 @dataclass(frozen=True)
@@ -64,8 +87,8 @@ class LlamaConfig:
     # is windowed (pattern 1) the engine returns window-dead pages to the
     # pool (engine._reclaim_window_pages).  A mixed local/global stack of
     # THIS module (Gemma-2) keeps and gathers all pages (one pool, a block
-    # spans the layer stack) and the mask hides them; models/cohere2_moe.py
-    # names its window layers (cfg.layer_windows), which then have a page
+    # spans the layer stack) and the mask hides them; a family whose config
+    # names its window layers (cfg.layer_windows) gives them a page
     # pool of their own: a sequence holds their pages for its window only,
     # gathers those, and fetches no others from the store.
     sliding_window: int | None = None
@@ -135,13 +158,10 @@ def scaled(cfg: LlamaConfig, **kw) -> LlamaConfig:
     return replace(cfg, **kw)
 
 
-def load_config_file(path: str) -> Tuple[str, Any, int]:
-    """Resolve a checked-in model config file (``configs/*.json``) to
-    ``(model_id, cfg, seed)``.  A file that names a ``family`` states the
-    source's sizes itself and is read by that family's module
-    (``config_from_file`` of ``models/mla_moe.py``,
-    ``models/cohere2_moe.py``, ``models/retention.py``,
-    ``models/lfm2_moe.py`` or ``models/jamba.py``); every other file
+def config_from_file(path: str, spec: dict) -> Tuple[str, LlamaConfig, int]:
+    """``(model_id, cfg, seed)`` of a checked-in model config file
+    (``configs/*.json``) that names no ``family`` (a file that names one is
+    read by that family's module: ``models.load_config_file``).  Such a file
     names a dense
     preset: a preset of this module by name, the
     ``published`` sizes it must agree with (so a jax-free launcher can read
@@ -152,22 +172,6 @@ def load_config_file(path: str) -> Tuple[str, Any, int]:
     depend on, so two files that build different weights never share store
     keys.  Other keys (``source``, ``stands_for``, ``assumed``) document
     the cut."""
-    import json
-
-    with open(path) as f:
-        spec = json.load(f)
-    if "family" in spec:
-        import importlib
-
-        module = {"deepseek_v3": "mla_moe", "cohere2_moe": "cohere2_moe",
-                  "brumby": "retention",
-                  "lfm2_moe": "lfm2_moe",
-                  "jamba": "jamba"}.get(spec["family"])
-        if module is None:
-            raise ValueError(f"{path}: family {spec['family']!r} is not one "
-                             f"infinistore_tpu.models computes")
-        return importlib.import_module(
-            f".{module}", __package__).config_from_file(path, spec)
     base = globals().get(spec.get("preset"))
     if type(base) is not LlamaConfig:
         raise ValueError(f"{path}: preset {spec.get('preset')!r} is not a "
@@ -614,3 +618,8 @@ def train_step_fn(cfg: LlamaConfig, lr: float = 1e-3):
         return params, loss
 
     return step
+
+
+# a file that names no ``family`` is this one's (``models.load_config_file``)
+FAMILY = Family(name=None, config_cls=LlamaConfig,
+                config_from_file=config_from_file, init=init_params)

@@ -44,7 +44,7 @@ from .attention import (
     latent_absorbed_decode_attention,
     latent_expanded_attention,
 )
-from .llama import Params, _mlp, head_logits, rmsnorm
+from .llama import Family, Params, _mlp, head_logits, rmsnorm
 from .moe import routed_experts, sigmoid_top_k
 
 
@@ -359,3 +359,8 @@ def mla_moe_decode_forward(
         x = x + _ffn(layer, cfg, h)
     x = rmsnorm(x, params["ln_out"], cfg.norm_eps)
     return x[:, 0] @ params["lm_head"], cache
+
+
+FAMILY = Family(name="deepseek_v3", config_cls=MlaMoeConfig,
+                config_from_file=config_from_file, init=init_mla_moe_params,
+                prefill_fn=mla_moe_prefill_forward, decode_fn=mla_moe_decode_forward)

@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .llama import Params, _attn_qkv, _layer, _mlp, head_logits, rmsnorm
+from .llama import Family, Params, _attn_qkv, _layer, _mlp, head_logits, rmsnorm
 
 
 @dataclass(frozen=True)
@@ -69,6 +69,9 @@ class RetentionConfig:
     attn_bias: bool = False
     rope_scaling: Any = None
     query_pre_attn_scalar: Any = None
+    # what a sequence keeps (kv/cache.py ``cache_kind``): a state a layer,
+    # no pages
+    cache_kind = "state"
 
     @property
     def state_dim(self) -> int:
@@ -438,3 +441,8 @@ def retention_decode_forward(
         x = x + (y.reshape(B, -1).astype(x.dtype) @ layer["wo"])[:, None, :]
         x = x + _mlp(layer, rmsnorm(x, layer["ln_mlp"], cfg.norm_eps))
     return _head(params, cfg, x[:, 0]), (S_all, z_all)
+
+
+FAMILY = Family(name="brumby", config_cls=RetentionConfig,
+                config_from_file=config_from_file, init=init_retention_params,
+                prefill_fn=retention_prefill_forward, decode_fn=retention_decode_forward)

@@ -2734,8 +2734,7 @@ def main(argv: Optional[List[str]] = None) -> None:
 
     import jax
 
-    from .engine import InferenceEngine
-    from .kv import PagedCacheConfig
+    from .engine import ENGINE_OF_KIND
     from .kv.cache import cache_kind
     from .models import TINY, family_of, init_params, load_config_file
 
@@ -2851,16 +2850,22 @@ def main(argv: Optional[List[str]] = None) -> None:
             Logger.warn(
                 f"no usable tokenizer in {tok_src!r}; serving token ids only"
             )
-    engine_cls = InferenceEngine
-    # what a sequence keeps of this model: pages, a state a layer, or both
-    keeps_state = cache_kind(cfg) == "state"
-    keeps_both = cache_kind(cfg) == "hybrid"
-    if keeps_state or keeps_both:
-        # the cache's unit is a state, not a page (engine/state_engine.py), or
-        # a state for some layers beside pages for the others
-        # (engine/hybrid_engine.py)
+    # what a sequence keeps of this model (pages, a state a layer, or both)
+    # brings its engine, and the engine its cache config and transfer engine
+    # (engine/__init__.py)
+    kind = cache_kind(cfg)
+    engine_cls = ENGINE_OF_KIND[kind]
+    if kind == "pages":
+        if args.state_stride is not None:
+            raise SystemExit("--state-stride is the checkpoint stride of a "
+                             "model whose layers (all, or some beside its "
+                             "attention layers) keep a state; every layer of "
+                             "this model keeps pages")
+        flag, sizes = "--window-blocks", {"window_blocks": args.window_blocks}
+    else:
         what = ("keeps pages for its attention layers and a state for the "
-                "others" if keeps_both else "keeps a state a layer and no pages")
+                "others" if kind == "hybrid"
+                else "keeps a state a layer and no pages")
         if args.state_stride is None or args.window_blocks is not None:
             raise SystemExit(
                 f"{args.model}: this model {what}: pass --state-stride (and "
@@ -2872,30 +2877,13 @@ def main(argv: Optional[List[str]] = None) -> None:
                 f"--state-stride {args.state_stride} must be a multiple of "
                 f"--prefill-chunk ({args.prefill_chunk}): a checkpoint is "
                 f"taken at the end of a chunk")
-        if keeps_both:
-            from .engine.hybrid_engine import HybridEngine as engine_cls
-            from .kv.cache import HybridCacheConfig as cache_cls
-        else:
-            from .engine.state_engine import StateEngine as engine_cls
-            from .kv.cache import StateCacheConfig as cache_cls
-        try:
-            pc = cache_cls.for_model(
-                cfg, args.n_blocks, args.block_tokens, args.state_stride,
-                max_rows=args.max_batch)
-        except ValueError as e:
-            raise SystemExit(f"--state-stride: {e}")
-    elif args.state_stride is not None:
-        raise SystemExit("--state-stride is the checkpoint stride of a model "
-                         "whose layers (all, or some beside its attention "
-                         "layers) keep a state; every layer of this model "
-                         "keeps pages")
-    else:
-        try:
-            pc = PagedCacheConfig.for_model(
-                cfg, args.n_blocks, args.block_tokens,
-                window_blocks=args.window_blocks)
-        except ValueError as e:
-            raise SystemExit(f"--window-blocks: {e}")
+        flag, sizes = "--state-stride", {"stride": args.state_stride,
+                                         "max_rows": args.max_batch}
+    try:
+        pc = engine_cls.cache_cls.for_model(
+            cfg, args.n_blocks, args.block_tokens, **sizes)
+    except ValueError as e:
+        raise SystemExit(f"{flag}: {e}")
     conn = None
     endpoints_spec = args.store_endpoints or os.environ.get(
         "ISTPU_STORE_ENDPOINTS"
@@ -2960,9 +2948,11 @@ def main(argv: Optional[List[str]] = None) -> None:
                 f"--draft-model vocab {dcfg.vocab_size} != target vocab "
                 f"{cfg.vocab_size}; speculation needs a shared vocabulary"
             )
-        dpc = PagedCacheConfig.for_model(
+        # a draft is a dense model (``refuse_for_family``): its cache is pages
+        draft_cls = ENGINE_OF_KIND["pages"]
+        dpc = draft_cls.cache_cls.for_model(
             dcfg, args.draft_n_blocks or args.n_blocks, args.block_tokens)
-        draft_engine = InferenceEngine(dparams, dcfg, dpc, **dfns)
+        draft_engine = draft_cls(dparams, dcfg, dpc, **dfns)
     if args.ngram_spec and draft_engine is not None:
         raise SystemExit("--ngram-spec and --draft-model are mutually "
                          "exclusive speculation modes")

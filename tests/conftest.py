@@ -3,6 +3,8 @@ import, so sharding tests (tp/dp/sp/pp) run without TPU hardware."""
 
 import os
 
+import pytest
+
 # Tests always run on a virtual 8-device CPU mesh; set ISTPU_TEST_TPU=1 on
 # a machine with a chip to run the TPU-gated tests against it instead.
 if not os.environ.get("ISTPU_TEST_TPU"):
@@ -16,6 +18,36 @@ if not os.environ.get("ISTPU_TEST_TPU"):
     # sets none while JAX_PLATFORMS pins cpu): XLA:CPU AOT reload warns
     # about machine-feature mismatches (+prefer-no-gather/scatter) with a
     # SIGILL caveat on this image — not worth the rerun speedup.
+
+
+# The SLO targets a fault walk's ``ServingServer`` is built under: a walk
+# that injects stalls of 0.4-2 s and compiles on the request path, on a CPU
+# that five other test workers load, violates the stock 2 s TTFT target by
+# design; the burn watchdog then pages, and the admission controller answers
+# 429 (``reason: burn``) or the health plane ``degraded`` in the walk's
+# HEALTHY phase.  These walks assert failure semantics; shedding on burn is
+# tests/test_admission.py's, under the stock targets.  (The fleets of
+# test_critpath / test_sessions / test_frontdoor set the same through
+# ISTPU_SLO_TTFT_S / ISTPU_SLO_TPOT_S, which their worker processes read.)
+WALK_SLO = {"slo_ttft_s": 60.0, "slo_tpot_s": 10.0}
+
+
+@pytest.fixture
+def timed_walk(tmp_path_factory):
+    """One lock across the run's workers, held by a test that TIMES a fleet
+    of processes (a floor, a ratio, a stage's delta): no two of them measure
+    against each other's stores; every other test still runs beside them."""
+    import fcntl
+
+    base = tmp_path_factory.getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        base = base.parent          # the run's directory, above popen-gwN
+    with open(base / "timed_walk.lock", "w") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)
+        try:
+            yield
+        finally:
+            fcntl.flock(f, fcntl.LOCK_UN)
 
 
 _DENSE_MEMO: dict = {}

@@ -772,12 +772,21 @@ def test_chaos_overload_sheds_lowest_lane_then_recovers(chaos_stack):
             "max_tokens": max_tokens, "temperature": 0,
             "priority": lane}, timeout=timeout)
 
-    # -- phase 0: healthy baseline on both lanes
+    # -- phase 0: healthy baseline on both lanes.  The stack is COLD: the
+    # first request of a shape compiles on the request path, and on a CPU
+    # that five other test workers load that alone overruns the 1 s target
+    # (read: 2.04 s, then 0.87 s for the next program); in these windows
+    # one violation of two finished requests is ``ttft_burn`` at 5x, and
+    # the next lane-0 ask was shed (429 ``reason: burn``) before the walk
+    # had begun.  So an ask rides out a shed (a wait on the condition
+    # "the lane admits", not on a clock), and phase 0 ends as it always
+    # did: burn clear, mode 1, /healthz ok.
+    def admitted(lane):
+        return _wait(lambda: ask(lane)[0] == 200, deadline_s=60)
+
     for _ in range(3):
-        st, body, _ = ask(0)
-        assert st == 200, body
-        st, body, _ = ask(10)
-        assert st == 200, body
+        assert admitted(0)
+        assert admitted(10)
     assert _wait(lambda: _metrics(srv.port).get(
         ("istpu_health_alert_active", (("rule", "ttft_burn"),))) == 0.0,
         deadline_s=10)

@@ -239,7 +239,7 @@ from infinistore_tpu.kv.hashing import chunk_keys  # noqa: E402
 from infinistore_tpu.models import TINY, init_params, scaled  # noqa: E402
 from infinistore_tpu.serve import ServingServer  # noqa: E402
 
-from conftest import make_dense_greedy  # noqa: E402
+from conftest import WALK_SLO, make_dense_greedy  # noqa: E402
 
 CFG = scaled(TINY, dtype=jnp.float32)
 PARAMS = init_params(CFG, jax.random.PRNGKey(7))
@@ -511,7 +511,8 @@ def chaos_cluster():
     prod_pool = RoutedStorePool(f.endpoints, op_timeout_s=5.0, replicas=2)
     prod = InferenceEngine(PARAMS, CFG, make_pc(), conn=prod_pool,
                            model_id="cluster-serve", kv_quant=None)
-    srv = ServingServer(eng, port=0, max_batch=4, model_id="cluster-serve")
+    srv = ServingServer(eng, port=0, max_batch=4, model_id="cluster-serve",
+                        **WALK_SLO)
     srv.start()
     yield srv, f, pool, prod
     srv.close()

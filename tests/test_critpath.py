@@ -589,7 +589,7 @@ def test_mesh_critpath_stage_sums_reproduce_client_ttft(mesh):
 
 
 def test_chaos_store_delay_named_by_trace_diff(mesh, live_store,
-                                               tmp_path):
+                                               tmp_path, timed_walk):
     """THE chaos walk (FaultInjector action first, house rule): a
     store-side ``GET_DESC`` delay — the in-flight shape of a dragging
     store tier — must be NAMED ``store_transfer`` by trace_diff from
@@ -598,16 +598,35 @@ def test_chaos_store_delay_named_by_trace_diff(mesh, live_store,
     fd, workers = mesh
     _port, mport = live_store
 
-    def drive(n, base):
+    def drive(n, base, tag):
         # FRESH prompts each round: a repeated prompt adopts from the
         # decode worker's LOCAL prefix cache and never touches the
-        # store, which would hide the armed fault entirely
-        for i in range(n):
+        # store, which would hide the armed fault entirely.  Client-
+        # minted trace ids, so a round's rows can be told from the ring's
+        tids = [f"chaos-{tag}-{i}" for i in range(n)]
+        for i, tid in enumerate(tids):
             status, _ = _post(fd.port, "/v1/completions",
                               {"prompt": list(range(base + 20 * i,
                                                     base + 20 * i + 16)),
-                               "max_tokens": 2, "temperature": 0})
+                               "max_tokens": 2, "temperature": 0},
+                              headers={"X-Istpu-Trace": tid})
             assert status == 200
+        return set(tids)
+
+    def capture(tids):
+        """The /debug/critpath payload over ONE ROUND's requests: the
+        live payload aggregates the whole ring, whose p99 of a stage over
+        under a hundred rows is its maximum, and the ring holds the
+        module's warm-up and every earlier test's rows; on a loaded host
+        one of those had a store hop slower than the armed delay and the
+        candidate's maximum did not move (``delta_ms: 0.007``).  Baseline
+        and candidate are each their own window, the same four prompts'
+        shapes in both."""
+        _s, snap = _get_json(fd.port, "/debug/critpath")
+        rows = [r for r in snap["rows"] if r.get("trace_id") in tids]
+        assert len(rows) == len(tids), (sorted(tids), snap["workers"])
+        return dict(snap, rows=rows, returned=len(rows),
+                    overall=critpath.aggregate(rows))
 
     def arm(rules):
         req = urllib.request.Request(
@@ -616,16 +635,16 @@ def test_chaos_store_delay_named_by_trace_diff(mesh, live_store,
         with urllib.request.urlopen(req, timeout=10) as r:
             return json.load(r)
 
-    drive(4, base=100)  # token ids stay under the TINY vocab (512)
-    _s, baseline = _get_json(fd.port, "/debug/critpath")
+    # token ids stay under the TINY vocab (512)
+    baseline = capture(drive(4, base=100, tag="base"))
     try:
         out = arm([{"op": "GET_DESC", "action": "delay",
                     "delay_s": 0.4}])
         assert out["armed"] == 1
-        drive(4, base=300)
+        tids = drive(4, base=300, tag="cand")
     finally:
         arm([])
-    _s, candidate = _get_json(fd.port, "/debug/critpath")
+    candidate = capture(tids)
 
     a = tmp_path / "baseline.json"
     b = tmp_path / "candidate.json"

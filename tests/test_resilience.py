@@ -419,7 +419,7 @@ from infinistore_tpu.kv import PagedCacheConfig  # noqa: E402
 from infinistore_tpu.models import TINY, init_params, scaled  # noqa: E402
 from infinistore_tpu.serve import ServingServer  # noqa: E402
 
-from conftest import make_dense_greedy  # noqa: E402
+from conftest import WALK_SLO, make_dense_greedy  # noqa: E402
 
 CFG = scaled(TINY, dtype=jnp.float32)
 PARAMS = init_params(CFG, jax.random.PRNGKey(7))
@@ -572,7 +572,8 @@ def chaos_stack():
     eng.decode_chunk = 4
     eng.breaker.failure_threshold = 2
     eng.breaker.cooldown_s = 0.5
-    srv = ServingServer(eng, port=0, max_batch=4, model_id="chaos-serve")
+    srv = ServingServer(eng, port=0, max_batch=4, model_id="chaos-serve",
+                        **WALK_SLO)
     srv.start()
     yield srv, proc, port, mport
     srv.close()

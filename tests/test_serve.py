@@ -22,7 +22,7 @@ PARAMS = init_params(CFG, jax.random.PRNGKey(7))
 PROMPT = [11, 42, 7, 99, 5, 3, 17, 28, 64, 1, 2]
 
 
-from conftest import make_dense_greedy
+from conftest import WALK_SLO, make_dense_greedy
 
 dense_greedy = make_dense_greedy(PARAMS, CFG)
 
@@ -887,8 +887,11 @@ def text_server():
         ),
     )
     eng.decode_chunk = 4
+    # the isolation rule of ``server`` above, for this module's second
+    # server: its tests are of the text contract, and on a loaded host the
+    # admission controller shed one of them (429 ``reason: queue``)
     srv = ServingServer(eng, port=0, max_batch=4, model_id="tiny-text",
-                        tokenizer=ByteTok())
+                        tokenizer=ByteTok(), **WALK_SLO)
     srv.start()
     yield srv
     srv.close()

@@ -471,6 +471,17 @@ def _metric(prom_text, family, **labels):
     return parsed.get(key)
 
 
+def _until(cond, timeout=30.0):
+    """``cond()`` once it is truthy (or its last value at ``timeout``): a
+    router judges a turn and (re)pins its session AFTER it has relayed the
+    response, so what a test reads of that right behind the response is
+    awaited, not assumed."""
+    deadline = time.time() + timeout
+    while not (got := cond()) and time.time() < deadline:
+        time.sleep(0.01)
+    return got
+
+
 def _sessions_of(port):
     _s, data = _get(port, "/debug/sessions")
     return json.loads(data)
@@ -516,20 +527,25 @@ def test_chaos_decode_drain_mid_conversation(live_store):
                                   "temperature": 0, "session": sid})
             return status, body
 
+        def affinity(result):
+            _s, data = _get(fd.port, "/metrics")
+            return _metric(data.decode(),
+                           "istpu_serve_session_affinity_total",
+                           result=result) or 0.0
+
         status, _b = turn(0)  # turn 1: fallback placement, then pinned
         assert status == 200
-        pinned = fd.session_pin(sid)
+        pinned = _until(lambda: fd.session_pin(sid))
         assert pinned, "turn 1 must bind the session"
         status, _b = turn(8)  # turn 2: a hit on the pin
         assert status == 200
+        hits_before = _until(lambda: affinity("hit"))
+        assert hits_before >= 1.0
         assert fd.session_pin(sid) == pinned
         _s, data = _get(fd.port, "/metrics")
         prom = data.decode()
         assert (_metric(prom, "istpu_serve_session_affinity_total",
                         result="fallback") or 0.0) >= 1.0
-        hits_before = _metric(prom, "istpu_serve_session_affinity_total",
-                              result="hit") or 0.0
-        assert hits_before >= 1.0
         miss_before = _metric(prom, "istpu_serve_session_affinity_total",
                               result="miss") or 0.0
 
@@ -559,13 +575,11 @@ def test_chaos_decode_drain_mid_conversation(live_store):
 
         status, _b = turn(8)  # turn 3: mid-conversation failover
         assert status == 200, "the drain must not surface to the client"
-        _s, data = _get(fd.port, "/metrics")
-        prom = data.decode()
-        assert (_metric(prom, "istpu_serve_session_affinity_total",
-                        result="miss") or 0.0) >= miss_before + 1.0
-        # the session re-pinned to whoever actually served
-        new_pin = fd.session_pin(sid)
-        assert new_pin == f"127.0.0.1:{survivor.port}"
+        # the session re-pinned to whoever actually served (the router
+        # counts the miss, then re-pins)
+        _until(lambda: fd.session_pin(sid) != pinned)
+        assert fd.session_pin(sid) == f"127.0.0.1:{survivor.port}"
+        assert affinity("miss") >= miss_before + 1.0
         # the survivor served turn 3 FROM THE STORE: adoption
         # provenance on its newest ledger record, not a recompute
         _s, data = _get(survivor.port, "/debug/requests")
@@ -582,9 +596,7 @@ def test_chaos_decode_drain_mid_conversation(live_store):
 
         status, _b = turn(8)  # turn 4: a hit on the NEW pin
         assert status == 200
-        _s, data = _get(fd.port, "/metrics")
-        assert (_metric(data.decode(), "istpu_serve_session_affinity_total",
-                        result="hit") or 0.0) >= hits_before + 1.0
+        assert _until(lambda: affinity("hit") >= hits_before + 1.0)
         # the router's fleet report carries the affinity tallies
         _s, data = _get(fd.port, "/debug/fleet")
         sess = json.loads(data).get("sessions") or {}
@@ -682,7 +694,8 @@ def test_serve_sessions_endpoint_validation_and_families():
             os.environ["ISTPU_ADMISSION"] = old
 
 
-def test_kv_persistence_contract_warm_store_vs_cold_control(live_store):
+def test_kv_persistence_contract_warm_store_vs_cold_control(live_store,
+                                                            timed_walk):
     """THE tier-1 acceptance walk (ROADMAP item 5's contract at engine
     grain): with the store holding turns 1..N-1 of an accumulating
     context, turn N's prefill ADOPTS the prior context (store
@@ -716,7 +729,6 @@ def test_kv_persistence_contract_warm_store_vs_cold_control(live_store):
         host_addr="127.0.0.1", service_port=live_store,
         connection_type=ist.TYPE_SHM, log_level="warning"))
     conn.connect()
-    os.environ.setdefault("ISTPU_CLIENT", "python")
     try:
         def conversation():
             """A 4-turn accumulating context: 128-token opener + 64
@@ -727,59 +739,83 @@ def test_kv_persistence_contract_warm_store_vs_cold_control(live_store):
                 context = context + toks(64)
             return out
 
-        def run_turns(contexts, attached):
-            """One timed prefill per turn on a FRESH engine; returns
-            (times, provenance states)."""
-            times, states = [], []
-            for ctx in contexts:
-                e = InferenceEngine(
-                    params, cfg, make_pc(),
-                    conn=conn if attached else None,
-                    model_id="sess-contract", prefill_chunk=64,
-                    store_durability="relaxed")
-                t0 = time.perf_counter()
-                s = e.prefill(list(ctx))
-                np.asarray(s.last_logits)
-                times.append(time.perf_counter() - t0)
-                states.append(s)
-                if attached:
-                    e.store_flush()  # turns 1..i now held by the store
-                e.release(s)
-            return times, states
+        def run_turn(ctx, attached):
+            """One timed prefill on a FRESH engine; returns (seconds,
+            provenance state)."""
+            e = InferenceEngine(
+                params, cfg, make_pc(),
+                conn=conn if attached else None,
+                model_id="sess-contract", prefill_chunk=64,
+                store_durability="relaxed")
+            t0 = time.perf_counter()
+            s = e.prefill(list(ctx))
+            np.asarray(s.last_logits)
+            dt = time.perf_counter() - t0
+            if attached:
+                e.store_flush()  # turns 1..i now held by the store
+            e.release(s)
+            return dt, s
+
+        def run_chains(contexts, r=0):
+            """The warm chain over ``contexts`` (turn i adopts turns 1..i-1
+            from the store and pushes itself) with the cold control of each
+            turn measured IN THE SAME WINDOW (right before or behind it, by
+            turns; detached, it never touches the store).  Returns
+            ((seconds, state) a turn) for warm and for cold."""
+            warm, cold = [], []
+            for i, ctx in enumerate(contexts):
+                for attached in ((True, False), (False, True))[(r + i) % 2]:
+                    (warm if attached else cold).append(run_turn(ctx, attached))
+            return warm, cold
 
         # warmup: the SAME chain shape on a throwaway context family —
         # compiles (prefill chunks per length AND the adoption scatter,
         # which traces per adopted-page count) are process-wide, so the
         # measured chains below pay transfer + compute only
-        _t, wst = run_turns(conversation(), True)
-        assert wst[-1].store_chunks >= 1  # the store round-trip works
-        run_turns(conversation(), False)
+        wst, _cold = run_chains(conversation())
+        assert wst[-1][1].store_chunks >= 1  # the store round-trip works
 
-        contexts = conversation()
-        lengths = [len(c) for c in contexts]
-        assert lengths == [128, 192, 256, 320]
-        t_warm, warm_states = run_turns(contexts, True)
-        t_cold, cold_states = run_turns(contexts, False)
-
-        # structural (deterministic): every warm turn >= 2 adopted the
-        # ENTIRE prior context from the store — fresh engines hold no
-        # local pages, so computed stays ~the 64 new tokens (near-flat
-        # in token terms) while the cold control recomputed everything
-        for i in range(1, len(contexts)):
-            st = warm_states[i]
-            assert st.local_chunks == 0
-            assert st.store_chunks >= lengths[i - 1] // 16, (
-                f"turn {i + 1}: adopted {st.store_chunks} chunks, "
-                f"expected the {lengths[i - 1] // 16} the store held")
-        for st in cold_states:
-            assert st.store_chunks == 0 and st.local_chunks == 0
+        # seven measured chains, each on a context family of its own
+        rounds = 7
+        t_warm = [[] for _ in range(4)]
+        t_cold = [[] for _ in range(4)]
+        for r in range(rounds):
+            contexts = conversation()
+            lengths = [len(c) for c in contexts]
+            assert lengths == [128, 192, 256, 320]
+            warm, cold = run_chains(contexts, r)
+            # structural (deterministic): every warm turn >= 2 adopted the
+            # ENTIRE prior context from the store — fresh engines hold no
+            # local pages, so computed stays ~the 64 new tokens (near-flat
+            # in token terms) while the cold control recomputed everything
+            for i in range(1, len(contexts)):
+                st = warm[i][1]
+                assert st.local_chunks == 0
+                assert st.store_chunks >= lengths[i - 1] // 16, (
+                    f"turn {i + 1}: adopted {st.store_chunks} chunks, "
+                    f"expected the {lengths[i - 1] // 16} the store held")
+            for _dt, st in cold:
+                assert st.store_chunks == 0 and st.local_chunks == 0
+            for i in range(len(contexts)):
+                t_warm[i].append(warm[i][0])
+                t_cold[i].append(cold[i][0])
         # timing (aggregate, generous): re-paying the context every
         # turn must cost more wall clock than adopting it — summed over
-        # turns 2..N so single-sample host jitter averages out
-        assert sum(t_warm[1:]) < sum(t_cold[1:]), (
-            f"warm {[f'{t * 1e3:.1f}' for t in t_warm]} ms vs "
-            f"cold {[f'{t * 1e3:.1f}' for t in t_cold]} ms "
-            f"(loadavg: {os.getloadavg()})"
+        # turns 2..N, each turn's time the BEST of the seven chains (the
+        # estimator this repo's put floors use: host load only ever adds
+        # to a timing, so the least of several is what the work costs).
+        # A warm turn and its control share a window, so a quiet moment
+        # serves both; one chain after the other, once, a burst of load
+        # landed on one side and decided the comparison, and at a load
+        # average of 16 the medians of five still read the host (a
+        # cross-process hop waits for the store to be scheduled, a
+        # recompute does not)
+        best_warm = [min(t) for t in t_warm[1:]]
+        best_cold = [min(t) for t in t_cold[1:]]
+        assert sum(best_warm) < sum(best_cold), (
+            f"warm {[f'{t * 1e3:.1f}' for t in best_warm]} ms vs "
+            f"cold {[f'{t * 1e3:.1f}' for t in best_cold]} ms, best of "
+            f"{rounds} (loadavg: {os.getloadavg()})"
         )
     finally:
         conn.close()

@@ -303,53 +303,6 @@ def test_console_serving_view_fixture():
 
 
 # ---------------------------------------------------------------------------
-# bench-history trend table (scripts/bench_history.py)
-# ---------------------------------------------------------------------------
-
-
-def test_bench_history_flags_regressions():
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, os.path.join(repo, "scripts"))
-    try:
-        import bench_history as bh
-    finally:
-        sys.path.pop(0)
-    rounds = [
-        (1, {"value": 4.5, "p50_read_latency_us": 16.0}, False),
-        (2, {"value": 5.5, "p50_read_latency_us": 20.0,
-             "tpu_hbm_put_gbps": 0.05}, False),
-        # latest: bandwidth down 20%, latency up 50%, stale tpu worse
-        (3, {"value": 4.4, "p50_read_latency_us": 24.0,
-             "tpu_hbm_put_gbps": 0.01}, True),
-    ]
-    flagged = bh.regressions(rounds, tolerance=0.05)
-    assert "value" in flagged  # up-metric that dropped
-    assert flagged["value"]["best_round"] == 2
-    assert "p50_read_latency_us" in flagged  # down-metric that rose
-    assert flagged["p50_read_latency_us"]["best_round"] == 1
-    # stale tpu numbers are never flagged as fresh regressions
-    assert "tpu_hbm_put_gbps" not in flagged
-    # within tolerance -> clean
-    assert bh.regressions(
-        [(1, {"value": 5.0}, False), (2, {"value": 4.9}, False)], 0.05
-    ) == {}
-    # fragment salvage: a truncated tail still yields metrics
-    sal = bh._salvage_pairs('"gbps": 4.5, "tpu_stale": true, "s": "x"')
-    assert sal == {"gbps": 4.5, "tpu_stale": True}
-    # the real repo records parse and render without error
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "scripts", "bench_history.py")],
-        capture_output=True, timeout=60, cwd=repo,
-    )
-    assert r.returncode == 0, r.stderr.decode()
-    assert b"metric" in r.stdout and b"r01" in r.stdout
-
-
-# ---------------------------------------------------------------------------
 # live: a mini open-loop run against a real server — the acceptance
 # surface (per-lane /metrics families, waterfall'd /debug/requests
 # joinable by trace id, goodput summary) in one pass

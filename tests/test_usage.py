@@ -322,18 +322,6 @@ def test_runbook_lint_green():
     assert r.returncode == 0, r.stdout + r.stderr
 
 
-def test_bench_history_strict_over_checked_in_records():
-    """The r05 failure mode (a truncated BENCH JSON silently skipped)
-    must fail --strict loudly; the checked-in set must pass it."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_history.py"),
-         "--strict"],
-        capture_output=True, text=True,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert r.returncode == 0, r.stdout + r.stderr
-
-
 def test_console_usage_view_fixture():
     from infinistore_tpu.top import Console, Snapshot
 
