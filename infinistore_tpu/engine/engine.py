@@ -43,7 +43,7 @@ from ..kv.cache import (
     write_pages,
 )
 from ..kv.hashing import chunk_keys
-from ..kv.transfer import KVTransferEngine
+from ..kv.transfer import KeysByPool, KVTransferEngine
 from ..models.attention import decode_kernel_engages
 from ..models.llama import (
     LlamaConfig,
@@ -292,16 +292,16 @@ def _write_prefill_pages(cache, block_ids, kv, block_tokens):
     )
 
 
-@partial(jax.jit, donate_argnums=(0,), static_argnums=(3, 4))
-def _write_prefill_pages_by_pool(caches, block_ids, kv, block_tokens,
-                                 pool_layers):
+@partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
+def _write_prefill_pages_by_pool(caches, block_ids, kv, block_tokens):
     """``_write_prefill_pages`` for a cache of one pool a layer kind: each
-    pool takes its own layers' rows of ``kv`` under its own page ids
-    (``block_ids`` one vector a pool, the same chunks in each)."""
+    pool takes its own layers' rows (``kv`` one array a pool, as the model's
+    prefill returns them: the pools' pages may differ in shape) under its own
+    page ids (``block_ids`` one vector a pool, the same chunks in each)."""
     return tuple(
         write_pages(c, ids, prefill_to_pages(
-            kv[np.asarray(layers), :, 0], ids.shape[0], block_tokens))
-        for c, ids, layers in zip(caches, block_ids, pool_layers))
+            rows[:, :, 0], ids.shape[0], block_tokens))
+        for c, ids, rows in zip(caches, block_ids, kv))
 
 
 @partial(jax.jit, static_argnums=(1,))
@@ -319,21 +319,26 @@ def _read_prefix_kv(cache, block_ids):
     return pages_to_seq_kv(read_pages(cache, block_ids))
 
 
-@partial(jax.jit, static_argnums=(2,))
-def _read_prefix_kv_by_pool(caches, block_ids, order):
-    """``_read_prefix_kv`` from a pool per layer kind: the first pool's ids
-    name every chunk of the prefix, the window pool's its LAST chunks only
-    (those inside the window); a window layer's rows below them are zeros
-    that no query reads (the layer slices its window out of the buffer).
-    ``order``: ``PagedCacheConfig.stack_order``."""
-    T = caches[0].shape[4]
-    n = block_ids[0].shape[0]
-    parts = []
-    for c, ids in zip(caches, block_ids):
-        kv = pages_to_seq_kv(read_pages(c, ids))
-        lead = (n - ids.shape[0]) * T
-        parts.append(jnp.pad(kv, ((0, 0),) * 3 + ((lead, 0),) + ((0, 0),) * 2))
-    return jnp.concatenate(parts, axis=0)[np.asarray(order)]
+def _last_rows_of(kv, rows):
+    """The last ``rows`` rows of the sequence axis (index 3) of [L, planes,
+    B, S, H, D], zeros in front where there are fewer: a window layer's
+    prefix buffer, whose rows END where the next chunk starts
+    (models/attention.py ``window_prefix_positions``), so that keeping it
+    from chunk to chunk is static slicing."""
+    S = kv.shape[3]
+    if S >= rows:
+        return kv[:, :, :, S - rows:]
+    return jnp.pad(kv, ((0, 0),) * 3 + ((rows - S, 0),) + ((0, 0),) * 2)
+
+
+_last_rows = jax.jit(_last_rows_of, static_argnums=(1,))
+
+
+@partial(jax.jit, static_argnums=(2,), donate_argnums=(0,))
+def _window_rows_after(buf, kv, rows):
+    """A window layer's prefix buffer after a chunk: the last ``rows`` rows
+    of what it held and the chunk's own."""
+    return _last_rows_of(jnp.concatenate([buf, kv], axis=3), rows)
 
 
 @partial(jax.jit, donate_argnums=(0,), static_argnums=(4,))
@@ -668,6 +673,9 @@ class SequenceState:
     # holds (never taken, or returned: stale ids, never gathered)
     window_ids: List[int] = field(default_factory=list)
     window_reclaimed: int = 0
+    # the window pool's pages this sequence may pin at once: reserved at its
+    # admission, given back at its release (``_window_quota``)
+    window_quota: int = 0
     # a cache of state slots (engine/state_engine.py): the slot this
     # sequence's running state lives in; no pages
     slot: int = -1
@@ -710,9 +718,11 @@ class PartialPrefill:
     off: int = 0         # next chunk offset into padded
     logits: Optional[jax.Array] = None
     adapter_id: int = 0  # LoRA adapter slot (0 = base model)
-    # the window pool's table (SequenceState.window_ids / window_reclaimed)
+    # the window pool's table (SequenceState.window_ids / window_reclaimed):
+    # as long as the pages WRITTEN so far, a chunk's pages taken before it
     window_ids: List[int] = field(default_factory=list)
     window_reclaimed: int = 0
+    window_quota: int = 0
     # a cache of state slots: the row's slot, and the position at which this
     # prompt's one checkpoint is taken (0: none)
     slot: int = -1
@@ -916,6 +926,10 @@ class InferenceEngine:
         self.wpages = (PrefixPageCache(BlockAllocator(pc.window_blocks))
                        if pc.window_layers else None)
         self._pool_layers = tuple(layers for layers, _ in pc.pools)
+        # window pages reserved by the sequences in flight, and the most any
+        # one of them has pinned at once (``_window_quota``)
+        self._window_reserved = 0
+        self.window_pinned_peak = 0
         # ``conn`` may be a single store connection (the classic
         # one-node path, byte-identical to every prior release) OR a
         # cluster.RoutedStorePool — then every store hop routes
@@ -978,6 +992,9 @@ class InferenceEngine:
                     f"sliding-window layers of different windows {sorted(sizes)}"
                     f": one window a stack is what the page rules cover")
             self._window = sizes.pop()
+            # rows of the window layers' prefix buffer: the window's pages
+            self._window_rows = -(-self._window // pc.block_tokens
+                                  ) * pc.block_tokens
             if self.transfer is not None and not getattr(
                     self.transfer, "loads_by_layer", False):
                 raise ValueError(
@@ -1194,6 +1211,17 @@ class InferenceEngine:
         n_local = reused = len(local_ids)
         lookup_s = load_s = 0.0  # wall seconds of the store hops (ledger)
         two = self.wpages is not None
+        n_pages_total = -(-S_total // T)
+        quota = self._window_quota(n_pages_total) if two else 0
+        if two and self._window_reserved + quota > self.pc.window_blocks:
+            # admitted only where its quota of window pages is free: no
+            # acquire can then fail between its chunks
+            self.pages.unpin(local_ids)
+            raise MemoryError(
+                f"out of KV pages: the window layers' pool has "
+                f"{self.pc.window_blocks - self._window_reserved} of "
+                f"{self.pc.window_blocks} blocks unreserved, a sequence "
+                f"reserves {quota}")
         # a local hit whose window pages are gone is filled from the store
         # or cut to ``usable``: the store is asked then too
         usable = self._usable_local(keys, n_local) if two else n_local
@@ -1204,21 +1232,20 @@ class InferenceEngine:
             with _stepprof.phase("kv.lookup") as ph:
                 # a stack with a window pool probes a layer whose page of
                 # every chunk it needs: a window layer's early pages may be
-                # gone from the store, and are not needed
+                # gone from the store (or were never sent), and are not
+                # needed
                 kw = ({"probe_layer": self._pool_layers[0][0]} if two else {})
                 n_store = min(self.transfer.guarded_lookup_prefix(keys, **kw),
                               max_reuse)
+                reused = max(reused, n_store)
+                if two:
+                    reused = self._deepest_with_window(
+                        keys, reused, n_store, usable)
             lookup_s = ph.s
-            reused = max(reused, n_store)
-            if two and any(i >= n_store
-                           for i in self._window_missing(keys, reused)):
-                # the store cannot fill the window of the longer prefix
-                reused = max(usable, n_store)
         else:
             reused = usable
 
         # pages for the rest of the sequence (incl. a partial tail page)
-        n_pages_total = -(-S_total // T)
         block_ids = list(local_ids)
         window_ids: List[int] = []
         try:
@@ -1229,11 +1256,11 @@ class InferenceEngine:
             n_local = len(block_ids)
             block_ids += self.pages.acquire(n_pages_total - n_local)
             if two:
-                window_ids, missing = self._acquire_window(
-                    keys, reused, n_pages_total)
+                window_ids, missing = self._acquire_window(keys, reused)
         except MemoryError:
             self.pages.unpin(block_ids)
             raise
+        self._window_reserved += quota
 
         if reused > n_local or (two and missing):  # store hop
             # guarded: BOTH the eviction race (a matched page vanished
@@ -1260,16 +1287,17 @@ class InferenceEngine:
                 # what is held locally, cut to where its window is held
                 try:
                     reused, window_ids = self._window_fallback(
-                        keys, block_ids, window_ids, n_local, reused,
-                        n_pages_total)
+                        keys, block_ids, window_ids, n_local, reused)
                 except MemoryError:
+                    self._window_reserved -= quota
                     self.pages.unpin(block_ids)
                     raise
             elif not ok:
                 reused = n_local
         return self._begin_chunks(
             tokens, keys, block_ids, reused, min(n_local, reused),
-            lookup_s, load_s, adapter_id=adapter_id, window_ids=window_ids)
+            lookup_s, load_s, adapter_id=adapter_id, window_ids=window_ids,
+            window_quota=quota)
 
     def _load(self, *args, **kw) -> Tuple[bool, float]:
         """``transfer.guarded_load`` into ``self.cache`` under phase
@@ -1302,7 +1330,8 @@ class InferenceEngine:
         P = reused * T
         if two:
             self._note_window_pages(
-                "acquired", n_pages_total - self._dead_chunks(reused))
+                "acquired", reused - self._dead_chunks(reused))
+            self._note_pinned(len(window_ids) - self._dead_chunks(reused))
         # provenance accounting AFTER the load settled (a failed store
         # load degrades those chunks back to computed, and must count so)
         if local_chunks:
@@ -1326,12 +1355,13 @@ class InferenceEngine:
 
         prefix_kv = None
         if reused and two:
-            prefix_kv = _read_prefix_kv_by_pool(
-                self.cache,
-                (jnp.asarray(block_ids[:reused]),
-                 jnp.asarray(window_ids[self._dead_chunks(reused):reused],
-                             dtype=jnp.int32)),
-                self.pc.stack_order)
+            # one buffer a pool: the full layers' over the prefix, the window
+            # layers' over the pages of the prefix's window (those held)
+            prefix_kv = (
+                _read_prefix_kv(self.cache[0], jnp.asarray(block_ids[:reused])),
+                _read_prefix_kv(self.cache[1], jnp.asarray(
+                    window_ids[self._dead_chunks(reused):reused],
+                    dtype=jnp.int32)))
         elif reused:
             prefix_kv = _read_prefix_kv(
                 self._page_pool, jnp.asarray(block_ids[:reused])
@@ -1361,6 +1391,10 @@ class InferenceEngine:
         single = C >= len(padded)
         if single:
             buf, plen = prefix_kv, P  # exact buffer: no masking, flash OK
+        elif two and prefix_kv is not None:
+            buf = (_pad_seq_axis(prefix_kv[0], cap_for(P)),
+                   _last_rows(prefix_kv[1], self._window_rows))
+            plen = P
         elif prefix_kv is not None:
             buf = _pad_seq_axis(prefix_kv, cap_for(P))
             plen = P
@@ -1412,24 +1446,102 @@ class InferenceEngine:
                 best = n
         return best
 
-    def _acquire_window(self, keys, reused: int, n_pages: int):
-        """The window pool's table of a sequence of ``n_pages`` pages that
-        adopts ``[0, reused)``: ``(window_ids, missing)``.  Chunks below the
-        adopted prefix's window get no page (a placeholder id that is never
-        gathered); those inside it the pool's resident page (pinned) or a
-        fresh one, ``missing``, for the store to fill; every later chunk a
-        fresh one.  All or nothing."""
+    def _acquire_window(self, keys, reused: int):
+        """The window pool's table of a sequence that adopts ``[0, reused)``,
+        as far as the adopted prefix: ``(window_ids, missing)``.  Chunks below
+        the adopted prefix's window get no page (a placeholder id that is
+        never gathered); those inside it the pool's resident page (pinned) or
+        a fresh one, ``missing``, for the store to fill.  All or nothing.  The
+        pages of the chunks it computes are taken A CHUNK AT A TIME, before
+        each (``_prefill_chunk``), and those of the tokens it decodes before
+        each run (``_grow_tables``)."""
         dead = self._dead_chunks(reused)
         hits = self.wpages.match_each(keys[dead:reused])
         held = [h for h in hits if h is not None]
         try:
-            fresh = iter(self.wpages.acquire(n_pages - dead - len(held)))
+            fresh = iter(self.wpages.acquire(len(hits) - len(held)))
         except MemoryError:
             self.wpages.unpin(held)
             raise
         ids = [0] * dead + [next(fresh) if h is None else h for h in hits]
-        ids.extend(fresh)
         return ids, [dead + j for j, h in enumerate(hits) if h is None]
+
+    def _window_quota(self, n_pages: int) -> int:
+        """The window pool's pages a sequence of ``n_pages`` prompt pages
+        reserves: what it can pin at once.  Before a chunk it holds the pages
+        of its window (``ceil(window / T)``: a chunk starts at a page's
+        edge), takes the chunk's, and
+        gives back after the chunk's push is snapshotted what lies below the
+        window of every position to come; in decode a run's pages take the
+        chunk's place.  One page over for a tail that is not whole.  The sum
+        of the quotas in flight never passes the pool (``prefill_start``
+        refuses the sequence that would), and the pages no sequence pins are
+        the unpinned residents an acquire evicts: no acquire fails between
+        chunks."""
+        T = self.pc.block_tokens
+        chunk = min(n_pages, (self.prefill_chunk or n_pages * T) // T)
+        run = -(-self.decode_chunk // T) + 1
+        return self._window_rows // T + max(chunk, run) + 1
+
+    def _note_pinned(self, n: int) -> None:
+        """A sequence pins ``n`` window pages: the peak, counted by its rises
+        (their sum is the peak: ``summary.kv.window_pinned_peak``)."""
+        if n > self.window_pinned_peak:
+            _stepprof.note_kv_pages(
+                window_pinned_peak=n - self.window_pinned_peak)
+            self.window_pinned_peak = n
+
+    def _window_sent(self, i: int, n_complete: int) -> bool:
+        """Whether a push sends the window layers' page of chunk ``i`` of a
+        prompt of ``n_complete`` whole blocks: whether a later hit can read
+        it.  A hit is adopted at a chunk boundary (an ABSOLUTE position that
+        is a multiple of ``prefill_chunk``, wherever this prompt's own chunks
+        began) or at the prompt's end (its last whole block, or the one
+        before: a prompt asked again computes its last token), and reads the
+        pages of ITS window; so of the next boundary ``b`` above ``i`` the
+        pages ``[_dead_chunks(b), b)``, and of the end likewise.  Where the
+        window is at least a chunk that is every page."""
+        per = (self.prefill_chunk or 0) // self.pc.block_tokens
+        if per:
+            b = (i // per + 1) * per
+            if b <= n_complete and i >= self._dead_chunks(b):
+                return True
+        return i >= self._dead_chunks(max(n_complete - 1, 0))
+
+    def _deepest_with_window(self, keys, deep: int, n_store: int,
+                             usable: int) -> int:
+        """The deepest prefix of at most ``deep`` chunks (whose full layers'
+        pages HBM or the store holds) at which the window layers' pages of
+        its window exist too, in the window pool or in the store.  Where every
+        window page is pushed (the window is at least a chunk) the store
+        holds those of its ``n_store`` chunks; where only the pages a hit at a
+        chunk boundary or at a prompt's end can read are pushed
+        (``_window_sent``), the depths tried are ``deep`` itself and the
+        deepest chunk boundary below it, and the store is asked for the
+        window's pages not held here (a handful; the last window layer's,
+        written last).  Else ``usable``, the local hit as far as its window
+        is held here."""
+        T = self.pc.block_tokens
+        per = (self.prefill_chunk or 0) // T    # 0: a prompt is one chunk
+        sparse = not per or per > self._window_rows // T
+        tries = [deep]
+        if sparse and per:
+            tries.append(deep // per * per)
+        elif not sparse:
+            tries.append(max(usable, n_store))
+        for r in tries:
+            if r <= usable:
+                break
+            missing = self._window_missing(keys, r)
+            if not missing:
+                return r
+            if not sparse:
+                if all(i < n_store for i in missing):
+                    return r
+            elif self.transfer.guarded_held(
+                    [keys[i] for i in missing], self._pool_layers[1][-1]):
+                return r
+        return usable
 
     def _window_loaded(self, keys, window_ids, n_local, reused, missing
                        ) -> None:
@@ -1452,7 +1564,7 @@ class InferenceEngine:
             counts["store_pages_window_skipped"])
 
     def _window_fallback(self, keys, block_ids, window_ids, n_local: int,
-                         reused: int, n_pages: int):
+                         reused: int):
         """A load that failed, for a sequence that had planned to adopt
         ``[0, reused)``: it keeps the local hit as far as the window pool
         holds its window, ``(kept, window_ids)``.  The window table is taken
@@ -1464,7 +1576,7 @@ class InferenceEngine:
         fresh = self.pages.acquire(n_local - keep)
         self.pages.unpin(block_ids[keep:n_local])
         block_ids[keep:n_local] = fresh
-        window_ids, _ = self._acquire_window(keys, keep, n_pages)
+        window_ids, _ = self._acquire_window(keys, keep)
         return keep, window_ids
 
     def _note_window_pages(self, event: str, n: int) -> None:
@@ -1540,6 +1652,14 @@ class InferenceEngine:
         T = self.pc.block_tokens
         off, C = pp.off, pp.C
         chunk = pp.padded[off : off + C]
+        if self.wpages is not None:
+            # the window pool's pages of THIS chunk, now: within the
+            # sequence's quota, so the acquire finds them (unpinned residents
+            # are what it evicts)
+            grow = len(chunk) // T
+            pp.window_ids.extend(self.wpages.acquire(grow))
+            self._note_window_pages("acquired", grow)
+            self._note_pinned(len(pp.window_ids) - pp.window_reclaimed)
         arr = jnp.asarray(chunk, dtype=jnp.int32)[None]
         kw = self._lora_args([pp.adapter_id]) | self._chunk_args(pp, len(chunk))
         if pp.buf is not None:
@@ -1569,7 +1689,7 @@ class InferenceEngine:
                 self.cache,
                 tuple(jnp.asarray(ids[pp.done : pp.done + n_pg], jnp.int32)
                       for ids in (pp.block_ids, pp.window_ids)),
-                kv, T, self._pool_layers,
+                kv, T,
             )
         prev_done, pp.done = pp.done, pp.done + n_pg
         # stream this chunk's complete pages to the store NOW — the
@@ -1585,10 +1705,9 @@ class InferenceEngine:
                 # bounded queue's put (where it blocks, two chunks already
                 # waiting, it is kv.push_wait)
                 with _stepprof.phase("kv.push_gather"):
-                    pages = self._gather_push(pp, lo, hi)
+                    pages, keys = self._gather_push(pp, lo, hi)
                     _stepprof.enter("kv.push_submit")
-                    self._streamer.submit(pages, pp.keys[lo:hi],
-                                          marker=pp.marker)
+                    self._streamer.submit(pages, keys, marker=pp.marker)
         if self.wpages is not None:
             # the push holds a snapshot: window pages that lie below the
             # window of every position still to come go back now
@@ -1600,14 +1719,23 @@ class InferenceEngine:
             # prefix buffer and append in place
             need = pp.plen + len(chunk)
             ncap = _round_up_pow2(need, C)
+            two = self.wpages is not None
+            # one buffer a pool: the window layers' keeps the rows that end
+            # where the next chunk starts, by static slicing
+            full, rows = kv if two else (kv, None)
             if pp.buf is None:
-                pp.buf = _pad_seq_axis(kv, ncap)
+                buf = _pad_seq_axis(full, ncap)
+                wbuf = _last_rows(rows, self._window_rows) if two else None
             else:
-                if ncap > pp.buf.shape[3]:
-                    pp.buf = _pad_seq_axis(pp.buf, ncap)
-                pp.buf = self._kv_append(
-                    pp.buf, kv, jnp.asarray(pp.plen, dtype=jnp.int32)
+                buf, wbuf = pp.buf if two else (pp.buf, None)
+                if ncap > buf.shape[3]:
+                    buf = _pad_seq_axis(buf, ncap)
+                buf = self._kv_append(
+                    buf, full, jnp.asarray(pp.plen, dtype=jnp.int32)
                 )
+                if two:
+                    wbuf = _window_rows_after(wbuf, rows, self._window_rows)
+            pp.buf = (buf, wbuf) if two else buf
             pp.plen = need
         else:
             # finished: a prefill that waits to be settled holds no prefix
@@ -1625,11 +1753,22 @@ class InferenceEngine:
         return kv
 
     def _gather_push(self, pp: "PartialPrefill", lo: int, hi: int):
-        """The snapshot of chunks ``[lo, hi)`` that goes to the store."""
-        return self.transfer.gather_pages(
-            self.cache,
-            pp.block_ids[lo:hi] if self.wpages is None
-            else (pp.block_ids[lo:hi], pp.window_ids[lo:hi]))
+        """The snapshot of chunks ``[lo, hi)`` that goes to the store, and
+        the keys it goes under.  Of a stack with a window pool: the full
+        layers' page of every chunk, the window layers' of the chunks a later
+        hit can read (``_window_sent``), counted sent and not sent."""
+        if self.wpages is None:
+            return (self.transfer.gather_pages(self.cache, pp.block_ids[lo:hi]),
+                    pp.keys[lo:hi])
+        sent = [i for i in range(lo, hi) if self._window_sent(i, pp.n_complete)]
+        n_win = len(self._pool_layers[1])
+        _stepprof.note_kv_pages(
+            window_pages_pushed=n_win * len(sent),
+            window_pages_push_skipped=n_win * (hi - lo - len(sent)))
+        return (self.transfer.gather_pages(
+                    self.cache, (pp.block_ids[lo:hi],
+                                 [pp.window_ids[i] for i in sent])),
+                KeysByPool((pp.keys[lo:hi], [pp.keys[i] for i in sent])))
 
     def _make_visible(self, pp: "PartialPrefill") -> SequenceState:
         """A finished prefill's decode-ready state.  Under strict durability
@@ -1655,6 +1794,7 @@ class InferenceEngine:
             last_logits=pp.logits,
             adapter_id=pp.adapter_id,
             window_ids=pp.window_ids, window_reclaimed=pp.window_reclaimed,
+            window_quota=pp.window_quota,
             local_chunks=pp.local_chunks, store_chunks=pp.store_chunks,
             store_load_s=pp.store_load_s, lookup_s=pp.lookup_s,
         )
@@ -1748,6 +1888,8 @@ class InferenceEngine:
         if self.wpages is not None:
             self.wpages.unpin(pp.window_ids[pp.window_reclaimed:])
             pp.window_ids = []
+            self._window_reserved -= pp.window_quota
+            pp.window_quota = 0
 
     def prefill_batch(
         self,
@@ -2632,6 +2774,7 @@ class InferenceEngine:
                 grow = need - len(st.window_ids)
                 st.window_ids.extend(self.wpages.acquire(grow))
                 self._note_window_pages("acquired", grow)
+                self._note_pinned(len(st.window_ids) - st.window_reclaimed)
 
     def _live_tokens(self, lens: np.ndarray) -> int:
         """What ``decode.live_token_steps`` counts a step: the rows' context
@@ -2817,11 +2960,14 @@ class InferenceEngine:
     @property
     def free_pages(self) -> int:
         """Pages a new sequence can obtain (fresh + reclaimable cached); of
-        a stack with a window pool, what BOTH pools can give: a sequence
-        takes at most as many window pages as pages of the other kind."""
+        a stack with a window pool, the full layers' pool's while the window
+        pool has a sequence's quota unreserved (``_window_quota``), else
+        none."""
         if self.wpages is None:
             return self.pages.available
-        return min(self.pages.available, self.wpages.available)
+        room = (self._window_reserved + self._window_quota(self.pc.n_blocks)
+                <= self.pc.window_blocks)
+        return self.pages.available if room else 0
 
     def _reclaim_window_pages(self, st, n_tokens: Optional[int] = None) -> None:
         """Window page reclamation: a page of sliding-window layers whose
@@ -2879,4 +3025,6 @@ class InferenceEngine:
             self.wpages.unpin(state.window_ids[state.window_reclaimed:])
             state.window_ids = []
             state.window_reclaimed = 0
+            self._window_reserved -= state.window_quota
+            state.window_quota = 0
         self.seqs.pop(state.seq_id, None)

@@ -182,8 +182,9 @@ class HybridEngine(SlotBook, InferenceEngine):
         ckpt = hi == pp.done and self._checkpoint_at(pp, hi)
         if ckpt:
             self._count(checkpoints_pushed=1, bytes_pushed=self.pc.slot_bytes)
-        return self.transfer.gather_pages(
-            self.cache, pp.block_ids[lo:hi], slot=pp.slot if ckpt else None)
+        return (self.transfer.gather_pages(
+            self.cache, pp.block_ids[lo:hi], slot=pp.slot if ckpt else None),
+            pp.keys[lo:hi])
 
     def _prefill_chunk(self, pp: PartialPrefill) -> None:
         if getattr(self.cfg, "state_update", None) == "scan":

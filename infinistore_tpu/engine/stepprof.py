@@ -121,7 +121,8 @@ PREFILL_COUNTS = ("granted_tokens", "spent_tokens",
 # what ``note_kv_pages`` sums, per step record and over the lifetime
 KV_COUNTS = ("store_pages_full", "store_pages_window",
              "store_pages_window_skipped", "window_pages_acquired",
-             "window_pages_returned")
+             "window_pages_returned", "window_pages_pushed",
+             "window_pages_push_skipped", "window_pinned_peak")
 
 # what ``note_state`` sums, per step record and over the lifetime
 STATE_COUNTS = ("checkpoints_taken", "checkpoints_pushed",
@@ -339,7 +340,11 @@ def note_kv_pages(**counts: int) -> None:
     window (engine.prefill_start).  Of the sliding-window layers' pool, the
     pages a sequence took into its table and those it returned BEFORE its
     release, their last token having left every window to come
-    (engine._reclaim_window_pages).  Summed under ``rec["kv"]``."""
+    (engine._reclaim_window_pages); the window layers' (layer, chunk) pages
+    a push sent and those it did NOT send because no later hit can read them
+    (engine._gather_push); and the most window-pool pages any one sequence
+    has pinned at once, noted as its RISES so that their sum is the peak
+    (engine._note_pinned).  Summed under ``rec["kv"]``."""
     _sum_into("kv", KV_COUNTS, counts)
 
 

@@ -36,7 +36,11 @@ keeps one such array PER LAYER KIND (``PagedCacheConfig.pools``): the window
 layers' pages are a pool of their own blocks, each pool with its own
 allocator, residency and block table, so that a sequence holds window-layer
 pages for its window only (engine.py).  Every other stack is the case "one
-kind": one array, one block id across every layer.
+kind": one array, one block id across every layer.  Where the kinds write
+pages of different shapes (4 key/value heads in the layers that read
+everything, 8 in the window layers, a key wider than a value), the page is a
+property of the POOL (``PagedCacheConfig.pool_kv``): one row a token of the
+pool's heads' keys and then their values, side by side.
 
 A family whose layers keep NO key or value per token (power retention,
 models/retention.py) has no page at all: a sequence's whole past in one layer
@@ -90,6 +94,18 @@ class PagedCacheConfig:
     # one block id across every layer.
     window_layers: Tuple[int, ...] = ()
     window_blocks: int = 0
+    # A PAGE HAS THE SHAPE OF ITS POOL.  Where the layer kinds write pages of
+    # different shapes (a model config's ``kv_pages``), per pool in ``pools``
+    # order: ``(key/value heads, width of a key, width of a value)``.  Such a
+    # pool's page is ONE plane of one row a token, its heads' keys side by
+    # side and then their values, ``heads x (key + value)`` wide and not a
+    # byte more: no key is padded to a value's width or a tile's.  (4 heads
+    # of 192 are 768 lanes, 8 are 1,536, the whole rows 1,280 and 2,560: all
+    # whole 128-lane tiles, where a minor axis of 192 or 320 would be padded
+    # to the next one in HBM.)  Empty: every pool's page is the one
+    # ``planes x n_kv_heads x head_dim`` above, which a config of this make
+    # states for its first pool.
+    pool_kv: Tuple[Tuple[int, int, int], ...] = ()
 
     @classmethod
     def for_model(cls, cfg, n_blocks: int, block_tokens: int = 16,
@@ -113,12 +129,14 @@ class PagedCacheConfig:
                 "window_blocks sizes the pool of a stack's sliding-window "
                 "layers beside its full ones; this model's layers are of "
                 "one kind")
+        pool_kv = tuple(getattr(cfg, "kv_pages", ())) if window_layers else ()
         return cls(n_layers=cfg.n_layers, n_kv_heads=heads, head_dim=width,
                    n_blocks=n_blocks, block_tokens=block_tokens,
                    dtype=cfg.dtype, planes=planes,
                    window_layers=window_layers,
                    window_blocks=((window_blocks or n_blocks)
-                                  if window_layers else 0))
+                                  if window_layers else 0),
+                   pool_kv=pool_kv)
 
     @property
     def pools(self) -> Tuple[Tuple[Tuple[int, ...], int], ...]:
@@ -133,28 +151,39 @@ class PagedCacheConfig:
                 (self.window_layers, self.window_blocks))
 
     @property
-    def stack_order(self) -> Tuple[int, ...]:
-        """Where each layer of the stack sits in the pools' arrays laid end
-        to end on the layer axis: ``concatenate(pool arrays)[stack_order]``
-        is in stack order (the identity for one pool)."""
-        flat = [li for layers, _ in self.pools for li in layers]
-        return tuple(sorted(range(len(flat)), key=flat.__getitem__))
-
-    @property
     def cache_bytes(self) -> int:
         """Bytes of the cache as ``init_cache`` allocates it, every pool."""
-        return sum(len(ls) * n * self.page_bytes for ls, n in self.pools)
+        return sum(len(ls) * n * self.page_bytes_of(p)
+                   for p, (ls, n) in enumerate(self.pools))
 
     @property
     def page_bytes(self) -> int:
-        """Bytes of one (layer, chunk) page: every plane, all heads."""
-        return (self.planes * self.block_tokens * self.n_kv_heads
-                * self.head_dim * np.dtype(jnp.dtype(self.dtype)).itemsize)
+        """Bytes of one (layer, chunk) page: every plane, all heads.  Of the
+        FIRST pool where the pools' pages differ (``page_bytes_of``)."""
+        return self.page_bytes_of(0)
 
     @property
     def page_shape(self) -> Tuple[int, ...]:
-        """Shape of one (layer, chunk) page as stored: [planes, H_kv, T, D]."""
-        return (self.planes, self.n_kv_heads, self.block_tokens, self.head_dim)
+        """Shape of one (layer, chunk) page as stored: [planes, H_kv, T, D];
+        the first pool's where they differ (``page_shape_of``)."""
+        return self.page_shape_of(0)
+
+    def page_shape_of(self, pool: int) -> Tuple[int, ...]:
+        """``page_shape`` of pool ``pool``'s layers."""
+        if not self.pool_kv:
+            return (self.planes, self.n_kv_heads, self.block_tokens,
+                    self.head_dim)
+        heads, k_width, v_width = self.pool_kv[pool]
+        return (1, 1, self.block_tokens, heads * (k_width + v_width))
+
+    def page_bytes_of(self, pool: int) -> int:
+        """``page_bytes`` of pool ``pool``'s layers."""
+        return int(np.prod(self.page_shape_of(pool))) * np.dtype(
+            jnp.dtype(self.dtype)).itemsize
+
+    def pool_of(self, layer: int) -> int:
+        """The pool that holds stack layer ``layer``'s pages."""
+        return int(bool(self.window_layers) and layer in self.window_layers)
 
 
 @dataclass(frozen=True)
@@ -417,20 +446,22 @@ def init_cache(cfg, sharding=None):
     stack with a pool per layer kind a tuple of one array a pool
     (``cfg.pools``), each over its own layers and blocks; for a
     ``StateCacheConfig`` the slots ``(S, z)``; for a ``HybridCacheConfig``
-    ``(pages [page layers, ...], slots [n_slots] + ``cfg.slot_shape``)``."""
+    ``(pages [page layers, ...], slots [n_slots] + ``cfg.slot_shape``)``.  A pool's
+    array is ``[its layers, planes, heads, its blocks, T, width]`` of ITS page
+    (``page_shape_of``)."""
     if isinstance(cfg, StateCacheConfig):
         lead = (cfg.n_slots, cfg.n_layers, cfg.n_kv_heads, cfg.state_dim)
         return (jnp.zeros(lead + (cfg.head_dim,), cfg.dtype, device=sharding),
                 jnp.zeros(lead, cfg.dtype, device=sharding))
-    arrays = tuple(
-        jnp.zeros((len(layers), cfg.planes, cfg.n_kv_heads, n_blocks,
-                   cfg.block_tokens, cfg.head_dim),
-                  dtype=cfg.dtype, device=sharding)
-        for layers, n_blocks in cfg.pools)
+    arrays = []
+    for p, (layers, n_blocks) in enumerate(cfg.pools):
+        planes, heads, T, width = cfg.page_shape_of(p)
+        arrays.append(jnp.zeros((len(layers), planes, heads, n_blocks, T, width),
+                                dtype=cfg.dtype, device=sharding))
     if isinstance(cfg, HybridCacheConfig):
         return arrays[0], jnp.zeros((cfg.n_slots,) + cfg.slot_shape,
                                     cfg.slot_dtype, device=sharding)
-    return arrays if cfg.window_layers else arrays[0]
+    return tuple(arrays) if cfg.window_layers else arrays[0]
 
 
 def write_pages(cache: jax.Array, block_ids: jax.Array, pages: jax.Array) -> jax.Array:

@@ -76,28 +76,50 @@ def _band_ranges(L: int, groups: int) -> List[Tuple[int, int]]:
     return [(l0, min(Lg, L - l0)) for l0 in range(0, L, Lg)]
 
 
-@partial(jax.jit, static_argnums=(2, 3, 4))
-def _gather_bands(cache, block_ids, order, quant, groups):
+@partial(jax.jit, static_argnums=(2, 3))
+def _gather_bands(cache, block_ids, quant, groups):
     """The device half of a push as ONE program: ``block_ids``'s pages of
     every layer gathered (``read_pages``), laid out as the store holds them,
     [L, n, planes, H, T, D] with each (layer, chunk) page contiguous,
     quantized and packed where ``quant`` (the packed rows ARE the wire
     pages, half the bytes to move), and cut into ``groups`` layer bands.
-    A cache of one pool a layer kind (a tuple) takes one id array a pool,
-    the same chunks in each, and ``order`` (``PagedCacheConfig.
-    stack_order``) puts the layers back in stack order.  Eager, each of
-    these was a launch of its own, and a band's slice the costliest."""
-    if isinstance(cache, tuple):
-        gathered = jnp.concatenate(
-            [read_pages(c, ids) for c, ids in zip(cache, block_ids)],
-            axis=0)[np.asarray(order)]
-    else:
-        gathered = read_pages(cache, block_ids)  # [L, planes, H, n, T, D]
+    Eager, each of these was a launch of its own, and a band's slice the
+    costliest.  A cache of one pool a layer kind: ``_gather_bands_by_pool``."""
+    gathered = read_pages(cache, block_ids)  # [L, planes, H, n, T, D]
     pages = jnp.transpose(gathered, (0, 3, 1, 2, 4, 5))
     if quant:
         pages = quantize_pages(pages)  # [L, n, wire_page_bytes] uint8
     return tuple(pages[l0 : l0 + n]
                  for l0, n in _band_ranges(pages.shape[0], groups))
+
+
+class KeysByPool(list):
+    """The chunk keys of a push from a cache of one pool a layer kind.  The
+    list itself names every chunk of the push (the first pool's layers, which
+    read everything, send a page of each); ``by_pool[p]`` names the chunks of
+    which pool ``p``'s layers send a page: a window layer sends only the pages
+    a later hit can read (engine ``_window_sent``)."""
+
+    def __init__(self, by_pool: Sequence[Sequence[str]]):
+        super().__init__(by_pool[0])
+        self.by_pool = tuple(list(keys) for keys in by_pool)
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _gather_bands_by_pool(caches, block_ids, plan, quant):
+    """``_gather_bands`` over a cache of one pool a layer kind, one program:
+    ``block_ids`` one id array a pool (of DIFFERENT lengths where a pool
+    sends fewer chunks), ``plan`` the bands as ``(pool, first layer in the
+    pool's array, layers)`` in stack order (``KVTransferEngine._band_plan``).
+    Each band [l, n of its pool, ...its pool's page] in store layout."""
+    # every layer of a pool gathered by ids at once, the bands cut out of the
+    # small result: a band's layers sliced out of the POOL first would be a
+    # copy of that slab (XLA:TPU fuses no slice into a gather's operand)
+    gathered = {pool: jnp.transpose(read_pages(caches[pool], block_ids[pool]),
+                                    (0, 3, 1, 2, 4, 5))
+                for pool in {pool for pool, _, _ in plan}}
+    bands = [gathered[pool][l0 : l0 + n] for pool, l0, n in plan]
+    return tuple(quantize_pages(b) if quant else b for b in bands)
 
 
 class KVTransferEngine:
@@ -148,14 +170,17 @@ class KVTransferEngine:
             raise ValueError(f"unsupported quant mode: {quant!r}")
         if quant and cfg.planes != 2:
             # kv/quant.py scales per (K|V, head); a page of another make
-            # (one latent plane) has no such scale, and a wrong one would
+            # (one latent plane, or keys and values of unequal widths side
+            # by side in one row) has no such scale, and a wrong one would
             # be served: refuse here, at start-up
             raise ValueError(
                 f"kv quant {quant!r} scales pages per (K|V, head); a page "
                 f"of {cfg.planes} plane(s) goes to the store as it is "
                 f"(--kv-quant none)")
         self.quant = quant
-        # bytes of one page as it crosses the wire / sits in the pool
+        # bytes of one page as it crosses the wire / sits in the pool; where
+        # the pools' pages differ (``cfg.pool_kv``) the FIRST pool's, and
+        # every path that moves another pool's asks ``_wire_bytes_of``
         self.wire_page_bytes = page_quant_bytes(cfg) if quant else cfg.page_bytes
         self._key_suffix = ":q8" if quant else ""
         # DOUBLE-buffered staging, alternated per load call: the banded
@@ -219,6 +244,12 @@ class KVTransferEngine:
     def _tokens_of(self, chunk_keys_: Sequence[str]) -> int:
         """Tokens a push of these keys stands for in ``push_totals``."""
         return len(chunk_keys_) * self.cfg.block_tokens
+
+    def _wire_bytes_of(self, pool: int) -> int:
+        """``wire_page_bytes`` of pool ``pool``'s page."""
+        if not getattr(self.cfg, "pool_kv", ()):
+            return self.wire_page_bytes
+        return self.cfg.page_bytes_of(pool)
 
     def _add_totals(self, which: str, **add) -> None:
         with self._totals_lock:
@@ -293,11 +324,14 @@ class KVTransferEngine:
         return self._layer_blocks(chunk_keys_, range(l0, l1))
 
     def _layer_blocks(
-        self, chunk_keys_: Sequence[str], layers: Sequence[int]
+        self, chunk_keys_: Sequence[str], layers: Sequence[int],
+        pb: Optional[int] = None,
     ) -> List[Tuple[str, int]]:
         """``_page_blocks`` for any layers, in the order given: which
-        layers own a page of each chunk is the caller's to say."""
-        pb = self.wire_page_bytes
+        layers own a page of each chunk is the caller's to say.  ``pb``: the
+        page's bytes where it is not ``wire_page_bytes`` (a pool of another
+        page shape)."""
+        pb = self.wire_page_bytes if pb is None else pb
         n = len(chunk_keys_)
         return [
             (layer_key(ck, layer) + self._key_suffix, (j * n + i) * pb)
@@ -319,15 +353,40 @@ class KVTransferEngine:
         behind the writes of the pages it reads), so a caller can hand
         them to a background pusher while the next chunk computes and the
         cache's pages are written again.  A cache of one pool a layer kind
-        (a tuple, ``cfg.pools``) takes one id list a pool, the same chunks
-        in each; what is pushed is every layer's page, in stack order."""
+        (a tuple, ``cfg.pools``) takes one id list a pool, the pages THAT
+        pool sends (``KeysByPool`` names their chunks; a pool may send fewer
+        than the first); the bands are ``_band_plan``'s, in stack order."""
         if isinstance(cache, tuple):
             ids = tuple(np.asarray(i, dtype=np.int32) for i in block_ids)
-            order = self.cfg.stack_order
-        else:
-            ids, order = np.asarray(block_ids, dtype=np.int32), None
-        return _gather_bands(cache, ids, order, bool(self.quant),
-                             self.pipeline_groups)
+            plan = tuple((p, l0, len(ls)) for p, l0, ls in self._band_plan(
+                [len(i) for i in ids]))
+            return _gather_bands_by_pool(cache, ids, plan, bool(self.quant))
+        return _gather_bands(cache, np.asarray(block_ids, dtype=np.int32),
+                             bool(self.quant), self.pipeline_groups)
+
+    def _band_plan(self, chunks_by_pool: Sequence[int]
+                   ) -> List[Tuple[int, int, Tuple[int, ...]]]:
+        """The bands of a push from a cache of one pool a layer kind, in
+        STACK order: the ``pipeline_groups`` bands of consecutive layers that
+        one array would be cut into, each cut again where the layer kind
+        changes (a band is gathered from one pool and has one page shape), as
+        ``(pool, the band's first layer's place in the pool's array, its
+        layers by their ids in the stack)``.  A pool that sends no chunk has
+        no band.  Keys are written in this order; the layer whose page says a
+        chunk is whole (``_last_page_layer``) is the first pool's last."""
+        place = {li: (p, j) for p, (layers, _) in enumerate(self.cfg.pools)
+                 for j, li in enumerate(layers)}
+        plan: list = []
+        for l0, n in _band_ranges(self.cfg.n_layers, self.pipeline_groups):
+            run: list = []
+            for li in range(l0, l0 + n):
+                if run and place[run[-1]][0] != place[li][0]:
+                    plan.append(run)
+                    run = []
+                run.append(li)
+            plan.append(run)
+        return [(place[run[0]][0], place[run[0]][1], tuple(run))
+                for run in plan if chunks_by_pool[place[run[0]][0]]]
 
     @staticmethod
     def _band_host(p: jax.Array):
@@ -372,7 +431,9 @@ class KVTransferEngine:
         token for ``push_commit``, the streamer-thread half."""
         for p in bands:
             p.copy_to_host_async()
-        return list(bands), list(chunk_keys_), time.perf_counter()
+        keys = (chunk_keys_ if isinstance(chunk_keys_, KeysByPool)
+                else list(chunk_keys_))
+        return list(bands), keys, time.perf_counter()
 
     def push_commit(self, token) -> int:
         """Off-critical-path half of a push: materialize each band —
@@ -386,14 +447,14 @@ class KVTransferEngine:
         written."""
         t_in = time.perf_counter()
         parts, chunk_keys_, t_begin = token
-        L = self.cfg.n_layers
-        pb = self.wire_page_bytes
         stages = {"d2h_s": 0.0, "pool_copy_s": 0.0, "wire_s": 0.0,
                   "alloc_s": 0.0, "commit_s": 0.0,
                   "zero_copy_bands": 0, "staged_bands": 0}
-        with tracing.span("kv.push_pages", pages=len(chunk_keys_) * L,
-                          bytes=len(chunk_keys_) * L * pb):
-            total = self._push_banded(parts, chunk_keys_, stages)
+        plan = self._parts_blocks(parts, chunk_keys_)
+        with tracing.span("kv.push_pages",
+                          pages=sum(len(b) for b, _ in plan),
+                          bytes=sum(len(b) * pb for b, pb in plan)):
+            total = self._push_banded(parts, plan, stages)
         self.last_push_stages = stages
         t_out = time.perf_counter()
         self._add_totals(
@@ -412,25 +473,35 @@ class KVTransferEngine:
         return (self._page_blocks(chunk_keys_, l0, l0 + part.shape[0]),
                 self.wire_page_bytes)
 
-    def _push_banded(self, parts, chunk_keys_: Sequence[str],
-                     stages: dict) -> int:
-        raw = self.conn
-        l0s = []
-        l0 = 0
+    def _parts_blocks(self, parts, chunk_keys_: Sequence[str]
+                      ) -> List[Tuple[List[Tuple[str, int]], int]]:
+        """``(blocks, block size)`` of every band of a push, in the bands'
+        order.  One array: the bands are consecutive layers' pages of every
+        chunk (``_part_blocks``).  One pool a layer kind (``KeysByPool``): the
+        bands are ``_band_plan``'s, each its layers' pages of the chunks ITS
+        pool sends, in its pool's page size."""
+        if isinstance(chunk_keys_, KeysByPool):
+            return [(self._layer_blocks(chunk_keys_.by_pool[p], layers,
+                                        self._wire_bytes_of(p)),
+                     self._wire_bytes_of(p))
+                    for p, _, layers in self._band_plan(
+                        [len(k) for k in chunk_keys_.by_pool])]
+        out, l0 = [], 0
         for p in parts:
-            l0s.append(l0)
+            out.append(self._part_blocks(chunk_keys_, l0, p))
             l0 += p.shape[0]
+        return out
+
+    def _push_banded(self, parts, plan, stages: dict) -> int:
+        raw = self.conn
         if (self.push_mode != "legacy"
                 and getattr(raw, "shm_mode", False)
                 and getattr(raw, "alloc_first", False)):
             # zero-copy path: descriptors learned up front, each band's
             # fill targets the mapped pool itself (exactly one copy
             # between the device buffer and the pool)
-            bands = [
-                (*self._part_blocks(chunk_keys_, l0, p),
-                 self._band_fill(p, stages))
-                for l0, p in zip(l0s, parts)
-            ]
+            bands = [(*blocks, self._band_fill(p, stages))
+                     for blocks, p in zip(plan, parts)]
             info = self._src.write_cache_into(bands, _stage)
             stages["alloc_s"] += info.get("alloc_s", 0.0)
             # a band whose allocation came back in pieces: scratch to pool
@@ -446,8 +517,7 @@ class KVTransferEngine:
             # i's socket write runs while band i+1's D2H (kicked at
             # push_begin) is still in flight
             total = 0
-            for l0, p in zip(l0s, parts):
-                blocks, pb = self._part_blocks(chunk_keys_, l0, p)
+            for (blocks, pb), p in zip(plan, parts):
                 nbytes = pb * len(blocks)
                 slot = self._ensure_push_staging(nbytes)
                 self._band_fill(p, stages)(slot[:nbytes])
@@ -460,10 +530,8 @@ class KVTransferEngine:
         # legacy path (push_mode="legacy", or an shm peer that did not
         # negotiate alloc-first): the pre-alloc-first banded pipelined
         # put, kept as the byte-parity reference and the old-server path
-        bands = [
-            (*self._part_blocks(chunk_keys_, l0, p), self._band_host(p))
-            for l0, p in zip(l0s, parts)
-        ]
+        bands = [(*blocks, self._band_host(p))
+                 for blocks, p in zip(plan, parts)]
         writer = getattr(self._src, "write_cache_pipelined", None)
         if writer is not None:
             return writer(bands)
@@ -538,9 +606,11 @@ class KVTransferEngine:
             groups = [(list(ls), list(cs), table)
                       for ls, cs, table in layer_chunks if len(ls) and len(cs)]
             pages = sum(len(ls) * len(cs) for ls, cs, _ in groups)
-            with tracing.span("kv.load_pages", pages=pages, bytes=pages * pb):
+            nbytes = sum(len(ls) * len(cs) * self._wire_bytes_of(
+                self._pool_of(ls)[0]) for ls, cs, _ in groups)
+            with tracing.span("kv.load_pages", pages=pages, bytes=nbytes):
                 return self._load_layer_groups(cache, chunk_keys_, groups,
-                                               pages)
+                                               pages, nbytes)
         nbytes = L * n * pb
         with tracing.span("kv.load_pages", pages=L * n, bytes=nbytes):
             return self._load_pages_banded(cache, block_ids, chunk_keys_, n)
@@ -553,7 +623,8 @@ class KVTransferEngine:
                 return p, [pool_layers.index(li) for li in layers]
         raise ValueError(f"layers {layers} are in no pool of the cache")
 
-    def _load_layer_groups(self, cache, chunk_keys_, groups, pages: int):
+    def _load_layer_groups(self, cache, chunk_keys_, groups, pages: int,
+                           nbytes: int):
         """Every group fetched (the all-or-nothing half: a missing page
         raises here, before the cache is touched), then every group
         scattered into the donated cache, or into its layers' pool of it."""
@@ -575,11 +646,11 @@ class KVTransferEngine:
         cache = tuple(pools) if isinstance(cache, tuple) else pools[0]
         self._landed(cache, t0, t1, stages, pages,
                      len({i for _, cs, _ in groups for i in cs})
-                     * self.cfg.block_tokens)
+                     * self.cfg.block_tokens, nbytes)
         return cache
 
     def _landed(self, out, t0: float, t1: float, stages: dict, pages: int,
-                tokens: int) -> None:
+                tokens: int, nbytes: Optional[int] = None) -> None:
         """The end of every load: wait until ``out`` has materialized (every
         read of this call's staging buffer must complete before a LATER
         call can rewrite it: with the double buffer, a stale optimistic
@@ -595,7 +666,8 @@ class KVTransferEngine:
         ts = time.perf_counter()
         jax.block_until_ready(out)
         t2 = time.perf_counter()
-        nbytes = pages * self.wire_page_bytes
+        if nbytes is None:
+            nbytes = pages * self.wire_page_bytes
         self.last_load_stages = {
             "fetch_s": round(t1 - t0, 6),
             "scatter_s": round(t2 - t1 - held, 6),
@@ -626,15 +698,19 @@ class KVTransferEngine:
         if stages is None:
             stages = dict.fromkeys(LOAD_STAGES, 0.0)
         n = len(chunk_keys_)
-        pb = self.wire_page_bytes
         layers = list(range(self.cfg.n_layers) if layers is None else layers)
+        # the page of the layers asked for: one fetch is of one pool's layers
+        by_kind = bool(getattr(self.cfg, "pool_kv", ()))
+        pool = self.cfg.pool_of(layers[0]) if by_kind else 0
+        shape = self.cfg.page_shape_of(pool) if by_kind else self.cfg.page_shape
+        pb = self._wire_bytes_of(pool)
         L = len(layers)
         nbytes = L * n * pb
         staging = self._ensure_staging(nbytes)
         bands = []
         meta = []  # (staging offset, span, n_layers) per band
         for l0, nl in _band_ranges(L, self.pipeline_groups):
-            blocks = self._layer_blocks(chunk_keys_, layers[l0 : l0 + nl])
+            blocks = self._layer_blocks(chunk_keys_, layers[l0 : l0 + nl], pb)
             off = l0 * n * pb
             bands.append((blocks, pb, staging.ctypes.data + off))
             meta.append((off, nl * n * pb, nl))
@@ -649,7 +725,7 @@ class KVTransferEngine:
             else:
                 host = (
                     band.view(jnp.dtype(self.cfg.dtype))
-                    .reshape((nl, n) + self.cfg.page_shape)
+                    .reshape((nl, n) + shape)
                 )
             # async H2D: returns immediately; the next band's pool copy
             # (and its prefetched GET_DESC) overlaps this band's DMA
@@ -722,8 +798,27 @@ class KVTransferEngine:
 
     @property
     def _last_page_layer(self) -> int:
-        """The layer whose page of a chunk is written last."""
-        return self.cfg.n_layers - 1
+        """The layer whose page of a chunk says the chunk is whole: the last
+        written of those that send a page of EVERY chunk (the first pool's
+        last layer; a window layer after it sends only some chunks' pages)."""
+        return self.cfg.pools[0][0][-1]
+
+    def guarded_held(self, chunk_keys_: Sequence[str], layer: int) -> bool:
+        """Whether the store holds ``layer``'s page of EVERY one of
+        ``chunk_keys_`` (one round trip each: a handful, the pages of one
+        window); False on a store failure or an open circuit.  A hint: the
+        load that follows is all or nothing whatever this says."""
+        if not self.breaker.allow():
+            return False
+        try:
+            return all(self._call(
+                "check_exist", layer_key(ck, layer) + self._key_suffix) == 0
+                for ck in chunk_keys_)
+        except _resilience.transport_errors():
+            self.breaker.record_failure()
+            return False
+        except Exception:  # noqa: BLE001 — a probe is an optimization
+            return False
 
     def _deepest_whole(self, chunk_keys_: Sequence[str], layer: int) -> int:
         """``i + 1`` for the deepest ``chunk_keys_[i]`` under which the store
@@ -954,7 +1049,7 @@ def _gather_bands_and_state(pages, block_ids, groups, conv, slot):
     """``_gather_bands`` over the page layers and, in the same program, slot
     ``slot``'s state of every state layer ``[state layers, width]`` as one
     band more: the one launch a push costs the engine thread carries both."""
-    bands = _gather_bands(pages, block_ids, None, False, groups)
+    bands = _gather_bands(pages, block_ids, False, groups)
     state = conv[slot]
     # a layer's state as one row, however the slots lay it out (slot_shape)
     return bands + (state.reshape(state.shape[0], -1),)
@@ -1014,7 +1109,7 @@ class HybridTransferEngine(KVTransferEngine):
         pages, conv = cache
         ids = np.asarray(block_ids, dtype=np.int32)
         if slot is None:
-            return _gather_bands(pages, ids, None, False, self.pipeline_groups)
+            return _gather_bands(pages, ids, False, self.pipeline_groups)
         return _gather_bands_and_state(pages, ids, self.pipeline_groups, conv,
                                        np.int32(slot))
 

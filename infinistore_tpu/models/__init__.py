@@ -1,7 +1,8 @@
 import json as _json
 
 from .. import jaxcfg as _jaxcfg  # noqa: F401 -- process-wide jax config
-from . import cohere2_moe, jamba, lfm2_moe, llama, mla_moe, retention
+from . import (cohere2_moe, jamba, lfm2_moe, llama, mimo_v2, mla_moe,
+               retention)
 from .llama import (
     GEMMA2_9B,
     LLAMA3_1B,
@@ -81,7 +82,7 @@ from .hf import (
 # (and, only where its sequences keep something new, its cache config).  The
 # dense family (``llama.FAMILY``) is what every other config and file is.
 FAMILIES = (mla_moe.FAMILY, cohere2_moe.FAMILY, retention.FAMILY,
-            lfm2_moe.FAMILY, jamba.FAMILY)
+            lfm2_moe.FAMILY, jamba.FAMILY, mimo_v2.FAMILY)
 
 
 def family_of(cfg) -> dict:

@@ -83,6 +83,53 @@ def apply_rope(
     return out.astype(x.dtype)
 
 
+def apply_rope_leading(
+    x: jax.Array,
+    positions: jax.Array,
+    theta: float,
+    rotated: int,
+) -> jax.Array:
+    """A PARTIAL rotation: the first ``rotated`` dimensions of every head are
+    rotated, dimension ``i`` paired with ``i + rotated / 2`` (the halves of the
+    rotated part, not neighbours as ``apply_rope`` pairs them), at the
+    frequencies ``theta ** (-2i / rotated)``; the rest of the head passes
+    through.  x: [..., S, H, D]; positions: broadcastable to [..., S]."""
+    half = rotated // 2
+    freqs = 1.0 / (theta ** (jnp.arange(0, rotated, 2, dtype=jnp.float32)
+                             / rotated))
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    cos = jnp.cos(angles)[..., None, :]
+    sin = jnp.sin(angles)[..., None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:rotated].astype(jnp.float32)
+    return jnp.concatenate(
+        [(x1 * cos - x2 * sin).astype(x.dtype),
+         (x2 * cos + x1 * sin).astype(x.dtype), x[..., rotated:]], axis=-1)
+
+
+def split_kv_rows(rows: jax.Array, heads: int, k_width: int):
+    """A page row of ``heads`` keys side by side and then their values
+    (``kv/cache.py`` ``pool_kv``) -> ``(k [..., heads, k_width], v [...,
+    heads, v_width])``."""
+    cut = heads * k_width
+    lead = rows.shape[:-1]
+    return (rows[..., :cut].reshape(lead + (heads, k_width)),
+            rows[..., cut:].reshape(lead + (heads, -1)))
+
+
+def softmax_with_sink(logits: jax.Array, sink: jax.Array | None) -> jax.Array:
+    """Softmax over the last axis of float32 ``logits`` (masked entries
+    ``-inf``); with ``sink`` (broadcastable to ``logits[..., 0]``) the
+    denominator holds ``exp(sink)`` besides: ``p_j = exp(s_j) / (exp(b) +
+    sum_j' exp(s_j'))``.  The sink is a key that every query sees and that
+    has no value: the weights sum to less than one."""
+    if sink is None:
+        return jax.nn.softmax(logits, axis=-1)
+    b = jnp.broadcast_to(sink.astype(jnp.float32), logits.shape[:-1])
+    return jax.nn.softmax(
+        jnp.concatenate([logits, b[..., None]], axis=-1), axis=-1)[..., :-1]
+
+
 def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
     """[..., S, H_kv, D] -> [..., S, H_kv*n_rep, D]: query head h reads KV
     head h // n_rep.
@@ -270,7 +317,8 @@ def _causal_attention_xla(q, k, v, prefix_len, *, q_offset=0, prefix_pad=None,
 
 
 def gather_layer_kv(
-    cache: jax.Array, layer: int, block_table: jax.Array
+    cache: jax.Array, layer: int, block_table: jax.Array,
+    kv_split: tuple | None = None,
 ) -> tuple[jax.Array, ...]:
     """One layer's pages for a block table, one array per plane of the
     page (keys and values; or the one latent plane), gathered by index
@@ -285,14 +333,23 @@ def gather_layer_kv(
     copies the layer's slab, and K's and V's halves of it, in every layer
     of every step (PERF.md, PR 27).  The advanced indices are split by a
     slice, so the table's dims land in front: [B, max_pages, H_kv, T, D].
-    Out-of-bounds page ids (pad rows) clamp; callers mask by length."""
+    Out-of-bounds page ids (pad rows) clamp; callers mask by length.
+
+    ``kv_split`` ``(heads, key width)``: the page is one plane of one row a
+    token, its heads' keys side by side and then their values
+    (``split_kv_rows``); what comes back is ``(k [B, S, heads, key width],
+    v [B, S, heads, value width])``."""
     B, max_pages = block_table.shape
     Hkv, _, T, D = cache.shape[2:]
-    return tuple(
+    planes = tuple(
         jnp.moveaxis(cache[layer, plane, :, block_table], 2, 3).reshape(
             B, max_pages * T, Hkv, D)
         for plane in range(cache.shape[1])
     )
+    if kv_split is None:
+        return planes
+    (rows,) = planes
+    return split_kv_rows(rows[:, :, 0], *kv_split)
 
 
 def _latent_kvb(w_kvb: jax.Array, n_heads: int, nope: int):
@@ -405,6 +462,7 @@ def paged_decode_attention(
     seq_lens: jax.Array,
     window: int | None = None,
     softcap: float | None = None,
+    kv_split: tuple | None = None,
 ) -> jax.Array:
     """One-token decode attention against the paged cache.
 
@@ -427,10 +485,15 @@ def paged_decode_attention(
     64 fill a 128-lane tile in pairs).  The XLA form views the gathered rows
     as ``H_kv`` heads of ``D`` again; the kernel is handed each query head in
     its own KV head's lanes of a row of zeros (``lanes_of_own_head``).
+
+    ``kv_split`` ``(heads, key width)``: a page of one plane whose row is the
+    heads' keys and then their values, a value narrower than a key
+    (``gather_layer_kv``): the XLA form, and the output is as wide as a value.
     """
     xla = functools.partial(_paged_decode_attention_xla, layer=layer,
-                            window=window, softcap=softcap)
-    if not decode_kernel_engages(q, cache, window, softcap):
+                            window=window, softcap=softcap, kv_split=kv_split)
+    if kv_split is not None or not decode_kernel_engages(
+            q, cache, window, softcap):
         return xla(q, cache, block_table, seq_lens)
     # Pallas is a second of import: paid by the programs that can hold the kernel
     from . import paged_decode_kernel
@@ -471,11 +534,12 @@ def _kernel_over_side_by_side_heads(kernel, q, cache, block_table, seq_lens):
 
 
 def _paged_decode_attention_xla(q, cache, block_table, seq_lens, *, layer,
-                                window=None, softcap=None):
+                                window=None, softcap=None, kv_split=None):
     """``paged_decode_attention`` in plain ``jax.numpy``: the table's pages
     gathered, contracted against the grouped query, masked by length."""
     B, H, D = q.shape
-    k, v = gather_layer_kv(cache, layer, block_table)
+    k, v = gather_layer_kv(cache, layer, block_table,
+                           **({"kv_split": kv_split} if kv_split else {}))
     if k.shape[-1] != D:    # KV heads side by side in a row: by head again
         k, v = (x.reshape(B, x.shape[1], -1, D) for x in (k, v))
     S_max, Hkv = k.shape[1:3]
@@ -494,7 +558,7 @@ def _paged_decode_attention_xla(q, cache, block_table, seq_lens, *, layer,
     logits = jnp.where(mask[:, None, None, :], logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgk,bkhd->bhgd", probs.astype(v.dtype), v)
-    return out.reshape(B, H, D)
+    return out.reshape(B, H, v.shape[-1])
 
 
 def paged_multitoken_attention_xla(
@@ -537,6 +601,17 @@ def paged_multitoken_attention_xla(
     return out.reshape(B, S, H, D)
 
 
+def window_prefix_positions(rows: int, start) -> tuple[jax.Array, jax.Array]:
+    """Of a window layer's prefix buffer, ``rows`` rows that END where the
+    chunk starts (``start``, the prefix's length: static or traced): each
+    row's absolute position and whether it holds a key at all (a row that
+    would lie before position 0 does not).  The buffer is right-aligned so
+    that keeping it is static slicing: after a chunk it is the last ``rows``
+    rows of itself and the chunk's own (engine ``_prefill_chunk``)."""
+    pos = start - rows + jnp.arange(rows)
+    return pos, pos >= 0
+
+
 def window_page_span(window: int, block_tokens: int) -> int:
     """Pages that can hold a key visible through a window of ``window``
     tokens ending at any position: the window's tokens lie in at most
@@ -551,6 +626,8 @@ def paged_window_decode_attention(
     block_table: jax.Array,
     seq_lens: jax.Array,
     window: int,
+    sink: jax.Array | None = None,
+    kv_split: tuple | None = None,
 ) -> jax.Array:
     """One-token decode attention of a sliding-window layer: the row's
     WINDOW'S pages are gathered, and no others.
@@ -563,7 +640,10 @@ def paged_window_decode_attention(
     below it is never read, so what it holds, or whether it was ever
     filled, cannot reach the arithmetic.  Same arguments and the same
     function of the visible keys: a key at ``j`` is visible to the token at
-    ``i = seq_len - 1`` iff ``i - window < j <= i``."""
+    ``i = seq_len - 1`` iff ``i - window < j <= i``.  ``sink`` [H], float32:
+    one learned logit a query head in the softmax's denominator
+    (``softmax_with_sink``); None for a family that has none, whose program
+    is what it was.  ``kv_split``: as ``gather_layer_kv``."""
     B, H, D = q.shape
     T = cache.shape[4]
     width = block_table.shape[1]
@@ -572,7 +652,8 @@ def paged_window_decode_attention(
     slots = first[:, None] + jnp.arange(span)[None, :]           # [B, span]
     sub = jnp.take_along_axis(block_table, jnp.minimum(slots, width - 1),
                               axis=1)
-    k, v = gather_layer_kv(cache, layer, sub)
+    k, v = gather_layer_kv(cache, layer, sub,
+                           **({"kv_split": kv_split} if kv_split else {}))
     Hkv = k.shape[2]
     q = q.reshape(B, Hkv, H // Hkv, D)
     scale = 1.0 / np.sqrt(D)
@@ -583,9 +664,10 @@ def paged_window_decode_attention(
     pos = first[:, None] * T + jnp.arange(span * T)[None, :]     # [B, span*T]
     mask = (pos < seq_lens[:, None]) & (pos >= seq_lens[:, None] - window)
     logits = jnp.where(mask[:, None, None, :], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = softmax_with_sink(
+        logits, None if sink is None else sink.reshape(Hkv, H // Hkv))
     out = jnp.einsum("bhgk,bkhd->bhgd", probs.astype(v.dtype), v)
-    return out.reshape(B, H, D)
+    return out.reshape(B, H, v.shape[-1])
 
 
 def grouped_chunk_attention(
@@ -596,13 +678,16 @@ def grouped_chunk_attention(
     k_pos: jax.Array,
     k_valid: jax.Array | None = None,
     window: int | None = None,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Attention of a prefill chunk by ABSOLUTE positions, one key/value
     head at a time.
 
-    q: [B, Sq, H, D]; k/v: [B, Sk, H_kv, D] (whatever rows the caller chose
-    to hand over: a whole prefix buffer or a window's slice of one, then the
-    chunk's own); q_pos [Sq], k_pos [Sk] absolute positions; k_valid [Sk]
+    q: [B, Sq, H, D]; k: [B, Sk, H_kv, D], v: [B, Sk, H_kv, Dv] (a value may
+    be narrower than a key; whatever rows the caller chose
+    to hand over: a whole prefix buffer or a window's rows, then the
+    chunk's own); ``sink`` [H] float32: one logit a query head in the
+    softmax's denominator (``softmax_with_sink``), None for none; q_pos [Sq], k_pos [Sk] absolute positions; k_valid [Sk]
     marks rows that hold a key at all (a padded buffer's slack is not one).
     A key is visible iff valid, ``k_pos <= q_pos`` and, with ``window``,
     ``k_pos > q_pos - window``.
@@ -623,13 +708,16 @@ def grouped_chunk_attention(
         mask &= k_valid[None, :]
 
     def one_head(args):
-        qh, kh, vh = args           # [B, Sq, G, D], [B, Sk, D], [B, Sk, D]
+        qh, kh, vh = args[:3]       # [B, Sq, G, D], [B, Sk, D], [B, Sk, Dv]
         logits = jnp.einsum("bsgd,bkd->bgsk", qh, kh).astype(jnp.float32) * scale
-        probs = jax.nn.softmax(jnp.where(mask[None, None], logits, -jnp.inf),
-                               axis=-1)
+        probs = softmax_with_sink(
+            jnp.where(mask[None, None], logits, -jnp.inf),
+            args[3][None, :, None] if sink is not None else None)
         return jnp.einsum("bgsk,bkd->bsgd", probs.astype(vh.dtype), vh)
 
-    out = jax.lax.map(one_head, (
-        jnp.moveaxis(q.reshape(B, Sq, Hkv, G, D), 2, 0),
-        jnp.moveaxis(k, 2, 0), jnp.moveaxis(v, 2, 0)))   # [Hkv, B, Sq, G, D]
-    return jnp.moveaxis(out, 0, 2).reshape(B, Sq, H, D)
+    heads = (jnp.moveaxis(q.reshape(B, Sq, Hkv, G, D), 2, 0),
+             jnp.moveaxis(k, 2, 0), jnp.moveaxis(v, 2, 0))
+    if sink is not None:
+        heads += (sink.reshape(Hkv, G),)
+    out = jax.lax.map(one_head, heads)                   # [Hkv, B, Sq, G, Dv]
+    return jnp.moveaxis(out, 0, 2).reshape(B, Sq, H, v.shape[-1])

@@ -10,8 +10,10 @@ What differs from models/llama.py and models/mla_moe.py, and where it lives:
   page is K|V by head, as the dense families'.  A window layer READS ITS
   WINDOW'S PAGES AND NO OTHERS: in decode through
   ``attention.paged_window_decode_attention`` (the table's slots picked by
-  index arithmetic on each row's length), in a prefill chunk through a
-  window's slice of the prefix buffer.  So a page wholly below the window
+  index arithmetic on each row's length), in a prefill chunk through the
+  window layers' OWN prefix buffer, which holds the window's rows and no
+  others (the prefix buffer is one array a pool).  So a page wholly below
+  the window
   may hold anything, or never have been loaded from the store
   (engine.prefill_start skips it): it cannot reach the arithmetic.
 * **The parallel block**: ``h = LayerNorm(x)`` once (mean subtracted, a
@@ -51,9 +53,10 @@ from .attention import (
     grouped_chunk_attention,
     paged_decode_attention,
     paged_window_decode_attention,
+    window_prefix_positions,
 )
 from .llama import Family, Params, _mlp, head_logits
-from .moe import routed_experts
+from .moe import held_pairs, routed_experts
 
 
 @dataclass(frozen=True)
@@ -284,11 +287,7 @@ def expert_layer(layer: Params, cfg: Cohere2MoeConfig, h: jax.Array,
         chosen, idx = jax.lax.top_k(scores, cfg.top_k)
         w = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
         idx = idx.astype(jnp.int32)
-        local = (idx >= cfg.first_expert) & (
-            idx < cfg.first_expert + cfg.n_experts_held)
-        if live is not None:
-            local &= jnp.repeat(live, S)[:, None]
-        n_local = jnp.sum(local.astype(jnp.int32))
+        n_local = held_pairs(idx, cfg.first_expert, cfg.n_experts_held, live)
     with jax.named_scope("istpu.moe.experts"):
         whole = cfg.n_experts_held == cfg.n_experts
         y = routed_experts(flat, idx, w, layer["w_gate"], layer["w_up"],
@@ -328,50 +327,53 @@ def cohere2_moe_prefill_forward(
     params: Params,
     cfg: Cohere2MoeConfig,
     tokens: jax.Array,
-    prefix_kv: jax.Array | None = None,
+    prefix_kv: Tuple[jax.Array, jax.Array] | None = None,
     prefix_len: jax.Array | None = None,
     head: str = "all",
     head_row: jax.Array | None = None,
-) -> Tuple[jax.Array | None, jax.Array]:
-    """tokens [B, S] -> (logits [B, S, V held], kv [L, 2, B, S, H_kv, D]).
+) -> Tuple[jax.Array | None, Tuple[jax.Array, jax.Array]]:
+    """tokens [B, S] -> (logits [B, S, V held], kv BY POOL: the full layers'
+    [L_full, 2, B, S, H_kv, D] and the window layers' [L_win, 2, B, S, H_kv,
+    D]).
 
-    The contract of ``models.llama.prefill_forward``: ``prefix_kv`` [L, 2,
-    B, P, H_kv, D] is the reused prefix's K and V (exact, or a padded buffer
-    of which ``prefix_len`` rows are valid), the returned rows cover the new
-    tokens.  A full layer attends to the whole buffer; a window layer to the
-    ``sliding_window`` rows that end at the prefix's end, SLICED out of the
-    buffer (one static width), then to the chunk's own: rows of the buffer
-    below the slice are not read.  ``head`` / ``head_row``: where the norm
-    and the head run, as there (``llama.head_logits``)."""
+    The contract of ``models.llama.prefill_forward`` with one prefix buffer A
+    POOL (kv/cache.PagedCacheConfig.pools): ``prefix_kv[0]`` [L_full, 2, B, P,
+    H_kv, D] is the reused prefix's K and V (exact, or a padded buffer of
+    which ``prefix_len`` rows are valid), which a full layer attends to whole;
+    ``prefix_kv[1]`` [L_win, 2, B, R, H_kv, D] holds the ``R`` rows that END
+    where the chunk starts (``attention.window_prefix_positions``; the engine
+    keeps the window's worth), then the chunk's own: a window layer's scores
+    are a band of ``R`` + the chunk's keys, and no row below is read or held.
+    The returned rows cover the new tokens.  ``head`` / ``head_row``: where
+    the norm and the head run, as there (``llama.head_logits``)."""
     B, S = tokens.shape
-    P = 0 if prefix_kv is None else prefix_kv.shape[3]
+    P = 0 if prefix_kv is None else prefix_kv[0].shape[3]
     start = P if prefix_len is None else prefix_len
     q_pos = jnp.arange(S) + start
     positions = jnp.broadcast_to(q_pos, (B, S))
     x = params["embed"][tokens]
-    kvs = []
+    kvs = ([], [])
     for li, layer in enumerate(params["layers"]):
         window = cfg.layer_windows[li]
+        p = int(window is not None)   # the layer's pool, and its place in it
+        lp = len(kvs[p])
         h = layernorm(x, layer["ln"], cfg.norm_eps)
         q, k, v = _qkv(layer, cfg, h, positions, window)
-        kvs.append(jnp.stack([k, v], axis=0))
+        kvs[p].append(jnp.stack([k, v], axis=0))
         with jax.named_scope("istpu.attn.window" if window is not None
                              else "istpu.attn.full"):
             k_pos, k_valid = q_pos, None
             if prefix_kv is not None:
-                # the rows of the buffer this layer reads: all of it, or the
-                # window's worth that ends where the prefix ends
-                n = P if window is None else min(window, P)
-                if prefix_len is None:
-                    lo = P - n
-                    pk, pv = prefix_kv[li, 0, :, lo:], prefix_kv[li, 1, :, lo:]
+                pk, pv = prefix_kv[p][lp, 0], prefix_kv[p][lp, 1]
+                if window is not None:
+                    b_pos, b_valid = window_prefix_positions(pk.shape[1], start)
                 else:
-                    lo = jnp.maximum(prefix_len - n, 0)
-                    pk = jax.lax.dynamic_slice_in_dim(prefix_kv[li, 0], lo, n, 1)
-                    pv = jax.lax.dynamic_slice_in_dim(prefix_kv[li, 1], lo, n, 1)
-                    k_valid = jnp.concatenate(
-                        [lo + jnp.arange(n) < prefix_len, jnp.ones((S,), bool)])
-                k_pos = jnp.concatenate([lo + jnp.arange(n), q_pos])
+                    b_pos = jnp.arange(P)
+                    b_valid = (None if prefix_len is None
+                               else b_pos < prefix_len)
+                if b_valid is not None:
+                    k_valid = jnp.concatenate([b_valid, jnp.ones((S,), bool)])
+                k_pos = jnp.concatenate([b_pos, q_pos])
                 k = jnp.concatenate([pk, k], axis=1)
                 v = jnp.concatenate([pv, v], axis=1)
             attn = grouped_chunk_attention(q, k, v, q_pos, k_pos, k_valid,
@@ -379,7 +381,7 @@ def cohere2_moe_prefill_forward(
         ffn, _ = expert_layer(layer, cfg, h)
         x = x + attn.reshape(B, S, -1) @ layer["wo"] + ffn
     return head_logits(x, head, head_row, partial(_head, params, cfg)
-                       ), jnp.stack(kvs)
+                       ), tuple(jnp.stack(rows) for rows in kvs)
 
 
 def cohere2_moe_decode_forward(

@@ -140,6 +140,18 @@ def sigmoid_top_k(scores: jax.Array, bias: jax.Array, top_k: int,
     return idx.astype(jnp.int32), w
 
 
+def held_pairs(idx: jax.Array, first_expert: int, n_held: int,
+               live: jax.Array | None = None) -> jax.Array:
+    """Of the (token, expert) pairs ``idx`` [B * S, k] names, how many name an
+    expert of ``[first_expert, first_expert + n_held)``, the experts one
+    chip's share of an expert-parallel layer holds.  ``live`` [B]: the
+    batch's rows that are sequences (a pad row's pairs are not counted)."""
+    local = (idx >= first_expert) & (idx < first_expert + n_held)
+    if live is not None:
+        local &= jnp.repeat(live, idx.shape[0] // live.shape[0])[:, None]
+    return jnp.sum(local.astype(jnp.int32))
+
+
 def routed_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
                    w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                    held_from: int | None = None) -> jax.Array:

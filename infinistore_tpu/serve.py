@@ -721,7 +721,10 @@ class ServingServer:
             raise ValueError(f"max_tokens must be >= {floor}")
         T = self.engine.pc.block_tokens
         need = -(-(len(prompt) + max_tokens) // T)
-        have = min(n for _, n in self.engine.pc.pools)
+        # the pool of the layers that keep every page of a sequence (the
+        # first); a sliding-window layers' pool beside it holds a sequence's
+        # window only, within its quota (engine._window_quota)
+        have = self.engine.pc.pools[0][1]
         if need > have:
             raise ValueError(
                 f"prompt + max_tokens needs {need} KV pages; this engine "
@@ -2612,10 +2615,11 @@ def main(argv: Optional[List[str]] = None) -> None:
                     "layers with layers that read everything: the blocks of "
                     "the WINDOW layers' own page pool (--n-blocks stays the "
                     "other layers'; a sequence holds window pages for its "
-                    "window only, and for a prompt it computes until each "
-                    "chunk is pushed).  Default: as many as --n-blocks, with "
-                    "which that pool never runs out first; any other model "
-                    "refuses the option")
+                    "window and the chunk it is computing only, a quota it "
+                    "reserves when it is admitted: max batch x (window pages "
+                    "+ chunk pages + 1) blocks serve, the rest keep resident "
+                    "last windows).  Default: as many as --n-blocks; any "
+                    "other model refuses the option")
     ap.add_argument("--state-stride", type=int, default=None,
                     help="for a model whose layers keep a STATE and no key "
                     "or value per token, all of them (power retention) or "

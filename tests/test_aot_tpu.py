@@ -559,6 +559,68 @@ def test_full_layer_on_tpu_reads_live_pages_through_the_kernel(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_pages_of_two_shapes_on_tpu_lie_unpadded_and_no_program_copies_a_pool(v5e):
+    """The window/full family whose layer kinds write pages of two shapes
+    (models/mimo_v2.py) at its published widths (the first 7 layers, 16 of 256
+    experts, the benchmark's pools of 10,240 and 512 blocks): each pool lies
+    on the device in the bytes the count gives it (a row of 4 x 320 or 8 x
+    320 values is whole 128-lane tiles: 5,120 B a token in the two full
+    layers' pool, 25,600 B in the five window layers'), the decode scan
+    (batch 8 over tables of 2,048 pages) and a prefill chunk over the largest
+    prefix buffers (the full layers' 16,384 rows, the window layers' 128)
+    copy NO pool, and the pools that come out of the scan are the donated
+    ones."""
+    from infinistore_tpu.models import mimo_v2
+
+    cfg = mimo_v2.MimoV2Config(
+        n_layers=7, n_experts_held=16, vocab_size=19072,
+        layer_pattern=(0, 1, 1, 1, 1, 0, 1), moe_layers=(0, 1, 1, 1, 1, 1, 1))
+    pc = PagedCacheConfig.for_model(cfg, 10240, T, window_blocks=512)
+    assert pc.cache_bytes == 10240 * T * 5120 + 512 * T * 25600
+    chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    params = _shaped(jax.eval_shape(
+        lambda: mimo_v2.init_mimo_v2_params(cfg, jax.random.PRNGKey(0))), chip)
+    cache = _shaped(jax.eval_shape(lambda: init_cache(pc)), chip)
+    batch, width = 8, 2048
+
+    def decode_scan(params, logits, pos, cache, table):
+        def step(carry, i):
+            logits, cache = carry
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            p = pos + i
+            blocks = jax.tree.map(lambda t: jnp.take_along_axis(
+                t, (p // T)[:, None], axis=1)[:, 0], table)
+            logits, cache, n = mimo_v2.mimo_v2_decode_forward(
+                params, cfg, tok, p, cache, table, p + 1, blocks, p % T)
+            return (logits, cache), (tok, n)
+
+        (logits, cache), (toks, n) = jax.lax.scan(
+            step, (logits, cache), jnp.arange(3))
+        return toks, n.sum(), logits, cache
+
+    scan = jax.jit(decode_scan, donate_argnums=(3,)).lower(
+        params, sds((batch, cfg.vocab_size), cfg.dtype),
+        sds((batch,), jnp.int32), cache,
+        (sds((batch, width), jnp.int32), sds((batch, width), jnp.int32)),
+    ).compile()
+    # the pools as the device lays them out: the count's bytes, not a tile more
+    assert scan.memory_analysis().alias_size_in_bytes == pc.cache_bytes
+    prefix = (sds((2, 1, 1, 16384, 1, 1280), cfg.dtype),
+              sds((5, 1, 1, 128, 1, 2560), cfg.dtype))
+    chunk = jax.jit(lambda p, t, kv, n: mimo_v2.mimo_v2_prefill_forward(
+        p, cfg, t, prefix_kv=kv, prefix_len=n, head="none")).lower(
+        params, sds((1, 512), jnp.int32), prefix, sds((), jnp.int32)).compile()
+    for compiled in (scan, chunk):
+        text = compiled.as_text()
+        assert len(_INSTRUCTION.findall(text)) > 100
+        copies = [c for pool in cache for c in _whole_cache_results(text, pool.shape)]
+        assert not copies, copies
+    # a chunk's scores: one group of 16 query heads over 16.9k keys in a full
+    # layer, a band of 128 + 512 keys in a window layer
+    assert chunk.memory_analysis().temp_size_in_bytes < 700 << 20
+
+
 def test_retention_decode_scan_on_tpu_moves_each_rows_state_in_place(v5e):
     """The power-retention family at its published widths (two layers, the
     benchmark's 16 state slots, 8 rows): the decode scan's slots that come out
@@ -767,13 +829,19 @@ def test_mamba_attention_programs_on_tpu_hold_both_kernels_and_copy_no_slots(v5e
 # (the cells' caches as BENCHMARK.json's configurations size them), and
 # what the TPU compiler says the one program keeps live beside its bands
 _PUSH_CELLS = {
-    # cell: (pools of (layers, planes, heads, blocks, width), stack order,
-    #        temporaries allowed, bytes of the bands)
+    # cell: (pools of (layers, planes, heads, blocks, width), the window
+    #        layers of a stack with a pool a layer kind and the pages each
+    #        pool sends, temporaries allowed, bytes of the bands)
     "qwen2.5-7b-l12": ([(12, 2, 4, 12288, 128)], None, 1 << 20, 12582912),
     "qwen3-8b-l12": ([(12, 2, 8, 6144, 128)], None, 1 << 20, 25165824),
     # the full layer's pool, then the three window layers'
     "command-a-plus-l4-e16": ([(1, 2, 8, 10240, 128), (3, 2, 8, 8192, 128)],
-                              (1, 2, 3, 0), 1 << 20, 8388608),
+                              ((0, 1, 2), (32, 32)), 1 << 20, 8388608),
+    # pages of two shapes: the two full layers' rows of 4 x 320 send every
+    # page of the chunk, the five window layers' rows of 8 x 320 the last 8
+    "mimo-v2-flash-l7-e16": ([(2, 1, 1, 10240, 1280), (5, 1, 1, 512, 2560)],
+                             ((1, 2, 3, 4, 6), (32, 8)), 1 << 20,
+                             2 * 32 * 40960 + 5 * 8 * 81920),
     # the latent page: ONE copy of the whole cache, 1.51 GB padded to
     # [16, 640]-wide tiles, which the bare gather by ids has too (below)
     "kanana-2-30b-a3b-l8": ([(8, 1, 1, 10240, 576)], None, 1700 << 20,
@@ -799,18 +867,35 @@ def test_push_program_on_tpu_adds_no_copy_of_the_cache(cell, v5e):
     once (its default layout puts the blocks innermost, PERF.md section 5):
     the one program has that ONE copy and adds none."""
     from infinistore_tpu.kv import read_pages
-    from infinistore_tpu.kv.transfer import _gather_bands
+    from infinistore_tpu.kv.transfer import (
+        KVTransferEngine,
+        _gather_bands,
+        _gather_bands_by_pool,
+    )
 
-    pools, order, temp_limit, band_bytes = _PUSH_CELLS[cell]
+    pools, by_kind, temp_limit, band_bytes = _PUSH_CELLS[cell]
     chip = SingleDeviceSharding(v5e[0])
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     shapes = [(n, planes, heads, blocks, T, width)
               for n, planes, heads, blocks, width in pools]
     caches = tuple(sds(s, jnp.bfloat16) for s in shapes)
-    ids = tuple(sds((32,), jnp.int32) for _ in shapes)
-    if order is None:
-        caches, ids = caches[0], ids[0]
-    compiled = _gather_bands.lower(caches, ids, order, False, 4).compile()
+    if by_kind is None:
+        caches = caches[0]
+        compiled = _gather_bands.lower(
+            caches, sds((32,), jnp.int32), False, 4).compile()
+    else:
+        # a pool a layer kind: the bands the transfer engine plans, in stack
+        # order, each gathered from its own pool (of its own page shape)
+        window_layers, sent = by_kind
+        pc = PagedCacheConfig(
+            n_layers=sum(n for n, *_ in pools), n_kv_heads=pools[0][2],
+            head_dim=pools[0][4], n_blocks=pools[0][3], block_tokens=T,
+            planes=pools[0][1], window_layers=window_layers,
+            window_blocks=pools[1][3])
+        plan = tuple((p, l0, len(ls)) for p, l0, ls in
+                     KVTransferEngine(None, pc)._band_plan(list(sent)))
+        ids = tuple(sds((n,), jnp.int32) for n in sent)
+        compiled = _gather_bands_by_pool.lower(caches, ids, plan, False).compile()
     mem = compiled.memory_analysis()
     assert band_bytes <= mem.output_size_in_bytes < band_bytes + 4096, mem
     assert mem.temp_size_in_bytes < temp_limit, mem
@@ -819,7 +904,7 @@ def test_push_program_on_tpu_adds_no_copy_of_the_cache(cell, v5e):
     if cell != "kanana-2-30b-a3b-l8":
         assert not copies, copies
         return
-    bare = jax.jit(read_pages).lower(caches, ids).compile()
+    bare = jax.jit(read_pages).lower(caches, sds((32,), jnp.int32)).compile()
     assert len(copies) == len(_whole_cache_results(bare.as_text(),
                                                    shapes[0])) == 1, copies
     assert mem.temp_size_in_bytes <= (

