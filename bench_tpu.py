@@ -721,6 +721,136 @@ def leg_chunk_attention(out: dict) -> None:
     out["chunk_attention"] = res
 
 
+def leg_decode_kernel(out: dict) -> None:
+    """The dense decode attention's kernel ALONE
+    (models/paged_decode_kernel.py): 48 calls a program over the model's
+    layers in turn (a program of two calls is timed by the host's dispatch,
+    0.23 ms, and not by the device), as a decode step makes them, each
+    call's query fed by the last call's output, at the rows and
+    pages the cells' steps have: 29 rows of 64-200 pages in a batch of 32
+    (``qwen2.5-7b-l12.batch-summarize``: 4 KV heads, groups of 7), one row
+    of 190 pages (``qwen3-8b-l12.doc-reask``: 8 KV heads, groups of 4), 8
+    rows of 512-1,024 pages, and the two ``doc-reask-long`` cells whose two
+    attention layers run it at 2 rows of 512-1,032 pages: LFM2's (pages of 4
+    PAIRS of heads of 64, each query head in its own head's lanes of a row
+    of zeros, scores scaled by the head's width) and Jamba's (one KV head,
+    20 query heads).  Milliseconds a call, nanoseconds a page and the share
+    of the bytes' floor (the pages' bytes at the chip's HBM rate,
+    ``benchmarks/peaks.json``), twice: the calls back to back
+    (``ms_a_call``), and with a product of the model's width between two
+    calls, as a step has (``ms_a_call_between_products``, the product's own
+    time taken off: what a call costs when it does not follow itself).  Where
+    ``ISTPU_PARENT_KERNEL`` names another commit's ``paged_decode_kernel.py``,
+    that file first, and whether the tree's output is its output to the bit.
+    Alone, three minutes of a chip: ``python -c "import bench_tpu, json;
+    out = {}; bench_tpu.leg_decode_kernel(out); print(json.dumps(out))"``."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from infinistore_tpu.models import paged_decode_kernel as tree
+    from infinistore_tpu.models.attention import lanes_of_own_head
+
+    smoke = os.environ.get("ISTPU_BENCH_MODEL") == "tiny"
+    T, D = 16, 128
+    # name, layers, live rows, rows of the batch, pages a row (from, to),
+    # table width, KV heads (rows of heads of a page), query heads a KV head,
+    # heads side by side in a page's row
+    shapes = [("batch-summarize", 12, 29, 32, (64, 200), 256, 4, 7, 1),
+              ("doc-reask", 12, 1, 1, (190, 190), 256, 8, 4, 1),
+              ("long", 12, 8, 8, (512, 1024), 1024, 4, 7, 1),
+              ("lfm2-doc-reask-long", 2, 2, 2, (512, 1032), 2048, 4, 8, 2),
+              ("jamba-doc-reask-long", 2, 2, 2, (512, 1032), 2048, 1, 20, 1)]
+    if smoke:
+        shapes = [("smoke", 2, 2, 3, (30, 40), 64, 2, 2, 1),
+                  ("smoke-pairs", 2, 2, 3, (30, 40), 64, 2, 2, 2)]
+        rate = None
+    else:
+        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               "benchmarks", "peaks.json")) as f:
+            rate = json.load(f)[jax.devices()[0].device_kind]["hbm_bytes_per_s"]
+    forms = [("tree", tree)]
+    if os.environ.get("ISTPU_PARENT_KERNEL"):
+        spec = importlib.util.spec_from_file_location(
+            "parent_paged_decode_kernel", os.environ["ISTPU_PARENT_KERNEL"])
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+        forms.insert(0, ("parent", parent))
+
+    calls = 4 if smoke else 48
+
+    def step(module, layers, scale, attend=True, product=False):
+        def run(q, w, cache, table, lens):
+            for i in range(calls):
+                if attend:
+                    o = module.paged_decode_attention_kernel(
+                        q, cache, table, lens, layer=i % layers,
+                        interpret=smoke, scale=scale)
+                    q = (q + o) * jnp.asarray(0.5, q.dtype)
+                if product:
+                    q = jnp.tanh(q.reshape(len(q), -1) @ w).reshape(q.shape)
+            return q
+        return jax.jit(run)
+
+    rng = np.random.RandomState(0)
+    res = {}
+    for name, L, rows, batch, (lo, hi), width, h_kv, group, side in shapes:
+        pages = rng.randint(lo, hi + 1, rows)
+        lens = np.zeros(batch, np.int32)
+        lens[:rows] = pages * T - rng.randint(0, T, rows)
+        n_blocks = int(pages.sum()) + 64
+        table = np.full((batch, width), n_blocks, np.int32)   # pad rows
+        ids = rng.permutation(n_blocks)
+        for b, n in enumerate(pages):
+            table[b, :n], ids = ids[:n], ids[n:]
+        cache = jax.random.normal(
+            jax.random.PRNGKey(0), (L, 2, h_kv, n_blocks, T, D), jnp.bfloat16)
+        q0 = jnp.asarray(rng.randn(batch, h_kv * group, D // side), jnp.bfloat16)
+        scale = None
+        if side > 1:
+            q0, scale = lanes_of_own_head(q0, side, h_kv), (D // side) ** -0.5
+        w = jnp.asarray(rng.randn(h_kv * group * D, h_kv * group * D)
+                        * (h_kv * group * D) ** -0.5, jnp.bfloat16)
+        table, lens = jnp.asarray(table), jnp.asarray(lens)
+        page_bytes = 2 * h_kv * T * D * 2
+
+        def ms_a_layer(fn):
+            return 1e3 * _timeit_chained(
+                lambda q, i: fn(q, w, cache, table, lens), q0, n=40) / calls
+
+        products = ms_a_layer(step(None, L, scale, attend=False, product=True))
+        row = {"rows": rows, "pages_a_call": int(pages.sum()),
+               "whole_block_pages_a_call": tree.pages_by_fill(
+                   np.asarray(lens)[:rows], T, width)[1],
+               "page_bytes": page_bytes,
+               "product_ms": round(products, 5)}
+        first = None
+        for form, module in forms:
+            fn = step(module, L, scale)
+            t0 = time.perf_counter()
+            got = np.asarray(fn(q0, w, cache, table, lens), np.float32)
+            compile_s = time.perf_counter() - t0
+            ms = ms_a_layer(fn)
+            between = ms_a_layer(step(module, L, scale, product=True)) - products
+            row[form] = {"ms_a_call": round(ms, 5),
+                         "ms_a_call_between_products": round(between, 5),
+                         "ns_a_page": round(1e6 * ms / pages.sum(), 2),
+                         "first_call_s": round(compile_s, 2)}
+            if rate:
+                row[form]["bytes_floor_share"] = round(
+                    pages.sum() * page_bytes / rate / (1e-3 * ms), 4)
+            if first is None:
+                first = got
+            else:
+                row[form]["bit_equal_to_" + forms[0][0]] = bool(
+                    np.array_equal(got, first))
+        res[name] = row
+        del cache
+    out["decode_kernel"] = res
+
+
 def leg_distilled_spec(out: dict) -> None:
     """The VERDICT r4 next #1 configuration verbatim: a genuinely cheap
     draft "trained briefly on the target's outputs" vs the 1B target.
@@ -1175,6 +1305,7 @@ def main() -> int:
         ("distilled_spec", leg_distilled_spec),
         ("prefill_breakdown", leg_prefill_breakdown),
         ("chunk_attention", leg_chunk_attention),
+        ("decode_kernel", leg_decode_kernel),
         ("store_hop", leg_store_hop),
         ("prefill_stream", leg_prefill_stream),
     ]

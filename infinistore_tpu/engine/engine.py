@@ -2535,13 +2535,15 @@ class InferenceEngine:
             fl.pen,
         )
         # one compiled scan dispatch advanced the whole batch a chunk
+        width = jax.tree.leaves(fl.block_table)[0].shape[1]
         _stepprof.note_decode(
-            steps=chunk, rows=fl.B, padded_rows=fl.Bp,
-            width_pages=jax.tree.leaves(fl.block_table)[0].shape[1],
+            steps=chunk, rows=fl.B, padded_rows=fl.Bp, width_pages=width,
             block_tokens=fl.T,
             live_tokens=self._live_tokens(fl.pos[:fl.B]),
             expert_routing=getattr(self.cfg, "expert_routing", None),
             attn_kernel=self._attn_in_kernel,
+            kernel_pages=(self._kernel_pages(fl.pos[:fl.B], chunk, fl.T, width)
+                          if self._attn_in_kernel else (0, 0)),
         )
         _stepprof.note_tokens(chunk * fl.B)
         if fl.logprobs:
@@ -2780,6 +2782,21 @@ class InferenceEngine:
         """What ``decode.live_token_steps`` counts a step: the rows' context
         lengths, the tokens a paged attention has to read."""
         return int(lens.sum())
+
+    @staticmethod
+    def _kernel_pages(pos: np.ndarray, steps: int, block_tokens: int,
+                      width: int) -> tuple:
+        """What ``decode.kernel_pages`` and ``decode.kernel_whole_block_pages``
+        count a dispatch: the kernel's own reckoning
+        (``paged_decode_kernel.pages_by_fill``) over the lengths of the
+        dispatch's steps (a row at position ``p`` attends to ``p + 1`` keys).
+        Called where the kernel engages only, so the module is the program's
+        already: Pallas is 1.7 s of import that no other process pays
+        (``attention.paged_decode_attention`` imports it so too)."""
+        from ..models.paged_decode_kernel import pages_by_fill
+
+        return pages_by_fill(pos[:, None] + 1 + np.arange(steps),
+                             block_tokens, width)
 
     def _chunk_attention_in_kernel(self, **kw) -> bool:
         """Whether the prefill program these arguments run holds the TPU's

@@ -104,7 +104,8 @@ MAX_STEP_IDS = 64
 
 # what ``note_decode`` sums, per step record and over the lifetime
 DECODE_COUNTS = ("steps", "row_steps", "live_token_steps",
-                 "table_token_steps", "attn_kernel_steps", "expert_pairs",
+                 "table_token_steps", "attn_kernel_steps", "kernel_pages",
+                 "kernel_whole_block_pages", "expert_pairs",
                  "experts_expected", "expert_pairs_local")
 
 # what ``note_prefill_budget``, ``note_push_wait`` and ``note_prefill_chunk``
@@ -271,7 +272,8 @@ def note_sync(kind: str, n: int = 1) -> None:
 def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
                 block_tokens: int, live_tokens: int,
                 expert_routing: Optional[tuple] = None,
-                attn_kernel: bool = False) -> None:
+                attn_kernel: bool = False,
+                kernel_pages: tuple = (0, 0)) -> None:
     """Count ONE decode-scan dispatch with what the engine knows at the
     call: ``steps`` scan steps over ``rows`` live rows in a batch bucket
     of ``padded_rows``, a block table ``width_pages`` wide, and
@@ -280,7 +282,10 @@ def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
     padded row, the whole width): what the XLA attention reads whatever
     ``seq_lens`` says, and what the TPU's kernel does NOT (it copies the
     live pages; ``attn_kernel`` says this dispatch's dense attention is
-    that kernel, and ``attn_kernel_steps`` sums its steps).
+    that kernel, and ``attn_kernel_steps`` sums its steps;
+    ``kernel_pages`` = (the pages one call of it copies a step, summed over
+    the dispatch's steps; those of them in a block whose every page is live:
+    the blocks it starts in one static run and awaits in one wait).
     ``expert_routing`` = (expert layers, experts a token, experts a layer)
     of a model with routed experts: ``expert_pairs`` counts the (token,
     expert) pairs the steps route (exact: k a row a layer, no token is
@@ -301,6 +306,8 @@ def note_decode(steps: int, rows: int, padded_rows: int, width_pages: int,
     b["live_token_steps"] += live_tokens * steps
     b["table_token_steps"] += padded_rows * width_pages * block_tokens * steps
     b["attn_kernel_steps"] += steps * bool(attn_kernel)
+    b["kernel_pages"] += kernel_pages[0]
+    b["kernel_whole_block_pages"] += kernel_pages[1]
     if expert_routing is not None:
         layers, k, n_experts = expert_routing
         b["expert_pairs"] += rows * k * layers * steps
@@ -697,6 +704,19 @@ class StepProfiler:
             "istpu_engine_dispatches_total{kind=prefill}, the share of chunk "
             "programs that write no score matrix",
         )
+        self._c_kernel_pages = reg.counter(
+            "istpu_engine_decode_kernel_pages_total",
+            "Pages one call of the TPU's decode-attention kernel "
+            "(models/paged_decode_kernel.py) copies, summed over the decode "
+            "steps dispatched: the rows' live pages, by their lengths",
+        )
+        self._c_kernel_whole_block_pages = reg.counter(
+            "istpu_engine_decode_kernel_whole_block_pages_total",
+            "Those of istpu_engine_decode_kernel_pages_total in a block "
+            "whose every page is live (every block of a row but its last): "
+            "the share the kernel starts in one static run and awaits in "
+            "one wait",
+        )
         self._c_compiles = reg.counter(
             "istpu_engine_compiles_total",
             "Backend compiles observed process-wide via jax.monitoring "
@@ -916,6 +936,11 @@ class StepProfiler:
             self._c_sync.labels(k).inc(n)
         for fname, n in rec["retraces"].items():
             self._c_retrace.labels(fname).inc(n)
+        kernel = rec.get("decode", {})
+        if kernel.get("kernel_pages"):
+            self._c_kernel_pages.inc(kernel["kernel_pages"])
+            self._c_kernel_whole_block_pages.inc(
+                kernel["kernel_whole_block_pages"])
         for k, n in rec.get("prefill", {}).items():
             what, _, wait = k.rpartition("_")
             if n and wait in ("dispatch", "settle"):

@@ -11,6 +11,10 @@ three quarters of the decode scan (PERF.md, PR 33).  Here nothing of shape
 a page (``cache[layer, :, :, page]``: 2 x H_kv tiles of ``[T, D]``),
 ``PAGES_PER_BLOCK`` pages a block into one of two VMEM buffers, the next
 block (of this row or of the next) in flight while this one is contracted.
+A block whose pages are all live (by the row's length: every block of a row
+but its last) starts its copies in one run of a static count and awaits
+them in ONE wait for the buffer's bytes; a row's last block walks its own
+count, once to start and once to wait.
 The softmax is online, in float32, over the group's ``G = H // H_kv``
 query heads; bf16 pages feed the MXU as they are.
 
@@ -40,10 +44,30 @@ from jax.sharding import PartitionSpec
 
 # pages a block: 512 tokens of K and V for every KV head, 1-2 MB a buffer.
 # Read on the chip (PERF.md, PR 36): 8 and 16 pages cost a fifth to a half
-# more at 27 rows, 64 pages 11% less there and 10% more at one row; copies
-# unrolled by the page are 17% faster at 27 rows and compile seven times as long
+# more at 27 rows, 64 pages 11% less there and 10% more at one row.
+# What the scalar core does a page sets this kernel's pace, not the memory.
+# Read on the chip (PERF.md, PR 53; ms a call at 29 rows of 64-200 pages, 8
+# rows of 512-1,024, one row of 190): every page in a loop walked twice a
+# block, to start and to wait (the kernel before), 0.244 / 0.378 / 0.0226;
+# whole blocks awaited in ONE wait, their starts still a loop: 0.226 / 0.343
+# / 0.0224; their starts unrolled besides (what ``block`` does): 0.201 /
+# 0.300 / 0.0224, 55 and 49 ns a page where the bytes allow 40, at a lowering
+# 0.1 s dearer.  Mosaic unrolls a loop by 1 or by its whole count and refuses
+# anything between.  The last block as a ladder of static runs and waits by
+# the bits of its count gained nothing (a twentieth of the cells' pages) and
+# cost 4% at one row
 PAGES_PER_BLOCK = 32
 _MASKED = -0.7 * float(np.finfo(np.float32).max)
+
+
+def pages_by_fill(lens: np.ndarray, block_tokens: int, width: int) -> tuple:
+    """On the host, what one call of the kernel copies for live rows of these
+    lengths under a table ``width`` pages wide: (all its pages, those of them
+    in a WHOLE block, ``PAGES_PER_BLOCK`` live pages: the blocks ``block``
+    starts in one static run and awaits in one wait).  ``lens``: any shape."""
+    pages = np.minimum(-(-np.asarray(lens) // block_tokens), width)
+    whole = pages // PAGES_PER_BLOCK * PAGES_PER_BLOCK
+    return int(pages.sum()), int(whole.sum())
 
 
 def _kernel(table_ref, lens_ref, layer_ref, q_ref, cache_ref, o_ref, buf, sems,
@@ -66,30 +90,47 @@ def _kernel(table_ref, lens_ref, layer_ref, q_ref, cache_ref, o_ref, buf, sems,
         return pltpu.make_async_copy(
             cache_ref.at[layer, :, :, page], buf.at[slot, j], sems.at[slot])
 
-    def each_page(b, i, slot, do):
-        # ``do`` ("start" or "wait") to the copy of every live page of a block
-        def body(j, _):
+    def block(b, i, slot, do):
+        # ``do`` ("start" or "wait") to the copies of block i of row b.  The
+        # scalar core sets the pace of this kernel, not the memory (see
+        # PAGES_PER_BLOCK), so a WHOLE block, all P pages live (by the row's
+        # length: every block of a row but its last), starts its copies in a
+        # run of a static count and awaits them in ONE wait: each page's copy
+        # signals the slot's semaphore, and a descriptor as large as the slot
+        # stands for the P of them.  A row's last block walks its own count.
+        live = n_pages(b) - i * P
+
+        def each(j, _):
             getattr(page_copy(b, i, slot, j), do)()
             return 0
 
-        lax.fori_loop(0, jnp.minimum(n_pages(b) - i * P, P), body, 0)
+        @pl.when(live >= P)
+        def _():
+            if do == "wait":
+                pltpu.make_async_copy(
+                    buf.at[slot], buf.at[slot], sems.at[slot]).wait()
+            else:
+                lax.fori_loop(0, P, each, 0, unroll=True)
 
-    start = functools.partial(each_page, do="start")
+        @pl.when(live < P)
+        def _():
+            lax.fori_loop(0, live, each, 0)
 
-    def next_live_row(b):
-        # the first row after b that has a page to read, or B
+    def next_live_row(r):
+        # the first row from r on that has a page to read, or B
         return lax.while_loop(
             lambda r: (r < B) & (n_chunks(jnp.minimum(r, B - 1)) == 0),
-            lambda r: r + 1, b + 1)
+            lambda r: r + 1, r)
+
+    def start(b, i, slot):
+        @pl.when(b < B)
+        def _():
+            block(b, i, slot, "start")
 
     # the buffers' slots past a row's last page keep what an earlier block
     # left there: zeros first, so that what a masked key multiplies is finite
     buf[...] = jnp.zeros_like(buf)
-    first = next_live_row(-1)
-
-    @pl.when(first < B)
-    def _():
-        start(first, 0, 0)
+    start(next_live_row(0), 0, 0)
 
     def row(b, slot):
         length = lens_ref[b]
@@ -98,19 +139,14 @@ def _kernel(table_ref, lens_ref, layer_ref, q_ref, cache_ref, o_ref, buf, sems,
         def chunk(i, carry):
             slot, m, l, acc = carry
 
-            @pl.when(i + 1 < chunks)
-            def _():
-                start(b, i + 1, 1 - slot)
-
-            @pl.when(i + 1 == chunks)
-            def _():
-                nxt = next_live_row(b)
-
-                @pl.when(nxt < B)
-                def _():
-                    start(nxt, 0, 1 - slot)
-
-            each_page(b, i, slot, "wait")
+            # the next block in flight while this one is contracted: this
+            # row's, or behind its last the next live row's first (row b
+            # itself is live, so the search from b stands still).  ONE site,
+            # so that the static run is in the program twice, not four times
+            last = i + 1 == chunks
+            start(next_live_row(jnp.where(last, b + 1, b)),
+                  jnp.where(last, 0, i + 1), 1 - slot)
+            block(b, i, slot, "wait")
             pos = i * (P * T) + lax.broadcasted_iota(jnp.int32, (G, P * T), 1)
             visible = pos < length
             m_out, l_out, acc_out = [], [], []

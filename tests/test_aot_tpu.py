@@ -534,6 +534,45 @@ def test_window_layers_on_tpu_gather_their_window_and_no_expert_leaf_is_copied(v
     assert mem.alias_size_in_bytes >= pc.cache_bytes, mem
 
 
+@pytest.mark.parametrize("batch,h_kv,group", [(32, 4, 7), (8, 8, 4), (2, 1, 20)],
+                         ids=["qwen2.5-32-rows", "qwen3-8-rows", "jamba-2-rows"])
+def test_decode_kernel_alone_on_tpu_holds_its_static_run_twice_and_two_waits(
+        batch, h_kv, group, v5e, monkeypatch, capsys):
+    """The kernel ALONE at the dense cells' heads (and Jamba's one KV head of
+    20 query heads) and a table of 256 pages:
+    the TPU's compiler takes its whole blocks' static run of copy starts and
+    their ONE wait on a descriptor as large as a slot, and what it costs to
+    lower is held by what the Mosaic module holds: the run of
+    ``PAGES_PER_BLOCK`` starts at the two places that start a block (the
+    first, and the prefetch of the next block or the next row's first) beside
+    each place's own loop of one, 66 starts, and two waits (a whole block's
+    one, the last block's loop of one).  The parent's module held 3 and 1;
+    copies unrolled by the page at all four places that start or wait took
+    seven times the parent's seconds to lower and compile (PR 36), where this
+    takes 0.58 / 0.69 s against 0.49 / 0.61 (by hand, PR 53, no chip)."""
+    from jax.experimental import pallas as pl
+
+    from infinistore_tpu.models import paged_decode_kernel
+
+    monkeypatch.setattr(
+        pl, "pallas_call", functools.partial(pl.pallas_call, debug=True))
+    chip = SingleDeviceSharding(v5e[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    # ``_call`` under a jit of this test's own: whatever this process has
+    # traced before, the kernel is traced here, and prints its module
+    compiled = jax.jit(paged_decode_kernel._call.__wrapped__).lower(
+        sds((batch, h_kv, group, 128), jnp.bfloat16),
+        sds((12, 2, h_kv, 4096, T, 128), jnp.bfloat16),
+        sds((batch, 256), jnp.int32), sds((batch,), jnp.int32),
+        sds((1,), jnp.int32)).compile()
+    assert len(_kernel_calls(compiled.as_text())) == 1
+    module = capsys.readouterr().out
+    starts = len(re.findall(r"\btpu\.enqueue_dma\b", module))
+    waits = len(re.findall(r"\btpu\.wait_dma", module))
+    assert (starts, waits) == (
+        2 * (paged_decode_kernel.PAGES_PER_BLOCK + 1), 2), (starts, waits)
+
+
 def test_full_layer_on_tpu_reads_live_pages_through_the_kernel(v5e):
     """The same program (groups of 16 query heads over 8 KV heads, 8 rows x
     2,048 pages): the one full layer's attention is one call of the kernel

@@ -28,6 +28,19 @@ WIDTH = 2 * PAGES_PER_BLOCK
 LENGTHS = (1, T - 1, T + 1, PAGES_PER_BLOCK * T, PAGES_PER_BLOCK * T + 1,
            WIDTH * T)
 GROUPS = [(8, 4), (4, 7), (8, 16)]       # (H_kv, G) of the benchmark's cells
+BLOCK = PAGES_PER_BLOCK * T              # the tokens of a whole block
+# what the kernel does by a block's fill (a WHOLE block's copies start in a
+# static run and are awaited in one wait, a row's last block walks its own
+# count): lengths a row, a pad row where None, the table's width in blocks
+BLOCK_FILLS = {
+    "one-whole-block": ((BLOCK,), 2),
+    "two-whole-blocks": ((2 * BLOCK,), 2),
+    "five-whole-blocks": ((5 * BLOCK,), 5),
+    "a-whole-block-then-a-one-page-tail": ((BLOCK + 1, BLOCK + T), 2),
+    "a-short-row-beside-a-long-one": ((3 * T + 2, 3 * BLOCK + 5 * T), 4),
+    # the next row's first block is started across the pad row
+    "a-pad-row-between-two-long-rows": ((2 * BLOCK + 7, None, 2 * BLOCK), 3),
+}
 
 
 def _case(h_kv, group, lengths, width, pad_rows=2, seed=0, D=D):
@@ -36,6 +49,8 @@ def _case(h_kv, group, lengths, width, pad_rows=2, seed=0, D=D):
     (``engine._block_table``'s pad), and a second cache in which every page
     past a row's length and every page no row names is NaN."""
     rng = np.random.default_rng(seed)
+    pads_within = [b for b, n in enumerate(lengths) if n is None]
+    lengths = [n or 0 for n in lengths]
     need = [-(-n // T) for n in lengths]
     n_blocks = sum(need) + 7
     cache = rng.standard_normal((L, 2, h_kv, n_blocks, T, D)).astype(np.float32)
@@ -52,6 +67,8 @@ def _case(h_kv, group, lengths, width, pad_rows=2, seed=0, D=D):
         table[b, n:] = order[0]
         named.extend(ids)
     lens = np.asarray(list(lengths) + list(range(1, pad_rows + 1)), np.int32)
+    for b in pads_within:                   # a pad row keeps a length: unread
+        table[b], lens[b] = n_blocks, 5
     poisoned = np.full_like(cache, np.nan)
     poisoned[:, :, :, named] = cache[:, :, :, named]
     q = rng.standard_normal((B, h_kv * group, D)).astype(np.float32)
@@ -165,6 +182,48 @@ def test_heads_of_64_side_by_side_in_pairs_agree_with_the_xla_form_by_head():
     assert (alone == got[4]).all()
 
 
+@pytest.mark.parametrize("h_kv,group", [(8, 4), (4, 7), (1, 20)],
+                         ids=["qwen3", "qwen2.5", "jamba"])
+@pytest.mark.parametrize("fill", list(BLOCK_FILLS))
+def test_whole_blocks_and_a_rows_last_block_agree_and_a_row_is_bit_equal(
+        fill, h_kv, group):
+    """By the fill of a row's blocks: agreement with the XLA form and the
+    float32 arithmetic, dead pages NaN, and every live row bit-equal alone,
+    among 32 rows and under a wider table."""
+    lengths, blocks = BLOCK_FILLS[fill]
+    width = blocks * PAGES_PER_BLOCK
+    q, cache, poisoned, table, lens = _case(h_kv, group, lengths, width,
+                                            pad_rows=0)
+    live = [b for b, n in enumerate(lengths) if n is not None]
+    want = np.asarray(attention._paged_decode_attention_xla(
+        q, cache, table, lens, layer=LAYER), np.float32)
+    exact = _float32_reference(q, cache, table, lens)
+    got = _kernel(q, poisoned, table, lens)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], want[live], atol=2e-2)
+    np.testing.assert_allclose(got[live], exact[live], atol=8e-3)
+    assert (np.delete(got, live, axis=0) == 0).all()
+    # the rows again among short ones and pad rows, 32 in all, at other places
+    pad = jnp.full((1, width), poisoned.shape[3], jnp.int32)
+    short = jnp.concatenate([table[live[0]:live[0] + 1, :1], pad[:, 1:]], axis=1)
+    rows, at = [], {}
+    for b in live:
+        rows += [(short, 9, q[live[0]]), (pad, 3, q[b])] * 2
+        at[b] = len(rows)
+        rows.append((table[b:b + 1], lens[b], q[b]))
+    rows += [(short, 11, q[live[0]])] * (32 - len(rows))
+    among = _kernel(jnp.stack([r[2] for r in rows]), poisoned,
+                    jnp.concatenate([r[0] for r in rows]),
+                    jnp.asarray([r[1] for r in rows], jnp.int32))
+    for b in live:
+        alone = _kernel(q[b:b + 1], poisoned, table[b:b + 1], lens[b:b + 1])[0]
+        wider = _kernel(q[b:b + 1], poisoned,
+                        jnp.concatenate([table[b:b + 1], pad, pad], axis=1),
+                        lens[b:b + 1])[0]
+        assert (got[b] == alone).all() and (got[b] == wider).all()
+        assert (got[b] == among[at[b]]).all()
+
+
 def test_a_batch_of_pad_rows_only_reads_nothing():
     q, _, poisoned, table, lens = _case(4, 7, (), WIDTH, pad_rows=3)
     assert (_kernel(q, poisoned, table, lens) == 0).all()
@@ -234,6 +293,40 @@ def test_attn_kernel_steps_counts_the_dispatched_steps_of_a_kernel_program(
                              attn_kernel=attn_kernel)
     d = prof.summary()["decode"]
     assert d["steps"] == 32 and d["attn_kernel_steps"] == steps
+
+
+@pytest.mark.parametrize("pos,steps,width,pages,whole", [
+    # one row at position 30: lengths 31 and 32 are 2 pages, 33 and 34 are 3
+    ([30], 4, 64, 2 + 2 + 3 + 3, 0),
+    # the row's 32nd page fills at length 512 = position 511, and stays whole
+    ([509], 4, 64, 32 * 3 + 33, 32 * 4),
+    # batch-summarize's rows: 3 of 4 blocks whole, 2 of 3, none of 1
+    ([16 * 100 - 1, 16 * 70 - 1, 16 * 20 - 1], 1, 256, 190, 96 + 64),
+    # the kernel reads no page past the table's width: 70 pages under 64
+    ([16 * 70 - 1, 16 * 20 - 1], 1, 64, 64 + 20, 64),
+], ids=["under-a-block", "across-a-block-boundary", "three-rows",
+        "clamped-to-the-width"])
+def test_the_engine_counts_the_kernels_pages_and_those_in_whole_blocks(
+        pos, steps, width, pages, whole):
+    from infinistore_tpu.engine import InferenceEngine
+
+    counted = InferenceEngine._kernel_pages(np.asarray(pos), steps, T, width)
+    assert counted == (pages, whole)
+    metrics = MetricsRegistry()
+    prof = stepprof.StepProfiler(metrics=metrics, sample=10**9)
+    for _ in range(2):
+        with prof.step():
+            stepprof.note_decode(steps=steps, rows=len(pos), padded_rows=4,
+                                 width_pages=width, block_tokens=T,
+                                 live_tokens=1,
+                                 attn_kernel=True, kernel_pages=counted)
+    d = prof.summary()["decode"]
+    assert (d["kernel_pages"], d["kernel_whole_block_pages"]) == (
+        2 * pages, 2 * whole)
+    text = metrics.to_prometheus_text()
+    assert f"istpu_engine_decode_kernel_pages_total {2 * pages}" in text
+    assert ("istpu_engine_decode_kernel_whole_block_pages_total "
+            f"{2 * whole}") in text
 
 
 class _OnTpu:
